@@ -1,0 +1,710 @@
+#!/usr/bin/env python
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, no children. Drives the main path once through the entry
+points a user would call, at sizes users of a stencil library call real,
+on the TPU this process finds (one chip, or the four chips of one host):
+
+- jacobi3d 512^3 fp32 (``apps.jacobi3d.run``): tight-x layout, temporal
+  multistep kernel; checked against the numpy reference at 128^3 through
+  the same kernels and against the XLA path at 512^3;
+- jacobi3d 768^3 fp32: the row-tiled multistep, checked against the XLA
+  path at 768^3;
+- halo exchange 512^3, radius 3, 4 x fp32 (``DistributedDomain.exchange``):
+  the Pallas self-fills, every halo cell of every quantity verified;
+- Astaroth 256^3, 8 x fp32, radius 3 (``apps.astaroth.run``): the three
+  fused RK3 substep kernels; finite, and equal to the XLA path at 64^3;
+- serving: 16 jacobi jobs of 64^3 through ``serve.ServeScheduler``, each
+  result bit-identical to ``campaign.run_sequential`` on the same chip;
+- with four chips: weak-scaled jacobi3d (global 512x1024x1024 on (1,2,2),
+  multi-block tight-x, overlap on) and the r3 4 x fp32 exchange at 512^3
+  per chip with every halo cell verified — ``ppermute`` over ICI — plus a
+  small global size against the numpy reference, four addressable shards
+  per array and balanced device memory.
+
+It fails at once, non-zero, when ``jax.devices()[0].platform`` is not
+``tpu``; any phase that fails makes the run exit non-zero after the other
+phases have run; the last line of stdout is the pass line
+``{"ok": true, "device": {...}}`` only when every phase this machine can
+run has passed. ``--rehearsal`` walks the same phase functions on the CPU
+at tiny sizes with interpret-mode kernels: it prints ``rehearsal``, never
+the pass line, and never exits 0.
+
+    python chip_smoke.py                # on the chip
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+        python chip_smoke.py --rehearsal
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import importlib.metadata
+import json
+import os
+import statistics
+import sys
+import tempfile
+import time
+import traceback
+
+import jax
+
+NO_TPU_RC = 2
+REHEARSAL_RC = 3
+PARTIAL_RC = 4
+
+_PRIME = 16777213  # largest prime below 2**24: every pattern value is exact in fp32
+
+
+def say(msg: str) -> None:
+    print(f"[smoke] {msg}", flush=True)
+
+
+# ------------------------------------------------------------ observation
+
+
+class PallasRecorder:
+    """Records every ``pl.pallas_call`` built while active: which kernel,
+    its grid, and whether it is an interpret-mode build. The smoke asserts
+    the kernel path from what was actually built, not from the selection
+    helpers (``_want_pallas`` / ``_self_fills``) that chose it."""
+
+    def __enter__(self):
+        from jax.experimental import pallas as pl
+
+        self._pl, self._orig, self.calls = pl, pl.pallas_call, []
+
+        def recording(kernel, *args, **kw):
+            gs = kw.get("grid_spec")
+            self.calls.append({
+                "kernel": kernel.__qualname__.split(".")[0],
+                "grid": tuple(kw.get("grid") or getattr(gs, "grid", ())),
+                "interpret": bool(kw.get("interpret", False)),
+            })
+            return self._orig(kernel, *args, **kw)
+
+        pl.pallas_call = recording
+        return self
+
+    def __exit__(self, *exc):
+        self._pl.pallas_call = self._orig
+
+    def of(self, kernel: str) -> list:
+        return [c for c in self.calls if c["kernel"] == kernel]
+
+
+def require_compiled_kernels(rec: PallasRecorder, kernels, rehearsal: bool):
+    """Every listed kernel family was built, and (on the chip) none of the
+    recorded builds is an interpret-mode one — libtpu compiled them all."""
+    missing = [k for k in kernels if not rec.of(k)]
+    assert not missing, f"kernels never built: {missing}; built {rec.calls}"
+    if not rehearsal:
+        interp = [c for c in rec.calls if c["interpret"]]
+        assert not interp, f"interpret-mode kernels on the chip: {interp}"
+
+
+def require_balanced(devs, what: str, tol: float = 0.10) -> list:
+    """Per-device ``bytes_in_use`` and ``peak_bytes_in_use`` within ``tol``
+    of each other — no chip staged another chip's share."""
+    keys = ("bytes_in_use", "peak_bytes_in_use")
+    stats = [{k: int(d.memory_stats()[k]) for k in keys} for d in devs]
+    for key in keys:
+        vals = [s[key] for s in stats]
+        assert min(vals) > 0 and max(vals) <= (1 + tol) * min(vals), (
+            f"{what}: {key} unbalanced across chips: {vals}")
+    return stats
+
+
+def require_four_shards(arr, devs, what: str) -> None:
+    shards = arr.addressable_shards
+    on = {s.device for s in shards}
+    assert len(shards) == len(devs) and on == set(devs), (
+        f"{what}: {len(shards)} shards on {sorted(d.id for d in on)}, "
+        f"want one on each of {sorted(d.id for d in devs)}")
+
+
+# ------------------------------------------------------------ jacobi
+
+
+@functools.lru_cache(maxsize=2)
+def _sel(size):
+    """``sphere_sel(size)``, computed once per size: at 768^3 the host
+    takes longer over it than the chip over the whole phase."""
+    from stencil_tpu.ops.jacobi import sphere_sel
+
+    return sphere_sel(size)
+
+
+def _masks(size):
+    sel = _sel(size)
+    return sel == 1, sel == 2
+
+
+def _random_field(size, seed: int):
+    import numpy as np
+
+    return np.random.RandomState(seed).rand(size.z, size.y, size.x).astype(
+        np.float32)
+
+
+def _run_loop(ex, loop, field, size):
+    """``field`` advanced by ``loop`` on ``ex``'s layout, back on the host."""
+    import numpy as np
+
+    from stencil_tpu.parallel.exchange import shard_blocks, unshard_blocks
+
+    curr = shard_blocks(field, ex.spec, ex.mesh)
+    nxt = shard_blocks(np.zeros_like(field), ex.spec, ex.mesh)
+    sel = shard_blocks(_sel(size), ex.spec, ex.mesh)
+    curr, nxt = loop(curr, nxt, sel)
+    return unshard_blocks(curr, ex.spec)
+
+
+def _jacobi_exchange(size, dim, devs, tight_x: bool):
+    """A radius-1 exchange. ``tight_x`` is the layout ``jacobi3d.run``
+    realizes on TPU devices (zero x radius, single-block x axis; the
+    rehearsal builds it by hand because ``run`` only chooses it on a TPU);
+    otherwise inline halos on every axis, which the XLA path needs."""
+    from stencil_tpu.domain.grid import GridSpec
+    from stencil_tpu.geometry import Radius
+    from stencil_tpu.parallel import HaloExchange, grid_mesh
+
+    r = Radius.constant(1)
+    spec = GridSpec(size, dim, r.without_x() if tight_x else r)
+    return HaloExchange(spec, grid_mesh(dim, devs))
+
+
+def _multistep_depth(rec: PallasRecorder, nz: int):
+    """(k, row_tiled) of the temporal multistep that was built, read off
+    its grid: the wavefront runs J = nz + 2k steps."""
+    rows = rec.of("_make_multistep_row_tiled")
+    full = rec.of("make_pallas_jacobi_multistep")
+    assert rows or full, f"no temporal multistep built: {rec.calls}"
+    grid = (rows or full)[-1]["grid"]
+    return (grid[-1] - nz) // 2, bool(rows)
+
+
+def _check_spheres(out, size, what: str) -> None:
+    import numpy as np
+
+    from stencil_tpu.ops.jacobi import COLD_TEMP, HOT_TEMP
+
+    hot, cold = _masks(size)
+    assert np.isfinite(out).all(), f"{what}: non-finite cells"
+    assert (out[hot] == HOT_TEMP).all() and (out[cold] == COLD_TEMP).all(), (
+        f"{what}: hot/cold sphere cells changed")
+
+
+def phase_jacobi(devs, n: int, rehearsal: bool, *, ref_n=None,
+                 want_rows: bool = False, time_sync: bool = False) -> dict:
+    """jacobi3d at ``n``^3 on one chip through ``apps.jacobi3d.run``, then
+    the same kernels against numpy (at ``ref_n``^3) and against the XLA
+    path (at ``n``^3)."""
+    import numpy as np
+
+    from stencil_tpu.apps import jacobi3d
+    from stencil_tpu.geometry import Dim3
+    from stencil_tpu.ops.jacobi import (_want_pallas, jacobi_reference,
+                                        make_jacobi_loop)
+
+    one = Dim3(1, 1, 1)
+    dev = devs[:1]
+    # rehearsal: x stays a lane multiple so the tight-x kernels can run
+    size = Dim3(128, n, n) if rehearsal else Dim3(n, n, n)
+    facts = {}
+
+    # 1. the function main() calls
+    with PallasRecorder() as rec:
+        r = jacobi3d.run(size.x, size.y, size.z, weak=False, devices=dev,
+                         iters=24, chunk=12)
+    ex = r["domain"].halo_exchange
+    k = min(12, (size.z - 1) // 2)
+    if not rehearsal:
+        rx = ex.spec.radius
+        assert rx.x(-1) == 0 and rx.x(1) == 0, f"not tight-x: {rx}"
+        assert ex.spec == _jacobi_exchange(size, one, dev, True).spec
+        assert _want_pallas(ex, None)
+        require_compiled_kernels(rec, [], rehearsal)
+        k, row_tiled = _multistep_depth(rec, size.z)
+        assert k >= 2, f"temporal multistep did not engage (k={k})"
+        assert row_tiled == want_rows, (
+            f"row-tiled staging {row_tiled}, expected {want_rows}")
+        facts.update(temporal_k=k, row_tiled=row_tiled,
+                     mcells_per_s=round(r["mcells_per_s"], 1))
+    _check_spheres(r["domain"].get_curr_global(r["handle"]), size,
+                   f"jacobi3d.run {size}")
+    del r
+
+    def kernel_loop(exch, iters):
+        # the chip takes the users' selection (use_pallas=None ->
+        # _want_pallas, asserted above); the rehearsal forces interpret
+        return make_jacobi_loop(exch, iters,
+                                use_pallas=True if rehearsal else None,
+                                interpret=rehearsal)
+
+    # 2. the same kernels against the plain numpy reference: one full
+    # temporal block from a random field, on the layout run() chose
+    if ref_n is not None:
+        rsize = Dim3(128, ref_n, ref_n) if rehearsal else Dim3(ref_n, ref_n,
+                                                               ref_n)
+        rk = min(12, (rsize.z - 1) // 2)
+        rex = _jacobi_exchange(rsize, one, dev, True)
+        field = _random_field(rsize, 1)
+        with PallasRecorder() as rec:
+            got = _run_loop(rex, kernel_loop(rex, rk), field, rsize)
+        require_compiled_kernels(rec, ["make_pallas_jacobi_multistep"],
+                                 rehearsal)
+        want = jacobi_reference(field, _masks(rsize), rk)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+        facts["numpy_ref"] = f"{rsize} x {rk} steps ok"
+
+    # 3. Pallas path against the XLA path, one full temporal block
+    if rehearsal:
+        ex = _jacobi_exchange(size, one, dev, True)
+    field = _random_field(size, 2)
+    loop = kernel_loop(ex, k)
+    got = _run_loop(ex, loop, field, size)
+    if time_sync:
+        facts["sync"] = _time_sync(ex, loop, field, size)
+    del loop
+    ex_xla = _jacobi_exchange(size, one, dev, False)
+    want = _run_loop(ex_xla, make_jacobi_loop(ex_xla, k, use_pallas=False),
+                     field, size)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+    _check_spheres(got, size, f"pallas {size}")
+    facts["vs_xla"] = (f"{k} steps, max|diff| "
+                       f"{float(np.abs(got - want).max()):.3g}, "
+                       f"bit-identical {bool(np.array_equal(got, want))}")
+    return facts
+
+
+def _time_sync(ex, loop, field, size) -> dict:
+    """One fused chunk timed both ways: ``jax.block_until_ready`` and the
+    scalar-fetch ``hard_sync``. ``fetch_after_bur`` is a ``hard_sync``
+    issued right after ``block_until_ready`` returned: were readiness
+    reported early, the remaining work would show up there."""
+    import numpy as np
+
+    from stencil_tpu.parallel.exchange import shard_blocks
+    from stencil_tpu.utils.sync import hard_sync
+
+    curr = shard_blocks(field, ex.spec, ex.mesh)
+    nxt = shard_blocks(np.zeros_like(field), ex.spec, ex.mesh)
+    sel = shard_blocks(_sel(size), ex.spec, ex.mesh)
+    t = {"block_until_ready": [], "hard_sync": [], "fetch_after_bur": []}
+    for _ in range(7):
+        t0 = time.perf_counter()
+        curr, nxt = loop(curr, nxt, sel)
+        jax.block_until_ready(curr)
+        t1 = time.perf_counter()
+        hard_sync(curr)
+        t2 = time.perf_counter()
+        curr, nxt = loop(curr, nxt, sel)
+        hard_sync(curr)
+        t3 = time.perf_counter()
+        t["block_until_ready"].append(t1 - t0)
+        t["fetch_after_bur"].append(t2 - t1)
+        t["hard_sync"].append(t3 - t2)
+    return {f"{k}_ms": round(1e3 * statistics.median(v), 4)
+            for k, v in t.items()}
+
+
+# ------------------------------------------------------------ exchange
+
+
+def _pattern_fns(spec, sharding):
+    """``(fill, check)`` for the coordinate pattern on the stacked layout
+    (the idiom of ``__graft_entry__._dryrun_direct26``, built on the
+    device so no chip stages the global array): cell (gz, gy, gx) of
+    quantity q holds ``(linear index + 7919 q) mod _PRIME`` — exact in
+    fp32. ``fill(q)`` writes compute cells only (halos zero);
+    ``check(arr, q)`` counts, over every compute and halo cell (faces,
+    edges and corners), the cells that differ from the periodically
+    wrapped source coordinate, and the halo cells it looked at."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    g, b, r = spec.global_size, spec.base, spec.radius
+    off = spec.compute_offset()
+    shape = spec.stacked_shape_zyx()
+    assert spec.is_uniform()
+
+    def axis(bdim, base, o, rm, rp, glob):
+        bi = lax.broadcasted_iota(jnp.int32, shape, bdim)
+        li = lax.broadcasted_iota(jnp.int32, shape, bdim + 3)
+        owned = (li >= o) & (li < o + base)
+        held = (li >= o - rm) & (li < o + base + rp)
+        return jnp.mod(bi * base + li - o, glob), owned, held
+
+    def cells(q):
+        gz, oz, hz = axis(0, b.z, off.z, r.z(-1), r.z(1), g.z)
+        gy, oy, hy = axis(1, b.y, off.y, r.y(-1), r.y(1), g.y)
+        gx, ox, hx = axis(2, b.x, off.x, r.x(-1), r.x(1), g.x)
+        want = jnp.mod(gz * (g.y * g.x) + gy * g.x + gx + 7919 * q, _PRIME)
+        return want.astype(jnp.float32), oz & oy & ox, hz & hy & hx
+
+    def fill(q):
+        want, owned, _ = cells(q)
+        return jnp.where(owned, want, 0.0)
+
+    def check(arr, q):
+        want, owned, held = cells(q)
+        bad = jnp.sum((arr != want) & held, dtype=jnp.int32)
+        return bad, jnp.sum(held & ~owned, dtype=jnp.int32)
+
+    return (jax.jit(fill, out_shardings=sharding),
+            jax.jit(check, in_shardings=(sharding, None)))
+
+
+def phase_exchange(devs, size, partition, rehearsal: bool) -> dict:
+    """The exchange_weak configuration (radius 3, four fp32 quantities)
+    through ``DistributedDomain`` -> ``exchange()``, every halo cell of
+    every quantity verified."""
+    from stencil_tpu.api import DistributedDomain
+    from stencil_tpu.parallel import NodeAware
+
+    nq = 4
+    multi = len(devs) > 1
+    dd = DistributedDomain(size.x, size.y, size.z)
+    dd.set_radius(3)
+    dd.set_devices(devs)
+    dd.set_partition(partition)
+    # exchange_weak's default placement: QAP over the halo volumes and the
+    # chips' ICI distances (device.coords, parallel/device_topo.py)
+    dd.set_placement(NodeAware())
+    handles = [dd.add_data(f"q{i}", "float32") for i in range(nq)]
+    with PallasRecorder() as rec:
+        dd.realize()
+        fill, check = _pattern_fns(dd.spec, dd.sharding())
+        for q, h in enumerate(handles):
+            dd.set_curr(h, fill(q))
+        if multi:
+            for h in handles:
+                require_four_shards(dd.get_curr(h), devs, f"exchange {h.name}")
+        facts = {}
+        if multi and not rehearsal:
+            facts["bytes_after_init"] = require_balanced(devs, "exchange init")
+        before = [check(dd.get_curr(h), q) for q, h in enumerate(handles)]
+        dd.exchange()
+    ex = dd.halo_exchange
+    if not rehearsal:
+        # single-block axes must take the Pallas self-fills, built here
+        single = [a for a, d in zip("xyz", (partition.x, partition.y,
+                                            partition.z)) if d == 1]
+        assert sorted(ex._self_fills) == sorted(single), (
+            f"self-fills {sorted(ex._self_fills)} != single-block axes "
+            f"{single}")
+        require_compiled_kernels(rec, ["make_self_fill"], rehearsal)
+        assert len(rec.of("make_self_fill")) >= len(single)
+    halo_cells = 0
+    for q, h in enumerate(handles):
+        bad0, n_halo = (int(v) for v in before[q])
+        bad, _ = (int(v) for v in check(dd.get_curr(h), q))
+        # the checker must have seen the unfilled halos as wrong, or a
+        # zero count afterwards would prove nothing
+        assert bad0 > 0.99 * n_halo > 0, (q, bad0, n_halo)
+        assert bad == 0, f"quantity {q}: {bad} wrong cells after exchange"
+        halo_cells += n_halo
+    if multi:
+        for h in handles:
+            require_four_shards(dd.get_curr(h), devs, f"exchange {h.name}")
+        if not rehearsal:
+            facts["bytes_after_run"] = require_balanced(devs, "exchange run")
+    facts.update(halo_cells_verified=halo_cells,
+                 self_fills=sorted(ex._self_fills),
+                 mesh=[[d.id, list(getattr(d, "coords", ()))]
+                       for d in dd.mesh.devices.flat])
+    return facts
+
+
+# ------------------------------------------------------------ astaroth
+
+
+def phase_astaroth(devs, nx: int, ref_nx: int, rehearsal: bool) -> dict:
+    """Astaroth 8 x fp32 radius 3 through ``apps.astaroth.run``: finite at
+    ``nx``^3, and equal to the XLA path at ``ref_nx``^3 to the tolerance
+    tests/test_pallas_astaroth.py uses."""
+    import numpy as np
+
+    from stencil_tpu.apps import astaroth
+    from stencil_tpu.astaroth.integrate import (FIELDS, make_astaroth_step,
+                                                uses_pallas)
+
+    dev = devs[:1]
+    kernels = ["make_pallas_substep"]
+
+    def fields(r):
+        return {k: r["domain"].get_curr_global(r["handles"][k])
+                for k in FIELDS}
+
+    with PallasRecorder() as rec:
+        r = astaroth.run(iters=3, nx=nx, dtype="float32", devices=dev)
+    facts = {"iter_ms": round(1e3 * r["iter_trimean_s"], 3)}
+    if not rehearsal:
+        assert uses_pallas(r["domain"].halo_exchange, None, "float32")
+        require_compiled_kernels(rec, kernels, rehearsal)
+        assert len(rec.of("make_pallas_substep")) == 3
+        facts["fills"] = sorted(r["domain"].halo_exchange._self_fills)
+    for name, f in fields(r).items():
+        assert np.isfinite(f).all(), f"astaroth {nx}^3: {name} not finite"
+    del r
+
+    want = astaroth.run(iters=2, nx=ref_nx, dtype="float32", devices=dev,
+                        use_pallas=False)
+    with PallasRecorder() as rec:
+        if rehearsal:
+            # run() only takes the fused kernels on a TPU: drive the same
+            # step builder with interpret kernels over the same 3 iterations
+            got = astaroth.run(iters=0, nx=ref_nx, dtype="float32",
+                               devices=dev, use_pallas=False, no_compute=True)
+            dd, hs = got["domain"], got["handles"]
+            step = make_astaroth_step(dd.halo_exchange, got["info"], iters=3,
+                                      use_pallas=True, interpret=True)
+            curr, _ = step({k: dd.get_curr(hs[k]) for k in FIELDS},
+                           {k: dd.get_next(hs[k]) for k in FIELDS})
+            for k in FIELDS:
+                dd.set_curr(hs[k], curr[k])
+        else:
+            got = astaroth.run(iters=2, nx=ref_nx, dtype="float32",
+                               devices=dev)
+    require_compiled_kernels(rec, kernels, rehearsal)
+    a, b = fields(got), fields(want)
+    for k in FIELDS:
+        np.testing.assert_allclose(a[k], b[k], rtol=1e-4, atol=1e-5,
+                                   err_msg=f"astaroth {ref_nx}^3 field {k}")
+    facts["vs_xla"] = (f"{ref_nx}^3 x 3 iterations, max|diff| "
+                       f"{max(float(np.abs(a[k] - b[k]).max()) for k in FIELDS):.3g}")
+    return facts
+
+
+# ------------------------------------------------------------ serving
+
+
+def phase_serve(devs, n: int, jobs: int, rehearsal: bool) -> dict:
+    """``jobs`` jacobi jobs of ``n``^3 dropped into a serve directory and
+    drained by ``ServeScheduler.serve()``; every result bit-identical to
+    ``campaign.run_sequential`` on the same chip."""
+    import numpy as np
+
+    from stencil_tpu.campaign import TenantJob, run_sequential
+    from stencil_tpu.serve import ServeScheduler
+
+    dev = devs[:1]
+    steps = 8
+    with tempfile.TemporaryDirectory(prefix="smoke-serve-") as sdir:
+        incoming = os.path.join(sdir, "jobs", "incoming")
+        os.makedirs(incoming)
+        for i in range(jobs):
+            doc = {"job": f"s-{i:04d}", "size": n, "steps": steps,
+                   "dtype": "float32", "workload": "jacobi", "seed": i,
+                   "tenant": f"tenant-{i % 4}", "priority": "normal"}
+            tmp = os.path.join(incoming, f".tmp-{i}")
+            with open(tmp, "w") as f:
+                json.dump(doc, f)
+            os.replace(tmp, os.path.join(incoming, f"{doc['job']}.json"))
+        t0 = time.perf_counter()
+        out = ServeScheduler(sdir, 8, devices=dev, chunk=2, poll_s=0.05,
+                             max_idle_s=0.5).serve()
+        wall = time.perf_counter() - t0
+    assert out["retired"] == jobs, f"retired {out['retired']}/{jobs}"
+    with PallasRecorder() as rec:
+        seq = run_sequential(
+            [TenantJob(f"s-{i:04d}", (n, n, n), steps, "float32", seed=i)
+             for i in range(jobs)], devices=dev, chunk=2)
+    require_compiled_kernels(rec, [], rehearsal)
+    for tid, want in seq["results"].items():
+        got = out["results"][tid]
+        assert got.outcome == want.outcome == "done", (tid, got.outcome)
+        assert got.steps == want.steps == steps
+        assert np.array_equal(got.final, want.final), (
+            f"{tid}: served result differs from run_sequential, max|diff| "
+            f"{float(np.abs(got.final - want.final).max()):.3g}")
+    return {"retired": out["retired"], "slots": out["slots"],
+            "serve_wall_s": round(wall, 2)}
+
+
+# ------------------------------------------------------------ four chips
+
+
+def phase_four_jacobi(devs, per, small, rehearsal: bool) -> dict:
+    """Weak-scaled jacobi3d over four chips through ``jacobi3d.run``
+    (``decompose_zy`` -> (1,2,2), multi-block tight-x, overlap on), then a
+    small global size against the numpy reference."""
+    import numpy as np
+
+    from stencil_tpu.apps import jacobi3d
+    from stencil_tpu.geometry import Dim3
+    from stencil_tpu.ops.jacobi import (INIT_TEMP, jacobi_reference,
+                                        make_jacobi_step)
+
+    facts = {}
+    with PallasRecorder() as rec:
+        r = jacobi3d.run(per.x, per.y, per.z, weak=True, devices=devs,
+                         iters=10, chunk=5)
+    dd, h = r["domain"], r["handle"]
+    size = dd.size
+    for name, arr in (("curr", dd.get_curr(h)), ("next", dd.get_next(h))):
+        require_four_shards(arr, devs, f"jacobi {name}")
+    if not rehearsal:
+        assert dd.spec.dim == Dim3(1, 2, 2), dd.spec.dim
+        assert size == Dim3(per.x, 2 * per.y, 2 * per.z), size
+        rx = dd.spec.radius
+        assert rx.x(-1) == 0 and rx.x(1) == 0, f"not tight-x: {rx}"
+        assert r["overlap"]
+        require_compiled_kernels(rec, ["make_pallas_jacobi_sweep"], rehearsal)
+        facts["bytes_after_run"] = require_balanced(devs, "jacobi 4 chips")
+        facts["mcells_per_s_per_dev"] = round(r["mcells_per_s_per_dev"], 1)
+    _check_spheres(dd.get_curr_global(h), size, f"jacobi3d.run {size}")
+    facts["global"] = str(size)
+    del r, dd
+
+    # small global size, exactly `steps` steps from the uniform start
+    steps = 6
+    r = jacobi3d.run(small.x, small.y, small.z, weak=True, devices=devs,
+                     iters=steps, chunk=3, warmup=0)
+    ssize = r["domain"].size
+    got = r["domain"].get_curr_global(r["handle"])
+    want = jacobi_reference(
+        np.full((ssize.z, ssize.y, ssize.x), INIT_TEMP, np.float32),
+        _masks(ssize), steps)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+    # and one step of the multi-block tight-x kernels from a random field
+    # (a uniform start cannot show an indexing error away from the spheres)
+    ex = (_jacobi_exchange(ssize, Dim3(1, 2, 2), devs, True) if rehearsal
+          else r["domain"].halo_exchange)
+    field = _random_field(ssize, 3)
+    with PallasRecorder() as rec:
+        step = make_jacobi_step(ex, use_pallas=True if rehearsal else None,
+                                interpret=rehearsal)
+        got = _run_loop(ex, step, field, ssize)
+    require_compiled_kernels(rec, ["make_pallas_jacobi_sweep"], rehearsal)
+    want = jacobi_reference(field, _masks(ssize), 1)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
+    facts["numpy_ref"] = f"{ssize} ok"
+    return facts
+
+
+# ------------------------------------------------------------ the run
+
+
+def build_phases(devs, rehearsal: bool) -> list:
+    """``[(name, min_devices, thunk)]`` in running order. The four-chip
+    phases come first: ``peak_bytes_in_use`` is a process-lifetime peak,
+    so the balance check must not see the one-chip phases' peaks."""
+    from stencil_tpu.geometry import Dim3
+
+    four = devs[:4]
+    p122 = Dim3(1, 2, 2)
+    if rehearsal:
+        return [
+            ("four_chip_jacobi", 4, lambda: phase_four_jacobi(
+                four, Dim3(16, 16, 16), Dim3(128, 8, 8), True)),
+            ("four_chip_exchange", 4, lambda: phase_exchange(
+                four, Dim3(16, 32, 32), p122, True)),
+            ("jacobi", 1, lambda: phase_jacobi(devs, 16, True, ref_n=16)),
+            ("exchange", 1, lambda: phase_exchange(
+                devs[:1], Dim3(16, 16, 16), Dim3(1, 1, 1), True)),
+            ("astaroth", 1, lambda: phase_astaroth(devs, 16, 16, True)),
+            ("serve", 1, lambda: phase_serve(devs, 8, 4, True)),
+        ]
+    return [
+        ("four_chip_jacobi", 4, lambda: phase_four_jacobi(
+            four, Dim3(512, 512, 512), Dim3(128, 32, 32), False)),
+        ("four_chip_exchange", 4, lambda: phase_exchange(
+            four, Dim3(512, 1024, 1024), p122, False)),
+        ("jacobi_512", 1, lambda: phase_jacobi(
+            devs, 512, False, ref_n=128, time_sync=True)),
+        ("jacobi_768", 1, lambda: phase_jacobi(
+            devs, 768, False, want_rows=True)),
+        ("exchange_512", 1, lambda: phase_exchange(
+            devs[:1], Dim3(512, 512, 512), Dim3(1, 1, 1), False)),
+        ("astaroth_256", 1, lambda: phase_astaroth(devs, 256, 64, False)),
+        ("serve", 1, lambda: phase_serve(devs, 64, 16, False)),
+    ]
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearsal", action="store_true",
+                    help="CPU walk-through at tiny sizes with interpret "
+                         "kernels; never prints the pass line")
+    ap.add_argument("--phases", default="",
+                    help="comma-separated phase names to run (debugging: "
+                         "a run that leaves phases out never passes)")
+    args = ap.parse_args(argv)
+
+    t_start = time.perf_counter()
+    devs = jax.devices()
+    d0 = devs[0]
+    if not args.rehearsal and d0.platform != "tpu":
+        print(f"chip_smoke: no TPU: jax.devices()[0].platform is "
+              f"{d0.platform!r}; nothing ran", file=sys.stderr)
+        return NO_TPU_RC
+
+    from stencil_tpu.utils.jax_cache import configure_compile_cache
+
+    cache_dir = configure_compile_cache()
+    cache = {"hits": 0, "misses": 0}
+
+    def on_event(event: str, **_) -> None:
+        if event.endswith("/compilation_cache/cache_hits"):
+            cache["hits"] += 1
+        elif event.endswith("/compilation_cache/cache_misses"):
+            cache["misses"] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+
+    device = {"platform": d0.platform, "kind": d0.device_kind,
+              "count": len(devs)}
+    say(f"{'rehearsal ' if args.rehearsal else ''}platform={d0.platform} "
+        f"device_kind={d0.device_kind} devices={len(devs)} "
+        f"jax={jax.__version__} "
+        f"libtpu={importlib.metadata.version('libtpu')} "
+        f"compile_cache={cache_dir}")
+
+    selected = [p for p in args.phases.split(",") if p]
+    phases = build_phases(devs, args.rehearsal)
+    unknown = set(selected) - {name for name, _, _ in phases}
+    if unknown:
+        ap.error(f"unknown phases {sorted(unknown)}")
+    outcome = {}
+    for name, need, thunk in phases:
+        if selected and name not in selected:
+            outcome[name] = "not run: not selected"
+            continue
+        if len(devs) < need:
+            outcome[name] = (f"not run: needs {need} devices, "
+                             f"this machine has {len(devs)}")
+            continue
+        say(f"{name}: start")
+        t0 = time.perf_counter()
+        try:
+            facts = thunk()
+        except Exception:
+            # the other phases still run; the run exits non-zero below
+            outcome[name] = "FAILED"
+            say(f"{name}: FAILED after {time.perf_counter() - t0:.1f}s\n"
+                f"{traceback.format_exc()}")
+            continue
+        outcome[name] = "passed"
+        say(f"{name}: passed in {time.perf_counter() - t0:.1f}s "
+            f"{json.dumps(facts)}")
+
+    say("summary: " + json.dumps({
+        "phases": outcome, "wall_s": round(time.perf_counter() - t_start, 1),
+        "compile_cache": dict(cache, dir=cache_dir), "device": device}))
+    if any(v == "FAILED" for v in outcome.values()):
+        return 1
+    if args.rehearsal:
+        say("rehearsal complete: not a chip result")
+        return REHEARSAL_RC
+    if any(v == "not run: not selected" for v in outcome.values()):
+        return PARTIAL_RC
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -139,7 +139,6 @@ def main() -> int:
         metrics.append(m3)
         code = f"""
 import json
-import stencil_tpu  # noqa: F401 - installs the jax-version compat shims
 import jax
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)

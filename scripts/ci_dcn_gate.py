@@ -47,7 +47,6 @@ import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["STENCIL_VIRTUAL_HOSTS"] = "2"
-import stencil_tpu  # first: applies the jax-compat shims
 import jax
 import numpy as np
 """

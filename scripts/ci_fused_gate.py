@@ -45,7 +45,6 @@ PY = sys.executable
 
 PARITY_CHILD = r"""
 import sys
-import stencil_tpu  # first: applies the jax-compat shims (old-jax containers)
 import jax
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)

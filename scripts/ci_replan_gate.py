@@ -58,7 +58,6 @@ LIVE_CONFIG = json.dumps(
 
 QAP_SNIPPET = r"""
 import numpy as np
-import stencil_tpu  # installs the jax_num_cpu_devices compat shim
 import jax
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)

@@ -21,7 +21,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import stencil_tpu  # noqa: F401 - older-jax shims must precede config use
 import jax
 
 jax.config.update("jax_platforms", "cpu")

@@ -9,6 +9,11 @@
 # Example (2 hosts x 4 devices, jacobi3d):
 #   scripts/launch_multiprocess.sh 2 4 stencil_tpu.apps.jacobi3d --x 64 --iters 3
 #
+# CPU/Gloo ONLY, by construction: STENCIL_LOCAL_CPU_DEVICES makes every
+# process force jax_platforms=cpu (parallel/distributed.init_distributed), so
+# none of the N processes ever takes a chip — a chip belongs to one process
+# at a time, and this script must never be how several reach for one.
+#
 # On a real TPU pod slice none of this is needed: every host runs the same
 # command and `stencil_tpu.parallel.distributed.init_distributed()` picks up
 # the cluster automatically.

@@ -54,7 +54,6 @@ if cpu_smoke:
     ).strip()
     os.environ["STENCIL_VIRTUAL_HOSTS"] = "2"
 
-import stencil_tpu  # noqa: F401  (jax-compat shims first)
 import jax
 
 if cpu_smoke:

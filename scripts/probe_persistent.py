@@ -46,7 +46,6 @@ if cpu_smoke:
         + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-import stencil_tpu  # noqa: F401  (jax-compat shims first)
 import jax
 
 if cpu_smoke:

@@ -2,9 +2,9 @@
 
 The full-plane multistep self-capped temporal depth at k=4 at 768^3 (VMEM
 staging holds full (py, px) planes — 55.3 Gcells/s vs 79-83 at 512^3,
-VERDICT r5 weak #2, scripts/r05_logs/jacobi_768.log). Row-tiled staging
-(ops/pallas_stencil.py, plan_multistep_staging) unchains depth from plane
-size; this probe A/Bs:
+VERDICT r5 weak #2; log deleted in PR 21, older unverified figure).
+Row-tiled staging (ops/pallas_stencil.py, plan_multistep_staging) unchains
+depth from plane size; this probe A/Bs:
 
 - default plan (row-tiled, k up to the 12 cap) — the new production path;
 - temporal_k=4 pin (what the old full-plane kernel could reach).

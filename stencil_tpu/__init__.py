@@ -8,10 +8,6 @@ partitioning, 26-neighbor periodic halo exchange as ``shard_map``-ped
 comm/compute overlap inside a single jitted step.
 """
 
-from .utils import jax_compat as _jax_compat
-
-_jax_compat.apply()  # older-jax shims; no-op on a current release
-
 from .domain import DataHandle, GridSpec, LocalBlock
 from .geometry import Dim3, Radius, Rect3
 from .parallel import HaloExchange, Method, grid_mesh

@@ -467,6 +467,8 @@ def csv_row(r: dict) -> str:
 def main(argv: Optional[list] = None) -> int:
     from ..parallel.distributed import maybe_init_from_env
     maybe_init_from_env()
+    from ..utils.jax_cache import configure_compile_cache
+    configure_compile_cache()
     p = argparse.ArgumentParser(description="Astaroth MHD mini-app (TPU)")
     p.add_argument("iters", type=int, nargs="?", default=10)
     p.add_argument("--conf", default=DEFAULT_CONF)

@@ -369,6 +369,8 @@ def ablate_row(row: dict) -> str:
 def main(argv: Optional[list] = None) -> int:
     from ..parallel.distributed import maybe_init_from_env
     maybe_init_from_env()
+    from ..utils.jax_cache import configure_compile_cache
+    configure_compile_cache()
     p = argparse.ArgumentParser(description="halo exchange radius-shape sweep")
     p.add_argument("--x", type=int, default=256)
     p.add_argument("--y", type=int, default=256)

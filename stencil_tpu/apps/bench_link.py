@@ -103,6 +103,8 @@ def csv_row(r: dict) -> str:
 def main(argv: Optional[list] = None) -> int:
     from ..parallel.distributed import maybe_init_from_env
     maybe_init_from_env()
+    from ..utils.jax_cache import configure_compile_cache
+    configure_compile_cache()
     p = argparse.ArgumentParser(description="per-mesh-axis shift bandwidth (TPU)")
     p.add_argument("--sizes-kb", type=str, default="64,256,1024,4096")
     p.add_argument("--iters", type=int, default=20)

@@ -223,6 +223,8 @@ def run_modes(args, campaign_dir: str, sentinel=None, status=None) -> dict:
 def main(argv: Optional[list] = None) -> int:
     from ..parallel.distributed import maybe_init_from_env
     maybe_init_from_env()
+    from ..utils.jax_cache import configure_compile_cache
+    configure_compile_cache()
     p = argparse.ArgumentParser(
         description="multi-tenant batched campaign driver")
     p.add_argument("--tenants", type=int, default=8,

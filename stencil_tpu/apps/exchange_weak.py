@@ -81,6 +81,8 @@ def csv_row(r: dict) -> str:
 def main(argv: Optional[list] = None) -> int:
     from ..parallel.distributed import maybe_init_from_env
     maybe_init_from_env()
+    from ..utils.jax_cache import configure_compile_cache
+    configure_compile_cache()
     p = argparse.ArgumentParser(description="weak-scaled halo exchange benchmark")
     p.add_argument("x", type=int)
     p.add_argument("y", type=int)

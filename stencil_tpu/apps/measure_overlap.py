@@ -169,6 +169,8 @@ def csv_row(r: dict) -> str:
 def main(argv: Optional[list] = None) -> int:
     from ..parallel.distributed import maybe_init_from_env
     maybe_init_from_env()
+    from ..utils.jax_cache import configure_compile_cache
+    configure_compile_cache()
     p = argparse.ArgumentParser(description="comm/compute overlap measurement (TPU)")
     p.add_argument("--x", type=int, default=64)
     p.add_argument("--y", type=int, default=64)

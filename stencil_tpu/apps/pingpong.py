@@ -74,6 +74,8 @@ def run(min_bytes=8, max_bytes=1 << 24, iters=100, devices=None):
 def main(argv: Optional[list] = None) -> int:
     from ..parallel.distributed import maybe_init_from_env
     maybe_init_from_env()
+    from ..utils.jax_cache import configure_compile_cache
+    configure_compile_cache()
     p = argparse.ArgumentParser(description="ppermute ping-pong microbenchmark")
     p.add_argument("--min-bytes", type=int, default=8)
     p.add_argument("--max-bytes", type=int, default=1 << 24)

@@ -143,6 +143,8 @@ def install_kill_hook(sched) -> None:
 def main(argv: Optional[list] = None) -> int:
     from ..parallel.distributed import maybe_init_from_env
     maybe_init_from_env()
+    from ..utils.jax_cache import configure_compile_cache
+    configure_compile_cache()
     p = argparse.ArgumentParser(
         description="always-on campaign serving daemon")
     p.add_argument("--serve-dir", required=True,

@@ -1,9 +1,14 @@
-"""ctypes loader for the native components (native/qap.cpp).
+"""ctypes loader for the native components (native/qap.cpp,
+native/paraview.cpp).
 
-The shared library is built by ``make -C native`` (a plain g++ -shared
-build); if it is missing, this module builds it on first import when a
-compiler is available, else raises so callers fall back to the pure-Python
-implementations. The C ABI is the stable boundary — no pybind11 needed.
+The shared library is never shipped: importing this module runs
+``make -C native`` (a plain g++ -shared build; make's mtime tracking makes
+it a no-op when the library is fresh) and loads what that build produced.
+When the build fails (no compiler, no ``make``) the import raises
+``ImportError``, and the callers take their pure-Python implementations
+knowingly — a library left over from another build or another machine is
+never loaded. The C ABI is
+the stable boundary — no pybind11 needed.
 """
 
 from __future__ import annotations
@@ -31,11 +36,9 @@ def _build() -> None:
 
 def _load() -> ctypes.CDLL:
     try:
-        # make's mtime tracking rebuilds after qap.cpp edits; no-op when fresh
         _build()
-    except Exception:
-        if not os.path.exists(_SO):
-            raise
+    except (OSError, subprocess.SubprocessError) as e:
+        raise ImportError(f"native library not built: {e}") from e
     lib = ctypes.CDLL(_SO)
     dp = ctypes.POINTER(ctypes.c_double)
     sp = ctypes.POINTER(ctypes.c_size_t)
@@ -43,17 +46,13 @@ def _load() -> ctypes.CDLL:
     lib.stencil_qap_solve.restype = ctypes.c_int
     lib.stencil_qap_solve_catch.argtypes = [ctypes.c_int, dp, dp, sp, dp]
     lib.stencil_qap_solve_catch.restype = ctypes.c_int
-    # optional symbol: a stale prebuilt .so (no compiler to rebuild) must
-    # not take down the QAP entry points with it
-    pw = getattr(lib, "stencil_paraview_write", None)
-    if pw is not None:
-        pw.argtypes = [
-            ctypes.c_char_p, ctypes.c_char_p,
-            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_int, ctypes.POINTER(dp),
-        ]
-        pw.restype = ctypes.c_int
+    lib.stencil_paraview_write.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int, ctypes.POINTER(dp),
+    ]
+    lib.stencil_paraview_write.restype = ctypes.c_int
     return lib
 
 
@@ -66,11 +65,6 @@ def paraview_write(path: str, header: str, origin, size, qs) -> None:
     ``origin``/``size`` are (z, y, x) tuples; ``qs`` is a list of dense
     [sz, sy, sx] float64 arrays. Emits byte-identical output to the
     Python fallback (shortest-round-trip floats, Python-repr rules)."""
-    if getattr(_LIB, "stencil_paraview_write", None) is None:
-        raise OSError(
-            "libstencil_native.so predates the paraview writer; "
-            "rebuild with `make -C native`"
-        )
     arrs = [np.ascontiguousarray(q, dtype=np.float64) for q in qs]
     dp = ctypes.POINTER(ctypes.c_double)
     ptrs = (dp * len(arrs))(*[a.ctypes.data_as(dp) for a in arrs])

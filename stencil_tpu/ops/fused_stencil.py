@@ -31,13 +31,13 @@ bit-identically to AXIS_COMPOSED. ``wire_dtype`` (bf16 or the fp8
 ``float8_e4m3fn`` tier) narrows wire-crossing carriers exactly like the
 axis carrier; self-wrap hand-offs stay lossless.
 
-This container has no TPU (no Pallas cross-device interpret mode), so —
-the PR-10 discipline — the kernels here are exercised on hardware via
-``scripts/probe_remote_dma.py``'s fused leg, while the host-orchestrated
-emulation (``parallel/remote_emu.FusedRemoteEmulation``) pins the fused
+The cross-device kernels here have not run on a chip (CHANGES.md PR 21,
+"not run on the chip"; ``scripts/probe_remote_dma.py``'s fused leg is the
+hardware probe). The host-orchestrated emulation
+(``parallel/remote_emu.FusedRemoteEmulation``) pins the fused
 schedule's semantics bit-identically to AXIS_COMPOSED on the CPU mesh
 (tests/test_fused_stencil.py, scripts/ci_fused_gate.py). The one piece
-that DOES run here is the all-self-wrap (single device) form of the
+the tests DO run is the all-self-wrap (single device) form of the
 jacobi mega-kernel in interpret mode: no remote copies exist, so the
 interior/boundary split and in-kernel wrap fills are parity-pinned
 against the XLA step on any host.

@@ -43,12 +43,12 @@ GROWN-region cells, so ``sel`` must arrive with its halos filled to
 the realized radius — one ``ex(sel)`` per loop build (sel is
 step-invariant; the step compilers in ops/jacobi.py do this).
 
-This container has no TPU (no Pallas cross-device interpret mode) —
-the PR 10/14 discipline applies: the all-self-wrap (single device)
-form of the mega-kernel runs in interpret mode, parity-pinned against
+The mega-kernel has not run on a chip (CHANGES.md PR 21, "not run on
+the chip"). In the tests the all-self-wrap (single device)
+form runs in interpret mode, parity-pinned against
 the XLA chunk program including uneven z extents whose mod-3 plane
-ring wraps mid-window; the crossing form is exercised on hardware via
-``scripts/probe_persistent.py`` (item-1 queue). Correctness on the CPU
+ring wraps mid-window; the crossing form waits for
+``scripts/probe_persistent.py`` on hardware. Correctness on the CPU
 mesh is owned by :func:`make_persistent_chunk_body` + the plain
 REMOTE_DMA emulation (ops/jacobi._compile_jacobi_persistent).
 

@@ -29,12 +29,13 @@ count per exchange is Q-independent (≤ 2 per phase per dtype group).
 ``wire_dtype`` (bf16-on-the-wire) narrows the staged carrier before the
 send and widens on unpack — only wire-crossing bytes pay precision.
 
-This container has no TPU (jax 0.4.37, no Pallas cross-device interpret
-mode), so this module is exercised on hardware via
-``scripts/probe_remote_dma.py``; the CPU emulation
-(``parallel/remote_emu.py``) pins the semantics bit-identically to
-AXIS_COMPOSED, and the plan-level claims (0 ppermutes, wire bytes) are
-pinned against the emulation's census in tests/test_remote_dma.py.
+These kernels have not run on a chip (CHANGES.md PR 21, "not run on
+the chip"); ``scripts/probe_remote_dma.py`` is the hardware probe. The
+tests run the CPU emulation (``parallel/remote_emu.py``), which pins the
+semantics bit-identically to AXIS_COMPOSED, and the plan-level claims
+(0 ppermutes, wire bytes) are pinned against the emulation's census in
+tests/test_remote_dma.py. Running the real kernels under the Pallas TPU
+interpreter inside ``shard_map`` is untried (ROADMAP C3).
 """
 
 from __future__ import annotations

@@ -1177,7 +1177,11 @@ def shard_blocks(
                     off.y : off.y + s.y,
                     off.x : off.x + s.x,
                 ] = global_zyx[o.z : o.z + s.z, o.y : o.y + s.y, o.x : o.x + s.x]
-    return jax.device_put(jnp.asarray(stacked), NamedSharding(mesh, BLOCK_PSPEC))
+    # each device receives only its own blocks, straight from the host
+    # array (no whole-array staging on the first device)
+    return jax.make_array_from_callback(
+        stacked.shape, NamedSharding(mesh, BLOCK_PSPEC), lambda idx: stacked[idx]
+    )
 
 
 def unshard_blocks(stacked, spec: GridSpec) -> np.ndarray:

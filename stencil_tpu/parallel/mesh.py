@@ -17,6 +17,8 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -42,6 +44,15 @@ def block_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, BLOCK_PSPEC)
 
 
+def sharded_full(shape, fill_value, dtype, sharding: NamedSharding):
+    """``() -> array``: a jitted constant fill whose result is BORN with
+    ``sharding`` — every device writes only its own shard, so no device
+    ever stages the whole global array (``device_put(jnp.full(...))``
+    materializes it on the first device before resharding)."""
+    return jax.jit(lambda: jnp.full(shape, fill_value, dtype),
+                   out_shardings=sharding)
+
+
 def grid_mesh(dim, devices: Optional[Sequence] = None, ordered: bool = False) -> Mesh:
     """Build a ``(dz, dy, dx)`` mesh for a partition grid ``dim`` (x, y, z).
 
@@ -54,8 +65,6 @@ def grid_mesh(dim, devices: Optional[Sequence] = None, ordered: bool = False) ->
     d = Dim3.of(dim)
     shape = (d.z, d.y, d.x)
     if devices is None:
-        import jax
-
         devices = jax.devices()
     devices = list(devices)
     n = int(np.prod(shape))

@@ -154,7 +154,9 @@ def _native():
             from ..native import qap_native
 
             _NATIVE = qap_native
-        except Exception as e:  # missing .so and no compiler — use Python
-            log.debug(f"native qap unavailable ({e}); using Python fallback")
+        except ImportError as e:
+            # the library is built from native/ at import; no compiler or
+            # no make means the Python solvers below do the work
+            log.warn(f"{e}; qap takes the Python solvers")
             _NATIVE = None
     return _NATIVE

@@ -3,8 +3,9 @@
 ``Method.REMOTE_DMA``'s real transport issues per-neighbor async remote
 copies from inside the compute kernel (``pltpu.make_async_remote_copy``,
 ops/remote_dma.py) — data movement the XLA collective path never sees.
-This container's jax (0.4.37) has no TPU and no Pallas cross-device
-interpret mode, so correctness is pinned here instead: the SAME
+The tests run on the CPU mesh and do not drive those kernels through a
+cross-device Pallas interpreter (untried, ROADMAP C3), so correctness is
+pinned here instead: the SAME
 per-neighbor copy schedule, executed as host-initiated device-to-device
 transfers (``jax.device_put`` of the packed boundary carrier straight to
 the neighbor device — the closest thing a CPU backend has to a remote

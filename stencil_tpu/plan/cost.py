@@ -5,7 +5,7 @@ The model scores one :class:`~stencil_tpu.plan.ir.PlanChoice` for one
 collective-permute count, estimated on-wire bytes, and local slab bytes
 fall out of the phase list (plan/ir.py), and the per-collective overhead
 constants are calibrated from the censuses + wall-clocks this repo has
-RECORDED (BASELINE.md rounds 7/10, 8-device CPU mesh, jax 0.4.37):
+RECORDED (BASELINE.md rounds 7/10, 8-device CPU mesh, an older jax):
 
 - Round 10 quantity-batching A/B (128^3, 2x2x2, fp32): Q=8 batched
   42.9 ms / 6 permutes vs per-quantity 70.6 ms / 48 permutes — the

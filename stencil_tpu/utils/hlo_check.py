@@ -159,10 +159,11 @@ COLLECTIVE_KINDS = (
     "collective-broadcast",
 )
 
-# `KIND(` right after the result type(s): matches both sync ops and the
-# `-start` half of async pairs (`-done` consumes no extra interconnect).
+# `= RESULT_TYPE KIND(`: matches both sync ops and the `-start` half of
+# async pairs (`-done` consumes no extra interconnect); group 1 is the
+# result type text the payload is read from.
 _COLLECTIVE_OP_RE = re.compile(
-    r"=\s*[^=]*?\b(" + "|".join(COLLECTIVE_KINDS) + r")(-start)?\("
+    r"=\s*([^=]*?)\b(" + "|".join(COLLECTIVE_KINDS) + r")(-start)?\("
 )
 # dtype token may carry interior digits (f8e4m3fn) — [a-z][a-z0-9]*
 _SHAPE_RE = re.compile(r"\b([a-z][a-z0-9]*)\[([0-9,]*)\]")
@@ -191,24 +192,28 @@ def collective_census(hlo_text: str) -> Dict[str, Tuple[int, int]]:
     census a single-exchange program, not a fused loop, when comparing
     strategies.
 
-    Bytes are the interconnect payload per op instance, summed per kind:
-    the operand buffer is the per-shard payload; for ``collective-permute``
-    it is multiplied by the number of ``source_target_pairs`` (each pair
-    carries one payload across a link — the exact figure the ablation
-    table wants); for gather/reduce/all-to-all kinds it is multiplied by
-    the participant count in ``replica_groups`` (a first-order upper bound
-    for ring/tree implementations). Async ``-start``/``-done`` pairs count
-    once, at the start op."""
+    Bytes are the interconnect payload per op instance, summed per kind.
+    The per-shard payload is read from the op's RESULT type — compiled HLO
+    text prints operands by name only (``collective-permute(%slice)``), and
+    for every kind this framework emits the result buffer has the
+    operand's shape. An async ``-start`` op returns a tuple whose first
+    element is that buffer (the rest are aliases and sync flags), and
+    ``-start``/``-done`` pairs count once, at the start op. For
+    ``collective-permute`` the payload is multiplied by the number of
+    ``source_target_pairs`` (each pair carries one payload across a link —
+    the exact figure the ablation table wants); for the other kinds by the
+    participant count in ``replica_groups`` (a first-order upper bound for
+    ring/tree implementations)."""
     out: Dict[str, Tuple[int, int]] = {}
     for ln in hlo_text.splitlines():
         m = _COLLECTIVE_OP_RE.search(ln)
         if not m:
             continue
-        kind = m.group(1)
-        # operand types sit between `KIND(` and the first `)` (shapes never
-        # contain parens in HLO text)
-        args = ln[m.end():].split(")", 1)[0]
-        payload = sum(_tensor_bytes(d, dims) for d, dims in _SHAPE_RE.findall(args))
+        kind = m.group(2)
+        shapes = _SHAPE_RE.findall(m.group(1))
+        if m.group(3):
+            shapes = shapes[:1]
+        payload = sum(_tensor_bytes(d, dims) for d, dims in shapes)
         pm = _PAIR_RE.search(ln)
         if kind == "collective-permute" and pm:
             fanout = pm.group(1).count("{")
@@ -263,7 +268,7 @@ def collective_permute_pairs(hlo_text: str):
     out = []
     for ln in hlo_text.splitlines():
         m = _COLLECTIVE_OP_RE.search(ln)
-        if not m or m.group(1) != "collective-permute":
+        if not m or m.group(2) != "collective-permute":
             continue
         pm = _PAIR_RE.search(ln)
         if not pm:
