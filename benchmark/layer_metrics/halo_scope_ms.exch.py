@@ -1,0 +1,10 @@
+"""Device self time per exchange in EVERY op under a scope of the halo
+layer (``stencil.halo.*``, the self-fill and remote-DMA kernels): Pallas,
+collective or XLA pack/unpack alike. Mean over chips, in an exchange
+cell."""
+
+from benchmark import scope_lib
+
+
+def read(ctx):
+    return scope_lib.class_ms(ctx, "halo")

@@ -79,6 +79,7 @@ class PallasRecorder:
             gs = kw.get("grid_spec")
             self.calls.append({
                 "kernel": kernel.__qualname__.split(".")[0],
+                "name": kw.get("name"),
                 "grid": tuple(kw.get("grid") or getattr(gs, "grid", ())),
                 "interpret": bool(kw.get("interpret", False)),
             })
@@ -99,6 +100,8 @@ def require_compiled_kernels(rec: PallasRecorder, kernels, rehearsal: bool):
     recorded builds is an interpret-mode one — libtpu compiled them all."""
     missing = [k for k in kernels if not rec.of(k)]
     assert not missing, f"kernels never built: {missing}; built {rec.calls}"
+    # the names the profiler will show them under (obs/scopes.KERNELS)
+    say(f"kernels built, by name: {sorted({str(c['name']) for c in rec.calls})}")
     if not rehearsal:
         interp = [c for c in rec.calls if c["interpret"]]
         assert not interp, f"interpret-mode kernels on the chip: {interp}"

@@ -249,6 +249,10 @@ def time_exchange(
         from ..parallel import FixedAssignment
 
         placement = FixedAssignment(placement)
+    # covered by spans without a hole: realize, warmup (build, compile or
+    # cache load, first call), steps
+    rec = telemetry.get()
+    end_realize = rec.open_span("exchange.realize", phase="init")
     dd = DistributedDomain(size.x, size.y, size.z)
     dd.set_radius(radius)
     dd.set_methods(method)
@@ -269,15 +273,12 @@ def time_exchange(
     for i in range(quantities):
         dd.add_data(f"d{i}", dtype)
     dd.realize()
+    end_realize()
 
-    rec = telemetry.get()
     itemsizes = [jnp.dtype(dtype).itemsize] * quantities
     state = dd.curr_state()
     chunk = max(1, min(chunk, iters))
     tail = iters % chunk
-    loops = {chunk: dd.halo_exchange.make_loop(chunk)}
-    if tail:
-        loops[tail] = dd.halo_exchange.make_loop(tail)
     # the wire tag keeps a --wire-ab run's legs separable in aggregation
     # (report._agg_key splits on it, like method/batched); the variant
     # tag does the same for the fused A/B legs
@@ -291,9 +292,13 @@ def time_exchange(
     # compile + warm every loop size OUTSIDE the timed region
     with rec.span("exchange.warmup", phase="compile", method=method.value,
                   batched=batch_quantities, **wtag):
+        loops = {chunk: dd.halo_exchange.make_loop(chunk, like=state)}
+        if tail:
+            loops[tail] = dd.halo_exchange.make_loop(tail, like=state)
         for fn in loops.values():
             state = fn(state)
         hard_sync(state)
+    end_steps = rec.open_span("exchange.steps", phase="exchange")
     census = None
     if rec.enabled:
         # compile-time truth: census the compiled single-exchange program
@@ -357,6 +362,7 @@ def time_exchange(
             phase="exchange", method=method.value, batched=batch_quantities,
             **wtag,
         )
+    end_steps()
     return {
         "domain": dd,
         "census": census,

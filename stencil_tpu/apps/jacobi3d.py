@@ -104,6 +104,10 @@ def run(
             "'fused' and 'persistent'")
     devices = list(devices) if devices is not None else jax.devices()
     n = len(devices)
+    # run() is covered by spans without a hole: realize, init, warmup
+    # (build, compile or cache load, first call), steps
+    rec = telemetry.get()
+    end_realize = rec.open_span("jacobi.realize", phase="init")
     if (weak and n > 1 and partition is None
             and x % 128 == 0
             and all(d.platform == "tpu" for d in devices)):
@@ -174,11 +178,11 @@ def run(
         dd.enable_autotune(db_path=plan_db)
     h = dd.add_data("temperature", "float32")
     dd.realize()
+    end_realize()
     if autotune:
         method = dd._method  # the tuned method labels the CSV row
 
     # init: uniform lukewarm field (reference: bin/jacobi3d.cu:18-27)
-    rec = telemetry.get()
     with rec.span("jacobi.init", phase="init"):
         sharding = dd.sharding()
         shape = dd.spec.stacked_shape_zyx()
@@ -286,6 +290,7 @@ def run(
             if warmup:
                 hard_sync(curr)
 
+    end_steps = rec.open_span("jacobi.steps", phase="step")
     # Iterations run in fused chunks: one dispatch + one hard sync per chunk
     # (utils/sync.py). The per-iteration statistic is each chunk's mean,
     # trimean'd over chunks like the reference's per-iter times
@@ -497,6 +502,7 @@ def run(
                          reason="pallas fast path not engaged")
     dd.set_curr(h, curr)
     dd.set_next(h, nxt)
+    end_steps()
 
     if paraview:
         dd.write_paraview(prefix + "jacobi3d_final")

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..geometry import Dim3, Rect3, exterior_regions, interior_region
+from ..obs import scopes
 from ..parallel.exchange import BLOCK_PSPEC, HaloExchange
 from .config import AcMeshInfo
 from .equations import Constants, continuity, entropy, induction, momentum
@@ -332,10 +333,13 @@ def make_astaroth_step(
         nres = ex.resident.flatten()
 
         def to3(d):
-            return tuple(d[k].reshape(p.z, p.y, p.x) for k in FIELDS)
+            with scopes.scope(scopes.CARRY):
+                return tuple(d[k].reshape(p.z, p.y, p.x) for k in FIELDS)
 
         def untuple(vals, like):
-            return {k: v.reshape(like[k].shape) for k, v in zip(FIELDS, vals)}
+            with scopes.scope(scopes.CARRY):
+                return {k: v.reshape(like[k].shape)
+                        for k, v in zip(FIELDS, vals)}
 
         def run_kernel(s, curr, out):
             """One fused substep over the shard. Resident (oversubscribed)
@@ -346,18 +350,20 @@ def make_astaroth_step(
             tx_cuda.cuh:41-113)."""
             if nres == 1:
                 return untuple(kernels[s](to3(curr), to3(out)), out)
-            cf = tuple(curr[k].reshape(nres, p.z, p.y, p.x) for k in FIELDS)
-            of = tuple(out[k].reshape(nres, p.z, p.y, p.x) for k in FIELDS)
+            with scopes.scope(scopes.CARRY):
+                cf = tuple(curr[k].reshape(nres, p.z, p.y, p.x) for k in FIELDS)
+                of = tuple(out[k].reshape(nres, p.z, p.y, p.x) for k in FIELDS)
             res = [
                 kernels[s](tuple(c[j] for c in cf), tuple(o[j] for o in of))
                 for j in range(nres)
             ]
-            return {
-                k: jnp.stack([res[j][i] for j in range(nres)]).reshape(
-                    out[k].shape
-                )
-                for i, k in enumerate(FIELDS)
-            }
+            with scopes.scope(scopes.CARRY):
+                return {
+                    k: jnp.stack([res[j][i] for j in range(nres)]).reshape(
+                        out[k].shape
+                    )
+                    for i, k in enumerate(FIELDS)
+                }
 
         def exchange_all(curr):
             return ex.exchange_blocks(curr)
@@ -392,13 +398,15 @@ def make_astaroth_step(
             if use_overlap and multi_block:
                 out = run_kernel(0, curr, out)
                 curr = exchange_all(curr)
-                for rect in exteriors:
-                    if tight_x:
-                        out = _integrate_shell_wrap_x(
-                            0, rect, inv_ds, c, dt, curr, out
-                        )
-                    else:
-                        out = _integrate_region(0, rect, inv_ds, c, dt, curr, out)
+                with scopes.scope(scopes.SWEEP_SHELL):
+                    for rect in exteriors:
+                        if tight_x:
+                            out = _integrate_shell_wrap_x(
+                                0, rect, inv_ds, c, dt, curr, out
+                            )
+                        else:
+                            out = _integrate_region(
+                                0, rect, inv_ds, c, dt, curr, out)
             elif use_dyn_overlap:
                 # uneven partition: same structure, shells at per-block
                 # dynamic offsets (substep 0 never reads out, so the full
@@ -406,10 +414,11 @@ def make_astaroth_step(
                 out = run_kernel(0, curr, out)
                 curr = exchange_all(curr)
                 _, shells = _dyn_geometry()
-                for lo, size in shells:
-                    out = _integrate_region_dyn(
-                        spec, 0, lo, size, inv_ds, c, dt, curr, out
-                    )
+                with scopes.scope(scopes.SWEEP_SHELL):
+                    for lo, size in shells:
+                        out = _integrate_region_dyn(
+                            spec, 0, lo, size, inv_ds, c, dt, curr, out
+                        )
             else:
                 curr = exchange_all(curr)
                 out = run_kernel(0, curr, out)
@@ -437,8 +446,9 @@ def make_astaroth_step(
             # for the whole dict); reads pre-update curr only, so the
             # overlap-as-dataflow structure is unchanged
             curr = ex.exchange_blocks(curr)
-            for rect in exteriors:
-                out = _integrate_region(0, rect, inv_ds, c, dt, curr, out)
+            with scopes.scope(scopes.SWEEP_SHELL):
+                for rect in exteriors:
+                    out = _integrate_region(0, rect, inv_ds, c, dt, curr, out)
             for s in (1, 2):
                 out = _integrate_region(s, compute, inv_ds, c, dt, curr, out)
             return out, curr  # one swap per iteration (astaroth.cu:642-648)
@@ -447,8 +457,10 @@ def make_astaroth_step(
             if use_overlap:
                 out = _integrate_region(substep, interior, inv_ds, c, dt, curr, out)
                 curr = ex.exchange_blocks(curr)
-                for rect in exteriors:
-                    out = _integrate_region(substep, rect, inv_ds, c, dt, curr, out)
+                with scopes.scope(scopes.SWEEP_SHELL):
+                    for rect in exteriors:
+                        out = _integrate_region(
+                            substep, rect, inv_ds, c, dt, curr, out)
             elif use_dyn_overlap:
                 # masked interior write (shell cells keep the pre-update out
                 # that substeps > 0 read as state_previous), exchange, then
@@ -459,11 +471,12 @@ def make_astaroth_step(
                 )
                 curr = ex.exchange_blocks(curr)
                 out_read = out
-                for lo, size in shells:
-                    out = _integrate_region_dyn(
-                        spec, substep, lo, size, inv_ds, c, dt, curr, out,
-                        out_read=out_read,
-                    )
+                with scopes.scope(scopes.SWEEP_SHELL):
+                    for lo, size in shells:
+                        out = _integrate_region_dyn(
+                            spec, substep, lo, size, inv_ds, c, dt, curr, out,
+                            out_read=out_read,
+                        )
             else:
                 curr = ex.exchange_blocks(curr)
                 out = _integrate_region(substep, compute, inv_ds, c, dt, curr, out)
@@ -493,7 +506,13 @@ def make_astaroth_step(
         out_specs=(BLOCK_PSPEC, BLOCK_PSPEC),
         check_vma=not interpret,
     )
-    return jax.jit(fn, donate_argnums=(0, 1))
+    # the abstract (curr, out) field dicts this iteration is built for:
+    # what obs.scopes.op_map lowers it with
+    field = jax.ShapeDtypeStruct(spec.stacked_shape_zyx(), jnp.dtype(dtype),
+                                 sharding=ex.sharding())
+    like = {k: field for k in FIELDS}
+    return scopes.jit_loop(scopes.ASTAROTH_ITER, fn, (like, like),
+                           donate_argnums=(0, 1))
 
 
 def make_fused_astaroth_loop(
@@ -689,7 +708,10 @@ def make_batched_astaroth_step(spec, info: AcMeshInfo, dt: float = 1e-8,
         return lax.fori_loop(
             0, iters, lambda _, co: iteration(co[0], co[1]), (curr, out))
 
+    # named like the single-domain iteration; the batch is the caller's,
+    # so nothing is registered for op_map
     if sharding is None:
-        return jax.jit(entry_fn)
+        return scopes.jit_loop(scopes.ASTAROTH_ITER, entry_fn)
     sh = {k: sharding for k in FIELDS}
-    return jax.jit(entry_fn, in_shardings=(sh, sh), out_shardings=(sh, sh))
+    return scopes.jit_loop(scopes.ASTAROTH_ITER, entry_fn,
+                           in_shardings=(sh, sh), out_shardings=(sh, sh))

@@ -5,9 +5,8 @@ plans with ``plan/cost.score`` — a prediction in seconds — and
 ``verify_plan`` audits the structural half of that prediction
 (collectives, bytes, DMAs) against the realized IR; what nobody checks
 is the seconds themselves. This module closes that gap per run: each
-timed exchange phase (the ``trace_range`` names the host spans and any
-xprof device capture both key on — "stencil.exchange_loop",
-"exchange.hierarchical", …) becomes one ``plan.attrib.phase`` meta
+timed exchange phase (the ``trace_range`` names of the host spans —
+"stencil.exchange_loop", "exchange.hierarchical", …) becomes one ``plan.attrib.phase`` meta
 record pairing the installed calibration's prediction with the measured
 wall time for the SAME (method, collectives, wire_bytes) point:
 

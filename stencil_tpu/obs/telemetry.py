@@ -20,7 +20,11 @@ Record schema (v1) — every line carries:
 - ``name``:  record name (e.g. ``jacobi.iter``, ``census.collective-permute``)
 - ``t``:     unix wall time of emission
 
-plus per kind: spans carry ``seconds`` (and usually ``phase``); counters
+plus per kind: spans carry ``seconds`` (and usually ``phase``), and those
+opened through :meth:`Recorder.span` also ``t0_ns``/``t1_ns`` (unix
+nanoseconds, ``time.time_ns()``: the clock the profiler's host and device
+events are on, so a span can be laid beside them) and ``parent`` (the span
+that was open when it started); counters
 carry ``value`` (a count) and/or ``bytes`` (a byte total — "bytes where
 applicable"); gauges carry ``value``; heartbeats carry ``seq``; metas are
 free-form. Anything else (``app``, ``phase``, ``method``, ``iters``, ...)
@@ -39,6 +43,7 @@ compile) touches that file; the watchdog reads only its mtime.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import os
@@ -149,8 +154,7 @@ NAME_FIELDS = {
     # back onto the ExchangePlan IR's prediction under the installed
     # calibration — the samples plan_tool calibrate fits and perf_tool
     # drift judges. `phase` is the trace_range name of the measured
-    # region (so xprof device attribution keys the same way);
-    # `collectives` carries the plan's collective count for the permute
+    # region; `collectives` carries the plan's collective count for the permute
     # methods and its DMA count for remote-dma (the per-copy overhead
     # is what the fit recovers there).
     "plan.attrib.phase": (("phase", str), ("method", str),
@@ -225,7 +229,18 @@ KNOWN_NAMES = frozenset(NAME_FIELDS) | frozenset({
     "serve.tenants_per_hour",
     "wire_ab.bytes_ratio", "wire_ab.max_abs_err", "wire_ab.max_rel_err",
     "wire_ab.max_ulp_err",
+    # what an application's run() spends before its first timed chunk
+    # (benchmark readers app_run_host_init_s / _compile_s / _steps_s) and
+    # the HBM bytes one call of a self-fill kernel reads and writes,
+    # counted where its DMAs are built (self_fill_moved_roofline)
+    "astaroth.realize", "astaroth.steps",
+    "exchange.realize", "exchange.steps",
+    "jacobi.realize", "jacobi.steps",
+    "halo.self_fill.bytes_dma",
 })
+
+# how many records a recorder keeps in memory (oldest dropped first)
+KEEP_RECORDS = 4096
 
 
 def new_run_id() -> str:
@@ -238,7 +253,9 @@ class Recorder:
     ``sink`` is a path (opened append) or a file-like object, or None — a
     disabled recorder still accumulates timer buckets in spans and still
     beats the watchdog heartbeat file, so supervision works even when no
-    metrics file was requested.
+    metrics file was requested. Whatever the sink, the last
+    ``KEEP_RECORDS`` records stay in memory for :meth:`records` (an
+    in-process reader, e.g. the benchmark's ``app_run_*`` metrics).
     """
 
     def __init__(
@@ -254,6 +271,7 @@ class Recorder:
         self._owns_sink = isinstance(sink, (str, os.PathLike))
         self._sink = open(sink, "a", buffering=1) if self._owns_sink else sink
         self._lock = threading.Lock()
+        self._kept: collections.deque = collections.deque(maxlen=KEEP_RECORDS)
         self._proc: Optional[int] = None
         self._hb_path = os.environ.get(HEARTBEAT_FILE_ENV) or None
         self._hb_interval = float(
@@ -311,6 +329,7 @@ class Recorder:
         for k, v in fields.items():
             if v is not None:
                 rec[k] = v
+        self._kept.append(rec)
         if self._sink is not None:
             line = json.dumps(rec, default=str)
             with self._lock:
@@ -322,6 +341,14 @@ class Recorder:
         self._maybe_beat()
         return rec
 
+    def records(self, kind: Optional[str] = None,
+                name: Optional[str] = None) -> List[dict]:
+        """The records kept in memory, oldest first, of one ``kind`` and
+        one ``name`` where given."""
+        return [r for r in list(self._kept)
+                if (kind is None or r["kind"] == kind)
+                and (name is None or r["name"] == name)]
+
     @contextlib.contextmanager
     def span(self, name: str, phase: Optional[str] = None,
              bucket: Optional[str] = None, **tags):
@@ -329,18 +356,32 @@ class Recorder:
 
         The record is emitted even when the body raises (the failed span
         is evidence), and the exception propagates — same discipline as
-        ``timer.trace_range``.
+        ``timer.trace_range``. ``bucket=False``: no timer bucket (the
+        exit-time ``timers:`` line stays as it is).
         """
+        t0_ns = time.time_ns()
         t0 = time.perf_counter()
         prev_span = self._progress.get("span")
         self._progress["span"] = name  # the heartbeat payload quotes this
+        timed = (contextlib.nullcontext() if bucket is False
+                 else timer.timed(bucket or name))
         try:
-            with timer.timed(bucket or name), timer.trace_range(name):
+            with timed, timer.trace_range(name):
                 yield
         finally:
             self._progress["span"] = prev_span
-            self.emit("span", name, phase=phase,
-                      seconds=time.perf_counter() - t0, **tags)
+            seconds = time.perf_counter() - t0
+            self.emit("span", name, phase=phase, seconds=seconds,
+                      t0_ns=t0_ns, t1_ns=t0_ns + int(seconds * 1e9),
+                      parent=prev_span, **tags)
+
+    def open_span(self, name: str, phase: Optional[str] = None, **tags):
+        """:meth:`span` for a stretch of a long function that no ``with``
+        block fits round: opens it now and returns the function that
+        closes it. It covers other spans' buckets, so it adds none."""
+        cm = self.span(name, phase=phase, bucket=False, **tags)
+        cm.__enter__()
+        return lambda: cm.__exit__(None, None, None)
 
     def counter(self, name: str, value: Optional[int] = None,
                 bytes: Optional[int] = None, phase: Optional[str] = None,

@@ -60,6 +60,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import scopes
 from ..ops.halo_fill import wire_narrow_dtype
 
 
@@ -231,8 +232,8 @@ def make_fused_exchange_kernel(spec, plan, nq: int, dtype,
             pltpu.SemaphoreType.DMA(()),
         ]
     )
-    return pl.pallas_call(
-        kernel,
+    return scopes.kernel_call(
+        "fused_exchange", kernel,
         grid=(1,),
         out_shape=(block,) * nq,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nq,
@@ -457,8 +458,8 @@ def make_fused_jacobi_kernel(spec, plan, dtype=jnp.float32,
             pltpu.SemaphoreType.DMA(()),
         ]
     )
-    return pl.pallas_call(
-        kernel,
+    return scopes.kernel_call(
+        "fused_jacobi", kernel,
         grid=(1,),
         out_shape=(block, block),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,

@@ -12,7 +12,8 @@ touching only the affected (8, 128) tiles.
 
 Axis economics per quantity (512^3, r=3, fp32):
 - z: halo planes are whole (py, px) slabs — 6 plane copies, ~16 MB.
-- y: halo rows live in one 8-row tile per side — RMW of 4 row-tiles, ~84 MB.
+- y: halo rows live in one 8-row tile per side — both rewritten, from two
+  source row-tiles read: 6 row-tile passes, ~64 MB.
 - x: halo columns live inside one 128-lane tile per side — RMW of both
   edge lane-tiles (~0.55 GB; the 128-lane tile is the minimum write
   granularity, a ~42x amplification that any layout storing x halos
@@ -22,6 +23,10 @@ Used by ``HaloExchange`` for AXIS_COMPOSED phases with a single block on
 the axis; multi-block phases keep the ppermute + update path. Phase
 ordering (x, then y, then z) is preserved because each axis is a separate
 kernel call — later phases read the earlier phases' filled halos.
+
+Each build records the HBM bytes one call reads and writes
+(``halo.self_fill.bytes_dma``, see ``_record_dma_bytes``): 0.560 + 0.064 +
+0.016 GB a quantity at the size above.
 """
 
 from __future__ import annotations
@@ -34,6 +39,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..domain.grid import GridSpec
+from ..obs import scopes
 
 _LANE = 128
 _SUB = 8
@@ -233,6 +239,22 @@ def self_fill_supported(spec: GridSpec, axis: str, dtype, z_stack: int = 1) -> b
     return True  # z: untiled dim, plane copies always work
 
 
+def _record_dma_bytes(axis: str, nq: int, shape, read: int, written: int):
+    """The HBM bytes ONE CALL of a self-fill kernel moves, counted where its
+    DMAs are built (HBM -> VMEM: bytes read; VMEM -> HBM: bytes written),
+    recorded once per build as ``halo.self_fill.bytes_dma``. The lane and
+    row tiles the x and y kernels rewrite whole are in it, which
+    ``HaloExchange.bytes_moved`` leaves out. ``utils/mosaic_traffic``
+    derives the same count from the lowered Mosaic module (a test's
+    cross-check)."""
+    from ..obs import telemetry
+
+    telemetry.get().counter(
+        "halo.self_fill.bytes_dma", bytes=read + written, phase="exchange",
+        axis=axis, quantities=nq, shape=list(shape), bytes_read=read,
+        bytes_written=written)
+
+
 def make_self_fill(spec: GridSpec, axis: str, vma=None, interpret: bool = False,
                    nq: int = 1, z_stack: int = 1):
     """Build the in-place periodic fill for one self-wrap axis of fp32
@@ -291,8 +313,11 @@ def make_self_fill(spec: GridSpec, axis: str, vma=None, interpret: bool = False,
                     copy(outs[q], o, o + sz, rp)  # first planes -> high halo
 
         nstage = max(rm, rp, 1)
-        return _wrap(pl.pallas_call(
-            kernel,
+        plane = py * px * 4
+        _record_dma_bytes("z", nq, (pz, py, px), nq * (rm + rp) * plane,
+                          nq * (rm + rp) * plane)
+        return _wrap(scopes.kernel_call(
+            "self_fill_z", kernel,
             grid=(1,),
             out_shape=_out_shape,
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nq,
@@ -366,8 +391,13 @@ def make_self_fill(spec: GridSpec, axis: str, vma=None, interpret: bool = False,
                     ]
                     wr(out, hi_t, hi_span, dv)
 
-        return _wrap(pl.pallas_call(
-            kernel,
+        rows = TZB * px * 4     # one row of a z batch
+        written = (lo_span if rm else 0) + (hi_span if rp else 0)
+        read = written + (src_hi_span if rm else 0) + (src_lo_span if rp else 0)
+        _record_dma_bytes("y", nq, (pz, py, px), n_b * nq * read * rows,
+                          n_b * nq * written * rows)
+        return _wrap(scopes.kernel_call(
+            "self_fill_y", kernel,
             grid=(n_b,),
             out_shape=_out_shape,
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nq,
@@ -483,8 +513,11 @@ def make_self_fill(spec: GridSpec, axis: str, vma=None, interpret: bool = False,
                 wr_wait(nslot, i - 1)
             wr_wait(slot, i)
 
-    return _wrap(pl.pallas_call(
-        kernel,
+    # every batch reads and rewrites both edge lane-tiles of every row
+    tiles = n_b * nq * 2 * TZB * py * _LANE * 4
+    _record_dma_bytes("x", nq, (pz, py, px), tiles, tiles)
+    return _wrap(scopes.kernel_call(
+        "self_fill_x", kernel,
         grid=(n_b,),
         out_shape=_out_shape,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nq,

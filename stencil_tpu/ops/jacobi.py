@@ -30,12 +30,40 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..geometry import Dim3, Radius, Rect3, exterior_regions, interior_region
+from ..obs import scopes
 from ..parallel.exchange import BLOCK_PSPEC, HaloExchange, Method
 from ..utils import timer
 
 HOT_TEMP = 1.0
 COLD_TEMP = 0.0
 INIT_TEMP = (HOT_TEMP + COLD_TEMP) / 2
+
+
+def _masks(sel):
+    """The hot and cold sphere masks of a step, from the packed ``sel``."""
+    with scopes.scope(scopes.MASK):
+        return sel == 1, sel == 2
+
+
+def _reshape(a, shape):
+    """A reshape round a kernel call (``stencil.carry``)."""
+    with scopes.scope(scopes.CARRY):
+        return a.reshape(shape)
+
+
+def _loop_args(ex: HaloExchange):
+    """The abstract (curr, nxt, sel) a jacobi loop over ``ex`` is built
+    for: what ``obs.scopes.op_map`` lowers it with."""
+    shape, sh = ex.spec.stacked_shape_zyx(), ex.sharding()
+    f32 = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sh)
+    return f32, f32, jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
+
+
+def _jit_jacobi(ex: HaloExchange, iters, fn, **jit_kwargs):
+    """The chunk loop (or the single step) under its stable module name,
+    registered with its abstract arguments."""
+    module = scopes.JACOBI_STEP if iters is None else scopes.JACOBI_LOOP
+    return scopes.jit_loop(module, fn, _loop_args(ex), **jit_kwargs)
 
 
 def _rect_slices(rect: Rect3, dz=0, dy=0, dx=0):
@@ -136,7 +164,9 @@ def _sweep_slab_dyn(src3, o3, sel3, lo, size):
         + slab[2 : sz + 2, 1 : sy + 1, 1 : sx + 1]
     ) / 6
     selc = lax.dynamic_slice(sel3, lo, size)
-    avg = jnp.where(selc == 1, HOT_TEMP, jnp.where(selc == 2, COLD_TEMP, avg))
+    with scopes.scope(scopes.MASK):
+        hot, cold = selc == 1, selc == 2
+    avg = jnp.where(hot, HOT_TEMP, jnp.where(cold, COLD_TEMP, avg))
     return lax.dynamic_update_slice(o3, avg.astype(o3.dtype), lo)
 
 
@@ -147,13 +177,15 @@ def _patch_shells_dyn(spec, src, out, sel, multi_block_only: bool):
 
     p = spec.padded()
     shp = out.shape
-    s3 = src.reshape(p.z, p.y, p.x)
-    o3 = out.reshape(p.z, p.y, p.x)
-    sel3 = sel.reshape(p.z, p.y, p.x)
-    sizes = dyn_block_sizes(spec)
-    for lo, size in shell_regions(spec, sizes, include_axes(spec, multi_block_only)):
-        o3 = _sweep_slab_dyn(s3, o3, sel3, lo, size)
-    return o3.reshape(shp)
+    with scopes.scope(scopes.SWEEP_SHELL):
+        s3 = src.reshape(p.z, p.y, p.x)
+        o3 = out.reshape(p.z, p.y, p.x)
+        sel3 = sel.reshape(p.z, p.y, p.x)
+        sizes = dyn_block_sizes(spec)
+        for lo, size in shell_regions(
+                spec, sizes, include_axes(spec, multi_block_only)):
+            o3 = _sweep_slab_dyn(s3, o3, sel3, lo, size)
+        return o3.reshape(shp)
 
 
 def jacobi6_block(block, radius: Radius, masks=None):
@@ -286,15 +318,16 @@ def _compile_jacobi_auto(ex: HaloExchange, overlap: bool, iters,
     use_overlap = overlap and spec.is_uniform()
 
     def body(curr, nxt, sel):
-        masks = (sel == 1, sel == 2)
+        masks = _masks(sel)
         if use_overlap:
             # overlap as dataflow: the interior never touches halos, so the
             # partitioner is free to run its synthesized permutes
             # concurrently with it; the exterior slabs read exchanged halos
             out = jacobi_sweep(curr, nxt, interior, masks)
             cur2 = ex.auto_fill(curr)
-            for rect in exteriors:
-                out = jacobi_sweep(cur2, out, rect, masks)
+            with scopes.scope(scopes.SWEEP_SHELL):
+                for rect in exteriors:
+                    out = jacobi_sweep(cur2, out, rect, masks)
         else:
             # serialized (or uneven): exchange, then sweep the full base
             # extent — cells past an uneven block's true size are dead pad
@@ -310,8 +343,8 @@ def _compile_jacobi_auto(ex: HaloExchange, overlap: bool, iters,
         )
 
     sh = ex.sharding()
-    return jax.jit(
-        entry_fn, in_shardings=(sh,) * 3, out_shardings=(sh, sh),
+    return _jit_jacobi(
+        ex, iters, entry_fn, in_shardings=(sh,) * 3, out_shardings=(sh, sh),
         donate_argnums=(0, 1),
     )
 
@@ -375,12 +408,10 @@ def _compile_jacobi_fused(ex: HaloExchange, iters,
             spec, ex.plan, wire_dtype=ex.wire_dtype)
 
         def body(curr, nxt, sel):
-            c2, out = kern(
-                curr.reshape(p.z, p.y, p.x),
-                nxt.reshape(p.z, p.y, p.x),
-                sel.reshape(p.z, p.y, p.x),
-            )
-            return out.reshape(curr.shape), c2.reshape(curr.shape)
+            p3 = (p.z, p.y, p.x)
+            c2, out = kern(_reshape(curr, p3), _reshape(nxt, p3),
+                           _reshape(sel, p3))
+            return _reshape(out, curr.shape), _reshape(c2, curr.shape)
 
         def entry_fn(curr, nxt, sel):
             if iters is None:
@@ -394,14 +425,14 @@ def _compile_jacobi_fused(ex: HaloExchange, iters,
             in_specs=(BLOCK_PSPEC,) * 3,
             out_specs=(BLOCK_PSPEC, BLOCK_PSPEC),
         )
-        return jax.jit(fn, donate_argnums=(0, 1))
+        return _jit_jacobi(ex, iters, fn, donate_argnums=(0, 1))
 
     # host-orchestrated fused schedule: compiled collective-free sweeps
     # slotted between the emulation's start/wait/finish
     uniform = spec.is_uniform()
 
     def interior_body(curr, nxt, sel):
-        masks = (sel == 1, sel == 2)
+        masks = _masks(sel)
         if uniform:
             return jacobi_sweep(curr, nxt, interior, masks)
         # uneven: full-region sweep on pre-exchange data (boundary
@@ -410,9 +441,10 @@ def _compile_jacobi_fused(ex: HaloExchange, iters,
 
     def boundary_body(cur2, out, sel):
         if uniform:
-            masks = (sel == 1, sel == 2)
-            for rect in exteriors:
-                out = jacobi_sweep(cur2, out, rect, masks)
+            masks = _masks(sel)
+            with scopes.scope(scopes.SWEEP_SHELL):
+                for rect in exteriors:
+                    out = jacobi_sweep(cur2, out, rect, masks)
             return out
         return _patch_shells_dyn(spec, cur2, out, sel,
                                  multi_block_only=False)
@@ -492,7 +524,7 @@ def _compile_jacobi_remote(ex: HaloExchange, iters,
     compute = Rect3(off, off + spec.base)
 
     def sweep_body(curr, nxt, sel):
-        masks = (sel == 1, sel == 2)
+        masks = _masks(sel)
         return jacobi_sweep(curr, nxt, compute, masks)
 
     sweep = jax.jit(jax.shard_map(
@@ -750,20 +782,19 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
 
             def sweep3(c, n):
                 if nres == 1:
-                    return pallas_sweep(
-                        c.reshape(p.z, p.y, p.x),
-                        n.reshape(p.z, p.y, p.x),
-                        sel.reshape(p.z, p.y, p.x),
-                    ).reshape(nxt.shape)
+                    p3 = (p.z, p.y, p.x)
+                    return _reshape(
+                        pallas_sweep(_reshape(c, p3), _reshape(n, p3),
+                                     _reshape(sel, p3)),
+                        nxt.shape)
                 # resident (oversubscribed) shard: the leading block dims
                 # stack whole padded blocks, each with exchange-filled
                 # halos — the per-block kernel runs once per resident
-                cf = c.reshape(nres, p.z, p.y, p.x)
-                nf = n.reshape(nres, p.z, p.y, p.x)
-                sf = sel.reshape(nres, p.z, p.y, p.x)
-                return jnp.stack(
-                    [pallas_sweep(cf[j], nf[j], sf[j]) for j in range(nres)]
-                ).reshape(nxt.shape)
+                p4 = (nres, p.z, p.y, p.x)
+                cf, nf, sf = _reshape(c, p4), _reshape(n, p4), _reshape(sel, p4)
+                outs = [pallas_sweep(cf[j], nf[j], sf[j]) for j in range(nres)]
+                with scopes.scope(scopes.CARRY):
+                    return jnp.stack(outs).reshape(nxt.shape)
 
             if pallas_axes is None:  # DIRECT26: no axis phases to subset
                 cur2 = ex.exchange_block(curr)
@@ -774,13 +805,14 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
                 # the true neighbor columns as side buffers and the two
                 # edge columns are re-swept from them (after any y/z
                 # shells, so edge cells inside shells are also correct)
-                masks = (sel == 1, sel == 2)
+                masks = _masks(sel)
                 if use_overlap:
                     out = sweep3(curr, nxt)
                     cur2 = ex.exchange_block(curr)
                     xlo, xhi = ex.x_side_buffers(curr, 1)
-                    for rect in pallas_shells:
-                        out = _sweep_shell_wrap_x(cur2, out, rect, masks)
+                    with scopes.scope(scopes.SWEEP_SHELL):
+                        for rect in pallas_shells:
+                            out = _sweep_shell_wrap_x(cur2, out, rect, masks)
                 else:
                     # FULL exchange (self-wrap fills included): the edge
                     # patch reads y/z halo rows of the edge columns, which
@@ -788,7 +820,9 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
                     cur2 = ex.exchange_block(curr)
                     xlo, xhi = ex.x_side_buffers(cur2, 1)
                     out = sweep3(cur2, nxt)
-                out = _patch_x_edges_sidebuf(cur2, out, compute, xlo, xhi, masks)
+                with scopes.scope(scopes.SWEEP_SHELL):
+                    out = _patch_x_edges_sidebuf(
+                        cur2, out, compute, xlo, xhi, masks)
                 return out, cur2
             if not pallas_axes:  # every axis self-wraps: no exchange at all
                 return sweep3(curr, nxt), curr
@@ -803,10 +837,11 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
                 # exchange (self-wrap fills included), not the subset
                 out = sweep3(curr, nxt)
                 cur2 = ex.exchange_block(curr)
-                masks = (sel == 1, sel == 2)
+                masks = _masks(sel)
                 shell_sweep = _sweep_shell_wrap_x if tight_x else jacobi_sweep
-                for rect in pallas_shells:
-                    out = shell_sweep(cur2, out, rect, masks)
+                with scopes.scope(scopes.SWEEP_SHELL):
+                    for rect in pallas_shells:
+                        out = shell_sweep(cur2, out, rect, masks)
                 return out, cur2
             if use_dyn_overlap:
                 # same structure, uneven partition: the kernel still wraps
@@ -819,12 +854,13 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
                 return out, cur2
             cur2 = ex.exchange_block(curr, axes=pallas_axes)
             return sweep3(cur2, nxt), cur2
-        masks = (sel == 1, sel == 2)
+        masks = _masks(sel)
         if use_overlap:
             out = jacobi_sweep(curr, nxt, interior, masks)
             cur2 = ex.exchange_block(curr)
-            for rect in exteriors:
-                out = jacobi_sweep(cur2, out, rect, masks)
+            with scopes.scope(scopes.SWEEP_SHELL):
+                for rect in exteriors:
+                    out = jacobi_sweep(cur2, out, rect, masks)
         elif use_dyn_overlap:
             # uneven: full-region sweep on PRE-exchange data (cells within r
             # of a boundary read stale halos and are re-swept below; jacobi
@@ -946,34 +982,32 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
                 def origin(jz, jy, jx):
                     # global block index = device index * residents + j
                     # (leading block dims shard in contiguous chunks)
-                    return jnp.stack([
-                        jnp.asarray((idx[0] * res[0] + jz) * spec.base.z, jnp.int32),
-                        jnp.asarray((idx[1] * res[1] + jy) * spec.base.y, jnp.int32),
-                        jnp.asarray((idx[2] * res[2] + jx) * spec.base.x, jnp.int32),
-                    ])
+                    with scopes.scope(scopes.CARRY):
+                        return jnp.stack([
+                            jnp.asarray((idx[0] * res[0] + jz) * spec.base.z, jnp.int32),
+                            jnp.asarray((idx[1] * res[1] + jy) * spec.base.y, jnp.int32),
+                            jnp.asarray((idx[2] * res[2] + jx) * spec.base.x, jnp.int32),
+                        ])
 
             def run_multi(c, x):
+                p3 = (p.z, p.y, p.x)
                 if nres == 1:
-                    if deep_halo:
-                        return multistep(
-                            origin(0, 0, 0), c.reshape(p.z, p.y, p.x),
-                            x.reshape(p.z, p.y, p.x),
-                        ).reshape(c.shape)
-                    return multistep(
-                        c.reshape(p.z, p.y, p.x), x.reshape(p.z, p.y, p.x)
-                    ).reshape(c.shape)
+                    org = (origin(0, 0, 0),) if deep_halo else ()
+                    return _reshape(
+                        multistep(*org, _reshape(c, p3), _reshape(x, p3)),
+                        c.shape)
                 # resident shard: one multistep per stacked block, each at
                 # its own global origin (residency implies multi-block axes,
                 # so this is always the deep-halo form)
                 assert deep_halo
-                cf = c.reshape(nres, p.z, p.y, p.x)
-                xf = x.reshape(nres, p.z, p.y, p.x)
+                cf, xf = _reshape(c, (nres,) + p3), _reshape(x, (nres,) + p3)
                 outs = []
                 for j in range(nres):
                     jz, rem = divmod(j, res[1] * res[2])
                     jy, jx = divmod(rem, res[2])
                     outs.append(multistep(origin(jz, jy, jx), cf[j], xf[j]))
-                return jnp.stack(outs).reshape(c.shape)
+                with scopes.scope(scopes.CARRY):
+                    return jnp.stack(outs).reshape(c.shape)
 
             def mbody(cn):
                 c, x = cn
@@ -1003,7 +1037,7 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
         out_specs=(BLOCK_PSPEC, BLOCK_PSPEC),
         check_vma=not interpret,
     )
-    return jax.jit(fn, donate_argnums=(0, 1))
+    return _jit_jacobi(ex, iters, fn, donate_argnums=(0, 1))
 
 
 def make_batched_jacobi_loop(spec, iters: int, *, sharding=None,
@@ -1075,7 +1109,7 @@ def make_batched_jacobi_loop(spec, iters: int, *, sharding=None,
             out = pallas_sweep(curr, nxt, sel)
             return out, curr
         cur2 = wrap_fill_batched(spec, curr)
-        masks = (sel == 1, sel == 2)
+        masks = _masks(sel)
         out = jacobi_sweep(cur2, nxt, compute, masks)
         return out, cur2
 
@@ -1087,10 +1121,12 @@ def make_batched_jacobi_loop(spec, iters: int, *, sharding=None,
         )
 
     with timer.timed("jacobi.build"), timer.trace_range("jacobi.build"):
+        # named like the single-domain loop; the batch is the caller's, so
+        # nothing is registered for op_map
         if sharding is None:
-            return jax.jit(entry_fn)
-        return jax.jit(
-            entry_fn,
+            return scopes.jit_loop(scopes.JACOBI_LOOP, entry_fn)
+        return scopes.jit_loop(
+            scopes.JACOBI_LOOP, entry_fn,
             in_shardings=(sharding, sharding, sel_sharding or sharding),
             out_shardings=(sharding, sharding),
         )

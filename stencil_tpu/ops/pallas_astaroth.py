@@ -78,6 +78,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..domain.grid import GridSpec
 from ..geometry import Rect3, Dim3
+from ..obs import scopes
 from ..astaroth.fd import field_data
 from ..astaroth.equations import Constants, continuity, entropy, induction, momentum
 
@@ -438,8 +439,8 @@ def make_pallas_substep(
     shape = jax.ShapeDtypeStruct(
         (pz, py, px), jnp.float32, vma=frozenset(vma) if vma is not None else None
     )
-    fn = pl.pallas_call(
-        kernel,
+    fn = scopes.kernel_call(
+        "astaroth_substep", kernel,
         grid=(n_ty, n_tz),
         out_shape=(shape,) * NF,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (2 * NF),

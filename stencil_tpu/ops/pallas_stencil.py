@@ -44,6 +44,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..domain.grid import GridSpec
 from ..geometry import Dim3
+from ..obs import scopes
 from .jacobi import COLD_TEMP, HOT_TEMP
 
 # VMEM scratch budget (~16 MB/core on v5e; leave headroom for the compiler)
@@ -347,8 +348,8 @@ def make_pallas_jacobi_sweep(
     else:
         # inside shard_map, declare the output varying over the mesh axes
         out_shape = jax.ShapeDtypeStruct(shape, jnp.float32, vma=frozenset(vma))
-    fn = pl.pallas_call(
-        kernel,
+    fn = scopes.kernel_call(
+        "jacobi_sweep", kernel,
         grid=(n_tiles,) if batch is None else (batch, n_tiles),
         out_shape=out_shape,
         in_specs=[
@@ -706,8 +707,8 @@ def make_pallas_jacobi_multistep(
         vmem_limit_bytes=100 * 1024 * 1024,
     )
     if use_org:
-        return pl.pallas_call(
-            kernel,
+        return scopes.kernel_call(
+            "jacobi_multistep", kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(J,),
@@ -723,8 +724,8 @@ def make_pallas_jacobi_multistep(
             compiler_params=params,
             interpret=interpret,
         )
-    return pl.pallas_call(
-        kernel,
+    return scopes.kernel_call(
+        "jacobi_multistep", kernel,
         grid=(J,),
         out_shape=out_shape,
         in_specs=[
@@ -990,8 +991,8 @@ def _make_multistep_row_tiled(
         vmem_limit_bytes=100 * 1024 * 1024,
     )
     if use_org:
-        return pl.pallas_call(
-            kernel,
+        return scopes.kernel_call(
+            "jacobi_multistep_rows", kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(n_ty, J),
@@ -1007,8 +1008,8 @@ def _make_multistep_row_tiled(
             compiler_params=params,
             interpret=interpret,
         )
-    return pl.pallas_call(
-        kernel,
+    return scopes.kernel_call(
+        "jacobi_multistep_rows", kernel,
         grid=(n_ty, J),
         out_shape=out_shape,
         in_specs=[

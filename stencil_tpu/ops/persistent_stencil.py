@@ -69,6 +69,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..geometry import DIRECTIONS_26, Dim3, Rect3
+from ..obs import scopes
 
 
 def persistent_kernel_supported(spec, resident) -> bool:
@@ -402,8 +403,8 @@ def make_persistent_jacobi_kernel(spec, plan, k: int, dtype=jnp.float32,
             pltpu.SemaphoreType.DMA(()),
         ]
     )
-    return pl.pallas_call(
-        kernel,
+    return scopes.kernel_call(
+        "persistent_jacobi", kernel,
         grid=(1,),
         out_shape=(block, block, sel_block),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,

@@ -48,6 +48,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs import scopes
 from ..ops.halo_fill import wire_narrow_dtype
 
 
@@ -199,8 +200,8 @@ def make_remote_axis_kernel(spec, phase, nq: int, dtype,
                           dslice(off + sz_my, rp))
 
     block = jax.ShapeDtypeStruct((pz, py, px), dtype)
-    return pl.pallas_call(
-        kernel,
+    return scopes.kernel_call(
+        "remote_dma", kernel,
         grid=(1,),
         out_shape=(block,) * nq,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nq,

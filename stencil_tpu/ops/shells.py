@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..domain.grid import GridSpec
+from ..obs import scopes
 
 
 def dyn_block_sizes(spec: GridSpec):
@@ -49,7 +50,8 @@ def dyn_block_sizes(spec: GridSpec):
         (AXIS_X, spec.dim.x, spec.sizes_x, spec.base.x),
     ):
         if d > 1 and min(szs) != max(szs):
-            out.append(jnp.asarray(szs, jnp.int32)[lax.axis_index(name)])
+            with scopes.scope(scopes.SWEEP_SHELL):
+                out.append(jnp.asarray(szs, jnp.int32)[lax.axis_index(name)])
         else:
             out.append(base)
     return tuple(out)
@@ -101,13 +103,14 @@ def interior_mask(spec: GridSpec, sizes, include: Sequence[bool]):
     shape = (spec.base.z, spec.base.y, spec.base.x)
     r = spec.radius
     rad = (r.z, r.y, r.x)
-    m = jnp.ones(shape, jnp.bool_)
-    for ax in range(3):
-        if not include[ax]:
-            continue
-        rel = lax.broadcasted_iota(jnp.int32, shape, ax)
-        m = m & (rel >= rad[ax](-1)) & (rel < sizes[ax] - rad[ax](1))
-    return m
+    with scopes.scope(scopes.MASK):
+        m = jnp.ones(shape, jnp.bool_)
+        for ax in range(3):
+            if not include[ax]:
+                continue
+            rel = lax.broadcasted_iota(jnp.int32, shape, ax)
+            m = m & (rel >= rad[ax](-1)) & (rel < sizes[ax] - rad[ax](1))
+        return m
 
 
 def include_axes(spec: GridSpec, multi_block_only: bool) -> Tuple[bool, bool, bool]:

@@ -77,6 +77,7 @@ from jax.sharding import Mesh, NamedSharding
 
 from ..domain.grid import GridSpec
 from ..geometry import DIRECTIONS_26, Dim3, halo_extent
+from ..obs import scopes
 from ..plan.ir import build_plan, spec_axis as _spec_axis
 from ..utils import timer
 from .mesh import AXIS_X, AXIS_Y, AXIS_Z, BLOCK_PSPEC, block_sharding, mesh_dim
@@ -343,15 +344,17 @@ class HaloExchange:
             raise ValueError("side buffers do not support x residency")
         n = len(sizes)
         nx = sizes[0]
-        hi_cols = block[..., nx - r : nx]
-        lo_cols = block[..., 0:r]
+        with scopes.scope(scopes.HALO_PACK):
+            hi_cols = block[..., nx - r : nx]
+            lo_cols = block[..., 0:r]
         if n > 1:
             fwd = [(i, (i + 1) % n) for i in range(n)]
             bwd = [(i, (i - 1) % n) for i in range(n)]
-            return (
-                lax.ppermute(hi_cols, AXIS_X, fwd),
-                lax.ppermute(lo_cols, AXIS_X, bwd),
-            )
+            with scopes.scope(scopes.HALO_WIRE):
+                return (
+                    lax.ppermute(hi_cols, AXIS_X, fwd),
+                    lax.ppermute(lo_cols, AXIS_X, bwd),
+                )
         return hi_cols, lo_cols
 
     def exchange_blocks(self, state, axes=None):
@@ -423,10 +426,11 @@ class HaloExchange:
                     for i in range(0, len(keys), ax_gmax):
                         chunk = keys[i : i + ax_gmax]
                         fill = self._multi_fill(name, len(chunk))
-                        res = fill(*[out[k].reshape(fshape) for k in chunk])
-                        res = (res,) if len(chunk) == 1 else res
-                        for k, v in zip(chunk, res):
-                            out[k] = v.reshape(state[k].shape)
+                        with scopes.scope(scopes.HALO_SELF_FILL):
+                            res = fill(*[out[k].reshape(fshape) for k in chunk])
+                            res = (res,) if len(chunk) == 1 else res
+                            for k, v in zip(chunk, res):
+                                out[k] = v.reshape(state[k].shape)
                 elif self.batch_quantities and len(keys) > 1:
                     blocks = self._axis_phase_batched(
                         [out[k] for k in keys], phase
@@ -530,14 +534,19 @@ class HaloExchange:
     def sharding(self) -> NamedSharding:
         return block_sharding(self.mesh)
 
-    def make_loop(self, iters: int):
+    def make_loop(self, iters: int, like=None):
         """``iters`` back-to-back exchanges in one compiled program — for
         benchmarking without per-dispatch host overhead (the analogue of the
         reference's timed exchange loop, bin/exchange_weak.cu:168-177).
         Loops are cached per ``iters``, so repeated calls reuse the jitted
-        program instead of retracing."""
+        program instead of retracing. The program is the module
+        ``stencil_exchange_loop``; with ``like`` (the state it will be
+        called with, arrays or structs) it is registered for
+        ``obs.scopes.op_map``."""
         cache = self.__dict__.setdefault("_loops", {})
         if iters not in cache:
+            args = None if like is None else (
+                scopes.abstract(like, self.sharding()),)
             # build-phase accounting for all strategies (the
             # flight-recorder bucket; jax.profiler sees the same range)
             with timer.timed("exchange.build"), \
@@ -556,9 +565,9 @@ class HaloExchange:
                         )
 
                     sh = self.sharding()
-                    cache[iters] = jax.jit(
-                        many, in_shardings=sh, out_shardings=sh,
-                        donate_argnums=0,
+                    cache[iters] = scopes.jit_loop(
+                        scopes.EXCHANGE_LOOP, many, args, in_shardings=sh,
+                        out_shardings=sh, donate_argnums=0,
                     )
                     return cache[iters]
 
@@ -571,7 +580,8 @@ class HaloExchange:
                     many, mesh=self.mesh, in_specs=BLOCK_PSPEC,
                     out_specs=BLOCK_PSPEC,
                 )
-                cache[iters] = jax.jit(fn, donate_argnums=0)
+                cache[iters] = scopes.jit_loop(
+                    scopes.EXCHANGE_LOOP, fn, args, donate_argnums=0)
         return cache[iters]
 
     def collective_census(self, state) -> Dict[str, Tuple[int, int]]:
@@ -612,8 +622,10 @@ class HaloExchange:
         span full padded extents, so this is >= bytes_logical. On a
         self-wrap (single-block) axis no collective carries data — the same
         slab bytes move in place, via the Pallas fill kernel on TPU (whose
-        x/y lane/row-tile RMW amplification is not counted here) or via
-        slice+update elsewhere. AUTO_SPMD expresses the composed slab
+        x/y lane/row-tile RMW amplification is not counted here: the
+        ``halo.self_fill.bytes_dma`` counter each fill build records holds
+        the HBM bytes its DMAs really read and write) or via slice+update
+        elsewhere. AUTO_SPMD expresses the composed slab
         program, so it shares the composed accounting (the partitioner may
         move less; collective_census counts what it actually emitted).
         Uneven DIRECT26 pads orthogonal extents to the base block size."""
@@ -701,9 +713,10 @@ class HaloExchange:
         ):
             # self-wrap axis: fill halos in place, touching only the edge
             # tiles, instead of materializing slabs + whole-array updates
-            return self._self_fills[phase.axis](
-                block.reshape(self._fill_shape())
-            ).reshape(block.shape)
+            with scopes.scope(scopes.HALO_SELF_FILL):
+                return self._self_fills[phase.axis](
+                    block.reshape(self._fill_shape())
+                ).reshape(block.shape)
         # the slab movement itself is the batched body's Q=1 degeneration
         # (pack_slabs is the identity there) — one copy of the geometry
         return self._axis_phase_batched([block], phase)[0]
@@ -741,19 +754,20 @@ class HaloExchange:
         from ..ops.halo_fill import wire_narrow_dtype
 
         w = wire_narrow_dtype(carrier.dtype, self.wire_dtype)
-        if w is None:
-            return lax.ppermute(carrier, name, pairs)
-        native = carrier.dtype
-        # optimization_barrier on BOTH sides: XLA's convert-mover happily
-        # hoists a narrowing convert across a collective-permute (and
-        # fuses the pair back into a sender-side rounding), which keeps
-        # the rounding but puts full-width bytes back on the wire — the
-        # barriers pin narrow-before-send / widen-after-receive so the
-        # permute payload (what the census bytes count) really is the
-        # wire dtype
-        wired = lax.optimization_barrier(carrier.astype(w))
-        out = lax.optimization_barrier(lax.ppermute(wired, name, pairs))
-        return out.astype(native)
+        with scopes.scope(scopes.HALO_WIRE):
+            if w is None:
+                return lax.ppermute(carrier, name, pairs)
+            native = carrier.dtype
+            # optimization_barrier on BOTH sides: XLA's convert-mover
+            # happily hoists a narrowing convert across a collective-permute
+            # (and fuses the pair back into a sender-side rounding), which
+            # keeps the rounding but puts full-width bytes back on the wire —
+            # the barriers pin narrow-before-send / widen-after-receive so
+            # the permute payload (what the census bytes count) really is
+            # the wire dtype
+            wired = lax.optimization_barrier(carrier.astype(w))
+            out = lax.optimization_barrier(lax.ppermute(wired, name, pairs))
+            return out.astype(native)
 
     # -- quantity-batched phases (packed carriers) ---------------------------
     def _axis_phase_batched(self, blocks, phase):
@@ -787,25 +801,29 @@ class HaloExchange:
         fwd, bwd = phase.fwd, phase.bwd
         nq = len(blocks)
         if rm > 0:
-            carrier = pack_slabs(
-                [_slice_in_dim(b, off + sz - rm, rm, adim) for b in blocks]
-            )
+            with scopes.scope(scopes.HALO_PACK):
+                carrier = pack_slabs(
+                    [_slice_in_dim(b, off + sz - rm, rm, adim) for b in blocks]
+                )
             if n > 1:  # ONE permute for the whole group
                 carrier = self._permute_wire(carrier, name, fwd)
-            blocks = [
-                _update_in_dim(b, s, off - rm, adim)
-                for b, s in zip(blocks, unpack_slabs(carrier, nq))
-            ]
+            with scopes.scope(scopes.HALO_UNPACK):
+                blocks = [
+                    _update_in_dim(b, s, off - rm, adim)
+                    for b, s in zip(blocks, unpack_slabs(carrier, nq))
+                ]
         if rp > 0:
-            carrier = pack_slabs(
-                [_slice_in_dim(b, off, rp, adim) for b in blocks]
-            )
+            with scopes.scope(scopes.HALO_PACK):
+                carrier = pack_slabs(
+                    [_slice_in_dim(b, off, rp, adim) for b in blocks]
+                )
             if n > 1:
                 carrier = self._permute_wire(carrier, name, bwd)
-            blocks = [
-                _update_in_dim(b, s, off + sz, adim)
-                for b, s in zip(blocks, unpack_slabs(carrier, nq))
-            ]
+            with scopes.scope(scopes.HALO_UNPACK):
+                blocks = [
+                    _update_in_dim(b, s, off + sz, adim)
+                    for b, s in zip(blocks, unpack_slabs(carrier, nq))
+                ]
         return blocks
 
     def _axis_phase_resident_batched(self, blocks, phase):
@@ -829,12 +847,14 @@ class HaloExchange:
             shp = list(b.shape)
             shp[bdim] = 1
             shp[adim] = width
-            return lax.dynamic_slice(b, starts, tuple(shp))
+            with scopes.scope(scopes.HALO_PACK):
+                return lax.dynamic_slice(b, starts, tuple(shp))
 
         def put_j(b, slab, j, start):
             starts = _starts(b.ndim, start, adim)
             starts = starts[:bdim] + (jnp.asarray(j, jnp.int32),) + starts[bdim + 1:]
-            return lax.dynamic_update_slice(b, slab, starts)
+            with scopes.scope(scopes.HALO_UNPACK):
+                return lax.dynamic_update_slice(b, slab, starts)
 
         blocks = list(blocks)
         if rm > 0:
@@ -844,8 +864,11 @@ class HaloExchange:
             ]
             incoming = [s[c - 1] for s in srcs]
             if m > 1:
-                carrier = self._permute_wire(pack_slabs(incoming), name, fwd)
-                incoming = unpack_slabs(carrier, nq)
+                with scopes.scope(scopes.HALO_PACK):
+                    carrier = pack_slabs(incoming)
+                carrier = self._permute_wire(carrier, name, fwd)
+                with scopes.scope(scopes.HALO_UNPACK):
+                    incoming = unpack_slabs(carrier, nq)
             for q in range(nq):
                 for j in range(c):
                     blocks[q] = put_j(
@@ -856,8 +879,11 @@ class HaloExchange:
             srcs = [[take_j(b, j, off, rp) for j in range(c)] for b in blocks]
             incoming = [s[0] for s in srcs]
             if m > 1:
-                carrier = self._permute_wire(pack_slabs(incoming), name, bwd)
-                incoming = unpack_slabs(carrier, nq)
+                with scopes.scope(scopes.HALO_PACK):
+                    carrier = pack_slabs(incoming)
+                carrier = self._permute_wire(carrier, name, bwd)
+                with scopes.scope(scopes.HALO_UNPACK):
+                    incoming = unpack_slabs(carrier, nq)
             for q in range(nq):
                 for j in range(c):
                     blocks[q] = put_j(
@@ -910,13 +936,20 @@ class HaloExchange:
             if rm > 0:
                 # every block's top rm planes -> its +neighbor's low halo:
                 # globally, a roll of the slab one step up the block dim
-                slab = lax.slice_in_dim(arr, off + sz - rm, off + sz, axis=adim)
-                slab = jnp.roll(slab, 1, axis=bdim)
-                arr = _update_in_dim(arr, slab, off - rm, adim)
+                with scopes.scope(scopes.HALO_PACK):
+                    slab = lax.slice_in_dim(
+                        arr, off + sz - rm, off + sz, axis=adim)
+                with scopes.scope(scopes.HALO_WIRE):
+                    slab = jnp.roll(slab, 1, axis=bdim)
+                with scopes.scope(scopes.HALO_UNPACK):
+                    arr = _update_in_dim(arr, slab, off - rm, adim)
             if rp > 0:
-                slab = lax.slice_in_dim(arr, off, off + rp, axis=adim)
-                slab = jnp.roll(slab, -1, axis=bdim)
-                arr = _update_in_dim(arr, slab, off + sz, adim)
+                with scopes.scope(scopes.HALO_PACK):
+                    slab = lax.slice_in_dim(arr, off, off + rp, axis=adim)
+                with scopes.scope(scopes.HALO_WIRE):
+                    slab = jnp.roll(slab, -1, axis=bdim)
+                with scopes.scope(scopes.HALO_UNPACK):
+                    arr = _update_in_dim(arr, slab, off + sz, adim)
             return arr
         # uneven axis: per-block source/dest offsets. The source gather and
         # the dest blend are elementwise along (block dim x data dim) pairs,
@@ -931,23 +964,32 @@ class HaloExchange:
             # receiver's low-side halo sits at the static [off - rm, off)
             ashape = [1] * ndim
             ashape[adim] = rm
-            gidx = sz_b + (off - rm) + jnp.arange(rm, dtype=jnp.int32).reshape(ashape)
-            slab = jnp.take_along_axis(arr, gidx, axis=adim)
-            slab = jnp.roll(slab, 1, axis=bdim)
-            arr = _update_in_dim(arr, slab, off - rm, adim)
+            with scopes.scope(scopes.HALO_PACK):
+                gidx = sz_b + (off - rm) + jnp.arange(
+                    rm, dtype=jnp.int32).reshape(ashape)
+                slab = jnp.take_along_axis(arr, gidx, axis=adim)
+            with scopes.scope(scopes.HALO_WIRE):
+                slab = jnp.roll(slab, 1, axis=bdim)
+            with scopes.scope(scopes.HALO_UNPACK):
+                arr = _update_in_dim(arr, slab, off - rm, adim)
         if rp > 0:
             # the sender side is static ([off, off + rp), the compute
             # origin); the receiver's high-side halo starts at the
             # per-block off + sizes[i] — a masked blend places it
-            slab = lax.slice_in_dim(arr, off, off + rp, axis=adim)
-            slab = jnp.roll(slab, -1, axis=bdim)
-            ashape = [1] * ndim
-            ashape[adim] = arr.shape[adim]
-            rel = jnp.arange(arr.shape[adim], dtype=jnp.int32).reshape(ashape) - (
-                sz_b + off
-            )
-            vals = jnp.take_along_axis(slab, jnp.clip(rel, 0, rp - 1), axis=adim)
-            arr = jnp.where((rel >= 0) & (rel < rp), vals, arr)
+            with scopes.scope(scopes.HALO_PACK):
+                slab = lax.slice_in_dim(arr, off, off + rp, axis=adim)
+            with scopes.scope(scopes.HALO_WIRE):
+                slab = jnp.roll(slab, -1, axis=bdim)
+            with scopes.scope(scopes.HALO_UNPACK):
+                ashape = [1] * ndim
+                ashape[adim] = arr.shape[adim]
+                rel = jnp.arange(
+                    arr.shape[adim], dtype=jnp.int32).reshape(ashape) - (
+                    sz_b + off
+                )
+                vals = jnp.take_along_axis(
+                    slab, jnp.clip(rel, 0, rp - 1), axis=adim)
+                arr = jnp.where((rel >= 0) & (rel < rp), vals, arr)
         return arr
 
     # -- direct-26 implementation -------------------------------------------
@@ -974,20 +1016,22 @@ class HaloExchange:
         boff = 1 if nq > 1 else 0  # the packed carrier's leading Q axis
         updates = []
         for ph in self.plan.direct_phases:
-            carrier = pack_slabs([
-                lax.dynamic_slice(
-                    b, (0, 0, 0) + ph.src, (cz, cy, cx) + ph.shape
-                )
-                for b in blocks
-            ])
+            with scopes.scope(scopes.HALO_PACK):
+                carrier = pack_slabs([
+                    lax.dynamic_slice(
+                        b, (0, 0, 0) + ph.src, (cz, cy, cx) + ph.shape
+                    )
+                    for b in blocks
+                ])
             carrier = self._roll_blocks(carrier, ph, boff=boff)
             updates.append((carrier, ph.dst))
         out = list(blocks)
-        for carrier, dsts in updates:
-            for q, piece in enumerate(unpack_slabs(carrier, nq)):
-                out[q] = lax.dynamic_update_slice(
-                    out[q], piece, (0, 0, 0) + dsts
-                )
+        with scopes.scope(scopes.HALO_UNPACK):
+            for carrier, dsts in updates:
+                for q, piece in enumerate(unpack_slabs(carrier, nq)):
+                    out[q] = lax.dynamic_update_slice(
+                        out[q], piece, (0, 0, 0) + dsts
+                    )
         return out
 
     def _direct26_batched_uneven(self, blocks):
@@ -1054,9 +1098,9 @@ class HaloExchange:
                     parts_z.append(_concat(parts_y, 1))
                 return _concat(parts_z, 0)
 
-            carrier = self._roll_blocks(
-                pack_slabs([gather(b) for b in out]), ph, boff=boff
-            )
+            with scopes.scope(scopes.HALO_PACK):
+                carrier = pack_slabs([gather(b) for b in out])
+            carrier = self._roll_blocks(carrier, ph, boff=boff)
             for q, slab in enumerate(unpack_slabs(carrier, nq)):
                 for jz in range(cz):
                     for jy in range(cy):
@@ -1066,13 +1110,14 @@ class HaloExchange:
                                 o - rm if dc == 1 else o + s if dc == -1 else o
                                 for (dc, o, rm, _rp, _b), s in zip(info, s3)
                             )
-                            piece = lax.dynamic_slice(
-                                slab, _starts6((jz, jy, jx), (0, 0, 0)),
-                                (1, 1, 1) + shape,
-                            )
-                            out[q] = lax.dynamic_update_slice(
-                                out[q], piece, _starts6((jz, jy, jx), dst)
-                            )
+                            with scopes.scope(scopes.HALO_UNPACK):
+                                piece = lax.dynamic_slice(
+                                    slab, _starts6((jz, jy, jx), (0, 0, 0)),
+                                    (1, 1, 1) + shape,
+                                )
+                                out[q] = lax.dynamic_update_slice(
+                                    out[q], piece, _starts6((jz, jy, jx), dst)
+                                )
         return out
 
     def _roll_blocks(self, slab, ph, boff: int = 0):
@@ -1088,35 +1133,37 @@ class HaloExchange:
         if not self.oversubscribed:
             return self._permute_wire(slab, (AXIS_Z, AXIS_Y, AXIS_X), ph.pairs)
         md = mesh_dim(self.mesh)
-        for name, bdim, comp, m, c in (
-            (AXIS_Z, boff + 0, d.z, md.z, self.resident.z),
-            (AXIS_Y, boff + 1, d.y, md.y, self.resident.y),
-            (AXIS_X, boff + 2, d.x, md.x, self.resident.x),
-        ):
-            if comp == 0:
-                continue
-            if c == 1:
-                if m > 1:
-                    pairs = [(i, (i + comp) % m) for i in range(m)]
-                    slab = self._permute_wire(slab, name, pairs)
-                continue
-            if comp == 1:
-                last = lax.slice_in_dim(slab, c - 1, c, axis=bdim)
-                if m > 1:
-                    last = self._permute_wire(
-                        last, name, [(i, (i + 1) % m) for i in range(m)])
-                slab = jnp.concatenate(
-                    [last, lax.slice_in_dim(slab, 0, c - 1, axis=bdim)], axis=bdim
-                )
-            else:
-                first = lax.slice_in_dim(slab, 0, 1, axis=bdim)
-                if m > 1:
-                    first = self._permute_wire(
-                        first, name, [(i, (i - 1) % m) for i in range(m)])
-                slab = jnp.concatenate(
-                    [lax.slice_in_dim(slab, 1, c, axis=bdim), first], axis=bdim
-                )
-        return slab
+        with scopes.scope(scopes.HALO_WIRE):  # resident shifts are the wire
+            for name, bdim, comp, m, c in (
+                (AXIS_Z, boff + 0, d.z, md.z, self.resident.z),
+                (AXIS_Y, boff + 1, d.y, md.y, self.resident.y),
+                (AXIS_X, boff + 2, d.x, md.x, self.resident.x),
+            ):
+                if comp == 0:
+                    continue
+                if c == 1:
+                    if m > 1:
+                        pairs = [(i, (i + comp) % m) for i in range(m)]
+                        slab = self._permute_wire(slab, name, pairs)
+                    continue
+                if comp == 1:
+                    last = lax.slice_in_dim(slab, c - 1, c, axis=bdim)
+                    if m > 1:
+                        last = self._permute_wire(
+                            last, name, [(i, (i + 1) % m) for i in range(m)])
+                    slab = jnp.concatenate(
+                        [last, lax.slice_in_dim(slab, 0, c - 1, axis=bdim)], axis=bdim
+                    )
+                else:
+                    first = lax.slice_in_dim(slab, 0, 1, axis=bdim)
+                    if m > 1:
+                        first = self._permute_wire(
+                            first, name, [(i, (i - 1) % m) for i in range(m)])
+                    slab = jnp.concatenate(
+                        [lax.slice_in_dim(slab, 1, c, axis=bdim), first], axis=bdim
+                    )
+            return slab
+
 
 def _starts(ndim: int, start, adim: int):
     """Per-dim start indices, uniformly int32 (mixed Python-int / traced-scalar

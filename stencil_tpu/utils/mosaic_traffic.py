@@ -25,6 +25,8 @@ from math import prod
 from typing import Callable, List, Sequence, Tuple
 
 _MARKER = "The Mosaic module for pallas_call kernel at "
+# a kernel built with name= prints that name in the place of "kernel"
+_MARKER_RE = re.compile(r"The Mosaic module for pallas_call \S+ at ")
 
 # string literals must not contribute to region-brace counting (MLIR
 # sym_name / location attributes may contain braces)
@@ -216,7 +218,7 @@ def _parse_module(name: str, lines: Sequence[str]) -> KernelTraffic:
 def parse_mosaic_dumps(text: str) -> List[KernelTraffic]:
     """Split a captured debug stream into per-kernel traffic records."""
     out: List[KernelTraffic] = []
-    chunks = text.split(_MARKER)[1:]
+    chunks = _MARKER_RE.split(text)[1:]
     for chunk in chunks:
         lines = chunk.splitlines()
         # first line: "<path>:<line>:"

@@ -282,8 +282,11 @@ def test_run_guarded_feeds_sentinel_and_detects_midrun(tmp_path):
 
         def step_fn(st, k):
             # steps 1..5 fast; step 6's chunk sleeps (a stand-in for the
-            # slow@N injection, whose sleep also lands inside the cycle)
-            time.sleep(0.08 if int(st["q"][0]) + k == 6 else 0.002)
+            # slow@N injection, whose sleep also lands inside the cycle).
+            # 20 ms a step, not 2: under six loaded test workers a step's
+            # own overhead jitters by milliseconds, which a 2 ms band read
+            # as a second anomaly (the run then never cleared)
+            time.sleep(0.4 if int(st["q"][0]) + k == 6 else 0.02)
             return {"q": st["q"] + k}
 
         state, done = run_guarded(

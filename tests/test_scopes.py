@@ -1,0 +1,286 @@
+"""The program's names for its own device work (``obs/scopes.py``): the
+vocabulary is the only source of scope, kernel and module names; every
+default loop can be mapped back from optimized-HLO instruction names to the
+scope and layer that asked for the work; the self-fill kernels count the
+bytes their DMAs move; the recorder keeps its records in memory."""
+
+import ast
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from stencil_tpu.obs import scopes, telemetry
+
+PKG = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                   "stencil_tpu")
+PLUMBING = {"parameter", "constant", "get-tuple-element", "tuple", "bitcast",
+            "while", "call", "conditional"}
+
+
+def _trees():
+    for base, _, files in os.walk(PKG):
+        for f in files:
+            if f.endswith(".py"):
+                path = os.path.join(base, f)
+                with open(path) as fh:
+                    yield os.path.relpath(path, PKG), ast.parse(fh.read())
+
+
+def _calls(tree, attr):
+    return [n for n in ast.walk(tree) if isinstance(n, ast.Call)
+            and isinstance(n.func, ast.Attribute) and n.func.attr == attr]
+
+
+# ------------------------------------------------------------ (a) vocabulary
+
+
+def test_every_pallas_call_passes_a_name_and_every_kernel_is_in_the_vocabulary():
+    sites, kernels = [], []
+    for rel, tree in _trees():
+        for call in _calls(tree, "pallas_call"):
+            sites.append(rel)
+            assert any(k.arg == "name" for k in call.keywords), rel
+        for call in _calls(tree, "kernel_call"):
+            first = call.args[0]
+            assert isinstance(first, ast.Constant), (rel, "literal name")
+            kernels.append(first.value)
+    assert sites == [os.path.join("obs", "scopes.py")], sites
+    assert len(kernels) == 13
+    assert set(kernels) == set(scopes.KERNELS)
+
+
+def test_every_named_scope_is_in_the_vocabulary():
+    constants = {v for k, v in vars(scopes).items()
+                 if k.isupper() and isinstance(v, str)}
+    seen = set()
+    for rel, tree in _trees():
+        for call in _calls(tree, "named_scope"):
+            # only obs/scopes.py opens jax.named_scope, from vocabulary names
+            assert rel == os.path.join("obs", "scopes.py"), rel
+        for call in _calls(tree, "scope"):
+            arg = call.args[0]
+            if isinstance(arg, ast.Constant):
+                assert arg.value in scopes.SCOPES, (rel, arg.value)
+                seen.add(arg.value)
+            elif (isinstance(arg, ast.Attribute)
+                  and isinstance(arg.value, ast.Name)
+                  and arg.value.id == "scopes"):
+                name = getattr(scopes, arg.attr)
+                assert name in scopes.SCOPES and name in constants, rel
+                seen.add(name)
+    assert seen == set(scopes.SCOPES)
+    assert all(s.startswith(scopes.PREFIX) for s in scopes.SCOPES)
+    assert scopes.layer_of("stencil.kernel.self_fill_x") == scopes.LAYER_HALO
+    assert scopes.layer_of("stencil.kernel.fused_jacobi") == scopes.LAYER_KERNELS
+    assert scopes.layer_of(None) is None
+    with pytest.raises(KeyError):
+        scopes.scope("stencil.typo")
+    with pytest.raises(KeyError):
+        scopes.kernel_call("nameless", lambda: None)
+    with pytest.raises(KeyError):
+        scopes.jit_loop("entry_fn", lambda x: x)
+
+
+# ------------------------------------------------------------ (b) op maps
+
+
+def _build(case):
+    """Build one default loop on the CPU mesh; the module it registers
+    under."""
+    from stencil_tpu.apps import astaroth, exchange_weak, jacobi3d
+
+    scopes.clear()
+    app, n = case
+    devices = jax.devices()[:n]
+    if app == "jacobi":
+        jacobi3d.run(32, 16, 16, iters=20, devices=devices)
+        return scopes.JACOBI_LOOP
+    if app == "exchange":
+        exchange_weak.run(16, 16, 16, iters=20, devices=devices)
+        return scopes.EXCHANGE_LOOP
+    astaroth.run(nx=16, iters=2, devices=devices, dtype="float32")
+    return scopes.ASTAROTH_ITER
+
+
+@pytest.mark.parametrize("case", [("jacobi", 1), ("jacobi", 4),
+                                  ("astaroth", 1), ("exchange", 1),
+                                  ("exchange", 4)], ids=lambda c: f"{c[0]}{c[1]}")
+def test_default_loop_maps_back_to_scopes(case):
+    module = _build(case)
+    assert scopes.registered(module) >= 1
+    text = scopes.hlo_text(module)
+    assert text.startswith(f"HloModule jit_{module}")
+    omap = scopes.op_map(module)
+    assert len(omap) > 10 and scopes.op_map_seconds(module) > 0
+    work = {k: v for k, v in omap.items() if v["opcode"] not in PLUMBING}
+    # no instruction lies under scopes of two layers
+    assert [k for k, v in omap.items() if len(v["layers"]) > 1] == []
+    for k, v in work.items():
+        assert v["layer"] == scopes.layer_of(v["scope"]), k
+        # every collective is the halo layer's wire
+        if v["opcode"].startswith("collective-permute"):
+            assert v["scope"] == scopes.HALO_WIRE, k
+    used = {v["scope"] for v in work.values()}
+    if case[0] == "exchange":
+        # an exchange loop is pack, wire, unpack and nothing else: every
+        # instruction that slices, stacks or updates carries a halo scope
+        for k, v in work.items():
+            if any(w in v["opcode"] for w in ("slice", "concatenate", "fusion")
+                   ) and "slice" in (v["op_name"] + v["opcode"]):
+                assert (v["scope"] or "").startswith("stencil.halo."), (k, v)
+        assert {scopes.HALO_PACK, scopes.HALO_UNPACK} <= used
+    if case == ("jacobi", 4):
+        assert {scopes.HALO_PACK, scopes.HALO_WIRE, scopes.HALO_UNPACK,
+                scopes.SWEEP_SHELL, scopes.MASK} <= used
+    if case[1] == 4:
+        assert scopes.HALO_WIRE in used
+
+
+def test_op_map_reads_tpu_style_text_copies_and_kernels():
+    text = '''HloModule jit_stencil_jacobi_loop, is_scheduled=true
+%body (p: (f32[8,128], f32[8,128])) -> (f32[8,128], f32[8,128]) {
+  %p = (f32[8,128]{1,0:T(8,128)}, f32[8,128]{1,0:T(8,128)}) parameter(0)
+  %get-tuple-element.1 = f32[8,128]{1,0:T(8,128)} get-tuple-element(%p), index=0
+  %copy.7 = f32[8,128]{1,0:T(8,128)} copy(%get-tuple-element.1)
+  %bitcast.3 = f32[1,8,128]{2,1,0:T(8,128)} bitcast(%copy.7)
+  %jacobi_multistep.2 = f32[1,8,128]{2,1,0:T(8,128)} custom-call(%bitcast.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(stencil_jacobi_loop)/while/body/stencil.kernel.jacobi_multistep/jacobi_multistep/pallas_call" source_file="/x/pallas_stencil.py" source_line=709}
+  %fusion.4 = f32[8,128]{1,0:T(8,128)} fusion(%jacobi_multistep.2), kind=kLoop, calls=%f, metadata={op_name="jit(stencil_jacobi_loop)/while/body/stencil.mask/eq"}
+  ROOT %tuple.9 = (f32[8,128]{1,0:T(8,128)}, f32[8,128]{1,0:T(8,128)}) tuple(%fusion.4, %copy.7)
+}
+'''
+    omap = scopes.parse_hlo_text(text)
+    k = omap["jacobi_multistep.2"]
+    assert k["scope"] == "stencil.kernel.jacobi_multistep"
+    assert k["layer"] == scopes.LAYER_KERNELS
+    assert k["source"] == "/x/pallas_stencil.py:709"
+    assert omap["fusion.4"]["layer"] == scopes.LAYER_GLUE
+    copy = omap["copy.7"]
+    assert copy["scope"] is None and copy["layer"] is None
+    assert copy["producer"] == {"instr": "p", "opcode": "parameter",
+                                "scope": None, "operand": None}
+    assert {"instr": "jacobi_multistep.2", "opcode": "custom-call",
+            "scope": "stencil.kernel.jacobi_multistep",
+            "operand": 0} in copy["consumers"]
+    assert any(c["opcode"] == "tuple" for c in copy["consumers"])
+
+
+# ------------------------------------------------------------ (d) bytes moved
+
+
+def _fill_bytes(n, axis, nq, radius=3, build=None):
+    """(spec, what ``build(make)`` returned, the one counter record) of a
+    self-fill built while a recorder of its own is installed."""
+    from stencil_tpu.domain.grid import GridSpec
+    from stencil_tpu.geometry import Dim3, Radius
+    from stencil_tpu.ops.halo_fill import make_self_fill
+
+    spec = GridSpec(Dim3(n, n, n), Dim3(1, 1, 1), Radius.constant(radius))
+    rec = telemetry.Recorder()
+    old, telemetry._recorder = telemetry._recorder, rec
+
+    def make():
+        return make_self_fill(spec, axis, nq=nq)
+
+    try:
+        built = make() if build is None else build(spec, make)
+    finally:
+        telemetry._recorder = old
+    (r,) = rec.records(kind="counter", name="halo.self_fill.bytes_dma")
+    assert r["axis"] == axis and r["quantities"] == nq
+    assert r["bytes"] == r["bytes_read"] + r["bytes_written"]
+    p = spec.padded()
+    assert r["shape"] == [p.z, p.y, p.x]
+    return spec, built, r
+
+
+def test_self_fill_byte_count_matches_the_hand_count_at_512_r3():
+    """ops/halo_fill.py's docstring, per quantity: z 6 plane copies, y two
+    8-row tiles rewritten from two more read, x both 128-lane edge tiles
+    of every row rewritten."""
+    total = 0
+    for axis in "xyz":
+        spec, _, r = _fill_bytes(512, axis, 4)
+        total += r["bytes"] / 4
+        p = spec.padded()
+        hand = {"z": 2 * 6 * p.y * p.x * 4,
+                "y": (4 + 2) * 8 * p.z * p.x * 4,
+                "x": 2 * 2 * 128 * p.z * p.y * 4}[axis]
+        assert abs(r["bytes"] / 4 - hand) <= 0.02 * hand, (axis, r, hand)
+    # 0.55 + 0.084 + 0.016 GB a quantity as the docstring had it
+    assert abs(total - 0.65e9) <= 0.02 * 0.65e9
+
+
+@pytest.mark.parametrize("axis", ["x", "y", "z"])
+def test_self_fill_byte_count_equals_the_lowered_mosaic_modules(axis):
+    """The cross-check: the DMAs of the lowered Mosaic module
+    (``utils/mosaic_traffic``), counted per grid step as they execute."""
+    from stencil_tpu.utils.mosaic_traffic import capture_traffic
+
+    nq = 2
+
+    def lowered(spec, make):
+        # the kernel is built under the capture's patch, which turns on the
+        # Mosaic dump
+        p = spec.padded()
+        arg = jax.ShapeDtypeStruct((p.z, p.y, p.x), jnp.float32)
+        return capture_traffic(lambda: (make(), (arg,) * nq))
+
+    x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)
+    try:
+        spec, (kt,), r = _fill_bytes(128, axis, nq, build=lowered)
+    finally:
+        jax.config.update("jax_enable_x64", x64)
+    p = spec.padded()
+    written = kt.steps * kt.output_bytes()
+    if axis == "x":
+        # the x kernel reads each batch once: at step 0, or prefetched a
+        # step ahead; its body spells both, and a third for a clamped tail
+        spelled = 3 if p.z % 16 else 2
+        read = kt.steps * kt.input_bytes() // spelled
+    else:
+        read = kt.steps * kt.input_bytes()
+    assert (r["bytes_read"], r["bytes_written"]) == (read, written)
+
+
+# ------------------------------------------------------------ (e) recorder
+
+
+def test_recorder_keeps_records_in_memory_without_a_sink():
+    rec = telemetry.Recorder()
+    assert not rec.enabled
+    with rec.span("jacobi.warmup", phase="compile"):
+        with rec.span("jacobi.init"):
+            rec.counter("halo.self_fill.bytes_dma", bytes=7, axis="x")
+    close = rec.open_span("jacobi.steps")
+    close()
+    inner, outer, steps = rec.records(kind="span")
+    assert (inner["name"], outer["name"]) == ("jacobi.init", "jacobi.warmup")
+    assert inner["parent"] == "jacobi.warmup" and "parent" not in outer
+    assert steps["name"] == "jacobi.steps" and "parent" not in steps
+    for r in (inner, outer, steps):
+        assert r["t0_ns"] <= r["t1_ns"]
+        assert r["t1_ns"] - r["t0_ns"] == int(r["seconds"] * 1e9)
+        assert telemetry.validate_record(r) == []
+    assert outer["t0_ns"] <= inner["t0_ns"] and inner["t1_ns"] <= outer["t1_ns"]
+    assert abs(outer["t0_ns"] / 1e9 - outer["t"]) < 60      # the unix clock
+    assert [r["bytes"] for r in rec.records(name="halo.self_fill.bytes_dma")] == [7]
+    assert rec.records(kind="gauge") == []
+
+
+def test_recorder_memory_is_bounded():
+    rec = telemetry.Recorder()
+    for i in range(telemetry.KEEP_RECORDS + 50):
+        rec.gauge("exchange.gb_per_s", float(i))
+    kept = rec.records()
+    assert len(kept) == telemetry.KEEP_RECORDS
+    assert kept[0]["value"] == 50.0 and kept[-1]["value"] == telemetry.KEEP_RECORDS + 49.0
+
+
+def test_new_names_are_in_the_telemetry_vocabulary():
+    for app in ("jacobi", "astaroth", "exchange"):
+        for part in ("realize", "warmup", "steps"):
+            assert f"{app}.{part}" in telemetry.KNOWN_NAMES
+    assert "halo.self_fill.bytes_dma" in telemetry.KNOWN_NAMES
