@@ -38,6 +38,7 @@ from jax import lax
 
 from ..geometry import Dim3, Rect3, exterior_regions, interior_region
 from ..obs import scopes
+from ..ops import double_buffer
 from ..parallel.exchange import BLOCK_PSPEC, HaloExchange
 from .config import AcMeshInfo
 from .equations import Constants, continuity, entropy, induction, momentum
@@ -236,7 +237,14 @@ def make_astaroth_step(
     """Build the jitted iteration: ``fn(curr, nxt) -> (curr, nxt)`` where
     curr/nxt are dicts of stacked sharded field arrays. Runs ``iters``
     iterations of 3 substeps in one compiled program; the dt=1e-8 default
-    matches the reference driver (astaroth.cu:578).
+    matches the reference driver (astaroth.cu:578). Both dicts are donated.
+    An iteration ends with the pair exchanged (the reference's pointer swap,
+    astaroth.cu:642-648: once an iteration, or after every substep under
+    ``swap_per_substep``: three, so odd either way). Compiled code never
+    exchanges the buffers (ops/double_buffer.py): a ``while`` trip runs two
+    iterations, the jitted program returns all sixteen fields in the slots
+    they came in, and the returned callable swaps the two dicts on the host
+    when ``iters`` is odd.
 
     ``use_pallas`` (None = auto, see :func:`uses_pallas`; ``dtype`` is the
     field dtype the step will be driven with) selects the fused VMEM
@@ -495,9 +503,10 @@ def make_astaroth_step(
             return curr, out
 
     def entry_fn(curr, out):
-        if iters == 1:
-            return iteration(curr, out)
-        return lax.fori_loop(0, iters, lambda _, co: iteration(co[0], co[1]), (curr, out))
+        # an iteration ends with the pair exchanged in either swap mode (one
+        # swap, or three under swap_per_substep): the step of the ping-pong
+        return double_buffer.repeat(lambda co: iteration(*co), iters,
+                                    (curr, out))
 
     fn = jax.shard_map(
         entry_fn,
@@ -511,8 +520,8 @@ def make_astaroth_step(
     field = jax.ShapeDtypeStruct(spec.stacked_shape_zyx(), jnp.dtype(dtype),
                                  sharding=ex.sharding())
     like = {k: field for k in FIELDS}
-    return scopes.jit_loop(scopes.ASTAROTH_ITER, fn, (like, like),
-                           donate_argnums=(0, 1))
+    return double_buffer.jit_in_place(scopes.ASTAROTH_ITER, fn, (like, like),
+                                      (iters,))
 
 
 def make_fused_astaroth_loop(

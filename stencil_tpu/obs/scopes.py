@@ -140,7 +140,9 @@ def jit_loop(module: str, fn, args=None, **jit_kwargs):
     """``jax.jit(fn, **jit_kwargs)`` under the stable module name
     ``module``, registered with the abstract ``args`` it was built for
     (``None``: named, not registered). Returns the jitted object itself:
-    the call path gains no wrapper."""
+    this function puts no wrapper on the call path (a ping-pong builder
+    hands the jitted object to ``ops/double_buffer.InPlaceLoop``, which
+    swaps two handles after the call)."""
     if module not in MODULES:
         raise KeyError(f"{module!r} is not a module name of the vocabulary")
     import jax

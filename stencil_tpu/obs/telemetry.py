@@ -173,6 +173,13 @@ NAME_FIELDS = {
     # prediction fell outside the measured phase's trimean±MAD band
     "calibration.drift": (("phase", str), ("predicted_s", float),
                           ("measured_s", float)),
+    # what ops/double_buffer.jit_in_place built, once per build: the
+    # exchanging steps of a ping-pong program, how many two-step trips
+    # hold them, how many run outside a trip, and whether the loop swaps
+    # the two handles on the host (an odd count)
+    "loop.pingpong": (("module", str), ("steps", int),
+                      ("steps_per_trip", int), ("trips", int),
+                      ("tail_steps", int), ("host_swap", bool)),
 }
 
 # The sanctioned metric-name vocabulary: every LITERAL name the library

@@ -33,6 +33,7 @@ from ..geometry import Dim3, Radius, Rect3, exterior_regions, interior_region
 from ..obs import scopes
 from ..parallel.exchange import BLOCK_PSPEC, HaloExchange, Method
 from ..utils import timer
+from . import double_buffer
 
 HOT_TEMP = 1.0
 COLD_TEMP = 0.0
@@ -59,11 +60,15 @@ def _loop_args(ex: HaloExchange):
     return f32, f32, jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
 
 
+def _module(iters) -> str:
+    """The stable module name of the chunk loop (or the single step)."""
+    return scopes.JACOBI_STEP if iters is None else scopes.JACOBI_LOOP
+
+
 def _jit_jacobi(ex: HaloExchange, iters, fn, **jit_kwargs):
     """The chunk loop (or the single step) under its stable module name,
     registered with its abstract arguments."""
-    module = scopes.JACOBI_STEP if iters is None else scopes.JACOBI_LOOP
-    return scopes.jit_loop(module, fn, _loop_args(ex), **jit_kwargs)
+    return scopes.jit_loop(_module(iters), fn, _loop_args(ex), **jit_kwargs)
 
 
 def _rect_slices(rect: Rect3, dz=0, dy=0, dx=0):
@@ -205,9 +210,13 @@ def make_jacobi_step(ex: HaloExchange, overlap: bool = True, use_pallas=None,
                      standard_spheres: bool = True, interpret: bool = False):
     """Build the jitted distributed iteration: exchange + stencil + swap.
 
-    Returns ``step(curr, nxt, hot, cold) -> (new_curr, new_next)`` over
-    stacked sharded arrays; buffers are donated (the double-buffer swap of
-    the reference, src/local_domain.cu:67-84, as input/output aliasing).
+    Returns ``step(curr, nxt, sel) -> (new_curr, new_next)`` over stacked
+    sharded arrays; both buffers are donated. The double-buffer swap of the
+    reference (a pointer swap, src/local_domain.cu:67-84) is realized in two
+    halves (ops/double_buffer.py): on the device each buffer keeps its slot
+    (the new state is written over ``nxt``, the jitted program returns the
+    pair in input order, so donation aliases both and no copy is made), and
+    the returned callable swaps the two handles on the host.
 
     ``overlap=True`` replicates the reference's interior/exterior split
     (bin/jacobi3d.cu:296-368): the interior sweep reads pre-exchange data
@@ -230,7 +239,14 @@ def make_jacobi_loop(ex: HaloExchange, iters: int, overlap: bool = True, use_pal
                      temporal_k: Optional[int] = None,
                      multistep_rows: Optional[int] = None):
     """Like :func:`make_jacobi_step` but runs ``iters`` iterations inside one
-    compiled program (``lax.fori_loop``) — one host dispatch per chunk.
+    compiled program — one host dispatch per chunk. Same contract:
+    ``loop(curr, nxt, sel) -> (new_curr, new_next)``, inputs donated. A
+    ``while`` trip runs TWO steps (or two multistep passes) so that it ends
+    with both buffers where it began, the odd step runs after the loop, and
+    the handles are swapped on the host when the number of steps (multistep
+    passes plus single steps) is odd: no compiled code ever exchanges the
+    pair (ops/double_buffer.py; the counter ``loop.pingpong`` says what was
+    built).
 
     This is the ``USE_CUDA_GRAPH`` analogue taken further: where the
     reference graph-captures one exchange (packer.cu:96-103), XLA compiles
@@ -966,7 +982,19 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
             rows=STRIP_ROWS,
         )
 
+    # the static runs of exchanging steps this program makes, in order:
+    # entry_fn makes them, double_buffer.jit_in_place derives trips and the
+    # host swap from them
+    if multistep is not None:
+        n_multi, n_single = divmod(iters, TEMPORAL_K)
+        counts = (n_multi,) + (1,) * n_single  # passes, then single steps
+    else:
+        counts = (1 if iters is None else iters,)
+
     def entry_fn(curr, nxt, sel):
+        def step(cn):
+            return body(*cn, sel)
+
         if multistep is not None:
             p = spec.padded()
             res = (ex.resident.z, ex.resident.y, ex.resident.x)
@@ -1017,18 +1045,11 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
                     c = ex.exchange_block(c, axes=pallas_axes)
                 return (run_multi(c, x), c)
 
-            n_multi, n_single = divmod(iters, TEMPORAL_K)
-            cn = (curr, nxt)
-            if n_multi:
-                cn = jax.lax.fori_loop(0, n_multi, lambda _, c: mbody(c), cn)
-            for _ in range(n_single):
-                cn = body(cn[0], cn[1], sel)
+            cn = double_buffer.repeat(mbody, counts[0], (curr, nxt))
+            for n in counts[1:]:
+                cn = double_buffer.repeat(step, n, cn)
             return cn
-        if iters is None:
-            return body(curr, nxt, sel)
-        return jax.lax.fori_loop(
-            0, iters, lambda _, cn: body(cn[0], cn[1], sel), (curr, nxt)
-        )
+        return double_buffer.repeat(step, counts[0], (curr, nxt))
 
     fn = jax.shard_map(
         entry_fn,
@@ -1037,7 +1058,8 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
         out_specs=(BLOCK_PSPEC, BLOCK_PSPEC),
         check_vma=not interpret,
     )
-    return _jit_jacobi(ex, iters, fn, donate_argnums=(0, 1))
+    return double_buffer.jit_in_place(_module(iters), fn, _loop_args(ex),
+                                      counts)
 
 
 def make_batched_jacobi_loop(spec, iters: int, *, sharding=None,
