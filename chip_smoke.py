@@ -179,13 +179,13 @@ def _jacobi_exchange(size, dim, devs, tight_x: bool):
 
 
 def _multistep_depth(rec: PallasRecorder, nz: int):
-    """(k, row_tiled) of the temporal multistep that was built, read off
-    its grid: the wavefront runs J = nz + 2k steps."""
+    """(k, row_tiled, grid) of the temporal multistep that was built, k
+    read off its grid: the wavefront runs J = nz + 2k steps, once a strip."""
     rows = rec.of("_make_multistep_row_tiled")
     full = rec.of("make_pallas_jacobi_multistep")
     assert rows or full, f"no temporal multistep built: {rec.calls}"
     grid = (rows or full)[-1]["grid"]
-    return (grid[-1] - nz) // 2, bool(rows)
+    return (grid[-1] - nz) // 2, bool(rows), grid
 
 
 def _check_spheres(out, size, what: str) -> None:
@@ -200,10 +200,13 @@ def _check_spheres(out, size, what: str) -> None:
 
 
 def phase_jacobi(devs, n: int, rehearsal: bool, *, ref_n=None,
-                 want_rows: bool = False, time_sync: bool = False) -> dict:
+                 want_rows: bool = False, time_sync: bool = False,
+                 chunk=12) -> dict:
     """jacobi3d at ``n``^3 on one chip through ``apps.jacobi3d.run``, then
     the same kernels against numpy (at ``ref_n``^3) and against the XLA
-    path (at ``n``^3)."""
+    path (at ``n``^3), at the temporal depth (and rows) that ``chunk``
+    iterations a dispatch give; ``chunk=None`` is the application's own
+    default, what the benchmark's cells run."""
     import numpy as np
 
     from stencil_tpu.apps import jacobi3d
@@ -217,10 +220,11 @@ def phase_jacobi(devs, n: int, rehearsal: bool, *, ref_n=None,
     size = Dim3(128, n, n) if rehearsal else Dim3(n, n, n)
     facts = {}
 
-    # 1. the function main() calls
+    # 1. the function main() calls; 60 iterations are whole chunks of 12 and
+    # of the default 10, so no tail chunk compiles inside run()'s timed loop
     with PallasRecorder() as rec:
         r = jacobi3d.run(size.x, size.y, size.z, weak=False, devices=dev,
-                         iters=24, chunk=12)
+                         iters=60, chunk=chunk)
     ex = r["domain"].halo_exchange
     k = min(12, (size.z - 1) // 2)
     if not rehearsal:
@@ -229,11 +233,11 @@ def phase_jacobi(devs, n: int, rehearsal: bool, *, ref_n=None,
         assert ex.spec == _jacobi_exchange(size, one, dev, True).spec
         assert _want_pallas(ex, None)
         require_compiled_kernels(rec, [], rehearsal)
-        k, row_tiled = _multistep_depth(rec, size.z)
+        k, row_tiled, grid = _multistep_depth(rec, size.z)
         assert k >= 2, f"temporal multistep did not engage (k={k})"
         assert row_tiled == want_rows, (
             f"row-tiled staging {row_tiled}, expected {want_rows}")
-        facts.update(temporal_k=k, row_tiled=row_tiled,
+        facts.update(temporal_k=k, row_tiled=row_tiled, grid=list(grid),
                      mcells_per_s=round(r["mcells_per_s"], 1))
     _check_spheres(r["domain"].get_curr_global(r["handle"]), size,
                    f"jacobi3d.run {size}")
@@ -620,6 +624,8 @@ def build_phases(devs, rehearsal: bool) -> list:
         ("jacobi_512", 1, lambda: phase_jacobi(
             devs, 512, False, ref_n=128, time_sync=True)),
         ("jacobi_768", 1, lambda: phase_jacobi(
+            devs, 768, False, want_rows=True, chunk=None)),
+        ("jacobi_768_k12", 1, lambda: phase_jacobi(
             devs, 768, False, want_rows=True)),
         ("exchange_512", 1, lambda: phase_exchange(
             devs[:1], Dim3(512, 512, 512), Dim3(1, 1, 1), False)),

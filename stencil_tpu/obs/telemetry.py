@@ -180,6 +180,16 @@ NAME_FIELDS = {
     "loop.pingpong": (("module", str), ("steps", int),
                       ("steps_per_trip", int), ("trips", int),
                       ("tail_steps", int), ("host_swap", bool)),
+    # what ops/jacobi.py staged for the temporal multistep it built, once
+    # per build (ops/pallas_stencil.multistep_staging): depth, strip height
+    # (0 = full planes), strips, the rows a staged strip holds beyond its
+    # own, the rows one pass computes over all stages and strips against
+    # the k * ny it keeps, and the VMEM scratch (benchmark reader
+    # multistep_recompute_share)
+    "kernel.multistep.staging": (("module", str), ("k", int), ("rows", int),
+                                 ("strips", int), ("halo_rows", int),
+                                 ("rows_computed", int), ("rows_kept", int),
+                                 ("vmem_bytes", int)),
 }
 
 # The sanctioned metric-name vocabulary: every LITERAL name the library

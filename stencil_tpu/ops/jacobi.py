@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..geometry import Dim3, Radius, Rect3, exterior_regions, interior_region
-from ..obs import scopes
+from ..obs import scopes, telemetry
 from ..parallel.exchange import BLOCK_PSPEC, HaloExchange, Method
 from ..utils import timer
 from . import double_buffer
@@ -973,7 +973,8 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
             "path) — timings reflect the per-step kernels"
         )
     if TEMPORAL_K >= 2:
-        from .pallas_stencil import make_pallas_jacobi_multistep
+        from .pallas_stencil import (make_pallas_jacobi_multistep,
+                                     multistep_staging)
         from ..parallel.mesh import MESH_AXES
 
         multistep = make_pallas_jacobi_multistep(
@@ -981,6 +982,11 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
             vma=None if interpret else MESH_AXES, interpret=interpret,
             rows=STRIP_ROWS,
         )
+        # what was staged, once per build (full planes: rows 0)
+        telemetry.get().counter(
+            "kernel.multistep.staging", value=TEMPORAL_K, phase="compute",
+            module=_module(iters),
+            **multistep_staging(spec, TEMPORAL_K, STRIP_ROWS))
 
     # the static runs of exchanging steps this program makes, in order:
     # entry_fn makes them, double_buffer.jit_in_place derives trips and the

@@ -415,24 +415,55 @@ def plan_multistep_staging(spec: GridSpec, k_want: int, budget: int):
     if k_want < 2:
         return k_want, None
     p = spec.padded()
-    off = spec.compute_offset()
-    nx, ny = spec.base.x, spec.base.y
-    mx = spec.dim.x > 1
-    _, kx, _ = _tight_x_layout(not mx, nx, off.x, p.x)
+    kx = _staged_columns(spec)
     k_full = (budget // (p.y * kx * 4) - (_N_IN + 2)) // 3 + 1
     if k_full >= k_want or spec.dim.y > 1:
         return max(0, min(k_want, k_full)), None
     for k in range(k_want, max(k_full, 1), -1):
-        hp = _round8(k)
         for ty in _ROW_CANDS:
-            if not valid_strip_rows(spec, k, ty):
-                continue
-            need = 4 * kx * (
-                (_N_IN + 3 * (k - 1)) * (ty + 2 * hp) + 2 * ty
-            )
-            if need <= budget:
+            if (valid_strip_rows(spec, k, ty)
+                    and _staging_bytes(kx, k, ty + 2 * _round8(k), ty)
+                    <= budget):
                 return k, ty
     return max(0, k_full), None
+
+
+def _staged_columns(spec: GridSpec) -> int:
+    """Columns of a staged multistep row: nx under the tight-x layout."""
+    _, kx, _ = _tight_x_layout(spec.dim.x == 1, spec.base.x,
+                               spec.compute_offset().x, spec.padded().x)
+    return kx
+
+
+def _staging_bytes(kx: int, k: int, rows_staged: int, rows_out: int) -> int:
+    """VMEM scratch of the depth-``k`` multistep: the input ring and three
+    planes a stage below the last at ``rows_staged`` rows, two output
+    planes at ``rows_out``."""
+    return 4 * kx * ((_N_IN + 3 * (k - 1)) * rows_staged + 2 * rows_out)
+
+
+def multistep_staging(spec: GridSpec, k: int, rows: Optional[int]) -> dict:
+    """What one pass of the depth-``k`` multistep stages and computes along
+    y, as the builders above lay it out (``rows``: the strip height, ``None``
+    = full planes): ``strips``; ``halo_rows``, the rows a staged strip holds
+    beyond the ``rows`` it writes; ``rows_computed``, summed over all stages
+    and strips (a strip's stage s computes ``k - s`` rows beyond each side of
+    its own, a multi-block y axis in full planes likewise; a re-anchored
+    last strip computes its overlap again); ``rows_kept`` = ``k * ny``, what
+    a pass with no recompute would compute; ``vmem_bytes`` of scratch."""
+    ny = spec.base.y
+    beyond = k * (k - 1)        # 2 (k - s) rows over the stages s = 1..k
+    if rows is None:
+        strips, staged, out = 1, spec.padded().y, spec.padded().y
+        computed = k * ny + (beyond if spec.dim.y > 1 else 0)
+    else:
+        strips, staged, out = -(-ny // rows), rows + 2 * _round8(k), rows
+        computed = strips * (k * rows + beyond)
+    return {"k": k, "rows": rows or 0, "strips": strips,
+            "halo_rows": staged - (rows or ny),
+            "rows_computed": computed, "rows_kept": k * ny,
+            "vmem_bytes": _staging_bytes(_staged_columns(spec), k, staged,
+                                         out)}
 
 
 def make_pallas_jacobi_multistep(
