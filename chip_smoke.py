@@ -402,7 +402,11 @@ def phase_exchange(devs, size, partition, rehearsal: bool) -> dict:
         assert sorted(ex._self_fills) == sorted(single), (
             f"self-fills {sorted(ex._self_fills)} != single-block axes "
             f"{single}")
-        require_compiled_kernels(rec, ["make_self_fill"], rehearsal)
+        want = ["make_self_fill"]
+        if partition.x > 1:
+            # a split lane axis packs and unpacks on the edge lane-tiles
+            want += ["make_split_x_pack", "make_split_x_unpack"]
+        require_compiled_kernels(rec, want, rehearsal)
         assert len(rec.of("make_self_fill")) >= len(single)
     halo_cells = 0
     for q, h in enumerate(handles):
@@ -603,13 +607,15 @@ def build_phases(devs, rehearsal: bool) -> list:
     from stencil_tpu.geometry import Dim3
 
     four = devs[:4]
-    p122 = Dim3(1, 2, 2)
+    p122, p221 = Dim3(1, 2, 2), Dim3(2, 2, 1)
     if rehearsal:
         return [
             ("four_chip_jacobi", 4, lambda: phase_four_jacobi(
                 four, Dim3(16, 16, 16), Dim3(128, 8, 8), True)),
             ("four_chip_exchange", 4, lambda: phase_exchange(
                 four, Dim3(16, 32, 32), p122, True)),
+            ("four_chip_exchange_x", 4, lambda: phase_exchange(
+                four, Dim3(32, 32, 16), p221, True)),
             ("jacobi", 1, lambda: phase_jacobi(devs, 16, True, ref_n=16)),
             ("exchange", 1, lambda: phase_exchange(
                 devs[:1], Dim3(16, 16, 16), Dim3(1, 1, 1), True)),
@@ -621,6 +627,9 @@ def build_phases(devs, rehearsal: bool) -> list:
             four, Dim3(512, 512, 512), Dim3(128, 32, 32), False)),
         ("four_chip_exchange", 4, lambda: phase_exchange(
             four, Dim3(512, 1024, 1024), p122, False)),
+        # exchange_weak's own pick on four chips: x is split
+        ("four_chip_exchange_x", 4, lambda: phase_exchange(
+            four, Dim3(1024, 1024, 512), p221, False)),
         ("jacobi_512", 1, lambda: phase_jacobi(
             devs, 512, False, ref_n=128, time_sync=True)),
         ("jacobi_768", 1, lambda: phase_jacobi(

@@ -54,9 +54,10 @@ SCOPES: Dict[str, str] = {
     CARRY: LAYER_GLUE,
 }
 
-# pallas_call name -> layer. The self-fills and the remote-DMA carriers are
-# the halo layer's kernels; a fused exchange-and-sweep kernel is named as
-# such and counted with the stencil kernels.
+# pallas_call name -> layer. The self-fills, the split-x pack and unpack and
+# the remote-DMA carriers are the halo layer's kernels; a fused
+# exchange-and-sweep kernel is named as such and counted with the stencil
+# kernels.
 KERNELS: Dict[str, str] = {
     "jacobi_sweep": LAYER_KERNELS,
     "jacobi_multistep": LAYER_KERNELS,
@@ -67,6 +68,8 @@ KERNELS: Dict[str, str] = {
     "self_fill_x": LAYER_HALO,
     "self_fill_y": LAYER_HALO,
     "self_fill_z": LAYER_HALO,
+    "split_x_pack": LAYER_HALO,
+    "split_x_unpack": LAYER_HALO,
     "remote_dma": LAYER_HALO,
     "fused_exchange": LAYER_HALO,
 }

@@ -253,7 +253,7 @@ KNOWN_NAMES = frozenset(NAME_FIELDS) | frozenset({
     "astaroth.realize", "astaroth.steps",
     "exchange.realize", "exchange.steps",
     "jacobi.realize", "jacobi.steps",
-    "halo.self_fill.bytes_dma",
+    "halo.self_fill.bytes_dma", "halo.split_x.bytes_dma",
 })
 
 # how many records a recorder keeps in memory (oldest dropped first)

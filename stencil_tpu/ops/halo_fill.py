@@ -20,18 +20,23 @@ Axis economics per quantity (512^3, r=3, fp32):
   inline must pay).
 
 Used by ``HaloExchange`` for AXIS_COMPOSED phases with a single block on
-the axis; multi-block phases keep the ppermute + update path. Phase
-ordering (x, then y, then z) is preserved because each axis is a separate
-kernel call — later phases read the earlier phases' filled halos.
+the axis. A multi-block phase keeps the ppermute + update path on y and z;
+on a split x (lane) axis it packs and unpacks with the two edge-tile
+kernels at the end of this module (``make_split_x_pack`` /
+``make_split_x_unpack``), which share the x fill's geometry, and XLA keeps
+only the lane-dense carrier and the ``ppermute``. Phase ordering (x, then
+y, then z) is preserved because each axis is a separate kernel call — later
+phases read the earlier phases' filled halos.
 
 Each build records the HBM bytes one call reads and writes
 (``halo.self_fill.bytes_dma``, see ``_record_dma_bytes``): 0.560 + 0.064 +
-0.016 GB a quantity at the size above.
+0.016 GB a quantity at the size above; the split-x pair records
+``halo.split_x.bytes_dma`` (0.287 + 0.567 GB a quantity there).
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -239,10 +244,13 @@ def self_fill_supported(spec: GridSpec, axis: str, dtype, z_stack: int = 1) -> b
     return True  # z: untiled dim, plane copies always work
 
 
-def _record_dma_bytes(axis: str, nq: int, shape, read: int, written: int):
-    """The HBM bytes ONE CALL of a self-fill kernel moves, counted where its
+def _record_dma_bytes(nq: int, shape, read: int, written: int,
+                      name: str = "halo.self_fill.bytes_dma", **tag):
+    """The HBM bytes ONE CALL of a halo kernel moves, counted where its
     DMAs are built (HBM -> VMEM: bytes read; VMEM -> HBM: bytes written),
-    recorded once per build as ``halo.self_fill.bytes_dma``. The lane and
+    recorded once per build: ``halo.self_fill.bytes_dma`` tagged ``axis``
+    for a self-fill, ``halo.split_x.bytes_dma`` tagged ``part`` (pack or
+    unpack; a record's ``kind`` is taken) for the split-x pair. The lane and
     row tiles the x and y kernels rewrite whole are in it, which
     ``HaloExchange.bytes_moved`` leaves out. ``utils/mosaic_traffic``
     derives the same count from the lowered Mosaic module (a test's
@@ -250,9 +258,8 @@ def _record_dma_bytes(axis: str, nq: int, shape, read: int, written: int):
     from ..obs import telemetry
 
     telemetry.get().counter(
-        "halo.self_fill.bytes_dma", bytes=read + written, phase="exchange",
-        axis=axis, quantities=nq, shape=list(shape), bytes_read=read,
-        bytes_written=written)
+        name, bytes=read + written, phase="exchange", quantities=nq,
+        shape=list(shape), bytes_read=read, bytes_written=written, **tag)
 
 
 def make_self_fill(spec: GridSpec, axis: str, vma=None, interpret: bool = False,
@@ -314,8 +321,8 @@ def make_self_fill(spec: GridSpec, axis: str, vma=None, interpret: bool = False,
 
         nstage = max(rm, rp, 1)
         plane = py * px * 4
-        _record_dma_bytes("z", nq, (pz, py, px), nq * (rm + rp) * plane,
-                          nq * (rm + rp) * plane)
+        _record_dma_bytes(nq, (pz, py, px), nq * (rm + rp) * plane,
+                          nq * (rm + rp) * plane, axis="z")
         return _wrap(scopes.kernel_call(
             "self_fill_z", kernel,
             grid=(1,),
@@ -394,8 +401,8 @@ def make_self_fill(spec: GridSpec, axis: str, vma=None, interpret: bool = False,
         rows = TZB * px * 4     # one row of a z batch
         written = (lo_span if rm else 0) + (hi_span if rp else 0)
         read = written + (src_hi_span if rm else 0) + (src_lo_span if rp else 0)
-        _record_dma_bytes("y", nq, (pz, py, px), n_b * nq * read * rows,
-                          n_b * nq * written * rows)
+        _record_dma_bytes(nq, (pz, py, px), n_b * nq * read * rows,
+                          n_b * nq * written * rows, axis="y")
         return _wrap(scopes.kernel_call(
             "self_fill_y", kernel,
             grid=(n_b,),
@@ -515,7 +522,7 @@ def make_self_fill(spec: GridSpec, axis: str, vma=None, interpret: bool = False,
 
     # every batch reads and rewrites both edge lane-tiles of every row
     tiles = n_b * nq * 2 * TZB * py * _LANE * 4
-    _record_dma_bytes("x", nq, (pz, py, px), tiles, tiles)
+    _record_dma_bytes(nq, (pz, py, px), tiles, tiles, axis="x")
     return _wrap(scopes.kernel_call(
         "self_fill_x", kernel,
         grid=(n_b,),
@@ -540,3 +547,331 @@ def make_self_fill(spec: GridSpec, axis: str, vma=None, interpret: bool = False,
         ),
         interpret=interpret,
     ))
+
+
+# -- split x axis: pack / unpack on the two edge lane-tiles ------------------
+# The x phase of a partition that SPLITS x. The halo source is the
+# neighbour's block, so a carrier crosses the wire between a pack and an
+# unpack, but on the field both are the x self-fill's job: 3 columns of
+# every row live in one 128-lane tile a side, and XLA's slice and
+# dynamic_update_slice each cost a pass over the whole field to reach them
+# (measured 28 of 34 ms an exchange at 4 x 512^3 on (2,2,1)). The two
+# kernels below touch the edge lane-tiles only, streamed in the x fill's own
+# z batches, and relayout in VMEM between "r lanes of every row" and a
+# lane-dense carrier.
+#
+# The carrier of one side is ``(groups, nq, py, 128)``: batch ``i``, plane
+# ``j`` of the batch, column ``c`` sits in group ``i // k`` at lane
+# ``((i % k) * TZB + j) * r + c``, with ``k = (128 // r) // TZB`` batches a
+# group (126 of 128 lanes in use at r = 3). Pack and unpack walk the same
+# batches (``z = min(i * TZB, pz - TZB)``), so a clamped last batch sends
+# its overlapping planes twice, to the same halo cells, and the unpack needs
+# no tail rule: what two of its batches write to one plane is equal.
+
+
+class _SplitSide(NamedTuple):
+    """One direction of a split-x phase: ``r`` columns leave lanes
+    ``[src, src + r)`` of the lane-tile at ``src_tile`` and land in lanes
+    ``[dst, dst + r)`` of the tile at ``dst_tile`` on the neighbour."""
+
+    r: int
+    src_tile: int
+    src: int
+    dst_tile: int
+    dst: int
+    k: int        # z batches that share one 128-lane carrier group
+    groups: int
+
+
+class _SplitX(NamedTuple):
+    shape: Tuple[int, int, int]
+    tzb: int
+    n_b: int
+    sides: Tuple[_SplitSide, ...]   # forward (toward +x) first; r > 0 only
+
+
+def _split_x_geom(spec: GridSpec, nq: int) -> _SplitX:
+    p = spec.padded()
+    o, sz, (rm, rp) = _axis_geom(spec, "x")
+    lo_t = 0
+    hi_t = ((o + sz) // _LANE) * _LANE
+    tzb = max(1, min(_x_tzb(spec, nq), _LANE // max(rm, rp)))
+    n_b = -(-p.z // tzb)
+    sides = []
+    # forward: my top rm columns fill the +x neighbour's low halo;
+    # backward: my first rp columns fill the -x neighbour's high halo
+    for r, src, dst in ((rm, o + sz - rm, o - rm), (rp, o, o + sz)):
+        if r:
+            s_t, d_t = (hi_t, lo_t) if dst < o else (lo_t, hi_t)
+            k = (_LANE // r) // tzb
+            sides.append(_SplitSide(r, s_t, src - s_t, d_t, dst - d_t, k,
+                                    -(-n_b // k)))
+    return _SplitX((p.z, p.y, p.x), tzb, n_b, tuple(sides))
+
+
+def split_x_supported(spec: GridSpec, dtype) -> bool:
+    """Whether :func:`make_split_x_pack` / :func:`make_split_x_unpack`
+    handle this block: the x self-fill's own gates (fp32, aligned, halo and
+    source columns inside the two edge lane-tiles, ``p.z >= 4``, the VMEM
+    budget). The carrier staging adds 4 tiles a quantity to the x fill's
+    ``8 * TZB``; the kernels' ``vmem_limit_bytes`` has the room."""
+    return self_fill_supported(spec, "x", dtype)
+
+
+def split_x_carrier_shapes(spec: GridSpec, nq: int):
+    """The carriers one pack returns and one unpack takes, forward first
+    (an inactive direction has none)."""
+    g = _split_x_geom(spec, nq)
+    return [(s.groups, nq, g.shape[1], _LANE) for s in g.sides]
+
+
+def _split_x_check(spec: GridSpec, nq: int):
+    if not split_x_supported(spec, jnp.float32):
+        raise ValueError("split-x pack/unpack unsupported on this spec")
+    if not 1 <= nq <= max_fill_group(spec):
+        raise ValueError(
+            f"split-x group size {nq} outside [1, {max_fill_group(spec)}]")
+
+
+def _lane_iota(py: int):
+    return jax.lax.broadcasted_iota(jnp.int32, (py, _LANE), 1)
+
+
+def make_split_x_pack(spec: GridSpec, nq: int = 1, vma=None,
+                      interpret: bool = False):
+    """``pack(b0, .., b{nq-1}) -> [carrier, ..]``: read both edge lane-tiles
+    of every fp32 ``(pz, py, px)`` field once and write the lane-dense
+    carriers of the active directions (forward first). The fields are plain
+    inputs: nothing is written to them. Both sources are interior columns
+    the x phase never writes, so both packs may precede both unpacks."""
+    _split_x_check(spec, nq)
+    g = _split_x_geom(spec, nq)
+    (pz, py, px), TZB, n_b = g.shape, g.tzb, g.n_b
+    ns = len(g.sides)
+    tiles = [(n, q) for n in range(ns) for q in range(nq)]  # one DMA each
+
+    def kernel(*refs):
+        fields = refs[:nq]
+        carriers = refs[nq : nq + ns]
+        scratch = refs[nq + ns :]
+        i = pl.program_id(0)
+        slot = jnp.mod(i, 2)
+        z_of = lambda step: jnp.minimum(step * TZB, pz - TZB)
+        lane = _lane_iota(py)
+
+        def rd(n, q, s, step):
+            buf, sem = scratch[4 * n], scratch[4 * n + 2]
+            return pltpu.make_async_copy(
+                fields[q].at[pl.ds(z_of(step), TZB), :,
+                             pl.ds(g.sides[n].src_tile, _LANE)],
+                buf.at[s, q], sem.at[s])
+
+        @pl.when(i == 0)
+        def _():
+            for n, q in tiles:
+                rd(n, q, slot, i).start()
+
+        @pl.when(i + 1 < n_b)
+        def _():
+            for n, q in tiles:
+                rd(n, q, 1 - slot, i + 1).start()
+
+        for n, q in tiles:
+            rd(n, q, slot, i).wait()
+
+        for n, side in enumerate(g.sides):
+            buf, cb, s_out = scratch[4 * n], scratch[4 * n + 1], scratch[4 * n + 3]
+            grp = i // side.k
+            sub = i - grp * side.k
+            gs = jnp.mod(grp, 2)
+
+            def out(group, cb=cb, s_out=s_out, n=n):
+                s = jnp.mod(group, 2)
+                return pltpu.make_async_copy(
+                    cb.at[s], carriers[n].at[group], s_out.at[s])
+
+            @pl.when(sub == 0)
+            def _():
+                # this staging slot last held group grp - 2
+                @pl.when(grp >= 2)
+                def _():
+                    out(grp - 2).wait()
+
+                cb[gs] = jnp.zeros((nq, py, _LANE), jnp.float32)
+
+            for q in range(nq):
+                acc = cb[gs, q]
+                for j in range(TZB):
+                    at = (sub * TZB + j) * side.r
+                    moved = pltpu.roll(
+                        buf[slot, q, j], jnp.mod(at - side.src + _LANE, _LANE), 1)
+                    acc = jnp.where(
+                        (lane >= at) & (lane < at + side.r), moved, acc)
+                cb[gs, q] = acc
+
+            @pl.when((sub == side.k - 1) | (i == n_b - 1))
+            def _():
+                out(grp).start()
+
+            @pl.when(i == n_b - 1)
+            def _():
+                if side.groups >= 2:
+                    out(grp - 1).wait()
+                out(grp).wait()
+
+    tile_bytes = py * _LANE * 4
+    _record_dma_bytes(
+        nq, g.shape, len(tiles) * n_b * TZB * tile_bytes,
+        sum(s.groups for s in g.sides) * nq * tile_bytes,
+        name="halo.split_x.bytes_dma", part="pack")
+    scratch = []
+    for _ in g.sides:
+        scratch += [
+            pltpu.VMEM((2, nq, TZB, py, _LANE), jnp.float32),
+            pltpu.VMEM((2, nq, py, _LANE), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
+    vma = frozenset(vma) if vma is not None else None
+    return scopes.kernel_call(
+        "split_x_pack", kernel,
+        grid=(n_b,),
+        out_shape=[jax.ShapeDtypeStruct(shp, jnp.float32, vma=vma)
+                   for shp in split_x_carrier_shapes(spec, nq)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nq,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * ns,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=100 * 1024 * 1024,
+        ),
+        interpret=interpret,
+    )
+
+
+def make_split_x_unpack(spec: GridSpec, nq: int = 1, vma=None,
+                        interpret: bool = False):
+    """``unpack(b0, .., b{nq-1}, carrier, ..) -> [b0', ..]``: in place
+    (``input_output_aliases``), read both edge lane-tiles of every field,
+    overwrite the low halo lanes from the carrier that arrived from the -x
+    neighbour (its forward carrier) and the high halo lanes from the +x
+    neighbour's backward carrier, write the tiles back. Nothing else of a
+    field is read or written."""
+    _split_x_check(spec, nq)
+    g = _split_x_geom(spec, nq)
+    (pz, py, px), TZB, n_b = g.shape, g.tzb, g.n_b
+    ns = len(g.sides)
+    tiles = [(n, q) for n in range(ns) for q in range(nq)]  # one DMA each
+
+    def kernel(*refs):
+        carriers = refs[nq : nq + ns]
+        outs = refs[nq + ns : 2 * nq + ns]
+        scratch = refs[2 * nq + ns :]
+        i = pl.program_id(0)
+        slot = jnp.mod(i, 2)
+        z_of = lambda step: jnp.minimum(step * TZB, pz - TZB)
+        lane = _lane_iota(py)
+
+        def tile(n, q, step):
+            return outs[q].at[pl.ds(z_of(step), TZB), :,
+                              pl.ds(g.sides[n].dst_tile, _LANE)]
+
+        def rd(n, q, s, step):
+            rb, s_r = scratch[6 * n], scratch[6 * n + 3]
+            return pltpu.make_async_copy(tile(n, q, step), rb.at[s, q],
+                                         s_r.at[s])
+
+        def wr(n, q, s, step):
+            wb, s_w = scratch[6 * n + 1], scratch[6 * n + 4]
+            return pltpu.make_async_copy(wb.at[s, q], tile(n, q, step),
+                                         s_w.at[s])
+
+        def cin(n, group):
+            cb, s_c = scratch[6 * n + 2], scratch[6 * n + 5]
+            s = jnp.mod(group, 2)
+            return pltpu.make_async_copy(
+                carriers[n].at[group], cb.at[s], s_c.at[s])
+
+        @pl.when(i == 0)
+        def _():
+            for n, q in tiles:
+                rd(n, q, slot, i).start()
+            for n in range(ns):
+                cin(n, 0).start()
+
+        @pl.when(i + 1 < n_b)
+        def _():
+            for n, q in tiles:
+                rd(n, q, 1 - slot, i + 1).start()
+
+        # the write buffers of batch i - 2 (same slot) must have drained
+        @pl.when(i >= 2)
+        def _():
+            for n, q in tiles:
+                wr(n, q, slot, i - 2).wait()
+
+        for n, side in enumerate(g.sides):
+            rb, wb, cb = scratch[6 * n : 6 * n + 3]
+            grp = i // side.k
+            sub = i - grp * side.k
+            gs = jnp.mod(grp, 2)
+
+            @pl.when(sub == 0)
+            def _():
+                cin(n, grp).wait()
+
+                # the other slot's group was last read a step ago
+                @pl.when(grp + 1 < side.groups)
+                def _():
+                    cin(n, grp + 1).start()
+
+            halo = (lane >= side.dst) & (lane < side.dst + side.r)
+            for q in range(nq):
+                rd(n, q, slot, i).wait()
+                arrived = cb[gs, q]
+                for j in range(TZB):
+                    at = (sub * TZB + j) * side.r
+                    moved = pltpu.roll(
+                        arrived, jnp.mod(side.dst - at + _LANE, _LANE), 1)
+                    wb[slot, q, j] = jnp.where(halo, moved, rb[slot, q, j])
+                wr(n, q, slot, i).start()
+
+        @pl.when(i == n_b - 1)
+        def _():
+            for n, q in tiles:
+                if n_b >= 2:
+                    wr(n, q, 1 - slot, i - 1).wait()
+                wr(n, q, slot, i).wait()
+
+    tile_bytes = py * _LANE * 4
+    moved = len(tiles) * n_b * TZB * tile_bytes
+    _record_dma_bytes(
+        nq, g.shape,
+        moved + sum(s.groups for s in g.sides) * nq * tile_bytes, moved,
+        name="halo.split_x.bytes_dma", part="unpack")
+    scratch = []
+    for _ in g.sides:
+        scratch += [
+            pltpu.VMEM((2, nq, TZB, py, _LANE), jnp.float32),
+            pltpu.VMEM((2, nq, TZB, py, _LANE), jnp.float32),
+            pltpu.VMEM((2, nq, py, _LANE), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+        ]
+    shape = jax.ShapeDtypeStruct(
+        g.shape, jnp.float32, vma=frozenset(vma) if vma is not None else None)
+    return scopes.kernel_call(
+        "split_x_unpack", kernel,
+        grid=(n_b,),
+        out_shape=(shape,) * nq,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (nq + ns),
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nq,
+        scratch_shapes=scratch,
+        input_output_aliases={q: q for q in range(nq)},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=100 * 1024 * 1024,
+        ),
+        interpret=interpret,
+    )

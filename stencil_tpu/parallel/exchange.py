@@ -624,8 +624,10 @@ class HaloExchange:
         slab bytes move in place, via the Pallas fill kernel on TPU (whose
         x/y lane/row-tile RMW amplification is not counted here: the
         ``halo.self_fill.bytes_dma`` counter each fill build records holds
-        the HBM bytes its DMAs really read and write) or via slice+update
-        elsewhere. AUTO_SPMD expresses the composed slab
+        the HBM bytes its DMAs really read and write, as
+        ``halo.split_x.bytes_dma`` does for a split x phase's edge-tile
+        kernels) or via slice+update elsewhere. AUTO_SPMD expresses the
+        composed slab
         program, so it shares the composed accounting (the partitioner may
         move less; collective_census counts what it actually emitted).
         Uneven DIRECT26 pads orthogonal extents to the base block size."""
@@ -784,7 +786,10 @@ class HaloExchange:
         historical per-quantity program (pack_slabs is the identity then),
         so :meth:`_axis_phase` delegates here — one copy of the geometry.
         All geometry (size table, permute pairs, radii, offsets) comes
-        from the phase record of the ExchangePlan IR."""
+        from the phase record of the ExchangePlan IR. One phase leaves
+        this body: a split x (lane) axis that :meth:`_split_x` accepts
+        packs and unpacks on the two edge lane-tiles
+        (:meth:`_split_x_phase`), same carrier count, same bits."""
         rm, rp, off, adim = phase.rm, phase.rp, phase.offset, phase.adim
         if rm == 0 and rp == 0:
             return blocks
@@ -792,6 +797,8 @@ class HaloExchange:
 
         if phase.resident > 1:
             return self._axis_phase_resident_batched(blocks, phase)
+        if self._split_x(phase, blocks[0].dtype):
+            return self._split_x_phase(blocks, phase)
         name = phase.axis
         n = phase.ring
         if phase.uniform:
@@ -825,6 +832,58 @@ class HaloExchange:
                     for b, s in zip(blocks, unpack_slabs(carrier, nq))
                 ]
         return blocks
+
+    def _split_x(self, phase, dtype) -> bool:
+        """Whether this phase packs and unpacks with the edge-tile kernels
+        of ops/halo_fill.py: the lane axis, split evenly with one block a
+        device, fp32, on TPUs, the halo and source columns inside the two
+        edge lane-tiles. Only there does placing a slab cost XLA a pass
+        over the whole field; every other phase keeps the XLA slab path."""
+        if not (phase.axis == AXIS_X and phase.blocks > 1 and phase.uniform
+                and dtype == jnp.float32
+                and self.resident == Dim3(1, 1, 1)):
+            return False
+        from ..ops.halo_fill import split_x_supported
+
+        return self._on_tpu() and split_x_supported(self.spec, dtype)
+
+    def _split_x_kernel(self, nq: int):
+        """(pack, unpack) for a group of ``nq`` fields, built once."""
+        cache = self.__dict__.setdefault("_split_x_kernels", {})
+        if nq not in cache:
+            from ..ops.halo_fill import make_split_x_pack, make_split_x_unpack
+            from .mesh import MESH_AXES
+
+            cache[nq] = (
+                make_split_x_pack(self.spec, nq, vma=MESH_AXES),
+                make_split_x_unpack(self.spec, nq, vma=MESH_AXES),
+            )
+        return cache[nq]
+
+    def _split_x_phase(self, blocks, phase):
+        """The x phase on a split lane axis: one kernel packs both
+        directions' columns from the two edge lane-tiles into lane-dense
+        carriers, the two permutes fly, one kernel unpacks both in place.
+        Both sources are interior columns that the phase never writes, so
+        the result is the slab path's, bit for bit."""
+        from ..ops.halo_fill import max_fill_group
+
+        fshape = self._fill_shape()
+        pairs = [pr for pr, r in ((phase.fwd, phase.rm), (phase.bwd, phase.rp))
+                 if r]
+        gmax = max_fill_group(self.spec)
+        out = []
+        for i in range(0, len(blocks), gmax):
+            chunk = [b.reshape(fshape) for b in blocks[i : i + gmax]]
+            pack, unpack = self._split_x_kernel(len(chunk))
+            with scopes.scope(scopes.HALO_PACK):
+                carriers = pack(*chunk)
+            carriers = [self._permute_wire(c, phase.axis, pr)
+                        for c, pr in zip(carriers, pairs)]
+            with scopes.scope(scopes.HALO_UNPACK):
+                res = unpack(*chunk, *carriers)
+            out += [v.reshape(b.shape) for v, b in zip(res, blocks[i:])]
+        return out
 
     def _axis_phase_resident_batched(self, blocks, phase):
         """:meth:`_axis_phase_resident` for a same-dtype group:

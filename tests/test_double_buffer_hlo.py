@@ -110,3 +110,50 @@ def test_no_whole_block_copy_in_the_compiled_loop(name, topo, as_on_the_chip):
              if block in shape]
     assert not whole, (
         f"{len(whole)} whole-block copies in {module}: {whole}")
+
+
+_RESULT = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\S+)\s+(copy|dynamic-update-slice)\(",
+    re.M)
+
+
+def test_split_x_exchange_loop_touches_no_whole_field_on_the_lane_axis(
+        topo, as_on_the_chip):
+    """``exchange512x4.r3q4``'s own loop (``make_loop(10)``, 4 x 512^3 r3 a
+    chip on (2,2,1)): the x phase is the two edge-tile kernels and two
+    permutes of lane-dense carriers. Before them this loop held four
+    whole-field ``copy`` and eight whole-field ``dynamic-update-slice`` on
+    the lane axis, 28 of its 34 ms (PERF.md, PR 29). The y phase's eight
+    in-place ``dynamic-update-slice`` (rows, 0.27 ms each) are still XLA's."""
+    import jax
+    import jax.numpy as jnp
+
+    from stencil_tpu.domain.grid import GridSpec
+    from stencil_tpu.geometry import Dim3, Radius
+    from stencil_tpu.obs import scopes
+    from stencil_tpu.parallel import HaloExchange, grid_mesh
+
+    d = Dim3(2, 2, 1)
+    spec = GridSpec(Dim3(1024, 1024, 512), d, Radius.constant(3))
+    ex = HaloExchange(spec, grid_mesh(d, list(topo.devices)[:4]))
+    like = {q: jax.ShapeDtypeStruct(spec.stacked_shape_zyx(), jnp.float32,
+                                    sharding=ex.sharding()) for q in range(4)}
+    scopes.clear()
+    ex.make_loop(10, like=like)
+    text = scopes.hlo_text(scopes.EXCHANGE_LOOP)
+    for kernel in ("split_x_pack", "split_x_unpack", "self_fill_z"):
+        assert re.search(rf"%{kernel}[.\d]* = .*tpu_custom_call", text), kernel
+    p = spec.padded()
+    field = f"{p.z},{p.y},{p.x}]"
+    whole = [(name, op, shape) for name, shape, op in _RESULT.findall(text)
+             if field in shape]
+    assert [w for w in whole if w[1] == "copy"] == []
+    # what is left are the y phase's: two sides of four fields
+    assert len(whole) == 8, whole
+    omap = scopes.op_map(scopes.EXCHANGE_LOOP)
+    for name, _op, _shape in whole:
+        assert omap[name]["scope"] == scopes.HALO_UNPACK
+    # and no 3-column slab of a field exists anywhere in the program
+    assert f"{p.z},{p.y},3]" not in text
+    # the carriers on the wire are lane-dense: 13 groups of 42 planes
+    assert "f32[13,4,528,128]" in text

@@ -162,3 +162,80 @@ def test_domain_quantity_batching_knob():
         dd.add_data("b", "float64")
         dd.realize()
         assert dd.halo_exchange.batch_quantities is enabled
+
+
+@pytest.mark.parametrize("wire", [None, "bfloat16"], ids=["native", "bf16wire"])
+def test_split_x_kernels_match_the_slab_path(monkeypatch, wire):
+    """A split lane axis packs and unpacks with the edge-tile kernels of
+    ops/halo_fill.py: forced onto that path off-TPU by injecting the
+    interpret-mode kernels on a 4-device mesh that splits x (as
+    test_exchange_blocks_fused_dispatch injects the self-fills), with
+    max_fill_group shrunk so a group of three is chunked 2 + 1. fp32 fields
+    take the kernels and the fp64 one the slab path, in one exchange; the
+    result is the XLA slab path's bit for bit, wire compression included."""
+    import stencil_tpu.ops.halo_fill as HF
+    from stencil_tpu.parallel.mesh import BLOCK_PSPEC
+
+    spec = GridSpec(Dim3(256, 16, 12), Dim3(2, 2, 1), Radius.constant(2))
+    mesh = grid_mesh(spec.dim, jax.devices()[:4])
+    dtypes = ("float32", "float32", "float64", "float32")
+
+    want = HaloExchange(spec, mesh, wire_dtype=wire)(_state(spec, mesh, dtypes))
+
+    ex = HaloExchange(spec, mesh, wire_dtype=wire)
+    assert HF.split_x_supported(spec, np.float32)
+    monkeypatch.setattr(ex, "_on_tpu", lambda: True)
+    monkeypatch.setattr(HF, "max_fill_group", lambda _spec: 2)
+    calls = []
+
+    def counted(nq):
+        pack = HF.make_split_x_pack(spec, nq, interpret=True)
+        unpack = HF.make_split_x_unpack(spec, nq, interpret=True)
+
+        def packed(*a):
+            calls.append(nq)
+            return pack(*a)
+
+        return packed, unpack
+
+    ex.__dict__["_split_x_kernels"] = {n: counted(n) for n in (1, 2)}
+    x_phase, y_phase = ex.plan.axis_phases[0], ex.plan.axis_phases[1]
+    assert ex._split_x(x_phase, np.dtype("float32"))
+    assert not ex._split_x(x_phase, np.dtype("float64"))
+    assert not ex._split_x(y_phase, np.dtype("float32"))
+    # the interpreter cannot be traced under shard_map's vma check
+    got = jax.jit(jax.shard_map(
+        ex.exchange_blocks, mesh=mesh, in_specs=BLOCK_PSPEC,
+        out_specs=BLOCK_PSPEC, check_vma=False))(_state(spec, mesh, dtypes))
+    assert calls == [2, 1]
+    for k, dt in enumerate(dtypes):
+        assert got[k].dtype == np.dtype(dt)
+        np.testing.assert_array_equal(
+            np.asarray(jax.device_get(got[k])), np.asarray(jax.device_get(want[k])))
+
+
+def test_split_x_needs_an_even_split_one_block_a_device_and_tpus():
+    """Everything the predicate refuses keeps the XLA slab path."""
+    spec = GridSpec(Dim3(256, 16, 12), Dim3(2, 2, 1), Radius.constant(2))
+    mesh = grid_mesh(spec.dim, jax.devices()[:4])
+    ex = HaloExchange(spec, mesh)
+    x_phase = ex.plan.axis_phases[0]
+    f32 = np.dtype("float32")
+    assert not ex._split_x(x_phase, f32)          # CPU devices
+    ex._on_tpu = lambda: True
+    assert ex._split_x(x_phase, f32)
+    # uneven x split
+    uneven = GridSpec(Dim3(250, 16, 12), Dim3(2, 2, 1), Radius.constant(2))
+    exu = HaloExchange(uneven, grid_mesh(uneven.dim, jax.devices()[:4]))
+    exu._on_tpu = lambda: True
+    assert not exu._split_x(exu.plan.axis_phases[0], f32)
+    # two x blocks resident on one device
+    res = GridSpec(Dim3(256, 16, 12), Dim3(4, 1, 1), Radius.constant(2))
+    exr = HaloExchange(res, grid_mesh(Dim3(2, 1, 1), jax.devices()[:2]))
+    exr._on_tpu = lambda: True
+    assert not exr._split_x(exr.plan.axis_phases[0], f32)
+    # x not split: the self-fill's phase
+    one = GridSpec(Dim3(128, 16, 12), Dim3(1, 2, 2), Radius.constant(2))
+    exo = HaloExchange(one, grid_mesh(one.dim, jax.devices()[:4]))
+    exo._on_tpu = lambda: True
+    assert not exo._split_x(exo.plan.axis_phases[0], f32)

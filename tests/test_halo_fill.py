@@ -282,3 +282,139 @@ def test_self_fill_gates_vmem_budget():
     # ...but a 4096-row plane exceeds the budget even at depth 2
     huge = GridSpec(Dim3(4096, 4096, 64), Dim3(1, 1, 1), Radius.constant(3))
     assert not self_fill_supported(huge, "x", jnp.float32)
+
+
+# -- split x axis: pack / unpack on the two edge lane-tiles -------------------
+
+
+def _radius_x(rm, rp, rest=2):
+    r = Radius.constant(rest)
+    r.set_dir((-1, 0, 0), rm)
+    r.set_dir((1, 0, 0), rp)
+    return r
+
+
+def _slab_path(mine, minus, plus, spec):
+    """numpy: what the XLA slab path leaves in ``mine`` when ``minus`` and
+    ``plus`` are its -x and +x neighbours."""
+    o, sx = spec.compute_offset().x, spec.base.x
+    rm, rp = spec.radius.x(-1), spec.radius.x(1)
+    want = mine.copy()
+    if rm:
+        want[:, :, o - rm : o] = minus[:, :, o + sx - rm : o + sx]
+    if rp:
+        want[:, :, o + sx : o + sx + rp] = plus[:, :, o : o + rp]
+    return want
+
+
+SPLIT_X_CASES = {
+    # size of one block, x radii (minus, plus), quantities
+    "r1.q1": ((256, 136, 24), (1, 1), 1),
+    "r2.q3": ((140, 160, 40), (2, 2), 3),
+    "r3.q3.lane_shift": ((200, 24, 30), (3, 3), 3),
+    "r3.q1.two_groups": ((256, 144, 30), (3, 3), 1),
+    # pz = 41 on z batches of 16: the clamped last batch overlaps its
+    # predecessor and sends planes 25 to 31 twice
+    "r3.q2.tail_overlap": ((128, 16, 35), (3, 3), 2),
+    "asymmetric.1.3": ((140, 160, 40), (1, 3), 2),
+    "asymmetric.3.1": ((128, 32, 20), (3, 1), 1),
+    "forward_only": ((140, 160, 40), (2, 0), 1),
+    "backward_only": ((140, 160, 40), (0, 2), 2),
+    # 9 columns: 14 planes a 128-lane group, so batches shrink to 8
+    "r9.q1": ((256, 16, 40), (9, 9), 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SPLIT_X_CASES))
+def test_split_x_pack_unpack_match_the_slab_path(case):
+    """Three blocks in a ring along x: each packs, the carriers go forward
+    and backward, each unpacks: bit for bit what slicing 3 columns off the
+    neighbours and writing them into the halos gives."""
+    from stencil_tpu.ops.halo_fill import (
+        make_split_x_pack, make_split_x_unpack, split_x_carrier_shapes,
+        split_x_supported)
+
+    size, (rm, rp), nq = SPLIT_X_CASES[case]
+    spec = GridSpec(Dim3(3 * size[0], size[1], size[2]), Dim3(3, 1, 1),
+                    _radius_x(rm, rp))
+    assert split_x_supported(spec, jnp.float32)
+    p = spec.padded()
+    rng = np.random.RandomState(11)
+    # garbage (NaN) in halos and padding: nothing but the source columns
+    # may reach a carrier, nothing but the halo lanes may change
+    off = spec.compute_offset()
+    blocks = []
+    for _ in range(3):
+        fields = []
+        for _q in range(nq):
+            a = np.full((p.z, p.y, p.x), np.nan, np.float32)
+            a[:, :, off.x : off.x + size[0]] = rng.rand(p.z, p.y, size[0])
+            fields.append(a)
+        blocks.append(fields)
+    pack = make_split_x_pack(spec, nq, interpret=True)
+    unpack = make_split_x_unpack(spec, nq, interpret=True)
+    shapes = split_x_carrier_shapes(spec, nq)
+    assert len(shapes) == (rm > 0) + (rp > 0)
+    carriers = [pack(*[jnp.asarray(f) for f in b]) for b in blocks]
+    for c in carriers:
+        assert [tuple(x.shape) for x in c] == [tuple(s) for s in shapes]
+        assert all(np.isfinite(np.asarray(x)).all() for x in c)
+    for i, mine in enumerate(blocks):
+        minus, plus = (i - 1) % 3, (i + 1) % 3
+        arriving = []
+        if rm:      # the -x neighbour's forward carrier
+            arriving.append(carriers[minus][0])
+        if rp:      # the +x neighbour's backward carrier
+            arriving.append(carriers[plus][-1])
+        got = unpack(*[jnp.asarray(f) for f in mine], *arriving)
+        for q in range(nq):
+            want = _slab_path(mine[q], blocks[minus][q], blocks[plus][q], spec)
+            np.testing.assert_array_equal(np.asarray(got[q]), want)
+
+
+def test_split_x_geometry_shares_the_x_fills_batches():
+    from stencil_tpu.ops import halo_fill as HF
+
+    # the four-chip exchange cell's block: 518 x 528 x 640, offset 3
+    spec = GridSpec(Dim3(1024, 1024, 512), Dim3(2, 2, 1), Radius.constant(3))
+    g = HF._split_x_geom(spec, 4)
+    assert g.shape == (518, 528, 640)
+    assert g.tzb == HF._x_tzb(spec, 4) == 2 and g.n_b == 259
+    fwd, bwd = g.sides
+    assert (fwd.src_tile, fwd.src, fwd.dst_tile, fwd.dst) == (512, 0, 0, 0)
+    assert (bwd.src_tile, bwd.src, bwd.dst_tile, bwd.dst) == (0, 3, 512, 3)
+    # 42 planes of 3 columns a 128-lane group: 126 lanes in use
+    assert fwd.k * g.tzb == 42 and fwd.groups == 13
+    assert HF.split_x_carrier_shapes(spec, 4) == [(13, 4, 528, 128)] * 2
+    # a clamped tail (pz % TZB != 0) keeps the batch count of the x fill
+    odd = GridSpec(Dim3(256, 16, 35), Dim3(2, 1, 1), Radius.constant(3))
+    go = HF._split_x_geom(odd, 1)
+    assert go.shape[0] % go.tzb and go.n_b == -(-go.shape[0] // go.tzb)
+
+
+def test_split_x_gates():
+    """The predicate is the x self-fill's: dtype, alignment, a radius on x,
+    depth, VMEM, halo and source columns inside the two edge lane-tiles."""
+    from stencil_tpu.ops.halo_fill import (
+        make_split_x_pack, make_split_x_unpack, max_fill_group,
+        split_x_supported)
+
+    ok = GridSpec(Dim3(256, 64, 16), Dim3(2, 1, 1), Radius.constant(1))
+    assert split_x_supported(ok, jnp.float32)
+    assert not split_x_supported(ok, jnp.float64)
+    unaligned = GridSpec(Dim3(256, 64, 16), Dim3(2, 1, 1), Radius.constant(1),
+                         aligned=False)
+    assert not split_x_supported(unaligned, jnp.float32)
+    no_x = GridSpec(Dim3(256, 64, 16), Dim3(2, 1, 1),
+                    Radius.constant(1).without_x())
+    assert not split_x_supported(no_x, jnp.float32)
+    thin = GridSpec(Dim3(256, 64, 1), Dim3(2, 1, 1), Radius.constant(1))
+    if thin.padded().z < 4:
+        assert not split_x_supported(thin, jnp.float32)
+    huge = GridSpec(Dim3(8192, 4096, 64), Dim3(2, 1, 1), Radius.constant(3))
+    assert not split_x_supported(huge, jnp.float32)
+    for make in (make_split_x_pack, make_split_x_unpack):
+        with pytest.raises(ValueError):
+            make(no_x, 1, interpret=True)
+        with pytest.raises(ValueError):
+            make(ok, max_fill_group(ok) + 1, interpret=True)
