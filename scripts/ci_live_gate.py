@@ -139,7 +139,7 @@ def main() -> int:
         # child output goes to FILES, not pipes: the poll loop never
         # drains a pipe, so a chatty child (debug logging, jax warnings)
         # would fill the OS buffer, block on write, and deadlock the
-        # gate — the round-4 bench.py lesson watchdog.supervise encodes
+        # gate — the lesson watchdog.supervise encodes
         out_path = os.path.join(work, "anomaly-run.log")
         with open(out_path, "w") as log_f:
             proc = subprocess.Popen(cmd, cwd=REPO, stdout=log_f,

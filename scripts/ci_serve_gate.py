@@ -212,7 +212,7 @@ def stage1_continuous_batching(work):
     print(f"[serve-gate] daemon (polled): {' '.join(cmd)}", flush=True)
     # child output goes to FILES, not pipes: the poll loop never drains
     # a pipe, so a chatty child would fill the OS buffer and deadlock
-    # the gate (the round-4 bench.py lesson watchdog.supervise encodes)
+    # the gate (the lesson watchdog.supervise encodes)
     out_path = os.path.join(work, "daemon1.out")
     err_path = os.path.join(work, "daemon1.err")
     polls, dropped_late, seen_nine = [], False, False

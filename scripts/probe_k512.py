@@ -4,7 +4,7 @@ The cap was measured before the tight-x kernels (k=2 5.69 / k=6 3.88 /
 k=10 3.20 ms/step, BASELINE round 2); the current multistep runs 1.77
 ms/step at k=10, so the wavefront floor moved and the diminishing-returns
 point needs re-measuring. The VMEM staging budget allows k~13 at 512^3.
-Uses the same iteration/chunk discipline as bench.py's headline leg.
+Fused chunks, an untimed warmup chunk, trimean over chunk means.
 
 Usage: python scripts/probe_k512.py [n] [k ...]
 """

@@ -15,7 +15,7 @@ measurement:
   12.7 ms standalone leg was never a floor term and BASELINE.md's closure
   must carry this delta instead.
 
-Bench discipline as bench.py's astaroth legs: fused chunks, untimed
+Bench discipline: fused chunks, untimed
 warmup chunk, trimean over chunk means, hard_sync. Run on the TPU host:
 
   python scripts/probe_ring_substep.py [n] [iters] [chunk]

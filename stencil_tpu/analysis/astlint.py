@@ -21,9 +21,8 @@ exits 1 only on findings NOT in the baseline.
 The shipped rules encode contracts PRs 3-12 stated in prose:
 
 - ``pure-stdlib``     obs/watchdog.py, obs/ledger.py, obs/status.py are
-                      loaded BY FILE PATH (bench.py parent, watchdog
-                      supervisors) and must import only the stdlib, at
-                      any nesting depth; bench.py's module top level too.
+                      loaded BY FILE PATH (watchdog supervisors) and
+                      must import only the stdlib, at any nesting depth.
 - ``telemetry-vocab`` literal metric names at Recorder record sites must
                       be in obs/telemetry.KNOWN_NAMES (typos validate
                       silently otherwise — schema v1 constrains shape,
@@ -73,16 +72,13 @@ PURE_STDLIB_FILES = (
     "serve/state.py",
     "scripts/serve_loadgen.py",
 )
-# bench.py's PARENT is pure-stdlib at module level only: the child code
-# paths (same file, function scope) import jax after the re-exec.
-PURE_STDLIB_TOP_LEVEL = ("bench.py",)
 
 # Directories never linted by default (tests use asserts and ad-hoc
 # metric names legitimately; generated caches are not source).
 EXCLUDE_DIR_NAMES = ("__pycache__", ".git", ".claude")
 EXCLUDE_PREFIXES = ("tests/", "native/")
 
-DEFAULT_PATHS = ("stencil_tpu", "scripts", "bench.py", "__graft_entry__.py")
+DEFAULT_PATHS = ("stencil_tpu", "scripts", "__graft_entry__.py")
 
 
 @dataclass(frozen=True)
@@ -238,32 +234,18 @@ def _is_stdlib(mod: str) -> bool:
 
 def _pure_stdlib_applies(relpath: str) -> bool:
     p = _norm(relpath)
-    return (any(p == f or p.endswith("/" + f) for f in PURE_STDLIB_FILES)
-            or any(p == f or p.endswith("/" + f)
-                   for f in PURE_STDLIB_TOP_LEVEL))
+    return any(p == f or p.endswith("/" + f) for f in PURE_STDLIB_FILES)
 
 
 @rule("pure-stdlib", severity="error", applies=_pure_stdlib_applies)
 def check_pure_stdlib(ctx: FileContext) -> List[Finding]:
     """File-path-loaded modules (obs/watchdog, obs/ledger, obs/status)
-    must import only the stdlib, at any nesting depth; bench.py's module
-    top level likewise (its child code paths may import jax in
-    functions)."""
-    p = _norm(ctx.relpath)
-    top_level_only = (
-        any(p == f or p.endswith("/" + f) for f in PURE_STDLIB_TOP_LEVEL)
-        and not any(p == f or p.endswith("/" + f)
-                    for f in PURE_STDLIB_FILES))
+    must import only the stdlib, at any nesting depth."""
     out: List[Finding] = []
     r = RULES["pure-stdlib"]
 
-    def visit(node, at_top: bool):
+    def visit(node):
         for child in ast.iter_child_nodes(node):
-            if isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef,
-                                  ast.Lambda)):
-                if not top_level_only:
-                    visit(child, False)
-                continue
             if isinstance(child, ast.Import):
                 for alias in child.names:
                     if not _is_stdlib(alias.name):
@@ -285,9 +267,9 @@ def check_pure_stdlib(ctx: FileContext) -> List[Finding]:
                         r, child,
                         f"non-stdlib import {child.module!r} in a "
                         f"pure-stdlib module"))
-            visit(child, at_top)
+            visit(child)
 
-    visit(ctx.tree, True)
+    visit(ctx.tree)
     return out
 
 

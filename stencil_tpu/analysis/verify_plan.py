@@ -456,276 +456,6 @@ def run_placement_sweep(count: int = 3, size: int = DEFAULT_SIZE,
     }
 
 
-# -- hierarchy conformance (the ISSUE-17 ICI+DCN leg) ------------------------
-
-# Hierarchical inner methods the DCN audit sweeps: the overlapped
-# composed schedule plus the sequential REMOTE_DMA family (the fused
-# variant's exchange program included). The persistent variant's
-# EXCHANGE program is the plain REMOTE_DMA one, so it rides that row.
-HIERARCHY_INNER_METHODS: Tuple[str, ...] = (
-    "axis-composed", "remote-dma", FUSED_METHOD_LABEL)
-
-
-def hierarchy_sweep_configs(
-    size: int = DEFAULT_SIZE,
-    radius: int = DEFAULT_RADIUS,
-    partitions: Sequence[Tuple[int, int, int]] = DEFAULT_PARTITIONS,
-    hosts: int = 2,
-    methods: Optional[Sequence[str]] = None,
-    qsets: Sequence[Sequence[str]] = DEFAULT_QSETS,
-) -> List[dict]:
-    """The hierarchical sweep grid: every partition whose z extent the
-    host count divides (z is the slowest-varying mesh coordinate, so the
-    identity device order groups each z segment onto one contiguous
-    host — no composed placement needed for the audit fixture), crossed
-    with the hierarchical inner methods and dtype sets."""
-    methods = list(methods or HIERARCHY_INNER_METHODS)
-    unknown = sorted(set(methods) - set(HIERARCHY_INNER_METHODS))
-    if unknown:
-        raise ValueError(
-            f"unknown hierarchical method(s): {', '.join(unknown)} "
-            f"(known: {', '.join(HIERARCHY_INNER_METHODS)})")
-    if hosts < 2:
-        raise ValueError(f"hierarchy audit needs hosts >= 2, got {hosts}")
-    out = []
-    for part in partitions:
-        px, py, pz = part
-        if pz % hosts:
-            continue  # the z split must land whole segments per host
-        for dtypes in qsets:
-            for method in methods:
-                short = "+".join(
-                    f"{n}x{dt.replace('float', 'f')}"
-                    for dt, n in sorted(
-                        {d: list(dtypes).count(d) for d in set(dtypes)}
-                        .items()))
-                out.append({
-                    "label": (f"{size}^3/{px}x{py}x{pz}/h=z{hosts}"
-                              f"/{method}/{short}"),
-                    "size": int(size), "radius": int(radius),
-                    "partition": tuple(part), "method": method,
-                    "dtypes": tuple(dtypes),
-                    "hierarchy": ("z", int(hosts)),
-                })
-    return out
-
-
-def audit_hierarchy(cfg: dict, devices=None,
-                    perturb_dcn: int = 0) -> Verdict:
-    """Audit one hierarchical config's DCN level against its plan.
-
-    Requires a multi-host fabric (real processes or
-    ``STENCIL_VIRTUAL_HOSTS`` — :func:`run_hierarchy_sweep` sets the
-    emulation up). Checks, on top of compiling both levels:
-
-    - predicted ``dcn_transfers_per_exchange x carriers`` equals the
-      transport's executed cross-host copy count
-      (``last_transfer_count`` — the DCN analogue of the DMA audit);
-    - predicted ``dcn_wire_bytes`` equals the executed carrier bytes
-      (exact on the one-block-per-device meshes this sweep stays in);
-    - the INNER census pins are unchanged: the hierarchical census's
-      collective-permute (count, bytes) equals the flat plan's, and no
-      stray collective kind appears (the DCN level compiles zero
-      collectives);
-    - for the REMOTE_DMA family, the inner emulated transfer count
-      still equals ``dmas_per_exchange x ndev`` (host-segmented wrap
-      pairs move exactly what the flat ring moved);
-    - the exchanged field is bit-identical to the flat lowering for
-      every quantity (hierarchy moves the SAME halos, only over a
-      different transport).
-    """
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ..geometry import Dim3, Radius
-    from ..parallel import HaloExchange, Method, grid_mesh
-    from ..parallel.exchange import shard_blocks, unshard_blocks
-    from ..plan.cost import feasible
-    from ..plan.ir import (FUSED_VARIANT, PlanChoice, PlanConfig,
-                           REMOTE_DMA, validate_hierarchy)
-
-    devices = list(devices) if devices is not None else jax.devices()
-    v = Verdict(label=cfg["label"], method=cfg["method"])
-    fused = cfg["method"] == FUSED_METHOD_LABEL
-    method = REMOTE_DMA if fused else cfg["method"]
-    size, dtypes = cfg["size"], list(cfg["dtypes"])
-    hierarchy = tuple(cfg["hierarchy"])
-    radius = Radius.constant(cfg["radius"])
-    nblocks = cfg["partition"][0] * cfg["partition"][1] * cfg["partition"][2]
-    if nblocks > len(devices):
-        v.skipped = True
-        v.ok = False
-        v.reason = (f"partition {cfg['partition']} needs {nblocks} "
-                    f"devices; {len(devices)} available")
-        return v
-    config = PlanConfig.make(Dim3(size, size, size), radius, dtypes,
-                             nblocks, devices[0].platform)
-    choice = PlanChoice(
-        partition=cfg["partition"], method=method,
-        kernel_variant=FUSED_VARIANT if fused else None,
-        hierarchy=hierarchy)
-    feas = feasible(config, choice)
-    if feas is None:
-        v.skipped = True
-        v.ok = False
-        v.reason = (f"infeasible for this config (plan/cost.feasible: "
-                    f"partition {cfg['partition']} with radius "
-                    f"{cfg['radius']} on {nblocks} device(s))")
-        return v
-    spec, mesh_dim, _resident = feas
-    herr = validate_hierarchy(hierarchy, mesh_dim)
-    if herr is not None:
-        v.skipped = True
-        v.ok = False
-        v.reason = herr
-        return v
-    mesh = grid_mesh(spec.dim, devices[:nblocks])
-    ex_h = HaloExchange(spec, mesh, Method(method), fused=fused,
-                        hierarchy=hierarchy)
-    ex_f = HaloExchange(spec, mesh, Method(method), fused=fused)
-    g = spec.global_size
-    base = np.arange(g.x * g.y * g.z, dtype=np.float64).reshape(
-        g.z, g.y, g.x)
-    state = {i: shard_blocks((base + i).astype(dt), spec, mesh)
-             for i, dt in enumerate(dtypes)}
-    plan = ex_h.plan
-    nq = len(dtypes)
-    ngroups = len(set(dtypes))
-    itemsizes = [np.dtype(d).itemsize for d in dtypes]
-    floating = [bool(np.issubdtype(np.dtype(d), np.floating))
-                for d in dtypes]
-
-    # the census first (it runs one exchange on an internal copy and
-    # compiles every piece — inner programs plus DCN take/updates)
-    census = ex_h.collective_census(state)
-    census_f = ex_f.collective_census(state)
-    stray = {k: c for k, (c, _b) in census.items()
-             if k != "collective-permute" and c}
-    ok = _check(v.checks, "inner_census_pin",
-                list(census_f.get("collective-permute", (0, 0))),
-                list(census.get("collective-permute", (0, 0))))
-    ok &= _check(v.checks, "stray_collective_kinds", {}, stray)
-
-    # one real exchange, counted: the executed DCN schedule vs the IR
-    out_h = ex_h(jax.tree.map(jnp.copy, state))
-    predicted_dcn = plan.dcn_transfers_per_exchange(nq, ngroups) \
-        + perturb_dcn
-    ok &= _check(v.checks, "dcn_transfers", predicted_dcn,
-                 ex_h._compiled.last_transfer_count)
-    ok &= _check(v.checks, "dcn_wire_bytes",
-                 plan.dcn_wire_bytes(itemsizes, floating=floating),
-                 ex_h._compiled.last_transfer_bytes)
-    if method == REMOTE_DMA:
-        # the sequential schedule ran the full inner program first: its
-        # host-segmented wrap pairs move the flat count — EXCEPT when a
-        # segment is a single device along the DCN axis, where the
-        # host-local wrap pair degenerates to a self hand-off and the
-        # pure-axis carriers leave the transport entirely (the DCN
-        # apply owns that whole halo side)
-        ax, hosts_n = hierarchy
-        ax_i = {"x": 0, "y": 1, "z": 2}[ax]
-        seg = {"x": mesh_dim.x, "y": mesh_dim.y,
-               "z": mesh_dim.z}[ax] // hosts_n
-        phases = plan.fused_phases if fused else plan.remote_phases
-        if seg > 1:
-            kept = list(phases)
-        elif fused:
-            kept = [p for p in phases
-                    if any(c for j, c in enumerate(p.direction)
-                           if j != ax_i)]
-        else:
-            kept = [p for p in phases if p.axis != ax]
-        carriers = ngroups if plan.batch_quantities else nq
-        ok &= _check(v.checks, "inner_dma_transfers",
-                     sum(p.dmas() for p in kept) * carriers * nblocks,
-                     ex_h._remote.last_transfer_count)
-
-    # hierarchy must be invisible in the data: bit parity with the flat
-    # lowering, every quantity
-    out_f = ex_f(jax.tree.map(jnp.copy, state))
-    parity = all(
-        unshard_blocks(out_h[i], spec).tobytes()
-        == unshard_blocks(out_f[i], spec).tobytes()
-        for i in range(nq))
-    ok &= _check(v.checks, "bit_identical_to_flat", True, bool(parity))
-    v.ok = bool(ok)
-    return v
-
-
-def run_hierarchy_sweep(
-    hosts: int = 2,
-    size: int = DEFAULT_SIZE,
-    radius: int = DEFAULT_RADIUS,
-    partitions: Sequence[Tuple[int, int, int]] = DEFAULT_PARTITIONS,
-    methods: Optional[Sequence[str]] = None,
-    qsets: Sequence[Sequence[str]] = DEFAULT_QSETS,
-    devices=None,
-    perturb_dcn: int = 0,
-    rec: Optional["telemetry.Recorder"] = None,
-) -> Dict:
-    """Audit the DCN level across the hierarchical sweep grid (the
-    ISSUE-17 gate). Runs on the ``STENCIL_VIRTUAL_HOSTS`` emulation:
-    the env knob is set to ``hosts`` for the duration and restored
-    after, exactly like :func:`run_sweep`'s x64 flip — a real
-    multi-process fabric audits the same way with ``hosts`` matching
-    ``jax.process_count()``. Emits the same ``analysis.plan_verdict``/
-    ``plan_mismatch``/``plan_sweep`` vocabulary as the method sweep."""
-    import os
-
-    rec = rec or telemetry.get()
-    configs = hierarchy_sweep_configs(size=size, radius=radius,
-                                      partitions=partitions, hosts=hosts,
-                                      methods=methods, qsets=qsets)
-    x64_prev = None
-    if any("64" in dt for cfg in configs for dt in cfg["dtypes"]):
-        import jax
-
-        x64_prev = bool(jax.config.jax_enable_x64)
-        jax.config.update("jax_enable_x64", True)
-    vh_prev = os.environ.get("STENCIL_VIRTUAL_HOSTS")
-    os.environ["STENCIL_VIRTUAL_HOSTS"] = str(hosts)
-    try:
-        verdicts: List[Verdict] = []
-        for cfg in configs:
-            with rec.span("analysis.verify_plan", phase="analysis",
-                          method=cfg["method"]):
-                try:
-                    v = audit_hierarchy(cfg, devices=devices,
-                                        perturb_dcn=perturb_dcn)
-                except Exception as e:  # an auditor crash is a FAILED config
-                    v = Verdict(label=cfg["label"], method=cfg["method"],
-                                ok=False,
-                                reason=f"{type(e).__name__}: {e}")
-            verdicts.append(v)
-            rec.meta("analysis.plan_verdict", method=v.method,
-                     ok=int(v.ok), label=v.label,
-                     skipped=int(v.skipped), reason=v.reason or None)
-            if not v.ok and not v.skipped:
-                rec.counter("analysis.plan_mismatch", value=1,
-                            phase="analysis", method=v.method)
-        checked = [v for v in verdicts if not v.skipped]
-        failed = [v for v in checked if not v.ok]
-        skipped = [v for v in verdicts if v.skipped]
-        rec.meta("analysis.plan_sweep", checked=len(checked),
-                 failed=len(failed), skipped=len(skipped))
-        return {
-            "verdicts": verdicts,
-            "checked": len(checked),
-            "failed": len(failed),
-            "skipped": len(skipped),
-        }
-    finally:
-        if vh_prev is None:
-            os.environ.pop("STENCIL_VIRTUAL_HOSTS", None)
-        else:
-            os.environ["STENCIL_VIRTUAL_HOSTS"] = vh_prev
-        if x64_prev is False:
-            import jax
-
-            jax.config.update("jax_enable_x64", False)
-
-
 # -- timed audit (the ISSUE-18 drift leg: seconds, not just structure) -------
 
 

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import os
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 import jax
@@ -69,7 +69,6 @@ class DistributedDomain:
         self._batch_quantities = True
         self._fused = False
         self._persistent = False
-        self._hierarchy: Optional[Tuple[str, int]] = None
         self._wire_dtype: Optional[str] = None
         self._devices: Optional[Sequence] = None
         self._partition_dim: Optional[Dim3] = None
@@ -178,26 +177,6 @@ class DistributedDomain:
         realize() raises loudly otherwise."""
         self._persistent = bool(enabled)
 
-    def set_hierarchy(self, axis, hosts: Optional[int] = None) -> None:
-        """Hierarchical (ICI + DCN) domain decomposition (ROADMAP #3):
-        split the ``axis`` ('z'/'y'/'x') ring into ``hosts`` contiguous
-        segments — the inner per-host exchange stays on the ICI while
-        the segment-boundary slabs cross the DCN, overlapped behind the
-        intra-host phases (parallel/hierarchy.py owns the schedule and
-        the bit-parity argument). Pass ``None`` (or ``hosts=1``) to
-        clear. Applied at realize(); also set automatically when a tuned
-        plan carries a ``hierarchy``. The realized mesh must group each
-        segment onto one host — in-process that is the
-        ``STENCIL_VIRTUAL_HOSTS`` fabric plus the two-level placement
-        (plan/cost.solve_two_level_placement); HaloExchange validates
-        loudly. Composed/remote-dma inner methods only."""
-        if axis is None:
-            self._hierarchy = None
-            return
-        if hosts is None:
-            axis, hosts = axis  # a ("z", 2) tuple
-        self._hierarchy = (str(axis), int(hosts))
-
     def set_quantity_batching(self, enabled: bool) -> None:
         """Quantity-batched exchange (default on): per collective, all
         same-dtype quantities' boundary slabs ride ONE packed ``(Q, ...)``
@@ -286,12 +265,6 @@ class DistributedDomain:
                     # not crash realize() on a stale fused flag)
                     self._fused = ch.is_fused
                     self._persistent = ch.is_persistent
-                    # same ownership rule for the outer DCN split: a
-                    # hierarchical choice realizes the two-level
-                    # transport, a flat one clears any prior
-                    # set_hierarchy (absent field == flat, the
-                    # pre-hierarchy DB/ckpt migration default)
-                    self._hierarchy = ch.hierarchy
                     if self._partition_dim is None:
                         self._partition_dim = Dim3.of(ch.partition)
             if self._partition_dim is not None:
@@ -362,7 +335,6 @@ class DistributedDomain:
                 wire_dtype=self._wire_dtype,
                 fused=self._fused,
                 persistent=self._persistent,
-                hierarchy=self._hierarchy,
             )
             sharding = self._exchange.sharding()
             zeros = {dt: sharded_full(shape, 0, dt, sharding)
@@ -529,21 +501,10 @@ class DistributedDomain:
                             else PERSISTENT_VARIANT if self._persistent
                             else None),
             placement=ch.placement if ch is not None else None,
-            hierarchy=self._hierarchy,
-            host_placement=ch.host_placement if ch is not None else None,
         )
-        # the realized host fabric: host index per mesh position, so a
-        # resume on a different host topology (other host count, other
-        # segment grouping) is visible in the manifest even when the
-        # plan itself is unchanged
-        from .parallel.device_topo import host_assignment
-
-        hosts = [int(h) for h in host_assignment(
-            list(self.mesh.devices.flat))]
         return {"key": cfg.to_json(), "choice": choice.to_json(),
                 "tuned": ch is not None,
-                "wire_dtype": self._wire_dtype,
-                "host_blocks": hosts}
+                "wire_dtype": self._wire_dtype}
 
     def _warn_plan_mismatch(self, manifest: dict) -> None:
         saved = (manifest.get("meta") or {}).get("plan")
@@ -558,25 +519,12 @@ class DistributedDomain:
         # upgrade must not make every old snapshot warn
         saved_ch.setdefault("placement", None)
         here_ch.setdefault("placement", None)
-        # same migration rule for the outer DCN split: pre-hierarchy
-        # snapshots never wrote the fields, and absent IS flat
+        # manifests written by PRs 17 to 29 carry the retired keys of
+        # the two-level exchange (hierarchy, host_placement; the host
+        # index per block beside the choice): the block geometry never
+        # depended on them, so a restore ignores them
         for k in ("hierarchy", "host_placement"):
-            saved_ch.setdefault(k, None)
-            here_ch.setdefault(k, None)
-        # host-topology delta: the plan may be unchanged while the host
-        # fabric moved under it (other host count / segment grouping) —
-        # restoring is still bit-exact, but recorded DCN performance is
-        # not comparable. Pre-hierarchy snapshots (no field) stay quiet.
-        saved_hosts = saved.get("host_blocks")
-        here_hosts = here.get("host_blocks")
-        if saved_hosts is not None and saved_hosts != here_hosts:
-            log.warn(
-                "ckpt: snapshot was written on host fabric "
-                f"{saved_hosts} but this run realizes {here_hosts} "
-                "(host index per mesh position) — the elastic restore "
-                "is bit-exact, but cross-host exchange behavior and any "
-                "recorded DCN timings differ"
-            )
+            saved_ch.pop(k, None)
         if not (saved.get("tuned") or here["tuned"]):
             # neither side went through the tuner: a partition-only delta
             # is the supported elastic mesh-reshape resume (PR 4) and must

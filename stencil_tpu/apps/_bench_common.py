@@ -224,7 +224,6 @@ def time_exchange(
     partition=None,
     wire_dtype=None,
     fused: bool = False,
-    hierarchy=None,
 ) -> dict:
     """Realize a domain with ``quantities`` quantities and time ``iters``
     exchanges in fused chunks. Returns stats + the domain.
@@ -239,11 +238,7 @@ def time_exchange(
     candidates probe through here). ``placement`` is a Placement
     strategy OR a plain assignment tuple (``PlanChoice.placement`` —
     wrapped in :class:`~stencil_tpu.parallel.FixedAssignment` so placed
-    plan candidates probe on exactly their tuned mesh). ``hierarchy``
-    is the outer DCN split ``(axis, hosts)`` — hierarchical candidates
-    time the two-level transport (DCN slabs overlapped behind the inner
-    phases), on whatever host fabric the process sees
-    (STENCIL_VIRTUAL_HOSTS in-process)."""
+    plan candidates probe on exactly their tuned mesh)."""
     devices = list(devices) if devices is not None else jax.devices()
     if placement is not None and not hasattr(placement, "arrange"):
         from ..parallel import FixedAssignment
@@ -259,8 +254,6 @@ def time_exchange(
     dd.set_quantity_batching(batch_quantities)
     if fused:
         dd.set_fused_exchange(True)
-    if hierarchy is not None:
-        dd.set_hierarchy(hierarchy)
     if wire_dtype:
         dd.set_wire_dtype(wire_dtype)
     if partition is not None:
@@ -285,10 +278,6 @@ def time_exchange(
     wtag = {"wire": str(wire_dtype)} if wire_dtype else {}
     if fused:
         wtag["variant"] = "fused"
-    if hierarchy is not None:
-        # keeps a hierarchical-vs-flat A/B's legs separable in
-        # aggregation, like wire/variant above
-        wtag["hierarchy"] = f"{hierarchy[0]}{hierarchy[1]}"
     # compile + warm every loop size OUTSIDE the timed region
     with rec.span("exchange.warmup", phase="compile", method=method.value,
                   batched=batch_quantities, **wtag):

@@ -414,20 +414,11 @@ def main(argv: Optional[list] = None) -> int:
                    help="use the fused compute+exchange transport "
                         "(REMOTE_DMA kernel_variant=fused) for --wire-ab")
     p.add_argument("--cpu", type=int, default=0)
-    p.add_argument("--virtual-hosts", type=int, default=0,
-                   help="emulate N hosts over the local device list "
-                        "(sets STENCIL_VIRTUAL_HOSTS: id-sorted "
-                        "contiguous groups) — the in-process fabric the "
-                        "hierarchical ICI+DCN exchange benches on")
     add_metrics_flags(p)
     args = p.parse_args(argv)
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
         jax.config.update("jax_num_cpu_devices", args.cpu)
-    if args.virtual_hosts:
-        import os
-
-        os.environ["STENCIL_VIRTUAL_HOSTS"] = str(args.virtual_hosts)
     start_metrics(args, "bench_exchange")
     qs = [int(t) for t in str(args.quantities).split(",") if t.strip()]
     if args.wire_ab:

@@ -651,12 +651,6 @@ def main(argv: Optional[list] = None) -> int:
                         "O(steps)")
     p.add_argument("--prefix", type=str, default="")
     p.add_argument("--cpu", type=int, default=0, help="force N virtual CPU devices")
-    p.add_argument("--virtual-hosts", type=int, default=0,
-                   help="emulate N hosts over the local device list "
-                        "(sets STENCIL_VIRTUAL_HOSTS: id-sorted "
-                        "contiguous groups) — opens the hierarchical "
-                        "ICI+DCN plan dimension to --autotune/--plan-db "
-                        "without a multi-process fabric")
     p.add_argument("--deep-halo", type=int, default=1,
                    help="realize radius-K halos so the fused loop advances K "
                         "steps per exchange on multi-block meshes "
@@ -684,8 +678,6 @@ def main(argv: Optional[list] = None) -> int:
         jax.config.update("jax_platforms", "cpu")
         # must happen before backend init to actually create N devices
         jax.config.update("jax_num_cpu_devices", args.cpu)
-    if args.virtual_hosts:
-        os.environ["STENCIL_VIRTUAL_HOSTS"] = str(args.virtual_hosts)
     rec = start_metrics(args, "jacobi3d")
     sentinel, status = make_live(args, rec, "jacobi3d")
 

@@ -217,17 +217,6 @@ def cmd_verify_plan(args) -> int:
             "failed": res["failed"] + pres["failed"],
             "skipped": res["skipped"] + pres["skipped"],
         }
-    if getattr(args, "hierarchy", 0):
-        hres = vp.run_hierarchy_sweep(
-            hosts=args.hierarchy, size=args.size, radius=args.radius,
-            partitions=_parse_partitions(args.partitions),
-            perturb_dcn=getattr(args, "perturb_dcn", 0), rec=rec)
-        res = {
-            "verdicts": res["verdicts"] + hres["verdicts"],
-            "checked": res["checked"] + hres["checked"],
-            "failed": res["failed"] + hres["failed"],
-            "skipped": res["skipped"] + hres["skipped"],
-        }
     if getattr(args, "time", 0):
         calibration = None
         if getattr(args, "time_db", ""):
@@ -389,16 +378,6 @@ def main(argv: Optional[list] = None) -> int:
                              "(the auditor must TRIP — CI's proof knob)")
         sp.add_argument("--perturb-wire", type=int, default=0)
         sp.add_argument("--perturb-dmas", type=int, default=0)
-        sp.add_argument("--hierarchy", type=int, default=0,
-                        help="ALSO audit the hierarchical (ICI+DCN) "
-                             "lowering on an N-virtual-host fabric: "
-                             "predicted DCN transfers/bytes vs the "
-                             "executed schedule, inner census pins "
-                             "unchanged, bit parity with the flat plan "
-                             "(the ISSUE-17 DCN gate; 0 = off)")
-        sp.add_argument("--perturb-dcn", type=int, default=0,
-                        help="offset the DCN transfer prediction (the "
-                             "hierarchy auditor must TRIP)")
         sp.add_argument("--placements", type=int, default=0,
                         help="ALSO audit N non-identity block placements "
                              "on the first partition: mesh device order "

@@ -52,14 +52,11 @@ def run(devices=None, size: int = 256, radius: int = 1) -> dict:
 def fabric_fingerprint(machine: Optional[Machine] = None,
                        devices=None) -> dict:
     """The scalar identity of the fabric a measurement ran on: process
-    count, host count, device count, platform, and the virtual-host
-    override if any. Attribution records (obs/attribution.emit_phase)
-    embed these as ``fabric_*`` extras so a fitted calibration row can
+    count, host count, device count and platform. Attribution records
+    (obs/attribution.emit_phase) embed these as ``fabric_*`` extras so a fitted calibration row can
     be traced to the fabric whose constants it encodes — a row fitted on
     an 8-device single-host CPU mesh must not silently price a 2-host
     TPU run."""
-    import os
-
     m = machine if machine is not None else Machine.detect(devices)
     platform = m.devices[0].platform if m.devices else "unknown"
     return {
@@ -67,7 +64,6 @@ def fabric_fingerprint(machine: Optional[Machine] = None,
         "hosts": int(m.num_nodes()),
         "devices": len(m.devices),
         "platform": str(platform),
-        "virtual_hosts": os.environ.get("STENCIL_VIRTUAL_HOSTS", ""),
     }
 
 

@@ -6,7 +6,8 @@ The operator's window into the plan/ subsystem (the analogue of
 - ``show``      list every tuned entry (config -> choice, provenance);
 - ``explain``   one config's DB entry + static cost ranking + the chosen
                 plan's ExchangePlan IR (phases, permute pairs, bytes);
-- ``prune``     drop entries by platform / source / age;
+- ``prune``     drop entries by platform / source / age, and every entry
+                that uses a retired choice key;
 - ``seed``      insert the RECORDED CPU-mesh verdicts (BASELINE.md
                 rounds 7/10) so fresh deployments replay them without
                 re-benching;
@@ -64,13 +65,15 @@ def _config_from(args) -> PlanConfig:
 
 def _entry_row(key: str, entry: dict) -> str:
     cfg = json.loads(key)
-    choice = PlanChoice.from_json(entry["choice"])
+    retired = plandb.retired_key(entry)
+    label = (f"retired({retired})" if retired is not None
+             else PlanChoice.from_json(entry["choice"]).label())
     g = cfg["grid"]
     qs = ",".join(f"{n}x{dt}" for dt, n in cfg["quantities"])
     measured = entry.get("measured_s")
     return (
         f"{g[0]}x{g[1]}x{g[2]},{qs},{cfg['ndev']},{cfg['platform']},"
-        f"{choice.label()},{entry.get('source')},"
+        f"{label},{entry.get('source')},"
         f"{'' if measured is None else f'{measured:.6f}'}"
     )
 
@@ -142,9 +145,6 @@ def cmd_explain(args) -> int:
             print(plan.describe())
             if args.placement:
                 _explain_placement(args, config, best, spec, mesh_dim)
-            if args.hierarchy:
-                _explain_hierarchy(args, config, best, spec, mesh_dim,
-                                   resident)
     return 0
 
 
@@ -201,99 +201,6 @@ def _explain_placement(args, config, choice, spec, mesh_dim) -> None:
           + (f" ({ident / placed:.3f}x better)" if placed < ident else
              " (identity-equivalent)" if placed == ident else
              " (WORSE than identity — re-tune)"))
-
-
-def _explain_hierarchy(args, config, choice, spec, mesh_dim,
-                       resident) -> None:
-    """The ``explain --hierarchy`` view: the two-level (ICI+DCN)
-    decomposition of the choice — one DCN phase per feasible outer
-    split (segment geometry, cross-host transfers, DCN wire bytes over
-    the inner plan) plus the two-level placement the QAP composes.
-    Jax-free: the fabric comes from ``--link-costs`` (file) and
-    ``--host-map`` (inline JSON device->host list); both default to the
-    uniform single-tier fabric, under which the solver returns identity
-    and the table says "flat-equivalent" instead of implying a win."""
-    import numpy as np
-
-    from ..geometry import Dim3
-    from ..plan.cost import (placement_cost, placement_wire_matrix,
-                             solve_two_level_placement)
-    from ..plan.ir import build_plan, validate_hierarchy
-
-    md = Dim3.of(mesh_dim)
-    n = md.flatten()
-    if choice.hierarchy is not None:
-        splits = [tuple(choice.hierarchy)]
-        print(f"hierarchy (tuned into the choice): "
-              f"{splits[0][1]} hosts on {splits[0][0]}")
-    else:
-        splits = [(ax, args.hosts) for ax in ("x", "y", "z")
-                  if validate_hierarchy((ax, args.hosts), md) is None]
-        if not splits:
-            print(f"hierarchy: no axis of mesh {tuple(md)} divides "
-                  f"into {args.hosts} host(s) — flat only")
-            return
-    if args.link_costs:
-        with open(args.link_costs) as fh:
-            link = np.asarray(json.load(fh), dtype=np.float64)
-        if link.shape != (n, n):
-            raise SystemExit(
-                f"--link-costs matrix is {link.shape}; the mesh has "
-                f"{n} positions")
-        fab = args.link_costs
-    else:
-        link = np.ones((n, n))
-        np.fill_diagonal(link, 0.0)
-        fab = "uniform default (pass --link-costs for a real fabric)"
-    host_map = None
-    if args.host_map:
-        host_map = [int(h) for h in json.loads(args.host_map)]
-        if len(host_map) != n:
-            raise SystemExit(
-                f"--host-map lists {len(host_map)} devices; the mesh "
-                f"has {n} positions")
-    itemsizes = config.itemsizes()
-    w = placement_wire_matrix(spec, md, per_cell_bytes=sum(itemsizes))
-    print(f"link costs: {fab}; host map: "
-          f"{host_map if host_map is not None else 'contiguous split'}")
-    for axis, h in splits:
-        plan = build_plan(spec, md, choice.method,
-                          choice.batch_quantities, resident,
-                          wire_dtype=args.wire_dtype or None,
-                          fused=choice.is_fused,
-                          persistent=choice.is_persistent,
-                          hierarchy=(axis, h))
-        dp = plan.dcn_phases[0]
-        nq = config.num_quantities
-        ngroups = len({dt for dt, _n in config.quantities})
-        print(f"outer split {axis} x {h} hosts (seg={dp.seg}, "
-              f"slice_devices={dp.slice_devices}):")
-        print(f"  DCN level: {plan.dcn_transfers_per_exchange(nq, ngroups)}"
-              f" cross-host copies/exchange, "
-              f"{plan.dcn_wire_bytes(itemsizes)} bytes (host-orchestrated"
-              f" — the census sees 0 ppermutes)")
-        print(f"  ICI level: {plan.collectives_per_exchange(nq, ngroups)}"
-              f" permutes/exchange, {plan.wire_bytes(itemsizes)} bytes "
-              f"(the flat plan's inner pins, unchanged)")
-        hp, comp = solve_two_level_placement(w, link, md, (axis, h),
-                                             host_map)
-        if hp is None and comp is None:
-            print("  two-level placement: identity — this fabric is "
-                  "flat-equivalent (the split changes the transport, "
-                  "not the halos or bytes; nothing to place)")
-            continue
-        print(f"  host placement (host slot -> host group): "
-              f"{list(hp) if hp is not None else 'identity'}")
-        print(f"  composed device placement: "
-              f"{list(comp) if comp is not None else 'identity'}")
-        ident = placement_cost(w, link)
-        placed = (placement_cost(w, link, comp) if comp is not None
-                  else ident)
-        print(f"  modeled wire cost: placed {placed:g} vs identity "
-              f"{ident:g}"
-              + (f" ({ident / placed:.3f}x better)" if placed < ident
-                 else " (identity-equivalent)" if placed == ident
-                 else " (WORSE than identity — re-tune)"))
 
 
 def cmd_prune(args) -> int:
@@ -542,25 +449,13 @@ def main(argv: Optional[list] = None) -> int:
                          "link-cost table the placement QAP minimized")
     sp.add_argument("--link-costs", default="",
                     help="JSON ndev x ndev link-cost matrix for "
-                         "--placement/--hierarchy (e.g. a dumped "
+                         "--placement (e.g. a dumped "
                          "parallel.topology.link_cost_matrix); default "
                          "uniform")
-    sp.add_argument("--hierarchy", action="store_true",
-                    help="also render the two-level (ICI+DCN) "
-                         "decomposition: per-split DCN transfers/bytes "
-                         "over the unchanged inner plan, plus the "
-                         "two-level placement (identity on a uniform "
-                         "fabric — rendered as flat-equivalent)")
-    sp.add_argument("--hosts", type=int, default=2,
-                    help="host count for --hierarchy what-if splits "
-                         "when the choice itself is flat (default 2)")
-    sp.add_argument("--host-map", default="",
-                    help="inline JSON device->host list for --hierarchy "
-                         "(e.g. '[0,1,0,1,0,1,0,1]' for an interleaved "
-                         "fabric); default: contiguous equal split")
     _add_config_flags(sp)
 
-    sp = sub.add_parser("prune", help="drop entries by filter")
+    sp = sub.add_parser("prune", help="drop entries by filter, and "
+                        "every entry that uses a retired key")
     sp.add_argument("--db", required=True)
     sp.add_argument("--platform", default="")
     sp.add_argument("--source", default="",

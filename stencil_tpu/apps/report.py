@@ -11,7 +11,7 @@ number of files/processes/runs, and reports:
   logical/moved exchange bytes) with cross-record consistency flagged;
 - gauges: per-name trimean (throughputs, timer buckets);
 - an optional vs-baseline delta against a JSON file of recorded numbers
-  (BASELINE.json / a bench.py payload / any flat {name: number} map).
+  (BASELINE.json / a bench payload / any flat {name: number} map).
 
 ``--validate`` makes it the CI schema gate: every line must parse and
 satisfy the telemetry schema, or the exit code is 1 (``--ledger`` extends
@@ -222,7 +222,7 @@ def tables(agg: dict, markdown: bool = False, p99: bool = False) -> str:
 
 def _flatten_numeric(obj, prefix: str = "") -> Dict[str, float]:
     """Dotted-path map of every numeric leaf in a baseline JSON — accepts
-    BASELINE.json, a bench.py payload ({"metric": ..., "value": ...}), or
+    BASELINE.json, a bench payload ({"metric": ..., "value": ...}), or
     any flat {name: number} map."""
     out: Dict[str, float] = {}
     if isinstance(obj, dict):

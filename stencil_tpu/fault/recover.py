@@ -233,8 +233,7 @@ def run_guarded(
             "rollbacks": {str(k): v for k, v in rollbacks.items()},
             "injections": injector.describe() if injector else [],
             "ckpt_dir": ckpt_dir,
-            "metrics": os.environ.get("STENCIL_METRICS_OUT")
-            or os.environ.get("STENCIL_BENCH_METRICS_OUT"),
+            "metrics": os.environ.get("STENCIL_METRICS_OUT"),
         }
         path = write_evidence(payload, evidence_dir or ckpt_dir)
         rec.meta("recover.aborted", reason=reason, step=int(fault.step),

@@ -179,7 +179,8 @@ class NodePartition:
     Reference: partition.hpp:120-256. First splits among ``nodes`` (hosts /
     TPU slices), then among ``gpus`` (chips per host), each cut taken on the
     axis with the smallest radius-weighted interface. On TPU the outer level
-    maps to DCN (multi-slice) and the inner level to ICI within a slice.
+    maps to the network between slices and the inner level to ICI within a
+    slice.
     """
 
     def __init__(self, size, radius: Radius, nodes: int, gpus: int):

@@ -10,15 +10,14 @@ Four parts, deliberately decoupled:
   measurement runs: supervises a child process on heartbeat
   + total-budget deadlines, distinguishes stall from crash, retries with
   backoff, archives logs. Pure stdlib, importable WITHOUT importing jax
-  (``bench.py``'s parent loads it by file path — the parent must never
-  touch a JAX backend).
+  (a supervising parent loads it by file path and must never touch a JAX
+  backend).
 - :mod:`stencil_tpu.obs.ledger` — the cross-run performance ledger:
   append-only schema-validated entries keyed by (metric, platform,
   config fingerprint, git rev, label), ingested from bench payloads and
   metrics-JSONL gauge trimeans; ``apps/perf_tool.py`` renders trends and
   runs the trimean ± MAD regression sentinel over it. Pure stdlib by the
-  same contract (``bench.py``'s parent appends the round payload when
-  ``STENCIL_BENCH_LEDGER`` is set).
+  same contract.
 - :mod:`stencil_tpu.obs.trace_export` — metrics JSONL ->
   Chrome-trace/Perfetto timeline JSON (one lane per (run, proc),
   fault/checkpoint instant markers); ``apps/report.py --trace-out``.

@@ -6,7 +6,7 @@ plans with ``plan/cost.score`` — a prediction in seconds — and
 (collectives, bytes, DMAs) against the realized IR; what nobody checks
 is the seconds themselves. This module closes that gap per run: each
 timed exchange phase (the ``trace_range`` names of the host spans —
-"stencil.exchange_loop", "exchange.hierarchical", …) becomes one ``plan.attrib.phase`` meta
+"stencil.exchange_loop", …) becomes one ``plan.attrib.phase`` meta
 record pairing the installed calibration's prediction with the measured
 wall time for the SAME (method, collectives, wire_bytes) point:
 

@@ -2,7 +2,7 @@
 
 ROADMAP item 1 calls the eventual hardware session "the TPU measurement
 ledger" — this module is the ledger as software. Every recorded
-measurement (a bench.py payload, a ``vs_baseline`` detail, a metrics-JSONL
+measurement (a bench payload, a ``vs_baseline`` detail, a metrics-JSONL
 gauge trimean) becomes one schema-validated JSON line in a ledger file,
 keyed by::
 
@@ -38,10 +38,8 @@ or future-versioned ledgers are REJECTED loudly (:class:`LedgerError`)
 (an entry whose key already exists is skipped, so re-running
 ``perf_tool ingest`` over the same files is safe).
 
-This module is PURE STDLIB by contract (the watchdog.py discipline):
-``bench.py``'s parent process — which must never import jax — loads it by
-file path to append the round payload when ``STENCIL_BENCH_LEDGER`` is
-set (``STENCIL_BENCH_LABEL`` names the round).
+This module is PURE STDLIB by contract (the watchdog.py discipline): a
+process that must never import jax can load it by file path.
 """
 
 from __future__ import annotations
@@ -65,10 +63,6 @@ LEDGER_KIND = "perf-ledger"
 SOURCES = ("bench", "legacy-bench", "legacy-multichip", "metrics", "manual",
            "serve")
 _TMP_PREFIX = ".tmp-"
-
-# bench.py contract: the parent appends its payload here after each round.
-ENV_LEDGER = "STENCIL_BENCH_LEDGER"
-ENV_LABEL = "STENCIL_BENCH_LABEL"
 
 
 class LedgerError(ValueError):
@@ -329,7 +323,7 @@ def entries_from_bench_payload(payload: dict, *, label: str,
                                rev: Optional[str] = None,
                                source: str = "bench",
                                t: Optional[float] = None) -> List[dict]:
-    """Map one bench.py payload (``{"metric", "value", "unit",
+    """Map one bench payload (``BENCH_r*.json``: ``{"metric", "value", "unit",
     "vs_baseline", "detail": {...}}``) into v1 entries: the headline
     metric, its ``vs_baseline`` ratio, and every numeric ``detail.*`` leg
     (nulls and strings skipped — a missing astaroth row is absence, not a

@@ -133,11 +133,6 @@ NAME_FIELDS = {
     "fused.interior": (),
     "fused.dma_wait": (),
     "fused.boundary": (),
-    # the hierarchical ICI+DCN level (parallel/hierarchy.py + the fused
-    # host loop): the window where cross-host DCN slabs are in flight
-    # behind the inner per-host programs — the outer-level analogue of
-    # fused.dma_wait
-    "fused.dcn": (),
     # the static-analysis vocabulary (stencil_tpu/analysis/): per-config
     # plan-auditor verdicts, the audit summaries the CI static gate
     # archives, and the lint summary — schema-gated like every other

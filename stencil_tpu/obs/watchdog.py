@@ -3,8 +3,7 @@
 The reference keeps its long benchmark campaigns alive with babysitting
 shell scripts; this repo's analogue is a measurement child whose backend
 init can stall or whose process can die mid-run (BENCH round-3 artifact,
-rc=1). ``bench.py`` supervises its accelerator attempts with this module
-(ROADMAP item 6's "revival watcher", VERDICT r5 "Next" #8).
+rc=1): ROADMAP item 6's "revival watcher", VERDICT r5 "Next" #8.
 
 Two layers:
 
@@ -26,9 +25,9 @@ Two layers:
   subprocess plumbing.
 
 This module is PURE STDLIB and must stay importable without the
-``stencil_tpu`` package: ``bench.py``'s parent process loads it by file
-path (``importlib``) precisely so the parent never imports jax — the
-wedge being supervised lives in JAX backend init.
+``stencil_tpu`` package: a supervising parent loads it by file path
+(``importlib``) precisely so the parent never imports jax — the wedge
+being supervised lives in JAX backend init.
 """
 
 from __future__ import annotations
@@ -152,9 +151,9 @@ def supervise(
     """Run ``cmd`` under the layered deadlines and return the Attempt.
 
     stdout/stderr go to temp FILES, not pipes: a child killed mid-write
-    loses pipe buffers, but file contents survive the kill (the round-4
-    bench.py lesson). ``heartbeat_timeout_s=None`` disables stall
-    detection (total budget only). ``first_beat_grace_s`` is the deadline
+    loses pipe buffers, but file contents survive the kill.
+    ``heartbeat_timeout_s=None`` disables stall detection (total budget
+    only). ``first_beat_grace_s`` is the deadline
     for the FIRST beat (interpreter + jax import are slow on small
     hosts); it defaults to ``max(heartbeat_timeout_s, 60)``.
 
@@ -162,14 +161,12 @@ def supervise(
     outcome (the fault/recovery ladder's rollback-exhausted abort) rather
     than a generic CRASH. On any non-OK outcome, when archiving is on and
     the child wrote a metrics JSONL (``metrics_path``, defaulting to the
-    ``STENCIL_METRICS_OUT`` / ``STENCIL_BENCH_METRICS_OUT`` entries of
-    the child's env), the metrics file is archived next to the log — a
-    post-mortem gets telemetry, not just stdout.
+    ``STENCIL_METRICS_OUT`` entry of the child's env), the metrics file
+    is archived next to the log — a post-mortem gets telemetry, not just stdout.
     """
     env = dict(env if env is not None else os.environ)
     if metrics_path is None:
-        metrics_path = (env.get("STENCIL_METRICS_OUT")
-                        or env.get("STENCIL_BENCH_METRICS_OUT"))
+        metrics_path = env.get("STENCIL_METRICS_OUT")
     hb_dir = None
     hb_path = None
     if heartbeat_timeout_s is not None:

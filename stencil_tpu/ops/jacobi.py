@@ -411,12 +411,8 @@ def _compile_jacobi_fused(ex: HaloExchange, iters,
     exteriors = exterior_regions(compute, interior)
     on_tpu = all(d.platform == "tpu" for d in ex.mesh.devices.flatten())
 
-    if (on_tpu and spec.is_uniform() and spec.aligned and not interpret
-            and not ex.hierarchical):
+    if on_tpu and spec.is_uniform() and spec.aligned and not interpret:
         # the mega-kernel path: exchange+sweep in ONE pallas_call
-        # (hierarchical plans fall through: the in-kernel exchange
-        # addresses the full ring, so the DCN level must ride the
-        # host-orchestrated schedule below)
         from .fused_stencil import make_fused_jacobi_kernel
 
         p = spec.padded()
@@ -478,16 +474,6 @@ def _compile_jacobi_fused(ex: HaloExchange, iters,
 
         rec = telemetry.get()
         emu = ex._fused_host_schedule
-        # hierarchical (ICI+DCN) plans: the fused inner messages wrap
-        # within each host segment (remote_emu._seg_wrap), and the
-        # cross-host slabs ride the sequential DCN schedule as a
-        # post-finish fix-up before the boundary compute
-        hier = ex._compiled if ex.hierarchical else None
-        dcn = (None if hier is None
-               else (lambda c2: hier.dcn_apply(c2, hier.dcn_start(c2))))
-        if hier is not None:
-            hier.last_transfer_count = 0
-            hier.last_transfer_bytes = 0
         t_interior = 0.0
         t_total = 0.0
         for _ in range(iters or 1):
@@ -495,7 +481,7 @@ def _compile_jacobi_fused(ex: HaloExchange, iters,
                 emu, curr,
                 interior=lambda: interior_fn(curr, nxt, sel),
                 boundary=lambda c2, o: boundary_fn(c2, o, sel),
-                rec=rec, dcn=dcn,
+                rec=rec,
             )
             t_interior += t_int
             t_total += t_tot
@@ -679,16 +665,6 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
         if getattr(ex, "fused", False):
             return _compile_jacobi_fused(ex, iters, temporal_k,
                                          multistep_rows, interpret)
-        return _compile_jacobi_remote(ex, iters, temporal_k, multistep_rows)
-    if ex.hierarchical:
-        # hierarchical AXIS_COMPOSED: the DCN level is host-orchestrated
-        # (parallel/hierarchy.py), so the cross-host slabs cannot inline
-        # into one compiled shard_map step program. The step serializes
-        # exactly like REMOTE_DMA — one hierarchical exchange dispatch
-        # (which overlaps the DCN copies behind the compiled DCN-axis
-        # phase internally) + one compiled collective-free sweep per
-        # step; bit-identical to the inline composed step because the
-        # sweep reads the same fully-exchanged state.
         return _compile_jacobi_remote(ex, iters, temporal_k, multistep_rows)
     assert min(r.y(-1), r.y(1), r.z(-1), r.z(1)) >= 1, (
         "jacobi needs face radius >= 1 on every side"

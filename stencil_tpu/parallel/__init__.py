@@ -3,7 +3,6 @@ from .mesh import (
     grid_mesh, mesh_dim,
 )
 from .exchange import Method, HaloExchange, direction_bytes
-from .hierarchy import HierarchicalExchange
 from .placement import (
     FixedAssignment, IntraNodeRandom, NodeAware, Placement, Trivial,
     comm_matrix,
@@ -18,7 +17,6 @@ __all__ = [
     "Boundary",
     "FixedAssignment",
     "HaloExchange",
-    "HierarchicalExchange",
     "IntraNodeRandom",
     "MESH_AXES",
     "Method",

@@ -6,16 +6,15 @@ NVLink/PCIe ancestor-ladder distances 0.1–7.0, bandwidth = 1/distance).
 
 On TPU the interconnect facts come from the device objects themselves:
 ``device.coords`` gives the chip's position in the physical ICI torus, so
-the distance between two chips is their torus hop count; chips in different
-processes (hosts) that still share the ICI keep their torus distance, while
-devices without coords (CPU/virtual) fall back to process locality. As in
+the distance between two chips of one process is their torus hop count;
+chips in different processes (hosts) take the remote cost, and devices
+without coords (CPU/virtual) fall back to process locality. As in
 the reference, bandwidth is modeled as 1/distance.
 """
 
 from __future__ import annotations
 
-import os
-from typing import List, Optional, Sequence
+from typing import Sequence
 
 import numpy as np
 
@@ -25,70 +24,11 @@ DIST_SELF = 0.1
 DIST_SAME_PROCESS = 1.0
 DIST_REMOTE = 7.0
 
-# The virtual-host knob: STENCIL_VIRTUAL_HOSTS=N partitions the single-
-# process device list into N emulated hosts whose crossing links take
-# the process-boundary cost — the in-process fabric the hierarchical
-# (ICI+DCN) exchange, two-level QAP, and 7x link-cost ladder are tested
-# on without Gloo CPU collectives.
-VIRTUAL_HOSTS_ENV = "STENCIL_VIRTUAL_HOSTS"
-
-
-def virtual_hosts() -> int:
-    """The ``STENCIL_VIRTUAL_HOSTS`` count (0 = knob off)."""
-    raw = os.environ.get(VIRTUAL_HOSTS_ENV, "").strip()
-    if not raw:
-        return 0
-    try:
-        n = int(raw)
-    except ValueError:
-        raise ValueError(
-            f"{VIRTUAL_HOSTS_ENV}={raw!r} is not an integer host count")
-    return max(0, n)
-
-
-def host_assignment(devices: Sequence,
-                    hosts: Optional[int] = None) -> List[int]:
-    """Per-device host index, aligned with ``devices``.
-
-    With N virtual hosts (``hosts``, defaulting to the env knob), the
-    id-SORTED device list splits into N contiguous segments —
-    deterministic and placement-invariant: permuting ``devices`` never
-    moves a device to a different emulated host, so a placement QAP
-    cannot game the fabric it is being priced against. With the knob
-    off, a device's host is its real ``process_index``."""
-    n = len(devices)
-    h = virtual_hosts() if hosts is None else int(hosts)
-    if h > 0:
-        if n % h:
-            raise ValueError(
-                f"{h} virtual hosts do not divide {n} devices")
-        order = sorted(range(n), key=lambda i: devices[i].id)
-        rank = {devices[i].id: r for r, i in enumerate(order)}
-        return [rank[d.id] * h // n for d in devices]
-    return [int(getattr(d, "process_index", 0)) for d in devices]
-
-
-def host_groups(devices: Sequence,
-                hosts: Optional[int] = None) -> List[list]:
-    """Devices grouped by host (ascending host index) — the outer level
-    of the hierarchical fabric (real processes, or the virtual-host
-    emulation)."""
-    assign = host_assignment(devices, hosts)
-    groups: dict = {}
-    for d, hidx in zip(devices, assign):
-        groups.setdefault(hidx, []).append(d)
-    return [groups[k] for k in sorted(groups)]
-
-
-def device_distance(a, b, same_host: Optional[bool] = None) -> float:
-    """Hop distance between two JAX devices. ``same_host`` overrides
-    the host-locality verdict (the virtual-host fabric: a crossing link
-    takes the process-boundary cost even on a single-process mesh);
-    ``None`` falls back to the real ``process_index`` comparison."""
+def device_distance(a, b) -> float:
+    """Hop distance between two JAX devices."""
     if a == b:
         return DIST_SELF
-    if same_host is False:
-        # crossing the (possibly emulated) host fabric: the DCN link
+    if getattr(a, "process_index", 0) != getattr(b, "process_index", 0):
         return DIST_REMOTE
     ca = getattr(a, "coords", None)
     cb = getattr(b, "coords", None)
@@ -98,19 +38,15 @@ def device_distance(a, b, same_host: Optional[bool] = None) -> float:
         hops = sum(abs(int(x) - int(y)) for x, y in zip(ca, cb))
         if hops > 0:
             return float(hops)
-    if same_host is None:
-        same_host = a.process_index == b.process_index
-    return DIST_SAME_PROCESS if same_host else DIST_REMOTE
+    return DIST_SAME_PROCESS
 
 
 def distance_matrix(devices: Sequence) -> np.ndarray:
     n = len(devices)
-    assign = host_assignment(devices)
     m = np.zeros((n, n), dtype=np.float64)
     for i, a in enumerate(devices):
         for j, b in enumerate(devices):
-            m[i, j] = device_distance(a, b,
-                                      same_host=(assign[i] == assign[j]))
+            m[i, j] = device_distance(a, b)
     return m
 
 

@@ -9,7 +9,8 @@ After it returns, ``jax.devices()`` is the *global* device list and the
 whole stack — NodePartition's host-level outer split (api.realize),
 process-grouped placement (placement.IntraNodeRandom), cross-process
 ``ppermute``s in the exchange — operates over all hosts; XLA routes the
-collectives over ICI within a slice and DCN/Gloo across hosts.
+collectives over ICI within a slice and the data-centre network (Gloo on
+CPU) across hosts.
 
 Launch styles:
 - TPU pods / GKE: ``init_distributed()`` with no arguments — JAX picks up

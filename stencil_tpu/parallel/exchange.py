@@ -141,8 +141,7 @@ class HaloExchange:
 
     def __init__(self, spec: GridSpec, mesh: Mesh, method: Method = Method.AXIS_COMPOSED,
                  batch_quantities: bool = True, wire_dtype=None,
-                 fused: bool = False, persistent: bool = False,
-                 hierarchy=None):
+                 fused: bool = False, persistent: bool = False):
         md = mesh_dim(mesh)
         # oversubscription (reference: dd.set_gpus({0,0}), stencil.hpp:154,
         # test_exchange.cu:52): more partition blocks than devices — the
@@ -173,26 +172,6 @@ class HaloExchange:
         self.mesh = mesh
         self.method = method
         self.batch_quantities = bool(batch_quantities)
-        # hierarchical (ICI+DCN) decomposition (ROADMAP #3): (axis,
-        # hosts) of the outer cross-host split, or None for the flat
-        # single-level exchange. Validated eagerly — the plan builder is
-        # the shape authority, and the method restriction is loud here
-        # so a bad PlanChoice fails at construction, not first call.
-        if hierarchy is not None:
-            from ..plan.ir import validate_hierarchy
-
-            err = validate_hierarchy(hierarchy, md)
-            if err is not None:
-                raise ValueError(err)
-            hierarchy = (str(hierarchy[0]), int(hierarchy[1]))
-            if hierarchy[1] > 1 and method not in (
-                    Method.AXIS_COMPOSED, Method.REMOTE_DMA):
-                raise ValueError(
-                    "hierarchical decomposition needs a composed-"
-                    "geometry inner method (axis-composed/remote-dma); "
-                    f"got {method}"
-                )
-        self.hierarchy = hierarchy
         # the fused compute+exchange variant (ROADMAP #5): still
         # REMOTE_DMA — kernel-initiated copies, zero ppermutes — but the
         # transport is the concurrent per-direction schedule the fused
@@ -267,13 +246,6 @@ class HaloExchange:
         """More partition blocks than devices on at least one axis."""
         return self.resident != Dim3(1, 1, 1)
 
-    @property
-    def hierarchical(self) -> bool:
-        """True when an outer DCN split with more than one host is set
-        — the compiled exchange is then the two-level transport
-        (parallel/hierarchy.HierarchicalExchange)."""
-        return self.hierarchy is not None and self.hierarchy[1] > 1
-
     def _on_tpu(self) -> bool:
         return all(d.platform == "tpu" for d in self.mesh.devices.flatten())
 
@@ -286,7 +258,7 @@ class HaloExchange:
             self.spec, mesh_dim(self.mesh), self.method,
             batch_quantities=self.batch_quantities, resident=self.resident,
             wire_dtype=self.wire_dtype, fused=self.fused,
-            persistent=self.persistent, hierarchy=self.hierarchy,
+            persistent=self.persistent,
         )
 
     # -- public API ----------------------------------------------------------
@@ -357,7 +329,7 @@ class HaloExchange:
                 )
         return hi_cols, lo_cols
 
-    def exchange_blocks(self, state, axes=None):
+    def exchange_blocks(self, state):
         """Per-block exchange of a whole quantity dict inside ``shard_map``.
 
         Unlike mapping :meth:`exchange_block` per quantity, the dict is
@@ -370,23 +342,14 @@ class HaloExchange:
         axis phase instead of one per quantity. Non-fp32 groups on
         self-wrap axes take a packed slab fill: one fused slice/update
         pair per phase for the group (the fp64 analogue of the fused
-        fills; ROADMAP #5).
-
-        ``axes`` (AXIS_* names) restricts the composed method to a
-        subset of axis phases — the hierarchical transport's A/B split
-        (DCN-axis phase overlapped behind the started cross-host
-        copies, the other phases after the apply). AXIS_COMPOSED only,
-        like :meth:`exchange_block`'s ``axes``."""
+        fills; ROADMAP #5)."""
         if self.method in (Method.AUTO_SPMD, Method.REMOTE_DMA):
             raise RuntimeError(
                 f"Method.{self.method.name} has no per-block exchange body "
                 "(see exchange_block); use __call__/make_loop instead"
             )
-        if axes is not None and self.method != Method.AXIS_COMPOSED:
-            raise ValueError("axis subsetting requires AXIS_COMPOSED")
         if not isinstance(state, dict):
-            return jax.tree.map(
-                lambda b: self.exchange_block(b, axes=axes), state)
+            return jax.tree.map(self.exchange_block, state)
         from ..ops.halo_fill import dtype_groups
 
         groups = dtype_groups(state)
@@ -399,9 +362,9 @@ class HaloExchange:
                 for k, b in zip(keys, blocks):
                     out[k] = b
             return out
-        return self._composed_quantities(state, groups, axes)
+        return self._composed_quantities(state, groups)
 
-    def _composed_quantities(self, state, groups, axes=None):
+    def _composed_quantities(self, state, groups):
         """AXIS_COMPOSED over a quantity dict, one same-dtype group at a
         time per axis phase: fused Pallas fills for fp32 self-wrap axes,
         packed-carrier phases (one ppermute pair per phase per group)
@@ -414,8 +377,6 @@ class HaloExchange:
         out = dict(state)
         for phase in self.plan.axis_phases:
             if not phase.active:
-                continue
-            if axes is not None and phase.axis not in axes:
                 continue
             name = phase.axis
             for dt, keys in groups:
@@ -508,13 +469,6 @@ class HaloExchange:
 
     @cached_property
     def _compiled(self):
-        if self.hierarchical:
-            # the two-level (ICI+DCN) transport: inner programs stay
-            # the lowerings below, the cross-host boundary slabs ride
-            # host-orchestrated copies overlapped behind them
-            from .hierarchy import HierarchicalExchange
-
-            return HierarchicalExchange(self)
         if self.method == Method.REMOTE_DMA:
             return self._remote
         if self.method == Method.AUTO_SPMD:
@@ -551,9 +505,6 @@ class HaloExchange:
             # flight-recorder bucket; jax.profiler sees the same range)
             with timer.timed("exchange.build"), \
                     timer.trace_range(f"exchange.{self.method.value}.build"):
-                if self.hierarchical:
-                    cache[iters] = self._compiled.make_loop(iters)
-                    return cache[iters]
                 if self.method == Method.REMOTE_DMA:
                     cache[iters] = self._remote.make_loop(iters)
                     return cache[iters]
@@ -595,12 +546,6 @@ class HaloExchange:
 
         with timer.timed("exchange.census"), \
                 timer.trace_range(f"exchange.{self.method.value}.census"):
-            if self.hierarchical:
-                # the two-level transport censuses every compiled piece
-                # (inner programs + DCN take/updates) — the inner
-                # permute count/bytes pin is unchanged, the DCN level
-                # contributes zero collectives
-                return self._compiled.collective_census(state)
             if self.method == Method.REMOTE_DMA:
                 # no single jitted program exists: the transport censuses
                 # EVERY compiled piece of one exchange (pack/update jits

@@ -1,6 +1,6 @@
 """Placement strategies: which device hosts which subdomain.
 
-TPU-native re-design of the reference's Placement hierarchy
+TPU-native re-design of the reference's Placement classes
 (reference: include/stencil/partition.hpp:264-289 abstract, :291-445
 Trivial, :525-831 NodeAware QAP placement;
 src/placement_intranoderandom.cpp IntraNodeRandom ablation baseline).

@@ -92,20 +92,6 @@ class RemoteDmaEmulation:
         c = phase.resident
         return tuple(int(phase.sizes[i * c + j]) for j in range(c))
 
-    def _seg_wrap(self, axis: str, i: int, step: int, m: int) -> int:
-        """Ring neighbor ``i + step`` along ``axis`` — wrapping within
-        the host segment instead of the full ring when the plan is
-        hierarchical on that axis (the emulated-DMA twin of the plan's
-        ``_segmented_ring_pairs``): the inner transport then never
-        reaches across a host, and the boundary slabs ride the DCN
-        level (parallel/hierarchy.py) instead."""
-        h = self.plan.hierarchy
-        if h is not None and h[1] > 1 and h[0] == axis:
-            seg = m // h[1]
-            base = (i // seg) * seg
-            return base + (i - base + step) % seg
-        return (i + step) % m
-
     def _take_fn(self, phase, sizes, shard_shape, dtype, nq, wire):
         """take(*shards) -> (hi_carrier?, lo_carrier?): the boundary
         slabs this device sends (+axis: its LAST resident's top rm slab;
@@ -255,10 +241,9 @@ class RemoteDmaEmulation:
                 out = list(sent[coords])
                 if phase.rm:
                     # +axis send: this device's top slab fills the low
-                    # halo of ring neighbor i+1 (the composed fwd pair;
-                    # host-segmented when the plan is hierarchical)
+                    # halo of ring neighbor i+1 (the composed fwd pair)
                     dst = list(coords)
-                    dst[axis_of] = self._seg_wrap(phase.axis, i, 1, m)
+                    dst[axis_of] = (i + 1) % m
                     dst = tuple(dst)
                     carrier = out.pop(0)
                     if dst != coords:
@@ -267,7 +252,7 @@ class RemoteDmaEmulation:
                     recv[dst].insert(0, ("lo", carrier))
                 if phase.rp:
                     dst = list(coords)
-                    dst[axis_of] = self._seg_wrap(phase.axis, i, -1, m)
+                    dst[axis_of] = (i - 1) % m
                     dst = tuple(dst)
                     carrier = out.pop(0)
                     if dst != coords:
@@ -478,14 +463,8 @@ class FusedRemoteEmulation(RemoteDmaEmulation):
                 iz, iy, ix = coords
                 for pi, ph in enumerate(phases):
                     dx, dy, dz = ph.direction
-                    # host-segmented on the DCN axis under a hierarchy:
-                    # no fused message crosses a host — the boundary
-                    # slabs ride the DCN level, whose full-extent apply
-                    # overwrites every garbage wrap cell (face+edge+
-                    # corner, all confined to the DCN-axis halo)
-                    dst = (self._seg_wrap("z", iz, dz, mz),
-                           self._seg_wrap("y", iy, dy, my),
-                           self._seg_wrap("x", ix, dx, mx))
+                    dst = ((iz + dz) % mz, (iy + dy) % my,
+                           (ix + dx) % mx)
                     car = carriers[pi]
                     if dst != coords:
                         car = jax.device_put(car, mdevs[dst])
@@ -539,7 +518,7 @@ class FusedRemoteEmulation(RemoteDmaEmulation):
         return self.fused_finish(pending)
 
 
-def run_fused_substep(emu, state, interior, boundary, rec=None, dcn=None):
+def run_fused_substep(emu, state, interior, boundary, rec=None):
     """One host-orchestrated fused substep — THE shared overlap
     protocol of the fused step loops (ops/jacobi._compile_jacobi_fused,
     astaroth/integrate.make_fused_astaroth_loop): start every emulated
@@ -552,14 +531,7 @@ def run_fused_substep(emu, state, interior, boundary, rec=None, dcn=None):
     (exchanged_state, out)`` returns the finished output. Both must be
     collective-free compiled programs. Returns ``(exchanged_state, out,
     interior_seconds, total_seconds)`` — the caller accumulates the two
-    times into its ``fused.overlap_fraction`` gauge.
-
-    ``dcn`` is the hierarchical fix-up (the sequential DCN schedule of
-    parallel/hierarchy.py): applied to the exchanged state AFTER
-    ``fused_finish`` — the fused messages are exact-extent, so the
-    cross-host slabs must be extracted post-inner, when sender
-    orthogonal halos are valid — and BEFORE the boundary compute reads
-    the host-boundary halos."""
+    times into its ``fused.overlap_fraction`` gauge."""
     import time as _time
 
     from ..obs import telemetry
@@ -576,9 +548,6 @@ def run_fused_substep(emu, state, interior, boundary, rec=None, dcn=None):
     with rec.span("fused.dma_wait", phase="exchange", variant="fused"):
         emu.fused_wait(pending)
     cur2 = emu.fused_finish(pending)
-    if dcn is not None:
-        with rec.span("fused.dcn", phase="exchange", variant="fused"):
-            cur2 = dcn(cur2)
     with rec.span("fused.boundary", phase="compute", variant="fused"):
         out = boundary(cur2, out)
         jax.block_until_ready(out)

@@ -18,8 +18,7 @@ from dataclasses import dataclass
 from typing import Sequence
 
 from ..geometry import Dim3
-from .device_topo import (distance_matrix, host_assignment,  # noqa: F401
-                          host_groups, virtual_hosts)
+from .device_topo import distance_matrix
 
 
 class Boundary(enum.Enum):
@@ -61,9 +60,5 @@ def link_cost_matrix(devices: Sequence):
     off-diagonal, which the plan search recognizes
     (``plan.cost.uniform_link_costs``) and prices every placement
     identically — identity wins, by design: placement only pays off where
-    the fabric is actually non-uniform. ``STENCIL_VIRTUAL_HOSTS=N``
-    (see :func:`~.device_topo.host_assignment`) makes the single-process
-    mesh non-uniform on purpose: crossing links between the N emulated
-    hosts take the 7.0 process-boundary cost, giving the two-level QAP
-    and the hierarchical plan search a real ladder to price in-process."""
+    the fabric is actually non-uniform."""
     return distance_matrix(devices)

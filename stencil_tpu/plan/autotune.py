@@ -116,22 +116,6 @@ def autotune(
         from ..parallel.topology import link_cost_matrix
 
         link_costs = link_cost_matrix(devices)
-    # host structure of the live fabric (real processes, or the
-    # STENCIL_VIRTUAL_HOSTS emulation): >1 host opens the hierarchical
-    # (ICI+DCN) half of the candidate space — outer splits along each
-    # dividing axis, placed by the two-level QAP
-    hierarchy_hosts = None
-    host_map = None
-    if devices is not None:
-        from ..parallel.device_topo import host_assignment
-
-        host_map = [int(h) for h in host_assignment(devices)]
-        nhosts = len(set(host_map))
-        if nhosts > 1:
-            hierarchy_hosts = nhosts
-        else:
-            host_map = None
-
     db = None
     db_ok = False
     if db_path:
@@ -174,9 +158,7 @@ def autotune(
     with rec.span("plan.autotune", phase="plan"):
         candidates = enumerate_candidates(config, methods=methods,
                                           ks=ks, variants=variants,
-                                          link_costs=link_costs,
-                                          hierarchy_hosts=hierarchy_hosts,
-                                          host_map=host_map)
+                                          link_costs=link_costs)
         ranked = rank(config, candidates, calibration,
                       link_costs=link_costs)
         if not ranked:

@@ -50,7 +50,6 @@ from .ir import (
     PlanChoice,
     PlanConfig,
     build_plan,
-    validate_hierarchy,
     validate_placement,
 )
 
@@ -117,24 +116,6 @@ DEFAULT_CALIBRATION: Dict[str, object] = {
         "cpu_dispatch_s": 2.0e-4,
         "provenance": "modeled, pending item-1 TPU recalibration",
     },
-    # The outer (cross-host DCN) level of a hierarchical plan: boundary
-    # slabs leaving the per-host ICI mesh pay a per-transfer latency and
-    # a bandwidth FAR below the ICI's — the defining economics of the
-    # hierarchy (the whole point of hiding DCN wire behind intra-host
-    # work). transfer_latency_s is the modeled per-copy DCN issue+rtt
-    # floor on a pod; cpu_emulation_overhead_s prices the virtual-host
-    # emulation honestly (each emulated DCN copy is a host-orchestrated
-    # device_put round-trip, like remote_dma's). Provenance: MODELED —
-    # no DCN measurement exists in this repo yet; scripts/probe_dcn.py
-    # is staged for the item-1 hardware session that flips this row to
-    # measured.
-    "dcn": {
-        "transfer_latency_s": 1.0e-3,
-        "wire_bytes_per_s": 2.5e7,
-        "cpu_emulation_overhead_s": 4.0e-3,
-        "provenance": "modeled, pending item-1 hardware "
-                      "(scripts/probe_dcn.py)",
-    },
 }
 
 
@@ -149,8 +130,6 @@ class PlanCost:
     local_bytes: int        # estimated local slab bytes per exchange
     compute_overhead_s: float  # multistep redundant-compute price per step
     dmas: int = 0           # kernel-initiated async copies (REMOTE_DMA only)
-    dcn_transfers: int = 0  # cross-host copies (hierarchical plans only)
-    dcn_wire_bytes: int = 0  # bytes crossing the DCN per exchange
 
     def to_json(self) -> dict:
         return {
@@ -161,8 +140,6 @@ class PlanCost:
             "local_bytes": self.local_bytes,
             "compute_overhead_s": self.compute_overhead_s,
             "dmas": self.dmas,
-            "dcn_transfers": self.dcn_transfers,
-            "dcn_wire_bytes": self.dcn_wire_bytes,
         }
 
 
@@ -328,141 +305,6 @@ def solve_placement(w, link_costs,
     return tuple(f)
 
 
-# -- two-level (hierarchical) placement: blocks->hosts, then blocks->chips ----
-#
-# The reference's NodeAware places at two granularities: subdomains to
-# NODES by the rank-boundary-penalized comm matrix, then to GPUs within
-# each node (partition.hpp:525-831). Here the outer level aggregates the
-# mesh-slot wire matrix to host slots (the hierarchy's contiguous
-# DCN-axis segments) and prices it against the host-to-host link matrix
-# (mean cross-group device distance — 7x on the process/virtual-host
-# ladder); the inner level re-runs the same QAP per host over the
-# intra-host sub-matrices. The composed flat device permutation is what
-# PlanChoice.placement carries, so realize() applies it through the
-# existing single-level machinery unchanged.
-
-
-def hierarchy_slot_hosts(mesh_dim, hierarchy) -> List[int]:
-    """Host slot of each mesh position (row-major x-fastest slot order,
-    matching :func:`placement_wire_matrix`): a position's host slot is
-    its DCN-axis coordinate divided by the segment length."""
-    axis, hosts = str(hierarchy[0]), int(hierarchy[1])
-    md = Dim3.of(mesh_dim)
-    n_ax = {"x": md.x, "y": md.y, "z": md.z}[axis]
-    if n_ax % hosts:
-        raise ValueError(
-            f"{hosts} hosts do not divide the {axis} mesh extent {n_ax}")
-    seg = n_ax // hosts
-    out = []
-    for z in range(md.z):
-        for y in range(md.y):
-            for x in range(md.x):
-                c = {"x": x, "y": y, "z": z}[axis]
-                out.append(c // seg)
-    return out
-
-
-def host_wire_matrix(w, mesh_dim, hierarchy):
-    """The outer QAP's H x H wire matrix: every cross-host-slot entry of
-    the mesh-slot wire matrix aggregated to its (sender host slot,
-    receiver host slot) pair; intra-host wire is excluded — it rides the
-    ICI whichever host serves the slot, so the outer assignment cannot
-    change its cost."""
-    import numpy as np
-
-    sh = hierarchy_slot_hosts(mesh_dim, hierarchy)
-    hosts = int(hierarchy[1])
-    w = np.asarray(w, dtype=np.float64)
-    out = np.zeros((hosts, hosts), dtype=np.float64)
-    for a in range(w.shape[0]):
-        for b in range(w.shape[1]):
-            if sh[a] != sh[b]:
-                out[sh[a], sh[b]] += w[a, b]
-    return out
-
-
-def host_link_matrix(link_costs, hosts: int, host_map=None):
-    """The outer QAP's H x H link-cost matrix: mean pairwise device
-    distance between host groups (0 diagonal). ``host_map`` gives each
-    device index's host; omitted, the contiguous equal split of the
-    device list is assumed — the id-sorted layout both the virtual-host
-    fabric (device_topo.host_assignment) and a process-contiguous
-    ``jax.devices()`` produce."""
-    import numpy as np
-
-    d = np.asarray(link_costs, dtype=np.float64)
-    n = d.shape[0]
-    if n % hosts:
-        raise ValueError(f"{hosts} hosts do not divide {n} devices")
-    if host_map is None:
-        g = n // hosts
-        host_map = [i // g for i in range(n)]
-    idx = {h: [i for i in range(n) if host_map[i] == h]
-           for h in range(hosts)}
-    out = np.zeros((hosts, hosts), dtype=np.float64)
-    for p in range(hosts):
-        for q in range(hosts):
-            if p == q or not idx[p] or not idx[q]:
-                continue
-            out[p, q] = float(np.mean(
-                [d[i, j] for i in idx[p] for j in idx[q]]))
-    return out
-
-
-def solve_two_level_placement(w, link_costs, mesh_dim, hierarchy,
-                              host_map=None):
-    """The hierarchical ``NodeAware``: ``(host_placement, placement)``.
-
-    Outer: blocks->hosts over (:func:`host_wire_matrix`,
-    :func:`host_link_matrix`) — ``host_placement[s]`` is the host group
-    serving host slot s (None = identity, which a uniform fabric solves
-    to by design). Inner: blocks->chips per host slot, the same QAP over
-    the intra-host sub-matrices. ``placement`` is the composed flat
-    device permutation (None when the composition is identity) — the
-    form realize() already applies. ``host_map`` as in
-    :func:`host_link_matrix`; a scrambled map (devices interleaved
-    across hosts) makes even the identity outer assignment compose to a
-    non-identity flat permutation, because each host slot's positions
-    must land on ITS host's devices — the alignment the hierarchy's
-    lowering requires."""
-    import numpy as np
-
-    hosts = int(hierarchy[1])
-    w = np.asarray(w, dtype=np.float64)
-    d = np.asarray(link_costs, dtype=np.float64)
-    n = w.shape[0]
-    if n % hosts:
-        return None, None
-    g = n // hosts
-    if host_map is None:
-        host_map = [i // g for i in range(n)]
-    groups = {h: [i for i in range(n) if host_map[i] == h]
-              for h in sorted(set(host_map))}
-    if len(groups) != hosts or any(len(v) != g for v in groups.values()):
-        return None, None  # uneven or mis-counted fabric: no hierarchy
-    order = sorted(groups)
-    sh = hierarchy_slot_hosts(mesh_dim, hierarchy)
-    wh = host_wire_matrix(w, mesh_dim, hierarchy)
-    dh = host_link_matrix(link_costs, hosts,
-                          host_map=[order.index(h) for h in host_map])
-    outer = solve_placement(wh, dh)
-    hp = list(outer) if outer is not None else list(range(hosts))
-    placement = [0] * n
-    for hs in range(hosts):
-        slots = [s for s in range(n) if sh[s] == hs]
-        devs = groups[order[hp[hs]]]
-        wsub = w[np.ix_(slots, slots)]
-        dsub = d[np.ix_(devs, devs)]
-        f = solve_placement(wsub, dsub)
-        fl = list(f) if f is not None else list(range(len(slots)))
-        for r, s in enumerate(slots):
-            placement[s] = devs[fl[r]]
-    host_placement = tuple(hp) if outer is not None else None
-    if placement == list(range(n)):
-        return host_placement, None
-    return host_placement, tuple(placement)
-
-
 def feasible(config: PlanConfig, choice: PlanChoice) -> Optional[Tuple]:
     """(spec, mesh_dim, resident) when the candidate can realize on this
     config, else None. Mirrors realize()'s constraints exactly: the
@@ -532,22 +374,6 @@ def feasible(config: PlanConfig, choice: PlanChoice) -> Optional[Tuple]:
     if (choice.kernel_variant == PERSISTENT_VARIANT
             and resident != Dim3(1, 1, 1)):
         return None  # the persistent kernel is single-resident too
-    if choice.hierarchy is not None:
-        # the hierarchy's inner program is composed-geometry only
-        # (build_plan rejects direct26/auto-spmd loudly; here the
-        # candidate is just infeasible), the hosts must divide the
-        # DCN-axis mesh extent, and a host_placement must permute the
-        # hierarchy's host slots
-        if choice.method not in (AXIS_COMPOSED, REMOTE_DMA):
-            return None
-        if validate_hierarchy(choice.hierarchy, mesh_dim) is not None:
-            return None
-        if (choice.host_placement is not None
-                and validate_placement(choice.host_placement,
-                                       int(choice.hierarchy[1])) is not None):
-            return None
-    elif choice.host_placement is not None:
-        return None  # a host placement without a hierarchy is meaningless
     return spec, mesh_dim, resident
 
 
@@ -588,7 +414,7 @@ def score(config: PlanConfig, choice: PlanChoice,
     plan = build_plan(spec, mesh_dim, choice.method,
                       batch_quantities=choice.batch_quantities,
                       resident=resident, fused=fused,
-                      persistent=persistent, hierarchy=choice.hierarchy)
+                      persistent=persistent)
     itemsizes = config.itemsizes()
     nq = config.num_quantities
     ngroups = config.dtype_group_count
@@ -682,39 +508,6 @@ def score(config: PlanConfig, choice: PlanChoice,
             + wire / cal["wire_bytes_per_s"] * pratio
             + local / cal["local_bytes_per_s"]
         )
-    # the outer (DCN) level of a hierarchical plan: boundary slabs cross
-    # hosts on their own calibration row (latency + bandwidth >> ICI).
-    # With the composed inner program the hierarchy's lowering schedules
-    # the DCN copies boundary-first and runs the intra-host phases while
-    # they fly — the overlap credit prices the exchange at
-    # max(inner, outer); the sequential schedule (REMOTE_DMA-family
-    # inner, whose program is an opaque host-orchestrated loop) pays the
-    # sum. A host_placement scales the DCN byte term by its outer QAP
-    # cost ratio, mirroring the inner pratio.
-    dcn_transfers = plan.dcn_transfers_per_exchange(nq, ngroups)
-    dcn_bytes = plan.dcn_wire_bytes(itemsizes,
-                                    floating=config.floating_flags())
-    if dcn_transfers:
-        dc = cal["dcn"]
-        per_transfer = (dc["transfer_latency_s"] if config.platform == "tpu"
-                        else dc["cpu_emulation_overhead_s"])
-        hratio = 1.0
-        if (link_costs is not None and choice.host_placement is not None
-                and dcn_bytes):
-            w = _cached_wire_matrix(spec, mesh_dim, config,
-                                    choice.multistep_k)
-            wh = host_wire_matrix(w, mesh_dim, choice.hierarchy)
-            dh = host_link_matrix(link_costs, int(choice.hierarchy[1]))
-            base = placement_cost(wh, dh)
-            if base > 0:
-                hratio = placement_cost(wh, dh,
-                                        choice.host_placement) / base
-        outer_s = (dcn_transfers * per_transfer
-                   + dcn_bytes / dc["wire_bytes_per_s"] * hratio)
-        if choice.method == AXIS_COMPOSED:
-            exchange_s = max(exchange_s, outer_s)
-        else:
-            exchange_s += outer_s
     k = choice.multistep_k
     compute_overhead_s = 0.0
     if k > 1:
@@ -735,7 +528,6 @@ def score(config: PlanConfig, choice: PlanChoice,
         total_s=total, exchange_s=exchange_s, collectives=collectives,
         wire_bytes=wire, local_bytes=local,
         compute_overhead_s=compute_overhead_s, dmas=dmas,
-        dcn_transfers=dcn_transfers, dcn_wire_bytes=dcn_bytes,
     )
 
 
@@ -774,8 +566,6 @@ def enumerate_candidates(
     variants: Iterable[Optional[str]] = DEFAULT_VARIANTS,
     oversubscribe: Sequence[int] = (1,),
     link_costs=None,
-    hierarchy_hosts: Optional[int] = None,
-    host_map: Optional[Sequence[int]] = None,
 ) -> List[PlanChoice]:
     """The search space: partition shape x method x quantity batching x
     temporal depth k x kernel variant x block placement. Batching only
@@ -798,19 +588,7 @@ def enumerate_candidates(
     placed candidate beside identity, never the factorial permutation
     space; the reference's NodeAware does exactly this). Uniform links
     solve to identity and add nothing, so the CPU-mesh search space is
-    byte-identical to the pre-placement one.
-
-    With ``hierarchy_hosts`` > 1 (the fabric has host structure — real
-    processes or the STENCIL_VIRTUAL_HOSTS emulation), every partition
-    additionally branches on the hierarchical decomposition: for each
-    mesh axis the host count divides, an ``(axis, hosts)`` outer split
-    beside the flat plan — so the search prices outer-axis choice x
-    inner partition JOINTLY — carrying the two-level QAP's
-    ``host_placement`` and composed ``placement``
-    (:func:`solve_two_level_placement`; ``host_map`` names each device
-    index's host for the link aggregation, contiguous split when
-    omitted). Composed-geometry inner methods only (the hierarchy has
-    no direct26/auto-spmd lowering)."""
+    byte-identical to the pre-placement one."""
     if config.num_quantities <= 1:
         batch_options = (True,)
     default_variants = variants is DEFAULT_VARIANTS
@@ -869,36 +647,6 @@ def enumerate_candidates(
                                 batch_quantities=batch, multistep_k=k,
                                 kernel_variant=variant,
                                 placement=placement,
-                            ))
-        if not hierarchy_hosts or hierarchy_hosts <= 1:
-            continue
-        feas = part_feas(part)
-        if feas is None:
-            continue
-        spec, mesh_dim, resident = feas
-        mdm = {"x": mesh_dim.x, "y": mesh_dim.y, "z": mesh_dim.z}
-        for axis in ("x", "y", "z"):
-            if mdm[axis] % hierarchy_hosts:
-                continue
-            hier = (axis, int(hierarchy_hosts))
-            hp: Optional[Tuple[int, ...]] = None
-            hpl: Optional[Tuple[int, ...]] = None
-            if link_costs is not None and resident == Dim3(1, 1, 1):
-                w = _cached_wire_matrix(spec, mesh_dim, config, 1)
-                hp, hpl = solve_two_level_placement(
-                    w, link_costs, mesh_dim, hier, host_map=host_map)
-            for method in methods:
-                if method not in (AXIS_COMPOSED, REMOTE_DMA):
-                    continue
-                for batch in batch_options:
-                    for k in ks:
-                        for variant in variant_list(method):
-                            out.append(PlanChoice(
-                                partition=part, method=method,
-                                batch_quantities=batch, multistep_k=k,
-                                kernel_variant=variant,
-                                placement=hpl, hierarchy=hier,
-                                host_placement=hp,
                             ))
     return out
 

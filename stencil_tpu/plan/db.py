@@ -37,7 +37,10 @@ import os
 import time
 from typing import List, Optional
 
-from .ir import METHODS, PlanChoice, PlanConfig, validate_placement
+from ..utils import logging as log
+from .ir import (
+    METHODS, PlanChoice, PlanConfig, retired_choice_key, validate_placement,
+)
 
 DB_VERSION = 1
 DB_KIND = "stencil-plan-db"
@@ -73,6 +76,16 @@ def make_entry(config: PlanConfig, choice: PlanChoice, source: str,
     }
 
 
+def retired_key(entry) -> Optional[str]:
+    """The retired choice key (``hierarchy`` / ``host_placement``) an
+    entry uses, or None — see :func:`~.ir.retired_choice_key`. Such an
+    entry stays valid on disk and is a miss to :func:`lookup`;
+    :func:`prune_db` removes it."""
+    if not isinstance(entry, dict):
+        return None
+    return retired_choice_key(entry.get("choice"))
+
+
 def validate_entry(key: str, entry) -> List[str]:
     errs: List[str] = []
     if not isinstance(entry, dict):
@@ -84,6 +97,10 @@ def validate_entry(key: str, entry) -> List[str]:
     if cfg.key() != key:
         errs.append(f"entry {key!r}: key does not match its config "
                     f"(canonical {cfg.key()!r})")
+    if retired_key(entry) is not None:
+        # well-formed for the program that wrote it, and kept on disk
+        # until pruned: lookup() never serves it
+        return errs
     try:
         choice = PlanChoice.from_json(entry["choice"])
     except (KeyError, TypeError, ValueError) as e:
@@ -102,27 +119,6 @@ def validate_entry(key: str, entry) -> List[str]:
     perr = validate_placement(choice.placement, cfg.ndev)
     if perr is not None:
         errs.append(f"entry {key!r}: {perr}")
-    # hierarchy/host_placement ride the same absent-field migration:
-    # every pre-hierarchy entry deserializes to None (flat) and replays
-    # unchanged; a present hierarchy must be a valid (axis, hosts) split
-    # of the choice's partition, a present host_placement a permutation
-    # of range(hosts)
-    if choice.hierarchy is not None:
-        from ..geometry import Dim3
-        from .ir import validate_hierarchy
-
-        px, py, pz = choice.partition
-        herr = validate_hierarchy(choice.hierarchy, Dim3(px, py, pz))
-        if herr is not None:
-            errs.append(f"entry {key!r}: {herr}")
-    if choice.host_placement is not None:
-        hp = list(choice.host_placement)
-        hosts = choice.hierarchy[1] if choice.hierarchy is not None else None
-        if hosts is None:
-            errs.append(f"entry {key!r}: host_placement without hierarchy")
-        elif sorted(hp) != list(range(hosts)):
-            errs.append(f"entry {key!r}: host_placement {hp} is not a "
-                        f"permutation of range({hosts})")
     if entry.get("source") not in SOURCES:
         errs.append(f"entry {key!r}: unknown source {entry.get('source')!r}")
     for fld in ("static_cost_s", "measured_s"):
@@ -239,6 +235,16 @@ def load_db(path: str) -> dict:
             f"invalid plan DB {path}: {errs[0]}"
             + (f" (+{len(errs) - 1} more)" if len(errs) > 1 else "")
         )
+    retired = sorted(
+        "{}x{}x{} on {} {}".format(*e["config"]["grid"], e["config"]["ndev"],
+                                   e["config"]["platform"])
+        for e in obj["entries"].values() if retired_key(e) is not None)
+    if retired:
+        log.warn(
+            f"plan DB {path}: {len(retired)} entries use a retired key "
+            "(hierarchy/host_placement: the exchange has one level) and "
+            "are never served — `plan_tool prune` removes them: "
+            + "; ".join(retired))
     return obj
 
 
@@ -259,8 +265,11 @@ def save_db(path: str, db: dict) -> None:
 
 
 def lookup(db: dict, config: PlanConfig) -> Optional[dict]:
-    """The entry tuned for ``config`` (exact canonical-key match)."""
-    return db["entries"].get(config.key())
+    """The entry tuned for ``config`` (exact canonical-key match). An
+    entry that uses a retired choice key is a miss (:func:`load_db`
+    warned about it)."""
+    entry = db["entries"].get(config.key())
+    return None if retired_key(entry) is not None else entry
 
 
 def record(db: dict, entry: dict) -> dict:
@@ -288,22 +297,28 @@ def lookup_calibration(db: dict, platform: str) -> Optional[dict]:
 def prune_db(db: dict, platform: Optional[str] = None,
              source: Optional[str] = None,
              older_than_s: Optional[float] = None) -> int:
-    """Drop entries matching every given filter; returns the count.
-    At least one filter is required — "prune everything" must be an
-    explicit ``source=...``/``platform=...`` decision, not a default."""
-    if platform is None and source is None and older_than_s is None:
-        raise ValueError("prune_db requires at least one filter")
+    """Drop entries matching every given filter, and every entry that
+    uses a retired choice key (:func:`retired_key`: nothing can serve
+    it); returns the count. With none of the latter at least one filter
+    is required — "prune everything" must be an explicit
+    ``source=...``/``platform=...`` decision, not a default."""
     now = time.time()
-    doomed = []
-    for key, entry in db["entries"].items():
-        if platform is not None and entry["config"].get("platform") != platform:
-            continue
-        if source is not None and entry.get("source") != source:
-            continue
-        if older_than_s is not None and (
-                now - entry.get("written_t", 0)) < older_than_s:
-            continue
-        doomed.append(key)
+
+    def matches(entry) -> bool:
+        return not (
+            (platform is not None
+             and entry["config"].get("platform") != platform)
+            or (source is not None and entry.get("source") != source)
+            or (older_than_s is not None
+                and now - entry.get("written_t", 0) < older_than_s))
+
+    filtered = not (platform is None and source is None
+                    and older_than_s is None)
+    doomed = [key for key, entry in db["entries"].items()
+              if retired_key(entry) is not None
+              or (filtered and matches(entry))]
+    if not filtered and not doomed:
+        raise ValueError("prune_db requires at least one filter")
     for key in doomed:
         del db["entries"][key]
     return len(doomed)

@@ -252,60 +252,6 @@ class FusedPhaseIR:
 
 
 @dataclass(frozen=True)
-class DcnPhaseIR:
-    """The outer (cross-host) level of a hierarchical exchange plan.
-
-    One hierarchy = one outer split along ONE mesh axis (the "DCN
-    axis"): ``hosts`` contiguous segments of ``seg = ring // hosts``
-    devices each. The inner program's DCN-axis phase wraps within each
-    segment (:func:`_segmented_ring_pairs` — same collective count as
-    flat, nothing crosses a host), and this phase moves the host-
-    boundary slabs across the DCN instead: for each of the ``hosts``
-    periodic segment boundaries, every device in the boundary axis-slice
-    (``slice_devices`` of them, one per orthogonal mesh position) sends
-    its boundary slab to the peer device on the far side, as a
-    host-orchestrated device-to-device copy (the PR-10 emulation
-    machinery in-process; a real DCN transport on a pod).
-
-    Like :class:`RemoteDmaPhaseIR`, nothing here rides the XLA
-    collective path — :meth:`collectives` is ZERO by construction, so
-    the inner census/byte pins are untouched and the DCN level is
-    audited through :meth:`transfers` (the executed copy count) and its
-    own byte model instead. The slabs span the FULL padded orthogonal
-    extents (stale edge/corner strips included — later inner phases
-    overwrite them), exactly the composed slab geometry."""
-
-    axis: str            # 'x' | 'y' | 'z' (the DCN mesh axis)
-    hosts: int           # outer segments (emulated or real hosts)
-    ring: int            # inner mesh extent along the axis
-    seg: int             # devices per host along the axis
-    slice_devices: int   # devices per boundary axis-slice (orth positions)
-    rm: int              # low-side radius (data received from -axis)
-    rp: int              # high-side radius
-    wire_cells: int      # cells crossing the DCN per exchange per quantity
-    local_cells: int = 0
-
-    @property
-    def active(self) -> bool:
-        return self.hosts > 1 and (self.rm > 0 or self.rp > 0)
-
-    def collectives(self) -> int:
-        """Always 0: host-orchestrated copies, nothing on the XLA
-        collective path (the same pin as RemoteDmaPhaseIR)."""
-        return 0
-
-    def transfers(self) -> int:
-        """Cross-host copies one carrier pays per exchange: one per
-        active direction per segment boundary per orthogonal mesh
-        position — the count the hierarchy transport measures and
-        verify_plan audits."""
-        if not self.active:
-            return 0
-        dirs = (1 if self.rm > 0 else 0) + (1 if self.rp > 0 else 0)
-        return dirs * self.hosts * self.slice_devices
-
-
-@dataclass(frozen=True)
 class ExchangePlan:
     """The full declarative exchange program for one (spec, mesh, method).
 
@@ -336,12 +282,6 @@ class ExchangePlan:
     # built against the radius*k spec); what changes is the launch
     # economics — see :meth:`launches_per_chunk`.
     persistent: bool = False
-    # hierarchical (ICI+DCN) decomposition: (axis, hosts) of the outer
-    # split, or None for the flat single-level plan. When set, the inner
-    # DCN-axis phase carries host-local wrap pairs and ``dcn_phases``
-    # describes the cross-host level the planner prices separately.
-    hierarchy: Optional[Tuple[str, int]] = None
-    dcn_phases: Tuple["DcnPhaseIR", ...] = ()
     synthesized: bool = False
     # bf16-on-the-wire halo compression: wire-crossing carriers narrow to
     # this dtype before the send and widen on unpack (None = native).
@@ -384,33 +324,6 @@ class ExchangePlan:
         carriers = dtype_groups if self.batch_quantities else quantities
         phases = self.fused_phases if self.fused else self.remote_phases
         return sum(p.dmas() for p in phases) * carriers
-
-    def dcn_transfers_per_exchange(self, quantities: int = 1,
-                                   dtype_groups: int = 1) -> int:
-        """Predicted cross-host (DCN-level) copies of one hierarchical
-        exchange — 0 for flat plans. Like DMAs, these bypass the XLA
-        collective path entirely; the hierarchy transport counts its
-        executed copies and verify_plan pins this prediction against
-        that count."""
-        carriers = dtype_groups if self.batch_quantities else quantities
-        return sum(p.transfers() for p in self.dcn_phases) * carriers
-
-    def dcn_wire_bytes(self, itemsizes: Sequence[int],
-                       floating: Optional[Sequence[bool]] = None) -> int:
-        """Estimated bytes crossing the DCN per exchange (all
-        quantities) — the outer level's own byte model, priced against
-        the ``dcn`` calibration row (latency + bandwidth >> ICI). NOT
-        part of :meth:`wire_bytes`: the census only sees the inner
-        program, so the inner byte pin stays exact."""
-        w = wire_itemsize(self.wire_dtype)
-        if w is None:
-            per_cell = sum(itemsizes)
-        else:
-            fl = ([True] * len(itemsizes) if floating is None
-                  else list(floating))
-            per_cell = sum(min(i, w) if f else i
-                           for i, f in zip(itemsizes, fl))
-        return sum(p.wire_cells for p in self.dcn_phases) * per_cell
 
     def launches_per_chunk(self, k: int = 1) -> int:
         """Predicted device-program launches one k-step chunk pays — the
@@ -481,17 +394,8 @@ class ExchangePlan:
             + (" (fused compute+exchange kernel)" if self.fused else "")
             + (" (persistent whole-chunk kernel)" if self.persistent
                else "")
-            + (f" hierarchy={self.hierarchy[1]} hosts on "
-               f"{self.hierarchy[0]}" if self.hierarchy else "")
             + (f" wire_dtype={self.wire_dtype}" if self.wire_dtype else ""),
         ]
-        for p in self.dcn_phases:
-            lines.append(
-                f"  dcn {p.axis}: hosts={p.hosts} seg={p.seg} "
-                f"slice_devices={p.slice_devices} rm={p.rm} rp={p.rp} "
-                f"permutes=0 transfers={p.transfers()} "
-                f"wire_cells={p.wire_cells}"
-            )
         for p in self.phases:
             if isinstance(p, FusedPhaseIR):
                 lines.append(
@@ -525,13 +429,6 @@ class ExchangePlan:
                 f"  total async remote copies/exchange (1 group): "
                 f"{self.dmas_per_exchange()} (kernel-initiated — the "
                 "census sees 0 ppermutes)"
-            )
-        if self.dcn_phases:
-            lines.append(
-                f"  total cross-host copies/exchange (1 group): "
-                f"{self.dcn_transfers_per_exchange()} "
-                f"({self.dcn_wire_bytes([4])} bytes at 1 fp32 quantity; "
-                "host-orchestrated — the census sees 0 ppermutes)"
             )
         if self.wire_dtype and not self.synthesized:
             import dataclasses
@@ -569,30 +466,6 @@ def _ring_pairs(n: int) -> Tuple[Tuple[Tuple[int, int], ...],
     fwd = tuple((i, (i + 1) % n) for i in range(n))
     bwd = tuple((i, (i - 1) % n) for i in range(n))
     return fwd, bwd
-
-
-def _segmented_ring_pairs(n: int, hosts: int
-                          ) -> Tuple[Tuple[Tuple[int, int], ...],
-                                     Tuple[Tuple[int, int], ...]]:
-    """Host-local wrap pairs: the ring of ``n`` positions split into
-    ``hosts`` contiguous segments, each wrapping WITHIN itself. Still a
-    full permutation of all ``n`` participants — the compiled program
-    emits exactly as many ppermutes as the flat ring (the inner census
-    pin) — but no pair crosses a segment boundary, so the inner ICI
-    program never reaches across hosts; the cross-host slabs ride the
-    DCN level instead (see :class:`DcnPhaseIR`). A boundary receiver's
-    wrap value is garbage by construction and is overwritten by the DCN
-    apply."""
-    if n % hosts:
-        raise ValueError(f"{hosts} hosts do not divide ring extent {n}")
-    seg = n // hosts
-    fwd, bwd = [], []
-    for h in range(hosts):
-        base = h * seg
-        for j in range(seg):
-            fwd.append((base + j, base + (j + 1) % seg))
-            bwd.append((base + j, base + (j - 1) % seg))
-    return tuple(fwd), tuple(bwd)
 
 
 def _perm26(dim: Dim3, d: Dim3) -> Tuple[Tuple[int, int], ...]:
@@ -784,65 +657,11 @@ def _fused_phases(spec, mesh_dim: Dim3) -> Tuple[FusedPhaseIR, ...]:
     return tuple(phases)
 
 
-def validate_hierarchy(hierarchy, mesh_dim) -> Optional[str]:
-    """The one hierarchy-shape authority: ``None`` (flat) or an
-    ``(axis, hosts)`` pair naming the outer DCN split. ``hosts`` must
-    divide the mesh extent along ``axis`` so every host owns the same
-    contiguous segment of the axis ring. Returns an error string, or
-    None when valid."""
-    if hierarchy is None:
-        return None
-    try:
-        axis, hosts = hierarchy
-        axis = str(axis)
-        hosts = int(hosts)
-    except (TypeError, ValueError):
-        return (f"hierarchy must be an (axis, hosts) pair, "
-                f"got {hierarchy!r}")
-    if axis not in ("x", "y", "z"):
-        return f"hierarchy axis must be 'x'|'y'|'z', got {axis!r}"
-    if hosts < 1:
-        return f"hierarchy needs hosts >= 1, got {hosts}"
-    md = Dim3.of(mesh_dim)
-    n = {"x": md.x, "y": md.y, "z": md.z}[axis]
-    if n % hosts:
-        return (f"{hosts} hosts do not divide the {axis} mesh extent "
-                f"{n}")
-    return None
-
-
-def _dcn_phases(spec, mesh_dim: Dim3, axis: str,
-                hosts: int) -> Tuple[DcnPhaseIR, ...]:
-    """The outer DCN level: one phase for the hierarchy axis. Slabs use
-    the composed geometry (radius-deep along the axis, FULL padded
-    orthogonal extents), sent only by the ``hosts * slice_devices``
-    segment-boundary devices per direction; with oversubscription only
-    the edge resident block of each boundary device crosses (the rest
-    shifted locally by the inner phase, exactly the composed wire
-    accounting)."""
-    p = spec.padded()
-    orth = {"x": p.y * p.z, "y": p.x * p.z, "z": p.x * p.y}[axis]
-    md = {"x": mesh_dim.x, "y": mesh_dim.y, "z": mesh_dim.z}
-    _sizes, rm, rp, _off = spec_axis(spec, axis)
-    ring = md[axis]
-    slice_devices = (mesh_dim.x * mesh_dim.y * mesh_dim.z) // ring
-    dirs = (1 if rm > 0 else 0) + (1 if rp > 0 else 0)
-    wire = 0
-    if hosts > 1:
-        wire = ((rm + rp) * orth * hosts * slice_devices)
-    return (DcnPhaseIR(
-        axis=axis, hosts=hosts, ring=ring, seg=ring // hosts,
-        slice_devices=slice_devices, rm=rm, rp=rp,
-        wire_cells=wire if dirs else 0,
-    ),)
-
-
 def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
                resident: Optional[Dim3] = None,
                wire_dtype: Optional[str] = None,
                fused: bool = False,
-               persistent: bool = False,
-               hierarchy: Optional[Tuple[str, int]] = None) -> ExchangePlan:
+               persistent: bool = False) -> ExchangePlan:
     """Build the ExchangePlan of one (GridSpec, mesh shape, method).
 
     Pure geometry — no jax, no devices. ``method`` may be the enum from
@@ -855,28 +674,11 @@ def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
     only, single-resident only — loud infeasibility otherwise);
     ``persistent`` marks the whole-chunk mega-kernel variant (same
     constraints; the phase geometry stays the composed slab program
-    against the caller's deep-halo radius*k spec). ``hierarchy`` is the
-    outer DCN split ``(axis, hosts)``: the inner DCN-axis phase gets
-    host-local wrap pairs (same collective count, nothing crossing a
-    host) and ``dcn_phases`` describes the cross-host slab level.
+    against the caller's deep-halo radius*k spec).
     """
     mval = getattr(method, "value", method)
     if mval not in METHODS:
         raise ValueError(f"unknown exchange method {method!r}")
-    err = validate_hierarchy(hierarchy, mesh_dim)
-    if err is not None:
-        raise ValueError(err)
-    if hierarchy is not None and mval == AUTO_SPMD:
-        # the partitioner owns the synthesized schedule — there is no
-        # seam to segment, so a hierarchical AUTO_SPMD plan would claim
-        # an inner/outer split the compiled program does not have
-        raise ValueError(
-            "hierarchical decomposition is not available for auto-spmd: "
-            "the SPMD partitioner synthesizes the collective schedule "
-            "and cannot be constrained to host-local rings"
-        )
-    if hierarchy is not None:
-        hierarchy = (str(hierarchy[0]), int(hierarchy[1]))
     if fused and mval != REMOTE_DMA:
         raise ValueError(
             "the fused compute+exchange variant is a REMOTE_DMA lowering "
@@ -912,38 +714,13 @@ def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
             f"partitions only (got resident {resident}); use the plain "
             "REMOTE_DMA carrier or AXIS_COMPOSED for oversubscription"
         )
-    if hierarchy is not None and mval == DIRECT26:
-        raise ValueError(
-            "hierarchical decomposition is not available for direct26: "
-            "its 26-direction permutation crosses hosts diagonally; use "
-            "a composed-geometry inner method (axis-composed/remote-dma)"
-        )
     synthesized = mval == AUTO_SPMD
     axis_phases = _axis_phases(spec, md, resident, synthesized)
-    if hierarchy is not None and hierarchy[1] > 1:
-        # the inner DCN-axis phase wraps within each host segment: same
-        # ppermute count and carrier bytes as the flat ring (the census
-        # pins), but no pair crosses a host — the boundary slabs ride
-        # the DCN level instead
-        import dataclasses
-
-        h_axis, h_hosts = hierarchy
-        axis_phases = tuple(
-            dataclasses.replace(
-                p, fwd=_segmented_ring_pairs(p.ring, h_hosts)[0],
-                bwd=_segmented_ring_pairs(p.ring, h_hosts)[1])
-            if p.axis == h_axis and p.ring > 1 else p
-            for p in axis_phases
-        )
     direct_phases = (
         _direct_phases(spec, md, resident) if mval == DIRECT26 else ()
     )
     remote_phases = _remote_phases(axis_phases) if mval == REMOTE_DMA else ()
     fused_phases = _fused_phases(spec, md) if fused else ()
-    dcn_phases = (
-        _dcn_phases(spec, md, hierarchy[0], hierarchy[1])
-        if hierarchy is not None else ()
-    )
     return ExchangePlan(
         method=mval,
         pack_groups="dtype" if batch_quantities else "quantity",
@@ -956,8 +733,6 @@ def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
         fused_phases=fused_phases,
         fused=fused,
         persistent=persistent,
-        hierarchy=hierarchy,
-        dcn_phases=dcn_phases,
         synthesized=synthesized,
         wire_dtype=wire_dtype,
     )
@@ -1087,6 +862,24 @@ def validate_placement(placement, ndev: int) -> Optional[str]:
     return None
 
 
+def retired_choice_key(obj) -> Optional[str]:
+    """The retired key a stored choice USES, or None. Plan DBs and
+    checkpoint manifests written by PRs 17 to 29 carry ``hierarchy``
+    (the outer split of a two-level ICI+DCN exchange) and
+    ``host_placement`` (its blocks-to-hosts assignment). Absent, null or
+    an identity ``host_placement`` is the one-level plan it always was;
+    anything else names a plan this program cannot run, and replaying it
+    as a one-level plan would be a different plan in silence."""
+    if not isinstance(obj, dict):
+        return None
+    if obj.get("hierarchy") is not None:
+        return "hierarchy"
+    hp = obj.get("host_placement")
+    if hp is not None and list(hp) != list(range(len(hp))):
+        return "host_placement"
+    return None
+
+
 @dataclass(frozen=True)
 class PlanChoice:
     """One point in the search space — what the autotuner picks and the
@@ -1099,16 +892,7 @@ class PlanChoice:
     position i, row-major (z, y, x) over the mesh grid. ``None`` is the
     identity assignment — the historical block order = device order —
     and is what every pre-placement DB entry deserializes to (the
-    schema-migration default: an absent field IS identity).
-
-    ``hierarchy`` is the outer DCN split ``(axis, hosts)`` — ``None``
-    (and every pre-hierarchy DB entry / ckpt meta, via the same
-    absent-field default) is the flat single-level plan.
-    ``host_placement`` is the outer QAP's blocks→hosts assignment
-    (``host_placement[s]`` = the host index serving host-slot s), the
-    two-level analogue of ``placement``; the composed per-device
-    permutation still lives in ``placement`` so realize() applies it
-    through the existing machinery unchanged."""
+    schema-migration default: an absent field IS identity)."""
 
     partition: Tuple[int, int, int]   # blocks (x, y, z)
     method: str                       # METHODS value string
@@ -1116,8 +900,6 @@ class PlanChoice:
     multistep_k: int = 1
     kernel_variant: Optional[str] = None
     placement: Optional[Tuple[int, ...]] = None
-    hierarchy: Optional[Tuple[str, int]] = None
-    host_placement: Optional[Tuple[int, ...]] = None
 
     def to_json(self) -> dict:
         return {
@@ -1128,17 +910,16 @@ class PlanChoice:
             "kernel_variant": self.kernel_variant,
             "placement": (None if self.placement is None
                           else list(self.placement)),
-            "hierarchy": (None if self.hierarchy is None
-                          else [self.hierarchy[0], self.hierarchy[1]]),
-            "host_placement": (None if self.host_placement is None
-                               else list(self.host_placement)),
         }
 
     @classmethod
     def from_json(cls, obj: dict) -> "PlanChoice":
         placement = obj.get("placement")
-        hierarchy = obj.get("hierarchy")
-        host_placement = obj.get("host_placement")
+        retired = retired_choice_key(obj)
+        if retired is not None:
+            raise ValueError(
+                f"plan choice carries the retired key {retired!r} "
+                f"({obj[retired]!r}): the exchange has one level")
         return cls(
             partition=tuple(obj["partition"]),
             method=str(obj["method"]),
@@ -1147,10 +928,6 @@ class PlanChoice:
             kernel_variant=obj.get("kernel_variant"),
             placement=(None if placement is None
                        else tuple(int(v) for v in placement)),
-            hierarchy=(None if hierarchy is None
-                       else (str(hierarchy[0]), int(hierarchy[1]))),
-            host_placement=(None if host_placement is None
-                            else tuple(int(v) for v in host_placement)),
         )
 
     @property
@@ -1170,11 +947,6 @@ class PlanChoice:
         return (self.placement is not None
                 and list(self.placement) != list(range(len(self.placement))))
 
-    @property
-    def is_hierarchical(self) -> bool:
-        """True when the choice carries a real (multi-host) outer split."""
-        return self.hierarchy is not None and self.hierarchy[1] > 1
-
     def fingerprint(self) -> str:
         """Short stable content hash of the choice (12 hex chars of the
         sha256 of its canonical JSON). The observatory's join key: a
@@ -1193,12 +965,6 @@ class PlanChoice:
             s += f"/k={self.multistep_k}"
         if self.kernel_variant:
             s += f"/{self.kernel_variant}"
-        if self.hierarchy is not None:
-            s += f"/h={self.hierarchy[0]}{self.hierarchy[1]}"
-        if self.host_placement is not None and \
-                list(self.host_placement) != \
-                list(range(len(self.host_placement))):
-            s += "/hp=" + "-".join(str(v) for v in self.host_placement)
         if self.is_placed:
             s += "/p=" + "-".join(str(v) for v in self.placement)
         return s

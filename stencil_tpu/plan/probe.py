@@ -69,10 +69,6 @@ def probe_choice(config: PlanConfig, choice: PlanChoice,
             # a placed candidate probes on its placed mesh — the tuned
             # assignment must be what the measurement measured
             placement=choice.placement,
-            # a hierarchical candidate probes the two-level transport on
-            # the live host fabric (its composed placement above is what
-            # aligns each segment onto one host)
-            hierarchy=choice.hierarchy,
         )
     trimean = r["trimean_s"]
     rec.gauge("plan.probe_trimean_s", trimean, phase="plan", unit="s",

@@ -73,7 +73,7 @@ class Statistics:
 
 def percentile(values: Iterable[float], q: float) -> float:
     """Module-level convenience: ``Statistics(values).percentile(q)`` —
-    the p50/p99 authority the campaign driver, apps/report.py's optional
-    p99 span column, and bench.py's latency legs share (same linear
-    interpolation as the trimean's quartiles)."""
+    the p50/p99 authority the campaign driver and apps/report.py's
+    optional p99 span column share (same linear interpolation as the
+    trimean's quartiles)."""
     return Statistics(values).percentile(q)

@@ -65,18 +65,6 @@ def test_pure_stdlib_clean_on_stdlib_only(tmp_path):
     assert findings == []
 
 
-def test_pure_stdlib_bench_parent_is_top_level_only(tmp_path):
-    # bench.py: module-level jax is a contract break, function-level is
-    # the child code path and allowed
-    bad = _lint_snippet(tmp_path, "bench.py", "import jax\n")
-    assert _rules(bad) == ["pure-stdlib"]
-    good = _lint_snippet(tmp_path, "bench.py", (
-        "import json\n"
-        "def child():\n    import jax\n    return jax\n"
-    ))
-    assert good == []
-
-
 def test_pure_stdlib_does_not_apply_elsewhere(tmp_path):
     findings = _lint_snippet(tmp_path, "lib/other.py", "import jax\n")
     assert [f for f in findings if f.rule == "pure-stdlib"] == []

@@ -420,3 +420,39 @@ def test_quarantine_invalid_snapshot(tmp_path):
     assert find_resume(d) is None
     # and a nonexistent name is a no-op
     assert quarantine_snapshot(d, snapshot_name(9)) is None
+
+
+# -- manifests written by PRs 17 to 29 carry the retired two-level keys ------
+
+
+@pytest.mark.parametrize("key,value", [
+    ("hierarchy", ["z", 2]),
+    ("host_placement", [1, 0]),
+    ("host_blocks", [0, 0, 0, 0, 1, 1, 1, 1]),
+])
+def test_restore_ignores_retired_plan_keys(tmp_path, capfd, key, value):
+    """The block geometry never depended on the outer split, its host
+    assignment or the host index per block: a manifest that carries them
+    (null in a one-level run, set in a two-level one) restores bit-exact,
+    and says nothing."""
+    ck = str(tmp_path / "ck")
+    dd, h = make_domain((12, 12, 8), "float32")
+    field = coord_field(dd.size, np.float32)
+    dd.set_curr_global(h, field)
+    dd.save_checkpoint(ck, 3, asynchronous=False)
+    snaps = [e for e in os.listdir(ck) if e.startswith("step-")]
+    mpath = os.path.join(ck, snaps[0], "manifest.json")
+    with open(mpath) as f:
+        manifest = json.load(f)
+    plan = manifest["meta"]["plan"]
+    # as the parent tree wrote a one-level run, then the key under test
+    plan["choice"].update(hierarchy=None, host_placement=None)
+    plan["host_blocks"] = [0] * 8
+    (plan if key == "host_blocks" else plan["choice"])[key] = value
+    with open(mpath, "w") as f:
+        json.dump(manifest, f)
+    dd2, h2 = make_domain((12, 12, 8), "float32")
+    capfd.readouterr()
+    assert dd2.restore_checkpoint(ck) == 3
+    assert "[WARN]" not in capfd.readouterr().err
+    np.testing.assert_array_equal(dd2.get_curr_global(h2), field)

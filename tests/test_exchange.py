@@ -414,3 +414,74 @@ def test_oversubscribed_direct26_halo_parity():
     np.testing.assert_array_equal(results["zstack"], results["full"])
     np.testing.assert_array_equal(results["mixed2"], results["full"])
     _assert_halos_wrap(results["mixed2"], spec, size)
+
+
+# -- one-level parity grid against DIRECT26, the reference lowering ----------
+#
+# Geometry/knob pairs nothing else ran: an uneven (1, 2, 4) split (x
+# self-wraps, four blocks on z) and a (2, 2, 4) partition stacked on four
+# devices, with mixed dtypes, bf16 on the wire and batching off.
+
+
+def _ramp_state(spec, mesh, dtypes):
+    g = spec.global_size
+    base = (np.arange(g.z)[:, None, None] * 1_000_000.0
+            + np.arange(g.y)[None, :, None] * 1_000.0
+            + np.arange(g.x)[None, None, :])
+    return {i: shard_blocks((base + i).astype(dt), spec, mesh)
+            for i, dt in enumerate(dtypes)}
+
+
+F32x2 = (np.float32, np.float32)
+
+
+@pytest.mark.parametrize("size,part,mesh_dim,ndev,dtypes,kw", [
+    ((14, 18, 20), (1, 2, 4), (1, 2, 4), 8, F32x2, {}),
+    ((14, 18, 20), (1, 2, 4), (1, 2, 4), 8,
+     (np.float32, np.float64, np.float32), {}),
+    ((12, 12, 16), (2, 2, 4), (1, 2, 2), 4, F32x2, {}),
+    ((16, 16, 16), (2, 2, 2), (2, 2, 2), 8, F32x2,
+     {"wire_dtype": "bfloat16"}),
+    ((14, 18, 20), (1, 2, 4), (1, 2, 4), 8, F32x2,
+     {"batch_quantities": False}),
+], ids=["uneven-1x2x4", "uneven-1x2x4-mixed", "oversub-2x2x4-on-4",
+        "bf16-wire", "uneven-1x2x4-batch-off"])
+def test_composed_parity_with_direct26(size, part, mesh_dim, ndev, dtypes, kw):
+    spec = GridSpec(Dim3(*size), Dim3(*part), Radius.constant(2))
+    mesh = grid_mesh(Dim3(*mesh_dim), jax.devices()[:ndev])
+    outs = {}
+    for method in (Method.DIRECT26, Method.AXIS_COMPOSED):
+        ex = HaloExchange(spec, mesh, method, **kw)
+        out = ex(_ramp_state(spec, mesh, dtypes))
+        outs[method] = [jax.device_get(out[i]) for i in sorted(out)]
+    for a, b, dt in zip(outs[Method.DIRECT26], outs[Method.AXIS_COMPOSED],
+                        dtypes):
+        assert a.dtype == b.dtype == dt
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("method,kw", [
+    (Method.AXIS_COMPOSED, {}),
+    (Method.REMOTE_DMA, {}),
+    (Method.REMOTE_DMA, {"fused": True}),
+], ids=["composed", "remote", "fused"])
+def test_jacobi_loop_parity_with_direct26_uneven_1x2x4(method, kw):
+    """Five iterations of the step loop on the uneven (1, 2, 4) split land
+    bit-identical to the DIRECT26 loop, whichever transport delivers the
+    halos."""
+    from stencil_tpu.ops.jacobi import make_jacobi_loop, sphere_sel
+
+    spec = GridSpec(Dim3(14, 18, 20), Dim3(1, 2, 4), Radius.constant(2))
+    g = spec.global_size
+    curr = np.random.default_rng(0).standard_normal(
+        (g.z, g.y, g.x)).astype(np.float32)
+    sel = sphere_sel(g)
+    mesh = grid_mesh(spec.dim, jax.devices()[:8])
+    outs = []
+    for m, k in ((Method.DIRECT26, {}), (method, kw)):
+        loop = make_jacobi_loop(HaloExchange(spec, mesh, m, **k), 5)
+        out, _ = loop(shard_blocks(curr, spec, mesh),
+                      shard_blocks(np.zeros_like(curr), spec, mesh),
+                      shard_blocks(sel, spec, mesh))
+        outs.append(unshard_blocks(out, spec))
+    np.testing.assert_array_equal(outs[0], outs[1])

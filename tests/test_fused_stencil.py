@@ -171,18 +171,24 @@ def test_fused_transfer_count_q_independent_and_predicted():
     assert counts[1] == ex.plan.dmas_per_exchange(1, 1) * 8
 
 
-@pytest.mark.parametrize("name,size,dim,ndev,dtypes,wire", [
-    ("uniform", (16, 16, 16), (2, 2, 2), 8, None, None),
-    ("uneven", (17, 19, 16), (2, 2, 2), 8, None, None),
-    ("fp64", (16, 16, 16), (2, 2, 2), 8, [np.float64, np.float64], None),
+@pytest.mark.parametrize("name,size,dim,ndev,dtypes,wire,batch", [
+    ("uniform", (16, 16, 16), (2, 2, 2), 8, None, None, True),
+    ("uneven", (17, 19, 16), (2, 2, 2), 8, None, None, True),
+    ("fp64", (16, 16, 16), (2, 2, 2), 8, [np.float64, np.float64], None,
+     True),
     ("mixed-dtype", (16, 16, 16), (2, 2, 2), 8,
-     [np.float32, np.float64, np.float32], None),
-    ("bf16-wire", (16, 16, 16), (2, 2, 2), 8, None, "bfloat16"),
-    ("fp8-wire", (16, 16, 16), (2, 2, 2), 8, None, "float8_e4m3fn"),
-    ("uneven-bf16", (17, 16, 16), (2, 2, 2), 8, None, "bfloat16"),
-    ("anisotropic", (16, 16, 16), (1, 2, 4), 8, None, None),
+     [np.float32, np.float64, np.float32], None, True),
+    ("bf16-wire", (16, 16, 16), (2, 2, 2), 8, None, "bfloat16", True),
+    ("fp8-wire", (16, 16, 16), (2, 2, 2), 8, None, "float8_e4m3fn", True),
+    ("uneven-bf16", (17, 16, 16), (2, 2, 2), 8, None, "bfloat16", True),
+    ("anisotropic", (16, 16, 16), (1, 2, 4), 8, None, None, True),
+    # x self-wraps, four uneven blocks on z (composed is held to DIRECT26
+    # on the same split in test_exchange.py)
+    ("uneven-1x2x4", (14, 18, 20), (1, 2, 4), 8, None, None, True),
+    ("batch-off", (16, 16, 16), (2, 2, 2), 8, None, None, False),
 ])
-def test_fused_bit_parity_vs_composed(name, size, dim, ndev, dtypes, wire):
+def test_fused_bit_parity_vs_composed(name, size, dim, ndev, dtypes, wire,
+                                      batch):
     spec = GridSpec(Dim3(*size), Dim3(*dim), Radius.constant(2))
     mesh = grid_mesh(Dim3(*dim), jax.devices()[:ndev])
     nq = len(dtypes) if dtypes else 2
@@ -193,7 +199,8 @@ def test_fused_bit_parity_vs_composed(name, size, dim, ndev, dtypes, wire):
     outs = {}
     for method, fused in ((Method.AXIS_COMPOSED, False),
                           (Method.REMOTE_DMA, True)):
-        ex = HaloExchange(spec, mesh, method, wire_dtype=wire, fused=fused)
+        ex = HaloExchange(spec, mesh, method, wire_dtype=wire, fused=fused,
+                          batch_quantities=batch)
         out = ex(_state(spec, mesh, nq, dtypes, scale=scale))
         outs[fused] = _gather(out)
     for a, b in zip(outs[False], outs[True]):

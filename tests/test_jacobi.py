@@ -989,3 +989,42 @@ def test_uneven_overlap_asymmetric_radius():
             curr, nxt = step(curr, nxt, sel)
         outs[label] = unshard_blocks(curr, spec)
     np.testing.assert_array_equal(outs["overlap"], outs["serial"])
+
+
+# The forks of the step builder, in one table: which builder
+# ``_compile_jacobi`` hands each (method, fused, persistent) to. ``None``
+# is the inline composed-geometry build (the one the benchmark's cells
+# run), which returns an in-place ping-pong loop.
+_BUILDERS = [
+    ("composed", Method.AXIS_COMPOSED, {}, None),
+    ("direct26", Method.DIRECT26, {}, None),
+    ("auto-spmd", Method.AUTO_SPMD, {}, "_compile_jacobi_auto"),
+    ("remote", Method.REMOTE_DMA, {}, "_compile_jacobi_remote"),
+    ("fused", Method.REMOTE_DMA, {"fused": True}, "_compile_jacobi_fused"),
+    ("persistent", Method.REMOTE_DMA, {"persistent": True},
+     "_compile_jacobi_persistent"),
+]
+
+
+@pytest.mark.parametrize("name,method,kw,builder", _BUILDERS,
+                         ids=[b[0] for b in _BUILDERS])
+def test_step_builder_dispatch(monkeypatch, name, method, kw, builder):
+    from stencil_tpu.domain.grid import GridSpec
+    from stencil_tpu.geometry import Radius
+    from stencil_tpu.ops import jacobi
+    from stencil_tpu.ops.double_buffer import InPlaceLoop
+    from stencil_tpu.parallel import HaloExchange, grid_mesh
+
+    spec = GridSpec(Dim3(16, 16, 16), Dim3(2, 2, 2), Radius.constant(2))
+    ex = HaloExchange(spec, grid_mesh(spec.dim, jax.devices()[:8]), method,
+                      **kw)
+    called = []
+    for b in sorted({b[3] for b in _BUILDERS if b[3]}):
+        monkeypatch.setattr(
+            jacobi, b,
+            lambda *a, _b=b, **k: called.append(_b) or _b)
+    loop = jacobi.make_jacobi_loop(ex, 2)
+    if builder is None:
+        assert called == [] and isinstance(loop, InPlaceLoop)
+    else:
+        assert called == [builder] and loop == builder

@@ -1,8 +1,6 @@
 """Performance-ledger tests: schema round-trip, atomic append + dedup,
 corruption rejection, the three ingest shapes (bench payload, committed
-legacy BENCH/MULTICHIP docs, metrics-JSONL gauge trimeans), and the
-bench.py parent hook (STENCIL_BENCH_LEDGER) through the same file-path
-loading the parent uses."""
+legacy BENCH/MULTICHIP docs, metrics-JSONL gauge trimeans)."""
 
 import io
 import json
@@ -181,40 +179,6 @@ def test_entries_from_metrics_records_gauge_trimeans():
     es3 = ledger.entries_from_metrics_records(records, label="run1",
                                               spans=True)
     assert any(e["metric"] == "work.trimean_s" for e in es3)
-
-
-# -- the bench.py parent hook -------------------------------------------------
-
-
-def test_bench_parent_ledger_hook(tmp_path, monkeypatch):
-    """The parent-side append: loaded by file path (never importing the
-    package), labeled from STENCIL_BENCH_LABEL, best-effort on failure."""
-    import importlib.util
-    import sys as _sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test", os.path.join(repo, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    _sys.modules["bench_under_test"] = bench
-    spec.loader.exec_module(bench)
-
-    path = str(tmp_path / "L.jsonl")
-    payload = {"metric": "m", "value": 2.0, "unit": "u", "vs_baseline": 1.1,
-               "detail": {"platform": "cpu", "size": 128, "leg_s": 0.5}}
-    monkeypatch.setenv("STENCIL_BENCH_LEDGER", path)
-    monkeypatch.setenv("STENCIL_BENCH_LABEL", "r99")
-    bench._append_ledger(payload)
-    es = ledger.load_ledger(path)
-    assert {e["metric"] for e in es} == {"m", "m.vs_baseline", "leg_s"}
-    assert all(e["label"] == "r99" and e["source"] == "bench" for e in es)
-    # unset -> no-op; corrupt ledger -> warn, never raise
-    monkeypatch.delenv("STENCIL_BENCH_LEDGER")
-    bench._append_ledger(payload)
-    monkeypatch.setenv("STENCIL_BENCH_LEDGER", path)
-    with open(path, "a") as f:
-        f.write("garbage\n")
-    bench._append_ledger(payload)  # must not raise (rc=0 contract)
 
 
 def test_git_rev_best_effort(tmp_path):

@@ -144,3 +144,59 @@ def test_prune_filters_and_guard(tmp_path):
     assert plandb.prune_db(db, platform="tpu") == 1
     assert len(db["entries"]) == 1
     assert plandb.prune_db(db, older_than_s=3600.0) == 0  # all fresh
+
+
+# -- entries that use the retired two-level keys (written by PRs 17 to 29) ---
+
+
+def _db_with_retired_entry(tmp_path):
+    """A DB file as the parent tree wrote it: one one-level entry (the
+    keys present and null) and one that split z over two hosts."""
+    flat, hier = _config(q=1), _config(q=2, grid=(32, 32, 32))
+    stored = dict(_choice().to_json(), hierarchy=None, host_placement=None)
+    db = plandb.empty_db()
+    for cfg, extra in ((flat, {}), (hier, {"hierarchy": ["z", 2],
+                                           "host_placement": [1, 0]})):
+        entry = plandb.make_entry(cfg, _choice(), "probe", measured_s=0.01)
+        entry["choice"] = {**stored, **extra}
+        db["entries"][cfg.key()] = entry
+    path = str(tmp_path / "plans.json")
+    with open(path, "w") as f:
+        json.dump(db, f, indent=1, sort_keys=True)
+    return path, flat, hier
+
+
+def _file_bytes(path):
+    with open(path, "rb") as f:
+        return f.read()
+
+
+@pytest.mark.parametrize("case", ["served-and-skipped", "file-untouched",
+                                  "pruned"])
+def test_entry_with_retired_keys(tmp_path, capfd, case):
+    path, flat, hier = _db_with_retired_entry(tmp_path)
+    before = _file_bytes(path)
+    capfd.readouterr()
+    db = plandb.load_db(path)
+    if case == "served-and-skipped":
+        got = plandb.lookup(db, flat)
+        assert PlanChoice.from_json(got["choice"]) == _choice()
+        assert plandb.lookup(db, hier) is None      # a miss, not a flat plan
+        assert plandb.lookup(db, hier) is None
+        err = capfd.readouterr().err
+        assert err.count("retired key") == 1, err   # once a load
+        assert "32x32x32 on 8 cpu" in err and "64x64x64" not in err
+    elif case == "file-untouched":
+        plandb.lookup(db, flat)
+        plandb.lookup(db, hier)
+        assert _file_bytes(path) == before
+        assert os.listdir(tmp_path) == ["plans.json"]
+    else:
+        # no filter needed: nothing can serve the entry, so any prune
+        # drops it; the one-level entry stays
+        assert plandb.prune_db(db) == 1
+        assert list(db["entries"]) == [flat.key()]
+        plandb.save_db(path, db)
+        capfd.readouterr()
+        assert plandb.lookup(plandb.load_db(path), flat) is not None
+        assert "retired" not in capfd.readouterr().err

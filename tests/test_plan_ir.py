@@ -169,3 +169,30 @@ def test_plan_config_key_and_choice_roundtrip():
                     kernel_variant="ring")
     assert PlanChoice.from_json(ch.to_json()) == ch
     assert "k=2" in ch.label() and "ring" in ch.label()
+
+
+# the choice exactly as PRs 17 to 29 wrote it for a one-level plan
+_STORED = {"partition": [2, 2, 2], "method": "axis-composed",
+           "batch_quantities": True, "multistep_k": 1,
+           "kernel_variant": None, "placement": None}
+
+
+@pytest.mark.parametrize("extra,refused", [
+    ({}, None),
+    ({"hierarchy": None, "host_placement": None}, None),
+    ({"hierarchy": ["z", 2], "host_placement": None}, "hierarchy"),
+    ({"hierarchy": None, "host_placement": [1, 0]}, "host_placement"),
+], ids=["absent", "null", "hierarchy", "host_placement"])
+def test_choice_from_json_of_retired_keys(extra, refused):
+    """Plan DBs and checkpoint manifests written since PR 17 carry the
+    two keys of the retired two-level exchange: absent or null loads as
+    the one-level plan it always was; a choice that USED them is refused
+    by name, never replayed as another plan."""
+    obj = {**_STORED, **extra}
+    if refused is None:
+        ch = PlanChoice.from_json(obj)
+        assert ch == PlanChoice(partition=(2, 2, 2), method="axis-composed")
+        assert set(ch.to_json()) == set(_STORED)
+    else:
+        with pytest.raises(ValueError, match=f"retired key '{refused}'"):
+            PlanChoice.from_json(obj)

@@ -168,23 +168,27 @@ def test_remote_transfer_count_q_independent():
     assert ex._remote.last_transfer_count == 4 * 8 * 6
 
 
-@pytest.mark.parametrize("name,size,dim,mesh_dim,ndev,dtypes", [
-    ("uniform", (16, 16, 16), (2, 2, 2), (2, 2, 2), 8, None),
-    ("uneven", (17, 19, 16), (2, 2, 2), (2, 2, 2), 8, None),
-    ("oversubscribed", (16, 16, 16), (2, 2, 2), (2, 2, 1), 4, None),
+@pytest.mark.parametrize("name,size,dim,mesh_dim,ndev,dtypes,radius,batch", [
+    ("uniform", (16, 16, 16), (2, 2, 2), (2, 2, 2), 8, None, 1, True),
+    ("uneven", (17, 19, 16), (2, 2, 2), (2, 2, 2), 8, None, 1, True),
+    ("oversubscribed", (16, 16, 16), (2, 2, 2), (2, 2, 1), 4, None, 1, True),
     ("mixed-dtype", (16, 16, 16), (2, 2, 2), (2, 2, 2), 8,
-     [np.float32, np.float64, np.float32]),
+     [np.float32, np.float64, np.float32], 1, True),
     ("uneven-oversub-f64", (17, 16, 16), (2, 2, 2), (2, 1, 2), 4,
-     [np.float64, np.float64]),
+     [np.float64, np.float64], 1, True),
+    # x self-wraps, four uneven blocks on z (composed is held to DIRECT26
+    # on the same split in test_exchange.py)
+    ("uneven-1x2x4-r2", (14, 18, 20), (1, 2, 4), (1, 2, 4), 8, None, 2, True),
+    ("batch-off", (16, 16, 16), (2, 2, 2), (2, 2, 2), 8, None, 2, False),
 ])
 def test_remote_bit_parity_vs_composed(name, size, dim, mesh_dim, ndev,
-                                       dtypes):
-    spec = GridSpec(Dim3(*size), Dim3(*dim), Radius.constant(1))
+                                       dtypes, radius, batch):
+    spec = GridSpec(Dim3(*size), Dim3(*dim), Radius.constant(radius))
     mesh = grid_mesh(Dim3(*mesh_dim), jax.devices()[:ndev])
     nq = len(dtypes) if dtypes else 2
     outs = {}
     for method in (Method.AXIS_COMPOSED, Method.REMOTE_DMA):
-        ex = HaloExchange(spec, mesh, method)
+        ex = HaloExchange(spec, mesh, method, batch_quantities=batch)
         out = ex(_state(spec, mesh, nq, dtypes))
         outs[method] = [np.asarray(jax.device_get(out[i]))
                         for i in sorted(out)]
