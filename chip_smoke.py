@@ -164,16 +164,17 @@ def _run_loop(ex, loop, field, size):
     return unshard_blocks(curr, ex.spec)
 
 
-def _jacobi_exchange(size, dim, devs, tight_x: bool):
-    """A radius-1 exchange. ``tight_x`` is the layout ``jacobi3d.run``
-    realizes on TPU devices (zero x radius, single-block x axis; the
-    rehearsal builds it by hand because ``run`` only chooses it on a TPU);
-    otherwise inline halos on every axis, which the XLA path needs."""
+def _jacobi_exchange(size, dim, devs, tight_x: bool, radius: int = 1):
+    """An exchange of ``radius`` halos. ``tight_x`` is the
+    layout ``jacobi3d.run`` realizes on TPU devices (zero x radius,
+    single-block x axis; the rehearsal builds it by hand because ``run``
+    only chooses it on a TPU); otherwise inline halos on every axis, which
+    the XLA path needs."""
     from stencil_tpu.domain.grid import GridSpec
     from stencil_tpu.geometry import Radius
     from stencil_tpu.parallel import HaloExchange, grid_mesh
 
-    r = Radius.constant(1)
+    r = Radius.constant(radius)
     spec = GridSpec(size, dim, r.without_x() if tight_x else r)
     return HaloExchange(spec, grid_mesh(dim, devs))
 
@@ -539,35 +540,50 @@ def phase_serve(devs, n: int, jobs: int, rehearsal: bool) -> dict:
 
 
 def phase_four_jacobi(devs, per, small, rehearsal: bool) -> dict:
-    """Weak-scaled jacobi3d over four chips through ``jacobi3d.run``
-    (``decompose_zy`` -> (1,2,2), multi-block tight-x, overlap on), then a
-    small global size against the numpy reference."""
+    """Weak-scaled jacobi3d over four chips through ``jacobi3d.run``, the
+    four-chip cell's own call (``decompose_zy`` -> (1,2,2), multi-block
+    tight-x, overlap on, ten steps a dispatch): the application realizes
+    deep y/z halos and the loop is deep-halo multistep passes. Then a small
+    global size against the numpy reference, and one dispatch of the pass
+    against the per-step path over the whole field."""
     import numpy as np
 
     from stencil_tpu.apps import jacobi3d
     from stencil_tpu.geometry import Dim3
     from stencil_tpu.ops.jacobi import (INIT_TEMP, jacobi_reference,
-                                        make_jacobi_step)
+                                        make_jacobi_loop, make_jacobi_step)
+    from stencil_tpu.ops.pallas_stencil import pick_temporal_depth
 
     facts = {}
+    p122 = Dim3(1, 2, 2)
     with PallasRecorder() as rec:
         r = jacobi3d.run(per.x, per.y, per.z, weak=True, devices=devs,
-                         iters=10, chunk=5)
+                         iters=10)
     dd, h = r["domain"], r["handle"]
     size = dd.size
     for name, arr in (("curr", dd.get_curr(h)), ("next", dd.get_next(h))):
         require_four_shards(arr, devs, f"jacobi {name}")
     if not rehearsal:
-        assert dd.spec.dim == Dim3(1, 2, 2), dd.spec.dim
+        assert dd.spec.dim == p122, dd.spec.dim
         assert size == Dim3(per.x, 2 * per.y, 2 * per.z), size
+        assert r["overlap"]
+        # the depth is the application's pick for a dispatch of ten, the
+        # halos are that deep on y and z, and the kernel libtpu compiled
+        # is the multistep at that depth
+        want_k, bound = pick_temporal_depth(size, p122, 10)
         rx = dd.spec.radius
         assert rx.x(-1) == 0 and rx.x(1) == 0, f"not tight-x: {rx}"
-        assert r["overlap"]
-        require_compiled_kernels(rec, ["make_pallas_jacobi_sweep"], rehearsal)
+        assert {rx.y(-1), rx.y(1), rx.z(-1), rx.z(1)} == {want_k}, rx
+        require_compiled_kernels(rec, ["make_pallas_jacobi_multistep"],
+                                 rehearsal)
+        k, row_tiled, grid = _multistep_depth(rec, dd.spec.base.z)
+        assert k == want_k >= 2 and not row_tiled, (k, want_k, row_tiled)
+        facts.update(temporal_k=k, bound=bound, grid=list(grid))
         facts["bytes_after_run"] = require_balanced(devs, "jacobi 4 chips")
         facts["mcells_per_s_per_dev"] = round(r["mcells_per_s_per_dev"], 1)
     _check_spheres(dd.get_curr_global(h), size, f"jacobi3d.run {size}")
     facts["global"] = str(size)
+    deep_ex = dd.halo_exchange
     del r, dd
 
     # small global size, exactly `steps` steps from the uniform start
@@ -582,8 +598,9 @@ def phase_four_jacobi(devs, per, small, rehearsal: bool) -> dict:
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
     # and one step of the multi-block tight-x kernels from a random field
-    # (a uniform start cannot show an indexing error away from the spheres)
-    ex = (_jacobi_exchange(ssize, Dim3(1, 2, 2), devs, True) if rehearsal
+    # (a uniform start cannot show an indexing error away from the spheres),
+    # on the halos that run realized: the single step a tail would take
+    ex = (_jacobi_exchange(ssize, p122, devs, True) if rehearsal
           else r["domain"].halo_exchange)
     field = _random_field(ssize, 3)
     with PallasRecorder() as rec:
@@ -594,6 +611,33 @@ def phase_four_jacobi(devs, per, small, rehearsal: bool) -> dict:
     want = jacobi_reference(field, _masks(ssize), 1)
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
     facts["numpy_ref"] = f"{ssize} ok"
+    del r
+
+    # one dispatch at the first run's size from a random field: the
+    # deep-halo pass against the per-step path (--deep-halo 1: sweep, mask,
+    # shells) over the WHOLE field, which no sampled box can give. The
+    # rehearsal has no TPU to make run() pick, so it builds both by hand
+    csize, chunk = (ssize, 3) if rehearsal else (size, 10)
+    k = pick_temporal_depth(csize, p122, chunk)[0]
+    assert k >= 2, k
+    if rehearsal:
+        deep_ex = _jacobi_exchange(csize, p122, devs, True, radius=k)
+    field = _random_field(csize, 5)
+    outs = {}
+    for name, cex, tk in (("pass", deep_ex, k),
+                          ("step", _jacobi_exchange(csize, p122, devs, True),
+                           None)):
+        with PallasRecorder() as rec:
+            loop = make_jacobi_loop(cex, chunk, temporal_k=tk,
+                                    use_pallas=True if rehearsal else None,
+                                    interpret=rehearsal)
+            outs[name] = _run_loop(cex, loop, field, csize)
+        require_compiled_kernels(rec, ["make_pallas_jacobi_multistep"]
+                                 if tk else ["make_pallas_jacobi_sweep"],
+                                 rehearsal)
+    diff = float(np.abs(outs["pass"] - outs["step"]).max())
+    assert diff <= 2e-6, diff       # a NaN on either side fails it too
+    facts["pass_vs_step"] = f"{csize} k={k} max|diff| {diff:.3g}"
     return facts
 
 

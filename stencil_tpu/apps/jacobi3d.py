@@ -50,6 +50,12 @@ def weak_scale(x: int, y: int, z: int, num_subdomains: int) -> Dim3:
     return Dim3(x, y, z)
 
 
+def _on_tpu(devices) -> bool:
+    """Whether the Pallas path is the one that runs: the tight-x layout and
+    the temporal depth are chosen on TPUs only."""
+    return all(d.platform == "tpu" for d in devices)
+
+
 def run(
     x: int,
     y: int,
@@ -65,7 +71,7 @@ def run(
     partition=None,
     warmup: int = 1,
     chunk: Optional[int] = None,
-    deep_halo: int = 1,
+    deep_halo: Optional[int] = None,
     multistep_rows: Optional[int] = None,
     metrics_dma: bool = False,
     ckpt_dir: Optional[str] = None,
@@ -87,13 +93,29 @@ def run(
     replan: bool = False,
     replan_probe: bool = False,
 ) -> dict:
+    """One jacobi3d campaign: realize the domain, warm up, run ``iters``
+    steps in dispatches of ``chunk`` (default ``min(iters, 10)``), report.
+
+    ``deep_halo`` is the depth K of the realized halos, and with it the
+    temporal depth of the fused loop across chips. ``None`` (the default):
+    the application picks. On TPUs, on a tight-x mesh with more than one
+    block (x not split, an even split, one block a device), with overlap
+    on, ``Method.AXIS_COMPOSED`` and no ``kernel_variant``, that is
+    :func:`~stencil_tpu.ops.pallas_stencil.pick_temporal_depth` of the
+    global size, the partition and ``chunk``: the deepest K <= ``chunk``
+    whose multistep staging fits VMEM, a divisor of ``chunk`` where one
+    fits, so that a dispatch is one radius-K exchange and one K-step pass
+    at a time. Everywhere else it is 1, the layout every run had before.
+    An integer (1 included) overrides the pick. What was chosen is the
+    counter ``jacobi.temporal_depth`` (``chunk``, ``passes``,
+    ``single_steps``, ``halo_zyx``, ``bound``)."""
     # kernel_variant is the tuned-plan vocabulary ("fused" / "persistent",
     # plan/ir.py); --fused stays as the historical spelling of the former
     if fused and kernel_variant is None:
         kernel_variant = "fused"
     if kernel_variant == "fused":
         fused = True
-    elif kernel_variant == "persistent" and deep_halo < 2:
+    elif kernel_variant == "persistent" and (deep_halo or 1) < 2:
         raise ValueError(
             "kernel_variant='persistent' is the whole-chunk temporal "
             "fusion: it needs --deep-halo >= 2 (the chunk depth k; the "
@@ -110,7 +132,7 @@ def run(
     end_realize = rec.open_span("jacobi.realize", phase="init")
     if (weak and n > 1 and partition is None
             and x % 128 == 0
-            and all(d.platform == "tpu" for d in devices)):
+            and _on_tpu(devices)):
         # TPU-first weak scaling: grow + split over z/y only
         # (geometry.decompose_zy) so every chip keeps the tight-x layout
         # and the mesh is a 2D ICI-friendly z x y grid; the reference's
@@ -125,24 +147,40 @@ def run(
         size = weak_scale(x, y, z, n) if weak else Dim3(x, y, z)
 
     dd = DistributedDomain(size.x, size.y, size.z)
-    # deep_halo > 1 realizes radius-k halos so the fused loop can take the
-    # communication-avoiding multistep on multi-block meshes (one radius-k
-    # exchange per k steps); the workload stays radius-1 jacobi
-    tight_x = False
+    # the steps a dispatch runs: the temporal depth below is cut to it
+    stepwise = paraview and paraview_every > 0
+    if chunk is None:
+        chunk = 1 if stepwise else min(iters, 10)
+    chunk = min(chunk, iters)
     pdim = None
     if partition is not None:
         pdim = Dim3.of(partition)
     elif n == 1:
         pdim = Dim3(1, 1, 1)
-    if (pdim is not None and pdim.x == 1 and pdim.flatten() == n
-            and size.x % 128 == 0
-            and size.y % pdim.y == 0 and size.z % pdim.z == 0
-            # no in-kernel x wrap in the global AUTO_SPMD program, and the
-            # REMOTE_DMA carrier/emulation assumes inline halos everywhere
-            and method not in (Method.AUTO_SPMD, Method.REMOTE_DMA)
-            and not autotune  # the tuner may pick AUTO_SPMD, which cannot
-                              # run the tight-x no-x-halo layout
-            and all(d.platform == "tpu" for d in devices)):
+    tight_x = (
+        pdim is not None and pdim.x == 1 and pdim.flatten() == n
+        and size.x % 128 == 0
+        and size.y % pdim.y == 0 and size.z % pdim.z == 0
+        # no in-kernel x wrap in the global AUTO_SPMD program, and the
+        # REMOTE_DMA carrier/emulation assumes inline halos everywhere
+        and method not in (Method.AUTO_SPMD, Method.REMOTE_DMA)
+        and not autotune  # the tuner may pick AUTO_SPMD, which cannot
+                          # run the tight-x no-x-halo layout
+        and _on_tpu(devices))
+    # radius-k halos let the fused loop take the communication-avoiding
+    # multistep on multi-block meshes (one radius-k exchange per k steps);
+    # the workload stays radius-1 jacobi. The application picks k from what
+    # it observes; an explicit deep_halo overrides the pick
+    if deep_halo is not None:
+        depth_bound = "explicit"
+    elif (tight_x and overlap and method == Method.AXIS_COMPOSED
+            and kernel_variant is None):
+        from ..ops.pallas_stencil import pick_temporal_depth
+
+        deep_halo, depth_bound = pick_temporal_depth(size, pdim, chunk)
+    else:
+        deep_halo, depth_bound = 1, "mesh"
+    if tight_x:
         # tight-x layout: a single-BLOCK x axis wraps x in-kernel (lane
         # rolls), so no x halo columns are allocated — every slab DMA
         # sheds the px/nx lane padding (1.36x at 512^3, BASELINE.md round
@@ -153,7 +191,6 @@ def run(
         from ..geometry import Radius
 
         dd.set_radius(Radius.constant(deep_halo).without_x())
-        tight_x = True
     else:
         dd.set_radius(deep_halo)
     dd.set_methods(method)
@@ -179,6 +216,14 @@ def run(
     h = dd.add_data("temperature", "float32")
     dd.realize()
     end_realize()
+    rad = dd.spec.radius
+    # once a run(): the depth the halos were realized for, and how a
+    # dispatch of `chunk` steps divides into deep-halo passes at it
+    passes, single_steps = (divmod(chunk, deep_halo) if deep_halo >= 2
+                            else (0, chunk))
+    rec.counter("jacobi.temporal_depth", value=deep_halo, phase="init",
+                chunk=chunk, passes=passes, single_steps=single_steps,
+                halo_zyx=[rad.z(1), rad.y(1), rad.x(1)], bound=depth_bound)
     if autotune:
         method = dd._method  # the tuned method labels the CSV row
 
@@ -214,20 +259,16 @@ def run(
             os._exit(17)
 
     curr, nxt = dd.get_curr(h), dd.get_next(h)
-    stepwise = paraview and paraview_every > 0
-    if chunk is None:
-        chunk = 1 if stepwise else min(iters, 10)
-    chunk = min(chunk, iters)
+    # the halos' depth pins the temporal depth at k=deep_halo on EVERY
+    # device count — a single-block run would otherwise take the full
+    # default depth (no radius bound) and poison weak-scaling columns
+    # against radius-capped N-chip runs (ADVICE r3)
+    tk = deep_halo if deep_halo >= 2 else None
 
     loops = {}  # iters-per-call -> compiled fn
 
     def get_loop(k: int):
         if k not in loops:
-            # an explicit deep_halo pins the temporal depth at k=deep_halo on
-            # EVERY device count — a single-block run would otherwise take
-            # the full default depth (no radius bound) and poison weak-scaling
-            # columns against radius-capped N-chip runs (ADVICE r3)
-            tk = deep_halo if deep_halo >= 2 else None
             # the persistent chunk driver owns ALL call sizes (a 1-iter
             # call is its depth-1 tail chunk); make_jacobi_step has no
             # chunk schedule
@@ -492,7 +533,7 @@ def run(
                         make_jacobi_loop(
                             dd.halo_exchange, chunk, overlap=overlap,
                             use_pallas=True,
-                            temporal_k=deep_halo if deep_halo >= 2 else None,
+                            temporal_k=tk,
                             multistep_rows=multistep_rows),
                         (curr, nxt, sel),
                     ),
@@ -651,10 +692,16 @@ def main(argv: Optional[list] = None) -> int:
                         "O(steps)")
     p.add_argument("--prefix", type=str, default="")
     p.add_argument("--cpu", type=int, default=0, help="force N virtual CPU devices")
-    p.add_argument("--deep-halo", type=int, default=1,
-                   help="realize radius-K halos so the fused loop advances K "
-                        "steps per exchange on multi-block meshes "
-                        "(communication-avoiding temporal blocking)")
+    p.add_argument("--deep-halo", type=int, default=None,
+                   help="override the halo depth K the application picks: "
+                        "radius-K halos let the fused loop advance K steps "
+                        "per exchange on multi-block meshes "
+                        "(communication-avoiding temporal blocking). "
+                        "Default: on TPUs with a tight-x multi-block mesh "
+                        "and overlap, the deepest K <= the dispatch's steps "
+                        "whose staging fits VMEM, a divisor of them where "
+                        "one fits (ops/pallas_stencil.pick_temporal_depth); "
+                        "1 everywhere else")
     p.add_argument("--multistep-rows", type=int, default=None,
                    help="force the temporal multistep's row-strip height "
                         "(default: automatic — full planes while they reach "

@@ -185,6 +185,16 @@ NAME_FIELDS = {
                                  ("strips", int), ("halo_rows", int),
                                  ("rows_computed", int), ("rows_kept", int),
                                  ("vmem_bytes", int)),
+    # once a jacobi3d.run(): the depth k (value) its halos were realized
+    # for, the steps a dispatch runs, how they divide into deep-halo
+    # passes of k and single steps (k = 1: no pass, the loop builder's own
+    # choice), the realized radius and what set k: "chunk", "cap", "vmem",
+    # "block", "mesh" (ops/pallas_stencil.pick_temporal_depth) or
+    # "explicit" (--deep-halo). No benchmark reader:
+    # kernel_scope_ms_per_iter and halo_scope_ms.app show the effect
+    "jacobi.temporal_depth": (("chunk", int), ("passes", int),
+                              ("single_steps", int), ("halo_zyx", list),
+                              ("bound", str)),
 }
 
 # The sanctioned metric-name vocabulary: every LITERAL name the library

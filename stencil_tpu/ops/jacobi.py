@@ -892,19 +892,12 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
     # block edges (deep-halo x needs radius >= k, which tight-x lacks)
     if (pallas_sweep is not None and pallas_axes is not None and not side_x
             and standard_spheres and iters and spec.is_uniform()):
-        import os
+        from .pallas_stencil import (MULTISTEP_VMEM_BUDGET,
+                                     plan_multistep_staging,
+                                     temporal_depth_cap)
 
-        from .pallas_stencil import plan_multistep_staging
-
-        budget = 46 * 1024 * 1024  # measured compile ceiling minus headroom
-        try:
-            hard_cap = int(os.environ.get("STENCIL_TEMPORAL_K_CAP", "12"))
-        except ValueError as e:
-            raise ValueError(
-                "STENCIL_TEMPORAL_K_CAP must be an integer, got "
-                f"{os.environ['STENCIL_TEMPORAL_K_CAP']!r}"
-            ) from e
-        k_want = max(0, min(hard_cap, (spec.base.z - 1) // 2, iters))
+        k_want = max(0, min(temporal_depth_cap(), (spec.base.z - 1) // 2,
+                            iters))
         if temporal_k is not None:
             k_want = min(k_want, temporal_k)
         if pallas_axes:
@@ -923,7 +916,8 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
         # staging plan: full planes while they reach k_want, row strips
         # when the plane size would otherwise self-cap the depth (the
         # 768^3 regime: k=4 full-plane -> k=12 row-tiled)
-        k_cap, STRIP_ROWS = plan_multistep_staging(spec, k_want, budget)
+        k_cap, STRIP_ROWS = plan_multistep_staging(spec, k_want,
+                                                   MULTISTEP_VMEM_BUDGET)
         if multistep_rows is not None:
             from .pallas_stencil import valid_strip_rows
 

@@ -53,6 +53,10 @@ _VMEM_BUDGET = 12 * 1024 * 1024
 # multistep input ring: 3 live planes + 1 in flight
 _N_IN = 4
 
+# VMEM the multistep's staging may take: the measured compile ceiling minus
+# headroom
+MULTISTEP_VMEM_BUDGET = 46 * 1024 * 1024
+
 # row-strip candidates for the row-tiled multistep staging (largest first:
 # wider strips mean fewer strip-start pipeline restarts and less overlap
 # recompute at uneven splits)
@@ -426,6 +430,62 @@ def plan_multistep_staging(spec: GridSpec, k_want: int, budget: int):
                     <= budget):
                 return k, ty
     return max(0, k_full), None
+
+
+def temporal_depth_cap() -> int:
+    """The deepest temporal multistep any build takes: 12, where the tight-x
+    kernels plateau (ops/jacobi.py, the temporal-blocking note);
+    ``STENCIL_TEMPORAL_K_CAP`` probes deeper."""
+    import os
+
+    try:
+        return int(os.environ.get("STENCIL_TEMPORAL_K_CAP", "12"))
+    except ValueError as e:
+        raise ValueError(
+            "STENCIL_TEMPORAL_K_CAP must be an integer, got "
+            f"{os.environ['STENCIL_TEMPORAL_K_CAP']!r}"
+        ) from e
+
+
+def pick_temporal_depth(size, partition, chunk: int,
+                        budget: int = MULTISTEP_VMEM_BUDGET) -> Tuple[int, str]:
+    """``(k, bound)``: how deep an application on the tight-x layout realizes
+    its y/z halos so that a dispatch of ``chunk`` steps runs as deep-halo
+    multistep passes (one radius-k exchange, then k steps in one pass over
+    the block), and which of ``"chunk"`` / ``"cap"`` / ``"vmem"`` /
+    ``"block"`` / ``"mesh"`` set it.
+
+    1 (``"mesh"``) where that kernel cannot engage: one block (it wraps in
+    the kernel at any radius), a split x axis (side buffers), an uneven
+    split. Else the deepest k <= ``chunk`` whose staging
+    (:func:`plan_multistep_staging`, the planner the loop builder asks) fits
+    ``budget`` at the DEEP plane, which grows with k; the wavefront needs
+    nz >= 2k + 1 and a halo comes from one neighbour, so k <= ny. A k that
+    divides ``chunk`` is preferred: the dispatch is then passes only, and no
+    single step runs its shells over deep halos."""
+    from ..geometry import Radius
+
+    g, d = Dim3.of(size), Dim3.of(partition)
+    if (d.flatten() == 1 or d.x > 1 or g.x % 128
+            or g.y % d.y or g.z % d.z):
+        return 1, "mesh"
+    ny, nz = g.y // d.y, g.z // d.z
+    k_block = min((nz - 1) // 2, ny if d.y > 1 else nz)
+    cap = temporal_depth_cap()
+    k_geo = min(chunk, cap, k_block)
+
+    def fits(k):
+        spec = GridSpec(g, d, Radius.constant(k).without_x())
+        return plan_multistep_staging(spec, k, budget)[0] >= k
+
+    fitting = [k for k in range(k_geo, 1, -1) if fits(k)]
+    dividing = [k for k in fitting if chunk % k == 0]
+    k = (dividing or fitting or [1])[0]
+    if k_geo >= 2 and (not fitting or fitting[0] < k_geo):
+        return k, "vmem"
+    if k_block < min(chunk, cap):
+        return k, "block"
+    return k, "cap" if cap < chunk else "chunk"
 
 
 def _staged_columns(spec: GridSpec) -> int:

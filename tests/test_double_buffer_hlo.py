@@ -11,6 +11,7 @@ on-chip-measurement guide, section 2): only the worker that gets this file
 loads libtpu.
 """
 
+import math
 import os
 import re
 
@@ -63,13 +64,21 @@ def _exchange(topo, n, radius, dim):
     return HaloExchange(spec, grid_mesh(d, list(topo.devices)[:d.flatten()]))
 
 
-def _jacobi(dim, iters):
+def _jacobi(dim, iters, deep_halo=None):
+    """``jacobi3d.run``'s loop of ``iters`` steps a dispatch at 512^3 a
+    chip: halos and depth as the application picks them for that dispatch,
+    or as ``--deep-halo`` pins them."""
     def build(topo):
+        from stencil_tpu.geometry import Dim3
         from stencil_tpu.obs import scopes
         from stencil_tpu.ops.jacobi import make_jacobi_loop
+        from stencil_tpu.ops.pallas_stencil import pick_temporal_depth
 
-        ex = _exchange(topo, 512, 1, dim)
-        make_jacobi_loop(ex, iters)
+        d = Dim3(*dim)
+        k = deep_halo or pick_temporal_depth(
+            Dim3(512 * d.x, 512 * d.y, 512 * d.z), d, iters)[0]
+        ex = _exchange(topo, 512, k, dim)
+        make_jacobi_loop(ex, iters, temporal_k=k if k >= 2 else None)
         return scopes.JACOBI_LOOP, ex.spec
 
     return build
@@ -88,9 +97,15 @@ def _astaroth(topo):
 
 
 LOOPS = {
-    # the application's default 10 iterations a dispatch, and an odd count
+    # the application's default 10 iterations a dispatch (since PR 31 one
+    # radius-10 exchange and one k = 10 pass), an odd count (k = 11), and a
+    # checkpoint-clamped dispatch on the halos of ten (one k = 4 pass)
     "jacobi512x4.weak.iters10": _jacobi((1, 2, 2), 10),
     "jacobi512x4.weak.iters11": _jacobi((1, 2, 2), 11),
+    "jacobi512x4.weak.iters4.halo10": _jacobi((1, 2, 2), 4, deep_halo=10),
+    # --deep-halo 1: the per-step sweep, its mask and its shells
+    "jacobi512x4.step.iters10": _jacobi((1, 2, 2), 10, deep_halo=1),
+    "jacobi512x4.step.iters11": _jacobi((1, 2, 2), 11, deep_halo=1),
     "jacobi512.steady.iters10": _jacobi((1, 1, 1), 10),
     "astaroth256.steady.iters1": _astaroth,
 }
@@ -110,6 +125,61 @@ def test_no_whole_block_copy_in_the_compiled_loop(name, topo, as_on_the_chip):
              if block in shape]
     assert not whole, (
         f"{len(whole)} whole-block copies in {module}: {whole}")
+
+
+_CALL = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*custom-call\(.*"
+                   r'custom_call_target="tpu_custom_call"', re.M)
+
+
+def test_the_four_chip_dispatch_is_one_exchange_and_one_pass(
+        topo, as_on_the_chip):
+    """``jacobi512x4.weak``'s own loop (512^3 a chip on (1,2,2), ten steps a
+    dispatch) at the depth ``jacobi3d.run`` picks: ONE radius-10 exchange
+    and ONE ``jacobi_multistep`` call, no loop, nothing under
+    ``stencil.mask`` or ``stencil.sweep.shell`` (0.62 and 0.29 ms an
+    iteration of the per-step path, PERF.md section 5), no ``copy`` of a
+    block, and two buffers are all it holds. The copies that are there are
+    XLA's own relayout of the 10-row y slabs round the permutes."""
+    from stencil_tpu.geometry import Dim3
+    from stencil_tpu.obs import scopes, telemetry
+    from stencil_tpu.ops.pallas_stencil import pick_temporal_depth
+
+    d = Dim3(1, 2, 2)
+    k, bound = pick_temporal_depth(Dim3(512, 1024, 1024), d, 10)
+    assert (k, bound) == (10, "chunk")
+    scopes.clear()
+    module, spec = _jacobi((1, 2, 2), 10)(topo)
+    staged = telemetry.get().records(
+        kind="counter", name="kernel.multistep.staging")[-1]
+    assert staged["k"] == k and staged["rows"] == 0
+    assert staged["rows_computed"] == k * 512 + k * (k - 1)
+    assert staged["vmem_bytes"] <= 46 * 1024 * 1024
+    p = spec.padded()
+    assert (p.z, p.y, p.x) == (532, 544, 512)
+
+    rec = scopes._registry[module][-1]
+    compiled = rec["fn"].lower(*rec["args"]).compile()
+    text = compiled.as_text()
+    assert _CALL.findall(text) == ["jacobi_multistep.1"]
+    assert "while(" not in text, "one k = 10 pass needs no loop"
+    # y and z, both directions
+    assert len(re.findall(r" collective-permute-start\(", text)) == 4
+    used = {v["scope"] for v in scopes.op_map(module).values()}
+    assert scopes.KERNEL_PREFIX + "jacobi_multistep" in used
+    assert not used & {scopes.MASK, scopes.SWEEP_SHELL}, used
+    slab = 4 * p.z * k * p.x
+    for instr, shape in _COPY.findall(text):
+        dims = [int(n) for n in re.search(r"\[([\d,]*)\]", shape).group(1)
+                .split(",") if n]
+        assert 4 * math.prod(dims) <= slab, (instr, shape)
+
+    mem = compiled.memory_analysis()
+    buffer = 4 * p.z * p.y * p.x
+    assert buffer == 592_707_584
+    # curr and nxt, donated and aliased to the results; sel is not read
+    assert mem.argument_size_in_bytes == 2 * buffer
+    assert mem.alias_size_in_bytes == 2 * buffer
+    assert mem.temp_size_in_bytes == 0
 
 
 _RESULT = re.compile(
