@@ -17,7 +17,9 @@ on the TPU this process finds (one chip, or the four chips of one host):
 - serving: 16 jacobi jobs of 64^3 through ``serve.ServeScheduler``, each
   result bit-identical to ``campaign.run_sequential`` on the same chip;
 - with four chips: weak-scaled jacobi3d (global 512x1024x1024 on (1,2,2),
-  multi-block tight-x, overlap on) and the r3 4 x fp32 exchange at 512^3
+  multi-block tight-x, overlap on), weak-scaled Astaroth (global
+  256x512x512 on (1,2,2): fused substeps, batched exchange, overlap shells;
+  equal to the XLA path at 128^3 a chip) and the r3 4 x fp32 exchange at 512^3
   per chip with every halo cell verified — ``ppermute`` over ICI — plus a
   small global size against the numpy reference, four addressable shards
   per array and balanced device memory.
@@ -440,15 +442,10 @@ def phase_astaroth(devs, nx: int, ref_nx: int, rehearsal: bool) -> dict:
     import numpy as np
 
     from stencil_tpu.apps import astaroth
-    from stencil_tpu.astaroth.integrate import (FIELDS, make_astaroth_step,
-                                                uses_pallas)
+    from stencil_tpu.astaroth.integrate import uses_pallas
 
     dev = devs[:1]
     kernels = ["make_pallas_substep"]
-
-    def fields(r):
-        return {k: r["domain"].get_curr_global(r["handles"][k])
-                for k in FIELDS}
 
     with PallasRecorder() as rec:
         r = astaroth.run(iters=3, nx=nx, dtype="float32", devices=dev)
@@ -458,18 +455,36 @@ def phase_astaroth(devs, nx: int, ref_nx: int, rehearsal: bool) -> dict:
         require_compiled_kernels(rec, kernels, rehearsal)
         assert len(rec.of("make_pallas_substep")) == 3
         facts["fills"] = sorted(r["domain"].halo_exchange._self_fills)
-    for name, f in fields(r).items():
+    for name, f in _astaroth_fields(r).items():
         assert np.isfinite(f).all(), f"astaroth {nx}^3: {name} not finite"
     del r
+    facts["vs_xla"] = _astaroth_vs_xla(dev, ref_nx, rehearsal)
+    return facts
 
-    want = astaroth.run(iters=2, nx=ref_nx, dtype="float32", devices=dev,
+
+def _astaroth_fields(r) -> dict:
+    from stencil_tpu.astaroth.integrate import FIELDS
+
+    return {k: r["domain"].get_curr_global(r["handles"][k]) for k in FIELDS}
+
+
+def _astaroth_vs_xla(devs, nx: int, rehearsal: bool) -> str:
+    """Three iterations at ``nx``^3 a device through ``apps.astaroth.run``
+    on ``devs``: the fused kernels against the XLA path, every cell of every
+    field, to the tolerance tests/test_pallas_astaroth.py uses."""
+    import numpy as np
+
+    from stencil_tpu.apps import astaroth
+    from stencil_tpu.astaroth.integrate import FIELDS, make_astaroth_step
+
+    want = astaroth.run(iters=2, nx=nx, dtype="float32", devices=devs,
                         use_pallas=False)
     with PallasRecorder() as rec:
         if rehearsal:
             # run() only takes the fused kernels on a TPU: drive the same
             # step builder with interpret kernels over the same 3 iterations
-            got = astaroth.run(iters=0, nx=ref_nx, dtype="float32",
-                               devices=dev, use_pallas=False, no_compute=True)
+            got = astaroth.run(iters=0, nx=nx, dtype="float32",
+                               devices=devs, use_pallas=False, no_compute=True)
             dd, hs = got["domain"], got["handles"]
             step = make_astaroth_step(dd.halo_exchange, got["info"], iters=3,
                                       use_pallas=True, interpret=True)
@@ -478,16 +493,14 @@ def phase_astaroth(devs, nx: int, ref_nx: int, rehearsal: bool) -> dict:
             for k in FIELDS:
                 dd.set_curr(hs[k], curr[k])
         else:
-            got = astaroth.run(iters=2, nx=ref_nx, dtype="float32",
-                               devices=dev)
-    require_compiled_kernels(rec, kernels, rehearsal)
-    a, b = fields(got), fields(want)
+            got = astaroth.run(iters=2, nx=nx, dtype="float32", devices=devs)
+    require_compiled_kernels(rec, ["make_pallas_substep"], rehearsal)
+    a, b = _astaroth_fields(got), _astaroth_fields(want)
     for k in FIELDS:
         np.testing.assert_allclose(a[k], b[k], rtol=1e-4, atol=1e-5,
-                                   err_msg=f"astaroth {ref_nx}^3 field {k}")
-    facts["vs_xla"] = (f"{ref_nx}^3 x 3 iterations, max|diff| "
-                       f"{max(float(np.abs(a[k] - b[k]).max()) for k in FIELDS):.3g}")
-    return facts
+                                   err_msg=f"astaroth {nx}^3 field {k}")
+    return (f"{got['global']} x 3 iterations, max|diff| "
+            f"{max(float(np.abs(a[k] - b[k]).max()) for k in FIELDS):.3g}")
 
 
 # ------------------------------------------------------------ serving
@@ -641,6 +654,54 @@ def phase_four_jacobi(devs, per, small, rehearsal: bool) -> dict:
     return facts
 
 
+def phase_four_astaroth(devs, nx: int, small: int, rehearsal: bool) -> dict:
+    """Weak-scaled Astaroth over four chips through ``astaroth.run``, the
+    four-chip cell's own call (``decompose_zy`` -> (1,2,2), tight-x, batched
+    quantities, overlap on, one iteration a dispatch): three compiled
+    substep kernels, no self-fill, four permutes an exchange, the overlap
+    shells in the step plan, every field finite. Then ``small``^3 a chip on
+    the same mesh: the fused overlap step against the XLA path, every cell."""
+    import numpy as np
+
+    from stencil_tpu.apps import astaroth
+    from stencil_tpu.astaroth.integrate import FIELDS, uses_pallas
+    from stencil_tpu.geometry import Dim3
+    from stencil_tpu.obs import telemetry
+
+    with PallasRecorder() as rec:
+        r = astaroth.run(iters=3, nx=nx, dtype="float32", devices=devs)
+    dd, hs = r["domain"], r["handles"]
+    ex = dd.halo_exchange
+    facts = {"global": str(r["global"]),
+             "iter_ms": round(1e3 * r["iter_trimean_s"], 3)}
+    for k in FIELDS:
+        require_four_shards(dd.get_curr(hs[k]), devs, f"astaroth {k}")
+    if not rehearsal:
+        assert dd.spec.dim == Dim3(1, 2, 2), dd.spec.dim
+        rx = dd.spec.radius
+        assert rx.x(-1) == 0 and rx.x(1) == 0, f"not tight-x: {rx}"
+        assert uses_pallas(ex, None, "float32")
+        require_compiled_kernels(rec, ["make_pallas_substep"], rehearsal)
+        assert len(rec.of("make_pallas_substep")) == 3
+        fills = [c for c in rec.calls if c["kernel"] != "make_pallas_substep"]
+        assert not fills and not ex._self_fills, (fills, ex._self_fills)
+        plan = telemetry.get().records(kind="counter",
+                                       name="astaroth.step_plan")[-1]
+        assert (plan["mode"], plan["shells"], plan["pallas"],
+                plan["tight_x"]) == ("overlap", 4, True, True), plan
+        census = ex.collective_census(
+            {k: dd.get_curr(hs[k]) for k in FIELDS})
+        assert census["collective-permute"][0] == 4, census
+        facts.update(permutes=4, halo_bytes_sent=plan["halo_bytes_sent"],
+                     shell_cells=plan["shell_cells"])
+        facts["bytes_after_run"] = require_balanced(devs, "astaroth 4 chips")
+    for name, f in _astaroth_fields(r).items():
+        assert np.isfinite(f).all(), f"astaroth {r['global']}: {name} not finite"
+    del r, dd, ex
+    facts["vs_xla"] = _astaroth_vs_xla(devs, small, rehearsal)
+    return facts
+
+
 # ------------------------------------------------------------ the run
 
 
@@ -656,6 +717,8 @@ def build_phases(devs, rehearsal: bool) -> list:
         return [
             ("four_chip_jacobi", 4, lambda: phase_four_jacobi(
                 four, Dim3(16, 16, 16), Dim3(128, 8, 8), True)),
+            ("four_chip_astaroth", 4, lambda: phase_four_astaroth(
+                four, 16, 16, True)),
             ("four_chip_exchange", 4, lambda: phase_exchange(
                 four, Dim3(16, 32, 32), p122, True)),
             ("four_chip_exchange_x", 4, lambda: phase_exchange(
@@ -669,6 +732,9 @@ def build_phases(devs, rehearsal: bool) -> list:
     return [
         ("four_chip_jacobi", 4, lambda: phase_four_jacobi(
             four, Dim3(512, 512, 512), Dim3(128, 32, 32), False)),
+        # the small size is 128: the lane floor of the tight-x layout
+        ("four_chip_astaroth", 4, lambda: phase_four_astaroth(
+            four, 256, 128, False)),
         ("four_chip_exchange", 4, lambda: phase_exchange(
             four, Dim3(512, 1024, 1024), p122, False)),
         # exchange_weak's own pick on four chips: x is split

@@ -56,6 +56,12 @@ def decompose_zyx(p: int) -> Dim3:
     return Dim3(x, y, z)
 
 
+def _on_tpu(devices) -> bool:
+    """Every device is a TPU: what the layout decisions below ask (tests
+    turn it on to walk them on the CPU mesh)."""
+    return all(d.platform == "tpu" for d in devices)
+
+
 def run(
     iters: int = 10,
     conf: str = DEFAULT_CONF,
@@ -98,8 +104,7 @@ def run(
     benchmark driver) — the returned ``iters_run`` records the actual
     number of timed iterations the state advanced."""
     devices = list(devices) if devices is not None else jax.devices()
-    if (overlap and np.dtype(dtype) == np.float64
-            and all(d.platform == "tpu" for d in devices)
+    if (overlap and np.dtype(dtype) == np.float64 and _on_tpu(devices)
             and os.environ.get("STENCIL_F64_OVERLAP") != "1"):
         # fp64 on TPU: the serialized step compiles in ~2 min. The round-3
         # per-substep overlap structure (7 integrate regions x 3 substeps
@@ -128,7 +133,7 @@ def run(
     # stays in z/y (geometry.decompose_zy): every chip keeps the tight-x
     # layout, no minor-dim slab slicing, 2D ICI mesh — the reference's
     # 3-axis decompose_zyx (astaroth.cu:263-276) remains for CPU.
-    if len(devices) > 1 and all(d.platform == "tpu" for d in devices):
+    if len(devices) > 1 and _on_tpu(devices):
         from ..geometry import decompose_zy
 
         d3 = decompose_zy(len(devices))
@@ -154,10 +159,14 @@ def run(
 
         tight = radius.without_x()
         tight_spec = GridSpec(size, d3, tight)
-        if (np.dtype(dtype) == np.float32
-                and all(d.platform == "tpu" for d in devices)
+        if (np.dtype(dtype) == np.float32 and _on_tpu(devices)
                 and substep_supported(tight_spec, jnp.float32)):
             radius = tight
+            # the layout holds on the partition the domain was sized for:
+            # left to itself, realize() splits the axis with the smallest
+            # halo interface first, and with no x halo that is x (PR 33:
+            # four chips stopped in make_astaroth_step on a split x axis)
+            dd.set_partition(d3)
     dd.set_radius(radius)
     dd.set_methods(method)
     # the 8-field state is where quantity batching pays: one packed

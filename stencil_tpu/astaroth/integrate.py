@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..geometry import Dim3, Rect3, exterior_regions, interior_region
-from ..obs import scopes
+from ..obs import scopes, telemetry
 from ..ops import double_buffer
 from ..parallel.exchange import BLOCK_PSPEC, HaloExchange
 from .config import AcMeshInfo
@@ -220,6 +220,52 @@ def uses_pallas(ex: HaloExchange, use_pallas, dtype="float32") -> bool:
         all(d.platform == "tpu" for d in devs)
         and substep_supported(ex.spec, jnp.dtype(dtype))
     )
+
+
+def _record_step_plan(ex, exteriors, iters, dtype, pallas_on, tight_x,
+                      swap_per_substep, use_overlap, use_dyn_overlap):
+    """The counter ``astaroth.step_plan``, once a :func:`make_astaroth_step`
+    build: the branch its ``iteration`` takes (mode, exchanges and shell
+    passes an iteration), the rects a pass integrates from exchanged halos
+    and the cells they hold (benchmark reader shell_ns_per_cell)."""
+    spec = ex.spec
+    if pallas_on:
+        # the fused path exchanges once an iteration unless every substep
+        # swaps, and only substep 0 has shells
+        if swap_per_substep:
+            mode, exchanges, passes = "per_substep", 3, 0
+        elif use_overlap and spec.dim.flatten() > 1:
+            mode, exchanges, passes = "overlap", 1, 1
+        elif use_dyn_overlap:
+            mode, exchanges, passes = "dyn_overlap", 1, 1
+        else:
+            mode, exchanges, passes = "serial", 1, 0
+    elif use_overlap and not swap_per_substep:
+        mode, exchanges, passes = "overlap", 1, 1      # the hoisted exchange
+    else:
+        mode = ("per_substep" if swap_per_substep
+                else "dyn_overlap" if use_dyn_overlap else "serial")
+        exchanges, passes = 3, 3 if use_overlap or use_dyn_overlap else 0
+    if not passes:
+        cells = []
+    elif use_dyn_overlap:
+        # a dynamic shell's extents are static: only its offset is traced
+        from ..ops.shells import shell_regions
+
+        cells = [sz[0] * sz[1] * sz[2] for _lo, sz in shell_regions(
+            spec, (0, 0, 0), (True, True, True))]
+    else:
+        cells = [(rect.hi - rect.lo).flatten() for rect in exteriors]
+    itemsize = jnp.dtype(dtype).itemsize
+    telemetry.get().counter(
+        "astaroth.step_plan", value=iters, phase="compute",
+        module=scopes.ASTAROTH_ITER, mode=mode, pallas=pallas_on,
+        tight_x=tight_x, blocks=spec.dim.flatten(),
+        quantities=len(FIELDS), exchanges_per_iter=exchanges,
+        shells=passes * len(cells), shell_cells=passes * sum(cells),
+        block_cells=spec.base.flatten(),
+        halo_bytes_sent=ex.plan.wire_bytes([itemsize] * len(FIELDS))
+        // ex.mesh.devices.size)
 
 
 def make_astaroth_step(
@@ -520,6 +566,8 @@ def make_astaroth_step(
     field = jax.ShapeDtypeStruct(spec.stacked_shape_zyx(), jnp.dtype(dtype),
                                  sharding=ex.sharding())
     like = {k: field for k in FIELDS}
+    _record_step_plan(ex, exteriors, iters, dtype, pallas_on, tight_x,
+                      swap_per_substep, use_overlap, use_dyn_overlap)
     return double_buffer.jit_in_place(scopes.ASTAROTH_ITER, fn, (like, like),
                                       (iters,))
 

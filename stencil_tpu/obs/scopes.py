@@ -228,6 +228,8 @@ _INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _SOURCE = re.compile(r'source_file="([^"]*)"(?:\s+source_line=(\d+))?')
 _OPERAND = re.compile(r"%([\w.\-]+)")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 # what a value passes through unchanged on its way to the op that uses it
 _TRANSPARENT = {"bitcast", "get-tuple-element", "copy-start", "copy-done",
                 "optimization-barrier"}
@@ -292,13 +294,35 @@ def parse_hlo_text(text: str) -> Dict[str, dict]:
     across a module's computations)."""
     instrs: Dict[str, dict] = {}
     users: Dict[str, list] = {}
+    held: Dict[str, set] = {}       # computation -> scopes of its instructions
+    calls: Dict[str, str] = {}      # fusion -> the computation it calls
+    comp = None
     for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            comp = head.group(1)
+            continue
         ins = _parse_line(line)
         if ins is None:
             continue
         instrs[ins["instr"]] = ins
+        if ins["scope"]:
+            held.setdefault(comp, set()).add(ins["scope"])
+        called = _CALLS.search(line) if ins["opcode"] == "fusion" else None
+        if called:
+            calls[ins["instr"]] = called.group(1)
         for pos, operand in enumerate(ins["operands"]):
             users.setdefault(operand, []).append((ins["instr"], pos))
+    # a fusion the compiler rebuilt without metadata (a concatenate turned
+    # into in-place updates of a fresh buffer) is the program's where every
+    # scoped instruction it fused carries one and the same scope
+    for name, called in calls.items():
+        inner = held.get(called, ())
+        if not instrs[name]["scope"] and len(inner) == 1:
+            (scope_name,) = inner
+            layer = layer_of(scope_name)
+            instrs[name].update(scope=scope_name, layer=layer,
+                                layers=[layer] if layer else [])
 
     def producer(ins):
         seen = set()

@@ -195,6 +195,21 @@ NAME_FIELDS = {
     "jacobi.temporal_depth": (("chunk", int), ("passes", int),
                               ("single_steps", int), ("halo_zyx", list),
                               ("bound", str)),
+    # what astaroth/integrate.make_astaroth_step built, once per build
+    # (value: iterations a dispatch): the branch its iteration takes
+    # ("overlap": substep 0 over the whole block from pre-exchange data
+    # beside the exchange, then the shells; "dyn_overlap": the same on an
+    # uneven partition; "serial": exchange, then compute; "per_substep":
+    # swap_per_substep), fused kernels or XLA, the layout, blocks of the
+    # mesh, exchanges an iteration, the rects integrated from exchanged
+    # halos beside the whole-block or interior pass and the cells they hold
+    # (a block an iteration), a block's owned cells, and the bytes a chip
+    # sends an exchange by the plan (benchmark reader shell_ns_per_cell)
+    "astaroth.step_plan": (("module", str), ("mode", str), ("pallas", bool),
+                           ("tight_x", bool), ("blocks", int),
+                           ("quantities", int), ("exchanges_per_iter", int),
+                           ("shells", int), ("shell_cells", int),
+                           ("block_cells", int), ("halo_bytes_sent", int)),
 }
 
 # The sanctioned metric-name vocabulary: every LITERAL name the library

@@ -83,7 +83,8 @@ def test_rehearsal_walks_the_phases_and_never_passes(tmp_path):
 @pytest.mark.slow
 def test_rehearsal_all_phases(tmp_path):
     proc = _smoke(["--rehearsal"], tmp_path, timeout=600)
-    _check_rehearsal(proc, tmp_path, FAST_PHASES.split(",") + ["astaroth"])
+    _check_rehearsal(proc, tmp_path, FAST_PHASES.split(",") + [
+        "four_chip_exchange_x", "four_chip_astaroth", "astaroth"])
 
 
 def test_compile_cache_placement(monkeypatch):

@@ -22,36 +22,6 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 _COPY = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\S+)\s+copy\(", re.M)
 
 
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler here: skip, do not fail
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-
-
-@pytest.fixture(scope="module")
-def as_on_the_chip():
-    """x64 off (the test session turns it on; no application enables it for
-    fp32 fields, and Mosaic's lowering recurses without end under it) and no
-    persistent cache (a described-device compile cannot be read back)."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    x64 = jax.config.jax_enable_x64
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_x64", False)
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_x64", x64)
-    jax.config.update("jax_enable_compilation_cache", cache)
-    cc.reset_cache()
-
-
 def _exchange(topo, n, radius, dim):
     """The tight-x exchange the applications realize on TPU devices."""
     from stencil_tpu.domain.grid import GridSpec

@@ -5,15 +5,13 @@ planner turns to row strips, the program is the row-tiled multistep at
 k = 10 and holds no whole-block ``copy``, and two buffers are all it
 allocates. Nothing runs; a compile that passes is not a chip result.
 
-The topology is described inside a module-scoped fixture (the
-on-chip-measurement guide, section 2): only the worker that gets this file
-loads libtpu.
+The topology is described inside a module-scoped fixture of
+``tests/conftest.py`` (the on-chip-measurement guide, section 2): only the
+worker that gets this file loads libtpu.
 """
 
 import os
 import re
-
-import pytest
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -21,36 +19,6 @@ N, ITERS = 768, 10
 _COPY = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\S+)\s+copy\(", re.M)
 _CALL = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*custom-call\(.*"
                    r'custom_call_target="tpu_custom_call"', re.M)
-
-
-@pytest.fixture(scope="module")
-def topo():
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler here: skip, do not fail
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-
-
-@pytest.fixture(scope="module")
-def as_on_the_chip():
-    """x64 off and no persistent cache, as tests/test_double_buffer_hlo.py
-    (Mosaic's lowering recurses without end under x64; a described-device
-    compile cannot be read back from the cache)."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    x64 = jax.config.jax_enable_x64
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_x64", False)
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_x64", x64)
-    jax.config.update("jax_enable_compilation_cache", cache)
-    cc.reset_cache()
 
 
 def test_the_768_loop_is_the_row_tiled_multistep_at_depth_10(

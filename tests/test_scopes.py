@@ -169,6 +169,44 @@ def test_op_map_reads_tpu_style_text_copies_and_kernels():
     assert any(c["opcode"] == "tuple" for c in copy["consumers"])
 
 
+def test_a_fusion_without_metadata_takes_the_scope_its_ops_agree_on():
+    """XLA rebuilds the carriers' concatenate as in-place updates of a fresh
+    buffer; the fusion it makes has no metadata, the slices it fused have
+    (the four-chip Astaroth step, PR 33). One scope inside: the fusion's.
+    Two, or none: the compiler's, as before."""
+    text = '''HloModule jit_stencil_astaroth_iter, is_scheduled=true
+%fused_computation.1 (param_0: f32[8,128]) -> f32[2,8,128] {
+  %custom-call.3 = f32[2,8,128]{2,1,0} custom-call(), custom_call_target="AllocateBuffer"
+  %param_0 = f32[8,128]{1,0} parameter(0)
+  %slice.1 = f32[3,128]{1,0} slice(%param_0), slice={[0:3], [0:128]}, metadata={op_name="jit(stencil_astaroth_iter)/shard_map/stencil.halo.pack/dynamic_slice"}
+  ROOT %dynamic-update-slice.1 = f32[2,8,128]{2,1,0} dynamic-update-slice(%custom-call.3, %slice.1)
+}
+%fused_computation.2 (param_0.1: f32[8,128]) -> f32[8,128] {
+  %param_0.1 = f32[8,128]{1,0} parameter(0)
+  %slice.2 = f32[8,128]{1,0} slice(%param_0.1), slice={[0:8], [0:128]}, metadata={op_name="jit(stencil_astaroth_iter)/shard_map/stencil.halo.pack/dynamic_slice"}
+  ROOT %add.2 = f32[8,128]{1,0} add(%slice.2, %slice.2), metadata={op_name="jit(stencil_astaroth_iter)/shard_map/stencil.sweep.shell/add"}
+}
+%fused_computation.3 (param_0.2: f32[8,128]) -> f32[8,128] {
+  %param_0.2 = f32[8,128]{1,0} parameter(0)
+  ROOT %copy.3 = f32[8,128]{1,0} copy(%param_0.2)
+}
+ENTRY %main (p: f32[8,128]) -> f32[8,128] {
+  %p = f32[8,128]{1,0} parameter(0)
+  %fusion.1 = f32[2,8,128]{2,1,0} fusion(%p), kind=kLoop, calls=%fused_computation.1
+  %fusion.2 = f32[8,128]{1,0} fusion(%p), kind=kLoop, calls=%fused_computation.2
+  %fusion.3 = f32[8,128]{1,0} fusion(%p), kind=kLoop, calls=%fused_computation.3
+  ROOT %fusion.4 = f32[8,128]{1,0} fusion(%p), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(stencil_astaroth_iter)/shard_map/stencil.carry/reshape"}
+}
+'''
+    omap = scopes.parse_hlo_text(text)
+    assert omap["fusion.1"]["scope"] == scopes.HALO_PACK
+    assert omap["fusion.1"]["layer"] == scopes.LAYER_HALO
+    assert omap["fusion.1"]["layers"] == [scopes.LAYER_HALO]
+    assert omap["fusion.2"]["scope"] is None        # two scopes inside
+    assert omap["fusion.3"]["scope"] is None        # none inside
+    assert omap["fusion.4"]["scope"] == scopes.CARRY    # its own stands
+
+
 # ------------------------------------------------------------ (d) bytes moved
 
 
