@@ -18,8 +18,9 @@ on the TPU this process finds (one chip, or the four chips of one host):
   result bit-identical to ``campaign.run_sequential`` on the same chip;
 - with four chips: weak-scaled jacobi3d (global 512x1024x1024 on (1,2,2),
   multi-block tight-x, overlap on), weak-scaled Astaroth (global
-  256x512x512 on (1,2,2): fused substeps, batched exchange, overlap shells;
-  equal to the XLA path at 128^3 a chip) and the r3 4 x fp32 exchange at 512^3
+  256x512x512 on (1,2,2): fused substeps after ONE batched exchange; equal
+  to the XLA path at 128^3 a chip with the overlap shells asked for and
+  without) and the r3 4 x fp32 exchange at 512^3
   per chip with every halo cell verified — ``ppermute`` over ICI — plus a
   small global size against the numpy reference, four addressable shards
   per array and balanced device memory.
@@ -468,39 +469,56 @@ def _astaroth_fields(r) -> dict:
     return {k: r["domain"].get_curr_global(r["handles"][k]) for k in FIELDS}
 
 
-def _astaroth_vs_xla(devs, nx: int, rehearsal: bool) -> str:
+def _astaroth_vs_xla(devs, nx: int, rehearsal: bool,
+                     schedules=((None, "serial"),)) -> str:
     """Three iterations at ``nx``^3 a device through ``apps.astaroth.run``
     on ``devs``: the fused kernels against the XLA path, every cell of every
-    field, to the tolerance tests/test_pallas_astaroth.py uses."""
+    field, to the tolerance tests/test_pallas_astaroth.py uses; once for
+    each ``(overlap, mode)`` of ``schedules``: what ``run()`` is asked (None:
+    it picks by itself) and the mode its step plan must then record."""
     import numpy as np
 
     from stencil_tpu.apps import astaroth
     from stencil_tpu.astaroth.integrate import FIELDS, make_astaroth_step
+    from stencil_tpu.obs import telemetry
 
     want = astaroth.run(iters=2, nx=nx, dtype="float32", devices=devs,
                         use_pallas=False)
-    with PallasRecorder() as rec:
-        if rehearsal:
-            # run() only takes the fused kernels on a TPU: drive the same
-            # step builder with interpret kernels over the same 3 iterations
-            got = astaroth.run(iters=0, nx=nx, dtype="float32",
-                               devices=devs, use_pallas=False, no_compute=True)
-            dd, hs = got["domain"], got["handles"]
-            step = make_astaroth_step(dd.halo_exchange, got["info"], iters=3,
-                                      use_pallas=True, interpret=True)
-            curr, _ = step({k: dd.get_curr(hs[k]) for k in FIELDS},
-                           {k: dd.get_next(hs[k]) for k in FIELDS})
-            for k in FIELDS:
-                dd.set_curr(hs[k], curr[k])
-        else:
-            got = astaroth.run(iters=2, nx=nx, dtype="float32", devices=devs)
-    require_compiled_kernels(rec, ["make_pallas_substep"], rehearsal)
-    a, b = _astaroth_fields(got), _astaroth_fields(want)
-    for k in FIELDS:
-        np.testing.assert_allclose(a[k], b[k], rtol=1e-4, atol=1e-5,
-                                   err_msg=f"astaroth {nx}^3 field {k}")
-    return (f"{got['global']} x 3 iterations, max|diff| "
-            f"{max(float(np.abs(a[k] - b[k]).max()) for k in FIELDS):.3g}")
+    b = _astaroth_fields(want)
+    said = []
+    for overlap, want_mode in schedules:
+        with PallasRecorder() as rec:
+            if rehearsal:
+                # run() only takes the fused kernels on a TPU: drive the same
+                # step builder with interpret kernels over the same 3
+                # iterations
+                got = astaroth.run(iters=0, nx=nx, dtype="float32",
+                                   devices=devs, use_pallas=False,
+                                   no_compute=True)
+                dd, hs = got["domain"], got["handles"]
+                step = make_astaroth_step(dd.halo_exchange, got["info"],
+                                          iters=3, use_pallas=True,
+                                          interpret=True, overlap=overlap)
+                curr, _ = step({k: dd.get_curr(hs[k]) for k in FIELDS},
+                               {k: dd.get_next(hs[k]) for k in FIELDS})
+                for k in FIELDS:
+                    dd.set_curr(hs[k], curr[k])
+            else:
+                got = astaroth.run(iters=2, nx=nx, dtype="float32",
+                                   devices=devs, overlap=overlap)
+        require_compiled_kernels(rec, ["make_pallas_substep"], rehearsal)
+        mode = telemetry.get().records(
+            kind="counter", name="astaroth.step_plan")[-1]["mode"]
+        assert mode == want_mode, (overlap, mode, want_mode)
+        a = _astaroth_fields(got)
+        for k in FIELDS:
+            np.testing.assert_allclose(
+                a[k], b[k], rtol=1e-4, atol=1e-5,
+                err_msg=f"astaroth {nx}^3 field {k}, overlap={overlap}")
+        diff = max(float(np.abs(a[k] - b[k]).max()) for k in FIELDS)
+        said.append(f"overlap={overlap}: plan {mode}, max|diff| {diff:.3g}")
+        del got
+    return f"{want['global']} x 3 iterations; " + "; ".join(said)
 
 
 # ------------------------------------------------------------ serving
@@ -657,10 +675,12 @@ def phase_four_jacobi(devs, per, small, rehearsal: bool) -> dict:
 def phase_four_astaroth(devs, nx: int, small: int, rehearsal: bool) -> dict:
     """Weak-scaled Astaroth over four chips through ``astaroth.run``, the
     four-chip cell's own call (``decompose_zy`` -> (1,2,2), tight-x, batched
-    quantities, overlap on, one iteration a dispatch): three compiled
-    substep kernels, no self-fill, four permutes an exchange, the overlap
-    shells in the step plan, every field finite. Then ``small``^3 a chip on
-    the same mesh: the fused overlap step against the XLA path, every cell."""
+    quantities, one iteration a dispatch, the schedule left to the builder:
+    exchange-first since PR 34): three compiled substep kernels, no
+    self-fill, four permutes an exchange, a serial step plan without shells,
+    every field finite. Then ``small``^3 a chip on the same mesh against the
+    XLA path, every cell: ``overlap=True`` (substep 0's shells, which no
+    default builds any more) and the default beside it."""
     import numpy as np
 
     from stencil_tpu.apps import astaroth
@@ -688,7 +708,8 @@ def phase_four_astaroth(devs, nx: int, small: int, rehearsal: bool) -> dict:
         plan = telemetry.get().records(kind="counter",
                                        name="astaroth.step_plan")[-1]
         assert (plan["mode"], plan["shells"], plan["pallas"],
-                plan["tight_x"]) == ("overlap", 4, True, True), plan
+                plan["tight_x"], plan["exchanges_per_iter"]) == (
+                    "serial", 0, True, True, 1), plan
         census = ex.collective_census(
             {k: dd.get_curr(hs[k]) for k in FIELDS})
         assert census["collective-permute"][0] == 4, census
@@ -698,7 +719,9 @@ def phase_four_astaroth(devs, nx: int, small: int, rehearsal: bool) -> dict:
     for name, f in _astaroth_fields(r).items():
         assert np.isfinite(f).all(), f"astaroth {r['global']}: {name} not finite"
     del r, dd, ex
-    facts["vs_xla"] = _astaroth_vs_xla(devs, small, rehearsal)
+    facts["vs_xla"] = _astaroth_vs_xla(
+        devs, small, rehearsal,
+        schedules=((True, "overlap"), (None, "serial")))
     return facts
 
 

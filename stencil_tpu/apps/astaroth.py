@@ -66,7 +66,7 @@ def run(
     iters: int = 10,
     conf: str = DEFAULT_CONF,
     devices=None,
-    overlap: bool = True,
+    overlap: Optional[bool] = None,
     method: Method = Method.AXIS_COMPOSED,
     trivial: bool = False,
     random_: bool = False,
@@ -102,9 +102,14 @@ def run(
     ``chunk`` does not divide ``iters``, the count is rounded UP to the next
     chunk multiple (a tail program would double the compile cost for a
     benchmark driver) — the returned ``iters_run`` records the actual
-    number of timed iterations the state advanced."""
+    number of timed iterations the state advanced.
+
+    ``overlap=None`` leaves the schedule to :func:`make_astaroth_step`
+    (exchange-first on the fused Pallas path, hoisted overlap on the XLA
+    path); ``True`` / ``False`` ask for the shells or for none."""
     devices = list(devices) if devices is not None else jax.devices()
-    if (overlap and np.dtype(dtype) == np.float64 and _on_tpu(devices)
+    if (overlap is not False and np.dtype(dtype) == np.float64
+            and _on_tpu(devices)
             and os.environ.get("STENCIL_F64_OVERLAP") != "1"):
         # fp64 on TPU: the serialized step compiles in ~2 min. The round-3
         # per-substep overlap structure (7 integrate regions x 3 substeps
@@ -576,7 +581,7 @@ def main(argv: Optional[list] = None) -> int:
             trivial=args.trivial,
             random_=args.random,
             no_compute=args.no_compute,
-            overlap=not args.no_overlap,
+            overlap=False if args.no_overlap else None,
             dtype="float64" if use_f64 else "float32",
             nx=args.nx,
             paraview_init=args.paraview_init,

@@ -30,7 +30,7 @@ recorded numbers keep the 3-substep structure.
 from __future__ import annotations
 
 import os
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -272,7 +272,7 @@ def make_astaroth_step(
     ex: HaloExchange,
     info: AcMeshInfo,
     dt: float = 1e-8,
-    overlap: bool = True,
+    overlap: Optional[bool] = None,
     swap_per_substep: bool = False,
     iters: int = 1,
     use_pallas=None,
@@ -298,12 +298,21 @@ def make_astaroth_step(
     once per iteration — legitimate because the in buffers do not change
     between substeps in reference swap-per-iteration mode, and
     re-exchanged before every substep in swap_per_substep mode. With
-    ``overlap`` on a multi-block mesh, that one exchange is scheduled
+    ``overlap=True`` on a multi-block mesh, that one exchange is scheduled
     concurrently with substep 0's full-region kernel pass (which reads
     pre-exchange data); the multi-block-axis shells of substep 0 are then
     re-integrated from the exchanged halos — the reference's
     interior/exterior overlap re-expressed as dataflow with the fused
-    kernel as the interior.
+    kernel as the interior. With ``overlap=False`` the exchange runs
+    first and every substep reads exchanged halos.
+
+    ``overlap=None`` (the default) is resolved from the path this builder
+    takes: exchange-first on the fused Pallas path, the hoisted-overlap
+    iteration on the XLA path. A shell cell re-integrated in XLA beside the
+    fused kernel costs 9.95 ns where the 32 B it sends cost 0.8 ns on the
+    wire (four v5e chips, PRs 33 and 34), at every block size, so the
+    shells cannot pay for the one permute they hide. On the XLA path a
+    shell cell costs what an interior cell costs, and the overlap stays.
 
     ``kernel_variant`` selects the fused kernel's sliding-window
     discipline: ``"shift"`` (plane-copy window shifts) or ``"ring"``
@@ -317,6 +326,8 @@ def make_astaroth_step(
         raise ValueError("astaroth needs face radius >= 3 (6th-order "
                          "stencils)")
     pallas_on = uses_pallas(ex, use_pallas, dtype)
+    if overlap is None:
+        overlap = not pallas_on
     tight_x = min(r.x(-1), r.x(1)) < 3
     if tight_x:
         # zero-x-radius tight layout (Radius.without_x): no x halo columns;
@@ -474,6 +485,8 @@ def make_astaroth_step(
                             spec, 0, lo, size, inv_ds, c, dt, curr, out
                         )
             else:
+                # exchange-first, what overlap=None resolves to on this
+                # path: substep 0 reads exchanged halos like 1 and 2
                 curr = exchange_all(curr)
                 out = run_kernel(0, curr, out)
             for s in (1, 2):

@@ -744,6 +744,41 @@ def test_oversubscribed_uneven_xy_overlap_falls_back():
                                    err_msg=k)
 
 
+@pytest.mark.parametrize("size, asked, mode", [
+    # the XLA path keeps the hoisted-overlap iteration: a shell cell costs
+    # what an interior cell costs there
+    ((16, 16, 16), dict(use_pallas=False), "overlap"),
+    # the fused path goes exchange-first, uneven partitions (the
+    # dyn_overlap twin) alike; asked for, the shells are still built
+    ((19, 16, 14), dict(use_pallas=True, interpret=True), "serial"),
+    ((19, 16, 14), dict(use_pallas=True, interpret=True, overlap=True),
+     "dyn_overlap"),
+    ((16, 16, 16), dict(use_pallas=True, interpret=True), "serial"),
+    ((16, 16, 16), dict(use_pallas=True, interpret=True, overlap=True),
+     "overlap"),
+], ids=["xla", "fused-uneven", "fused-uneven-asked", "fused", "fused-asked"])
+def test_overlap_none_is_resolved_from_the_path_the_builder_takes(
+        size, asked, mode):
+    """``make_astaroth_step(overlap=None)``, the default (PR 34): read from
+    the plan the build records, nothing traced."""
+    from stencil_tpu.obs import telemetry
+
+    info = ac_config.AcMeshInfo()
+    with open(DEFAULT_CONF) as f:
+        ac_config.parse_config(f.read(), info)
+    for axis, n in zip("xyz", size):
+        info.int_params[f"AC_n{axis}"] = n
+    info.update_builtin_params()
+    spec = GridSpec(Dim3(*size), Dim3(2, 2, 2), Radius.constant(3))
+    assert spec.is_uniform() == (size[0] % 2 == 0)
+    ex = HaloExchange(spec, grid_mesh(spec.dim, jax.devices()[:8]))
+    make_astaroth_step(ex, info, dtype="float32", **asked)
+    plan = telemetry.get().records(kind="counter",
+                                   name="astaroth.step_plan")[-1]
+    assert (plan["mode"], plan["exchanges_per_iter"]) == (mode, 1)
+    assert (plan["shells"] > 0) == (mode != "serial")
+
+
 def test_reductions_on_oversubscribed_mesh():
     """Masked reductions with 2 z-blocks resident per device: the local
     reduce spans the residents, the collectives run over the smaller mesh."""

@@ -1,11 +1,17 @@
 """The layout the four-chip Astaroth cell runs (``astaroth256x4.weak``), at a
 size tier-1 can afford: tight-x blocks on a (1,2,2) mesh, the fused substep
-kernels (interpret mode), ONE batched exchange an iteration and substep 0's
-overlap shells re-integrated from the exchanged halos over x-wrapped slabs
+kernels (interpret mode) and ONE batched exchange an iteration, in both
+schedules the fused path has. ``serial`` is exchange-first, what the cell
+runs since PR 34 (``overlap=None``, the default, resolves to it on the
+fused path: ``run()``'s own call and the plan at the cell's block say so
+below). ``overlap`` is what ``overlap=True`` still builds and the cell ran
+until then: substep 0's kernel on pre-exchange data and its overlap shells
+re-integrated from the exchanged halos over x-wrapped slabs
 (``_integrate_shell_wrap_x``). Every owned cell of the 8 fields is held
 against the benchmark's plain float64 reference iterated on the periodic
-global field, the overlap build against the serial one, and the build-time
-counter ``astaroth.step_plan`` against the geometry counted the slow way.
+global field in both, the overlap build against the serial one, and the
+build-time counter ``astaroth.step_plan`` against the geometry counted the
+slow way.
 
 The tier-1 form of ``tests/test_astaroth.py::
 test_tight_x_multiblock_yz_matches_reference`` (marked slow), whose
@@ -100,15 +106,17 @@ def x4():
             curr, nxt = step(curr, nxt)
         runs[mode] = ({k: unshard_blocks(curr[k], spec) for k in FIELDS},
                       plans)
-    return {"runs": runs, "seeded": seeded, "spec": spec}
+    return {"runs": runs, "seeded": seeded, "spec": spec,
+            "want": _reference(seeded, ITERS)}
 
 
+@pytest.mark.parametrize("schedule", ["serial", "overlap"])
 @pytest.mark.parametrize("field", FIELDS)
-def test_every_owned_cell_matches_the_plain_reference(x4, field):
+def test_every_owned_cell_matches_the_plain_reference(x4, field, schedule):
     """The whole global field, so every shell, both block seams and every
     periodic wrap: the tolerance of the slow test it stands in for."""
-    got = x4["runs"]["overlap"][0][field]
-    want = _reference(x4["seeded"], ITERS)[field]
+    got = x4["runs"][schedule][0][field]
+    want = x4["want"][field]
     assert got.shape == want.shape == (NZ, NY, NX)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=1e-6,
                                err_msg=field)
@@ -169,28 +177,31 @@ def test_shell_cells_are_the_exteriors_counted_the_slow_way(x4):
     assert plan["shell_cells"] == NX * (by * bz - (by - 6) * (bz - 6))
 
 
-@pytest.mark.parametrize("dim, mode, shells", [
-    ((1, 1, 1), "serial", 0),       # what astaroth256.steady builds
-    ((1, 2, 2), "overlap", 4),      # what astaroth256x4.weak builds
+@pytest.mark.parametrize("dim, overlap, mode, shells", [
+    ((1, 1, 1), None, "serial", 0),     # what astaroth256.steady builds
+    ((1, 2, 2), None, "serial", 0),     # what astaroth256x4.weak builds
+    ((1, 2, 2), True, "overlap", 4),    # ... built until PR 34; asked for
+    ((1, 2, 2), False, "serial", 0),
+    ((1, 1, 1), True, "serial", 0),     # one block has nothing to overlap
 ])
-def test_the_plan_at_the_cells_own_block(dim, mode, shells):
+def test_the_plan_at_the_cells_own_block(dim, overlap, mode, shells):
     """The two Astaroth cells' builds at their real 256^3 block (nothing is
-    traced or compiled by a build)."""
+    traced or compiled by a build): the default is exchange-first on the
+    fused path, the shells are there for whoever asks."""
     d = Dim3(*dim)
     spec = GridSpec(Dim3(256 * d.x, 256 * d.y, 256 * d.z), d, TIGHT)
     ex = HaloExchange(spec, grid_mesh(d, jax.devices()[:d.flatten()]))
     _, plans = _new_plans(lambda: make_astaroth_step(
         ex, _info(256, 256, 256), dtype="float32", use_pallas=True,
-        interpret=True))
+        interpret=True, overlap=overlap))
     (plan,) = plans
-    assert (plan["mode"], plan["shells"], plan["blocks"]) == (
-        mode, shells, d.flatten())
+    assert (plan["mode"], plan["shells"], plan["blocks"],
+            plan["exchanges_per_iter"]) == (mode, shells, d.flatten(), 1)
     assert plan["block_cells"] == 256 ** 3
-    if shells:
-        assert plan["shell_cells"] == 256 * (256 ** 2 - 250 ** 2) == 777_216
-        assert plan["halo_bytes_sent"] == 26_247_168    # 26.2 MB a chip
-    else:
-        assert plan["shell_cells"] == 0 and plan["halo_bytes_sent"] == 0
+    assert 256 * (256 ** 2 - 250 ** 2) == 777_216
+    assert plan["shell_cells"] == (777_216 if shells else 0)
+    # 26.2 MB a chip an exchange in either schedule; one block sends nothing
+    assert plan["halo_bytes_sent"] == (26_247_168 if d.flatten() > 1 else 0)
 
 
 def test_the_xla_path_records_its_plan_too():
@@ -215,9 +226,15 @@ def test_the_xla_path_records_its_plan_too():
             plan["shell_cells"]) == ("serial", 3, 0, 0)
 
 
+@pytest.mark.parametrize("asked, plan_is", [
+    ({}, ("serial", True, 0)),          # run()'s defaults: the cell's call
+    ({"overlap": True}, ("overlap", True, 4)),
+], ids=["default", "overlap"])
 def test_run_on_four_tpus_keeps_the_partition_it_sized_the_domain_for(
-        tmp_path, monkeypatch):
-    """What stopped the parent on the chip (PR 33): ``run()`` sized the
+        tmp_path, monkeypatch, asked, plan_is):
+    """``run()`` leaves the schedule to the builder, which takes
+    exchange-first on the fused path (PR 34); ``overlap=True`` still gets
+    the shells. What stopped the parent on the chip (PR 33): ``run()`` sized the
     domain for ``decompose_zy(4)`` = (1,2,2) and picked the tight-x radius,
     but left the partition to ``realize()``, whose min-interface split cuts
     the axis with no halo first: x, four ways, which the tight layout
@@ -240,13 +257,13 @@ def test_run_on_four_tpus_keeps_the_partition_it_sized_the_domain_for(
         app, "make_astaroth_step", lambda *a, **kw: build(
             *a, **dict(kw, use_pallas=True, interpret=True)))
     (r, plans) = _new_plans(lambda: app.run(
-        iters=1, conf=str(conf), dtype="float32", devices=jax.devices()[:4]))
+        iters=1, conf=str(conf), dtype="float32", devices=jax.devices()[:4],
+        **asked))
     spec = r["domain"].spec
     assert r["global"] == size and spec.dim == Dim3(1, 2, 2)
     assert spec.radius.x(-1) == spec.radius.x(1) == 0
     (plan,) = plans
-    assert (plan["mode"], plan["tight_x"], plan["shells"]) == (
-        "overlap", True, 4)
+    assert (plan["mode"], plan["tight_x"], plan["shells"]) == plan_is
     for k in FIELDS:
         f = r["domain"].get_curr_global(r["handles"][k])
         assert f.shape == (NZ, NY, NX) and np.isfinite(f).all(), k

@@ -1,15 +1,20 @@
 """The step the cell ``astaroth256x4.weak`` dispatches (what
 ``apps/astaroth.run(nx=256, dtype="float32")`` builds on the four chips of a
 host: (1,2,2), tight-x blocks of 262 x 272 x 256, one iteration a dispatch),
-compiled at its real size for a described ``v5e:2x2``: three fused substep
-kernels, four permutes of the 8-quantity carriers, at least one of them in
-flight across substep 0's kernel, no whole-block ``copy``, every fusion and
-in-place update between the kernels under a ``stencil.*`` scope, sixteen
-donated buffers. Nothing runs; a compile that passes is not a chip result.
+compiled at its real size for a described ``v5e:2x2``, in both schedules
+the fused path has. ``default`` is what the cell runs since PR 34
+(``overlap=None`` resolves to exchange-first on the fused path): four
+permutes of the 8-quantity carriers all done BEFORE substep 0's kernel,
+then the three fused substep kernels with nothing between them but
+``stencil.carry``, no shell. ``overlap`` is what ``overlap=True`` still
+builds (the cell's step until PR 34): substep 0's kernel on pre-exchange
+data, at least one permute in flight across it, the four shells
+re-integrated in XLA. Both: no whole-block ``copy``, every fusion and
+in-place update under a ``stencil.*`` scope, sixteen donated buffers.
+Nothing runs; a compile that passes is not a chip result.
 
-What the next issue is to move is PRINTED, not asserted: how many permutes
-fly beside the kernel, how many of XLA's async copies are of a whole block,
-and the temporaries.
+PRINTED, not asserted: how many permutes fly beside the kernel, how many of
+XLA's async copies are of a whole block, and the temporaries.
 
 The topology is described inside a module-scoped fixture (the
 on-chip-measurement guide, section 2): only the worker that gets this file
@@ -18,6 +23,7 @@ loads libtpu.
 
 import os
 import re
+from collections import Counter
 
 import pytest
 
@@ -32,11 +38,20 @@ _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\(.*?\)|\S+)\s+"
 _OPERAND = re.compile(r"%([\w.\-]+)")
 
 
-@pytest.fixture(scope="module")
-def step(topo, as_on_the_chip):
+# the two schedules: what the builder is asked, and what it then records in
+# ``astaroth.step_plan`` (mode, shells, shell cells, exchanges an iteration).
+# ``default`` asks nothing, as ``run()`` on TPU devices does
+SCHEDULES = {"default": ({}, ("serial", 0, 0, 1)),
+             "overlap": ({"overlap": True}, ("overlap", 4, 777_216, 1))}
+
+
+@pytest.fixture(scope="module", params=sorted(SCHEDULES))
+def step(request, topo, as_on_the_chip):
     """The compiled step: its ENTRY computation in schedule order (one
     parsed instruction a line, ``shape`` beside it), the memory analysis,
-    the padded block and the step plan the build recorded."""
+    the padded block, the step plan the build recorded and the schedule's
+    name."""
+    asked, _ = SCHEDULES[request.param]
     from stencil_tpu.apps.astaroth import DEFAULT_CONF
     from stencil_tpu.astaroth.config import load_config
     from stencil_tpu.astaroth.integrate import make_astaroth_step
@@ -54,7 +69,7 @@ def step(topo, as_on_the_chip):
     ex = HaloExchange(spec, grid_mesh(dim, list(topo.devices)[:4]))
     scopes.clear()
     # as run() calls it on TPU devices: nothing pinned but dt and the dtype
-    make_astaroth_step(ex, info, dt=1e-8, dtype="float32", iters=1)
+    make_astaroth_step(ex, info, dt=1e-8, dtype="float32", iters=1, **asked)
     rec = scopes._registry[scopes.ASTAROTH_ITER][-1]
     compiled = rec["fn"].lower(*rec["args"]).compile()
     text = compiled.as_text()
@@ -70,7 +85,8 @@ def step(topo, as_on_the_chip):
     plan = telemetry.get().records(kind="counter",
                                    name="astaroth.step_plan")[-1]
     return {"entry": lines, "mem": compiled.memory_analysis(),
-            "padded": spec.padded(), "plan": plan}
+            "padded": spec.padded(), "plan": plan,
+            "schedule": request.param}
 
 
 def _kernels(entry):
@@ -104,19 +120,43 @@ def test_three_fused_substeps_and_four_permutes_of_the_batched_carriers(step):
     # the plan counts the same bytes: 26.2 MB a chip an exchange
     assert step["plan"]["halo_bytes_sent"] == 4 * QUANTITIES * 2 * 3 * (
         p.z + p.y) * p.x
-    assert (step["plan"]["mode"], step["plan"]["shells"],
-            step["plan"]["exchanges_per_iter"]) == ("overlap", 4, 1)
+    plan = step["plan"]
+    assert (plan["mode"], plan["shells"], plan["shell_cells"],
+            plan["exchanges_per_iter"]) == SCHEDULES[step["schedule"]][1]
 
 
-def test_the_exchange_overlaps_substep_0_as_dataflow(step):
-    """At least one permute starts before substep 0's kernel and is done
-    after it. How many do is the next issue's to move: printed."""
+def test_where_the_exchange_lies_against_substep_0(step):
+    """``default``: exchange-first. All four permutes are done before
+    substep 0's kernel starts, the three kernels follow with nothing
+    between them but ``stencil.carry``, and no op anywhere is a shell's.
+    ``overlap``: at least one permute starts before substep 0's kernel and
+    is done after it (how many: printed), and the shells are there."""
     entry = step["entry"]
-    first = _kernels(entry)[0]
-    beside = [(s, d) for s, d, _ in _permutes(entry) if s < first < d]
+    kernels = _kernels(entry)
+    k0, k2 = kernels[0], kernels[-1]
+    permutes = _permutes(entry)
+    beside = [(s, d) for s, d, _ in permutes if s < k0 < d]
     print(f"permutes in flight across substep 0's kernel: {len(beside)} of "
-          f"{len(_permutes(entry))}")
-    assert beside
+          f"{len(permutes)}; instructions in the entry: {len(entry)}")
+    shells = [ins["instr"] for ins in entry
+              if ins["scope"] == "stencil.sweep.shell"]
+    if step["schedule"] == "overlap":
+        assert beside
+        assert len(shells) > 100
+        return
+    assert all(d < k0 for _, d, _ in permutes), (permutes, k0)
+    assert not shells, shells
+    between = [ins for i, ins in enumerate(entry[k0:k2], k0)
+               if i not in kernels]
+    kinds = Counter((ins["opcode"], ins["scope"]) for ins in between)
+    print(f"instructions between the three kernels: {len(between)}: "
+          f"{dict(kinds)}")
+    # a kernel's eight results come off its tuple and are re-viewed: no
+    # device time; anything that takes some is the carry's
+    other = [(ins["instr"], ins["opcode"], ins["scope"]) for ins in between
+             if ins["scope"] != "stencil.carry"
+             and ins["opcode"] not in ("get-tuple-element", "bitcast")]
+    assert not other, other
 
 
 def test_no_synchronous_copy_of_a_whole_block(step):
@@ -130,26 +170,35 @@ def test_no_synchronous_copy_of_a_whole_block(step):
           f"{sum(block in ins['shape'] for ins in starts)}")
 
 
-def test_everything_between_the_kernels_carries_a_scope(step):
-    """Every fusion and in-place update between substep 0's kernel and
-    substep 1's is the program's own: shells, pack, wire, unpack, carry.
-    (The async copies XLA adds to move operands between memory spaces carry
-    none and read as ``glue_compiler_ms_per_iter``.)"""
+def test_every_fusion_and_update_carries_a_scope(step):
+    """Every fusion and in-place update of the step is the program's own:
+    pack, wire, unpack, carry and, where asked for, shells: with the
+    shells, the hundreds between substep 0's kernel and substep 1's;
+    exchange-first, the few dozen before substep 0's. (The async copies XLA
+    adds to move operands between memory spaces carry none and read as
+    ``glue_compiler_ms_per_iter``.)"""
     entry = step["entry"]
     k0, k1, _ = _kernels(entry)
-    between = [ins for ins in entry[k0 + 1:k1]
-               if ins["opcode"] in ("fusion", "dynamic-update-slice")]
-    assert len(between) > 100
-    bare = [ins["instr"] for ins in between if not ins["scope"]]
+    shells = step["schedule"] == "overlap"
+    glue = [ins for ins in (entry[k0 + 1:k1] if shells else entry[:k0])
+            if ins["opcode"] in ("fusion", "dynamic-update-slice")]
+    bare = [ins["instr"] for ins in glue if not ins["scope"]]
     assert not bare, f"no stencil.* scope on: {bare}"
-    by_scope = {}
-    for ins in between:
-        by_scope[ins["scope"]] = by_scope.get(ins["scope"], 0) + 1
-    print(f"fusions and updates between the kernels, by scope: {by_scope}")
-    assert by_scope.get("stencil.sweep.shell", 0) > 100
-    assert set(by_scope) <= {"stencil.sweep.shell", "stencil.halo.pack",
-                             "stencil.halo.unpack", "stencil.halo.wire",
-                             "stencil.carry"}
+    by_scope = Counter(ins["scope"] for ins in glue)
+    print(f"fusions and updates {'between' if shells else 'before'} the "
+          f"kernels, by scope: {dict(by_scope)}")
+    halo = {"stencil.halo.pack", "stencil.halo.unpack", "stencil.halo.wire",
+            "stencil.carry"}
+    if shells:
+        assert len(glue) > 100
+        assert by_scope["stencil.sweep.shell"] > 100
+        assert set(by_scope) <= halo | {"stencil.sweep.shell"}
+    else:
+        assert 0 < len(glue) < 100
+        assert set(by_scope) <= halo
+        assert not [ins["instr"] for ins in entry[k0:]
+                    if ins["opcode"] in ("fusion", "dynamic-update-slice")
+                    and ins["scope"] != "stencil.carry"]
 
 
 def test_sixteen_buffers_all_aliased(step):
