@@ -769,8 +769,14 @@ def phase_four_iso3dfd(devs, n, cell, rehearsal: bool) -> dict:
             assert (plan["exchanged"], plan["quantities"], plan["periodic"],
                     plan["faces_only"]) == (1, 3, [False] * 3, True), plan
             census = ex.collective_census(dd.get_curr(hs["prev"]))
-            assert census["collective-permute"][0] == 4, census
-            facts.update(permutes=4, halo_bytes_sent=plan["halo_bytes_sent"],
+            # a block has one neighbour an axis: ONE permute an axis, the
+            # y and the z one in one wave (PR 36)
+            assert census["collective-permute"][0] == 2, census
+            wires = telemetry.get().records(kind="counter",
+                                            name="halo.wire_schedule")[-1]
+            assert wires["waves"] == 1 and all(
+                ph["merged"] for ph in wires["phases"]), wires
+            facts.update(permutes=2, halo_bytes_sent=plan["halo_bytes_sent"],
                          halo_bytes_if_all=plan["halo_bytes_if_all"])
             facts["bytes_after_run"] = require_balanced(devs, "iso3dfd 4 chips")
         del r, dd, hs

@@ -321,7 +321,7 @@ class DistributedDomain:
                 # stencil.hpp:154): run any partition on fewer devices by
                 # stacking c = blocks/devices resident blocks per device;
                 # the exchange shifts resident-neighbor slabs locally
-                # (exchange.py _axis_phase_resident). Stacking may mix
+                # (exchange.py _axis_phase_resident_batched). Stacking may mix
                 # axes — prefer z-heavy (the cheapest slab geometry), then
                 # y, then x.
                 c, rem = divmod(dim.flatten(), n)

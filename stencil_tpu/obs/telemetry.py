@@ -223,6 +223,15 @@ NAME_FIELDS = {
                           ("chunk", int), ("block_cells", int),
                           ("halo_bytes_sent", int),
                           ("halo_bytes_if_all", int)),
+    # what a composed per-block exchange body issued, once per build
+    # (value: ppermutes in all): per axis phase its ``axis``, the
+    # ``permutes`` issued for it and whether its two directions went as
+    # ONE (``merged``: a block with one neighbour on the axis), and the
+    # ``waves`` of the body: groups of permutes with no data dependence
+    # inside a group, each group packing from what the one before
+    # delivered. No benchmark reader: collective_exposed_ms and
+    # halo_scope_ms.app show the effect
+    "halo.wire_schedule": (("phases", list), ("waves", int)),
 }
 
 # The sanctioned metric-name vocabulary: every LITERAL name the library

@@ -50,6 +50,17 @@ or corner halo is filled and fewer bytes move. Which QUANTITIES move is the
 caller's: every entry point takes the state it is to exchange, and
 ``DistributedDomain`` hands over those declared ``exchanged``.
 
+The wire's schedule on the slab path (``_slab_phases``): both directions
+of an axis phase are packed from the blocks as the phase finds them, both
+permutes follow with no data dependence between them, both slabs are
+placed last. Where a block has ONE neighbour on the axis (a fixed axis of
+two blocks: ``AxisPhaseIR.merged``) the phase sends one carrier in one
+``ppermute`` over ``fwd + bwd``, the slab's and the halo's start picked by
+``lax.axis_index``. The phases of a faces-only plan that read nothing of
+each other (y and z: each slab is cut to the other's compute region) leave
+in one wave (``_waves``). The counter ``halo.wire_schedule`` records what
+a composed body issued.
+
 Send-extent rule pinned from the reference: the data sent toward direction
 ``d`` fills the receiver's ``-d``-side halo, so its extent is
 ``halo_extent(-d)`` and a direction is active iff ``radius.dir(-d) != 0``
@@ -160,7 +171,7 @@ class HaloExchange:
         # test_exchange.cu:52): more partition blocks than devices — the
         # extra blocks are RESIDENT: stacked along the block dims of each
         # shard, exchanged by intra-device slab shifts (see
-        # _axis_phase_resident). Any axis may stack (mixed (cz,cy,cx)
+        # _axis_phase_resident_batched). Any axis may stack (mixed (cz,cy,cx)
         # stacking included) and splits may be uneven — per-resident sizes
         # come from traced lookups into the static per-axis size tables,
         # the same machinery as the dynamic overlap shells (ops/shells.py).
@@ -258,6 +269,9 @@ class HaloExchange:
         # the plan here refuses anything else at once
         self.periodic = tuple(bool(v) for v in periodic)
         self.faces_only = bool(faces_only)
+        # axis -> (permutes, stages) while a composed body is being built
+        # (:meth:`_permute_wire`), for the counter ``halo.wire_schedule``
+        self._wired = None
         if not all(self.periodic) or self.faces_only:
             self.plan
 
@@ -385,43 +399,91 @@ class HaloExchange:
             return out
         return self._composed_quantities(state, groups)
 
-    def _composed_quantities(self, state, groups):
+    def _composed_quantities(self, state, groups, axes=None):
         """AXIS_COMPOSED over a quantity dict, one same-dtype group at a
-        time per axis phase: fused Pallas fills for fp32 self-wrap axes,
+        time per wave of axis phases (:meth:`_waves`: as a rule one phase;
+        the phases of a faces-only plan that read nothing of each other
+        leave together): fused Pallas fills for fp32 self-wrap axes,
         packed-carrier phases (one ppermute pair per phase per group)
-        elsewhere, per-quantity phases when ``batch_quantities`` is off."""
+        elsewhere, per-quantity carriers when ``batch_quantities`` is off.
+        ``axes`` restricts the phases (:meth:`exchange_block`). Records
+        ``halo.wire_schedule``, once a build."""
+        fills = self._self_fills
+        out = dict(state)
+        waves = self._waves([ph for ph in self.plan.axis_phases if ph.active
+                             and (axes is None or ph.axis in axes)])
+        self._wired = {}
+        try:
+            for wave in waves:
+                for dt, keys in groups:
+                    fill = (len(wave) == 1 and wave[0].blocks == 1
+                            and wave[0].axis in fills and dt == jnp.float32)
+                    for batch in ([keys] if fill or self.batch_quantities
+                                  else [[k] for k in keys]):
+                        blocks = [out[k] for k in batch]
+                        if fill:
+                            blocks = self._self_fill_group(
+                                wave[0].axis, blocks)
+                        elif len(wave) == 1:
+                            blocks = self._axis_phase_batched(blocks, wave[0])
+                        else:
+                            blocks = self._slab_phases(blocks, wave)
+                        out.update(zip(batch, blocks))
+        finally:
+            wired, self._wired = self._wired, None
+        from ..obs import telemetry
+
+        def tally(ph):                  # (permutes, stages) of a phase
+            return wired.get(ph.axis, (0, 0))
+
+        telemetry.get().counter(
+            "halo.wire_schedule", phase="exchange",
+            value=sum(count for count, _stages in wired.values()),
+            phases=[{"axis": ph.axis, "permutes": tally(ph)[0],
+                     "merged": ph.merged}
+                    for wave in waves for ph in wave],
+            waves=sum(max(tally(ph)[1] for ph in wave) for wave in waves))
+        return out
+
+    @staticmethod
+    def _waves(phases):
+        """The phases in the plan's order, grouped into waves that leave
+        together: a phase joins the wave before it where it reads nothing
+        that wave's phases write. Its slab is then cut (``trim``) to the
+        compute region along each of their axes, which is what a
+        faces-only plan does to its y and z slabs (x rides whole: y and z
+        carry the x halos, so they wait for an x phase). Every member
+        crosses the wire on evenly split blocks (on an uneven split the
+        static cut reaches into a smaller block's halo). Any other phase
+        is a wave of its own."""
+        waves = []
+        for ph in phases:
+            cut = {dim for dim, _lo, _w in ph.trim}
+            if waves and ph.ring > 1 and ph.uniform and all(
+                    m.ring > 1 and m.uniform and m.adim in cut
+                    for m in waves[-1]):
+                waves[-1].append(ph)
+            else:
+                waves.append([ph])
+        return waves
+
+    def _self_fill_group(self, name: str, blocks):
+        """One fp32 group on a self-wrap axis: the Pallas fill writes the
+        halos in place, touching only the edge tiles. Only the x kernel's
+        scratch scales with the quantity count; y/z fills carry every
+        quantity in one kernel."""
         from ..ops.halo_fill import max_fill_group
 
-        fills = self._self_fills
         fshape = self._fill_shape()
-        gmax = max_fill_group(self.spec) if fills else 0
-        out = dict(state)
-        for phase in self.plan.axis_phases:
-            if not phase.active:
-                continue
-            name = phase.axis
-            for dt, keys in groups:
-                if phase.blocks == 1 and name in fills and dt == jnp.float32:
-                    # only the x kernel's scratch scales with the quantity
-                    # count; y/z fills carry every quantity in one kernel
-                    ax_gmax = gmax if name == AXIS_X else len(keys)
-                    for i in range(0, len(keys), ax_gmax):
-                        chunk = keys[i : i + ax_gmax]
-                        fill = self._multi_fill(name, len(chunk))
-                        with scopes.scope(scopes.HALO_SELF_FILL):
-                            res = fill(*[out[k].reshape(fshape) for k in chunk])
-                            res = (res,) if len(chunk) == 1 else res
-                            for k, v in zip(chunk, res):
-                                out[k] = v.reshape(state[k].shape)
-                elif self.batch_quantities and len(keys) > 1:
-                    blocks = self._axis_phase_batched(
-                        [out[k] for k in keys], phase
-                    )
-                    for k, b in zip(keys, blocks):
-                        out[k] = b
-                else:
-                    for k in keys:
-                        out[k] = self._axis_phase(out[k], phase)
+        step = max_fill_group(self.spec) if name == AXIS_X else len(blocks)
+        out = []
+        for i in range(0, len(blocks), step):
+            chunk = blocks[i : i + step]
+            fill = self._multi_fill(name, len(chunk))
+            with scopes.scope(scopes.HALO_SELF_FILL):
+                res = fill(*[b.reshape(fshape) for b in chunk])
+                res = (res,) if len(chunk) == 1 else res
+                out += [v.reshape(b.shape) for v, b in zip(res, chunk)]
         return out
 
     def _multi_fill(self, axis: str, nq: int):
@@ -638,11 +700,9 @@ class HaloExchange:
 
     # -- axis-composed implementation ---------------------------------------
     def _composed_blocks(self, block, axes=None):
-        for phase in self.plan.axis_phases:
-            if axes is not None and phase.axis not in axes:
-                continue
-            block = self._axis_phase(block, phase)
-        return block
+        """One quantity: the dict body's one-key degeneration."""
+        return self._composed_quantities(
+            {0: block}, [(block.dtype, [0])], axes)[0]
 
     @cached_property
     def _self_fills(self):
@@ -682,26 +742,6 @@ class HaloExchange:
         p = self.spec.padded()
         return (self.resident.z * p.z, p.y, p.x)
 
-    def _axis_phase(self, block, phase):
-        if not phase.active:
-            return block
-        if phase.resident > 1:
-            return self._axis_phase_resident(block, phase)
-        if (
-            phase.blocks == 1
-            and block.dtype == jnp.float32
-            and phase.axis in self._self_fills
-        ):
-            # self-wrap axis: fill halos in place, touching only the edge
-            # tiles, instead of materializing slabs + whole-array updates
-            with scopes.scope(scopes.HALO_SELF_FILL):
-                return self._self_fills[phase.axis](
-                    block.reshape(self._fill_shape())
-                ).reshape(block.shape)
-        # the slab movement itself is the batched body's Q=1 degeneration
-        # (pack_slabs is the identity there) — one copy of the geometry
-        return self._axis_phase_batched([block], phase)[0]
-
     def _resident_sizes(self, name: str, c: int):
         """This device's ``c`` resident block sizes along one axis: static
         ints on a uniform split, traced lookups into the static size table
@@ -714,26 +754,22 @@ class HaloExchange:
         idx = lax.axis_index(name)
         return [tbl[idx * c + j] for j in range(c)]
 
-    def _axis_phase_resident(self, block, phase):
-        """Axis phase with partition blocks resident per device along
-        this axis (oversubscription). Neighbor slabs between resident
-        blocks shift along the stacked block dim — a pure local copy, the
-        analogue of the reference's same-GPU ``PeerAccessSender``
-        short-circuit (tx_cuda.cuh:41-113) — and only the two boundary
-        slabs ride the collective permute. Works on any axis, uneven
-        splits included (per-resident sizes may be traced scalars).
-        Implemented as the batched body's Q=1 degeneration."""
-        return self._axis_phase_resident_batched([block], phase)[0]
-
-    def _permute_wire(self, carrier, name, pairs):
+    def _permute_wire(self, carrier, name, pairs, stage: int = 0):
         """One wire-crossing ``ppermute`` of a packed carrier, paying the
         optional bf16-on-the-wire compression: the carrier narrows to
         ``wire_dtype`` on the send side and widens back after the permute
         (rounding ``astype``, never a bitcast). ONLY data that actually
         crosses the interconnect comes through here — self-wrap copies
-        and resident-neighbor shifts never do, so they stay lossless."""
+        and resident-neighbor shifts never do, so they stay lossless.
+        ``stage``: which of its phase's groups of permutes this one is in;
+        a later group packs from what an earlier one delivered (only the
+        resident body has two). A composed body under construction tallies
+        both for ``halo.wire_schedule``."""
         from ..ops.halo_fill import wire_narrow_dtype
 
+        if self._wired is not None:
+            count, stages = self._wired.get(name, (0, 0))
+            self._wired[name] = (count + 1, max(stages, stage + 1))
         w = wire_narrow_dtype(carrier.dtype, self.wire_dtype)
         with scopes.scope(scopes.HALO_WIRE):
             if w is None:
@@ -754,73 +790,95 @@ class HaloExchange:
     def _axis_phase_batched(self, blocks, phase):
         """One composed axis phase for a same-dtype quantity group: every
         quantity's boundary slab is gathered and stacked into one packed
-        ``(Q, ...slab)`` carrier, and ONE ``ppermute`` pair moves the
-        whole group — the collective count per phase is independent of Q
-        (the DevicePacker's per-neighbor multi-quantity message,
+        ``(Q, ...slab)`` carrier, and ONE ``ppermute`` a direction moves
+        the whole group — the collective count per phase is independent
+        of Q (the DevicePacker's per-neighbor multi-quantity message,
         packer.cu:10-26, as a ppermute payload). Self-wrap axes (n == 1)
         skip the permute: the packed carrier is a single fused slab copy,
         which is also the non-fp32 fill path (fp32 self-wrap axes use the
-        Pallas fills upstream). Bit-identical to the per-quantity phases —
-        the exchange is pure data movement. Q=1 degenerates to the exact
-        historical per-quantity program (pack_slabs is the identity then),
-        so :meth:`_axis_phase` delegates here — one copy of the geometry.
-        All geometry (size table, permute pairs, radii, offsets) comes
-        from the phase record of the ExchangePlan IR. One phase leaves
-        this body: a split x (lane) axis that :meth:`_split_x` accepts
-        packs and unpacks on the two edge lane-tiles
-        (:meth:`_split_x_phase`), same carrier count, same bits."""
-        rm, rp, off, adim = phase.rm, phase.rp, phase.offset, phase.adim
-        if rm == 0 and rp == 0:
+        Pallas fills upstream). Bit-identical to per-quantity phases —
+        the exchange is pure data movement. Q=1 is the per-quantity
+        program (pack_slabs is the identity then). All geometry (size
+        table, permute pairs, radii, offsets) comes from the phase record
+        of the ExchangePlan IR. Two phases leave the slab body
+        (:meth:`_slab_phases`): blocks resident on this axis
+        (:meth:`_axis_phase_resident_batched`), and a split x (lane) axis
+        that :meth:`_split_x` accepts, which packs and unpacks on the two
+        edge lane-tiles (:meth:`_split_x_phase`), same carrier count, same
+        bits."""
+        if not phase.active:
             return blocks
-        from ..ops.halo_fill import pack_slabs, unpack_slabs
-
         if phase.resident > 1:
             return self._axis_phase_resident_batched(blocks, phase)
         if self._split_x(phase, blocks[0].dtype):
             return self._split_x_phase(blocks, phase)
-        name = phase.axis
-        n = phase.ring
+        return self._slab_phases(blocks, (phase,))
+
+    def _sides(self, phase):
+        """THE slab geometry of an axis phase, one entry a carrier:
+        ``(source start, halo start, width, permute pairs, edge)``. The
+        low halo ``[off - rm, off)`` is filled from the top ``rm`` owned
+        cells of the block below (``fwd``), the high halo at ``off + sz``
+        from the bottom ``rp`` of the block above (``bwd``); sources are
+        owned cells, which no side writes. On a fixed axis the block at
+        ``edge`` hears nothing on that side and keeps what its halo holds
+        (the domain's ghost); ``None``: every block hears. Where the phase
+        is ``merged`` a block has one neighbour, so ONE side whose two
+        starts depend on which end the block is: the start of a slice,
+        not a select over data."""
+        rm, rp, off, n = phase.rm, phase.rp, phase.offset, phase.ring
         if phase.uniform:
             sz = phase.sizes[0]
         else:
-            sz = jnp.asarray(phase.sizes, dtype=jnp.int32)[lax.axis_index(name)]
-        fwd, bwd = phase.fwd, phase.bwd
+            sz = jnp.asarray(phase.sizes, dtype=jnp.int32)[
+                lax.axis_index(phase.axis)]
+        low = (off + sz - rm, off - rm, rm, phase.fwd,
+               None if phase.periodic else 0)
+        high = (off, off + sz, rp, phase.bwd,
+                None if phase.periodic else n - 1)
+        if phase.merged:
+            (lower, _upper), = phase.fwd
+            # the lower block sends up the axis and hears from above
+            up = lax.axis_index(phase.axis) == lower
+            return [(jnp.where(up, low[0], high[0]),
+                     jnp.where(up, high[1], low[1]), rm,
+                     phase.fwd + phase.bwd, None)]
+        return [side for side in (low, high) if side[2] > 0]
+
+    def _slab_phases(self, blocks, phases):
+        """Axis phases that read nothing of each other (:meth:`_waves`;
+        as a rule ONE phase), for a same-dtype group, one block a device:
+        every carrier of every phase is packed from the blocks as they
+        come in, then every permute is issued with no data dependence on
+        another, then every slab is placed. Bit for bit what one side
+        after another gives: the sources are cells no side writes and the
+        placements are disjoint."""
+        from ..ops.halo_fill import pack_slabs, unpack_slabs
+
         nq = len(blocks)
-        trim = phase.trim
-
-        def take(b, start, width):
-            return _slice_in_dim(b, start, width, adim, trim)
-
-        def place(b, slabs, start, edge):
-            """The received slabs into the halo at ``start``. On a fixed
-            axis the block at ``edge`` received nothing: its halo there is
-            the domain's ghost and keeps what it holds."""
-            if not phase.periodic:
-                kept = lax.axis_index(name) == edge
-                slabs = [jnp.where(kept, take(bi, start, s.shape[adim]), s)
-                         for bi, s in zip(b, slabs)]
-            return [_update_in_dim(bi, s, start, adim, trim)
-                    for bi, s in zip(b, slabs)]
-
-        if rm > 0:
+        flights = []
+        for ph in phases:
             with scopes.scope(scopes.HALO_PACK):
-                carrier = pack_slabs(
-                    [take(b, off + sz - rm, rm) for b in blocks]
-                )
-            if n > 1:  # ONE permute for the whole group
-                carrier = self._permute_wire(carrier, name, fwd)
+                for src, dst, width, pairs, edge in self._sides(ph):
+                    carrier = pack_slabs([
+                        _slice_in_dim(b, src, width, ph.adim, ph.trim)
+                        for b in blocks])
+                    flights.append((ph, carrier, pairs, dst, edge))
+        flights = [
+            (ph, self._permute_wire(carrier, ph.axis, pairs)
+             if ph.ring > 1 else carrier, dst, edge)   # ONE for the group
+            for ph, carrier, pairs, dst, edge in flights]
+        for ph, carrier, dst, edge in flights:
             with scopes.scope(scopes.HALO_UNPACK):
-                blocks = place(blocks, unpack_slabs(carrier, nq), off - rm, 0)
-        if rp > 0:
-            with scopes.scope(scopes.HALO_PACK):
-                carrier = pack_slabs(
-                    [take(b, off, rp) for b in blocks]
-                )
-            if n > 1:
-                carrier = self._permute_wire(carrier, name, bwd)
-            with scopes.scope(scopes.HALO_UNPACK):
-                blocks = place(blocks, unpack_slabs(carrier, nq), off + sz,
-                               n - 1)
+                slabs = unpack_slabs(carrier, nq)
+                if edge is not None:
+                    kept = lax.axis_index(ph.axis) == edge
+                    slabs = [
+                        jnp.where(kept, _slice_in_dim(
+                            b, dst, s.shape[ph.adim], ph.adim, ph.trim), s)
+                        for b, s in zip(blocks, slabs)]
+                blocks = [_update_in_dim(b, s, dst, ph.adim, ph.trim)
+                          for b, s in zip(blocks, slabs)]
         return blocks
 
     def _split_x(self, phase, dtype) -> bool:
@@ -876,11 +934,16 @@ class HaloExchange:
         return out
 
     def _axis_phase_resident_batched(self, blocks, phase):
-        """:meth:`_axis_phase_resident` for a same-dtype group:
-        resident-neighbor slabs stay per-quantity local copies (they never
-        were collectives), and the two boundary slabs of ALL quantities
-        ride one packed carrier per ``ppermute`` — still one collective
-        pair per phase regardless of Q."""
+        """Axis phase with partition blocks resident per device along
+        this axis (oversubscription), for a same-dtype group. Neighbor
+        slabs between resident blocks shift along the stacked block dim —
+        a pure local copy, per quantity, the analogue of the reference's
+        same-GPU ``PeerAccessSender`` short-circuit (tx_cuda.cuh:41-113)
+        — and only the two boundary slabs of ALL quantities ride one
+        packed carrier per ``ppermute``: one collective pair per phase
+        regardless of Q. Works on any axis, uneven splits included
+        (per-resident sizes may be traced scalars). The high side packs
+        from what the low side placed: two stages."""
         from ..ops.halo_fill import pack_slabs, unpack_slabs
 
         name, adim, bdim = phase.axis, phase.adim, phase.bdim
@@ -930,7 +993,8 @@ class HaloExchange:
             if m > 1:
                 with scopes.scope(scopes.HALO_PACK):
                     carrier = pack_slabs(incoming)
-                carrier = self._permute_wire(carrier, name, bwd)
+                carrier = self._permute_wire(
+                    carrier, name, bwd, stage=1 if rm > 0 else 0)
                 with scopes.scope(scopes.HALO_UNPACK):
                     incoming = unpack_slabs(carrier, nq)
             for q in range(nq):
@@ -1097,7 +1161,7 @@ class HaloExchange:
         the layered-overwrite argument covers packed carriers unchanged.
         Per-block compute extents come from traced lookups into the static
         per-axis size tables, the same machinery as
-        :meth:`_axis_phase_resident` (VERDICT r5 "Next" #5; ROADMAP #4).
+        :meth:`_axis_phase_resident_batched` (VERDICT r5 "Next" #5; ROADMAP #4).
         Q=1 degenerates to the per-quantity program (identity pack)."""
         from ..ops.halo_fill import pack_slabs, unpack_slabs
 

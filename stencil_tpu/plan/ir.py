@@ -133,10 +133,26 @@ class AxisPhaseIR:
     def active(self) -> bool:
         return self.rm > 0 or self.rp > 0
 
+    @property
+    def merged(self) -> bool:
+        """``fwd`` and ``bwd`` together are one permutation of the whole
+        ring and both slabs are one shape: every block has ONE neighbour on
+        this axis (a fixed axis of two blocks), one slab to send and one to
+        receive, so the lowering sends one carrier in one ``ppermute`` over
+        ``fwd + bwd``. On a periodic ring of two a block would be a source
+        twice; on a longer fixed axis the inner blocks would."""
+        pairs = self.fwd + self.bwd
+        ring = list(range(self.ring))
+        return (self.rm == self.rp > 0
+                and sorted(s for s, _ in pairs) == ring
+                and sorted(d for _, d in pairs) == ring)
+
     def collectives(self) -> int:
         """ppermutes one lowering of this phase emits (per carrier)."""
         if self.ring <= 1 or not self.active:
             return 0
+        if self.merged:
+            return 1
         return (1 if self.rm > 0 else 0) + (1 if self.rp > 0 else 0)
 
 
