@@ -884,15 +884,9 @@ def main(argv=None) -> int:
     from stencil_tpu.utils.jax_cache import configure_compile_cache
 
     cache_dir = configure_compile_cache()
-    cache = {"hits": 0, "misses": 0}
+    from stencil_tpu.obs import telemetry
 
-    def on_event(event: str, **_) -> None:
-        if event.endswith("/compilation_cache/cache_hits"):
-            cache["hits"] += 1
-        elif event.endswith("/compilation_cache/cache_misses"):
-            cache["misses"] += 1
-
-    jax.monitoring.register_event_listener(on_event)
+    cache = telemetry.watch_compiles()   # counts cache hits and misses
 
     device = {"platform": d0.platform, "kind": d0.device_kind,
               "count": len(devs)}
@@ -932,7 +926,9 @@ def main(argv=None) -> int:
 
     say("summary: " + json.dumps({
         "phases": outcome, "wall_s": round(time.perf_counter() - t_start, 1),
-        "compile_cache": dict(cache, dir=cache_dir), "device": device}))
+        "compile_cache": {"hits": cache.cache_hits,
+                          "misses": cache.cache_misses, "dir": cache_dir},
+        "device": device}))
     if any(v == "FAILED" for v in outcome.values()):
         return 1
     if args.rehearsal:

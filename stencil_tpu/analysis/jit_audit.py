@@ -38,30 +38,14 @@ INJECT_MODES = ("recompile", "host-sync")
 
 # -- compile counter (jax.monitoring backend_compile events) ------------------
 
-_compile_count = 0
-_listener_installed = False
-
-
-def _ensure_compile_listener() -> None:
-    """Install the process-wide compile-event counter once (listeners
-    cannot be unregistered portably, so it stays — counting is cheap)."""
-    global _listener_installed
-    if _listener_installed:
-        return
-    import jax
-
-    def _on_event(event, *args, **kwargs):
-        global _compile_count
-        if "backend_compile" in str(event):
-            _compile_count += 1
-
-    jax.monitoring.register_event_duration_secs_listener(_on_event)
-    _listener_installed = True
-
 
 def compile_count() -> int:
-    """Backend compiles observed since the listener was installed."""
-    return _compile_count
+    """Backend compiles observed since the process's compile watcher
+    (``obs/telemetry.watch_compiles``: the one ``jax.monitoring``
+    listener of the package) was installed; installs it where jax is
+    imported and it is not there yet."""
+    watcher = telemetry.watch_compiles()
+    return 0 if watcher is None else watcher.backend_compiles
 
 
 @dataclass
@@ -110,7 +94,6 @@ def run_audit(size: int = 16, iters: int = 10, chunk: int = 4,
     from ..parallel.exchange import shard_blocks
     from ..utils.sync import hard_sync
 
-    _ensure_compile_listener()
     rec = rec or telemetry.get()
     devices = list(devices) if devices is not None else jax.devices()
 

@@ -305,15 +305,15 @@ def time_exchange(
     done = 0
     while done < iters:
         k = min(chunk, iters - done)
-        t0 = time.perf_counter()
+        t0_ns, t0 = time.time_ns(), time.perf_counter()
         state = loops[k](state)
         hard_sync(state)
         per = (time.perf_counter() - t0) / k
         stats.insert(per)
         samples.append(per)
-        rec.emit("span", "exchange.iter", phase="exchange", seconds=per,
-                 iters=k, method=method.value, batched=batch_quantities,
-                 **wtag)
+        rec.child_span("exchange.iter", t0_ns, per, wall_s=per * k,
+                       phase="exchange", iters=k, method=method.value,
+                       batched=batch_quantities, **wtag)
         done += k
     dd._curr = dict(state)  # the loops donated the original buffers
     if rec.enabled:

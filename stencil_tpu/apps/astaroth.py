@@ -242,14 +242,14 @@ def run(
             hard_sync(curr)
         end_steps = rec.open_span("astaroth.steps", phase="step")
         for _ in range(iters):
-            t0 = time.perf_counter()
+            t0_ns, t0 = time.time_ns(), time.perf_counter()
             curr = loop(curr)
             hard_sync(curr)
             dt_iter = time.perf_counter() - t0
             iter_time.insert(dt_iter)
             exch_time.insert(dt_iter)
-            rec.emit("span", "astaroth.exchange", phase="exchange",
-                     seconds=dt_iter, iters=3)
+            rec.child_span("astaroth.exchange", t0_ns, dt_iter,
+                           phase="exchange", iters=3)
     else:
         chunk = max(1, min(chunk, iters))
         with rec.span("astaroth.warmup", phase="compile", iters=chunk):
@@ -325,8 +325,11 @@ def run(
                     at=injector.steps() if injector is not None else (),
                 )
 
+            chunk_t0_ns = 0
+
             def step_fn(st, k):
-                nonlocal nxt
+                nonlocal nxt, chunk_t0_ns
+                chunk_t0_ns = time.time_ns()
                 c, n2 = get_step(k)(st, nxt)
                 hard_sync(c)
                 nxt = n2
@@ -335,15 +338,15 @@ def run(
             def on_chunk(st, k, per, done_now):
                 for _ in range(k):
                     iter_time.insert(per)
-                rec.emit("span", "astaroth.iter", phase="step", seconds=per,
-                         iters=k)
-                t1 = time.perf_counter()
+                rec.child_span("astaroth.iter", chunk_t0_ns, per,
+                               wall_s=per * k, phase="step", iters=k)
+                t1_ns, t1 = time.time_ns(), time.perf_counter()
                 st = exch_loop(st)
                 hard_sync(st)
                 ex_dt = time.perf_counter() - t1
                 exch_time.insert(ex_dt)
-                rec.emit("span", "astaroth.exchange", phase="exchange",
-                         seconds=ex_dt, iters=n_ex)
+                rec.child_span("astaroth.exchange", t1_ns, ex_dt,
+                               phase="exchange", iters=n_ex)
                 return st
 
             save_fn = restore_fn = quarantine_fn = flush_fn = None
@@ -380,25 +383,25 @@ def run(
             next_ckpt = (start // ckpt_every + 1) * ckpt_every if (
                 ckpt_dir and ckpt_every > 0) else None
             while done < iters:
-                t0 = time.perf_counter()
+                t0_ns, t0 = time.time_ns(), time.perf_counter()
                 curr, nxt = step(curr, nxt)
                 hard_sync(curr)
                 per = (time.perf_counter() - t0) / chunk
                 for _ in range(chunk):
                     iter_time.insert(per)
-                rec.emit("span", "astaroth.iter", phase="step", seconds=per,
-                         iters=chunk)
+                rec.child_span("astaroth.iter", t0_ns, per,
+                               wall_s=per * chunk, phase="step", iters=chunk)
                 done += chunk
                 if next_ckpt is not None and done >= next_ckpt and done < iters:
                     save_ckpt(done, curr)
                     next_ckpt = (done // ckpt_every + 1) * ckpt_every
-                t0 = time.perf_counter()
+                t0_ns, t0 = time.time_ns(), time.perf_counter()
                 curr = exch_loop(curr)
                 hard_sync(curr)
                 ex_dt = time.perf_counter() - t0
                 exch_time.insert(ex_dt)
-                rec.emit("span", "astaroth.exchange", phase="exchange",
-                         seconds=ex_dt, iters=n_ex)
+                rec.child_span("astaroth.exchange", t0_ns, ex_dt,
+                               phase="exchange", iters=n_ex)
         if ckpt_dir:
             if done > start or start == 0:
                 save_ckpt(done, curr)  # the final state is always durable

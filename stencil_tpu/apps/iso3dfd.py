@@ -138,14 +138,14 @@ def run(
     done = 0
     t_loop = time.perf_counter()
     while done < iters:
-        t0 = time.perf_counter()
+        t0_ns, t0 = time.time_ns(), time.perf_counter()
         prev, nxt = step(prev, nxt, vel)
         hard_sync(prev)
         per = (time.perf_counter() - t0) / chunk
         for _ in range(chunk):
             iter_time.insert(per)
-        rec.emit("span", "iso3dfd.iter", phase="step", seconds=per,
-                 iters=chunk)
+        rec.child_span("iso3dfd.iter", t0_ns, per, wall_s=per * chunk,
+                       phase="step", iters=chunk)
         done += chunk
     wall = time.perf_counter() - t_loop
     mcells = size.flatten() * done / wall / 1e6

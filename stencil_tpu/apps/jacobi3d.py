@@ -343,8 +343,11 @@ def run(
     # valid snapshot with exponential backoff.
     iter_time = Statistics()
 
+    chunk_t0_ns = 0
+
     def step_fn(st, k):
-        nonlocal nxt
+        nonlocal nxt, chunk_t0_ns
+        chunk_t0_ns = time.time_ns()
         c, n2 = get_loop(k)(st["temperature"], nxt, sel)
         hard_sync(c)
         nxt = n2
@@ -352,7 +355,8 @@ def run(
 
     def on_chunk(st, k, per, done_now):
         iter_time.insert(per)
-        rec.emit("span", "jacobi.iter", phase="step", seconds=per, iters=k)
+        rec.child_span("jacobi.iter", chunk_t0_ns, per, wall_s=per * k,
+                       phase="step", iters=k)
         if stepwise and done_now % paraview_every == 0:
             dd.set_curr(h, st["temperature"])
             dd.write_paraview(f"{prefix}jacobi3d_{done_now}")
@@ -483,15 +487,15 @@ def run(
                 slow_tail = FaultPlan(tail, seed=injector.seed)
         exch_samples = []
         for i in range(3):
-            t0 = time.perf_counter()
+            t0_ns, t0 = time.time_ns(), time.perf_counter()
             st = exch_loop(st)
             if slow_tail is not None:
                 st = slow_tail.fire_due(st, iters + i, iters + i + 1)
             hard_sync(st)
             per = (time.perf_counter() - t0) / n_ex
             exch_samples.append(per)
-            rec.emit("span", "jacobi.exchange", phase="exchange",
-                     seconds=per, iters=n_ex)
+            rec.child_span("jacobi.exchange", t0_ns, per,
+                           wall_s=per * n_ex, phase="exchange", iters=n_ex)
         curr = st[h.idx]
         # per-phase attribution: pair the cost model's prediction for the
         # realized plan with the measured exchange share — the autotuner's

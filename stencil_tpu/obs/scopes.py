@@ -106,18 +106,30 @@ def scope(name: str):
 
 def kernel_call(name: str, kernel, **kwargs):
     """``pl.pallas_call(kernel, name=name, **kwargs)`` whose every call is
-    traced under ``stencil.kernel.<name>``."""
+    traced under ``stencil.kernel.<name>``. ``call`` runs only while jax
+    traces the caller (never on a compiled loop's call path), and
+    ``fn(*args)`` is where ``pallas_call`` traces the kernel's body: each
+    invocation leaves one ``kernel.trace`` span, a child of the span open
+    then."""
     if name not in KERNELS:
         raise KeyError(f"{name!r} is not in the kernel vocabulary")
     import jax
     from jax.experimental import pallas as pl
 
+    from . import telemetry
+
     fn = pl.pallas_call(kernel, name=name, **kwargs)
     scope_name = KERNEL_PREFIX + name
 
     def call(*args):
-        with jax.named_scope(scope_name):
-            return fn(*args)
+        t0_ns, t0 = time.time_ns(), time.perf_counter()
+        try:
+            with jax.named_scope(scope_name):
+                return fn(*args)
+        finally:
+            telemetry.get().child_span(
+                "kernel.trace", t0_ns, time.perf_counter() - t0,
+                phase="compile", kernel=name)
 
     return call
 

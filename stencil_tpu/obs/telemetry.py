@@ -35,6 +35,13 @@ Spans ride :func:`stencil_tpu.utils.timer.timed` (global buckets keep
 accumulating exactly as before) and ``timer.trace_range`` (so
 ``jax.profiler`` gets the same named range for free).
 
+Compile stages are spans too (:class:`CompileWatcher`): once the first
+top-level span of a process has opened, every OUTERMOST jaxpr trace,
+lowering and backend compile (or cache load) that jax reports through
+``jax.monitoring`` lands as ``compile.trace`` / ``compile.lower`` /
+``compile.backend`` with ``parent`` the span open at the time. Nothing
+fires on a compiled program's call.
+
 Heartbeats close the loop with :mod:`stencil_tpu.obs.watchdog`: when the
 supervisor set ``STENCIL_HEARTBEAT_FILE``, every emitted record (and a
 background thread, for long silent stretches like a 3-minute kernel
@@ -232,6 +239,34 @@ NAME_FIELDS = {
     # delivered. No benchmark reader: collective_exposed_ms and
     # halo_scope_ms.app show the effect
     "halo.wire_schedule": (("phases", list), ("waves", int)),
+    # one OUTERMOST compile stage of a program named from scopes.MODULES
+    # (CompileWatcher): ``parent`` is the span open when it happened
+    # (absent outside any), ``fun`` jax's fun_name without ``jit(...)``,
+    # ``module`` the same; a backend stage says whether the persistent
+    # cache served it (``hit``: the seconds are retrieval and load,
+    # ``retrieval_s`` of them the read; ``miss``: XLA and Mosaic compiled;
+    # ``off``: no cache was asked). Benchmark readers app_run_trace_s /
+    # _lower_s / _backend_s / _cache_misses (benchmark/compile_lib.py)
+    "compile.trace": (("fun", str), ("module", str), ("t0_ns", int),
+                      ("t1_ns", int)),
+    "compile.lower": (("fun", str), ("module", str), ("t0_ns", int),
+                      ("t1_ns", int)),
+    "compile.backend": (("fun", str), ("module", str), ("t0_ns", int),
+                        ("t1_ns", int), ("cache", str)),
+    # every other outermost stage (jnp helpers, seeding and checking
+    # programs), folded: one record per parent span and ``stage``, written
+    # when that parent closes (no parent: when a second has passed without
+    # a stage, a top-level span opens or flush_compile_stages() is
+    # called); ``seconds`` summed over ``count`` stages between ``t0_ns``
+    # and ``t1_ns``, ``funs`` the seconds by fun_name; a backend fold also
+    # ``hits``, ``misses`` and ``missed`` (the names that missed). Same
+    # readers, and compile_lib's table
+    "compile.other": (("stage", str), ("count", int), ("t0_ns", int),
+                      ("t1_ns", int), ("funs", dict)),
+    # one invocation of a scopes.kernel_call kernel while jax traces its
+    # caller: the seconds pallas_call spent tracing the kernel's body
+    # (compile_lib's table: the kernels' share of compile.trace)
+    "kernel.trace": (("kernel", str), ("t0_ns", int), ("t1_ns", int)),
 }
 
 # The sanctioned metric-name vocabulary: every LITERAL name the library
@@ -302,6 +337,13 @@ KNOWN_NAMES = frozenset(NAME_FIELDS) | frozenset({
 
 # how many records a recorder keeps in memory (oldest dropped first)
 KEEP_RECORDS = 4096
+
+# the top-level spans that cover an application's run() without a hole
+# (``jacobi.realize`` ... ``iso3dfd.steps``): each carries the devices'
+# memory when it closes (``mem_bytes_in_use``, ``mem_peak_bytes``), so the
+# phase that set a run's peak is read and not inferred; no other span pays
+# the query
+RUN_SPANS = (".realize", ".init", ".warmup", ".steps")
 
 
 def new_run_id() -> str:
@@ -420,9 +462,13 @@ class Recorder:
         ``timer.trace_range``. ``bucket=False``: no timer bucket (the
         exit-time ``timers:`` line stays as it is).
         """
+        prev_span = self._progress.get("span")
+        if prev_span is None and watch_compiles() is not None:
+            # a top-level span: compile stages are recorded from here on,
+            # and what was folded outside any span is written
+            _watcher.flush(None)
         t0_ns = time.time_ns()
         t0 = time.perf_counter()
-        prev_span = self._progress.get("span")
         self._progress["span"] = name  # the heartbeat payload quotes this
         timed = (contextlib.nullcontext() if bucket is False
                  else timer.timed(bucket or name))
@@ -432,9 +478,27 @@ class Recorder:
         finally:
             self._progress["span"] = prev_span
             seconds = time.perf_counter() - t0
+            if _watcher is not None:
+                _watcher.flush(name)
+            if prev_span is None and name.endswith(RUN_SPANS):
+                tags = {**_device_memory(), **tags}
             self.emit("span", name, phase=phase, seconds=seconds,
                       t0_ns=t0_ns, t1_ns=t0_ns + int(seconds * 1e9),
                       parent=prev_span, **tags)
+
+    def child_span(self, name: str, t0_ns: int, seconds: float,
+                   wall_s: Optional[float] = None,
+                   phase: Optional[str] = None, **tags) -> dict:
+        """A span that was timed where it ran (a chunk of a step loop, a
+        compile stage, a kernel body's trace), with the fields of one
+        opened through :meth:`span`: it started at ``t0_ns``
+        (``time.time_ns()``), lasted ``wall_s`` (``seconds`` where not
+        given: a chunk's record carries seconds an iteration) and its
+        ``parent`` is the span open now."""
+        wall = seconds if wall_s is None else wall_s
+        return self.emit("span", name, phase=phase, seconds=seconds,
+                         t0_ns=t0_ns, t1_ns=t0_ns + int(wall * 1e9),
+                         parent=self._progress.get("span"), **tags)
 
     def open_span(self, name: str, phase: Optional[str] = None, **tags):
         """:meth:`span` for a stretch of a long function that no ``with``
@@ -575,6 +639,233 @@ def get() -> Recorder:
 
 def enabled() -> bool:
     return _recorder is not None and _recorder.enabled
+
+
+def _device_memory() -> dict:
+    """``mem_bytes_in_use`` and ``mem_peak_bytes``: the maximum over the
+    local devices' ``memory_stats()``; empty where jax is not imported or
+    the backend keeps none (the CPU's)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return {}
+    try:
+        stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    except Exception:
+        return {}
+    if not any("bytes_in_use" in s for s in stats):
+        return {}
+    return {"mem_bytes_in_use": max(int(s.get("bytes_in_use", 0))
+                                    for s in stats),
+            "mem_peak_bytes": max(int(s.get("peak_bytes_in_use", 0))
+                                  for s in stats)}
+
+
+# -- compile stages as spans ---------------------------------------------------
+
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+# a miss is an executable compiled and WRITTEN to the persistent cache: one
+# the cache's thresholds keep out reads "off", like one with no cache
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_misses": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+FOLD_GAP_S = 1.0      # a fold with no parent closes after this much quiet
+FOLD_NAMES = 24       # fun_names a fold keeps apart
+
+
+class _OpenStages(threading.local):
+    """A thread's open compile stages, outermost first, and what the cache
+    said inside the outermost."""
+
+    def __init__(self):
+        self.stages = []          # (event, start in unix seconds)
+        self.cache = "off"
+        self.retrieval_s = None
+
+
+class CompileWatcher:
+    """jax's compile events as spans of the process-default recorder.
+
+    jax reports every jaxpr trace, lowering to MLIR and backend compile
+    (on a persistent-cache hit: the retrieval and load) through
+    ``jax.monitoring``: a scalar event at the start, a duration event at
+    the end, both with ``fun_name``; the cache's events fire inside the
+    backend stage. Stages nest (tracing a loop traces every jitted ``jnp``
+    function inside it, and an eager op under a trace compiles): only the
+    OUTERMOST of a thread is recorded, as ``compile.trace`` / ``.lower``
+    / ``.backend`` where its function is a module of ``scopes.MODULES``
+    and folded into ``compile.other`` otherwise (``NAME_FIELDS``). A
+    listener never raises into a compile.
+
+    ``backend_compiles``, ``cache_hits`` and ``cache_misses`` count every
+    event, nested or not, since the watcher was installed
+    (``analysis/jit_audit.py``, ``chip_smoke.py``).
+    """
+
+    def __init__(self):
+        self.backend_compiles = 0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        self.errors = 0           # listener calls that failed (first logged)
+        self._open = _OpenStages()
+        self._folds: Dict[tuple, dict] = {}
+        self._folds_lock = threading.Lock()
+
+    def install(self, monitoring) -> None:
+        monitoring.register_event_listener(self._guarded(self._on_event))
+        monitoring.register_event_duration_secs_listener(
+            self._guarded(self._on_duration))
+        # a jax without the scalar start event: every stage counts as
+        # outermost and starts at its end less its duration
+        register = getattr(monitoring, "register_scalar_listener", None)
+        if register is not None:
+            register(self._guarded(self._on_start))
+
+    def _guarded(self, fn):
+        def listener(*args, **kwargs):
+            try:
+                fn(*args, **kwargs)
+            except Exception as e:  # a compile must not fail for its record
+                self.errors += 1
+                if self.errors == 1:
+                    from ..utils import logging as log
+
+                    log.warn(f"compile watcher: {fn.__name__} failed "
+                             f"({type(e).__name__}: {e}); later failures "
+                             f"are counted, not logged")
+
+        return listener
+
+    def _on_start(self, event: str, value, **_kw) -> None:
+        if event in _STAGES:
+            state = self._open
+            state.stages.append((event, value))
+            if len(state.stages) == 1:
+                state.cache, state.retrieval_s = "off", None
+
+    def _on_event(self, event: str, **_kw) -> None:
+        verdict = _CACHE_EVENTS.get(event)
+        if verdict is None:
+            return
+        if verdict == "hit":
+            self.cache_hits += 1
+        else:
+            self.cache_misses += 1
+        state = self._open
+        if len(state.stages) <= 1:
+            state.cache = verdict
+
+    def _on_duration(self, event: str, seconds: float, **kw) -> None:
+        stage = _STAGES.get(event)
+        state = self._open
+        if stage is None:
+            if event == _CACHE_RETRIEVAL and len(state.stages) <= 1:
+                state.retrieval_s = float(seconds)
+            return
+        if stage == "backend":
+            self.backend_compiles += 1
+        start = None
+        if state.stages:
+            opened, start = state.stages.pop()
+            if opened != event:      # lost track: start over
+                state.stages.clear()
+                start = None
+        if state.stages:
+            return                   # inside an outer stage, which covers it
+        cache, retrieval_s = state.cache, state.retrieval_s
+        state.cache, state.retrieval_s = "off", None
+        t1_ns = time.time_ns()
+        t0_ns = (t1_ns - int(seconds * 1e9) if start is None
+                 else int(float(start) * 1e9))
+        self._stage(stage, t0_ns, float(seconds), kw.get("fun_name"),
+                    cache, retrieval_s)
+
+    def _stage(self, stage, t0_ns, seconds, fun_name, cache, retrieval_s):
+        from . import scopes
+
+        rec = get()
+        fun = None if fun_name is None else str(fun_name)
+        if fun and fun.startswith("jit(") and fun.endswith(")"):
+            fun = fun[4:-1]
+        if fun in scopes.MODULES:
+            tags = ({"cache": cache, "retrieval_s": retrieval_s}
+                    if stage == "backend" else {})
+            rec.child_span(f"compile.{stage}", t0_ns, seconds,
+                           phase="compile", fun=fun, module=fun, **tags)
+        else:
+            self._fold(rec, stage, t0_ns, seconds, fun or "(unnamed)", cache)
+
+    def _fold(self, rec, stage, t0_ns, seconds, key, cache) -> None:
+        parent = rec._progress.get("span")
+        t1_ns = t0_ns + int(seconds * 1e9)
+        with self._folds_lock:
+            fold = self._folds.get((parent, stage))
+            if (fold is not None and parent is None
+                    and t0_ns - fold["t1_ns"] > FOLD_GAP_S * 1e9):
+                self._write(rec, parent, stage)
+                fold = None
+            if fold is None:
+                fold = self._folds[(parent, stage)] = {
+                    "count": 0, "seconds": 0.0, "t0_ns": t0_ns,
+                    "t1_ns": t1_ns, "funs": {}}
+                if stage == "backend":
+                    fold.update(hits=0, misses=0, missed=[])
+            fold["count"] += 1
+            fold["seconds"] += seconds
+            fold["t1_ns"] = max(fold["t1_ns"], t1_ns)
+            funs = fold["funs"]
+            if key not in funs and len(funs) >= FOLD_NAMES:
+                key = "(others)"
+            funs[key] = funs.get(key, 0.0) + seconds
+            if stage == "backend" and cache != "off":
+                fold["hits" if cache == "hit" else "misses"] += 1
+                if cache == "miss" and len(fold["missed"]) < FOLD_NAMES:
+                    fold["missed"].append(key)
+
+    def _write(self, rec, parent, stage) -> None:
+        """Emit and forget one fold (the caller holds the lock)."""
+        fold = self._folds.pop((parent, stage), None)
+        if fold is not None:
+            rec.emit("span", "compile.other", phase="compile", stage=stage,
+                     parent=parent, **fold)
+
+    def flush(self, parent: Optional[str]) -> None:
+        """Write the folds of ``parent`` (a span that closes; ``None``:
+        of the stages outside any span)."""
+        with self._folds_lock:
+            for key in [k for k in self._folds if k[0] == parent]:
+                self._write(get(), *key)
+
+
+_watcher: Optional[CompileWatcher] = None
+
+
+def watch_compiles() -> Optional[CompileWatcher]:
+    """The process's one compile watcher, installed at the first call that
+    finds jax imported (``None`` before that: nothing here imports jax, and
+    ``jax.monitoring`` starts no backend). :meth:`Recorder.span` calls this
+    when a top-level span opens. jax offers no portable unregister, so the
+    listeners stay: they run on compile events only."""
+    global _watcher
+    if _watcher is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        _watcher = CompileWatcher()
+        _watcher.install(jax.monitoring)
+    return _watcher
+
+
+def flush_compile_stages() -> None:
+    """Write the folded stages that ran outside any span (a reader calls
+    this before it reads :meth:`Recorder.records`)."""
+    if _watcher is not None:
+        _watcher.flush(None)
 
 
 # -- static truth: what the compiled artifacts say moves ---------------------
