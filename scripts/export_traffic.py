@@ -97,9 +97,10 @@ def substep(n: int = 64, tight_x: bool = False) -> dict:
 def fill(axis: str) -> dict:
     """In-place halo fill at 256^3 r=3 for one self-wrap axis: x pins the
     edge-lane-tile RMW amplification (any inline-x-halo layout pays
-    128-lane writes), y the 8-row-tile RMW windows, z the staged whole
-    plane copies."""
-    from stencil_tpu.ops.halo_fill import _x_tzb, make_self_fill
+    128-lane writes), y the 8-row-tile windows (the two source windows
+    read and written onto the destination windows, which at 256 rows hold
+    no owned row and are not read), z the staged whole plane copies."""
+    from stencil_tpu.ops.halo_fill import _x_tzb, _y_tzb, make_self_fill
 
     spec = GridSpec(Dim3(256, 256, 256), Dim3(1, 1, 1), Radius.constant(3))
     p = spec.padded()
@@ -118,8 +119,8 @@ def fill(axis: str) -> dict:
                    spec.compute_offset().x],
         "base": [spec.base.z, spec.base.y, spec.base.x],
     }
-    if axis == "x":
-        rep["tzb"] = _x_tzb(spec)
+    if axis != "z":
+        rep["tzb"] = (_x_tzb if axis == "x" else _y_tzb)(spec)
     return rep
 
 

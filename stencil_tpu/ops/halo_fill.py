@@ -12,8 +12,14 @@ touching only the affected (8, 128) tiles.
 
 Axis economics per quantity (512^3, r=3, fp32):
 - z: halo planes are whole (py, px) slabs — 6 plane copies, ~16 MB.
-- y: halo rows live in one 8-row tile per side — both rewritten, from two
-  source row-tiles read: 6 row-tile passes, ~64 MB.
+- y: halo rows live in one 8-row tile per side. Where a block's rows are a
+  multiple of 8 neither tile holds an owned row, and the source row-tile
+  (the halo rows at the same place in it) is read and written onto it
+  whole: 4 row-tile passes, ~43 MB. A tile that does hold owned rows (140
+  rows) is read, its halo rows overwritten in VMEM, and written: 6 passes.
+  Streamed over z in batches of up to 32 planes, two buffer slots: every
+  DMA of a batch is in flight at once and the next batch loads while this
+  one is written.
 - x: halo columns live inside one 128-lane tile per side — RMW of both
   edge lane-tiles (~0.55 GB; the 128-lane tile is the minimum write
   granularity, a ~42x amplification that any layout storing x halos
@@ -29,8 +35,10 @@ y, then z) is preserved because each axis is a separate kernel call — later
 phases read the earlier phases' filled halos.
 
 Each build records the HBM bytes one call reads and writes
-(``halo.self_fill.bytes_dma``, see ``_record_dma_bytes``): 0.560 + 0.064 +
-0.016 GB a quantity at the size above; the split-x pair records
+(``halo.self_fill.bytes_dma``, see ``_record_dma_bytes``): 0.560 + 0.043 +
+0.016 GB a quantity at the size above (the y record also says how many
+DMAs a call issues, how many its schedule keeps in flight and how many
+destination tiles it does not read); the split-x pair records
 ``halo.split_x.bytes_dma`` (0.287 + 0.567 GB a quantity there).
 """
 
@@ -162,6 +170,8 @@ def _axis_geom(spec: GridSpec, axis: str) -> Tuple[int, int, int]:
 # VMEM scratch budget for a fill kernel (kernels pass vmem_limit_bytes to
 # lift the 16 MB default scoped limit; leave headroom for Mosaic).
 _VMEM_BUDGET = 24 * 1024 * 1024
+# buffer slots of the y kernel: batch i + 1 loads while batch i is written
+_Y_SLOTS = 2
 
 
 def _x_tzb(spec: GridSpec, nq: int = 1, z_stack: int = 1) -> int:
@@ -176,27 +186,85 @@ def _x_tzb(spec: GridSpec, nq: int = 1, z_stack: int = 1) -> int:
     return tzb
 
 
-def max_fill_group(spec: GridSpec) -> int:
-    """Largest quantity count a fused x fill can carry under the VMEM
-    budget (callers chunk larger quantity sets)."""
-    nq = 1
-    while nq < 16 and 8 * (nq + 1) * 2 * spec.padded().y * _LANE * 4 <= _VMEM_BUDGET:
-        nq += 1
-    return nq
+def max_fill_group(spec: GridSpec, axis: str = "x") -> int:
+    """Largest quantity count a fused x or y fill can carry under the VMEM
+    budget at its shallowest z batch (callers chunk larger quantity sets;
+    the z fill stages one plane set whatever the count)."""
+    if axis == "y":
+        one = _y_scratch_bytes(spec, 1, _SUB)
+    else:
+        one = 8 * 2 * spec.padded().y * _LANE * 4
+    return max(1, min(16, _VMEM_BUDGET // one))
+
+
+class _YSide(NamedTuple):
+    """One halo of a y fill: rows ``[dst_at, dst_at + r)`` of the row-tile
+    window ``[dst_t, dst_t + dst_span)`` take rows ``[src_at, src_at + r)``
+    of the window at ``src_t``. ``rebuilt``: the destination window holds
+    no owned row and the source window has its shape and its rows at the
+    same place, so the source window as read IS the destination window
+    (its halo rows right, its dead rows some owned rows' values) and is
+    written there without the destination being read first."""
+
+    r: int
+    dst_t: int
+    dst_span: int
+    dst_at: int
+    src_t: int
+    src_span: int
+    src_at: int
+    rebuilt: bool
+
+
+def _y_sides(spec: GridSpec) -> Tuple[_YSide, ...]:
+    """The active halos of a y fill, low first."""
+    o, sz, (rm, rp) = _axis_geom(spec, "y")
+    py = spec.padded().y
+
+    def window(row, r):
+        t = (row // _SUB) * _SUB
+        return t, min(-(-(row + r - t) // _SUB) * _SUB, py - t), row - t
+
+    sides = []
+    # rows [o-rm, o) <- rows [o+sz-rm, o+sz); rows [o+sz, o+sz+rp) <- [o, o+rp)
+    for r, dst, src in ((rm, o - rm, o + sz - rm), (rp, o + sz, o)):
+        if r:
+            d, s = window(dst, r), window(src, r)
+            owned = d[0] < o + sz and d[0] + d[1] > o
+            sides.append(_YSide(r, *d, *s, not owned and d[1:] == s[1:]))
+    return tuple(sides)
+
+
+def _y_scratch_bytes(spec: GridSpec, nq: int, tzb: int) -> int:
+    """VMEM of the y kernel's buffers: a source window a side and, where
+    the destination is read too, its window, in ``_Y_SLOTS`` slots."""
+    rows = sum(s.src_span + (0 if s.rebuilt else s.dst_span)
+               for s in _y_sides(spec))
+    return _Y_SLOTS * nq * tzb * rows * spec.padded().x * 4
+
+
+def _y_tzb(spec: GridSpec, nq: int = 1, z_stack: int = 1) -> int:
+    """z-batch depth of the y kernel: the deepest of 32/16/8 whose buffers
+    (x nq quantities) fit the budget, then evened out over the batches
+    that takes, so that the clamped last batch moves fewer than one plane
+    a batch twice (518 planes: 17 batches of 31, not of 32)."""
+    pz = spec.padded().z * z_stack
+    tzb = 32
+    while tzb > _SUB and (
+            _y_scratch_bytes(spec, nq, tzb) > _VMEM_BUDGET or tzb > pz):
+        tzb //= 2
+    return -(-pz // -(-pz // tzb))
 
 
 def _scratch_bytes(spec: GridSpec, axis: str, z_stack: int = 1) -> int:
-    """VMEM scratch the kernel for ``axis`` would allocate (see make_self_fill)."""
+    """VMEM scratch the kernel for ``axis`` would allocate at one quantity
+    (see make_self_fill)."""
     p = spec.padded()
     o, sz, (rm, rp) = _axis_geom(spec, axis)
     if axis == "z":
         return max(rm, rp, 1) * p.y * p.x * 4
     if axis == "y":
-        spans = []
-        for a, b in ((o - rm, o), (o + sz, o + sz + rp), (o, o + rp), (o + sz - rm, o + sz)):
-            t = (a // _SUB) * _SUB
-            spans.append(-(-(b - t) // _SUB) * _SUB)
-        return 2 * 8 * max(spans) * p.x * 4
+        return _y_scratch_bytes(spec, 1, _y_tzb(spec, 1, z_stack))
     # x (nq=1): 4 double-buffered 2-slot buffers
     return 8 * _x_tzb(spec, z_stack=z_stack) * p.y * _LANE * 4
 
@@ -252,9 +320,12 @@ def _record_dma_bytes(nq: int, shape, read: int, written: int,
     for a self-fill, ``halo.split_x.bytes_dma`` tagged ``part`` (pack or
     unpack; a record's ``kind`` is taken) for the split-x pair. The lane and
     row tiles the x and y kernels rewrite whole are in it, which
-    ``HaloExchange.bytes_moved`` leaves out. ``utils/mosaic_traffic``
-    derives the same count from the lowered Mosaic module (a test's
-    cross-check)."""
+    ``HaloExchange.bytes_moved`` leaves out. A y fill's record adds
+    ``dmas`` (the DMAs a call issues), ``in_flight`` (the most its
+    schedule has started and not yet waited for) and ``dst_read_skipped``
+    (destination windows written without being read: 0, 1 or 2).
+    ``utils/mosaic_traffic`` derives the same counts from the lowered
+    Mosaic module (a test's cross-check)."""
     from ..obs import telemetry
 
     telemetry.get().counter(
@@ -281,10 +352,10 @@ def make_self_fill(spec: GridSpec, axis: str, vma=None, interpret: bool = False,
             f"self-wrap fill unsupported for axis {axis!r} on this spec "
             f"(z_stack={z_stack})"
         )
-    if axis == "x" and not 1 <= nq <= max_fill_group(spec):
+    if axis != "z" and not 1 <= nq <= max_fill_group(spec, axis):
         raise ValueError(
-            f"x-phase fill group size {nq} outside "
-            f"[1, {max_fill_group(spec)}]"
+            f"{axis}-phase fill group size {nq} outside "
+            f"[1, {max_fill_group(spec, axis)}]"
         )
     p = spec.padded()
     pz, py, px = p.z * z_stack, p.y, p.x
@@ -342,78 +413,119 @@ def make_self_fill(spec: GridSpec, axis: str, vma=None, interpret: bool = False,
             interpret=interpret,
         ))
 
-    TZB = 8
-    n_b = -(-pz // TZB)  # overlapping last batch: z is untiled, restart anywhere
-
     if axis == "y":
-        # dest/source row-tile windows (lo halo, hi halo)
-        lo_t = ((o - rm) // _SUB) * _SUB
-        lo_span = -(-(o - lo_t) // _SUB) * _SUB
-        hi_t = ((o + sz) // _SUB) * _SUB
-        hi_span = -(-(o + sz + rp - hi_t) // _SUB) * _SUB
-        hi_span = min(hi_span, py - hi_t)
-        src_lo_t = (o // _SUB) * _SUB  # wrap source rows [o, o+rp)
-        src_lo_span = -(-(o + rp - src_lo_t) // _SUB) * _SUB
-        src_hi_t = ((o + sz - rm) // _SUB) * _SUB
-        src_hi_span = -(-(o + sz - src_hi_t) // _SUB) * _SUB
-        spans = (lo_span, hi_span, src_lo_span, src_hi_span)
-        vspan = max(spans)
+        # Every DMA of a z batch flies with its batch, and batches overlap:
+        # step i starts batch i + 1's reads (every quantity's source window
+        # a side and, where it is read, the destination window, each into a
+        # buffer of its own in the next slot), then waits for batch i's,
+        # copies the halo rows in VMEM where a destination was read, starts
+        # every write of batch i and ends on their wait, so the next step
+        # finds its load slot written back. A rebuilt side (_YSide) has no
+        # destination read and no copy: its source buffer is written out.
+        #
+        # z is untiled, so the last batch is clamped and overlaps the one
+        # before it when pz % TZB != 0. Unlike in the x kernel its reads may
+        # be prefetched past that batch's writes: every row a source window
+        # gives is an owned row, which this kernel never changes, and of a
+        # destination window that was read the halo rows are overwritten in
+        # VMEM and the others written back as read, so whichever of the two
+        # writes a prefetched read races, the tile it writes is the same,
+        # and the two batches write equal values to the planes they share.
+        sides = _y_sides(spec)
+        TZB = _y_tzb(spec, nq, z_stack)
+        n_b = -(-pz // TZB)
 
         def kernel(*refs):
             outs = refs[nq : 2 * nq]
-            dv, sv, sem = refs[2 * nq :]
             i = pl.program_id(0)
-            z0 = jnp.minimum(i * TZB, pz - TZB)
+            # a side's scratch: the source buffer, its semaphores, the
+            # writes' semaphores and, where the destination is read, its
+            # buffer and semaphores
+            bufs, rest = [], list(refs[2 * nq :])
+            for side in sides:
+                n = 3 if side.rebuilt else 5
+                bufs.append(rest[:n])
+                rest = rest[n:]
 
-            def rd(out, base, span, buf):
-                cp = pltpu.make_async_copy(
-                    out.at[pl.ds(z0, TZB), pl.ds(base, span)], buf.at[:, pl.ds(0, span)], sem
-                )
-                cp.start()
+            def copy(q, step, t, span, buf, sem, out=False):
+                """One DMA of batch ``step``: rows [t, t + span) of its z
+                planes of quantity q into the batch's slot of ``buf``, or
+                ``out`` of it."""
+                slot = jnp.mod(step, _Y_SLOTS)
+                z0 = jnp.minimum(step * TZB, pz - TZB)
+                hbm = outs[q].at[pl.ds(z0, TZB), pl.ds(t, span)]
+                vmem = buf.at[slot, q]
+                return pltpu.make_async_copy(
+                    *((vmem, hbm) if out else (hbm, vmem)), sem.at[slot])
+
+            def reads(step):
+                cps = []
+                for side, (src, s_src, _, *dst) in zip(sides, bufs):
+                    for q in range(nq):
+                        cps.append(copy(q, step, side.src_t, side.src_span,
+                                        src, s_src))
+                        if dst:
+                            cps.append(copy(q, step, side.dst_t,
+                                            side.dst_span, *dst))
+                return cps
+
+            def writes(step):
+                return [copy(q, step, side.dst_t, side.dst_span,
+                             dst[0] if dst else src, s_wr, out=True)
+                        for side, (src, _, s_wr, *dst) in zip(sides, bufs)
+                        for q in range(nq)]
+
+            @pl.when(i == 0)
+            def _():
+                for cp in reads(i):
+                    cp.start()
+
+            @pl.when(i + 1 < n_b)
+            def _():
+                for cp in reads(i + 1):
+                    cp.start()
+
+            for cp in reads(i):
                 cp.wait()
-
-            def wr(out, base, span, buf):
-                cp = pltpu.make_async_copy(
-                    buf.at[:, pl.ds(0, span)], out.at[pl.ds(z0, TZB), pl.ds(base, span)], sem
-                )
+            slot = jnp.mod(i, _Y_SLOTS)
+            for side, (src, _, _, *dst) in zip(sides, bufs):
+                for q in range(nq if dst else 0):
+                    dst[0][slot, q, :, side.dst_at : side.dst_at + side.r, :] = (
+                        src[slot, q, :, side.src_at : side.src_at + side.r, :])
+            written = writes(i)
+            for cp in written:
                 cp.start()
+            for cp in written:
                 cp.wait()
-
-            for q in range(nq):
-                out = outs[q]
-                if rm:
-                    rd(out, lo_t, lo_span, dv)
-                    rd(out, src_hi_t, src_hi_span, sv)
-                    # rows [o-rm, o) <- rows [o+sz-rm, o+sz)
-                    dv[:, o - rm - lo_t : o - lo_t, :] = sv[
-                        :, o + sz - rm - src_hi_t : o + sz - src_hi_t, :
-                    ]
-                    wr(out, lo_t, lo_span, dv)
-                if rp:
-                    rd(out, hi_t, hi_span, dv)
-                    rd(out, src_lo_t, src_lo_span, sv)
-                    # rows [o+sz, o+sz+rp) <- rows [o, o+rp)
-                    dv[:, o + sz - hi_t : o + sz + rp - hi_t, :] = sv[
-                        :, o - src_lo_t : o + rp - src_lo_t, :
-                    ]
-                    wr(out, hi_t, hi_span, dv)
 
         rows = TZB * px * 4     # one row of a z batch
-        written = (lo_span if rm else 0) + (hi_span if rp else 0)
-        read = written + (src_hi_span if rm else 0) + (src_lo_span if rp else 0)
+        written = sum(s.dst_span for s in sides)
+        read = sum(s.src_span + (0 if s.rebuilt else s.dst_span) for s in sides)
+        n_rd = nq * sum(1 if s.rebuilt else 2 for s in sides)
         _record_dma_bytes(nq, (pz, py, px), n_b * nq * read * rows,
-                          n_b * nq * written * rows, axis="y")
+                          n_b * nq * written * rows, axis="y",
+                          dmas=n_b * (n_rd + nq * len(sides)),
+                          in_flight=2 * n_rd if n_b > 1 else n_rd,
+                          dst_read_skipped=sum(s.rebuilt for s in sides))
+        scratch = []
+        for s in sides:
+            scratch += [
+                pltpu.VMEM((_Y_SLOTS, nq, TZB, s.src_span, px), jnp.float32),
+                pltpu.SemaphoreType.DMA((_Y_SLOTS,)),
+                pltpu.SemaphoreType.DMA((_Y_SLOTS,)),
+            ]
+            if not s.rebuilt:
+                scratch += [
+                    pltpu.VMEM((_Y_SLOTS, nq, TZB, s.dst_span, px), jnp.float32),
+                    pltpu.SemaphoreType.DMA((_Y_SLOTS,)),
+                ]
         return _wrap(scopes.kernel_call(
             "self_fill_y", kernel,
             grid=(n_b,),
             out_shape=_out_shape,
             in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nq,
             out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nq,
-            scratch_shapes=[
-                pltpu.VMEM((TZB, vspan, px), jnp.float32),
-                pltpu.VMEM((TZB, vspan, px), jnp.float32),
-                pltpu.SemaphoreType.DMA(()),
-            ],
+            scratch_shapes=scratch,
             input_output_aliases=_aliases,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
@@ -426,7 +538,7 @@ def make_self_fill(spec: GridSpec, axis: str, vma=None, interpret: bool = False,
     # axis == "x": rewrite both edge lane-tiles, double-buffered over z.
     # 8 buffers (rd/wr x lo/hi x 2 slots); depth picked by the VMEM budget
     TZB = _x_tzb(spec, nq, z_stack)
-    n_b = -(-pz // TZB)
+    n_b = -(-pz // TZB)  # overlapping last batch: z is untiled, restart anywhere
     lo_t = 0
     hi_t = ((o + sz) // _LANE) * _LANE
 

@@ -469,13 +469,15 @@ class HaloExchange:
 
     def _self_fill_group(self, name: str, blocks):
         """One fp32 group on a self-wrap axis: the Pallas fill writes the
-        halos in place, touching only the edge tiles. Only the x kernel's
-        scratch scales with the quantity count; y/z fills carry every
+        halos in place, touching only the edge tiles. The x and y kernels'
+        scratch scales with the quantity count, so a group larger than
+        their VMEM budget carries goes in chunks; the z fill carries every
         quantity in one kernel."""
         from ..ops.halo_fill import max_fill_group
 
         fshape = self._fill_shape()
-        step = max_fill_group(self.spec) if name == AXIS_X else len(blocks)
+        step = (len(blocks) if name == AXIS_Z
+                else max_fill_group(self.spec, name))
         out = []
         for i in range(0, len(blocks), step):
             chunk = blocks[i : i + step]

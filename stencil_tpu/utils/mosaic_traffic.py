@@ -44,6 +44,9 @@ _DMA = re.compile(
 #   "tpu.enqueue_dma"(%a, %b, %sem) <{...}> : (memref<src>, memref<dst>, ...)
 # operand order is the same (source, then target); types carry the spaces
 _DMA_GENERIC = re.compile(r'"tpu\.enqueue_dma"\(.*?\).*?:\s*\((.*)\)')
+_WAIT = re.compile(
+    r"tpu\.wait_dma2\s+semaphore\(.*?\)\s+src\((.*?)\)\s+dst\((.*?)\)\s*$"
+)
 _BOUNDS = re.compile(r"iteration_bounds = array<i64: ([0-9, ]+)>")
 
 
@@ -73,6 +76,18 @@ class DmaOp:
         return self.dst_space == "any" and self.src_space != "any"
 
 
+@dataclass(frozen=True)
+class DmaEvent:
+    """One ``tpu.enqueue_dma`` ("start") or ``tpu.wait_dma2`` ("wait") in
+    body order. ``branch``: the ordinals (in module order) of the
+    ``scf.if`` ops that enclose it, outermost first; ``()`` runs every
+    grid step."""
+
+    kind: str
+    op: DmaOp
+    branch: Tuple[int, ...]
+
+
 @dataclass
 class KernelTraffic:
     """DMA inventory of one compiled Pallas kernel."""
@@ -80,6 +95,13 @@ class KernelTraffic:
     name: str  # "<basename>:<line>" of the pallas_call site
     grid: Tuple[int, ...]  # iteration_bounds
     dmas: List[DmaOp]
+    events: Tuple[DmaEvent, ...] = ()  # the schedule: starts and waits in order
+    vmem_bytes: int = 0  # the VMEM operands of @main: blocks and scratch
+
+    def schedule(self, branches: Sequence[int]) -> List[DmaEvent]:
+        """The events a grid step executes when exactly the ``scf.if`` ops
+        with these ordinals are taken."""
+        return [e for e in self.events if set(e.branch) <= set(branches)]
 
     @property
     def steps(self) -> int:
@@ -143,8 +165,17 @@ def _parse_module(name: str, lines: Sequence[str]) -> KernelTraffic:
     # string attrs (sym_name, location strings) would otherwise silently
     # skew the if/loop DMA attribution (ADVICE r5 #1).
     stack: List[str] = []
+    ifs: List[int] = []  # ordinal of each 'if' frame of the stack
+    n_ifs = 0
+    events: List[DmaEvent] = []
+    vmem_bytes = 0
     opened = False  # the module op's own region has been entered
     for ln in lines:
+        if "func.func @main(" in ln:
+            vmem_bytes = sum(
+                prod(int(t) for t in dims.split("x") if t) * _ITEMSIZE.get(dt, 4)
+                for dims, dt, space in _MEMREF.findall(ln.split(" attributes ")[0])
+                if space == "vmem")
         b = _BOUNDS.search(ln)
         if b:
             grid = tuple(int(t) for t in b.group(1).replace(" ", "").split(","))
@@ -183,6 +214,15 @@ def _parse_module(name: str, lines: Sequence[str]) -> KernelTraffic:
                     loop_depth=sum(1 for f in stack if f == "loop"),
                 )
             )
+            events.append(DmaEvent("start", dmas[-1], tuple(ifs)))
+        w = _WAIT.search(ln)
+        if w:
+            src, dst = _parse_ref(w.group(1)), _parse_ref(w.group(2))
+            if src is None or dst is None:
+                raise ValueError(f"unparseable wait_dma2 operands: {ln.strip()}")
+            events.append(DmaEvent("wait", DmaOp(
+                src[2], dst[2], dst[0], dst[1], len(ifs), stack.count("loop")),
+                tuple(ifs)))
         bare = _STRLIT.sub('""', ln)
         net = bare.count("{") - bare.count("}")
         if net > 0:
@@ -193,6 +233,10 @@ def _parse_module(name: str, lines: Sequence[str]) -> KernelTraffic:
             else:
                 kind = "op"
             stack.extend([kind] * net)
+            if kind == "if":
+                if "scf.if" in bare:
+                    n_ifs += 1
+                ifs.extend([n_ifs - 1] * net)
             opened = True
         elif net < 0:
             if -net > len(stack):
@@ -200,7 +244,9 @@ def _parse_module(name: str, lines: Sequence[str]) -> KernelTraffic:
                     f"unbalanced region braces in Mosaic dump of {name}: "
                     f"{-net} closes against a {len(stack)}-deep stack"
                 )
+            closed = sum(1 for f in stack[net:] if f == "if")
             del stack[net:]
+            del ifs[len(ifs) - closed:]
         # '} else {' with net == 0: the closed and opened regions are both
         # arms of the same scf.if — the stack is already correct.
         if opened and not stack:
@@ -212,7 +258,8 @@ def _parse_module(name: str, lines: Sequence[str]) -> KernelTraffic:
             f"Mosaic dump of {name} ended with an unbalanced region stack "
             f"(opened={opened}, depth={len(stack)})"
         )
-    return KernelTraffic(name=name, grid=grid, dmas=dmas)
+    return KernelTraffic(name=name, grid=grid, dmas=dmas, events=tuple(events),
+                         vmem_bytes=vmem_bytes)
 
 
 def parse_mosaic_dumps(text: str) -> List[KernelTraffic]:

@@ -9,10 +9,38 @@ import pytest
 
 from stencil_tpu.domain.grid import GridSpec
 from stencil_tpu.geometry import Dim3, Radius
-from stencil_tpu.ops.halo_fill import make_self_fill, self_fill_supported
+from stencil_tpu.ops.halo_fill import (
+    _y_sides, _y_tzb, make_self_fill, self_fill_supported)
 
 
-@pytest.mark.parametrize("size,r", [((256, 136, 24), 1), ((140, 160, 40), 2), ((256, 144, 30), 3)])
+def _assert_y_fill(got, want, base, spec):
+    """``got`` is ``want`` on every row but the dead rows (neither halo nor
+    owned) of a destination window the y kernel rebuilds from its source
+    window: each of those is finite and a row of the field, the source
+    window's at the same place."""
+    dead = {s.dst_t + j: s.src_t + j
+            for s in _y_sides(spec) if s.rebuilt
+            for j in range(s.dst_span) if not s.dst_at <= j < s.dst_at + s.r}
+    keep = [y for y in range(got.shape[1]) if y not in dead]
+    np.testing.assert_array_equal(got[:, keep], want[:, keep])
+    for y, src in dead.items():
+        assert np.isfinite(got[:, y]).all(), y
+        np.testing.assert_array_equal(got[:, y], base[:, src])
+
+
+def _garbage_outside_y(base, spec):
+    """Every row of the y halos and of the dead pad starts as NaN."""
+    o, sy = spec.compute_offset().y, spec.base.y
+    base[:, :o] = np.nan
+    base[:, o + sy:] = np.nan
+
+
+# 136, 160, 144 rows: a multiple of 8, so neither y destination window holds
+# an owned row and the kernel rebuilds both; 140: the high window holds rows
+# 144 to 147 of the block and is read, modified and written; 142: it spans
+# two row tiles. 21 and 37 planes leave a clamped last z batch.
+@pytest.mark.parametrize("size,r", [((256, 136, 24), 1), ((140, 160, 40), 2), ((256, 144, 30), 3),
+                                    ((128, 140, 21), 3), ((128, 142, 37), 3)])
 @pytest.mark.parametrize("axis", ["x", "y", "z"])
 def test_self_fill_matches_numpy(size, r, axis):
     sx, sy, sz = size
@@ -22,6 +50,10 @@ def test_self_fill_matches_numpy(size, r, axis):
     o = spec.compute_offset()
     rng = np.random.RandomState(0)
     base = rng.rand(p.z, p.y, p.x).astype(np.float32)
+    if axis == "y":
+        _garbage_outside_y(base, spec)
+        assert sz % 2 == 0 or p.z % _y_tzb(spec) != 0   # 21, 37: clamped
+        assert [s.rebuilt for s in _y_sides(spec)] == [sy % 8 == 0] * 2
     fill = make_self_fill(spec, axis, interpret=True)
     got = np.asarray(fill(jnp.asarray(base)))
     want = base.copy()
@@ -31,66 +63,101 @@ def test_self_fill_matches_numpy(size, r, axis):
     elif axis == "y":
         want[:, o.y - r : o.y, :] = base[:, o.y + sy - r : o.y + sy, :]
         want[:, o.y + sy : o.y + sy + r, :] = base[:, o.y : o.y + r, :]
+        assert np.isfinite(want[:, o.y - r : o.y + sy + r]).all()
+        return _assert_y_fill(got, want, base, spec)
     else:
         want[:, :, o.x - r : o.x] = base[:, :, o.x + sx - r : o.x + sx]
         want[:, :, o.x + sx : o.x + sx + r] = base[:, :, o.x : o.x + r]
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("axis", ["x", "y", "z"])
-def test_self_fill_asymmetric_radius(axis):
+@pytest.mark.parametrize("axis,sy", [("x", 160), ("y", 160), ("z", 160), ("y", 140)])
+def test_self_fill_asymmetric_radius(axis, sy):
     # rm != rp per side (the reference's per-direction Radius semantics)
     r = Radius.constant(0)
     lo = {"x": (-1, 0, 0), "y": (0, -1, 0), "z": (0, 0, -1)}[axis]
     hi = {"x": (1, 0, 0), "y": (0, 1, 0), "z": (0, 0, 1)}[axis]
     r.set_dir(lo, 1)
     r.set_dir(hi, 3)
-    spec = GridSpec(Dim3(140, 160, 40), Dim3(1, 1, 1), r)
+    spec = GridSpec(Dim3(140, sy, 40), Dim3(1, 1, 1), r)
     assert self_fill_supported(spec, axis, jnp.float32)
     p = spec.padded()
     o = spec.compute_offset()
     rng = np.random.RandomState(3)
     base = rng.rand(p.z, p.y, p.x).astype(np.float32)
+    if axis == "y":
+        _garbage_outside_y(base, spec)
     got = np.asarray(make_self_fill(spec, axis, interpret=True)(jnp.asarray(base)))
     want = base.copy()
     # active send dir d fills the receiver's -d halo: radius.dir(-d) gates,
     # so lo-side halo width = r.dir(lo) = 1, hi-side = r.dir(hi) = 3
-    sx, sy, sz = 140, 160, 40
+    sx, sz = 140, 40
     if axis == "z":
         want[o.z - 1 : o.z] = base[o.z + sz - 1 : o.z + sz]
         want[o.z + sz : o.z + sz + 3] = base[o.z : o.z + 3]
     elif axis == "y":
         want[:, o.y - 1 : o.y, :] = base[:, o.y + sy - 1 : o.y + sy, :]
         want[:, o.y + sy : o.y + sy + 3, :] = base[:, o.y : o.y + 3, :]
+        return _assert_y_fill(got, want, base, spec)
     else:
         want[:, :, o.x - 1 : o.x] = base[:, :, o.x + sx - 1 : o.x + sx]
         want[:, :, o.x + sx : o.x + sx + 3] = base[:, :, o.x : o.x + 3]
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("axis", ["x", "y", "z"])
-def test_multi_quantity_fill_matches_per_quantity(axis):
-    # fused nq=3 kernel must equal three independent single-quantity fills
-    spec = GridSpec(Dim3(140, 160, 40), Dim3(1, 1, 1), Radius.constant(2))
+def test_y_fill_rebuilt_windows_hold_rows_of_the_field():
+    """Where the kernel skips the read of a destination window it writes
+    the source window there: from halos and dead rows that start as NaN,
+    every row of both windows comes out finite and equal to the row of the
+    field at the same place in the source window, and nothing else of the
+    block changes."""
+    spec = GridSpec(Dim3(128, 64, 19), Dim3(1, 1, 1), Radius.constant(3))
+    p = spec.padded()
+    rng = np.random.RandomState(11)
+    base = rng.rand(p.z, p.y, p.x).astype(np.float32)
+    _garbage_outside_y(base, spec)
+    got = np.asarray(make_self_fill(spec, "y", interpret=True)(jnp.asarray(base)))
+    sides = _y_sides(spec)
+    assert [s.rebuilt for s in sides] == [True, True]
+    touched = np.zeros(p.y, bool)
+    for s in sides:
+        win = got[:, s.dst_t : s.dst_t + s.dst_span]
+        assert np.isfinite(win).all()
+        np.testing.assert_array_equal(win, base[:, s.src_t : s.src_t + s.src_span])
+        touched[s.dst_t : s.dst_t + s.dst_span] = True
+    np.testing.assert_array_equal(got[:, ~touched], base[:, ~touched])
+
+
+@pytest.mark.parametrize("axis,nq,sy", [
+    ("x", 3, 160), ("y", 3, 160), ("z", 3, 160),
+    # y: both branches of the destination read (160 rows: rebuilt; 140:
+    # read) at the quantity counts of the cells (4, 8) and at 1
+    ("y", 1, 160), ("y", 4, 160), ("y", 8, 160),
+    ("y", 1, 140), ("y", 4, 140), ("y", 8, 140)])
+def test_multi_quantity_fill_matches_per_quantity(axis, nq, sy):
+    # fused kernel must equal nq independent single-quantity fills
+    spec = GridSpec(Dim3(140, sy, 40), Dim3(1, 1, 1), Radius.constant(2))
     p = spec.padded()
     rng = np.random.RandomState(5)
-    bases = [rng.rand(p.z, p.y, p.x).astype(np.float32) for _ in range(3)]
+    bases = [rng.rand(p.z, p.y, p.x).astype(np.float32) for _ in range(nq)]
     single = make_self_fill(spec, axis, interpret=True)
-    multi = make_self_fill(spec, axis, interpret=True, nq=3)
+    multi = make_self_fill(spec, axis, interpret=True, nq=nq)
     got = multi(*[jnp.asarray(b) for b in bases])
-    for q in range(3):
+    got = (got,) if nq == 1 else got
+    for q in range(nq):
         want = np.asarray(single(jnp.asarray(bases[q])))
         np.testing.assert_array_equal(np.asarray(got[q]), want)
 
 
-@pytest.mark.parametrize("axis", ["x", "y"])
+@pytest.mark.parametrize("axis,sy", [("x", 32), ("y", 32), ("y", 36)])
 @pytest.mark.parametrize("nq", [1, 2])
-def test_self_fill_z_stack_matches_per_block(axis, nq):
+def test_self_fill_z_stack_matches_per_block(axis, nq, sy):
     """``z_stack=c``: one fill over the (c*pz, py, px) view of a resident
     z-stack must equal the single-block fill applied to each stacked block
-    (VERDICT r4 item 7 — the resident Pallas fast path)."""
+    (VERDICT r4 item 7 — the resident Pallas fast path). 36 rows: the y
+    kernel reads its high destination window; 32: it rebuilds both."""
     c = 3
-    spec = GridSpec(Dim3(140, 32, 16), Dim3(1, 1, c), Radius.constant(2))
+    spec = GridSpec(Dim3(140, sy, 16), Dim3(1, 1, c), Radius.constant(2))
     assert self_fill_supported(spec, axis, jnp.float32, z_stack=c)
     p = spec.padded()
     rng = np.random.RandomState(7)
@@ -145,7 +212,7 @@ def test_exchange_blocks_fused_dispatch(monkeypatch):
         for a in ("x", "y", "z")
         for n in (1, 2, 3, 5)
     }
-    monkeypatch.setattr(HF, "max_fill_group", lambda _spec: 2)
+    monkeypatch.setattr(HF, "max_fill_group", lambda _spec, _axis="x": 2)
 
     rng = np.random.RandomState(9)
     coords = (
@@ -203,7 +270,7 @@ def test_exchange_blocks_fused_dispatch_resident(monkeypatch):
         for a in ("x", "y")
         for n in (1, 2, 3, 5)
     }
-    monkeypatch.setattr(HF, "max_fill_group", lambda _spec: 2)
+    monkeypatch.setattr(HF, "max_fill_group", lambda _spec, _axis="x": 2)
 
     coords = (
         np.arange(g.z)[:, None, None] * 10000
@@ -236,6 +303,57 @@ def test_exchange_blocks_fused_dispatch_resident(monkeypatch):
                         checked += 1
                         bad += blk[off.z + zz, off.y + yy, off.x + xx] != want
             assert checked > 0 and bad == 0, (key, j, bad)
+
+
+def test_y_fill_group_follows_the_vmem_budget(monkeypatch):
+    """The y kernel's buffers scale with the quantity count (a slot pair a
+    quantity and window), so ``max_fill_group(spec, "y")`` is what
+    ``HaloExchange._self_fill_group`` chunks by: at a budget that carries
+    two quantities a group of five goes as 2 + 2 + 1, ``make_self_fill``
+    refuses a third, and every halo cell comes out right."""
+    import stencil_tpu.ops.halo_fill as HF
+    from stencil_tpu.parallel import HaloExchange, grid_mesh
+    from stencil_tpu.parallel.exchange import shard_blocks
+
+    g = Dim3(140, 16, 16)
+    spec = GridSpec(g, Dim3(1, 1, 1), Radius.constant(2))
+    assert HF.max_fill_group(spec, "y") == 16
+    monkeypatch.setattr(HF, "_VMEM_BUDGET", 600_000)
+    assert HF.max_fill_group(spec, "y") == 2 == HF.max_fill_group(spec)
+    assert HF._y_tzb(spec, 2) <= 8
+    assert HF._y_scratch_bytes(spec, 2, 8) <= 600_000 < HF._y_scratch_bytes(spec, 3, 8)
+    with pytest.raises(ValueError, match="group size 3"):
+        make_self_fill(spec, "y", interpret=True, nq=3)
+
+    built = []
+    real = HF.make_self_fill
+
+    def interpreted(spec, axis, vma=None, nq=1, z_stack=1):
+        built.append((axis, nq))
+        return real(spec, axis, interpret=True, nq=nq, z_stack=z_stack)
+
+    monkeypatch.setattr(HF, "make_self_fill", interpreted)
+    mesh = grid_mesh(spec.dim, jax.devices()[:1])
+    ex = HaloExchange(spec, mesh)
+    ex.__dict__["_self_fills"] = {a: interpreted(spec, a) for a in "xyz"}
+    del built[:]
+    coords = (np.arange(g.z)[:, None, None] * 10000
+              + np.arange(g.y)[None, :, None] * 100
+              + np.arange(g.x)[None, None, :]).astype(np.float32)
+    out = ex.exchange_blocks(
+        {i: shard_blocks(coords, spec, mesh) for i in range(5)})
+    assert sorted(built) == [("x", 2), ("y", 2), ("z", 5)]   # 1: the cached one
+    off, r = spec.compute_offset(), 2
+    wrap = [np.arange(-r, n + r) % n for n in (g.z, g.y, g.x)]
+    for arr in out.values():
+        blk = np.asarray(jax.device_get(arr))[0, 0, 0]
+        held = blk[off.z - r : off.z + g.z + r, off.y - r : off.y + g.y + r,
+                   off.x - r : off.x + g.x + r]
+        np.testing.assert_array_equal(held, coords[np.ix_(*wrap)])
+
+    # a plane too wide for even one quantity at the shallowest batch
+    monkeypatch.setattr(HF, "_VMEM_BUDGET", 200_000)
+    assert not self_fill_supported(spec, "y", jnp.float32)
 
 
 def test_max_fill_group_positive():

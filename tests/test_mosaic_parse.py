@@ -66,6 +66,50 @@ def test_trailing_text_after_module_close_is_ignored():
     assert len(k.dmas) == 1
 
 
+_WAIT_LINE = (
+    "      tpu.wait_dma2 semaphore(%3 : memref<!tpu.dma_semaphore, "
+    "#tpu.memory_space<semaphore_mem>>) src(%0 : memref<2x8x128xf32, "
+    "#tpu.memory_space<any>>) dst(%1 : memref<2x8x128xf32, "
+    "#tpu.memory_space<vmem>>)"
+)
+
+
+def test_events_keep_body_order_and_the_enclosing_ifs():
+    # starts and waits in order, each with the ordinals of the scf.if ops
+    # round it; schedule() picks a grid step's by the branches it takes
+    body = "\n".join(
+        [
+            "module @kernel {",
+            "  func.func @main(%arg0: i32, %arg1: memref<4x8x128xf32, "
+            "#tpu.memory_space<any>>, %arg2: memref<2x8x128xf32, "
+            "#tpu.memory_space<vmem>>, %arg3: memref<2x!tpu.dma_semaphore, "
+            "#tpu.memory_space<semaphore_mem>>) attributes {a = [{}]} {",
+            "  scf.if %first {",
+            _DMA_LINE,
+            "  }",
+            "  scf.if %more {",
+            "    scf.if %inner {",
+            _WAIT_LINE,
+            "    }",
+            _DMA_LINE,
+            "  } else {",
+            _DMA_LINE,
+            "  }",
+            _WAIT_LINE,
+            "  }",
+            "}",
+        ]
+    )
+    (k,) = mt.parse_mosaic_dumps(_dump(body))
+    assert [(e.kind, e.branch) for e in k.events] == [
+        ("start", (0,)), ("wait", (1, 2)), ("start", (1,)), ("start", (1,)),
+        ("wait", ())]
+    assert all(e.op.is_input and e.op.nbytes == 2 * 8 * 128 * 4 for e in k.events)
+    assert [e.kind for e in k.schedule([1])] == ["start", "start", "wait"]
+    assert [e.kind for e in k.schedule([1, 2])] == ["wait", "start", "start", "wait"]
+    assert len(k.dmas) == 3 and k.vmem_bytes == 2 * 8 * 128 * 4
+
+
 _DMA_GENERIC_LINE = (
     '      "tpu.enqueue_dma"(%129, %130, %132) <{operandSegmentSizes = '
     "array<i32: 1, 0, 1, 1, 0, 0>}> : (memref<1x144x384xf32, "

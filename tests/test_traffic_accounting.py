@@ -139,24 +139,29 @@ def test_substep_steady_state_amplification(n, tight):
         assert ty == 128
 
 
-def test_fill_y_rmw_row_tiles_only():
+def test_fill_y_moves_row_tiles_only():
     rep = _report("fill-y")
     (k,) = rep["kernels"]
+    tzb = rep["tzb"]
     pz, py, px = rep["padded"]
     r = rep["radius"]
-    g = _groups(k)
-    tile = (8, 8, px)
-    # per z batch: 4 row-tile reads (dest + wrap-source windows, both
-    # sides) and 2 writes, all unconditional — the 8-row-tile RMW
-    # economics of ops/halo_fill.py:15 ("RMW of 4 row-tiles")
-    assert g[("in", tile)] == 4 and g[("out", tile)] == 2
-    assert len(k["dmas"]) == 6
-    assert all(d["if_depth"] == 0 and d["loop_depth"] == 0 for d in k["dmas"])
-    assert k["grid"] == [-(-pz // 8)]
+    tile = (tzb, 8, px)
+    ins = [d for d in k["dmas"] if d["dir"] == "in"]
+    outs = [d for d in k["dmas"] if d["dir"] == "out"]
+    # every transfer is one 8-row window of a z batch. 256 rows are a
+    # multiple of 8, so neither destination window holds an owned row and
+    # neither is read: a batch reads the two source windows (spelled twice,
+    # at step 0 and prefetched a step ahead) and writes them onto the two
+    # destination windows — the 8-row-tile economics of ops/halo_fill.py
+    assert all(tuple(d["shape"]) == tile for d in ins + outs)
+    assert len(ins) == 4 and all(d["if_depth"] == 1 for d in ins)
+    assert len(outs) == 2 and all(d["if_depth"] == 0 for d in outs)
+    assert all(d["loop_depth"] == 0 for d in k["dmas"])
+    assert k["grid"] == [-(-pz // tzb)]
     # written rows per batch vs the 2r logical halo rows: the 8-row
     # minimum write granularity
-    written = sum(d["bytes"] for d in k["dmas"] if d["dir"] == "out")
-    logical = 2 * r * 8 * px * 4
+    written = sum(d["bytes"] for d in outs)
+    logical = 2 * r * tzb * px * 4
     assert written / logical == pytest.approx(16 / 6, rel=1e-12)
 
 
