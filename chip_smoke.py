@@ -808,6 +808,72 @@ def phase_four_iso3dfd(devs, n, cell, rehearsal: bool) -> dict:
     return facts
 
 
+def phase_mg_x4(devs, klass: str, rehearsal: bool) -> dict:
+    """NPB MG over four chips through ``mg.run`` (the application's own
+    mesh, (1,2,2): x whole, every level's blocks halving with the level):
+    four iterations of class ``klass`` from NPB's own data, every owned
+    cell of the finest u and r against the float64 reference of the
+    benchmark on the host, and the norm beside the reference's. On the
+    chip the levels whose rows are whole lane tiles run the compiled box
+    and transfer kernels and the others XLA, by the application's own
+    ``mg.cycle_plan``; every level's every array is finite."""
+    import numpy as np
+
+    from benchmark.reference import mg as reference
+    from stencil_tpu.apps import mg
+    from stencil_tpu.geometry import Dim3
+    from stencil_tpu.obs import telemetry
+    from stencil_tpu.parallel.exchange import unshard_blocks
+
+    nit = 4
+    with PallasRecorder() as rec:
+        r = mg.run(klass=klass, nit=nit, devices=devs)
+    dd, hs = r["levels"][0]
+    assert dd.spec.dim == Dim3(1, 2, 2), dd.spec.dim
+    for q in ("u", "r", "v"):
+        require_four_shards(dd.get_curr(hs[q]), devs, f"mg {q}")
+    plan = telemetry.get().records(kind="counter", name="mg.cycle_plan")[-1]
+    assert len(plan["levels"]) == len(r["levels"]) == len(
+        reference.levels(r["n"])), plan
+    tight = [lv for lv in plan["levels"] if lv["layout"] == "tight_x"]
+    facts = {"iter_ms": round(1e3 * r["iter_trimean_s"], 3),
+             "levels": len(plan["levels"]), "tight_x_levels": len(tight)}
+    if not rehearsal:
+        assert len(tight) >= 2, plan
+        for lv in tight:
+            assert {lv["operators"][op]["impl"] for op in
+                    ("mg_resid", "mg_psinv")} == {"pallas"}, lv
+        assert tight[0]["operators"]["mg_rprj3"]["impl"] == "pallas", tight[0]
+        assert tight[0]["operators"]["mg_interp"]["impl"] == "pallas", tight[0]
+        require_compiled_kernels(
+            rec, ["make_pallas_mg_box", "make_pallas_mg_rprj3",
+                  "make_pallas_mg_interp"], rehearsal)
+        facts["bytes_after_run"] = require_balanced(devs, "mg 4 chips")
+    got = {q: unshard_blocks(dd.get_curr(hs[q]), dd.spec) for q in ("u", "r")}
+    for lv, lhs in r["levels"]:
+        for q in ("u", "r"):
+            assert np.isfinite(unshard_blocks(lv.get_curr(lhs[q]),
+                                              lv.spec)).all(), (lv.size, q)
+    n, _, smoother, _ = reference.CLASSES[klass]
+    plus, minus = reference.zran3(n)
+    u, res, norm = reference.run(n, nit, smoother,
+                                 reference.charges_field(n, plus, minus))
+    for q, want in (("u", u), ("r", res)):
+        scale = float(np.abs(want).max())
+        diff = float(np.abs(got[q] - want).max())
+        say(f"mg class {klass} {q}: max |four chips - reference| = "
+            f"{diff:.3e} of a largest value {scale:.3e}")
+        # r is what is left of charges of 1 after four cycles
+        assert diff <= 4e-6 * max(scale, 1.0 if q == "r" else scale), (
+            q, diff, scale)
+        facts[f"max_abs_diff_{q}"] = diff
+    say(f"mg class {klass}: norm {r['rnm2']:.10e} after {nit} iterations, "
+        f"the reference's {norm:.10e}")
+    assert abs(r["rnm2"] - norm) <= 2e-3 * norm, (r["rnm2"], norm)
+    facts["rnm2"] = r["rnm2"]
+    return facts
+
+
 # ------------------------------------------------------------ the run
 
 
@@ -827,6 +893,7 @@ def build_phases(devs, rehearsal: bool) -> list:
                 four, 16, 16, True)),
             ("four_chip_iso3dfd", 4, lambda: phase_four_iso3dfd(
                 four, (48, 48, 48), "iso3dfd1024x4.steady", True)),
+            ("mg_class_b_x4", 4, lambda: phase_mg_x4(four, "S", True)),
             ("four_chip_exchange", 4, lambda: phase_exchange(
                 four, Dim3(16, 32, 32), p122, True)),
             ("four_chip_exchange_x", 4, lambda: phase_exchange(
@@ -845,6 +912,9 @@ def build_phases(devs, rehearsal: bool) -> list:
             four, 256, 128, False)),
         ("four_chip_iso3dfd", 4, lambda: phase_four_iso3dfd(
             four, (256, 256, 512), "iso3dfd1024x4.steady", False)),
+        # class B: 256^3, blocks of 128 x 128 x 256 and of 64 x 64 x 128 on
+        # the tight-x layout, the six levels below them inline
+        ("mg_class_b_x4", 4, lambda: phase_mg_x4(four, "B", False)),
         ("four_chip_exchange", 4, lambda: phase_exchange(
             four, Dim3(512, 1024, 1024), p122, False)),
         # exchange_weak's own pick on four chips: x is split

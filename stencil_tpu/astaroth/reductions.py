@@ -48,9 +48,9 @@ class Reductions:
 
     def __init__(self, ex: HaloExchange):
         self.ex = ex
-        self.mask = jax.device_put(
-            jnp.asarray(compute_mask(ex.spec)), ex.sharding()
-        )
+        # from the host straight to each device's shard: a jnp.asarray
+        # first would stage the whole mask on the default device
+        self.mask = jax.device_put(compute_mask(ex.spec), ex.sharding())
         self._scal = jax.jit(self._build_scal())
         self._vec = jax.jit(self._build_vec())
 

@@ -43,6 +43,10 @@ HALO_UNPACK = "stencil.halo.unpack"         # carrier -> halo cells
 SWEEP_SHELL = "stencil.sweep.shell"         # exterior slabs, dynamic shells
 MASK = "stencil.mask"                       # the sel == 1 / sel == 2 masks
 CARRY = "stencil.carry"                     # reshapes, swaps, stacking
+# a multigrid level's tag, ``stencil.mg.level<k>`` (k = 1 the coarsest):
+# OUTSIDE the kernel, halo and glue scopes of what runs on the level, and of
+# no layer itself, so an op keeps the layer of its innermost scope
+MG_LEVEL = "stencil.mg.level"
 
 SCOPES: Dict[str, str] = {
     HALO_SELF_FILL: LAYER_HALO,
@@ -64,6 +68,11 @@ KERNELS: Dict[str, str] = {
     "jacobi_multistep_rows": LAYER_KERNELS,
     "astaroth_substep": LAYER_KERNELS,
     "iso3dfd_step": LAYER_KERNELS,
+    # MG's four operators, Pallas or plain XLA (:func:`kernel_scope`)
+    "mg_resid": LAYER_KERNELS,
+    "mg_psinv": LAYER_KERNELS,
+    "mg_rprj3": LAYER_KERNELS,
+    "mg_interp": LAYER_KERNELS,
     "fused_jacobi": LAYER_KERNELS,
     "persistent_jacobi": LAYER_KERNELS,
     "self_fill_x": LAYER_HALO,
@@ -82,8 +91,9 @@ JACOBI_STEP = "stencil_jacobi_step"
 ASTAROTH_ITER = "stencil_astaroth_iter"
 EXCHANGE_LOOP = "stencil_exchange_loop"
 ISO3DFD_LOOP = "stencil_iso3dfd_loop"
+MG_ITER = "stencil_mg_iter"
 MODULES = (JACOBI_LOOP, JACOBI_STEP, ASTAROTH_ITER, EXCHANGE_LOOP,
-           ISO3DFD_LOOP)
+           ISO3DFD_LOOP, MG_ITER)
 
 
 def layer_of(scope: Optional[str]) -> Optional[str]:
@@ -102,6 +112,34 @@ def scope(name: str):
     import jax
 
     return jax.named_scope(name)
+
+
+def kernel_scope(name: str):
+    """``jax.named_scope("stencil.kernel.<name>")`` for an operator of the
+    kernel vocabulary that plain XLA computes: its ops carry the name a
+    Pallas build of it would."""
+    if name not in KERNELS:
+        raise KeyError(f"{name!r} is not in the kernel vocabulary")
+    import jax
+
+    return jax.named_scope(KERNEL_PREFIX + name)
+
+
+def level_scope(level: int):
+    """``jax.named_scope("stencil.mg.level<level>")``: the tag of a
+    multigrid level, opened outside everything that runs on it."""
+    import jax
+
+    return jax.named_scope(f"{MG_LEVEL}{int(level)}")
+
+
+def level_of(op_name: str) -> Optional[int]:
+    """The multigrid level an ``op_name`` path is tagged with, ``None``
+    for none."""
+    for part in scopes_in(op_name):
+        if part.startswith(MG_LEVEL) and part[len(MG_LEVEL):].isdigit():
+            return int(part[len(MG_LEVEL):])
+    return None
 
 
 def kernel_call(name: str, kernel, **kwargs):

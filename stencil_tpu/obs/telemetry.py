@@ -230,6 +230,16 @@ NAME_FIELDS = {
                           ("chunk", int), ("block_cells", int),
                           ("halo_bytes_sent", int),
                           ("halo_bytes_if_all", int)),
+    # what ops/mg.make_mg_iter built, once per build (value: iterations a
+    # dispatch): per level, finest first, its ``level`` (NPB's k), ``grid``,
+    # a ``block``'s owned cells, the ``layout`` (tight_x / inline), the
+    # fills an iteration makes of its arrays, and per operator
+    # (``operators``: mg_resid, mg_psinv, mg_rprj3 FROM the level,
+    # mg_interp ONTO it) what implements it (pallas / xla), its calls an
+    # iteration, the least bytes a call moves (its own arrays once each)
+    # and the halo bytes the fill after it writes (benchmark/apps/mg.py
+    # holds the configuration to it)
+    "mg.cycle_plan": (("module", str), ("levels", list)),
     # what a composed per-block exchange body issued, once per build
     # (value: ppermutes in all): per axis phase its ``axis``, the
     # ``permutes`` issued for it and whether its two directions went as
@@ -330,6 +340,8 @@ KNOWN_NAMES = frozenset(NAME_FIELDS) | frozenset({
     "astaroth.realize", "astaroth.steps",
     "iso3dfd.realize", "iso3dfd.init", "iso3dfd.warmup", "iso3dfd.steps",
     "iso3dfd.iter", "iso3dfd.iter_trimean_s", "iso3dfd.mcells_per_s",
+    "mg.realize", "mg.init", "mg.warmup", "mg.steps", "mg.iter",
+    "mg.iter_trimean_s", "mg.mcells_per_s", "mg.rnm2",
     "exchange.realize", "exchange.steps",
     "jacobi.realize", "jacobi.steps",
     "halo.self_fill.bytes_dma", "halo.split_x.bytes_dma",
@@ -339,7 +351,7 @@ KNOWN_NAMES = frozenset(NAME_FIELDS) | frozenset({
 KEEP_RECORDS = 4096
 
 # the top-level spans that cover an application's run() without a hole
-# (``jacobi.realize`` ... ``iso3dfd.steps``): each carries the devices'
+# (``jacobi.realize`` ... ``mg.steps``): each carries the devices'
 # memory when it closes (``mem_bytes_in_use``, ``mem_peak_bytes``), so the
 # phase that set a run's peak is read and not inferred; no other span pays
 # the query

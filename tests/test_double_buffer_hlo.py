@@ -197,3 +197,53 @@ def test_split_x_exchange_loop_touches_no_whole_field_on_the_lane_axis(
     assert f"{p.z},{p.y},3]" not in text
     # the carriers on the wire are lane-dense: 13 groups of 42 planes
     assert "f32[13,4,528,128]" in text
+
+
+@pytest.mark.parametrize("part", [(1, 1, 1), (1, 2, 2)], ids=str)
+def test_the_mg_iteration_copies_no_level_and_holds_nothing_of_its_own(
+        part, topo, as_on_the_chip):
+    """``mg512.steady``'s own program (class C: nine levels, 34 operators
+    and their fills, one iteration) and the same on the application's
+    four-chip mesh: every level's u and r is donated and comes back where
+    it lay, the program allocates nothing beside them, and no ``copy`` has
+    the shape of a block of a megabyte or more (the tight-x levels and the first below them). The copies
+    that are there are XLA's interleaves of the prolongation below the
+    tight-x levels, the largest the owned cells of 128^3, and relayouts of
+    the 2^3 and 4^3 levels' few rows."""
+    from stencil_tpu.domain.grid import GridSpec
+    from stencil_tpu.geometry import Dim3
+    from stencil_tpu.obs import scopes, telemetry
+    from stencil_tpu.ops import mg
+    from stencil_tpu.parallel import HaloExchange, grid_mesh
+
+    d = Dim3(*part)
+    mesh = grid_mesh(d, list(topo.devices)[:d.flatten()])
+    exs = [HaloExchange(GridSpec(Dim3(m, m, m), d, mg.level_radius(m, d)),
+                        mesh) for m in mg.level_sizes(512)]
+    scopes.clear()
+    mg.make_mg_iter(exs)
+    rec = scopes._registry[scopes.MG_ITER][-1]
+    compiled = rec["fn"].lower(*rec["args"]).compile()
+    text = compiled.as_text()
+    plan = telemetry.get().records(kind="counter", name="mg.cycle_plan")[-1]
+    pallas = sum(op["calls_per_iter"] for lv in plan["levels"]
+                 for op in lv["operators"].values() if op["impl"] == "pallas")
+    assert pallas == 11, plan
+    for kernel in mg.OPERATORS:
+        assert re.search(rf"%{kernel}[.\d]* = .*tpu_custom_call", text), kernel
+    blocks = []
+    for ex in exs:
+        p = ex.spec.padded()
+        if 4 * p.z * p.y * p.x >= 1 << 20:
+            blocks.append(f"{p.z},{p.y},{p.x}]")
+    assert len(blocks) >= 3
+    whole = [(instr, shape) for instr, shape in _COPY.findall(text)
+             if any(shape.startswith("f32[" + b) or ",1," + b in shape
+                    for b in blocks)]
+    assert not whole, whole
+    mem = compiled.memory_analysis()
+    levels = sum(2 * 4 * math.prod(ex.spec.block_shape_zyx()) for ex in exs)
+    v = 4 * math.prod(exs[0].spec.block_shape_zyx())
+    assert mem.argument_size_in_bytes == levels + v
+    assert mem.alias_size_in_bytes == levels
+    assert mem.temp_size_in_bytes == 0

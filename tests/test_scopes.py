@@ -47,8 +47,17 @@ def test_every_pallas_call_passes_a_name_and_every_kernel_is_in_the_vocabulary()
             assert isinstance(first, ast.Constant), (rel, "literal name")
             kernels.append(first.value)
     assert sites == [os.path.join("obs", "scopes.py")], sites
-    assert len(kernels) == 16
+    assert len(kernels) == 20
     assert set(kernels) == set(scopes.KERNELS)
+    # an operator that plain XLA may compute carries its kernel's name all
+    # the same (kernel_scope); a name outside the vocabulary is refused
+    assert scopes.layer_of("stencil.kernel.mg_rprj3") == scopes.LAYER_KERNELS
+    with pytest.raises(KeyError):
+        scopes.kernel_scope("nameless")
+    # a multigrid level's tag is of no layer: an op keeps its innermost's
+    assert scopes.layer_of(scopes.MG_LEVEL + "3") is None
+    assert scopes.level_of("jit(f)/stencil.mg.level7/stencil.carry/mul") == 7
+    assert scopes.level_of("jit(f)/stencil.carry/mul") is None
 
 
 def test_every_named_scope_is_in_the_vocabulary():
