@@ -808,15 +808,23 @@ def phase_four_iso3dfd(devs, n, cell, rehearsal: bool) -> dict:
     return facts
 
 
-def phase_mg_x4(devs, klass: str, rehearsal: bool) -> dict:
-    """NPB MG over four chips through ``mg.run`` (the application's own
-    mesh, (1,2,2): x whole, every level's blocks halving with the level):
-    four iterations of class ``klass`` from NPB's own data, every owned
-    cell of the finest u and r against the float64 reference of the
-    benchmark on the host, and the norm beside the reference's. On the
-    chip the levels whose rows are whole lane tiles run the compiled box
-    and transfer kernels and the others XLA, by the application's own
-    ``mg.cycle_plan``; every level's every array is finite."""
+def phase_mg(devs, klass: str, rehearsal: bool) -> dict:
+    """NPB MG through ``mg.run`` on the application's own mesh: four
+    iterations of class ``klass`` from NPB's own data, every owned cell of
+    the finest u and r against the float64 reference of the benchmark on
+    the host, and the norm beside the reference's; every level's every
+    array is finite. On four chips ((1,2,2): x whole, every level's blocks
+    halving with the level) the levels whose rows are whole lane tiles run
+    the compiled box and transfer kernels and the others XLA, by the
+    application's own ``mg.cycle_plan``. On ONE chip the levels under the
+    coarsest tight-x level are besides ONE compiled call that keeps them
+    in VMEM (``mg_coarse``: 23 of the calls, 22 of the fills): the cell
+    ``mg512.steady``'s own path, whose ``correct`` reads the first
+    iteration on 13 boxes only. The rehearsal's twin of that is class W,
+    128^3, the smallest with a tight-x level, its kernels interpreted."""
+    import functools
+    from unittest import mock
+
     import numpy as np
 
     from benchmark.reference import mg as reference
@@ -825,19 +833,35 @@ def phase_mg_x4(devs, klass: str, rehearsal: bool) -> dict:
     from stencil_tpu.obs import telemetry
     from stencil_tpu.parallel.exchange import unshard_blocks
 
-    nit = 4
-    with PallasRecorder() as rec:
+    nit, one = 4, len(devs) == 1
+    # run() takes the kernels on a TPU only: the one-chip rehearsal tells
+    # the builder to interpret them, as the benchmark's rehearsal does
+    builder = functools.partial(
+        mg.make_mg_iter, use_pallas=True, interpret=True) \
+        if rehearsal and one else mg.make_mg_iter
+    with PallasRecorder() as rec, \
+            mock.patch.object(mg, "make_mg_iter", builder):
         r = mg.run(klass=klass, nit=nit, devices=devs)
     dd, hs = r["levels"][0]
-    assert dd.spec.dim == Dim3(1, 2, 2), dd.spec.dim
-    for q in ("u", "r", "v"):
-        require_four_shards(dd.get_curr(hs[q]), devs, f"mg {q}")
+    assert dd.spec.dim == (Dim3(1, 1, 1) if one else Dim3(1, 2, 2)), \
+        dd.spec.dim
+    if not one:
+        for q in ("u", "r", "v"):
+            require_four_shards(dd.get_curr(hs[q]), devs, f"mg {q}")
     plan = telemetry.get().records(kind="counter", name="mg.cycle_plan")[-1]
     assert len(plan["levels"]) == len(r["levels"]) == len(
         reference.levels(r["n"])), plan
     tight = [lv for lv in plan["levels"] if lv["layout"] == "tight_x"]
     facts = {"iter_ms": round(1e3 * r["iter_trimean_s"], 3),
-             "levels": len(plan["levels"]), "tight_x_levels": len(tight)}
+             "levels": len(plan["levels"]), "tight_x_levels": len(tight),
+             "resident_levels": plan["resident_levels"]}
+    kernels = ["make_pallas_mg_box"]
+    if one:
+        assert (plan["resident_levels"], plan["resident_calls"],
+                plan["resident_fills"]) == (6, 23, 22), plan
+        kernels.append("make_pallas_mg_coarse")
+    else:
+        assert plan["resident_levels"] == 0, plan
     if not rehearsal:
         assert len(tight) >= 2, plan
         for lv in tight:
@@ -845,10 +869,11 @@ def phase_mg_x4(devs, klass: str, rehearsal: bool) -> dict:
                     ("mg_resid", "mg_psinv")} == {"pallas"}, lv
         assert tight[0]["operators"]["mg_rprj3"]["impl"] == "pallas", tight[0]
         assert tight[0]["operators"]["mg_interp"]["impl"] == "pallas", tight[0]
-        require_compiled_kernels(
-            rec, ["make_pallas_mg_box", "make_pallas_mg_rprj3",
-                  "make_pallas_mg_interp"], rehearsal)
-        facts["bytes_after_run"] = require_balanced(devs, "mg 4 chips")
+        kernels += ["make_pallas_mg_rprj3", "make_pallas_mg_interp"]
+        if not one:
+            facts["bytes_after_run"] = require_balanced(devs, "mg 4 chips")
+    if one or not rehearsal:
+        require_compiled_kernels(rec, kernels, rehearsal)
     got = {q: unshard_blocks(dd.get_curr(hs[q]), dd.spec) for q in ("u", "r")}
     for lv, lhs in r["levels"]:
         for q in ("u", "r"):
@@ -861,7 +886,7 @@ def phase_mg_x4(devs, klass: str, rehearsal: bool) -> dict:
     for q, want in (("u", u), ("r", res)):
         scale = float(np.abs(want).max())
         diff = float(np.abs(got[q] - want).max())
-        say(f"mg class {klass} {q}: max |four chips - reference| = "
+        say(f"mg class {klass} {q}: max |{len(devs)} chip(s) - reference| = "
             f"{diff:.3e} of a largest value {scale:.3e}")
         # r is what is left of charges of 1 after four cycles
         assert diff <= 4e-6 * max(scale, 1.0 if q == "r" else scale), (
@@ -893,12 +918,13 @@ def build_phases(devs, rehearsal: bool) -> list:
                 four, 16, 16, True)),
             ("four_chip_iso3dfd", 4, lambda: phase_four_iso3dfd(
                 four, (48, 48, 48), "iso3dfd1024x4.steady", True)),
-            ("mg_class_b_x4", 4, lambda: phase_mg_x4(four, "S", True)),
+            ("mg_class_b_x4", 4, lambda: phase_mg(four, "S", True)),
             ("four_chip_exchange", 4, lambda: phase_exchange(
                 four, Dim3(16, 32, 32), p122, True)),
             ("four_chip_exchange_x", 4, lambda: phase_exchange(
                 four, Dim3(32, 32, 16), p221, True)),
             ("jacobi", 1, lambda: phase_jacobi(devs, 16, True, ref_n=16)),
+            ("mg_class_a", 1, lambda: phase_mg(devs[:1], "W", True)),
             ("exchange", 1, lambda: phase_exchange(
                 devs[:1], Dim3(16, 16, 16), Dim3(1, 1, 1), True)),
             ("astaroth", 1, lambda: phase_astaroth(devs, 16, 16, True)),
@@ -914,7 +940,7 @@ def build_phases(devs, rehearsal: bool) -> list:
             four, (256, 256, 512), "iso3dfd1024x4.steady", False)),
         # class B: 256^3, blocks of 128 x 128 x 256 and of 64 x 64 x 128 on
         # the tight-x layout, the six levels below them inline
-        ("mg_class_b_x4", 4, lambda: phase_mg_x4(four, "B", False)),
+        ("mg_class_b_x4", 4, lambda: phase_mg(four, "B", False)),
         ("four_chip_exchange", 4, lambda: phase_exchange(
             four, Dim3(512, 1024, 1024), p122, False)),
         # exchange_weak's own pick on four chips: x is split
@@ -929,6 +955,9 @@ def build_phases(devs, rehearsal: bool) -> list:
         ("exchange_512", 1, lambda: phase_exchange(
             devs[:1], Dim3(512, 512, 512), Dim3(1, 1, 1), False)),
         ("astaroth_256", 1, lambda: phase_astaroth(devs, 256, 64, False)),
+        # class A: 256^3, 256^3 and 128^3 on the tight-x layout, the six
+        # levels below them ONE call that keeps them in VMEM
+        ("mg_class_a", 1, lambda: phase_mg(devs[:1], "A", False)),
         ("serve", 1, lambda: phase_serve(devs, 64, 16, False)),
     ]
 

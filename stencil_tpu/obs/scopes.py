@@ -73,6 +73,9 @@ KERNELS: Dict[str, str] = {
     "mg_psinv": LAYER_KERNELS,
     "mg_rprj3": LAYER_KERNELS,
     "mg_interp": LAYER_KERNELS,
+    # the V-cycle's coarse half in one call: every level below the coarsest
+    # tight-x level, its 21 operators, their fills and both transfers
+    "mg_coarse": LAYER_KERNELS,
     "fused_jacobi": LAYER_KERNELS,
     "persistent_jacobi": LAYER_KERNELS,
     "self_fill_x": LAYER_HALO,

@@ -25,8 +25,16 @@ block half the one above on every axis, so that a coarse cell and the fine
 cells it reads or feeds lie in the same block (or its halo). A level whose
 rows are whole lane tiles takes the tight-x layout (no x halo: x wraps by a
 lane roll) and, on a TPU, the Pallas kernels of ``pallas_mg`` (the box,
-and the transfers between two such levels); the others keep inline x halos
-and plain XLA.
+and the transfers between two such levels); the others keep inline x
+halos. Where such a kernel level lies above them and the partition is ONE
+block (what the realized specs say: ``pallas_mg.coarse_supported``; fp32),
+those others are RESIDENT: one call, ``mg_coarse``, keeps their u and r in
+VMEM from the restriction out of the tight-x level above to the
+prolongation back onto it, 23 of class C's 34 operator calls with the 22
+fills between them, and writes each to its HBM slot once. Everywhere else
+(a split partition, whose coarse blocks need the wire; off a TPU; a
+hierarchy with no tight-x level, such as class S) they are plain XLA with
+the level's own exchange after each operator.
 
 :func:`make_mg_iter` returns ONE jitted program that updates the
 hierarchy's state where it lies: every array is donated and comes back in
@@ -47,9 +55,10 @@ from ..geometry import Dim3, Radius
 from ..obs import scopes, telemetry
 from ..parallel.exchange import HaloExchange, Method
 from ..parallel.mesh import BLOCK_PSPEC, MESH_AXES
-from .pallas_mg import (LANE, box_supported, make_pallas_mg_box,
-                        make_pallas_mg_interp, make_pallas_mg_rprj3,
-                        transfer_supported)
+from .pallas_mg import (LANE, box_supported, coarse_supported,
+                        coarse_vmem_bytes, make_pallas_mg_box,
+                        make_pallas_mg_coarse, make_pallas_mg_interp,
+                        make_pallas_mg_rprj3, transfer_supported)
 
 A = (-8.0 / 3.0, 0.0, 1.0 / 6.0, 1.0 / 12.0)
 S_SMALL = (-3.0 / 8.0, 1.0 / 32.0, -1.0 / 64.0, 0.0)     # classes S, W, A
@@ -308,10 +317,11 @@ def _transfer(name: str, src: _Level, dst: _Level, add: bool = False,
 
 def cycle_plan(levels: Sequence[_Level], impls: dict, itemsize: int) -> list:
     """Per level, finest first, what one iteration runs there: the grid,
-    a block, the layout, and per operator what implements it, its calls an
+    a block, the layout of its HBM slot, whether it is resident in the
+    coarse call, and per operator what implements it, its calls an
     iteration, the least bytes a call moves (its own arrays' owned cells
     once each, a block) and the fills that follow with the halo bytes one
-    fill writes."""
+    fill writes (a resident level's are wraps in VMEM)."""
     top = len(levels)
     out = []
     for i, lv in enumerate(levels):
@@ -335,6 +345,9 @@ def cycle_plan(levels: Sequence[_Level], impls: dict, itemsize: int) -> list:
         out.append({
             "level": k, "grid": [g.z, g.y, g.x], "block": list(lv.n),
             "layout": "tight_x" if lv.tight else "inline",
+            # u and r live in VMEM for the whole coarse call (``layout``
+            # still says how the HBM slot lies, where they are written once)
+            "resident": impls[(k, "mg_psinv")] == "resident",
             "operators": {
                 name: {"impl": impls[(k, name)], "calls_per_iter": calls[name],
                        "bytes_min": arrays[name] * itemsize,
@@ -355,11 +368,50 @@ def _radii(spec: GridSpec):
     return ((r.z(-1), r.z(1)), (r.y(-1), r.y(1)), (r.x(-1), r.x(1)))
 
 
+def _resident_from(levels: Sequence[_Level], dtype):
+    """The index of the finest level that the coarse call keeps in VMEM
+    (it and every level below), or ``None``: the first level off the
+    tight-x layout, where the level above runs the box kernel and
+    ``coarse_supported`` takes the specs (one block a level, fp32, the
+    arrays fit)."""
+    first = next((i for i, lv in enumerate(levels) if not lv.tight), 0)
+    above = levels[first - 1]           # tight, as every level before it
+    if first and above.pallas and coarse_supported(
+            above.ex.spec, [lv.ex.spec for lv in levels[first:]], dtype):
+        return first
+    return None
+
+
+def _coarse(levels: Sequence[_Level], first: int, smoother, interpret):
+    """The levels from ``first`` down as ONE call, with both transfers to
+    and from the level above: ``fn(r_above, u_above, us, rs) -> (u_above,
+    us, rs)`` over (1, 1, 1, pz, py, px) blocks."""
+    above, below = levels[first - 1], levels[first:]
+    kernel = make_pallas_mg_coarse(
+        above.ex.spec, [lv.ex.spec for lv in below], A, smoother,
+        add=first == 1, interpret=interpret, vma=MESH_AXES)
+
+    def fn(r_above, u_above, us, rs):
+        with scopes.scope(scopes.CARRY):
+            args = (r_above.reshape(above.block), u_above.reshape(above.block),
+                    [a.reshape(lv.block) for a, lv in zip(us, below)],
+                    [a.reshape(lv.block) for a, lv in zip(rs, below)])
+        u_out, us_out, rs_out = kernel(*args)
+        with scopes.scope(scopes.CARRY):
+            return (u_out.reshape(u_above.shape),
+                    [a.reshape(b.shape) for a, b in zip(us_out, us)],
+                    [a.reshape(b.shape) for a, b in zip(rs_out, rs)])
+
+    return fn
+
+
 def _build(exchanges, smoother, dtype, use_pallas, interpret):
     """``(levels, ops, impls)``: the hierarchy's layouts finest first, per
     ``(k, operator)`` the per-block function (k NPB's level; ``resid_v``
     is the finest level's residual against v, which lands in r: three
-    arrays), and per ``(k, kernel name)`` what implements it."""
+    arrays; ``coarse``, on the level above the resident ones, the one call
+    that runs them all), and per ``(k, kernel name)`` what implements it
+    (``resident``: inside that call)."""
     top = len(exchanges)
     if top < 2:
         raise ValueError("a V-cycle takes two levels or more")
@@ -372,22 +424,33 @@ def _build(exchanges, smoother, dtype, use_pallas, interpret):
                 f"level {coarse.number}'s blocks {coarse.n} are not half "
                 f"level {fine.number}'s {fine.n} on the same partition")
     impls, ops = {}, {}
+    first = _resident_from(levels, dtype)
+    if first is not None:
+        k = levels[first - 1].number
+        ops[(k, "coarse")] = _coarse(levels, first, smoother, interpret)
+        impls[(k, "mg_rprj3")] = impls[(k, "mg_interp")] = "resident"
+        for lv in levels[first:]:
+            impls.update({(lv.number, name): "resident"
+                          for name in OPERATORS})
 
     def build(i, name, *args, **kw):
         fn, impl = _box_op(levels[i], name, *args, interpret=interpret, **kw)
         impls[(levels[i].number, name)] = impl
         return fn
 
-    for i, lv in enumerate(levels):
+    for i, lv in enumerate(levels[:first]):         # all of them for None
         k = lv.number
         ops[(k, "psinv")] = build(i, "mg_psinv", smoother, 1.0, has_p=k > 1)
-        if k > 1:
-            ops[(k, "resid")] = build(i, "mg_resid", A, -1.0)
-            ops[(k, "rprj3")], impls[(k, "mg_rprj3")] = _transfer(
-                "mg_rprj3", lv, levels[i + 1], interpret=interpret)
-            ops[(k, "interp")], impls[(k, "mg_interp")] = _transfer(
-                "mg_interp", levels[i + 1], lv, add=k == top,
-                interpret=interpret)
+        if k == 1:
+            continue
+        ops[(k, "resid")] = build(i, "mg_resid", A, -1.0)
+        if i + 1 == first:          # its transfers are the coarse call's
+            continue
+        ops[(k, "rprj3")], impls[(k, "mg_rprj3")] = _transfer(
+            "mg_rprj3", lv, levels[i + 1], interpret=interpret)
+        ops[(k, "interp")], impls[(k, "mg_interp")] = _transfer(
+            "mg_interp", levels[i + 1], lv, add=k == top,
+            interpret=interpret)
     ops[(top, "resid_v")] = build(0, "mg_resid", A, -1.0, separate_dst=True)
     return levels, ops, impls
 
@@ -401,12 +464,19 @@ def make_mg_iter(exchanges: Sequence[HaloExchange], smoother=S_LARGE,
     and ``v`` of the finest level: ``iters`` iterations (``mg3P`` then
     ``resid``) in one program. ``state`` is donated and every array comes
     back where it lay; ``v`` is read only. Every array's halos are valid on
-    entry and on return."""
+    entry and on return, a resident level's too (module docstring): the
+    coarse call writes it back whole, halos wrapped. The counter
+    ``mg.cycle_plan`` says what was built: per level ``layout`` and
+    ``resident``, per operator ``impl`` (pallas / xla / resident), and how
+    far the coarse call reaches (``resident_levels``, ``resident_calls``
+    and ``resident_fills`` of 34, ``resident_vmem_bytes``)."""
     dtype = jnp.dtype(dtype)
     levels, ops, impls = _build(exchanges, smoother, dtype, use_pallas,
                                 interpret)
     top = len(levels)
     mesh = levels[0].ex.mesh
+    first = next((i for i, lv in enumerate(levels)
+                  if impls[(lv.number, "mg_psinv")] == "resident"), None)
 
     def at(i):
         return scopes.level_scope(levels[i].number)
@@ -417,18 +487,30 @@ def make_mg_iter(exchanges: Sequence[HaloExchange], smoother=S_LARGE,
 
     def one(u, r, v):
         u, r = list(u), list(r)
-        bottom = top - 1
+        # the last level with calls of its own: the 2^3 level, or the one
+        # above the resident levels
+        bottom = top - 1 if first is None else first - 1
         for i in range(bottom):                       # down
             with at(i):
                 r[i + 1] = ops[(top - i, "rprj3")](r[i], r[i + 1])
             r[i + 1] = filled(i + 1, r[i + 1])
-        with at(bottom):
-            u[bottom] = ops[(1, "psinv")](r[bottom], None, u[bottom])
-        u[bottom] = filled(bottom, u[bottom])
-        for i in range(bottom - 1, -1, -1):           # up
+        if first is None:
+            with at(bottom):
+                u[bottom] = ops[(1, "psinv")](r[bottom], None, u[bottom])
+            u[bottom] = filled(bottom, u[bottom])
+            start = bottom - 1
+        else:
+            # down from ``bottom`` and back, its own interp included
+            with at(first):
+                u[bottom], u[first:], r[first:] = ops[
+                    (top - bottom, "coarse")](r[bottom], u[bottom], u[first:],
+                                              r[first:])
+            start = bottom
+        for i in range(start, -1, -1):                # up
             k = top - i
-            with at(i):
-                u[i] = ops[(k, "interp")](u[i + 1], u[i])
+            if i < bottom:
+                with at(i):
+                    u[i] = ops[(k, "interp")](u[i + 1], u[i])
             u[i] = filled(i, u[i])
             with at(i):
                 r[i] = (ops[(k, "resid_v")](u[i], v, r[i]) if i == 0
@@ -460,9 +542,19 @@ def make_mg_iter(exchanges: Sequence[HaloExchange], smoother=S_LARGE,
 
     like = [jax.ShapeDtypeStruct(lv.ex.spec.stacked_shape_zyx(), dtype,
                                  sharding=lv.ex.sharding()) for lv in levels]
+    plan = cycle_plan(levels, impls, dtype.itemsize)
     telemetry.get().counter(
         "mg.cycle_plan", value=iters, phase="compute", module=scopes.MG_ITER,
-        levels=cycle_plan(levels, impls, dtype.itemsize))
+        levels=plan,
+        # how far the coarse call reaches, of 34 calls and 34 fills
+        resident_levels=sum(lv["resident"] for lv in plan),
+        resident_calls=sum(
+            op["calls_per_iter"] for lv in plan
+            for op in lv["operators"].values() if op["impl"] == "resident"),
+        resident_fills=sum(lv["fills_per_iter"] for lv in plan
+                           if lv["resident"]),
+        resident_vmem_bytes=0 if first is None else coarse_vmem_bytes(
+            levels[first - 1].ex.spec, [lv.ex.spec for lv in levels[first:]]))
     return scopes.jit_loop(scopes.MG_ITER, program,
                            ({"u": like, "r": like}, like[0]),
                            donate_argnums=(0,))

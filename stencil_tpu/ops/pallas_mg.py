@@ -1,7 +1,10 @@
-"""Pallas TPU kernel for NPB MG's two box operators, ``resid`` and
-``psinv``: one builder, ``out = p +- Box(q)`` with ``Box`` a 27-point
-stencil whose weight depends only on the neighbour's class (centre, faces,
-edges, corners).
+"""Pallas TPU kernels for NPB MG: the box, the transfers between two
+tight-x levels, and (last section) the V-cycle's whole coarse half as one
+call that never leaves VMEM.
+
+The two box operators, ``resid`` and ``psinv``, have one builder: ``out =
+p +- Box(q)`` with ``Box`` a 27-point stencil whose weight depends only on
+the neighbour's class (centre, faces, edges, corners).
 
     resid   r = v - A u      (q = u, p = v or r, sign -)
     psinv   u = u + S r      (q = r, p = u,      sign +)
@@ -30,7 +33,7 @@ exchange that follows every operator fills them.
 
 from __future__ import annotations
 
-from typing import Sequence
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -383,5 +386,419 @@ def make_pallas_mg_interp(coarse: GridSpec, fine: GridSpec, add: bool,
 
     def fn(uc, uf):
         return call(uc, uc, _varying(matrix, vma), uf)
+
+    return fn
+
+
+# ------------------------------------------------------------ the coarse half
+#
+# Below the coarsest tight-x level a block is at most 64 cells wide: one
+# lane tile holds a row with its two inline halo cells, and u and r of every
+# such level together are a few megabytes. One call keeps them all in VMEM
+# for the whole coarse half of the V-cycle: the restriction from the tight-x
+# level above streams in, the 21 operators of the levels below run where the
+# arrays lie, the prolongation onto the level above streams out, and every
+# level is written to its HBM slot once. The periodic wrap after an operator
+# is part of the operator: x by the halo lanes (a roll with the wrapped lane
+# selected; in a transfer two more columns of the matrix), y by two rows, z
+# by two planes, in that order, so edges and corners come along as in
+# ``comm3``.
+
+_COARSE_CHUNKS = 4          # DMAs of the level above's planes, either way
+_COARSE_VMEM_LIMIT = 64 * 1024 * 1024
+_COARSE_ROWS = 32           # rows an operator computes at a time
+
+
+class _Geom(NamedTuple):
+    """A block's layout: padded planes and rows, offsets, owned cells."""
+
+    pz: int
+    py: int
+    zo: int
+    yo: int
+    xo: int
+    nz: int
+    ny: int
+    nx: int
+
+
+def _geom(spec: GridSpec) -> _Geom:
+    p, o, b = spec.padded(), spec.compute_offset(), spec.base
+    return _Geom(p.z, p.y, o.z, o.y, o.x, b.z, b.y, b.x)
+
+
+def _inline_supported(spec: GridSpec) -> bool:
+    """One block, one lane tile a row, radius 1 with room for it on every
+    side: the layout of a level the coarse call keeps in VMEM."""
+    if not spec.aligned or not spec.is_uniform():
+        return False
+    if (spec.dim.x, spec.dim.y, spec.dim.z) != (1, 1, 1):
+        return False
+    g, r = _geom(spec), spec.radius
+    if spec.padded().x != LANE or any(
+            face(side) != 1 for face in (r.x, r.y, r.z) for side in (-1, 1)):
+        return False
+    return (g.xo >= 1 and g.xo + g.nx < LANE and g.yo >= 1
+            and g.yo + g.ny < g.py and g.zo >= 1 and g.zo + g.nz < g.pz)
+
+
+def coarse_vmem_bytes(above: GridSpec, specs: Sequence[GridSpec]) -> int:
+    """VMEM the coarse call holds: u and r of every level of ``specs``, one
+    block of the level above (its r on the way in, its u on the way out),
+    the transfers' matrices and three widened planes."""
+    a = _geom(above)
+    cells = a.pz * a.py * LANE + 3 * _geom(specs[0]).py * LANE
+    cells += sum(2 * g.pz * g.py * LANE for g in map(_geom, specs))
+    return 4 * cells + 2 * 2 * len(specs) * LANE * LANE
+
+
+def coarse_supported(above: GridSpec, specs: Sequence[GridSpec],
+                     dtype) -> bool:
+    """Whether one call can hold the levels ``specs`` (finest first) under
+    the tight-x level ``above``: fp32, ONE block a level (a coarse block of
+    a split partition needs the wire), ``above`` on the box kernel's layout
+    and one lane tile wide, every level below half the one before on the
+    inline layout, all of it inside half the VMEM limit the call asks for."""
+    if not specs or not box_supported(above, dtype):
+        return False
+    if (above.dim.y, above.dim.z) != (1, 1) or above.base.x != LANE:
+        return False
+    if not all(_inline_supported(s) for s in specs):
+        return False
+    sizes = [(s.base.x, s.base.y, s.base.z) for s in (above, *specs)]
+    if any(f != tuple(2 * m for m in c) for f, c in zip(sizes, sizes[1:])):
+        return False
+    return 2 * coarse_vmem_bytes(above, specs) <= _COARSE_VMEM_LIMIT
+
+
+def _restrict_lanes(fine: GridSpec, coarse: GridSpec):
+    """(128, 128) over lanes: the coarse lane of cell c takes 1/4 of fine
+    cells 2c and 2c + 2 and 1/2 of 2c + 1 (the x rule with the operator's
+    overall 1/2 folded in), and the coarse level's two halo lanes take what
+    the cells they mirror take. A tight fine row wraps in the matrix; an
+    inline one is read with its high halo lane."""
+    import numpy as np
+
+    f, c = _geom(fine), _geom(coarse)
+    tight = fine.radius.x(1) == 0
+    m = np.zeros((LANE, LANE), np.float32)
+    for cell in range(-1, c.nx + 1):
+        for d, w in enumerate((0.25, 0.5, 0.25)):
+            src = 2 * (cell % c.nx) + d
+            m[f.xo + (src % f.nx if tight else src), c.xo + cell] += w
+    return m
+
+
+def _prolong_lanes(coarse: GridSpec, fine: GridSpec):
+    """(128, 128) over lanes: fine cell 2c + 1 takes coarse c, fine 2c half
+    of coarse c - 1 (the low halo lane for c = 0) and half of c; an inline
+    fine level's two halo lanes take what the cells they mirror take."""
+    import numpy as np
+
+    c, f = _geom(coarse), _geom(fine)
+    tight = fine.radius.x(1) == 0
+    m = np.zeros((LANE, LANE), np.float32)
+    for cell in range(0, f.nx) if tight else range(-1, f.nx + 1):
+        src, odd = divmod(cell % f.nx, 2)
+        if odd:
+            m[c.xo + src, f.xo + cell] += 1.0
+        else:
+            m[c.xo + src - 1, f.xo + cell] += 0.5
+            m[c.xo + src, f.xo + cell] += 0.5
+    return m
+
+
+def make_pallas_mg_coarse(above: GridSpec, specs: Sequence[GridSpec],
+                          resid_weights: Sequence[float],
+                          psinv_weights: Sequence[float], add: bool,
+                          interpret: bool = False, vma=None):
+    """Build ``fn(r_above, u_above, us, rs) -> (u_above, us, rs)`` over
+    padded fp32 blocks: the V-cycle from the tight-x level ``above`` down
+    through the levels ``specs`` (finest first; ``us`` / ``rs`` their u and
+    r, lists in that order) and back, in the source's order: ``rprj3`` of
+    ``r_above`` and on down, ``psinv`` at the bottom, then ``interp``,
+    ``resid``, ``psinv`` a level up to the finest of ``specs``, then
+    ``interp`` onto ``u_above`` (``add``: added to it, as on the finest
+    level of a hierarchy; else written over it unread). Every level of
+    ``specs`` comes back with its halos valid, having been read from HBM
+    never and written once; ``u_above`` comes back with its owned planes'
+    owned rows written and their other rows as ``make_pallas_mg_interp``
+    leaves them, for the level's own fill. ``specs`` ends at NPB's level 1,
+    which gives the level tags inside the kernel."""
+    if not coarse_supported(above, specs, jnp.float32):
+        raise ValueError("pallas mg coarse unsupported on these specs")
+    import numpy as np
+
+    A, G = _geom(above), [_geom(s) for s in specs]
+    L = len(G)
+    rw, sw = ([float(w) for w in ws] for ws in (resid_weights, psinv_weights))
+    if len(rw) != 4 or len(sw) != 4:
+        raise ValueError("a box takes four class weights")
+    chain = [above, *specs]
+    # 2i: the restriction INTO level i of ``specs``; 2i + 1: the
+    # prolongation FROM it
+    matrices = jnp.asarray(np.stack(
+        [m for fine, coarse in zip(chain, chain[1:])
+         for m in (_restrict_lanes(fine, coarse),
+                   _prolong_lanes(coarse, fine))]), jnp.bfloat16)
+    chunks = _COARSE_CHUNKS if G[0].nz % _COARSE_CHUNKS == 0 else 1
+    per = G[0].nz // chunks             # coarse planes a chunk
+    sem_in, sem_out, sem_add, sem_lv = 0, chunks + 1, 2 * chunks + 1, \
+        3 * chunks + 1
+
+    def rows_of(n):
+        return min(n, _COARSE_ROWS)
+
+    def kernel(*refs):
+        r_hbm, u_hbm = refs[:2]
+        m_ref = refs[2 + 2 * L]
+        outs = refs[3 + 2 * L:4 + 4 * L]
+        u_out, lv_out = outs[0], outs[1:]
+        stage = refs[4 + 4 * L]
+        u_s = refs[5 + 4 * L:5 + 5 * L]
+        r_s = refs[5 + 5 * L:5 + 6 * L]
+        wide, sems = refs[5 + 6 * L:]
+
+        def wrap_x(res, g):
+            """Owned lanes kept, the two halo lanes from the owned lanes
+            they mirror, 0 elsewhere."""
+            lane = jax.lax.broadcasted_iota(jnp.int32, res.shape, 1)
+            low = pltpu.roll(res, LANE - g.nx, 1)
+            high = pltpu.roll(res, g.nx, 1)
+            own = (lane >= g.xo) & (lane < g.xo + g.nx)
+            return jnp.where(own, res, jnp.where(
+                lane == g.xo - 1, low,
+                jnp.where(lane == g.xo + g.nx, high, 0.0)))
+
+        def wrap_y(ref, z, g, row, rows, first, last):
+            """The two halo rows of plane z from the chunk that holds the
+            owned row each mirrors (``first`` / ``last``: a chunk's)."""
+            if row == 0:
+                ref[z, pl.ds(g.yo + g.ny, 1), :] = first
+            if row + rows == g.ny:
+                ref[z, pl.ds(g.yo - 1, 1), :] = last
+
+        def wrap_z(ref, g):
+            ref[g.zo - 1, :, :] = ref[g.zo + g.nz - 1, :, :]
+            ref[g.zo + g.nz, :, :] = ref[g.zo, :, :]
+
+        def both(t):
+            return pltpu.roll(t, 1, 1) + pltpu.roll(t, LANE - 1, 1)
+
+        def box(q, p, out, g, w, minus):
+            """``out = p +- Box(q)`` (``p`` None: the box alone) on one
+            level, ``p`` and ``out`` the same array or not, wrapped."""
+            w0, w1, w2, w3 = w
+            rows = rows_of(g.ny)
+
+            def plane(z, carry):
+                for row in range(0, g.ny, rows):
+                    def at(dz, dy):
+                        return q[z + dz, pl.ds(g.yo + row + dy, rows), :]
+
+                    def ysum(dz):
+                        return at(dz, -1) + at(dz, 1)
+
+                    c = at(0, 0)
+                    u1 = ysum(0) + at(-1, 0) + at(1, 0)
+                    b = w0 * c
+                    if w1:
+                        b = b + w1 * (both(c) + u1)
+                    if w2 or w3:
+                        u2 = ysum(-1) + ysum(1)
+                        if w2:
+                            b = b + w2 * (u2 + both(u1))
+                        if w3:
+                            b = b + w3 * both(u2)
+                    own = pl.ds(g.yo + row, rows)
+                    if p is not None:
+                        b = p[z, own, :] - b if minus else p[z, own, :] + b
+                    res = wrap_x(b, g)
+                    out[z, own, :] = res
+                    wrap_y(out, z, g, row, rows, res[0:1, :],
+                           res[rows - 1:rows, :])
+                return carry
+
+            jax.lax.fori_loop(g.zo, g.zo + g.nz, plane, 0)
+            wrap_z(out, g)
+
+        def restrict(src, sg, dst, dg, mi, first, count):
+            """Coarse planes ``first .. first + count`` of ``dst`` from
+            ``src``: z, then y at a row stride of 2, then x on the MXU."""
+            rows = rows_of(dg.ny)
+
+            def plane(i, carry):
+                c = first + i
+                f = sg.zo + 2 * c
+                for row in range(0, dg.ny, rows):
+                    def tz(d):
+                        at = pl.ds(sg.yo + 2 * row + d, rows, stride=2)
+                        return (0.5 * (src[f, at, :] + src[f + 2, at, :])
+                                + src[f + 1, at, :])
+
+                    res = _dot3(0.5 * (tz(0) + tz(2)) + tz(1), m_ref[mi])
+                    dst[dg.zo + c, pl.ds(dg.yo + row, rows), :] = res
+                    wrap_y(dst, dg.zo + c, dg, row, rows, res[0:1, :],
+                           res[rows - 1:rows, :])
+                return carry
+
+            jax.lax.fori_loop(0, count, plane, 0)
+
+        def widen(slot, src, sg, z, mi):
+            wide[slot, pl.ds(0, sg.py), :] = _dot3(src[z, :, :], m_ref[mi])
+
+        def prolong(src, sg, dst, dg, mi, first, count, onto_above):
+            """Fine planes ``2 first .. 2 (first + count)`` of ``dst`` from
+            ``src``: x on the MXU (a coarse plane once, kept in ``wide[1]``
+            for the next), then z, then y at a row stride of 2."""
+            rows = rows_of(sg.ny)
+            held = pl.ds(0, sg.py)
+
+            def emit(slot, z):
+                for row in range(0, sg.ny, rows):
+                    odd = wide[slot, pl.ds(sg.yo + row, rows), :]
+                    even = 0.5 * (wide[slot, pl.ds(sg.yo + row - 1, rows), :]
+                                  + odd)
+                    to_even = pl.ds(dg.yo + 2 * row, rows, stride=2)
+                    to_odd = pl.ds(dg.yo + 2 * row + 1, rows, stride=2)
+                    if onto_above and add:
+                        even = dst[z, to_even, :] + even
+                        odd = dst[z, to_odd, :] + odd
+                    dst[z, to_even, :] = even
+                    dst[z, to_odd, :] = odd
+                    if not onto_above:
+                        wrap_y(dst, z, dg, 2 * row, 2 * rows, even[0:1, :],
+                               odd[rows - 1:rows, :])
+                if onto_above and not add:
+                    for start, stop in ((0, dg.yo), (dg.yo + dg.ny, dg.py)):
+                        dst[z, pl.ds(start, stop - start), :] = jnp.zeros(
+                            (stop - start, LANE), jnp.float32)
+
+            def plane(i, carry):
+                c = first + i
+                widen(0, src, sg, sg.zo + c, mi)
+                wide[2, held, :] = 0.5 * (wide[0, held, :] + wide[1, held, :])
+                emit(2, dg.zo + 2 * c)
+                emit(0, dg.zo + 2 * c + 1)
+                wide[1, held, :] = wide[0, held, :]
+                return carry
+
+            jax.lax.fori_loop(0, count, plane, 0)
+
+        def level(i):
+            return scopes.level_scope(L - i)
+
+        def planes(ref, g, sem):            # a chunk of the level above
+            at = pl.ds(A.zo + 2 * per * g, 2 * per)
+            return ref.at[at], stage.at[at], sems.at[sem + g]
+
+        def fetch(g):
+            return pltpu.make_async_copy(*planes(r_hbm, g, sem_in))
+
+        def old(g):
+            return pltpu.make_async_copy(*planes(u_hbm, g, sem_add))
+
+        def push(g):
+            src, dst, sem = planes(u_out, g, sem_out)
+            return pltpu.make_async_copy(dst, src, sem)
+
+        def keep(i):                        # a level to its HBM slots
+            return [pltpu.make_async_copy(held[i], lv_out[j * L + i],
+                                          sems.at[sem_lv + j * L + i])
+                    for j, held in enumerate((u_s, r_s))]
+
+        top = pl.ds(A.zo + A.nz, 1)         # the high halo plane
+        last = pltpu.make_async_copy(r_hbm.at[top], stage.at[top],
+                                     sems.at[sem_in + chunks])
+        for g in range(chunks):
+            fetch(g).start()
+        last.start()
+        # rows and lanes that no operator writes hold 0, not what VMEM held
+        for g, ref in zip(G + G, (*u_s, *r_s)):
+            def clear(z, carry, ref=ref, g=g):
+                ref[z, :, :] = jnp.zeros((g.py, LANE), jnp.float32)
+                return carry
+
+            jax.lax.fori_loop(0, g.pz, clear, 0)
+        last.wait()
+        fetch(0).wait()
+
+        with level(0):
+            def entrance(g, carry):
+                @pl.when(g + 1 < chunks)
+                def _():
+                    fetch(g + 1).wait()
+
+                restrict(stage, A, r_s[0], G[0], 0, g * per, per)
+                return carry
+
+            jax.lax.fori_loop(0, chunks, entrance, 0)
+            wrap_z(r_s[0], G[0])
+        if add:
+            for g in range(chunks):
+                old(g).start()
+        for i in range(1, L):
+            with level(i):
+                restrict(r_s[i - 1], G[i - 1], r_s[i], G[i], 2 * i, 0,
+                         G[i].nz)
+                wrap_z(r_s[i], G[i])
+        with level(L - 1):
+            box(r_s[L - 1], None, u_s[L - 1], G[L - 1], sw, False)
+        writes = keep(L - 1)
+        for i in range(L - 2, -1, -1):
+            with level(i):
+                widen(1, u_s[i + 1], G[i + 1], G[i + 1].zo - 1, 2 * i + 3)
+                prolong(u_s[i + 1], G[i + 1], u_s[i], G[i], 2 * i + 3, 0,
+                        G[i + 1].nz, False)
+                wrap_z(u_s[i], G[i])
+                box(u_s[i], r_s[i], r_s[i], G[i], rw, True)
+                box(r_s[i], u_s[i], u_s[i], G[i], sw, False)
+            writes += keep(i)
+        for cp in writes:
+            cp.start()
+        if add:
+            for g in range(chunks):
+                old(g).wait()
+        widen(1, u_s[0], G[0], G[0].zo - 1, 1)
+
+        def leave(g, carry):
+            prolong(u_s[0], G[0], stage, A, 1, g * per, per, True)
+            push(g).start()
+            return carry
+
+        jax.lax.fori_loop(0, chunks, leave, 0)
+        for g in range(chunks):
+            push(g).wait()
+        for cp in writes:
+            cp.wait()
+
+    def shape(g):
+        return jax.ShapeDtypeStruct(
+            (g.pz, g.py, LANE), jnp.float32,
+            vma=frozenset(vma) if vma is not None else None)
+
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    call = scopes.kernel_call(
+        "mg_coarse", kernel,
+        out_shape=[shape(A)] + [shape(g) for g in G + G],
+        in_specs=[anywhere] * (2 + 2 * L)
+        + [pl.BlockSpec(memory_space=pltpu.VMEM)],
+        out_specs=[anywhere] * (1 + 2 * L),
+        scratch_shapes=[pltpu.VMEM((A.pz, A.py, LANE), jnp.float32)]
+        + [pltpu.VMEM((g.pz, g.py, LANE), jnp.float32) for g in G + G]
+        + [pltpu.VMEM((3, G[0].py, LANE), jnp.float32),
+           pltpu.SemaphoreType.DMA((3 * chunks + 1 + 2 * L,))],
+        # r_above is read only; every other array comes back in its slot
+        input_output_aliases={1 + j: j for j in range(1 + 2 * L)},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_COARSE_VMEM_LIMIT),
+        interpret=interpret,
+    )
+
+    def fn(r_above, u_above, us, rs):
+        with scopes.scope(scopes.CARRY):
+            lanes = _varying(matrices, vma)
+        out = call(r_above, u_above, *us, *rs, lanes)
+        return out[0], list(out[1:1 + L]), list(out[1 + L:])
 
     return fn

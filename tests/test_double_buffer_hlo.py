@@ -207,9 +207,10 @@ def test_the_mg_iteration_copies_no_level_and_holds_nothing_of_its_own(
     four-chip mesh: every level's u and r is donated and comes back where
     it lay, the program allocates nothing beside them, and no ``copy`` has
     the shape of a block of a megabyte or more (the tight-x levels and the first below them). The copies
-    that are there are XLA's interleaves of the prolongation below the
-    tight-x levels, the largest the owned cells of 128^3, and relayouts of
-    the 2^3 and 4^3 levels' few rows."""
+    that are there on the split partition are XLA's interleaves of the
+    prolongation below the tight-x levels, the largest the owned cells of
+    128^3, and relayouts of the 2^3 and 4^3 levels' few rows; on one block
+    those levels are the coarse call's and XLA computes nothing."""
     from stencil_tpu.domain.grid import GridSpec
     from stencil_tpu.geometry import Dim3
     from stencil_tpu.obs import scopes, telemetry
@@ -231,6 +232,12 @@ def test_the_mg_iteration_copies_no_level_and_holds_nothing_of_its_own(
     assert pallas == 11, plan
     for kernel in mg.OPERATORS:
         assert re.search(rf"%{kernel}[.\d]* = .*tpu_custom_call", text), kernel
+    # on one block the six levels under 128^3 are ONE more call, compiled
+    # here at its real size with its 18 MB of VMEM; a split partition has
+    # none, and its coarse levels stay XLA
+    coarse = re.findall(r"%mg_coarse[.\d]* = .*tpu_custom_call", text)
+    assert len(coarse) == (1 if part == (1, 1, 1) else 0)
+    assert plan["resident_calls"] == (23 if part == (1, 1, 1) else 0)
     blocks = []
     for ex in exs:
         p = ex.spec.padded()

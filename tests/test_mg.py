@@ -2,7 +2,8 @@
 every level size against the plain numpy reference of the benchmark, on
 one, four and eight blocks, every owned cell; whole iterations at class S
 in float32 and float64; the published class-S norm; the box kernel
-(interpreted); corners; the lowered iteration's scopes."""
+(interpreted); the coarse half as one call, alone and in whole iterations,
+every held cell; corners; the lowered iteration's scopes."""
 
 import functools
 
@@ -18,7 +19,9 @@ from stencil_tpu.domain.grid import GridSpec
 from stencil_tpu.geometry import Dim3, Radius
 from stencil_tpu.obs import scopes, telemetry
 from stencil_tpu.ops import mg as ops
-from stencil_tpu.ops.pallas_mg import (box_supported, make_pallas_mg_box,
+from stencil_tpu.ops.pallas_mg import (box_supported, coarse_supported,
+                                       make_pallas_mg_box,
+                                       make_pallas_mg_coarse,
                                        make_pallas_mg_interp,
                                        make_pallas_mg_rprj3,
                                        transfer_supported)
@@ -371,8 +374,9 @@ def test_the_box_kernel_takes_tight_x_fp32_blocks_only():
 
 def test_a_tight_x_level_runs_the_kernel_and_an_iteration_matches():
     """128 x 128 x 128 is the smallest hierarchy with a tight-x level: the
-    top level takes the (interpreted) kernel, the rest XLA, and one
-    iteration from the seeded state is the reference's on sampled boxes."""
+    top level takes the (interpreted) box kernel, the six below it and both
+    transfers to and from them are the coarse call's, and one iteration
+    from the seeded state is the reference's on sampled boxes."""
     exs = _exchanges((1, 1, 1), n=128)
     step = ops.make_mg_iter(exs, use_pallas=True, interpret=True)
     plan = telemetry.get().records(kind="counter", name="mg.cycle_plan")[-1]
@@ -380,7 +384,7 @@ def test_a_tight_x_level_runs_the_kernel_and_an_iteration_matches():
     assert top["layout"] == "tight_x" and top["grid"] == [128] * 3
     assert top["operators"]["mg_resid"]["impl"] == "pallas"
     assert top["operators"]["mg_psinv"]["impl"] == "pallas"
-    assert top["operators"]["mg_rprj3"]["impl"] == "xla"
+    assert top["operators"]["mg_rprj3"]["impl"] == "resident"
     assert [lv["layout"] for lv in plan["levels"][1:]] == ["inline"] * 6
     state, v = _seeded_state(exs, SEED)
     state = step(state, v)
@@ -398,9 +402,199 @@ def test_a_tight_x_level_runs_the_kernel_and_an_iteration_matches():
 _FILL = ("stencil.halo.", "stencil.kernel.self_fill")
 
 
-def _scoped_equations(jaxpr, outer=""):
-    """The name stack of every equation in program order, nested programs
-    (the jit, the shard_map) walked in place."""
+def _every_held_cell(spec, a):
+    """A block's owned cells with the halo shell round them (what an
+    exchange fills): every cell an operator may read."""
+    o, b, r = spec.compute_offset(), spec.base, spec.radius
+    return np.asarray(a)[0, 0, 0][o.z - 1:o.z + b.z + 1,
+                                  o.y - 1:o.y + b.y + 1,
+                                  o.x - r.x(-1):o.x + b.x + r.x(1)]
+
+
+def _wrapped(spec, level):
+    """A whole periodic level as ``_every_held_cell`` cuts a block."""
+    x = spec.radius.x(1)
+    return np.pad(level, ((1, 1), (1, 1), (x, x)), mode="wrap")
+
+
+@pytest.mark.parametrize("add", [False, True], ids=["over", "added"])
+def test_the_coarse_kernel_interpreted_against_the_reference(add):
+    """The levels 64^3 .. 2^3 under a 128^3 block as ONE call: ``rprj3`` of
+    the level above's r down to 2^3, ``psinv`` there, ``interp``, ``resid``,
+    ``psinv`` a level back up and ``interp`` onto the level above's u
+    (``add``: as on a hierarchy's finest level). Every level comes back
+    with every owned cell the reference's and every halo cell the wrap,
+    from slots that held NaN: nothing of them is read."""
+    specs = [ex.spec for ex in _exchanges((1, 1, 1), n=128)]
+    above, below = specs[0], specs[1:]
+    assert coarse_supported(above, below, jnp.float32)
+    assert not coarse_supported(above, below, jnp.float64)
+    assert not coarse_supported(above, below[1:], jnp.float32)
+    r_top, u_top = (_random(128, salt, "float32") for salt in (1, 2))
+    nan = [np.full(s.block_shape_zyx(), np.nan, np.float32) for s in below]
+    fn = make_pallas_mg_coarse(above, below, ops.A, ops.S_LARGE, add,
+                               interpret=True)
+    u_out, us, rs = fn(_block(above, r_top), np.nan_to_num(_block(above, u_top)),
+                       nan, nan)
+    want_r = reference.down(r_top.astype(np.float64))
+    want_u, want_r = reference.up(want_r, reference.S_LARGE, to=1)
+    for spec, u, r, wu, wr in zip(below, us, rs, want_u, want_r):
+        for got, want in ((u, wu), (r, wr)):
+            np.testing.assert_allclose(
+                _every_held_cell(spec, np.asarray(got)[None, None, None]),
+                _wrapped(spec, want), rtol=0, atol=2e-6,
+                err_msg=f"{spec.base.x}^3")
+            assert np.isfinite(np.asarray(got)).all()
+    prolonged = reference.interp(reference.grow(want_u[0]))
+    np.testing.assert_allclose(_owned_of(above, np.asarray(u_out)),
+                               prolonged + (u_top if add else 0.0),
+                               rtol=0, atol=2e-6)
+
+
+_RESIDENT_ITERS = (1, 3)
+
+
+@functools.lru_cache(maxsize=None)
+def _resident_and_xla_iterations():
+    """The 128^3 one-block hierarchy stepped from the seeded state by the
+    program with the coarse call (the kernels interpreted), by the all-XLA
+    program and by the float64 reference: per iteration count of
+    ``_RESIDENT_ITERS`` the two states as numpy arrays, and the
+    reference's ``(us, rs)``, finest first, every level."""
+    exs = _exchanges((1, 1, 1), n=128)
+    steps = {"resident": ops.make_mg_iter(exs, use_pallas=True,
+                                          interpret=True),
+             "xla": ops.make_mg_iter(exs, use_pallas=False)}
+    states = {how: _seeded_state(exs, SEED) for how in steps}
+    plus, minus = reference.seeded_charges(SEED, 128)
+    v = reference.charges_field(128, plus, minus)
+    u, r = (reference.seeded_level(SEED, q, 128) for q in (0, 1))
+    out = {}
+    for it in range(1, max(_RESIDENT_ITERS) + 1):
+        for how, step in steps.items():
+            state, held_v = states[how]
+            states[how] = (step(state, held_v), held_v)
+        # the reference's iteration with its coarse levels kept
+        rs = reference.down(r)
+        us, rs = reference.up(rs, reference.S_LARGE, to=1)
+        u, r = reference.iteration(u, v, r, reference.S_LARGE)
+        if it in _RESIDENT_ITERS:
+            out[it] = (
+                {how: jax.tree.map(np.asarray, state)
+                 for how, (state, _) in states.items()},
+                ([u] + us, [r] + rs))
+    return out
+
+
+@pytest.mark.parametrize("iters", _RESIDENT_ITERS)
+def test_iterations_with_the_coarse_call_match_the_xla_path_and_the_reference(
+        iters):
+    """After one and after three iterations (a wrong wrap that only the
+    NEXT cycle reads shows in the third): every owned and every halo cell
+    of u and r of every level is the all-XLA program's to float32 rounding
+    and the float64 reference's to the file's tolerance."""
+    exs = _exchanges((1, 1, 1), n=128)
+    states, (want_u, want_r) = _resident_and_xla_iterations()[iters]
+    for q, wants in (("u", want_u), ("r", want_r)):
+        for ex, got, xla, want in zip(exs, states["resident"][q],
+                                      states["xla"][q], wants):
+            m = ex.spec.global_size.x
+            np.testing.assert_allclose(
+                _every_held_cell(ex.spec, got), _every_held_cell(ex.spec, xla),
+                rtol=0, atol=3e-6, err_msg=f"{q} of {m}^3 against XLA")
+            np.testing.assert_allclose(
+                _every_held_cell(ex.spec, got), _wrapped(ex.spec, want),
+                rtol=0, atol=4e-6, err_msg=f"{q} of {m}^3 against float64")
+
+
+def _plan_of(part, n, use_pallas):
+    ops.make_mg_iter(_exchanges(part, n=n), use_pallas=use_pallas)
+    return telemetry.get().records(kind="counter", name="mg.cycle_plan")[-1]
+
+
+def test_the_cycle_plan_says_how_far_the_coarse_call_reaches():
+    """Class C on one block, built and not run: the six levels under 128^3
+    are resident, 23 of the 34 operator calls and 22 of the 34 fills are
+    the coarse call's, and the HBM slots lie as they lay."""
+    plan = _plan_of((1, 1, 1), 512, True)
+    assert (plan["resident_levels"], plan["resident_calls"],
+            plan["resident_fills"]) == (6, 23, 22)
+    # u and r of 64^3 .. 2^3, one 128^3 block, matrices, widened planes
+    assert 16 << 20 < plan["resident_vmem_bytes"] < 20 << 20
+    levels = plan["levels"]
+    assert [lv["resident"] for lv in levels] == [False] * 3 + [True] * 6
+    assert [lv["layout"] for lv in levels] == ["tight_x"] * 3 + ["inline"] * 6
+    for lv in levels[:3]:
+        impls = {name: op["impl"] for name, op in lv["operators"].items()}
+        between = "resident" if lv["level"] == 7 else "pallas"
+        assert impls == {"mg_resid": "pallas", "mg_psinv": "pallas",
+                         "mg_rprj3": between, "mg_interp": between}
+    assert all(op["impl"] == "resident" for lv in levels[3:]
+               for op in lv["operators"].values())
+    assert sum(lv["fills_per_iter"] for lv in levels) == 34
+    assert sum(op["calls_per_iter"] for lv in levels
+               for op in lv["operators"].values()) == 34
+
+
+@pytest.mark.parametrize("case", [
+    ((1, 2, 2), 512, True, 3), ((1, 1, 1), 32, True, 0),
+    ((1, 1, 1), 128, False, 0), ((1, 1, 1), 128, None, 0)], ids=str)
+def test_a_split_partition_class_s_and_the_xla_path_build_todays_program(case):
+    """The coarse call wants ONE block a level (a split partition's coarse
+    blocks need the wire), a Pallas tight-x level above (class S has none;
+    off a TPU the default builds none) and nothing else is asked: every
+    other hierarchy builds the program it built before."""
+    part, n, use_pallas, kernels = case
+    plan = _plan_of(part, n, use_pallas)
+    assert (plan["resident_levels"], plan["resident_calls"],
+            plan["resident_fills"], plan["resident_vmem_bytes"]) == (0,) * 4
+    for i, lv in enumerate(plan["levels"]):
+        assert lv["resident"] is False
+        for name, op in lv["operators"].items():
+            box = name in ("mg_resid", "mg_psinv")
+            pallas = i < kernels if box else i + 1 < kernels
+            assert op["impl"] == ("pallas" if pallas else "xla"), (lv, name)
+
+
+def test_the_one_block_iteration_holds_twelve_operator_calls_and_no_coarse_fill():
+    """The class-C iteration on ONE block with the kernels, traced (nothing
+    compiles, nothing runs): twelve Pallas operator calls, the eleven of
+    the tight-x levels and the coarse call under level 6's tag between the
+    last restriction above it and level 7's fill, and under the tags of
+    levels 1 to 6 no equation of the halo layer and no other operator."""
+    exs = _exchanges((1, 1, 1), n=512)
+    step = ops.make_mg_iter(exs, use_pallas=True)
+    args = scopes._registry[scopes.MG_ITER][-1]["args"]
+    calls, coarse = [], []
+    for name, prim in _scoped_equations(jax.make_jaxpr(step)(*args).jaxpr,
+                                        primitives=True):
+        level, parts = scopes.level_of(name), scopes.scopes_in(name)
+        if level is None:
+            continue
+        kernel = parts[-1][len(scopes.KERNEL_PREFIX):] if parts[-1].startswith(
+            scopes.KERNEL_PREFIX) else None
+        if prim == "pallas_call" and kernel and kernel.startswith("mg_"):
+            calls.append((level, kernel))
+        if level <= 6:
+            assert not parts[-1].startswith(_FILL), name
+            coarse.append((kernel, parts[-1]))
+    want = [(9, "mg_rprj3"), (8, "mg_rprj3"), (6, "mg_coarse")]
+    for k in (7, 8, 9):
+        want += [(k, "mg_interp")] if k > 7 else []
+        want += [(k, "mg_resid"), (k, "mg_psinv")]
+    want += [(9, "mg_resid")]
+    assert len(want) == 12 and calls == want
+    # under the coarse levels' tags: the one call and the reshapes round it
+    assert {c for c in coarse if c[0]} == {("mg_coarse",
+                                           "stencil.kernel.mg_coarse")}
+    assert {c[1] for c in coarse if not c[0]} == {scopes.CARRY}
+
+
+
+def _scoped_equations(jaxpr, outer="", primitives=False):
+    """The name stack of every equation in program order (``primitives``:
+    with its primitive's name), nested programs (the jit, the shard_map)
+    walked in place."""
     from jax._src import core
 
     for eqn in jaxpr.eqns:
@@ -408,9 +602,9 @@ def _scoped_equations(jaxpr, outer=""):
         inner = list(core.jaxprs_in_params(eqn.params))
         if inner and eqn.primitive.name != "pallas_call":
             for sub in inner:
-                yield from _scoped_equations(sub, stack)
+                yield from _scoped_equations(sub, stack, primitives)
         else:
-            yield stack
+            yield (stack, eqn.primitive.name) if primitives else stack
 
 
 def test_the_lowered_iteration_holds_34_operators_each_with_its_fill():
