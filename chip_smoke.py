@@ -899,6 +899,116 @@ def phase_mg(devs, klass: str, rehearsal: bool) -> dict:
     return facts
 
 
+def _lbm_reference(f, omega: float, steps: int, planes: int = 8):
+    """``steps`` steps of the benchmark's float64 reference from the 19
+    whole periodic arrays ``f``: its own ``stream`` and ``collide``, the
+    collision on ``planes`` z planes at a time over a few threads (it is
+    pointwise, numpy drops the lock inside an operation and a slab's
+    temporaries stay near the cache: on whole arrays of 33.5 M cells a step
+    took the four-chip host 2.9 minutes; my chip run, PR 42)."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    import numpy as np
+
+    from benchmark.reference import lbm as reference
+
+    f = [np.asarray(a, np.float64) for a in f]
+    with ThreadPoolExecutor(max_workers=8) as pool:
+        for _ in range(steps):
+            g = reference.stream(f)
+            f = [np.empty_like(a) for a in g]
+
+            def slab(z, g=g, f=f):
+                at = slice(z, z + planes)
+                for new, out in zip(reference.collide(
+                        [a[at] for a in g], omega), f):
+                    out[at] = new
+
+            list(pool.map(slab, range(0, g[0].shape[0], planes)))
+    return f
+
+
+def phase_lbm(devs, size, rehearsal: bool) -> dict:
+    """D3Q19 lattice-Boltzmann through ``lbm.run`` on the application's
+    own four-chip mesh ((1,2,2): x whole, so the blocks lie tight-x and the
+    compiled ``lbm_d3q19`` kernel takes them; y and z are permutes): four
+    steps from the application's own vortex (two dispatches of two), every
+    cell of every population against the float64 reference of the
+    benchmark on the host from the very state the first dispatch was
+    handed. The exchange is the path no cell holds yet: a radius a
+    quantity across chips, 5 populations a direction, so that a chip sends
+    5/19 of what a plan of one radius sends on the split axes (by the
+    application's own ``lbm.step_plan`` and by the permutes the body
+    issued, ``halo.wire_schedule``: one a direction)."""
+    from unittest import mock
+
+    import numpy as np
+
+    from benchmark.reference import lbm as reference
+    from stencil_tpu.apps import lbm
+    from stencil_tpu.geometry import Dim3
+    from stencil_tpu.obs import telemetry
+    from stencil_tpu.parallel.exchange import unshard_blocks
+
+    first, make = {}, lbm.make_lbm_step
+
+    def builder(ex, omega, **kw):
+        step = make(ex, omega, **kw)
+
+        def call(curr, nxt):
+            if not first:
+                first["f"] = [unshard_blocks(a, ex.spec) for a in curr]
+            return step(curr, nxt)
+
+        return call
+
+    steps = 4
+    with PallasRecorder() as rec, \
+            mock.patch.object(lbm, "make_lbm_step", builder):
+        r = lbm.run(x=size.x, y=size.y, z=size.z, steps=steps // 2,
+                    chunk=steps // 2, devices=devs)
+    assert r["steps_run"] == steps, r["steps_run"]
+    dd, hs = r["domain"], r["handles"]
+    assert dd.spec.dim == Dim3(1, 2, 2), dd.spec.dim
+    for h in hs[:2]:
+        require_four_shards(dd.get_curr(h), devs, f"lbm {h.name}")
+    plan = telemetry.get().records(kind="counter", name="lbm.step_plan")[-1]
+    wires = telemetry.get().records(kind="counter",
+                                    name="halo.wire_schedule")[-1]
+    assert plan["layout"] == "tight_x", plan
+    assert sorted(plan["carried"]) == ["y+", "y-", "z+", "z-"], plan
+    assert all(len(v) == 5 for v in plan["carried"].values()), plan
+    # across chips every slab is on the wire, and 10 of 38 slabs are sent
+    assert plan["halo_bytes_wire"] == plan["halo_bytes_sent"] > 0, plan
+    assert (19 * plan["halo_bytes_wire"]
+            == 5 * plan["halo_bytes_wire_if_all"]), plan
+    assert [(ph["axis"], ph["permutes"]) for ph in wires["phases"]] == [
+        ("y", 2), ("z", 2)], wires
+    facts = {"step_ms": round(1e3 * r["step_trimean_s"], 3),
+             "mlups": round(r["mlups"], 1), "kernel": plan["kernel"],
+             "halo_bytes_sent": plan["halo_bytes_sent"],
+             "halo_bytes_if_all": plan["halo_bytes_if_all"]}
+    if not rehearsal:
+        assert plan["kernel"] == "pallas", plan
+        require_compiled_kernels(rec, ["make_pallas_lbm_step"], rehearsal)
+        facts["bytes_after_run"] = require_balanced(devs, "lbm 4 chips")
+    want = _lbm_reference(first["f"], reference.omega_of(1.0), steps)
+    worst = 0.0
+    for h, w in zip(hs, want):
+        got = unshard_blocks(dd.get_curr(h), dd.spec)
+        assert np.isfinite(got).all() and got.min() > 0, h.name
+        worst = max(worst, float(np.abs(got - w).max()))
+    say(f"lbm {size}: max |{len(devs)} chips - reference| = {worst:.3e} "
+        f"over 19 populations after {steps} steps")
+    # populations of 0.03 to 0.33, four float32 steps
+    assert worst <= 5e-7, worst
+    facts["max_abs_diff"] = worst
+    mass = [r["invariants_before"][0], r["invariants_after"][0]]
+    assert abs(mass[1] - mass[0]) <= 2e-5 * mass[0], mass
+    facts["mass"] = mass
+    return facts
+
+
 # ------------------------------------------------------------ the run
 
 
@@ -919,6 +1029,7 @@ def build_phases(devs, rehearsal: bool) -> list:
             ("four_chip_iso3dfd", 4, lambda: phase_four_iso3dfd(
                 four, (48, 48, 48), "iso3dfd1024x4.steady", True)),
             ("mg_class_b_x4", 4, lambda: phase_mg(four, "S", True)),
+            ("lbm_x4", 4, lambda: phase_lbm(four, Dim3(128, 16, 16), True)),
             ("four_chip_exchange", 4, lambda: phase_exchange(
                 four, Dim3(16, 32, 32), p122, True)),
             ("four_chip_exchange_x", 4, lambda: phase_exchange(
@@ -941,6 +1052,9 @@ def build_phases(devs, rehearsal: bool) -> list:
         # class B: 256^3, blocks of 128 x 128 x 256 and of 64 x 64 x 128 on
         # the tight-x layout, the six levels below them inline
         ("mg_class_b_x4", 4, lambda: phase_mg(four, "B", False)),
+        # blocks of 256 x 128 x 256, tight-x: the kernel of the cell
+        # lbm384.steady behind an exchange that crosses chips
+        ("lbm_x4", 4, lambda: phase_lbm(four, Dim3(256, 256, 512), False)),
         ("four_chip_exchange", 4, lambda: phase_exchange(
             four, Dim3(512, 1024, 1024), p122, False)),
         # exchange_weak's own pick on four chips: x is split

@@ -69,6 +69,9 @@ class DistributedDomain:
         # holds a second (``next``) buffer
         self._exchanged: List[bool] = []
         self._buffered: List[bool] = []
+        # per quantity: the halos exchange() fills for it (None: the
+        # domain's radius)
+        self._radii: List[Optional[Radius]] = []
         # per axis (x, y, z): periodic unless the application says fixed;
         # and whether the stencil is a star (faces-only slabs)
         self._periodic = (True, True, True)
@@ -132,7 +135,8 @@ class DistributedDomain:
         self._faces_only = bool(faces_only)
 
     def add_data(self, name: str = "", dtype="float32",
-                 exchanged: bool = True, buffered: bool = True) -> DataHandle:
+                 exchanged: bool = True, buffered: bool = True,
+                 radius: Optional[Radius] = None) -> DataHandle:
         """Register a quantity (reference: stencil.hpp:128).
 
         ``exchanged=False``: a quantity the stencil reads at the centre
@@ -140,9 +144,20 @@ class DistributedDomain:
         alone and the byte counts leave it out. ``buffered=False``: it
         holds no second buffer (:meth:`get_next` has none and
         :meth:`swap` passes it by): a read-only field, or one whose two
-        time levels are quantities of their own."""
+        time levels are quantities of their own. ``radius``: which of the
+        26 directions' halos :meth:`exchange` FILLS for this quantity (a
+        lattice-Boltzmann population is read at one offset, so it wants
+        the halo of one side an axis and one edge): each direction 0 or
+        what :meth:`set_radius` gave the domain, which stays what every
+        quantity ALLOCATES. ``None``: the domain's radius; a domain whose
+        quantities all pass none builds the plan it always did. Needs the
+        default ``Method.AXIS_COMPOSED``, one block a device and periodic
+        axes."""
         if self._realized:
             raise RuntimeError("add_data after realize()")
+        if radius is not None and not exchanged:
+            raise ValueError("a radius for a quantity that is not exchanged")
+        self._radii.append(radius)
         idx = len(self._names)
         self._names.append(name or f"data{idx}")
         self._dtypes.append(str(jnp.dtype(dtype)))
@@ -377,6 +392,7 @@ class DistributedDomain:
                 persistent=self._persistent,
                 periodic=self._periodic,
                 faces_only=self._faces_only,
+                quantity_radius=self._quantity_radius(),
             )
             sharding = self._exchange.sharding()
             zeros = {dt: sharded_full(shape, 0, dt, sharding)
@@ -451,6 +467,17 @@ class DistributedDomain:
     def _exchanged_itemsizes(self) -> List[int]:
         return [jnp.dtype(dt).itemsize
                 for dt, ex in zip(self._dtypes, self._exchanged) if ex]
+
+    def _exchanged_keys(self) -> List[int]:
+        return [i for i, ex in enumerate(self._exchanged) if ex]
+
+    def _quantity_radius(self) -> Optional[Dict[int, Radius]]:
+        """``{index: Radius}`` of every exchanged quantity where one gave
+        its own (the others take the domain's), else ``None``."""
+        if all(r is None for r in self._radii):
+            return None
+        return {i: self._radii[i] or self.radius
+                for i in self._exchanged_keys()}
 
     def exchange(self) -> None:
         """Fill every halo of every exchanged quantity from the neighbors,
@@ -530,10 +557,12 @@ class DistributedDomain:
         """Logical halo bytes per exchange attributed to ``method``."""
         if method != self._method:
             return 0
-        return self._exchange.bytes_logical(self._exchanged_itemsizes())
+        return self._exchange.bytes_logical(self._exchanged_itemsizes(),
+                                            keys=self._exchanged_keys())
 
     def exchange_bytes_moved(self) -> int:
-        return self._exchange.bytes_moved(self._exchanged_itemsizes())
+        return self._exchange.bytes_moved(self._exchanged_itemsizes(),
+                                          keys=self._exchanged_keys())
 
     def plan_meta(self) -> dict:
         """The EFFECTIVE exchange plan of this realized domain — what the

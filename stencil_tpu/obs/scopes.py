@@ -76,6 +76,8 @@ KERNELS: Dict[str, str] = {
     # the V-cycle's coarse half in one call: every level below the coarsest
     # tight-x level, its 21 operators, their fills and both transfers
     "mg_coarse": LAYER_KERNELS,
+    # D3Q19's stream-collide pass, Pallas or plain XLA (:func:`kernel_scope`)
+    "lbm_d3q19": LAYER_KERNELS,
     "fused_jacobi": LAYER_KERNELS,
     "persistent_jacobi": LAYER_KERNELS,
     "self_fill_x": LAYER_HALO,
@@ -95,8 +97,9 @@ ASTAROTH_ITER = "stencil_astaroth_iter"
 EXCHANGE_LOOP = "stencil_exchange_loop"
 ISO3DFD_LOOP = "stencil_iso3dfd_loop"
 MG_ITER = "stencil_mg_iter"
+LBM_STEP = "stencil_lbm_step"
 MODULES = (JACOBI_LOOP, JACOBI_STEP, ASTAROTH_ITER, EXCHANGE_LOOP,
-           ISO3DFD_LOOP, MG_ITER)
+           ISO3DFD_LOOP, MG_ITER, LBM_STEP)
 
 
 def layer_of(scope: Optional[str]) -> Optional[str]:

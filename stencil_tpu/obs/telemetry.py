@@ -240,6 +240,20 @@ NAME_FIELDS = {
     # and the halo bytes the fill after it writes (benchmark/apps/mg.py
     # holds the configuration to it)
     "mg.cycle_plan": (("module", str), ("levels", list)),
+    # what ops/lbm.make_lbm_step built, once per build (value: steps a
+    # dispatch): blocks of the mesh, the ``layout`` (tight_x / inline),
+    # what implements the stream-collide pass (``kernel``: pallas / xla),
+    # a block's owned cells, per axis and direction the populations the
+    # exchange fills (``carried``: {"y-": [...], "y+": [...], ...}; 5 a
+    # direction under a radius a quantity, 19 under one radius), the bytes
+    # a chip's slabs carry a step by the plan (``halo_bytes_sent``; the
+    # part that crosses chips ``halo_bytes_wire``) and what a plan of one
+    # radius would (``halo_bytes_if_all``, ``halo_bytes_wire_if_all``)
+    # (benchmark/apps/lbm.py holds the configuration to it)
+    "lbm.step_plan": (("module", str), ("blocks", int), ("layout", str),
+                      ("kernel", str), ("chunk", int), ("block_cells", int),
+                      ("carried", dict), ("halo_bytes_sent", int),
+                      ("halo_bytes_if_all", int)),
     # what a composed per-block exchange body issued, once per build
     # (value: ppermutes in all): per axis phase its ``axis``, the
     # ``permutes`` issued for it and whether its two directions went as
@@ -342,6 +356,8 @@ KNOWN_NAMES = frozenset(NAME_FIELDS) | frozenset({
     "iso3dfd.iter", "iso3dfd.iter_trimean_s", "iso3dfd.mcells_per_s",
     "mg.realize", "mg.init", "mg.warmup", "mg.steps", "mg.iter",
     "mg.iter_trimean_s", "mg.mcells_per_s", "mg.rnm2",
+    "lbm.realize", "lbm.init", "lbm.warmup", "lbm.steps", "lbm.step",
+    "lbm.step_trimean_s", "lbm.mlups",
     "exchange.realize", "exchange.steps",
     "jacobi.realize", "jacobi.steps",
     "halo.self_fill.bytes_dma", "halo.split_x.bytes_dma",

@@ -49,6 +49,15 @@ region of its orthogonal leading axes (whole rows stay whole), so no edge
 or corner halo is filled and fewer bytes move. Which QUANTITIES move is the
 caller's: every entry point takes the state it is to exchange, and
 ``DistributedDomain`` hands over those declared ``exchanged``.
+``quantity_radius`` (``{state key: Radius}``, every key of the state) is a
+radius A QUANTITY: which of the 26 directions' halos are FILLED for it,
+among those the spec allocates. A direction of an axis phase then carries
+only the quantities that want that side (a lattice-Boltzmann population,
+read at one offset, wants one side an axis), whether the phase is a
+permute, an XLA slab copy or a self-fill kernel
+(``make_self_fill(sides=)``); a z slab keeps the y halo rows only where a
+carried quantity's edge gate asks for them. ``None``: the plan and the
+program there always were.
 
 The wire's schedule on the slab path (``_slab_phases``): both directions
 of an axis phase are packed from the blocks as the phase finds them, both
@@ -165,7 +174,8 @@ class HaloExchange:
     def __init__(self, spec: GridSpec, mesh: Mesh, method: Method = Method.AXIS_COMPOSED,
                  batch_quantities: bool = True, wire_dtype=None,
                  fused: bool = False, persistent: bool = False,
-                 periodic=(True, True, True), faces_only: bool = False):
+                 periodic=(True, True, True), faces_only: bool = False,
+                 quantity_radius=None):
         md = mesh_dim(mesh)
         # oversubscription (reference: dd.set_gpus({0,0}), stencil.hpp:154,
         # test_exchange.cu:52): more partition blocks than devices — the
@@ -272,7 +282,10 @@ class HaloExchange:
         # axis -> (permutes, stages) while a composed body is being built
         # (:meth:`_permute_wire`), for the counter ``halo.wire_schedule``
         self._wired = None
-        if not all(self.periodic) or self.faces_only:
+        # a radius a quantity (module docstring): ``{state key: Radius}``
+        self.quantity_radius = dict(quantity_radius) if quantity_radius else None
+        if (not all(self.periodic) or self.faces_only
+                or self.quantity_radius is not None):
             self.plan
 
     @property
@@ -294,6 +307,7 @@ class HaloExchange:
             wire_dtype=self.wire_dtype, fused=self.fused,
             persistent=self.persistent, periodic=self.periodic,
             faces_only=self.faces_only,
+            quantity_radius=self.quantity_radius,
         )
 
     # -- public API ----------------------------------------------------------
@@ -327,6 +341,11 @@ class HaloExchange:
             if axes is not None:
                 raise ValueError("axis subsetting requires AXIS_COMPOSED")
             return self._direct26_blocks(block)
+        if self.quantity_radius is not None:
+            raise RuntimeError(
+                "this exchange has a radius a quantity: hand "
+                "exchange_blocks the quantity dict, so that each block is "
+                "known by its key")
         return self._composed_blocks(block, axes)
 
     def x_side_buffers(self, block, r: int):
@@ -385,6 +404,12 @@ class HaloExchange:
             )
         if not isinstance(state, dict):
             return jax.tree.map(self.exchange_block, state)
+        if self.quantity_radius is not None and (
+                set(state) != set(self.quantity_radius)):
+            raise ValueError(
+                f"the state's keys {sorted(state)} are not the quantities "
+                f"this exchange has a radius for "
+                f"({sorted(self.quantity_radius)})")
         from ..ops.halo_fill import dtype_groups
 
         groups = dtype_groups(state)
@@ -407,7 +432,9 @@ class HaloExchange:
         packed-carrier phases (one ppermute pair per phase per group)
         elsewhere, per-quantity carriers when ``batch_quantities`` is off.
         ``axes`` restricts the phases (:meth:`exchange_block`). Records
-        ``halo.wire_schedule``, once a build."""
+        ``halo.wire_schedule``, once a build. Under a radius a quantity
+        each phase moves what its two directions carry
+        (:meth:`_carried_phase`)."""
         fills = self._self_fills
         out = dict(state)
         waves = self._waves([ph for ph in self.plan.axis_phases if ph.active
@@ -416,6 +443,10 @@ class HaloExchange:
         try:
             for wave in waves:
                 for dt, keys in groups:
+                    if self.quantity_radius is not None:
+                        (ph,) = wave    # nothing of such a plan is cut to
+                        self._carried_phase(out, dt, keys, ph)  # leave together
+                        continue
                     fill = (len(wave) == 1 and wave[0].blocks == 1
                             and wave[0].axis in fills and dt == jnp.float32)
                     for batch in ([keys] if fill or self.batch_quantities
@@ -467,12 +498,13 @@ class HaloExchange:
                 waves.append([ph])
         return waves
 
-    def _self_fill_group(self, name: str, blocks):
+    def _self_fill_group(self, name: str, blocks, sides=(True, True)):
         """One fp32 group on a self-wrap axis: the Pallas fill writes the
         halos in place, touching only the edge tiles. The x and y kernels'
         scratch scales with the quantity count, so a group larger than
         their VMEM budget carries goes in chunks; the z fill carries every
-        quantity in one kernel."""
+        quantity in one kernel. ``sides``: the (low, high) halos this
+        group wants filled."""
         from ..ops.halo_fill import max_fill_group
 
         fshape = self._fill_shape()
@@ -481,27 +513,28 @@ class HaloExchange:
         out = []
         for i in range(0, len(blocks), step):
             chunk = blocks[i : i + step]
-            fill = self._multi_fill(name, len(chunk))
+            fill = self._multi_fill(name, len(chunk), sides)
             with scopes.scope(scopes.HALO_SELF_FILL):
                 res = fill(*[b.reshape(fshape) for b in chunk])
                 res = (res,) if len(chunk) == 1 else res
                 out += [v.reshape(b.shape) for v, b in zip(res, chunk)]
         return out
 
-    def _multi_fill(self, axis: str, nq: int):
+    def _multi_fill(self, axis: str, nq: int, sides=(True, True)):
         cache = self.__dict__.setdefault("_multi_fills", {})
-        if (axis, nq) not in cache:
-            if nq == 1:
-                cache[(axis, nq)] = self._self_fills[axis]
+        key = (axis, nq) if all(sides) else (axis, nq, tuple(sides))
+        if key not in cache:
+            if nq == 1 and all(sides):
+                cache[key] = self._self_fills[axis]
             else:
                 from ..ops.halo_fill import make_self_fill
                 from .mesh import MESH_AXES
 
-                cache[(axis, nq)] = make_self_fill(
+                one_sided = {} if all(sides) else {"sides": tuple(sides)}
+                cache[key] = make_self_fill(
                     self.spec, axis, vma=MESH_AXES, nq=nq,
-                    z_stack=self.resident.z,
-                )
-        return cache[(axis, nq)]
+                    z_stack=self.resident.z, **one_sided)
+        return cache[key]
 
     @cached_property
     def _remote(self):
@@ -640,12 +673,32 @@ class HaloExchange:
             txt = self._compiled.lower(state).compile().as_text()
             return collective_census(txt)
 
-    def bytes_logical(self, itemsizes: Sequence[int]) -> int:
+    def _by_key(self, itemsizes, keys) -> Dict:
+        if keys is None or len(keys) != len(itemsizes):
+            raise ValueError(
+                "an exchange with a radius a quantity counts bytes by "
+                "quantity: pass keys= aligned with itemsizes")
+        return dict(zip(keys, itemsizes))
+
+    def bytes_logical(self, itemsizes: Sequence[int], keys=None) -> int:
         """Total halo bytes delivered per exchange (reference-parity count),
         for the quantities whose ``itemsizes`` are given (the exchanged
         ones). A fixed axis delivers nothing across the domain's edge and a
-        faces-only exchange nothing to edges and corners."""
+        faces-only exchange nothing to edges and corners; under a radius a
+        quantity (``keys``: the state keys the itemsizes belong to) each
+        quantity counts the directions its own radius has."""
         spec = self.spec
+        if self.quantity_radius is not None:
+            total = 0
+            for key, size in self._by_key(itemsizes, keys).items():
+                r = self.quantity_radius[key]
+                total += size * sum(
+                    halo_extent(d, spec.block_size((ix, iy, iz)),
+                                spec.radius).flatten()
+                    for d in DIRECTIONS_26 if r.dir(d)
+                    for iz in range(spec.dim.z) for iy in range(spec.dim.y)
+                    for ix in range(spec.dim.x))
+            return total
         per_item = 0
         for d in DIRECTIONS_26:
             if spec.radius.dir(d) == 0 or (
@@ -662,7 +715,7 @@ class HaloExchange:
                                 spec.radius).flatten()
         return per_item * sum(itemsizes)
 
-    def bytes_moved(self, itemsizes: Sequence[int]) -> int:
+    def bytes_moved(self, itemsizes: Sequence[int], keys=None) -> int:
         """Bytes relocated by the exchange implementation: composed slabs
         span full padded extents, so this is >= bytes_logical. On a
         self-wrap (single-block) axis no collective carries data — the same
@@ -694,6 +747,9 @@ class HaloExchange:
                     ext *= rm if dc == 1 else rp if dc == -1 else base
                 total += ext
             return total * sum(itemsizes) * self.spec.num_blocks()
+        if self.quantity_radius is not None:
+            sizes = self._by_key(itemsizes, keys)
+            return self.plan.wire_bytes(sizes) + self.plan.local_bytes(sizes)
         # the plan's own slab extents (x, y and z phase: both radii times
         # the padded orthogonal extent, every block; no wrap slab on a
         # fixed axis, orthogonal axes cut to the compute region for a star)
@@ -882,6 +938,54 @@ class HaloExchange:
                 blocks = [_update_in_dim(b, s, dst, ph.adim, ph.trim)
                           for b, s in zip(blocks, slabs)]
         return blocks
+
+    def _carried_phase(self, out, dtype, keys, phase):
+        """One axis phase of a plan with a radius a quantity, for the
+        same-dtype group ``keys`` of the state ``out`` (updated in place),
+        one block a device: each direction moves the quantities its
+        :class:`~stencil_tpu.plan.ir.SideIR` names and nothing else. On a
+        self-wrap axis with a fill kernel the group goes in up to three
+        calls, by which of the two halos a quantity wants (the kernels
+        move whole planes and row windows, so they cut no rows); elsewhere
+        both directions' carriers are packed, both permutes follow, both
+        slabs are placed, as :meth:`_slab_phases` does, a z slab's rows
+        cut where the plan says so."""
+        from ..ops.halo_fill import pack_slabs, unpack_slabs
+
+        low, high = phase.sides
+        if (phase.blocks == 1 and phase.axis in self._self_fills
+                and dtype == jnp.float32):
+            for want in ((True, True), (True, False), (False, True)):
+                batch = [k for k in keys
+                         if (k in low.keys, k in high.keys) == want]
+                if batch:
+                    out.update(zip(batch, self._self_fill_group(
+                        phase.axis, [out[k] for k in batch], sides=want)))
+            return
+        flights = []
+        # :meth:`_sides` leaves a direction of no width out: so here
+        carried = [side for side, width in zip(
+            phase.sides, (phase.rm, phase.rp)) if width > 0]
+        with scopes.scope(scopes.HALO_PACK):
+            for (src, dst, width, pairs, _edge), side in zip(
+                    self._sides(phase), carried):
+                batch = [k for k in keys if k in side.keys]
+                for group in ([batch] if self.batch_quantities
+                              else [[k] for k in batch]):
+                    if group:
+                        flights.append((group, pack_slabs([
+                            _slice_in_dim(out[k], src, width, phase.adim,
+                                          side.trim) for k in group]),
+                            pairs, dst, side.trim))
+        flights = [
+            (group, self._permute_wire(carrier, phase.axis, pairs)
+             if phase.ring > 1 else carrier, dst, trim)
+            for group, carrier, pairs, dst, trim in flights]
+        with scopes.scope(scopes.HALO_UNPACK):
+            for group, carrier, dst, trim in flights:
+                for k, slab in zip(group, unpack_slabs(carrier, len(group))):
+                    out[k] = _update_in_dim(out[k], slab, dst, phase.adim,
+                                            trim)
 
     def _split_x(self, phase, dtype) -> bool:
         """Whether this phase packs and unpacks with the edge-tile kernels

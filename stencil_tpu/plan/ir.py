@@ -85,6 +85,22 @@ AXIS_ORDER = (("x", 5, 2), ("y", 4, 1), ("z", 3, 0))
 
 
 @dataclass(frozen=True)
+class SideIR:
+    """One direction of an axis phase of a plan with a radius a quantity
+    (``build_plan(quantity_radius=...)``): the halo on ONE side of the axis
+    and the quantities whose radius wants it filled."""
+
+    # the quantities (state keys) carried, in the order they were declared
+    keys: Tuple
+    # ``(data dim, start, width)`` of each EARLIER axis whose halo the slab
+    # leaves out (as ``AxisPhaseIR.trim``): no carried quantity has an edge
+    # or corner direction on this side that reaches into it. The lane axis
+    # rides whole, so only a z slab's rows are ever cut.
+    trim: Tuple[Tuple[int, int, int], ...]
+    cells: int              # cells of ONE quantity's slabs, every block
+
+
+@dataclass(frozen=True)
 class AxisPhaseIR:
     """One composed axis phase (or one AUTO_SPMD roll phase).
 
@@ -120,6 +136,10 @@ class AxisPhaseIR:
     # Empty: the slab spans the full padded extent and composes edges and
     # corners across phases.
     trim: Tuple[Tuple[int, int, int], ...] = ()
+    # ``(low, high)`` under a radius a quantity: what each direction
+    # carries. ``None``: both carry every exchanged quantity, and
+    # ``wire_cells`` / ``local_cells`` count one quantity's two slabs.
+    sides: Optional[Tuple[SideIR, SideIR]] = None
 
     @property
     def blocks(self) -> int:
@@ -132,6 +152,12 @@ class AxisPhaseIR:
     @property
     def active(self) -> bool:
         return self.rm > 0 or self.rp > 0
+
+    def carried(self):
+        """``[(SideIR, width)]`` of the directions that move anything under
+        a radius a quantity, low first."""
+        return [(side, r) for side, r in zip(self.sides, (self.rm, self.rp))
+                if r > 0 and side.keys]
 
     @property
     def merged(self) -> bool:
@@ -151,6 +177,8 @@ class AxisPhaseIR:
         """ppermutes one lowering of this phase emits (per carrier)."""
         if self.ring <= 1 or not self.active:
             return 0
+        if self.sides is not None:
+            return len(self.carried())
         if self.merged:
             return 1
         return (1 if self.rm > 0 else 0) + (1 if self.rp > 0 else 0)
@@ -319,10 +347,27 @@ class ExchangePlan:
     # alone; and whether slabs carry faces only (a star stencil)
     periodic: Tuple[bool, bool, bool] = (True, True, True)
     faces_only: bool = False
+    # a radius a quantity: ``((key, radius_dirs(radius)), ...)`` of the
+    # quantities that gave one; the axis phases then say what each
+    # direction carries (``AxisPhaseIR.sides``). ``None``: the spec's
+    # radius for every quantity, the plan of every domain that passes none.
+    quantity_radius: Optional[Tuple] = None
 
     @property
     def batch_quantities(self) -> bool:
         return self.pack_groups == "dtype"
+
+    def _carried_bytes(self, itemsizes, wire: bool) -> int:
+        """Bytes of a plan with a radius a quantity: a direction's cells
+        times the itemsizes of what it carries. ``itemsizes`` maps the
+        state's keys to theirs."""
+        if not hasattr(itemsizes, "keys"):
+            raise ValueError(
+                "a plan with a radius a quantity counts bytes by key: pass a "
+                "mapping of each state key to its itemsize")
+        return sum(side.cells * sum(itemsizes[k] for k in side.keys)
+                   for ph in self.axis_phases if (ph.ring > 1) == wire
+                   for side, _r in ph.carried())
 
     @property
     def phases(self) -> Tuple:
@@ -398,6 +443,8 @@ class ExchangePlan:
         lowering (halo_fill.wire_narrow_dtype) never compresses integer
         carriers, so their wire bytes must stay native; omitted, every
         quantity is assumed floating (this framework's default)."""
+        if self.quantity_radius is not None:
+            return self._carried_bytes(itemsizes, wire=True)
         w = wire_itemsize(self.wire_dtype) if not self.synthesized else None
         if w is None:
             per_cell = sum(itemsizes)
@@ -411,6 +458,8 @@ class ExchangePlan:
     def local_bytes(self, itemsizes: Sequence[int]) -> int:
         """Estimated bytes moved without touching the interconnect
         (self-wrap fills, resident-neighbor shifts)."""
+        if self.quantity_radius is not None:
+            return self._carried_bytes(itemsizes, wire=False)
         per_cell = sum(itemsizes)
         return sum(p.local_cells for p in self.phases) * per_cell
 
@@ -448,6 +497,12 @@ class ExchangePlan:
                     f"rm={p.rm} rp={p.rp} permutes={p.collectives()} "
                     f"wire_cells={p.wire_cells} local_cells={p.local_cells}"
                 )
+                for sign, side in zip("-+", (p.active and p.sides) or ()):
+                    lines.append(
+                        f"    {sign}{p.axis} halo: {len(side.keys)} "
+                        f"quantities {list(side.keys)} cells={side.cells}"
+                        + (" rows cut to the compute region"
+                           if side.trim else ""))
             else:
                 lines.append(
                     f"  dir {p.direction}: shape(zyx)={p.shape} "
@@ -521,9 +576,43 @@ def _perm26(dim: Dim3, d: Dim3) -> Tuple[Tuple[int, int], ...]:
     return tuple(pairs)
 
 
+def _phase_sides(spec, name: str, radii, orth_cells: int
+                 ) -> Tuple[SideIR, SideIR]:
+    """(low, high) of one axis phase under a radius a quantity (``radii``:
+    ``{key: Radius}`` of EVERY exchanged quantity). A quantity is carried
+    toward the side where any of its directions points (a face, or an edge
+    or corner that the phases compose through this one); a slab keeps an
+    earlier axis's halo rows where a carried quantity's edge or corner
+    gate on this side reaches into them, and is cut to the compute region
+    there otherwise (the lane axis rides whole, as in a star's slabs)."""
+    comp = {"x": 0, "y": 1, "z": 2}
+    a = comp[name]
+    off, base, p = spec.compute_offset(), spec.base, spec.padded()
+    # the earlier, non-lane axes of this phase: (component, data dim,
+    # compute start, compute width, padded width)
+    earlier = [(1, 4, off.y, base.y, p.y)] if name == "z" else []
+    _sizes, rm, rp, _off = spec_axis(spec, name)
+    sides = []
+    for sign, width in ((-1, rm), (1, rp)):
+        def points(r, also=None):
+            return any(v for d, v in r._r.items() if d[a] == sign
+                       and d != (0, 0, 0)
+                       and (also is None or d[also] != 0))
+
+        keys = tuple(k for k, r in radii.items() if points(r))
+        trim, cells = [], width * orth_cells
+        for e, dim, lo, n, padded in earlier:
+            if keys and not any(points(radii[k], e) for k in keys):
+                trim.append((dim, lo, n))
+                cells = cells // padded * n
+        sides.append(SideIR(keys=keys, trim=tuple(trim), cells=cells))
+    return tuple(sides)
+
+
 def _axis_phases(spec, mesh_dim: Dim3, resident: Dim3,
                  synthesized: bool, periodic=(True, True, True),
-                 faces_only: bool = False) -> Tuple[AxisPhaseIR, ...]:
+                 faces_only: bool = False,
+                 radii=None) -> Tuple[AxisPhaseIR, ...]:
     p = spec.padded()
     orth = {  # padded cells orthogonal to each axis, per block
         "x": p.y * p.z,
@@ -578,6 +667,8 @@ def _axis_phases(spec, mesh_dim: Dim3, resident: Dim3,
             bwd=bwd if not synthesized else (),
             wire_cells=wire, local_cells=slab_cells - wire,
             periodic=wraps[name], trim=trims[name],
+            sides=None if radii is None else _phase_sides(
+                spec, name, radii, orth[name] * nblocks),
         ))
     return tuple(phases)
 
@@ -722,7 +813,8 @@ def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
                fused: bool = False,
                persistent: bool = False,
                periodic=(True, True, True),
-               faces_only: bool = False) -> ExchangePlan:
+               faces_only: bool = False,
+               quantity_radius=None) -> ExchangePlan:
     """Build the ExchangePlan of one (GridSpec, mesh shape, method).
 
     Pure geometry — no jax, no devices. ``method`` may be the enum from
@@ -739,6 +831,14 @@ def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
     marks fixed axes and ``faces_only`` a star stencil's slab extents
     (see :class:`AxisPhaseIR`); both are lowerings of AXIS_COMPOSED with
     one block a device, and anything else refuses them.
+    ``quantity_radius`` (``{key: Radius}``, one entry for EVERY quantity
+    the exchange will be handed, in their order) is a radius a quantity:
+    which of the 26 directions' halos are filled for it, each either 0 or
+    what ``spec.radius`` allocates. The axis phases then carry, a
+    direction, the quantities that want that side
+    (:class:`SideIR`). AXIS_COMPOSED, one block a device, periodic, no
+    star and no wire dtype; ``None`` or empty is the plan there always
+    was.
     """
     mval = getattr(method, "value", method)
     if mval not in METHODS:
@@ -800,8 +900,28 @@ def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
             "a faces-only exchange is a star stencil's: the radius has "
             "edge or corner directions set (Radius.face_edge_corner(r, 0, "
             "0) is a star)")
+    radii = dict(quantity_radius) if quantity_radius else None
+    if radii is not None:
+        if (mval != AXIS_COMPOSED or resident != Dim3(1, 1, 1)
+                or not all(periodic) or faces_only or wire_dtype is not None):
+            raise ValueError(
+                f"a radius a quantity is lowered by {AXIS_COMPOSED!r} with "
+                "one block a device, periodic on every axis, without "
+                f"faces_only or a wire dtype (got method {mval!r}, resident "
+                f"{resident}, periodic {periodic}, faces_only {faces_only}, "
+                f"wire_dtype {wire_dtype})")
+        for key, r in radii.items():
+            for d, v in r._r.items():
+                have = spec.radius.dir(d)
+                if d != (0, 0, 0) and v and v != have and (
+                        not have or sum(map(abs, d)) == 1):
+                    raise ValueError(
+                        f"quantity {key!r} asks radius {v} toward {d}; the "
+                        f"domain allocates {have} there: a quantity's radius "
+                        "selects among the domain's halos (0, or the "
+                        "domain's own)")
     axis_phases = _axis_phases(spec, md, resident, synthesized, periodic,
-                               faces_only)
+                               faces_only, radii)
     direct_phases = (
         _direct_phases(spec, md, resident) if mval == DIRECT26 else ()
     )
@@ -823,6 +943,8 @@ def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
         wire_dtype=wire_dtype,
         periodic=periodic,
         faces_only=bool(faces_only),
+        quantity_radius=None if radii is None else tuple(
+            (k, radius_dirs(r)) for k, r in radii.items()),
     )
 
 

@@ -254,3 +254,50 @@ def test_the_mg_iteration_copies_no_level_and_holds_nothing_of_its_own(
     assert mem.argument_size_in_bytes == levels + v
     assert mem.alias_size_in_bytes == levels
     assert mem.temp_size_in_bytes == 0
+
+
+def test_the_lbm_step_holds_two_lattices_and_nothing_else(topo,
+                                                          as_on_the_chip):
+    """``lbm384.steady``'s own program (384^3, 19 populations, five steps a
+    dispatch): both lattices are donated and come back where they lay, the
+    program allocates nothing beside their 9.0 GB, and no ``copy`` of a
+    megabyte or more exists (two lattices leave 7 GB of the chip: one stray
+    copy of a lattice is an out-of-memory a user would meet). A step is
+    five Pallas calls: the one-sided y and z fills, two each (the low and
+    the high halo's five populations), and the stream-collide pass."""
+    from stencil_tpu.apps.lbm import DEFAULT_CHUNK
+    from stencil_tpu.domain.grid import GridSpec
+    from stencil_tpu.geometry import Dim3
+    from stencil_tpu.obs import scopes, telemetry
+    from stencil_tpu.ops import lbm
+    from stencil_tpu.parallel import HaloExchange, grid_mesh
+
+    d = Dim3(1, 1, 1)
+    spec = GridSpec(Dim3(384, 384, 384), d, lbm.domain_radius(True))
+    ex = HaloExchange(
+        spec, grid_mesh(d, list(topo.devices)[:1]),
+        quantity_radius={i: lbm.population_radius(i, True)
+                         for i in range(lbm.Q)})
+    scopes.clear()
+    lbm.make_lbm_step(ex, lbm.omega_of(1.0), iters=DEFAULT_CHUNK)
+    rec = scopes._registry[scopes.LBM_STEP][-1]
+    compiled = rec["fn"].lower(*rec["args"]).compile()
+    text = compiled.as_text()
+    plan = telemetry.get().records(kind="counter", name="lbm.step_plan")[-1]
+    assert (plan["kernel"], plan["layout"]) == ("pallas", "tight_x")
+    # a while of two steps a trip and the odd fifth: three steps' calls
+    for kernel, count in (("lbm_d3q19", 3), ("self_fill_y", 6),
+                          ("self_fill_z", 6)):
+        calls = re.findall(rf"%{kernel}[.\d]* = .*tpu_custom_call", text)
+        assert len(calls) == count, (kernel, len(calls))
+    big = [(instr, shape) for instr, shape in _COPY.findall(text)
+           if 4 * math.prod(int(n) for n in re.findall(
+               r"\d+", shape.split("[", 1)[1].split("]")[0]) or [1])
+           >= 1 << 20]
+    assert not big, big
+    mem = compiled.memory_analysis()
+    lattices = 2 * lbm.Q * 4 * math.prod(spec.block_shape_zyx())
+    assert lattices == 9_012_019_200
+    assert mem.argument_size_in_bytes == lattices
+    assert mem.alias_size_in_bytes == lattices
+    assert mem.temp_size_in_bytes == 0

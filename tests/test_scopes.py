@@ -47,7 +47,7 @@ def test_every_pallas_call_passes_a_name_and_every_kernel_is_in_the_vocabulary()
             assert isinstance(first, ast.Constant), (rel, "literal name")
             kernels.append(first.value)
     assert sites == [os.path.join("obs", "scopes.py")], sites
-    assert len(kernels) == 21
+    assert len(kernels) == 22
     assert set(kernels) == set(scopes.KERNELS)
     # an operator that plain XLA may compute carries its kernel's name all
     # the same (kernel_scope); a name outside the vocabulary is refused
