@@ -24,7 +24,8 @@ from ..parallel.exchange import HaloExchange, Method
 from ..parallel.mesh import BLOCK_PSPEC, MESH_AXES, mesh_dim
 from ..plan.ir import build_plan
 from . import double_buffer
-from .pallas_iso3dfd import RADIUS, make_pallas_iso3dfd_step, step_supported
+from .pallas_iso3dfd import (RADIUS, kernel_plan, make_pallas_iso3dfd_step,
+                             step_supported)
 
 # the sample's constants (include/iso3dfd.h, src/iso3dfd.cpp main())
 DT = 0.002
@@ -135,6 +136,7 @@ def make_iso3dfd_step(ex: HaloExchange, iters: int = 1, use_pallas=None,
         block_cells=spec.base.flatten(),
         halo_bytes_sent=ex.plan.wire_bytes([dtype.itemsize] * EXCHANGED)
         // ex.mesh.devices.size,
-        halo_bytes_if_all=halo_bytes_if_all(ex, dtype.itemsize))
+        halo_bytes_if_all=halo_bytes_if_all(ex, dtype.itemsize),
+        **(kernel_plan(spec, tiles) if pallas_on else {}))
     return double_buffer.jit_in_place(scopes.ISO3DFD_LOOP, fn,
                                       (like, like, like), (iters,))

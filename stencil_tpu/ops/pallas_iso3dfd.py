@@ -17,12 +17,28 @@ is copied inside VMEM, and the only input amplification is the y window
 (3 and 2 slots, the lag-1 rule of ``pallas_astaroth.py``), and ``next``'s
 write-back drains behind the following tiles.
 
-Rows are whole (``px`` lanes, the x halo inline): the x pencils are lane
-rolls of the centre rows, whose wrapped-in lanes land only in the halo
-columns, and those take ``next``'s own value back. The y pencils are loads
-at static row offsets, the z pencils other slots of the ring. The kernel
-writes owned cells only: ``next``'s halo planes, rows and columns, where
-the domain's fixed ring lives, keep what they hold.
+Rows are whole (``px`` lanes, the x halo inline), and the body walks a
+strip's plane a ROW GROUP at a time: 8 rows, one (8, 128) tile deep, so
+that every load is on the tile. A group is loaded once; its y pencil reads
+it and the groups before and after it, whose rows at +-r are a select of
+two groups rotated in registers (a row load off the tile costs Mosaic two
+loads, two rotations and a select), and r = 8 is the two neighbours
+themselves. The z pencils are the same rows of other slots of the ring.
+The x pencils are lane rolls of the centre rows, whose wrapped-in lanes
+land only in the halo columns, and those take ``next``'s own value back. A
+lane roll is what the body pays most for on a v5e (three units, a roll
+each per 6 to 8 bundles: 2 to 2.7 cycles a vreg where a VALU operation is
+a quarter), so the 16 shifts are NOT 16 rolls: the centre rows are rolled
+by +-1, +-2 and +-8, the terms at +-3 .. +-7 are two sums of the five near
+shifts, each weighted by the coefficient of where a roll by +-5 lands it,
+rolled once: 8 rolls a group. The sum is the same 49 products in float32
+in another order, the x terms multiplied one by one where the y and z
+terms of a radius are added first. The walk is unrolled over the strip's
+groups (their arithmetic is traced once, ``x_near`` / ``finish``), and a
+group's rolls are issued a group ahead so that they fly under the
+arithmetic of the one before. The kernel writes owned cells only:
+``next``'s halo planes, rows and columns, where the domain's fixed ring
+lives, keep what they hold.
 
 Shares nothing with ``pallas_astaroth.py``'s window code but the buffering
 discipline: that window is a stack of 8 fields shifted (or ring-indexed)
@@ -46,11 +62,17 @@ from ..domain.grid import GridSpec
 from ..obs import scopes
 
 RADIUS = 8
-# explicit scratch under this many bytes (the Astaroth kernel's measured
-# room on v5e is ~34 MB; this body keeps 8 rows of a plane live at a time,
-# so its temporaries are small)
-_SCRATCH_BUDGET = 24 * 1024 * 1024
-_ROWS = 8           # rows of a plane the body computes at a time
+# explicit scratch under this many bytes, and a strip of at most this many
+# row groups: what a v5e took best of the tiles that divide the block of
+# iso3dfd1024x4 (PERF.md section 7 has the sweep: (4, 168), 31.9 MB, 12.75
+# ms a call; one strip of 63 groups and 63 MB is 1.6 % faster and doubles
+# the body's trace, which is unrolled over a strip's groups)
+_SCRATCH_BUDGET = 32 * 1024 * 1024
+_STRIP_GROUPS = 32
+_ROWS = 8           # rows of a plane the body computes at a time: one tile
+_ROW_LOADS = 1      # loads of its own plane a row group: the one group the
+                    # walk down the strip has not yet seen, on the tile
+_LANE_ROLLS = 8     # whole-row lane rolls a row group: by +-1, +-2, +-8, +-5
 
 
 def scratch_bytes(spec: GridSpec, tz: int, ty: int) -> int:
@@ -63,21 +85,39 @@ def scratch_bytes(spec: GridSpec, tz: int, ty: int) -> int:
 
 def pick_tiles(spec: GridSpec) -> Tuple[int, int]:
     """(tz, ty) under the scratch budget: the tallest strip first (the y
-    window is the only read amplification), then the deepest tile (fewer
-    grid steps). ``tz`` divides 16 so that a tile's fresh planes are one
-    contiguous run of ring slots; ``(0, 0)`` where nothing fits."""
+    window is the only read amplification) of no more row groups than the
+    body is worth unrolling over, then the deepest tile (fewer grid steps).
+    ``tz`` divides 16 so that a tile's fresh planes are one contiguous run
+    of ring slots; ``(0, 0)`` where nothing fits."""
     nz, ny = spec.base.z, spec.base.y
     best = None
     for tz in (16, 8, 4, 2, 1):
         if nz % tz:
             continue
-        for ty in range(8, ny + 1, 8):
+        for ty in range(8, min(ny, _STRIP_GROUPS * _ROWS) + 1, 8):
             if ny % ty or scratch_bytes(spec, tz, ty) > _SCRATCH_BUDGET:
                 continue
             key = (-ty, -tz)
             if best is None or key < best[0]:
                 best = (key, (tz, ty))
     return best[1] if best else (0, 0)
+
+
+def kernel_plan(spec: GridSpec, tiles: Tuple[int, int] = None) -> dict:
+    """What the kernel built at ``tiles`` (the pick's by default) does, for
+    the counter ``iso3dfd.step_plan``: the tiles and their grid steps, the
+    scratch they take, how often a ``prev`` row is read from HBM, and a row
+    group's loads of its own plane and lane rolls."""
+    tz, ty = tiles if tiles is not None else pick_tiles(spec)
+    return {
+        "tiles": [tz, ty],
+        "grid_steps": (spec.base.z // tz) * (spec.base.y // ty),
+        "scratch_bytes": scratch_bytes(spec, tz, ty),
+        "prev_reread": (ty + 2 * RADIUS) / ty,
+        "row_loads": _ROW_LOADS,
+        "row_loads_off_tile": 0,
+        "lane_rolls": _LANE_ROLLS,
+    }
 
 
 def step_supported(spec: GridSpec, dtype) -> bool:
@@ -127,6 +167,51 @@ def make_pallas_iso3dfd_step(
     rows_in = ty + 2 * R          # y window [y0 - 8, y0 + ty + 8), 8-aligned
     first = tz + 2 * R            # planes a strip's first tile loads
     ring = 2 * tz + 2 * R
+    groups = ty // _ROWS
+
+    def xroll(a, t):
+        return pltpu.roll(a, t % px, 1)
+
+    # the body's arithmetic on (8, px) row groups, each traced once however
+    # many groups the strip has (the kernel calls them a group at a time)
+
+    @jax.jit
+    def x_near(ctr):
+        """What of a group's x pencil needs no second roll: the terms at
+        +-1, +-2 and +-8, and the two weighted sums that ONE more roll each,
+        by +-5, turns into the terms at +-3 .. +-7 (a lane roll is the
+        body's dearest operation: 8 a row group where 16 shifts make 16)."""
+        e1, w1 = xroll(ctr, 1), xroll(ctr, -1)
+        e2, w2 = xroll(ctr, 2), xroll(ctr, -2)
+        far = xroll(ctr, R) + xroll(ctr, -R)
+        c5 = cr[4] * ctr
+        qe = ((cr[2] * w2 + cr[3] * w1) + c5) + (cr[5] * e1 + cr[6] * e2)
+        qw = ((cr[6] * w2 + cr[5] * w1) + c5) + (cr[3] * e1 + cr[2] * e2)
+        near = (cr[0] * (e1 + w1) + cr[1] * (e2 + w2)) + cr[R - 1] * far
+        return near, qe, qw
+
+    def x_far(qe, qw):
+        return xroll(qe, 5) + xroll(qw, -5)
+
+    @jax.jit
+    def finish(ctr, lo, hi, xs, old, vel, owned, takes, zs):
+        """A row group's new ``next``: ``lo`` / ``hi`` are the aligned
+        groups above and below ``ctr`` in y, ``xs`` its x pencil, ``zs``
+        its 16 z neighbours."""
+        lap = c0 * ctr
+        for r in range(1, R + 1):
+            if r < _ROWS:
+                # rows +r and -r: a select of two groups, rotated in
+                # registers
+                up = pltpu.roll(jnp.where(takes[r], ctr, hi), _ROWS - r, 0)
+                dn = pltpu.roll(jnp.where(takes[_ROWS - r], lo, ctr), r, 0)
+                ys = up + dn
+            else:
+                ys = hi + lo
+            lap = lap + cr[r - 1] * (ys + (zs[2 * r - 2] + zs[2 * r - 1]))
+        lap = lap + xs
+        new = (2.0 * ctr - old) + lap * vel
+        return jnp.where(owned, new, old)
 
     def kernel(prev_hbm, nin_hbm, vel_hbm, out_hbm, win, nxt_v, vel_v,
                s_win, s_stage, s_nin, s_vel, s_out):
@@ -208,26 +293,36 @@ def make_pallas_iso3dfd_step(
 
         lane = lax.broadcasted_iota(jnp.int32, (_ROWS, px), 1)
         owned = (lane >= xo) & (lane < xo + nx)
+        sub = lax.broadcasted_iota(jnp.int32, (_ROWS, px), 0)
+        takes = {r: sub >= r for r in range(1, _ROWS)}   # sublanes r..7
 
         def plane(j, carry):
             m = zi * tz + R + j             # this plane's index in the strip
             centre = m % ring
             above = [(m + r) % ring for r in range(1, R + 1)]
             below = [(m - r + ring) % ring for r in range(1, R + 1)]
-            for row in range(0, ty, _ROWS):
-                w = R + row                 # window row of the first row
-                ctr = win[centre, pl.ds(w, _ROWS), :]
-                lap = c0 * ctr
-                for r in range(1, R + 1):
-                    xs = pltpu.roll(ctr, r, 1) + pltpu.roll(ctr, px - r, 1)
-                    ys = (win[centre, pl.ds(w + r, _ROWS), :]
-                          + win[centre, pl.ds(w - r, _ROWS), :])
-                    zs = (win[above[r - 1], pl.ds(w, _ROWS), :]
-                          + win[below[r - 1], pl.ds(w, _ROWS), :])
-                    lap = lap + cr[r - 1] * ((xs + ys) + zs)
-                old = nxt_v[s3, j, pl.ds(row, _ROWS), :]
-                new = (2.0 * ctr - old) + lap * vel_v[s2, j, pl.ds(row, _ROWS), :]
-                nxt_v[s3, j, pl.ds(row, _ROWS), :] = jnp.where(owned, new, old)
+
+            def rows(slot, g):
+                """Row group g of a plane of the window, on the tile (g =
+                -1 and ``groups`` are the window's halo rows)."""
+                return win[slot, pl.ds(R + g * _ROWS, _ROWS), :]
+
+            # the walk down the strip, unrolled: a group's x pencil is
+            # started a group ahead, its second rolls first of all, so that
+            # the rolls of one group fly under the arithmetic of another
+            lo, ctr = rows(centre, -1), rows(centre, 0)
+            near, qe, qw = x_near(ctr)
+            for g in range(groups):
+                xs = near + x_far(qe, qw)
+                hi = rows(centre, g + 1)
+                if g + 1 < groups:
+                    near, qe, qw = x_near(hi)
+                at = pl.ds(g * _ROWS, _ROWS)
+                zs = [rows(s, g) for pair in zip(above, below) for s in pair]
+                nxt_v[s3, j, at, :] = finish(
+                    ctr, lo, hi, xs, nxt_v[s3, j, at, :], vel_v[s2, j, at, :],
+                    owned, takes, zs)
+                lo, ctr = ctr, hi
             return carry
 
         lax.fori_loop(0, tz, plane, 0)

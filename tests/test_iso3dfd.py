@@ -25,6 +25,7 @@ from stencil_tpu.plan.ir import build_plan
 
 R = 8
 N = (48, 48, 48)            # the grid (z, y, x), ring included
+TALL = (32, 88, 48)         # 16 planes of 72 rows: nine row groups a strip
 STEPS = 3
 SEED = 4_000_000_007
 WAVES = ("prev", "next")
@@ -56,21 +57,21 @@ def _exchange(part, n=N):
     return HaloExchange(spec, mesh, periodic=(False,) * 3, faces_only=True)
 
 
-def _initial(kind):
+def _initial(kind, n=N):
     if kind == "sample":
-        return reference.sample_initial(N)
-    z, y, x = (np.arange(n) for n in N)
+        return reference.sample_initial(n)
+    z, y, x = (np.arange(m) for m in n)
     return [a.astype(np.float64) for a in reference.seeded(
-        SEED, z[:, None, None], y[None, :, None], x[None, None, :], N)]
+        SEED, z[:, None, None], y[None, :, None], x[None, None, :], n)]
 
 
 @functools.lru_cache(maxsize=None)
-def _kernel_run(kind, tiles):
+def _kernel_run(kind, tiles, n=N):
     """One block, the kernel interpreted: (got prev, got next, want prev,
     want next) over the interior after STEPS steps."""
-    ex = _exchange((1, 1, 1))
+    ex = _exchange((1, 1, 1), n)
     assert step_supported(ex.spec, jnp.float32)
-    p0, n0, v0 = _initial(kind)
+    p0, n0, v0 = _initial(kind, n)
     step = ops.make_iso3dfd_step(ex, iters=1, use_pallas=True,
                                  interpret=True, tiles=tiles)
     prev, nxt, vel = (_stacked(ex.spec, ex.mesh, a.astype(np.float32))
@@ -83,13 +84,28 @@ def _kernel_run(kind, tiles):
             want_p[cut], want_n[cut])
 
 
+# where the body treats a row group differently: the first and the last of
+# a strip read the window's halo rows as their neighbour group, and the
+# last looks ahead into them for the x pencil of a group that is not there
+TILE_CASES = {
+    "pick": (None, N),              # one tile: a strip of four groups
+    "4x8": ((4, 8), N),             # strips of ONE group, 4 strips of 8 tiles
+    "1x16": ((1, 16), N),           # tz = 1; first and last group, a seam
+    "2x16": ((2, 16), N),           # two groups a strip, two strips
+    "16x32": ((16, 32), N),         # tz = 16: the ring's deepest tile
+    "8x32": ((8, 32), N),           # four groups in one trip of the loop
+    "2x72": ((2, 72), TALL),        # nine groups: three trips of three
+    "4x24": ((4, 24), TALL),        # three strips of three groups
+}
+
+
 @pytest.mark.parametrize("field", WAVES)
 @pytest.mark.parametrize("kind", ["sample", "seeded"])
-@pytest.mark.parametrize("tiles", [None, (4, 8)])
-def test_kernel_interpreted_matches_the_reference(kind, tiles, field):
-    """One block; ``tiles=None`` is the pick (one tile), (4, 8) walks 4
-    strips of 8 tiles through the ring of planes and every prefetch."""
-    got_p, got_n, want_p, want_n = _kernel_run(kind, tiles)
+@pytest.mark.parametrize("case", sorted(TILE_CASES))
+def test_kernel_interpreted_matches_the_reference(kind, case, field):
+    """One block; ``pick`` is the pick (one tile), 4x8 walks 4 strips of 8
+    tiles through the ring of planes and every prefetch."""
+    got_p, got_n, want_p, want_n = _kernel_run(kind, *TILE_CASES[case])
     got, want = (got_p, want_p) if field == "prev" else (got_n, want_n)
     scale = np.abs(want).max()
     assert scale > 0
@@ -97,7 +113,8 @@ def test_kernel_interpreted_matches_the_reference(kind, tiles, field):
 
 
 def test_tile_pick_fits_the_budget_and_the_cells_block():
-    from stencil_tpu.ops.pallas_iso3dfd import _SCRATCH_BUDGET, scratch_bytes
+    from stencil_tpu.ops.pallas_iso3dfd import (_SCRATCH_BUDGET,
+                                                _STRIP_GROUPS, scratch_bytes)
 
     spec = GridSpec(Dim3(1008, 1008, 2032), Dim3(1, 2, 2),
                     Radius.face_edge_corner(R, 0, 0))
@@ -105,9 +122,77 @@ def test_tile_pick_fits_the_budget_and_the_cells_block():
     assert (p.x, p.y, p.z) == (1024, 520, 1032)
     tz, ty = pick_tiles(spec)
     assert 16 % tz == 0 and spec.base.z % tz == 0 and spec.base.y % ty == 0
-    assert scratch_bytes(spec, tz, ty) <= _SCRATCH_BUDGET
+    # the tiles the chip took best of those that divide the block (PR 43)
+    assert (tz, ty) == (4, 168)
+    assert _SCRATCH_BUDGET == 32 * 1024 * 1024
+    assert scratch_bytes(spec, tz, ty) == 31_850_496 <= _SCRATCH_BUDGET
+    assert ty // 8 <= _STRIP_GROUPS
+    # a deeper tile or the whole block's rows in one strip are over it
+    assert scratch_bytes(spec, 8, 168) > _SCRATCH_BUDGET
+    assert spec.base.y // 8 > _STRIP_GROUPS
     assert step_supported(spec, jnp.float32)
     assert not step_supported(spec, jnp.float64)
+
+
+def _eqns(jaxpr, out):
+    for e in jaxpr.eqns:
+        out.append(e)
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _eqns(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("tiles", [None, (2, 16), (4, 8)])
+def test_step_plan_counter_says_what_the_built_kernel_does(tiles):
+    """The kernel's half of ``iso3dfd.step_plan`` against the traced body:
+    every read of the window starts on the 8-row tile, a row group loads
+    one group of its own plane and rolls whole rows 8 times."""
+    from stencil_tpu.obs import telemetry
+    from stencil_tpu.ops.pallas_iso3dfd import (kernel_plan,
+                                                make_pallas_iso3dfd_step,
+                                                scratch_bytes)
+
+    ex = _exchange((1, 1, 1))
+    spec = ex.spec
+    ops.make_iso3dfd_step(ex, use_pallas=True, interpret=True, tiles=tiles)
+    plan = telemetry.get().records(kind="counter",
+                                   name="iso3dfd.step_plan")[-1]
+    tz, ty = plan["tiles"]
+    assert (tz, ty) == (tiles or pick_tiles(spec))
+    want = kernel_plan(spec, tiles)
+    assert {k: plan[k] for k in want} == want
+    assert plan["grid_steps"] == (spec.base.z // tz) * (spec.base.y // ty)
+    assert plan["scratch_bytes"] == scratch_bytes(spec, tz, ty)
+    assert plan["prev_reread"] == (ty + 16) / ty
+    assert plan["row_loads_off_tile"] == 0
+
+    p = spec.padded()
+    like = jax.ShapeDtypeStruct((p.z, p.y, p.x), jnp.float32)
+    fn = make_pallas_iso3dfd_step(spec, ops.coefficients(), interpret=True,
+                                  tiles=tiles)
+    eqns = _eqns(jax.make_jaxpr(fn)(like, like, like).jaxpr, [])
+    window = (2 * tz + 16, ty + 16, p.x)
+    reads = [e for e in eqns if e.primitive.name == "get"
+             and e.invars[0].aval.shape == window]
+    groups = ty // 8
+    # a plane's walk: its first two groups, then one more a group, and the
+    # 16 z neighbours of each; the body is traced once a plane
+    assert len(reads) == 2 + groups * (plan["row_loads"] + 16)
+    for e in reads:
+        _, rows, lanes = jax.tree_util.tree_unflatten(
+            e.params["tree"], e.invars[1:])[0].indices
+        assert rows.size == 8 and isinstance(rows.start, int)
+        assert rows.start % 8 == 0, "a window read starts off the tile"
+        assert (lanes.start, lanes.size) == (0, p.x)
+    rolls = [e for e in eqns if e.primitive.name == "roll"]
+    lane = [e for e in rolls if e.params["axis"] == 1]
+    # a group: six of the centre rows and two of their weighted sums; the
+    # 14 sublane rotations are the y pencil's, made in registers
+    assert len(lane) == groups * plan["lane_rolls"] == groups * 8
+    assert len(rolls) - len(lane) == groups * 14
 
 
 PARTS = {"1": ((1, 1, 1), 1), "1x2x2": ((1, 2, 2), 4), "1x1x4": ((1, 1, 4), 4)}
