@@ -173,8 +173,13 @@ def probe_decomp():
                       slice(rect.lo.z, rect.hi.z),
                       slice(rect.lo.y, rect.hi.y),
                       slice(rect.lo.x, rect.hi.x)]
-            k = [val * (1.0 + 0.01 * i) for i in range(10)]
-            return FieldData(*k)
+            k = [val * (1.0 + 0.01 * i) for i in range(14)]
+            # fd.FieldData since PR 44: the diagonal with the gradient, the
+            # y and z differences the mixed x derivatives are shifted from,
+            # the view's x shift (none here) and deryz on demand
+            return FieldData(*k[:7], dy=tuple(k[7:10]), dz=tuple(k[10:13]),
+                             xshift=lambda v, d: v, inv_ds=tuple(ids),
+                             hyz_of=lambda: k[13])
         pa.field_data = fake
 
     chunk = 60

@@ -27,6 +27,7 @@ from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
 
+from . import fd
 from .fd import FieldData
 
 Vec = Tuple  # (x, y, z) of arrays
@@ -95,12 +96,10 @@ def laplace_vec(v) -> Vec:
 
 
 def gradient_of_divergence(v) -> Vec:
-    """Column sums of the component hessians (user_kernels.h:246-251)."""
-    return (
-        v[0].hxx + v[1].hxy + v[2].hxz,
-        v[0].hxy + v[1].hyy + v[2].hyz,
-        v[0].hxz + v[1].hyz + v[2].hzz,
-    )
+    """Column sums of the component hessians (user_kernels.h:246-251),
+    assembled by :func:`fd.gradient_of_divergence` from what the gradients
+    already hold."""
+    return fd.gradient_of_divergence(v)
 
 
 def stress_tensor(v):

@@ -217,6 +217,17 @@ NAME_FIELDS = {
                            ("quantities", int), ("exchanges_per_iter", int),
                            ("shells", int), ("shell_cells", int),
                            ("block_cells", int), ("halo_bytes_sent", int)),
+    # what ops/pallas_astaroth.make_pallas_substep built, once per build
+    # (value: the RK3 substep), when its body is first traced: the (tz, ty)
+    # tile, the layout, the window variant, and what fd's pencils and the
+    # right-hand sides asked of the window view a vreg position of the
+    # tile: whole-row lane rolls (tight-x: every x shift of a formed
+    # value; 0 inline) and window reads. No benchmark reader:
+    # kernel_scope_ms_per_iter shows the effect
+    "astaroth.substep_plan": (("tiles", list), ("tight_x", bool),
+                              ("variant", str),
+                              ("lane_rolls_per_position", int),
+                              ("window_reads_per_position", int)),
     # what ops/iso3dfd.make_iso3dfd_step built, once per build (value:
     # steps a dispatch): blocks of the mesh, the star's radius, the
     # domain's quantities and how many of them a step exchanges, which

@@ -20,10 +20,23 @@ layout (Radius.without_x: px == nx, x pencils via lane rolls) reduces
 to 1.
 
 The math is NOT duplicated: derivative pencils come from
-``astaroth.fd.field_data`` and the physics from ``astaroth.equations`` —
-the same functions the XLA path executes — applied to VMEM refs through a
-window-local view adapter. Parity between the two paths is therefore
-structural (pinned by tests/test_pallas_astaroth.py in interpret mode).
+``astaroth.fd.field_data`` / ``gradient_of_divergence`` and the physics
+from ``astaroth.equations`` — the same functions the XLA path executes —
+applied to VMEM refs through a window-local view adapter. Parity between
+the two paths is therefore structural (pinned by
+tests/test_pallas_astaroth.py in interpret mode).
+
+The body walks the tile ONE 8-row group of one plane a trip of a loop that
+carries nothing (a vreg or three a value, so what fd and the equations form
+stays in registers; on whole (2, 128) x 256 tiles every value was the
+register file, 64 vregs, and the one store slot a bundle bound the kernel:
+492 spill stores a vreg position of 669 bundles, PERF.md section 6). Rows
+are loaded whole at their tile boundary, shifted in y by a select and a
+sublane rotation in registers, and everything shifted in x is a lane roll
+of a value AFTER it is formed (fd: the x pencil from the centre rows, the
+mixed derivatives from the y and z differences the gradient has): 84 rolls
+and 120 window reads a vreg position where 144 and 440 were, counted while
+the body is traced (counter ``astaroth.substep_plan``).
 
 Layout contract: padded fp32 blocks with TPU-aligned planes
 (GridSpec(aligned=True)), face radii >= 3, exchanged halos (including the
@@ -78,9 +91,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..domain.grid import GridSpec
 from ..geometry import Rect3, Dim3
-from ..obs import scopes
+from ..obs import scopes, telemetry
 from ..astaroth.fd import field_data
-from ..astaroth.equations import Constants, continuity, entropy, induction, momentum
+from ..astaroth.equations import (
+    Constants,
+    continuity,
+    entropy,
+    gradient_of_divergence,
+    induction,
+    momentum,
+)
 
 FIELDS = ("lnrho", "uux", "uuy", "uuz", "ax", "ay", "az", "entropy")
 NF = len(FIELDS)
@@ -158,46 +178,82 @@ def substep_supported(spec: GridSpec, dtype) -> bool:
 
 
 class _SlabView:
-    """Adapter letting fd.field_data slice a field's plane window of the
-    VMEM scratch ref as if it were a plain [z, y, x] array.
+    """Adapter letting fd.field_data slice ONE 8-row group of ONE plane of
+    a field's window in the VMEM scratch ref as if it were a plain
+    [z, y, x] array of whole rows: local rows [8, 16) are the group,
+    [0, 8) and [16, 24) the groups before and after it; ``row0`` (traced,
+    a multiple of 8) is the window row of local row 0 and ``plane0``
+    (traced) the window plane of local plane 0.
 
-    ``wrap_nx``: tight-x layout — the window carries exactly nx columns
-    with no halos, and x-shifted pencil reads become in-VMEM lane rolls
-    (out[j] = base[(j + dx) mod nx], the periodic neighborhood).
+    Every load is of a whole row group at its own (8-row) tile boundary,
+    made once however often fd asks (``loads``); a read at rows +-dy of
+    the group is built in registers from two of them: a select and ONE
+    sublane rotation (Mosaic takes no load at a traced row offset off
+    the tile, and builds a static one from two loads, two to three
+    rotations and selects).
+
+    Rows are whole (``px`` columns) in both layouts, and fd shifts what it
+    formed from them in x through :meth:`xroll`, an in-VMEM lane roll
+    (out[j] = v[(j + d) mod px]). Tight-x (px == nx, no x halos): the
+    roll IS the periodic neighborhood. Inline x halos: a compute column's
+    neighbours lie inside the row, so the wrap only ever lands in the
+    columns the kernel does not write.
 
     ``zmap``: ring-indexed window — maps a logical window plane j to its
-    (traced) physical slot. Slices over z are then read plane-by-plane at
-    dynamic slots and reassembled by concatenation (the slot math of the
-    jacobi multistep, ops/pallas_stencil.py)."""
+    (traced) physical slot (the slot math of the jacobi multistep,
+    ops/pallas_stencil.py).
 
-    __slots__ = ("ref", "pre", "wrap_nx", "zmap")
+    ``asked`` counts what the traced body asks of the window, a vreg
+    position (the counter ``astaroth.substep_plan``)."""
 
-    def __init__(self, ref, pre, wrap_nx=None, zmap=None):
+    __slots__ = ("ref", "pre", "plane0", "row0", "px", "asked", "zmap",
+                 "loads")
+
+    def __init__(self, ref, pre, plane0, row0, px, asked, zmap=None):
         self.ref = ref
         self.pre = pre
-        self.wrap_nx = wrap_nx
+        self.plane0 = plane0
+        self.row0 = row0
+        self.px = px
+        self.asked = asked
         self.zmap = zmap
+        self.loads = {}
 
-    def _read(self, zidx, ysl, xsl):
-        nx = self.wrap_nx
-        if nx is not None:
-            dx = xsl.start  # tight layout: xsl == slice(dx, nx + dx)
-            assert xsl.stop - dx == nx, (xsl, nx)
-            if dx != 0:
-                base = self.ref[self.pre + (zidx, ysl, slice(0, nx))]
-                return pltpu.roll(base, (-dx) % nx, 2)
-        return self.ref[self.pre + (zidx, ysl, xsl)]
+    def xroll(self, v, d):
+        if d == 0:
+            return v
+        self.asked["lane_rolls"] += 1
+        return pltpu.roll(v, (-d) % self.px, 2)
+
+    def group(self, k, j):
+        """Local rows [8k, 8k + 8) of local plane ``j``: one on-tile load."""
+        if (k, j) not in self.loads:
+            self.asked["window_reads"] += 1
+            rows = pl.ds(pl.multiple_of(self.row0 + 8 * k, 8), 8)
+            plane = self.plane0 + j
+            if self.zmap is not None:
+                plane = self.zmap(plane)
+            self.loads[k, j] = self.ref[self.pre + (pl.ds(plane, 1), rows)]
+        return self.loads[k, j]
 
     def __getitem__(self, idx):
         assert isinstance(idx, tuple) and idx[0] is Ellipsis, idx
         zsl, ysl, xsl = idx[1:]
-        if self.zmap is None:
-            return self._read(zsl, ysl, xsl)
-        parts = [
-            self._read(pl.ds(self.zmap(j), 1), ysl, xsl)
-            for j in range(zsl.start, zsl.stop)
-        ]
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
+        assert (xsl.start, xsl.stop) == (0, self.px), xsl
+        assert zsl.stop - zsl.start == 1, zsl
+        dy = ysl.start - 8
+        assert ysl.stop - ysl.start == 8 and abs(dy) <= _HALO, ysl
+        ctr = self.group(1, zsl.start)
+        if dy == 0:
+            return ctr
+        sub = jax.lax.broadcasted_iota(jnp.int32, ctr.shape, 1)
+        if dy > 0:
+            # rows dy.. of the group, then the first dy of the next one
+            mixed = jnp.where(sub >= dy, ctr, self.group(2, zsl.start))
+        else:
+            # the last -dy rows of the group before, then this one's
+            mixed = jnp.where(sub >= 8 + dy, self.group(0, zsl.start), ctr)
+        return pltpu.roll(mixed, (-dy) % 8, 1)
 
 
 def make_pallas_substep(
@@ -251,9 +307,10 @@ def make_pallas_substep(
     beta = RK3_BETA[substep]
     alpha_over_pb = RK3_ALPHA[substep] / RK3_BETA[substep - 1] if substep else 0.0
     ids = tuple(float(v) for v in inv_ds)
-    # window-local region the rates are produced over
-    rect = Rect3(Dim3(xo, 8, H), Dim3(xo + nx, 8 + ty, H + tz))
-    wxs = slice(xo, xo + nx)  # compute columns within a window row
+    # the region the rates of one 8-row group of one plane are produced
+    # over, in the group's frame (_SlabView: local rows [8, 16), whole rows)
+    rect = Rect3(Dim3(0, 8, H), Dim3(px, 16, H + 1))
+    plan = {}  # what the first trace of the body asked of the window
 
     def kernel(*refs):
         curr_hbm = refs[:NF]
@@ -271,15 +328,6 @@ def make_pallas_substep(
         # slot (zi*tz + j) % W; a strip start (zi == 0) is offset 0, so the
         # full-window DMA below needs no variant-specific handling
         zmap = (lambda j: jnp.mod(zi * tz + j, W)) if ring else None
-
-        def win_planes(f, j0, ysl, xsl):
-            """win[f, j0:j0+tz, ysl, xsl] in logical window order."""
-            if not ring:
-                return win[f, j0 : j0 + tz, ysl, xsl]
-            parts = [
-                win[f, pl.ds(zmap(j0 + i), 1), ysl, xsl] for i in range(tz)
-            ]
-            return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
 
         def tile_zy(ti):
             return zo + (ti % n_tz) * tz, yo + (ti // n_tz) * ty
@@ -383,44 +431,64 @@ def make_pallas_substep(
                     out_dma(s3, t - 3, f).wait()
 
         # derivatives + physics over the tile, via the shared fd/equations
-        # implementation (reference: solve<step>, user_kernels.h:437-469)
-        fds = [
-            field_data(
-                _SlabView(
-                    win, (f,), wrap_nx=nx if tight_x else None, zmap=zmap
-                ),
-                rect,
-                ids,
-            )
-            for f in range(NF)
-        ]
-        lnrho, uux, uuy, uuz, ax, ay, az, ss = fds
-        uu = (uux, uuy, uuz)
-        aa = (ax, ay, az)
-        rates = [None] * NF
-        rates[0] = continuity(uu, lnrho)
-        mom = momentum(c, uu, lnrho, ss, aa)
-        ind = induction(c, uu, aa)
-        rates[1], rates[2], rates[3] = mom
-        rates[4], rates[5], rates[6] = ind
-        rates[7] = entropy(c, ss, uu, lnrho, aa)
+        # implementation (reference: solve<step>, user_kernels.h:437-469),
+        # ONE 8-row group of one plane a trip: what a group forms is a
+        # vreg a lane tile, so it lives in registers (module docstring).
+        # The loop carries nothing and its body is traced once.
+        n_g = ty // 8
 
-        for f in range(NF):
-            curr_c = win_planes(f, H, slice(8, 8 + ty), wxs)
-            if substep:
-                old = out_v[s3, f, :, :, wxs]
-                new = curr_c + beta * (
-                    alpha_over_pb * (curr_c - old) + rates[f] * dt
-                )
-            else:
-                new = curr_c + beta * dt * rates[f]
-            if tight_x:
-                out_v[s3, f] = new  # full rows ARE the compute columns
-            else:
-                # non-compute columns carry curr so the store covers whole
-                # aligned rows
-                out_v[s3, f] = win_planes(f, H, slice(8, 8 + ty), slice(None))
-                out_v[s3, f, :, :, wxs] = new
+        def group(i, _):
+            p0, g = jax.lax.div(i, n_g), jax.lax.rem(i, n_g)
+            asked = {"lane_rolls": 0, "window_reads": 0}
+            fds = [
+                field_data(
+                    _SlabView(win, (f,), p0, g * 8, px, asked, zmap=zmap),
+                    rect, ids)
+                for f in range(NF)
+            ]
+            lnrho, uux, uuy, uuz, ax, ay, az, ss = fds
+            uu = (uux, uuy, uuz)
+            aa = (ax, ay, az)
+            # the shifted sums of both vectors before the right-hand sides
+            # that read them: their rolls fly under the arithmetic between
+            gradient_of_divergence(uu)
+            gradient_of_divergence(aa)
+            rates = [None] * NF
+            rates[0] = continuity(uu, lnrho)
+            mom = momentum(c, uu, lnrho, ss, aa)
+            ind = induction(c, uu, aa)
+            rates[1], rates[2], rates[3] = mom
+            rates[4], rates[5], rates[6] = ind
+            rates[7] = entropy(c, ss, uu, lnrho, aa)
+            if not plan:
+                plan.update(asked)
+                telemetry.get().counter(
+                    "astaroth.substep_plan", value=substep, phase="compute",
+                    tiles=[tz, ty], tight_x=tight_x, variant=variant,
+                    lane_rolls_per_position=asked["lane_rolls"],
+                    window_reads_per_position=asked["window_reads"])
+
+            rows = pl.ds(pl.multiple_of(g * 8, 8), 8)  # of the tile
+            if not tight_x:
+                col = jax.lax.broadcasted_iota(jnp.int32, (1, 8, px), 2)
+                computed = (col >= xo) & (col < xo + nx)
+            for f in range(NF):
+                curr_c = fds[f].value
+                if substep:
+                    old = out_v[s3, f, pl.ds(p0, 1), rows]
+                    new = curr_c + beta * (
+                        alpha_over_pb * (curr_c - old) + rates[f] * dt
+                    )
+                else:
+                    new = curr_c + beta * dt * rates[f]
+                if not tight_x:
+                    # the other columns carry curr, so that the store
+                    # covers whole aligned rows
+                    new = jnp.where(computed, new, curr_c)
+                out_v[s3, f, pl.ds(p0, 1), rows] = new
+            return _
+
+        jax.lax.fori_loop(0, tz * n_g, group, 0)
 
         for f in range(NF):
             out_dma(s3, t, f).start()

@@ -198,18 +198,63 @@ def roll_field_data(f: np.ndarray, inv_ds) -> fd.FieldData:
         return res * inv_a * inv_b
 
     ix, iy, iz = inv_ds
+    # the roll view: whole periodic rows, so what fd forms from the y and
+    # z differences is shifted in x by a roll (the fused kernel's tight-x
+    # window does the same with a lane roll)
     return fd.FieldData(
         value=f,
         gx=first(lambda i: (0, 0, i), ix),
         gy=first(lambda i: (0, i, 0), iy),
         gz=first(lambda i: (i, 0, 0), iz),
         hxx=second(lambda i: (0, 0, i), ix),
-        hxy=cross(lambda i: (0, i, i), lambda i: (0, -i, i), ix, iy),
-        hxz=cross(lambda i: (i, 0, i), lambda i: (-i, 0, i), ix, iz),
         hyy=second(lambda i: (0, i, 0), iy),
-        hyz=cross(lambda i: (i, i, 0), lambda i: (-i, i, 0), iy, iz),
         hzz=second(lambda i: (i, 0, 0), iz),
+        dy=tuple(sh(0, i, 0) - sh(0, -i, 0) for i in (1, 2, 3)),
+        dz=tuple(sh(i, 0, 0) - sh(-i, 0, 0) for i in (1, 2, 3)),
+        xshift=lambda v, d: np.roll(v, -d, -1),
+        inv_ds=(ix, iy, iz),
+        hyz_of=lambda: cross(lambda i: (i, i, 0), lambda i: (-i, i, 0), iy, iz),
     )
+
+
+@pytest.mark.parametrize("view", ["slice", "roll"])
+@pytest.mark.parametrize("column", [0, 1, 2])
+def test_gradient_of_divergence_is_the_column_sums_of_the_plain_pencils(
+        view, column):
+    """fd assembles grad(div v) from the y and z differences the gradients
+    hold, shifted in x after they are summed; the plain per-derivative
+    pencils (derxx .. derzz, derxy, derxz, deryz) are what it must equal,
+    whether the shift is a slice of x-extended rows or a roll of whole
+    periodic ones."""
+    n = 12
+    rng = np.random.RandomState(11)
+    inv = (1.3, 0.7, 2.1)
+    comps = [rng.randn(n, n + 2, n + 4) for _ in range(3)]
+    padded = [periodic_padded(f) for f in comps]
+    rect = Rect3(Dim3(3, 3, 3), Dim3(3 + n + 4, 3 + n + 2, 3 + n))
+    ix, iy, iz = inv
+    h = [
+        {
+            "xx": fd.derxx(f, rect, ix), "yy": fd.deryy(f, rect, iy),
+            "zz": fd.derzz(f, rect, iz), "xy": fd.derxy(f, rect, ix, iy),
+            "xz": fd.derxz(f, rect, ix, iz), "yz": fd.deryz(f, rect, iy, iz),
+        }
+        for f in padded
+    ]
+    want = (
+        h[0]["xx"] + h[1]["xy"] + h[2]["xz"],
+        h[0]["xy"] + h[1]["yy"] + h[2]["yz"],
+        h[0]["xz"] + h[1]["yz"] + h[2]["zz"],
+    )[column]
+    if view == "slice":
+        v = tuple(fd.field_data(f, rect, inv) for f in padded)
+    else:
+        v = tuple(roll_field_data(f, inv) for f in comps)
+    got = eq.gradient_of_divergence(v)[column]
+    assert np.asarray(got).dtype == np.float64
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=1e-12)
+    # one assembly a vector, however many equations ask
+    assert eq.gradient_of_divergence(v)[column] is got
 
 
 def global_reference_iteration(fields, out, info, dt):

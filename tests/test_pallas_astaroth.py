@@ -223,3 +223,72 @@ def test_pick_tiles_budget():
     from stencil_tpu.ops.pallas_astaroth import _SCRATCH_BUDGET, scratch_bytes
 
     assert scratch_bytes(spec, tz, ty) <= _SCRATCH_BUDGET
+
+
+def _eqns(jaxpr, out):
+    """Every equation of a jaxpr, the bodies of its calls and loops too."""
+    for e in jaxpr.eqns:
+        out.append(e)
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _eqns(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("layout", ["tight", "inline"])
+def test_substep_plan_counter_says_what_the_traced_body_asks(layout):
+    """``astaroth.substep_plan``, once a build, against the traced body of
+    the cells' layout (tight-x) and the inline one: a vreg position costs
+    at most 84 whole-row lane rolls (8 x 6 of the x pencils, 2 x 18 of the
+    mixed derivatives: the y and z differences shifted after they are
+    summed) and 200 window reads, every read of the window is ONE plane's
+    8-row group at its tile boundary, and the loop over the groups is
+    traced once."""
+    from stencil_tpu.obs import telemetry
+
+    n = 128 if layout == "tight" else 16
+    radius = Radius.constant(3)
+    spec = GridSpec(Dim3(n, 16, 16), Dim3(1, 1, 1),
+                    radius.without_x() if layout == "tight" else radius)
+    assert substep_supported(spec, jnp.float32)
+    info, _ = load_config(CONF)
+    c = Constants.from_info(info)
+    inv_ds = tuple(info.real_params[k]
+                   for k in ("AC_inv_dsx", "AC_inv_dsy", "AC_inv_dsz"))
+    rec = telemetry.get()
+    before = len(rec.records(kind="counter", name="astaroth.substep_plan"))
+    fn = make_pallas_substep(spec, c, inv_ds, 1, DT, interpret=True)
+    p = spec.padded()
+    like = tuple(jax.ShapeDtypeStruct((p.z, p.y, p.x), jnp.float32)
+                 for _ in FIELDS)
+    eqns = _eqns(jax.make_jaxpr(fn)(like, like).jaxpr, [])
+    jax.make_jaxpr(fn)(like, like)  # a second trace records nothing
+    plans = rec.records(kind="counter", name="astaroth.substep_plan")[before:]
+    assert len(plans) == 1
+    plan = plans[0]
+    tz, ty = pick_tiles(spec)
+    assert plan["value"] == 1 and plan["tiles"] == [tz, ty]
+    assert plan["tight_x"] is (layout == "tight")
+    assert plan["variant"] == "shift"
+    assert plan["lane_rolls_per_position"] <= 84
+    assert plan["window_reads_per_position"] <= 200
+
+    rolls = [e for e in eqns if e.primitive.name == "roll"]
+    lane = [e for e in rolls if e.params["axis"] == 2]
+    assert len(lane) == plan["lane_rolls_per_position"] == 84
+    # the y pencils and deryz, built in registers: one rotation a read
+    assert len(rolls) - len(lane) == 8 * 6 + 4 * 12
+    loops = [e for e in eqns if e.primitive.name == "scan"]
+    assert [e.params["length"] for e in loops] == [tz * ty // 8]
+    window = (len(FIELDS), tz + 6, ty + 16, p.x)
+    reads = [e for e in _eqns(loops[0].params["jaxpr"].jaxpr, [])
+             if e.primitive.name == "get"
+             and e.invars[0].aval.shape == window]
+    assert len(reads) == plan["window_reads_per_position"] == 120
+    for e in reads:
+        _, planes, rows, lanes = jax.tree_util.tree_unflatten(
+            e.params["tree"], e.invars[1:])[0].indices
+        assert planes.size == 1 and rows.size == 8
+        assert (lanes.start, lanes.size) == (0, p.x)
