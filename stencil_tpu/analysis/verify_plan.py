@@ -1,11 +1,10 @@
 """ExchangePlan IR vs compiled-HLO conformance auditor.
 
 The ExchangePlan IR (plan/ir.py) *predicts* what each lowering puts on
-the interconnect — ``collectives_per_exchange``, ``wire_bytes``,
-``dmas_per_exchange`` — and the autotuner ranks candidates on those
-predictions without compiling them. The lowering (parallel/exchange.py)
-is required to compile to exactly what the plan says; historically that
-contract was pinned by a handful of hand-written counts in
+the interconnect — ``collectives_per_exchange``, ``wire_bytes`` — and
+the autotuner ranks candidates on those predictions without compiling
+them. The lowering (parallel/exchange.py) is required to compile to
+exactly what the plan says; historically that contract was pinned by a handful of hand-written counts in
 tests/test_plan_ir.py. This module makes it a *sweepable gate*: for a
 grid of partition x method x dtype x Q configs it compiles each lowering
 and cross-checks the IR's predictions against the compiled truth:
@@ -13,21 +12,11 @@ and cross-checks the IR's predictions against the compiled truth:
 - predicted ``collectives_per_exchange`` == the compiled program's
   ``collective-permute`` census count (``utils/hlo_check``), for every
   method — composed / direct26 / auto-spmd (the round-7 "partitioner
-  reinvents the composed schedule per quantity" finding, encoded) /
-  remote-dma (ZERO by construction, censused over every compiled piece);
+  reinvents the composed schedule per quantity" finding, encoded);
 - predicted ``wire_bytes`` == the census byte total for the ppermute
   methods (exact on one-block-per-device meshes — the scope this sweep
   stays in; the model documents its oversubscription overestimate);
-- no collective kind beyond ``collective-permute`` ever appears;
-- for REMOTE_DMA, the emulated per-neighbor transfer count equals
-  ``dmas_per_exchange x ndev`` (each device issues the plan's per-device
-  copies) and the census carries zero collective bytes;
-- for the persistent whole-chunk variant (``remote-dma+persistent``,
-  audited at chunk depth k = 2 with the radius*k deep halo), one real
-  chunk additionally runs through the persistent loop and the MEASURED
-  ``last_launches_per_chunk`` must equal the plan's
-  ``launches_per_chunk(k)`` prediction — the launch-count census the
-  cost model prices and the CI gate pins.
+- no collective kind beyond ``collective-permute`` ever appears.
 
 One schema-valid JSON verdict per config (``analysis.plan_verdict``
 records through obs/telemetry when a recorder is attached; the same
@@ -87,23 +76,6 @@ class Verdict:
         }
 
 
-# The fused compute+exchange variant audits as a fifth "method" label:
-# method remote-dma with kernel_variant=fused (its lowering — the
-# concurrent per-direction transport — has its own census/byte/DMA
-# predictions to conform to).
-FUSED_METHOD_LABEL = "remote-dma+fused"
-
-# The persistent whole-chunk variant is the sixth label: method
-# remote-dma with kernel_variant=persistent at multistep_k=2 (the
-# minimum chunk depth — the spec realizes radius*2 halos through
-# plan/cost.feasible exactly as realize() would). Beyond the shared
-# zero-collective/DMA-count checks, its audit runs one real chunk loop
-# and cross-checks the MEASURED ``ex.last_launches_per_chunk`` against
-# the plan's ``launches_per_chunk(k)`` prediction — the launch census
-# as a conformance-audited prediction, not just a telemetry gauge.
-PERSISTENT_METHOD_LABEL = "remote-dma+persistent"
-
-
 def sweep_configs(
     size: int = DEFAULT_SIZE,
     radius: int = DEFAULT_RADIUS,
@@ -112,17 +84,14 @@ def sweep_configs(
     qsets: Sequence[Sequence[str]] = DEFAULT_QSETS,
 ) -> List[dict]:
     """The sweep grid as plain dicts (label, size, radius, partition,
-    method, dtypes). Default methods: every ``plan.ir.METHODS`` entry
-    PLUS the variant labels ``remote-dma+fused`` and
-    ``remote-dma+persistent``."""
+    method, dtypes). Default methods: every ``plan.ir.METHODS`` entry."""
     from ..plan.ir import METHODS
 
-    known = tuple(METHODS) + (FUSED_METHOD_LABEL, PERSISTENT_METHOD_LABEL)
-    methods = list(methods or known)
-    unknown = sorted(set(methods) - set(known))
+    methods = list(methods or METHODS)
+    unknown = sorted(set(methods) - set(METHODS))
     if unknown:
         raise ValueError(f"unknown method(s): {', '.join(unknown)} "
-                         f"(known: {', '.join(known)})")
+                         f"(known: {', '.join(METHODS)})")
     out = []
     for part in partitions:
         for dtypes in qsets:
@@ -151,8 +120,7 @@ def _check(checks: List[dict], name: str, predicted, actual) -> bool:
 
 def audit_config(cfg: dict, devices=None,
                  perturb_collectives: int = 0,
-                 perturb_wire: int = 0,
-                 perturb_dmas: int = 0) -> Verdict:
+                 perturb_wire: int = 0) -> Verdict:
     """Compile one config's exchange and cross-check the IR predictions.
 
     Feasibility goes through ``plan/cost.feasible`` (the realize()
@@ -164,14 +132,11 @@ def audit_config(cfg: dict, devices=None,
     from ..parallel import HaloExchange, Method, grid_mesh
     from ..parallel.exchange import shard_blocks
     from ..plan.cost import feasible
-    from ..plan.ir import (FUSED_VARIANT, PERSISTENT_VARIANT, PlanChoice,
-                           PlanConfig, REMOTE_DMA)
+    from ..plan.ir import PlanChoice, PlanConfig
 
     devices = list(devices) if devices is not None else jax.devices()
     v = Verdict(label=cfg["label"], method=cfg["method"])
-    fused = cfg["method"] == FUSED_METHOD_LABEL
-    persistent = cfg["method"] == PERSISTENT_METHOD_LABEL
-    method = REMOTE_DMA if (fused or persistent) else cfg["method"]
+    method = cfg["method"]
     size, dtypes = cfg["size"], list(cfg["dtypes"])
     import numpy as np
 
@@ -187,14 +152,7 @@ def audit_config(cfg: dict, devices=None,
         return v
     config = PlanConfig.make(Dim3(size, size, size), radius, dtypes,
                              nblocks, devices[0].platform)
-    choice = PlanChoice(
-        partition=cfg["partition"], method=method,
-        kernel_variant=(PERSISTENT_VARIANT if persistent
-                        else FUSED_VARIANT if fused else None),
-        # persistent IS temporal fusion: k=2 is its minimum depth, and
-        # feasible() scales the realized radius to radius*k — the deep
-        # halo the audited exchange actually stages
-        multistep_k=2 if persistent else 1)
+    choice = PlanChoice(partition=cfg["partition"], method=method)
     feas = feasible(config, choice)
     if feas is None:
         v.skipped = True
@@ -205,8 +163,7 @@ def audit_config(cfg: dict, devices=None,
         return v
     spec, mesh_dim, _resident = feas
     mesh = grid_mesh(spec.dim, devices[:nblocks])
-    ex = HaloExchange(spec, mesh, Method(method), fused=fused,
-                      persistent=persistent)
+    ex = HaloExchange(spec, mesh, Method(method))
     g = spec.global_size
     base = np.arange(g.x * g.y * g.z, dtype=np.float64).reshape(
         g.z, g.y, g.x)
@@ -224,7 +181,6 @@ def audit_config(cfg: dict, devices=None,
         + perturb_collectives
     predicted_wire = plan.wire_bytes(itemsizes, floating=floating) \
         + perturb_wire
-    predicted_dmas = plan.dmas_per_exchange(nq, ngroups) + perturb_dmas
 
     actual_coll = census.get("collective-permute", (0, 0))[0]
     actual_bytes = sum(b for _c, b in census.values())
@@ -234,36 +190,7 @@ def audit_config(cfg: dict, devices=None,
     ok = _check(v.checks, "collectives_per_exchange",
                 predicted_coll, actual_coll)
     ok &= _check(v.checks, "stray_collective_kinds", {}, stray)
-    if method == REMOTE_DMA:
-        # the transport bypasses XLA collectives entirely (fused
-        # variant included): the census must carry ZERO bytes, and the
-        # wire prediction is cross-checked through the emulated
-        # per-neighbor transfer count instead
-        ok &= _check(v.checks, "census_bytes", 0, actual_bytes)
-        ex(state)  # one real (emulated) exchange counts its transfers
-        actual_transfers = ex._remote.last_transfer_count
-        ok &= _check(v.checks, "dma_transfers",
-                     predicted_dmas * nblocks, actual_transfers)
-        if persistent:
-            # the launch census as a conformance-audited PREDICTION:
-            # run one real k=2 chunk through the persistent loop and
-            # require the measured dispatches-per-chunk to equal the
-            # plan's launches_per_chunk(k) — the figure cost.score
-            # prices and the CI gate pins
-            from ..ops.jacobi import make_jacobi_loop
-
-            import jax.numpy as jnp
-
-            loop = make_jacobi_loop(ex, 2, standard_spheres=False,
-                                    temporal_k=2)
-            sel = shard_blocks(
-                np.zeros((g.z, g.y, g.x), dtype=np.int32), spec, mesh)
-            loop(state[0], jnp.zeros_like(state[0]), sel)
-            ok &= _check(v.checks, "launches_per_chunk",
-                         plan.launches_per_chunk(2),
-                         ex.last_launches_per_chunk)
-    else:
-        ok &= _check(v.checks, "wire_bytes", predicted_wire, actual_bytes)
+    ok &= _check(v.checks, "wire_bytes", predicted_wire, actual_bytes)
     v.ok = bool(ok)
     return v
 
@@ -488,16 +415,13 @@ def audit_time(cfg: dict, devices=None, iters: int = 6,
     from ..parallel import HaloExchange, Method, grid_mesh
     from ..parallel.exchange import shard_blocks
     from ..plan.cost import feasible
-    from ..plan.ir import (FUSED_VARIANT, PERSISTENT_VARIANT, PlanChoice,
-                           PlanConfig, REMOTE_DMA)
+    from ..plan.ir import PlanChoice, PlanConfig
     from ..utils.sync import hard_sync
 
     rec = rec or telemetry.get()
     devices = list(devices) if devices is not None else jax.devices()
     v = Verdict(label=cfg["label"], method=cfg["method"])
-    fused = cfg["method"] == FUSED_METHOD_LABEL
-    persistent = cfg["method"] == PERSISTENT_METHOD_LABEL
-    method = REMOTE_DMA if (fused or persistent) else cfg["method"]
+    method = cfg["method"]
     size, dtypes = cfg["size"], list(cfg["dtypes"])
     radius = Radius.constant(cfg["radius"])
     nblocks = cfg["partition"][0] * cfg["partition"][1] * cfg["partition"][2]
@@ -509,11 +433,7 @@ def audit_time(cfg: dict, devices=None, iters: int = 6,
         return v
     config = PlanConfig.make(Dim3(size, size, size), radius, dtypes,
                              nblocks, devices[0].platform)
-    choice = PlanChoice(
-        partition=cfg["partition"], method=method,
-        kernel_variant=(PERSISTENT_VARIANT if persistent
-                        else FUSED_VARIANT if fused else None),
-        multistep_k=2 if persistent else 1)
+    choice = PlanChoice(partition=cfg["partition"], method=method)
     feas = feasible(config, choice)
     if feas is None:
         v.skipped = True
@@ -528,8 +448,7 @@ def audit_time(cfg: dict, devices=None, iters: int = 6,
         return v
     spec, mesh_dim, _resident = feas
     mesh = grid_mesh(spec.dim, devices[:nblocks])
-    ex = HaloExchange(spec, mesh, Method(method), fused=fused,
-                      persistent=persistent)
+    ex = HaloExchange(spec, mesh, Method(method))
     g = spec.global_size
     base = np.arange(g.x * g.y * g.z, dtype=np.float64).reshape(
         g.z, g.y, g.x)
@@ -606,7 +525,6 @@ def run_time_sweep(configs: Sequence[dict], devices=None,
 
 def run_sweep(configs: Sequence[dict], devices=None,
               perturb_collectives: int = 0, perturb_wire: int = 0,
-              perturb_dmas: int = 0,
               rec: Optional["telemetry.Recorder"] = None) -> Dict:
     """Audit every config; returns ``{verdicts, checked, failed,
     skipped}`` and emits the ``analysis.*`` telemetry vocabulary when a
@@ -625,7 +543,7 @@ def run_sweep(configs: Sequence[dict], devices=None,
         jax.config.update("jax_enable_x64", True)
     try:
         return _run_sweep(configs, devices, perturb_collectives,
-                          perturb_wire, perturb_dmas, rec)
+                          perturb_wire, rec)
     finally:
         if x64_prev is False:
             import jax
@@ -634,7 +552,7 @@ def run_sweep(configs: Sequence[dict], devices=None,
 
 
 def _run_sweep(configs, devices, perturb_collectives, perturb_wire,
-               perturb_dmas, rec) -> Dict:
+               rec) -> Dict:
     verdicts: List[Verdict] = []
     for cfg in configs:
         with rec.span("analysis.verify_plan", phase="analysis",
@@ -643,7 +561,7 @@ def _run_sweep(configs, devices, perturb_collectives, perturb_wire,
                 v = audit_config(
                     cfg, devices=devices,
                     perturb_collectives=perturb_collectives,
-                    perturb_wire=perturb_wire, perturb_dmas=perturb_dmas)
+                    perturb_wire=perturb_wire)
             except Exception as e:  # an auditor crash is a FAILED config
                 v = Verdict(label=cfg["label"], method=cfg["method"],
                             ok=False,

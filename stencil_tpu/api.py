@@ -78,8 +78,6 @@ class DistributedDomain:
         self._faces_only = False
         self._method = Method.AXIS_COMPOSED
         self._batch_quantities = True
-        self._fused = False
-        self._persistent = False
         self._wire_dtype: Optional[str] = None
         self._devices: Optional[Sequence] = None
         self._partition_dim: Optional[Dim3] = None
@@ -206,32 +204,6 @@ class DistributedDomain:
         """The effective tuned choice (None on a plan-less domain)."""
         return self._plan_choice
 
-    def set_fused_exchange(self, enabled: bool) -> None:
-        """The FUSED compute+exchange variant of ``Method.REMOTE_DMA``
-        (ROADMAP #5): the exchange moves one exact-extent message per
-        active direction, all started boundary-first so the step loops
-        overlap interior compute behind the wire (the Pallas mega-kernel
-        on TPU, the host-orchestrated schedule elsewhere — both zero
-        collective-permutes). Applied at realize(); also set
-        automatically when a tuned plan carries
-        ``kernel_variant == "fused"``. Single-resident partitions only —
-        realize() raises loudly otherwise."""
-        self._fused = bool(enabled)
-
-    def set_persistent_exchange(self, enabled: bool) -> None:
-        """The PERSISTENT whole-chunk variant of ``Method.REMOTE_DMA``
-        (ROADMAP #7, ops/persistent_stencil.py): the step driver
-        exchanges radius*k-deep halos ONCE per k-step chunk and runs the
-        k substeps with no further communication — launch count drops
-        from O(steps) to O(chunks). The domain must be realized at the
-        DEEPENED radius (radius*k) — the step drivers that own the knob
-        (``jacobi3d --kernel-variant persistent``) do this; also set
-        automatically when a tuned plan carries
-        ``kernel_variant == "persistent"``. Mutually exclusive with
-        :meth:`set_fused_exchange`; single-resident REMOTE_DMA only —
-        realize() raises loudly otherwise."""
-        self._persistent = bool(enabled)
-
     def set_quantity_batching(self, enabled: bool) -> None:
         """Quantity-batched exchange (default on): per collective, all
         same-dtype quantities' boundary slabs ride ONE packed ``(Q, ...)``
@@ -311,15 +283,6 @@ class DistributedDomain:
                 else:
                     self._method = Method(ch.method)
                     self._batch_quantities = ch.batch_quantities
-                    # the tuned choice owns the variant BOTH ways: a
-                    # fused choice realizes the fused transport, and a
-                    # non-fused choice clears any prior
-                    # set_fused_exchange(True) — the autotune -> DB ->
-                    # zero-probe replay round-trip must reproduce the
-                    # tuned program exactly (and a composed winner must
-                    # not crash realize() on a stale fused flag)
-                    self._fused = ch.is_fused
-                    self._persistent = ch.is_persistent
                     if self._partition_dim is None:
                         self._partition_dim = Dim3.of(ch.partition)
             if self._partition_dim is not None:
@@ -388,8 +351,6 @@ class DistributedDomain:
                 self.spec, self.mesh, self._method,
                 batch_quantities=self._batch_quantities,
                 wire_dtype=self._wire_dtype,
-                fused=self._fused,
-                persistent=self._persistent,
                 periodic=self._periodic,
                 faces_only=self._faces_only,
                 quantity_radius=self._quantity_radius(),
@@ -577,18 +538,13 @@ class DistributedDomain:
         devs = self.mesh.devices.flatten()
         cfg = PlanConfig.make(self.size, self.radius, self._dtypes,
                               len(devs), devs[0].platform)
-        from .plan.ir import FUSED_VARIANT, PERSISTENT_VARIANT
-
         ch = self._plan_choice
         choice = PlanChoice(
             partition=(self.spec.dim.x, self.spec.dim.y, self.spec.dim.z),
             method=self._method.value,
             batch_quantities=self._batch_quantities,
             multistep_k=ch.multistep_k if ch is not None else 1,
-            kernel_variant=(ch.kernel_variant if ch is not None
-                            else FUSED_VARIANT if self._fused
-                            else PERSISTENT_VARIANT if self._persistent
-                            else None),
+            kernel_variant=ch.kernel_variant if ch is not None else None,
             placement=ch.placement if ch is not None else None,
         )
         return {"key": cfg.to_json(), "choice": choice.to_json(),
@@ -596,6 +552,8 @@ class DistributedDomain:
                 "wire_dtype": self._wire_dtype}
 
     def _warn_plan_mismatch(self, manifest: dict) -> None:
+        from .plan.ir import retired_choice_key
+
         saved = (manifest.get("meta") or {}).get("plan")
         if not saved:
             return  # pre-plan snapshot: nothing to compare
@@ -625,14 +583,17 @@ class DistributedDomain:
             saved_ch.pop("placement", None)
             here_ch.pop("placement", None)
         # the comparison is data-driven (plain dicts), so a snapshot
-        # written under a method this build does not know — REMOTE_DMA
-        # from a newer build, or any future transport — still WARNS
-        # instead of crashing on an unknown enum name; name the methods
-        # in the message so the operator sees what moved
+        # written under a method this build does not know — the retired
+        # kernel-initiated transport of PRs 10 to 45, or any future
+        # one — still WARNS instead of crashing on an unknown enum name;
+        # name the methods in the message so the operator sees what moved
         saved_m = saved_ch.get("method")
         here_m = here_ch.get("method")
         known = {m.value for m in Method}
-        unknown = (f" (method {saved_m!r} is unknown to this build)"
+        retired = retired_choice_key(saved_ch)
+        unknown = (f" (its {retired} {saved_ch[retired]!r} is retired)"
+                   if retired is not None else
+                   f" (method {saved_m!r} is unknown to this build)"
                    if saved_m is not None and saved_m not in known else "")
         wire_delta = saved.get("wire_dtype") != here.get("wire_dtype")
         if saved_ch != here_ch or wire_delta:

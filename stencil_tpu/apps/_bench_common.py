@@ -223,7 +223,6 @@ def time_exchange(
     batch_quantities: bool = True,
     partition=None,
     wire_dtype=None,
-    fused: bool = False,
 ) -> dict:
     """Realize a domain with ``quantities`` quantities and time ``iters``
     exchanges in fused chunks. Returns stats + the domain.
@@ -232,10 +231,8 @@ def time_exchange(
     one-collective-per-quantity program (the ``--batched-ab`` baseline);
     ``partition`` forces the block grid (e.g. ``(2, 2, 2)``) so A/B runs
     pin the mesh instead of trusting the auto-partitioner; ``wire_dtype``
-    turns on the (lossy) bf16/fp8-on-the-wire carrier compression;
-    ``fused`` times the fused compute+exchange variant's concurrent
-    per-direction transport (REMOTE_DMA only — the autotuner's fused
-    candidates probe through here). ``placement`` is a Placement
+    turns on the (lossy) bf16/fp8-on-the-wire carrier compression.
+    ``placement`` is a Placement
     strategy OR a plain assignment tuple (``PlanChoice.placement`` —
     wrapped in :class:`~stencil_tpu.parallel.FixedAssignment` so placed
     plan candidates probe on exactly their tuned mesh)."""
@@ -252,8 +249,6 @@ def time_exchange(
     dd.set_radius(radius)
     dd.set_methods(method)
     dd.set_quantity_batching(batch_quantities)
-    if fused:
-        dd.set_fused_exchange(True)
     if wire_dtype:
         dd.set_wire_dtype(wire_dtype)
     if partition is not None:
@@ -273,11 +268,8 @@ def time_exchange(
     chunk = max(1, min(chunk, iters))
     tail = iters % chunk
     # the wire tag keeps a --wire-ab run's legs separable in aggregation
-    # (report._agg_key splits on it, like method/batched); the variant
-    # tag does the same for the fused A/B legs
+    # (report._agg_key splits on it, like method/batched)
     wtag = {"wire": str(wire_dtype)} if wire_dtype else {}
-    if fused:
-        wtag["variant"] = "fused"
     # compile + warm every loop size OUTSIDE the timed region
     with rec.span("exchange.warmup", phase="compile", method=method.value,
                   batched=batch_quantities, **wtag):
@@ -333,7 +325,6 @@ def time_exchange(
             pchoice,
             samples,
             phase="exchange.iter",
-            kernel_variant="fused" if fused else None,
             fabric=fabric_fingerprint(devices=devices),
         )
         # the run's plan identity — the join key between this metrics

@@ -35,10 +35,8 @@ from ._bench_common import (
     add_metrics_flags, coord_state, start_metrics, time_exchange,
 )
 
-# ablation order: manual composed, manual direct, partitioner-synthesized,
-# kernel-initiated (remote DMA — 0 ppermutes; CPU runs the emulation)
-ABLATE_METHODS = (Method.AXIS_COMPOSED, Method.DIRECT26, Method.AUTO_SPMD,
-                  Method.REMOTE_DMA)
+# ablation order: manual composed, manual direct, partitioner-synthesized
+ABLATE_METHODS = (Method.AXIS_COMPOSED, Method.DIRECT26, Method.AUTO_SPMD)
 
 
 def sweep_radii(face: int = 2, edge: int = 1):
@@ -248,13 +246,11 @@ def wire_gate(wire: str):
 
 
 def wire_ab(x, y, z, iters=30, quantities=4, devices=None, radius=2,
-            wire="bfloat16", method=Method.AXIS_COMPOSED, partition=None,
-            fused: bool = False):
+            wire="bfloat16", method=Method.AXIS_COMPOSED, partition=None):
     """Wire-compression A/B (bf16 or the fp8 tier): the same exchange
     with native carriers vs ``wire``-compressed ones, reporting the
     on-wire byte reduction and the measured error the compression pays
-    for it. ``fused`` A/Bs the fused compute+exchange transport's
-    concurrent per-direction carriers instead (REMOTE_DMA only).
+    for it.
 
     Narrow-range wire dtypes (float8_e4m3fn tops out at 448 and maps
     overflow to NaN) get the coordinate fixture scaled into their finite
@@ -294,7 +290,7 @@ def wire_ab(x, y, z, iters=30, quantities=4, devices=None, radius=2,
         r = time_exchange(
             Dim3(x, y, z), Radius.constant(radius), iters, method=method,
             devices=devices, quantities=quantities, wire_dtype=wd,
-            partition=partition, fused=fused,
+            partition=partition,
         )
         dd = r["domain"]
         ex = dd.halo_exchange
@@ -302,17 +298,10 @@ def wire_ab(x, y, z, iters=30, quantities=4, devices=None, radius=2,
         if scale < 1.0:
             state = {k: v * jnp.asarray(scale, v.dtype)
                      for k, v in state.items()}
-        # the lowered-module wire truth (see docstring); REMOTE_DMA has
-        # no single lowered program — its wire bytes come from the plan
-        if method == Method.REMOTE_DMA:
-            itemsizes = [np.dtype("float32").itemsize] * quantities
-            wire_bytes[wd] = ex.plan.wire_bytes(itemsizes)
-            cp = (0, wire_bytes[wd])
-        else:
-            census = stablehlo_wire_census(
-                ex._compiled.lower(state).as_text())
-            cp = census.get("collective-permute", (0, 0))
-            wire_bytes[wd] = cp[1]
+        # the lowered-module wire truth (see docstring)
+        census = stablehlo_wire_census(ex._compiled.lower(state).as_text())
+        cp = census.get("collective-permute", (0, 0))
+        wire_bytes[wd] = cp[1]
         label = f"wire={wd or 'native'}"
         rows.append({
             "config": f"{x}-{y}-{z}/q={quantities}/{label}",
@@ -410,9 +399,6 @@ def main(argv: Optional[list] = None) -> int:
                         "tier float8_e4m3fn): the radius sweep runs "
                         "with it on; --wire-ab A/Bs it against native "
                         "(default bfloat16 there)")
-    p.add_argument("--fused", action="store_true",
-                   help="use the fused compute+exchange transport "
-                        "(REMOTE_DMA kernel_variant=fused) for --wire-ab")
     p.add_argument("--cpu", type=int, default=0)
     add_metrics_flags(p)
     args = p.parse_args(argv)
@@ -432,7 +418,6 @@ def main(argv: Optional[list] = None) -> int:
             args.x, args.y, args.z, iters=args.iters,
             quantities=qs[0] if qs else 4, wire=wire,
             method=Method(args.method), partition=partition,
-            fused=args.fused,
         )
         print(ablate_header())
         for row in rows:
@@ -443,7 +428,7 @@ def main(argv: Optional[list] = None) -> int:
               f"{err['max_ulp_err']:.0f}")
         # dtype-derived gate (wire_gate): >= 95% of the ideal fp32-native
         # byte ratio (bf16 1.9x, fp8 3.8x), error within the wire dtype's
-        # rounding half-ulp, and an UNCHANGED permute/DMA count — the
+        # rounding half-ulp, and an UNCHANGED permute count — the
         # compression must never change what moves, only how wide
         ratio_thr, rel_bound = wire_gate(wire)
         count_ok = len({row["cp_count"] for row in rows}) == 1

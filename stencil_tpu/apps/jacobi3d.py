@@ -86,8 +86,6 @@ def run(
     rollback_backoff: float = 0.25,
     inject: Optional[str] = None,
     wire_dtype: Optional[str] = None,
-    fused: bool = False,
-    kernel_variant: Optional[str] = None,
     sentinel=None,
     status=None,
     replan: bool = False,
@@ -100,7 +98,7 @@ def run(
     temporal depth of the fused loop across chips. ``None`` (the default):
     the application picks. On TPUs, on a tight-x mesh with more than one
     block (x not split, an even split, one block a device), with overlap
-    on, ``Method.AXIS_COMPOSED`` and no ``kernel_variant``, that is
+    on and ``Method.AXIS_COMPOSED``, that is
     :func:`~stencil_tpu.ops.pallas_stencil.pick_temporal_depth` of the
     global size, the partition and ``chunk``: the deepest K <= ``chunk``
     whose multistep staging fits VMEM, a divisor of ``chunk`` where one
@@ -109,21 +107,6 @@ def run(
     An integer (1 included) overrides the pick. What was chosen is the
     counter ``jacobi.temporal_depth`` (``chunk``, ``passes``,
     ``single_steps``, ``halo_zyx``, ``bound``)."""
-    # kernel_variant is the tuned-plan vocabulary ("fused" / "persistent",
-    # plan/ir.py); --fused stays as the historical spelling of the former
-    if fused and kernel_variant is None:
-        kernel_variant = "fused"
-    if kernel_variant == "fused":
-        fused = True
-    elif kernel_variant == "persistent" and (deep_halo or 1) < 2:
-        raise ValueError(
-            "kernel_variant='persistent' is the whole-chunk temporal "
-            "fusion: it needs --deep-halo >= 2 (the chunk depth k; the "
-            "domain realizes radius*k halos)")
-    elif kernel_variant not in (None, "fused", "persistent"):
-        raise ValueError(
-            f"unknown kernel_variant {kernel_variant!r}: valid values are "
-            "'fused' and 'persistent'")
     devices = list(devices) if devices is not None else jax.devices()
     n = len(devices)
     # run() is covered by spans without a hole: realize, init, warmup
@@ -161,9 +144,8 @@ def run(
         pdim is not None and pdim.x == 1 and pdim.flatten() == n
         and size.x % 128 == 0
         and size.y % pdim.y == 0 and size.z % pdim.z == 0
-        # no in-kernel x wrap in the global AUTO_SPMD program, and the
-        # REMOTE_DMA carrier/emulation assumes inline halos everywhere
-        and method not in (Method.AUTO_SPMD, Method.REMOTE_DMA)
+        # no in-kernel x wrap in the global AUTO_SPMD program
+        and method != Method.AUTO_SPMD
         and not autotune  # the tuner may pick AUTO_SPMD, which cannot
                           # run the tight-x no-x-halo layout
         and _on_tpu(devices))
@@ -173,8 +155,7 @@ def run(
     # it observes; an explicit deep_halo overrides the pick
     if deep_halo is not None:
         depth_bound = "explicit"
-    elif (tight_x and overlap and method == Method.AXIS_COMPOSED
-            and kernel_variant is None):
+    elif tight_x and overlap and method == Method.AXIS_COMPOSED:
         from ..ops.pallas_stencil import pick_temporal_depth
 
         deep_halo, depth_bound = pick_temporal_depth(size, pdim, chunk)
@@ -195,15 +176,6 @@ def run(
         dd.set_radius(deep_halo)
     dd.set_methods(method)
     dd.set_devices(devices)
-    if fused:
-        # the fused compute+exchange variant (REMOTE_DMA only —
-        # DistributedDomain validates loudly at realize())
-        dd.set_fused_exchange(True)
-    if kernel_variant == "persistent":
-        # the persistent whole-chunk variant (REMOTE_DMA only — realize()
-        # raises loudly otherwise): one radius*k exchange per k-step
-        # chunk, k = deep_halo (the radius the domain realized above)
-        dd.set_persistent_exchange(True)
     if wire_dtype:
         dd.set_wire_dtype(wire_dtype)
     if partition is not None:
@@ -269,14 +241,11 @@ def run(
 
     def get_loop(k: int):
         if k not in loops:
-            # the persistent chunk driver owns ALL call sizes (a 1-iter
-            # call is its depth-1 tail chunk); make_jacobi_step has no
-            # chunk schedule
             loops[k] = (
                 make_jacobi_loop(dd.halo_exchange, k, overlap=overlap,
                                  temporal_k=tk,
                                  multistep_rows=multistep_rows)
-                if k > 1 or kernel_variant == "persistent"
+                if k > 1
                 else make_jacobi_step(dd.halo_exchange, overlap=overlap)
             )
         return loops[k]
@@ -678,22 +647,6 @@ def main(argv: Optional[list] = None) -> int:
                         "exchange carriers narrow to this dtype (LOSSY — "
                         "halos round to the wire precision; "
                         "bench_exchange --wire-ab measures the error)")
-    p.add_argument("--fused", action="store_true",
-                   help="the fused compute+exchange variant of "
-                        "--method remote-dma: every per-direction copy "
-                        "starts boundary-first and interior compute hides "
-                        "the wire (ops/fused_stencil.py; "
-                        "fused.overlap_fraction in the metrics)")
-    p.add_argument("--kernel-variant", choices=["fused", "persistent"],
-                   default=None,
-                   help="REMOTE_DMA kernel variant (plan/ir.py vocabulary; "
-                        "an unknown value is rejected here, naming this "
-                        "set): 'fused' = the --fused overlap kernel; "
-                        "'persistent' = the whole-chunk temporal fusion "
-                        "(ops/persistent_stencil.py) — one radius*k "
-                        "exchange per k-step chunk with k = --deep-halo "
-                        "(>= 2 required), launch count O(chunks) not "
-                        "O(steps)")
     p.add_argument("--prefix", type=str, default="")
     p.add_argument("--cpu", type=int, default=0, help="force N virtual CPU devices")
     p.add_argument("--deep-halo", type=int, default=None,
@@ -717,9 +670,6 @@ def main(argv: Optional[list] = None) -> int:
     add_metrics_flags(p, dma=True)
     add_live_flags(p)
     args = p.parse_args(argv)
-    if args.fused and args.kernel_variant == "persistent":
-        p.error("--fused conflicts with --kernel-variant persistent "
-                "(mutually exclusive kernel variants)")
     try:
         canonicalize_live_config(args)
     except (OSError, ValueError) as e:
@@ -771,8 +721,6 @@ def main(argv: Optional[list] = None) -> int:
             rollback_backoff=args.rollback_backoff,
             inject=args.inject or None,
             wire_dtype=args.wire_dtype or None,
-            fused=args.fused,
-            kernel_variant=args.kernel_variant,
             sentinel=sentinel,
             status=status,
             replan=args.replan,

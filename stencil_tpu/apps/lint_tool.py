@@ -10,7 +10,7 @@ validate-nothing run must never read as a pass):
                   ``--changed`` restricts to ``git diff --name-only``
                   files (the fast pre-commit path).
 - ``verify-plan`` ExchangePlan-IR vs compiled-HLO conformance sweep
-                  (verify_plan.py): per-config census/byte/DMA
+                  (verify_plan.py): per-config census/byte
                   cross-checks; infeasible configs (plan/cost.feasible)
                   are skipped loudly, an all-skipped sweep exits 2.
 - ``jit-audit``   step-loop audit (jit_audit.py): transfer_guard +
@@ -205,8 +205,7 @@ def cmd_verify_plan(args) -> int:
     rec = _metrics(args, "lint_tool")
     res = vp.run_sweep(configs,
                        perturb_collectives=args.perturb_collectives,
-                       perturb_wire=args.perturb_wire,
-                       perturb_dmas=args.perturb_dmas, rec=rec)
+                       perturb_wire=args.perturb_wire, rec=rec)
     if getattr(args, "placements", 0):
         pres = vp.run_placement_sweep(
             count=args.placements, size=args.size, radius=args.radius,
@@ -368,7 +367,7 @@ def main(argv: Optional[list] = None) -> int:
         sp.add_argument("--partitions", default="2x2x2,1x2x4")
         sp.add_argument("--methods", default="",
                         help="comma-separated method subset (default: "
-                             "all four)")
+                             "all three)")
         sp.add_argument("--quantities", default="f32,f32+f32+f32,"
                                                 "f32+f32+f64",
                         help="comma-separated quantity groups, dtypes "
@@ -377,7 +376,6 @@ def main(argv: Optional[list] = None) -> int:
                         help="offset the IR's collective prediction "
                              "(the auditor must TRIP — CI's proof knob)")
         sp.add_argument("--perturb-wire", type=int, default=0)
-        sp.add_argument("--perturb-dmas", type=int, default=0)
         sp.add_argument("--placements", type=int, default=0,
                         help="ALSO audit N non-identity block placements "
                              "on the first partition: mesh device order "

@@ -115,14 +115,12 @@ def cmd_explain(args) -> int:
     print(f"static ranking ({len(ranked)} feasible candidates; "
           f"calibration: {cal_note}):")
     for cost, choice in ranked[: args.top]:
-        extra = (f" dmas={cost.dmas}" if choice.method == "remote-dma"
-                 else "")
         print(f"  {choice.label():45s} {cost.total_s * 1e3:9.3f} ms/step  "
-              f"permutes={cost.collectives} wire={cost.wire_bytes}{extra}")
+              f"permutes={cost.collectives} wire={cost.wire_bytes}")
     if args.method:
-        # explain one method's plan IR explicitly (e.g. remote-dma with
-        # its 0-ppermute census prediction, DMA count, and the
-        # wire_dtype-compressed byte model) instead of the ranked best
+        # explain one method's plan IR explicitly (e.g. direct26 with
+        # its census prediction and the wire_dtype-compressed byte
+        # model) instead of the ranked best
         best = next((ch for _c, ch in ranked if ch.method == args.method),
                     None)
         if best is None:
@@ -374,29 +372,6 @@ def cmd_autotune(args) -> int:
     for m in methods:
         if m not in METHODS:
             raise SystemExit(f"unknown method {m!r} (choose from {METHODS})")
-    # kernel variants to search (e.g. --variants fused pins the search
-    # to the fused compute+exchange candidates, --variants none to the
-    # unvariant programs only); default: the unvariant program plus,
-    # for remote-dma, the fused variant (cost.enumerate_candidates adds
-    # it). Validated like --methods — a typo'd variant must fail here,
-    # not land in the DB as a string no lowering recognizes.
-    from ..plan.cost import DEFAULT_VARIANTS
-    from ..plan.ir import FUSED_VARIANT, PERSISTENT_VARIANT
-
-    if args.variants:
-        variants = []
-        for t in (s.strip() for s in args.variants.split(",") if s.strip()):
-            if t == "none":
-                variants.append(None)
-            elif t in (FUSED_VARIANT, PERSISTENT_VARIANT):
-                variants.append(t)
-            else:
-                raise SystemExit(
-                    f"unknown kernel variant {t!r} (choose from "
-                    f"'{FUSED_VARIANT}', '{PERSISTENT_VARIANT}', 'none')")
-        variants = tuple(variants)
-    else:
-        variants = DEFAULT_VARIANTS
     ks = tuple(int(t) for t in args.ks.split(",") if t.strip()) or (1,)
     for k in ks:
         if k < 1:
@@ -407,7 +382,7 @@ def cmd_autotune(args) -> int:
         devices=jax.devices()[: args.ndev] if args.ndev else None,
         db_path=args.db or None, top_n=args.top_n,
         probe_iters=args.probe_iters, probe=not args.no_probe,
-        force=args.force, methods=methods, ks=ks, variants=variants,
+        force=args.force, methods=methods, ks=ks,
     )
     print(f"chosen: {res.choice.label()}")
     print(f"source: {res.source}  cache_hit: {res.cache_hit}  "
@@ -437,9 +412,8 @@ def main(argv: Optional[list] = None) -> int:
     sp.add_argument("--top", type=int, default=8)
     sp.add_argument("--method", default="",
                     choices=("",) + plandb.METHODS,
-                    help="dump THIS method's plan IR (e.g. remote-dma: "
-                         "0-ppermute prediction + DMA count) instead of "
-                         "the ranked best")
+                    help="dump THIS method's plan IR instead of the "
+                         "ranked best")
     sp.add_argument("--wire-dtype", default="",
                     help="render the plan's wire bytes under this "
                          "wire-compression dtype (e.g. bfloat16)")
@@ -513,21 +487,11 @@ def main(argv: Optional[list] = None) -> int:
                     help="re-tune through an existing DB entry")
     sp.add_argument("--methods", default="",
                     help="comma list restricting the searched exchange "
-                         "methods (e.g. 'remote-dma' to tune/persist a "
-                         "remote-dma-keyed entry); default: all")
-    sp.add_argument("--variants", default="",
-                    help="comma list restricting the searched kernel "
-                         "variants: 'fused' (the fused compute+exchange "
-                         "variant), 'persistent' (the whole-chunk "
-                         "mega-kernel; needs --ks depths >= 2) and/or "
-                         "'none' (the unvariant programs); default: the "
-                         "unvariant program + remote-dma's fused variant "
-                         "+ (when --ks reaches 2) its persistent "
-                         "variant")
+                         "methods (e.g. 'direct26' to tune/persist a "
+                         "direct26-keyed entry); default: all")
     sp.add_argument("--ks", default="1",
                     help="comma list of temporal multistep depths to "
-                         "search (deep-halo k; e.g. '1,2,4' lets the "
-                         "persistent whole-chunk variant compete)")
+                         "search (deep-halo k; e.g. '1,2,4')")
     _add_config_flags(sp)
     from ._bench_common import add_metrics_flags
 

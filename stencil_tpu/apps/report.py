@@ -83,16 +83,12 @@ def _agg_key(rec: dict) -> str:
     samples in one ab run — a folded p99 would describe neither leg)."""
     # ``wire`` splits the bf16/fp8-on-the-wire A/B (bench_exchange
     # --wire-ab): the compressed and native legs' timings/census differ
-    # by design. ``variant`` splits the kernel-variant legs the same way
-    # (the fused compute+exchange A/B: a fused.overlap_fraction or
-    # exchange.trimean_s folded across variants would describe neither).
-    # ``priority`` splits the serving daemon's per-class latency gauges
+    # by design. ``priority`` splits the serving daemon's per-class gauges
     # (serve.p99_ms): a folded p99 would average high and low lanes into
     # a number that describes neither class's SLO
     name = rec["name"]
     tags = [str(rec[t])
-            for t in ("method", "batched", "mode", "wire", "variant",
-                      "priority")
+            for t in ("method", "batched", "mode", "wire", "priority")
             if t in rec]
     if tags:
         return f"{name}[{','.join(tags)}]"

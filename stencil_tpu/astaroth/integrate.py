@@ -46,8 +46,7 @@ from .fd import field_data
 
 FIELDS = ("lnrho", "uux", "uuy", "uuz", "ax", "ay", "az", "entropy")
 
-# the fused-substep sliding-window vocabulary (ops/pallas_astaroth.py);
-# distinct from the exchange-plan kernel_variant ("fused"/"persistent")
+# the fused-substep sliding-window vocabulary (ops/pallas_astaroth.py)
 _VARIANTS = ("shift", "ring")
 
 
@@ -583,142 +582,6 @@ def make_astaroth_step(
                       swap_per_substep, use_overlap, use_dyn_overlap)
     return double_buffer.jit_in_place(scopes.ASTAROTH_ITER, fn, (like, like),
                                       (iters,))
-
-
-def make_fused_astaroth_loop(
-    ex: HaloExchange,
-    info: AcMeshInfo,
-    iters: int = 1,
-    dt: float = 1e-8,
-    use_pallas=None,
-    dtype="float32",
-    interpret: bool = False,
-    kernel_variant: str = None,
-):
-    """The FUSED REMOTE_DMA astaroth iteration (ROADMAP #5's 8-field
-    fold-in): ``loop(curr, out) -> (curr, out)`` over field dicts,
-    host-chunked like the jacobi fused path.
-
-    Same hoisted dataflow as :func:`make_astaroth_step`'s reference
-    swap-per-iteration overlap mode — substep 0's full-region pass reads
-    PRE-exchange data, the iteration's single exchange flies behind it,
-    substep 0's boundary shells re-integrate from the exchanged halos,
-    substeps 1-2 read post-exchange data — but the exchange is the fused
-    per-direction kernel-initiated schedule (``HaloExchange(fused=True)``;
-    astaroth's 6th-order cross-derivative pencils read edge halos, which
-    is exactly why the fused geometry is the 26-direction exact-extent
-    message set: every diagonal ships concurrently too). Zero
-    collective-permutes in every compiled piece; output bit-identical to
-    the composed overlap step (tests/test_fused_stencil.py).
-
-    On TPU the compute passes are the ring-indexed Pallas multistep
-    kernels (``kernel_variant="ring"`` — ops/pallas_astaroth.py) run
-    between the fused start/wait, so 8-field MHD overlaps the same way;
-    off-TPU the XLA region math runs. Uniform single-resident partitions
-    only (loud); the fused-into-one-kernel astaroth substep is the
-    hardware session's follow-up, staged behind probe_remote_dma.py."""
-    from ..parallel.exchange import Method
-
-    spec = ex.spec
-    r = spec.radius
-    _check_variant(kernel_variant)
-    if ex.method != Method.REMOTE_DMA or not getattr(ex, "fused", False):
-        raise ValueError(
-            "make_fused_astaroth_loop needs HaloExchange(Method.REMOTE_DMA,"
-            " fused=True)"
-        )
-    if min(r.x(-1), r.x(1), r.y(-1), r.y(1), r.z(-1), r.z(1)) < 3:
-        raise ValueError("astaroth needs face radius >= 3 (6th-order "
-                         "stencils; the fused path keeps inline halos)")
-    if not spec.is_uniform() or ex.oversubscribed:
-        raise ValueError(
-            "the fused astaroth loop takes uniform single-resident "
-            "partitions today (uneven/oversubscribed stay on the "
-            "composed paths)"
-        )
-    inv_ds = (
-        info.real_params["AC_inv_dsx"],
-        info.real_params["AC_inv_dsy"],
-        info.real_params["AC_inv_dsz"],
-    )
-    c = Constants.from_info(info)
-    off = spec.compute_offset()
-    compute = Rect3(off, off + spec.base)
-    interior = interior_region(compute, r)
-    exteriors = exterior_regions(compute, interior)
-    pallas_on = uses_pallas(ex, use_pallas, dtype)
-
-    if pallas_on:
-        from ..ops.pallas_astaroth import make_pallas_substep
-        from ..parallel.mesh import MESH_AXES
-
-        variant = kernel_variant or os.environ.get(
-            "STENCIL_ASTAROTH_VARIANT", "ring"
-        )
-        kernels = [
-            make_pallas_substep(
-                spec, c, inv_ds, s, dt,
-                vma=None if interpret else MESH_AXES,
-                interpret=interpret, variant=variant,
-            )
-            for s in range(3)
-        ]
-        p = spec.padded()
-
-        def full_body(s, curr, out):
-            vals = kernels[s](
-                tuple(curr[k].reshape(p.z, p.y, p.x) for k in FIELDS),
-                tuple(out[k].reshape(p.z, p.y, p.x) for k in FIELDS),
-            )
-            return {k: v.reshape(out[k].shape)
-                    for k, v in zip(FIELDS, vals)}
-    else:
-        def full_body(s, curr, out):
-            return _integrate_region(s, compute, inv_ds, c, dt, curr, out)
-
-    def shells_body(curr, out):
-        for rect in exteriors:
-            out = _integrate_region(0, rect, inv_ds, c, dt, curr, out)
-        return out
-
-    def _smap(fn):
-        return jax.jit(jax.shard_map(
-            fn, mesh=ex.mesh,
-            in_specs=(BLOCK_PSPEC, BLOCK_PSPEC),
-            out_specs=BLOCK_PSPEC, check_vma=not interpret,
-        ))
-
-    full_fns = [_smap(lambda cu, o, s=s: full_body(s, cu, o))
-                for s in range(3)]
-    shells_fn = _smap(shells_body)
-
-    def loop(curr, out):
-        from ..obs import telemetry
-        from ..parallel.remote_emu import run_fused_substep
-
-        rec = telemetry.get()
-        emu = ex._fused_host_schedule
-        t_interior = 0.0
-        t_total = 0.0
-        for _ in range(iters):
-            cur2, out, t_int, t_tot = run_fused_substep(
-                emu, curr,
-                interior=lambda: full_fns[0](curr, out),
-                boundary=lambda c2, o: shells_fn(c2, o),
-                rec=rec,
-            )
-            for s in (1, 2):
-                out = full_fns[s](cur2, out)
-            t_interior += t_int
-            t_total += t_tot
-            # one swap per iteration (astaroth.cu:642-648)
-            curr, out = out, cur2
-        if rec.enabled and t_total > 0:
-            rec.gauge("fused.overlap_fraction", t_interior / t_total,
-                      phase="exchange", variant="fused")
-        return curr, out
-
-    return loop
 
 
 def make_batched_astaroth_step(spec, info: AcMeshInfo, dt: float = 1e-8,

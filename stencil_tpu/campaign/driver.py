@@ -940,9 +940,7 @@ class CampaignDriver:
 def run_sequential(jobs: Sequence[TenantJob], *,
                    devices: Optional[Sequence] = None, radius: int = 1,
                    chunk: int = 2,
-                   cache: Optional[CompileCache] = None,
-                   kernel_variant: Optional[str] = None,
-                   temporal_k: Optional[int] = None) -> dict:
+                   cache: Optional[CompileCache] = None) -> dict:
     """Serve the same jobs one tenant at a time through the standard
     single-domain machinery (``DistributedDomain`` partitioned over ALL
     the given devices + ``make_jacobi_loop``): the honest baseline of
@@ -950,29 +948,12 @@ def run_sequential(jobs: Sequence[TenantJob], *,
     reused per shape bucket (sequential serving amortizes compiles too —
     the ratio measures batching, not compilation); timing covers the
     stepping loop, and per-chunk per-step latencies feed the same
-    p50/p99 statistics as the batched driver.
-
-    ``kernel_variant`` selects the REMOTE_DMA exchange variant for the
-    tenant domains — ``"fused"`` (overlap kernel) or ``"persistent"``
-    (whole-chunk temporal fusion, ops/persistent_stencil.py; needs
-    ``temporal_k >= 2`` — domains realize ``radius * temporal_k`` halos
-    and each compiled loop exchanges once per ``temporal_k``-step chunk,
-    the dispatch-dominated small-domain regime ROADMAP #7 targets)."""
+    p50/p99 statistics as the batched driver."""
     from ..api import DistributedDomain
     from ..ops.jacobi import make_jacobi_loop
-    from ..parallel import Method
     from ..parallel.exchange import shard_blocks
     from ..plan.ir import PlanConfig
 
-    if kernel_variant not in (None, "fused", "persistent"):
-        raise ValueError(
-            f"unknown kernel_variant {kernel_variant!r}: valid values "
-            "are 'fused' and 'persistent'")
-    if kernel_variant == "persistent" and (temporal_k is None
-                                           or temporal_k < 2):
-        raise ValueError(
-            "kernel_variant='persistent' needs temporal_k >= 2 (the "
-            f"chunk depth; got {temporal_k!r})")
     devices = list(devices) if devices is not None else jax.devices()
     cache = cache if cache is not None else CompileCache()
     rec = telemetry.get()
@@ -1003,17 +984,7 @@ def run_sequential(jobs: Sequence[TenantJob], *,
         x, y, z = size
         cells = x * y * z
         dd = DistributedDomain(x, y, z)
-        if kernel_variant == "persistent":
-            # deep-halo realize: radius*k exteriors feed each k-step chunk
-            dd.set_radius(radius * temporal_k)
-            dd.set_methods(Method.REMOTE_DMA)
-            dd.set_persistent_exchange(True)
-        elif kernel_variant == "fused":
-            dd.set_radius(radius)
-            dd.set_methods(Method.REMOTE_DMA)
-            dd.set_fused_exchange(True)
-        else:
-            dd.set_radius(radius)
+        dd.set_radius(radius)
         dd.set_devices(devices)
         h = dd.add_data(QUANTITY, dtype)
         dd.realize()
@@ -1027,11 +998,9 @@ def run_sequential(jobs: Sequence[TenantJob], *,
                             iters=int(k),
                             partition=[dd.spec.dim.x, dd.spec.dim.y,
                                        dd.spec.dim.z],
-                            devices=[d.id for d in devices],
-                            variant=kernel_variant or "")
+                            devices=[d.id for d in devices])
             return cache.get(
-                key, lambda: make_jacobi_loop(dd.halo_exchange, k,
-                                              temporal_k=temporal_k))
+                key, lambda: make_jacobi_loop(dd.halo_exchange, k))
 
         for job in by_bucket[bucket]:
             dd.set_curr_global(h, tenant_init_field(job))

@@ -3,7 +3,7 @@
 The predict→measure→refit loop's MEASURE third. The autotuner ranks
 plans with ``plan/cost.score`` — a prediction in seconds — and
 ``verify_plan`` audits the structural half of that prediction
-(collectives, bytes, DMAs) against the realized IR; what nobody checks
+(collectives, bytes) against the realized IR; what nobody checks
 is the seconds themselves. This module closes that gap per run: each
 timed exchange phase (the ``trace_range`` names of the host spans —
 "stencil.exchange_loop", …) becomes one ``plan.attrib.phase`` meta
@@ -22,10 +22,6 @@ formula ``perf_tool.evaluate_gate`` applies to ledger history, applied
 to a phase's measured samples with the prediction as the judged value —
 a stale calibration is a prediction that fell out of the band of what
 the fabric actually does.
-
-For remote-dma plans the ``collectives`` field carries the DMA count:
-cost.score prices per-copy overhead there, and the fit must see the
-count that multiplies the constant it is recovering.
 """
 
 from __future__ import annotations
@@ -34,7 +30,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence
 
 from ..plan import cost as plan_cost
-from ..plan.ir import REMOTE_DMA, PlanChoice, PlanConfig
+from ..plan.ir import PlanChoice, PlanConfig
 from .ledger import mad, trimean
 
 ATTRIB_NAME = "plan.attrib.phase"
@@ -52,7 +48,7 @@ class PhasePrediction:
 
     method: str
     predicted_s: float
-    collectives: int     # DMA count for remote-dma (per-copy pricing)
+    collectives: int
     wire_bytes: int
     provenance: str = "modeled(default)"
 
@@ -69,10 +65,9 @@ def predict_exchange(config: PlanConfig, choice: PlanChoice,
     prov = "modeled(default)"
     if calibration:
         prov = str(calibration.get("provenance", "override"))
-    n = c.dmas if choice.method == REMOTE_DMA else c.collectives
     return PhasePrediction(method=choice.method,
                            predicted_s=float(c.exchange_s),
-                           collectives=int(n),
+                           collectives=int(c.collectives),
                            wire_bytes=int(c.wire_bytes),
                            provenance=prov)
 

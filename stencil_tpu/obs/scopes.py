@@ -58,10 +58,8 @@ SCOPES: Dict[str, str] = {
     CARRY: LAYER_GLUE,
 }
 
-# pallas_call name -> layer. The self-fills, the split-x pack and unpack and
-# the remote-DMA carriers are the halo layer's kernels; a fused
-# exchange-and-sweep kernel is named as such and counted with the stencil
-# kernels.
+# pallas_call name -> layer. The self-fills and the split-x pack and unpack
+# are the halo layer's kernels.
 KERNELS: Dict[str, str] = {
     "jacobi_sweep": LAYER_KERNELS,
     "jacobi_multistep": LAYER_KERNELS,
@@ -78,15 +76,11 @@ KERNELS: Dict[str, str] = {
     "mg_coarse": LAYER_KERNELS,
     # D3Q19's stream-collide pass, Pallas or plain XLA (:func:`kernel_scope`)
     "lbm_d3q19": LAYER_KERNELS,
-    "fused_jacobi": LAYER_KERNELS,
-    "persistent_jacobi": LAYER_KERNELS,
     "self_fill_x": LAYER_HALO,
     "self_fill_y": LAYER_HALO,
     "self_fill_z": LAYER_HALO,
     "split_x_pack": LAYER_HALO,
     "split_x_unpack": LAYER_HALO,
-    "remote_dma": LAYER_HALO,
-    "fused_exchange": LAYER_HALO,
 }
 
 # module names of the jitted chunk loops (the trace's ``XLA Modules`` line

@@ -129,17 +129,6 @@ NAME_FIELDS = {
     # one (rejected — a throwing autotuner/apply must never kill a run)
     "replan.applied": (("old", str), ("new", str), ("step", int)),
     "replan.rejected": (("reason", str), ("step", int)),
-    # the fused compute+exchange vocabulary (ops/fused_stencil +
-    # the host-orchestrated fused loops in ops/jacobi /
-    # astaroth/integrate): the overlap split of one fused substep —
-    # pack+start, interior compute (the hiding window), the recv-
-    # semaphore wait, boundary compute — variant-tagged spans so the
-    # PR-12 live sentinel and the trace export see where wire time
-    # goes; no extra required fields beyond the span schema
-    "fused.pack": (),
-    "fused.interior": (),
-    "fused.dma_wait": (),
-    "fused.boundary": (),
     # the static-analysis vocabulary (stencil_tpu/analysis/): per-config
     # plan-auditor verdicts, the audit summaries the CI static gate
     # archives, and the lint summary — schema-gated like every other
@@ -156,9 +145,7 @@ NAME_FIELDS = {
     # back onto the ExchangePlan IR's prediction under the installed
     # calibration — the samples plan_tool calibrate fits and perf_tool
     # drift judges. `phase` is the trace_range name of the measured
-    # region; `collectives` carries the plan's collective count for the permute
-    # methods and its DMA count for remote-dma (the per-copy overhead
-    # is what the fit recovers there).
+    # region; `collectives` carries the plan's collective count.
     "plan.attrib.phase": (("phase", str), ("method", str),
                           ("predicted_s", float), ("measured_s", float),
                           ("residual", float), ("collectives", int),
@@ -328,12 +315,9 @@ KNOWN_NAMES = frozenset(NAME_FIELDS) | frozenset({
     "dma.capture_error", "dma.skipped",
     "exchange.bytes_logical", "exchange.bytes_moved",
     "exchange.bytes_on_wire", "exchange.bytes_on_wire_per_quantity",
-    "exchange.gb_per_s", "exchange.iter", "exchange.launches_per_chunk",
+    "exchange.gb_per_s", "exchange.iter",
     "exchange.permutes_per_quantity",
     "exchange.trimean_s", "exchange.warmup",
-    # interior-compute time over total fused-substep time: how much of
-    # the wire the fused schedule actually hid (gauge, variant-tagged)
-    "fused.overlap_fraction",
     "hb",
     "jacobi.exchange", "jacobi.exchange_bytes", "jacobi.exchange_warmup",
     "jacobi.init", "jacobi.iter", "jacobi.iter_trimean_s",
@@ -957,21 +941,6 @@ def record_exchange_truth(ex, state, itemsizes: Sequence[int],
     cp_count = census.get("collective-permute", (0, 0))[0]
     rec.gauge("exchange.permutes_per_quantity", cp_count / nq,
               phase="exchange", method=method, quantities=nq, **tags)
-    # launch-count census (ROADMAP #7): the step driver's measured host
-    # dispatches per chunk when a persistent/multistep loop ran
-    # (ops/jacobi sets last_launches_per_chunk), else the plan's static
-    # prediction — tagged so the auditor and the CI pin can tell a
-    # measurement from a model (utils/hlo_check.kernel_launch_census is
-    # the compiled-module side of the same evidence)
-    lpc = getattr(ex, "last_launches_per_chunk", 0)
-    src = "measured"
-    if not lpc:
-        plan = getattr(ex, "plan", None)
-        lpc = plan.launches_per_chunk() if plan is not None else 0
-        src = "modeled"
-    if lpc:
-        rec.gauge("exchange.launches_per_chunk", lpc, phase="exchange",
-                  method=method, source=src, **tags)
     rec.counter("exchange.bytes_logical", bytes=ex.bytes_logical(itemsizes),
                 phase="exchange", method=method, **tags)
     rec.counter("exchange.bytes_moved", bytes=ex.bytes_moved(itemsizes),

@@ -98,6 +98,12 @@ def make_iso3dfd_step(ex: HaloExchange, iters: int = 1, use_pallas=None,
     if ex.method != Method.AXIS_COMPOSED:
         raise ValueError("iso3dfd steps through Method.AXIS_COMPOSED")
     spec = ex.spec
+    if not spec.is_uniform():
+        # the sweep covers the base block: a shorter block's fixed ring
+        # lies inside it and would be overwritten
+        raise ValueError(
+            f"iso3dfd: blocks of {spec.sizes_x} x {spec.sizes_y} x "
+            f"{spec.sizes_z} cells: an uneven split is not supported")
     dtype = jnp.dtype(dtype)
     coeffs = coefficients()
     pallas_on = uses_pallas(ex, use_pallas, dtype)

@@ -365,290 +365,6 @@ def _compile_jacobi_auto(ex: HaloExchange, overlap: bool, iters,
     )
 
 
-def _compile_jacobi_fused(ex: HaloExchange, iters,
-                          temporal_k: Optional[int] = None,
-                          multistep_rows: Optional[int] = None,
-                          interpret: bool = False):
-    """The FUSED REMOTE_DMA iteration (ROADMAP #5): one substep =
-    pack boundary slabs → START every per-neighbor copy → interior
-    compute while the DMAs fly → wait → boundary compute.
-
-    On an all-TPU mesh with an aligned uniform spec, the whole substep
-    is ONE Pallas mega-kernel (ops/fused_stencil.make_fused_jacobi_kernel)
-    inside a shard_map'd ``fori_loop`` — wire time hides behind interior
-    FLOPs *inside* the kernel. Everywhere else (the CPU mesh, uneven
-    partitions) the SAME schedule runs host-orchestrated: the fused
-    emulation's start/wait/finish split
-    (parallel/remote_emu.FusedRemoteEmulation) brackets compiled
-    collective-free sweeps — the interior sweep dispatches while the
-    emulated copies fly, so the overlap is real wall-clock overlap, and
-    the step output is bit-identical to the AXIS_COMPOSED overlap step
-    (tests/test_fused_stencil.py pins it, wire compression included).
-
-    The host path narrates itself: ``fused.pack`` / ``fused.interior`` /
-    ``fused.dma_wait`` / ``fused.boundary`` spans (variant-tagged, so
-    report aggregation splits them per kernel variant) plus the
-    ``fused.overlap_fraction`` gauge — interior-compute time over total
-    substep time, the overlap split the PR-12 live sentinel and the
-    trace export see."""
-    spec = ex.spec
-    r = spec.radius
-    assert min(
-        r.x(-1), r.x(1), r.y(-1), r.y(1), r.z(-1), r.z(1)
-    ) >= 1, "jacobi needs face radius >= 1 on every side"
-    if temporal_k is not None or multistep_rows is not None:
-        from ..utils import logging as log
-
-        log.warn(
-            f"temporal_k={temporal_k} multistep_rows={multistep_rows} "
-            "ignored: the temporal multistep composes with in-step "
-            "ppermute exchanges; the FUSED path runs one fused "
-            "exchange+sweep substep per step"
-        )
-    off = spec.compute_offset()
-    compute = Rect3(off, off + spec.base)
-    interior = interior_region(compute, r)
-    exteriors = exterior_regions(compute, interior)
-    on_tpu = all(d.platform == "tpu" for d in ex.mesh.devices.flatten())
-
-    if on_tpu and spec.is_uniform() and spec.aligned and not interpret:
-        # the mega-kernel path: exchange+sweep in ONE pallas_call
-        from .fused_stencil import make_fused_jacobi_kernel
-
-        p = spec.padded()
-        kern = make_fused_jacobi_kernel(
-            spec, ex.plan, wire_dtype=ex.wire_dtype)
-
-        def body(curr, nxt, sel):
-            p3 = (p.z, p.y, p.x)
-            c2, out = kern(_reshape(curr, p3), _reshape(nxt, p3),
-                           _reshape(sel, p3))
-            return _reshape(out, curr.shape), _reshape(c2, curr.shape)
-
-        def entry_fn(curr, nxt, sel):
-            if iters is None:
-                return body(curr, nxt, sel)
-            return lax.fori_loop(
-                0, iters, lambda _, cn: body(cn[0], cn[1], sel),
-                (curr, nxt))
-
-        fn = jax.shard_map(
-            entry_fn, mesh=ex.mesh,
-            in_specs=(BLOCK_PSPEC,) * 3,
-            out_specs=(BLOCK_PSPEC, BLOCK_PSPEC),
-        )
-        return _jit_jacobi(ex, iters, fn, donate_argnums=(0, 1))
-
-    # host-orchestrated fused schedule: compiled collective-free sweeps
-    # slotted between the emulation's start/wait/finish
-    uniform = spec.is_uniform()
-
-    def interior_body(curr, nxt, sel):
-        masks = _masks(sel)
-        if uniform:
-            return jacobi_sweep(curr, nxt, interior, masks)
-        # uneven: full-region sweep on pre-exchange data (boundary
-        # cells re-swept from the exchanged state below)
-        return jacobi_sweep(curr, nxt, compute, masks)
-
-    def boundary_body(cur2, out, sel):
-        if uniform:
-            masks = _masks(sel)
-            with scopes.scope(scopes.SWEEP_SHELL):
-                for rect in exteriors:
-                    out = jacobi_sweep(cur2, out, rect, masks)
-            return out
-        return _patch_shells_dyn(spec, cur2, out, sel,
-                                 multi_block_only=False)
-
-    interior_fn = jax.jit(jax.shard_map(
-        interior_body, mesh=ex.mesh,
-        in_specs=(BLOCK_PSPEC,) * 3, out_specs=BLOCK_PSPEC))
-    boundary_fn = jax.jit(jax.shard_map(
-        boundary_body, mesh=ex.mesh,
-        in_specs=(BLOCK_PSPEC,) * 3, out_specs=BLOCK_PSPEC))
-
-    def loop(curr, nxt, sel):
-        from ..obs import telemetry
-        from ..parallel.remote_emu import run_fused_substep
-
-        rec = telemetry.get()
-        emu = ex._fused_host_schedule
-        t_interior = 0.0
-        t_total = 0.0
-        for _ in range(iters or 1):
-            cur2, out, t_int, t_tot = run_fused_substep(
-                emu, curr,
-                interior=lambda: interior_fn(curr, nxt, sel),
-                boundary=lambda c2, o: boundary_fn(c2, o, sel),
-                rec=rec,
-            )
-            t_interior += t_int
-            t_total += t_tot
-            curr, nxt = out, cur2  # the reference double-buffer swap
-        if rec.enabled and t_total > 0:
-            rec.gauge("fused.overlap_fraction", t_interior / t_total,
-                      phase="exchange", variant="fused")
-        return curr, nxt
-
-    return loop
-
-
-def _compile_jacobi_remote(ex: HaloExchange, iters,
-                           temporal_k: Optional[int] = None,
-                           multistep_rows: Optional[int] = None):
-    """The REMOTE_DMA iteration: the exchange is NOT a ppermute program
-    that can inline into the shard_map'd step — on TPU it is the carrier-
-    kernel program (ops/remote_dma.py), off-TPU the host-orchestrated
-    emulation (parallel/remote_emu.py) — so the step is a host-chunked
-    serialized loop: one compiled exchange dispatch + one compiled
-    collective-free sweep per iteration. Values are bit-identical to the
-    AXIS_COMPOSED paths (the exchange fills the same cells; the sweep is
-    the same shifted-slice program reading the same exchanged state —
-    tests/test_remote_dma.py pins the full step). Fusing the carrier
-    into the substep kernel itself (the §5.8 endgame) is the hardware
-    session's follow-up, staged behind scripts/probe_remote_dma.py."""
-    spec = ex.spec
-    r = spec.radius
-    assert min(
-        r.x(-1), r.x(1), r.y(-1), r.y(1), r.z(-1), r.z(1)
-    ) >= 1, "jacobi needs face radius >= 1 on every side"
-    if temporal_k is not None or multistep_rows is not None:
-        from ..utils import logging as log
-
-        log.warn(
-            f"temporal_k={temporal_k} multistep_rows={multistep_rows} "
-            "ignored: the temporal multistep composes with in-step "
-            "ppermute exchanges; the REMOTE_DMA path runs per-step "
-            "exchange + sweep dispatches"
-        )
-    off = spec.compute_offset()
-    compute = Rect3(off, off + spec.base)
-
-    def sweep_body(curr, nxt, sel):
-        masks = _masks(sel)
-        return jacobi_sweep(curr, nxt, compute, masks)
-
-    sweep = jax.jit(jax.shard_map(
-        sweep_body, mesh=ex.mesh,
-        in_specs=(BLOCK_PSPEC,) * 3, out_specs=BLOCK_PSPEC,
-    ))
-
-    def loop(curr, nxt, sel):
-        for _ in range(iters or 1):
-            curr = ex(curr)        # kernel-initiated / emulated exchange
-            out = sweep(curr, nxt, sel)
-            curr, nxt = out, curr  # the reference double-buffer swap
-        return curr, nxt
-
-    return loop
-
-
-def _compile_jacobi_persistent(ex: HaloExchange, iters,
-                               temporal_k: Optional[int] = None,
-                               multistep_rows: Optional[int] = None,
-                               interpret: bool = False):
-    """The PERSISTENT whole-chunk iteration (ROADMAP #7): one k-step
-    chunk = ONE deep (radius*k) exchange + ONE chunk program — k
-    substeps over shrinking grown regions with no further exchange
-    (ops/persistent_stencil.py owns the chunk math and the parity
-    argument). Launch count drops from O(steps) to O(chunks): 2 host
-    dispatches per chunk on the host-orchestrated schedule (the CPU
-    emulation, which the tests pin), ONE mega-kernel per chunk on
-    an all-TPU aligned uniform mesh (the in-kernel exchange; item-1
-    recalibrates the plan's conservative 2-dispatch model there).
-
-    The driver realizes the spec at radius*k (exactly the deep-halo
-    multistep opt-in, see the temporal-blocking comment below) and
-    passes ``temporal_k=k``; without it the depth defaults to the
-    realized min face radius. ``sel`` is exchanged ONCE per loop call
-    (step-invariant) so grown-region sweeps re-impose neighbor sphere
-    cells bit-identically.
-
-    The measured launch census lands in ``ex.last_launches_per_chunk``
-    after every loop call — what record_exchange_truth reports and
-    analysis/verify_plan.py audits against the plan's
-    ``launches_per_chunk`` prediction."""
-    from .persistent_stencil import (chunk_schedule,
-                                     make_persistent_chunk_body,
-                                     persistent_kernel_supported)
-
-    spec = ex.spec
-    r = spec.radius
-    rmin = min(r.x(-1), r.x(1), r.y(-1), r.y(1), r.z(-1), r.z(1))
-    if rmin < 1:
-        raise ValueError("jacobi needs face radius >= 1 on every side")
-    k = int(temporal_k) if temporal_k is not None else rmin
-    if k < 1:
-        raise ValueError(f"persistent temporal_k must be >= 1, got {k}")
-    if multistep_rows is not None:
-        from ..utils import logging as log
-
-        log.warn(
-            f"multistep_rows={multistep_rows} ignored: row-strip staging "
-            "is the composed multistep's knob; the persistent chunk "
-            "re-sweeps whole grown regions"
-        )
-    sched = chunk_schedule(iters or 1, k)
-    on_tpu = all(d.platform == "tpu" for d in ex.mesh.devices.flatten())
-    use_kernel = (on_tpu and spec.aligned and not interpret
-                  and persistent_kernel_supported(spec, ex.resident))
-    p = spec.padded()
-
-    # one compiled chunk program per distinct depth (a shallow tail
-    # chunk reuses the same machinery at its own depth)
-    chunk_fns = {}
-    kernel_depths = set()
-    for d in set(sched):
-        if use_kernel and d >= 2:
-            from .persistent_stencil import make_persistent_jacobi_kernel
-
-            kern = make_persistent_jacobi_kernel(spec, ex.plan, d)
-
-            def kbody(curr, nxt, sel, _kern=kern, _d=d):
-                c2, o2, _s2 = _kern(
-                    curr.reshape(p.z, p.y, p.x),
-                    nxt.reshape(p.z, p.y, p.x),
-                    sel.reshape(p.z, p.y, p.x),
-                )
-                fin, scr = (o2, c2) if _d % 2 else (c2, o2)
-                return fin.reshape(curr.shape), scr.reshape(curr.shape)
-
-            chunk_fns[d] = jax.jit(jax.shard_map(
-                kbody, mesh=ex.mesh,
-                in_specs=(BLOCK_PSPEC,) * 3,
-                out_specs=(BLOCK_PSPEC, BLOCK_PSPEC),
-            ))
-            kernel_depths.add(d)
-        else:
-            body = make_persistent_chunk_body(spec, d)
-            chunk_fns[d] = jax.jit(jax.shard_map(
-                body, mesh=ex.mesh,
-                in_specs=(BLOCK_PSPEC,) * 3,
-                out_specs=(BLOCK_PSPEC, BLOCK_PSPEC),
-            ))
-
-    def loop(curr, nxt, sel):
-        # sel halos once per loop call (step-invariant; excluded from
-        # the per-chunk census — a loop invariant, not a chunk cost)
-        sel2 = ex(sel)
-        launches = 0
-        for d in sched:
-            if d in kernel_depths:
-                # the mega-kernel exchanges in-kernel: ONE dispatch
-                out, scratch = chunk_fns[d](curr, nxt, sel2)
-                launches += 1
-            else:
-                curr = ex(curr)  # deep halo, once per chunk
-                out, scratch = chunk_fns[d](curr, nxt, sel2)
-                launches += 2
-            curr, nxt = out, scratch
-        ex.last_launches_per_chunk = launches // len(sched)
-        return curr, nxt
-
-    return loop
-
-
 def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
                     standard_spheres: bool = True, interpret: bool = False,
                     temporal_k: Optional[int] = None,
@@ -658,14 +374,6 @@ def _compile_jacobi(ex: HaloExchange, overlap: bool, iters, use_pallas=None,
     if ex.method == Method.AUTO_SPMD:
         return _compile_jacobi_auto(ex, overlap, iters, temporal_k,
                                     multistep_rows)
-    if ex.method == Method.REMOTE_DMA:
-        if getattr(ex, "persistent", False):
-            return _compile_jacobi_persistent(ex, iters, temporal_k,
-                                              multistep_rows, interpret)
-        if getattr(ex, "fused", False):
-            return _compile_jacobi_fused(ex, iters, temporal_k,
-                                         multistep_rows, interpret)
-        return _compile_jacobi_remote(ex, iters, temporal_k, multistep_rows)
     assert min(r.y(-1), r.y(1), r.z(-1), r.z(1)) >= 1, (
         "jacobi needs face radius >= 1 on every side"
     )

@@ -110,7 +110,7 @@ from jax.sharding import Mesh, NamedSharding
 from ..domain.grid import GridSpec
 from ..geometry import DIRECTIONS_26, Dim3, halo_extent
 from ..obs import scopes
-from ..plan.ir import build_plan, spec_axis as _spec_axis
+from ..plan.ir import RETIRED_METHODS, build_plan, spec_axis as _spec_axis
 from ..utils import timer
 from .mesh import AXIS_X, AXIS_Y, AXIS_Z, BLOCK_PSPEC, block_sharding, mesh_dim
 
@@ -121,17 +121,6 @@ class Method(enum.Enum):
     AXIS_COMPOSED = "axis-composed"
     DIRECT26 = "direct26"
     AUTO_SPMD = "auto-spmd"
-    # Kernel-initiated halo exchange (the reference's tx_colocated /
-    # ColocatedDirectAccessSender peer-access analogue, §5.8): boundary
-    # slabs move as per-neighbor async remote copies issued from INSIDE
-    # the kernel (pltpu.make_async_remote_copy), bypassing the XLA
-    # collective path — a compiled REMOTE_DMA exchange contains ZERO
-    # collective-permutes. On TPU the carrier kernel lives in
-    # ops/remote_dma.py; off-TPU a semantics-exact emulation
-    # (parallel/remote_emu.py) performs the same per-neighbor copies as
-    # host-initiated device-to-device transfers — bit-identical to
-    # AXIS_COMPOSED, still zero collectives in every compiled program.
-    REMOTE_DMA = "remote-dma"
 
 
 def direction_bytes(spec: GridSpec, direction, itemsize: int) -> int:
@@ -173,9 +162,13 @@ class HaloExchange:
 
     def __init__(self, spec: GridSpec, mesh: Mesh, method: Method = Method.AXIS_COMPOSED,
                  batch_quantities: bool = True, wire_dtype=None,
-                 fused: bool = False, persistent: bool = False,
                  periodic=(True, True, True), faces_only: bool = False,
                  quantity_radius=None):
+        if method in RETIRED_METHODS:
+            raise ValueError(
+                f"exchange method {method!r} is retired: the halo has one "
+                f"transport (choose from {[m.value for m in Method]})")
+        method = Method(method)
         md = mesh_dim(mesh)
         # oversubscription (reference: dd.set_gpus({0,0}), stencil.hpp:154,
         # test_exchange.cu:52): more partition blocks than devices — the
@@ -206,58 +199,6 @@ class HaloExchange:
         self.mesh = mesh
         self.method = method
         self.batch_quantities = bool(batch_quantities)
-        # the fused compute+exchange variant (ROADMAP #5): still
-        # REMOTE_DMA — kernel-initiated copies, zero ppermutes — but the
-        # transport is the concurrent per-direction schedule the fused
-        # substep kernels overlap compute behind (plan.fused_phases;
-        # ops/fused_stencil.py on TPU, the host-orchestrated
-        # FusedRemoteEmulation elsewhere). Single-resident only, loudly.
-        self.fused = bool(fused)
-        if self.fused:
-            if method != Method.REMOTE_DMA:
-                raise ValueError(
-                    "fused=True is the REMOTE_DMA fused compute+exchange "
-                    f"variant; got method {method}"
-                )
-            if self.resident != Dim3(1, 1, 1):
-                raise ValueError(
-                    "the fused compute+exchange variant supports "
-                    "single-resident partitions only (got resident "
-                    f"{self.resident}); use plain REMOTE_DMA or "
-                    "AXIS_COMPOSED for oversubscription"
-                )
-        # the persistent whole-chunk variant (ROADMAP #7): the EXCHANGE is
-        # the plain REMOTE_DMA slab transport at the deep radius*k the
-        # driver realized — what changes is the step structure (one
-        # exchange + ONE whole-chunk program per k-step chunk instead of
-        # per step; ops/persistent_stencil.py). The knob exists so the
-        # step compilers (ops/jacobi.py) dispatch the chunk loop and the
-        # plan carries the launches_per_chunk prediction.
-        self.persistent = bool(persistent)
-        if self.persistent:
-            if method != Method.REMOTE_DMA:
-                raise ValueError(
-                    "persistent=True is the REMOTE_DMA whole-chunk "
-                    f"kernel variant; got method {method}"
-                )
-            if self.fused:
-                raise ValueError(
-                    "fused and persistent are mutually exclusive kernel "
-                    "variants (the persistent chunk at k == 1 IS the "
-                    "fused substep)"
-                )
-            if self.resident != Dim3(1, 1, 1):
-                raise ValueError(
-                    "the persistent whole-chunk variant supports "
-                    "single-resident partitions only (got resident "
-                    f"{self.resident}); use plain REMOTE_DMA or "
-                    "AXIS_COMPOSED for oversubscription"
-                )
-        # launch census (satellite of ROADMAP #7): host-visible program
-        # dispatches of the last compiled step loop, per k-step chunk —
-        # set by the step compilers, audited against
-        # plan.launches_per_chunk (analysis/verify_plan.py)
-        self.last_launches_per_chunk: int = 0
         # bf16-on-the-wire halo compression: wire-crossing packed
         # carriers narrow to this dtype before the send and widen on
         # unpack (ops/halo_fill.wire_narrow_dtype owns the policy: only
@@ -304,8 +245,7 @@ class HaloExchange:
         return build_plan(
             self.spec, mesh_dim(self.mesh), self.method,
             batch_quantities=self.batch_quantities, resident=self.resident,
-            wire_dtype=self.wire_dtype, fused=self.fused,
-            persistent=self.persistent, periodic=self.periodic,
+            wire_dtype=self.wire_dtype, periodic=self.periodic,
             faces_only=self.faces_only,
             quantity_radius=self.quantity_radius,
         )
@@ -328,14 +268,6 @@ class HaloExchange:
                 "collectives are synthesized by the SPMD partitioner from "
                 "the global program (use __call__/make_loop/auto_fill, or a "
                 "manual method for shard_map composition)"
-            )
-        if self.method == Method.REMOTE_DMA:
-            raise RuntimeError(
-                "Method.REMOTE_DMA has no ppermute-style per-block body: "
-                "on TPU the carrier kernel owns the whole phase "
-                "(ops/remote_dma.py), and the CPU emulation is "
-                "host-orchestrated (use __call__/make_loop, or a manual "
-                "ppermute method for shard_map composition)"
             )
         if self.method == Method.DIRECT26:
             if axes is not None:
@@ -397,9 +329,9 @@ class HaloExchange:
         self-wrap axes take a packed slab fill: one fused slice/update
         pair per phase for the group (the fp64 analogue of the fused
         fills; ROADMAP #5)."""
-        if self.method in (Method.AUTO_SPMD, Method.REMOTE_DMA):
+        if self.method == Method.AUTO_SPMD:
             raise RuntimeError(
-                f"Method.{self.method.name} has no per-block exchange body "
+                "Method.AUTO_SPMD has no per-block exchange body "
                 "(see exchange_block); use __call__/make_loop instead"
             )
         if not isinstance(state, dict):
@@ -537,58 +469,7 @@ class HaloExchange:
         return cache[key]
 
     @cached_property
-    def _remote(self):
-        """The REMOTE_DMA transport: the Pallas carrier kernels on an
-        all-TPU mesh (ops/remote_dma.py — pltpu.make_async_remote_copy
-        from inside the kernel), the semantics-exact host-orchestrated
-        emulation everywhere else (parallel/remote_emu.py). Both are
-        callables over the state pytree; both compile ZERO collectives.
-        With ``fused`` the transport is the concurrent per-direction
-        schedule instead (ops/fused_stencil.FusedRemoteDmaExchange on
-        TPU; FusedRemoteEmulation off it) — same zero-collective pin,
-        plus the start/wait split the fused step loops overlap compute
-        behind."""
-        assert self.method == Method.REMOTE_DMA
-        if self._on_tpu():
-            if self.fused:
-                from ..ops.fused_stencil import FusedRemoteDmaExchange
-
-                return FusedRemoteDmaExchange(self)
-            from ..ops.remote_dma import RemoteDmaExchange
-
-            return RemoteDmaExchange(self)
-        if self.fused:
-            from .remote_emu import FusedRemoteEmulation
-
-            return FusedRemoteEmulation(self)
-        from .remote_emu import RemoteDmaEmulation
-
-        return RemoteDmaEmulation(self)
-
-    @cached_property
-    def _fused_host_schedule(self):
-        """The host-orchestrated start/wait/finish split of the fused
-        schedule — what the fused STEP loops bracket their compiled
-        sweeps with when the substep is not one mega-kernel. Off-TPU
-        this IS :attr:`_remote` (the FusedRemoteEmulation); on a TPU
-        mesh :attr:`_remote` is the carrier-kernel transport
-        (FusedRemoteDmaExchange — one kernel, no host-visible split),
-        so the loops get a separate host-orchestrated instance whose
-        ``device_put``s ride between the TPU devices. Requires
-        ``fused=True``."""
-        if not self.fused:
-            raise RuntimeError(
-                "_fused_host_schedule requires HaloExchange(fused=True)")
-        from .remote_emu import FusedRemoteEmulation
-
-        if not self._on_tpu():
-            return self._remote
-        return FusedRemoteEmulation(self)
-
-    @cached_property
     def _compiled(self):
-        if self.method == Method.REMOTE_DMA:
-            return self._remote
         if self.method == Method.AUTO_SPMD:
             sh = self.sharding()
             return jax.jit(
@@ -623,9 +504,6 @@ class HaloExchange:
             # flight-recorder bucket; jax.profiler sees the same range)
             with timer.timed("exchange.build"), \
                     timer.trace_range(f"exchange.{self.method.value}.build"):
-                if self.method == Method.REMOTE_DMA:
-                    cache[iters] = self._remote.make_loop(iters)
-                    return cache[iters]
                 if self.method == Method.AUTO_SPMD:
                     def many(state):
                         return lax.fori_loop(
@@ -664,12 +542,6 @@ class HaloExchange:
 
         with timer.timed("exchange.census"), \
                 timer.trace_range(f"exchange.{self.method.value}.census"):
-            if self.method == Method.REMOTE_DMA:
-                # no single jitted program exists: the transport censuses
-                # EVERY compiled piece of one exchange (pack/update jits
-                # of the emulation; the carrier-kernel program on TPU) —
-                # the 0-ppermute claim is over everything that compiles
-                return self._remote.collective_census(state)
             txt = self._compiled.lower(state).compile().as_text()
             return collective_census(txt)
 

@@ -30,7 +30,6 @@ from .ir import (
     ExchangePlan,
     PlanChoice,
     PlanConfig,
-    RemoteDmaPhaseIR,
     build_plan,
     validate_placement,
 )
@@ -41,7 +40,6 @@ __all__ = [
     "ExchangePlan",
     "PlanChoice",
     "PlanConfig",
-    "RemoteDmaPhaseIR",
     "build_plan",
     "validate_placement",
 ]

@@ -25,7 +25,7 @@ from typing import List, Optional, Sequence, Tuple
 from ..geometry import Dim3, Radius
 from ..utils import logging as log
 from . import db as plandb
-from .cost import DEFAULT_VARIANTS, enumerate_candidates, rank
+from .cost import enumerate_candidates, rank
 from .ir import METHODS, PlanChoice, PlanConfig
 
 
@@ -74,7 +74,6 @@ def autotune(
     force: bool = False,
     methods: Sequence[str] = METHODS,
     ks: Sequence[int] = (1,),
-    variants: Sequence[Optional[str]] = DEFAULT_VARIANTS,
     calibration: Optional[dict] = None,
     link_costs=None,
     rec=None,
@@ -157,7 +156,7 @@ def autotune(
 
     with rec.span("plan.autotune", phase="plan"):
         candidates = enumerate_candidates(config, methods=methods,
-                                          ks=ks, variants=variants,
+                                          ks=ks,
                                           link_costs=link_costs)
         ranked = rank(config, candidates, calibration,
                       link_costs=link_costs)

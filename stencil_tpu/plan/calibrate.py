@@ -9,13 +9,6 @@ cost model's own linear form (plan/cost.score's permute branch):
 
     measured_s  ≈  overhead[method] * collectives  +  wire_bytes / bw
 
-For ``remote-dma`` samples the ``collectives`` field carries the plan's
-DMA count (cost.score prices per-copy overhead there), so the same
-design matrix recovers the per-copy constant; on a cpu-platform fit it
-lands in ``remote_dma.cpu_emulation_overhead_s``, on tpu in
-``remote_dma.dma_overhead_s`` — the platform split score() already
-prices.
-
 Pure stdlib by design (normal equations + Gaussian elimination on a
 handful of unknowns): a calibrate run must work backend-less, exactly
 like ``plan_tool show``. Degenerate input is refused loudly
@@ -40,10 +33,9 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from .cost import DEFAULT_CALIBRATION
-from .ir import AUTO_SPMD, AXIS_COMPOSED, DIRECT26, METHODS, REMOTE_DMA
+from .ir import METHODS
 
 ATTRIB_NAME = "plan.attrib.phase"
-PERMUTE_METHODS = (AXIS_COMPOSED, DIRECT26, AUTO_SPMD)
 
 
 class CalibrationError(ValueError):
@@ -55,7 +47,7 @@ class Sample:
     """One measured attribution point (one ``plan.attrib.phase`` record)."""
 
     method: str
-    collectives: int      # permute count, or DMA count for remote-dma
+    collectives: int      # permute count
     wire_bytes: int
     measured_s: float
     phase: str = ""
@@ -206,7 +198,7 @@ def fit(samples: Sequence[Sample], *, platform: str = "cpu",
             raise CalibrationError(f"bad sample: {err}")
         if s.collectives == 0:
             raise CalibrationError(
-                f"sample for {s.method} has 0 collectives/DMAs — its "
+                f"sample for {s.method} has 0 collectives — its "
                 "overhead column is unidentifiable")
     base = base or DEFAULT_CALIBRATION
     base_bw = float(base.get("wire_bytes_per_s",
@@ -248,16 +240,9 @@ def fit(samples: Sequence[Sample], *, platform: str = "cpu",
     r2 = _r2(predicted, [s.measured_s for s in samples])
 
     cal: Dict[str, object] = {}
-    permute = {m: overheads[m] for m in methods if m in PERMUTE_METHODS}
-    if permute:
-        cal["permute_overhead_s"] = permute
+    cal["permute_overhead_s"] = dict(overheads)
     n = len(samples)
     prov = provenance_string(n, r2)
-    if REMOTE_DMA in overheads:
-        key = ("dma_overhead_s" if platform == "tpu"
-               else "cpu_emulation_overhead_s")
-        cal["remote_dma"] = {key: overheads[REMOTE_DMA],
-                             "provenance": prov}
     if bandwidth_fit:
         cal["wire_bytes_per_s"] = wire_bps
     cal["provenance"] = prov
@@ -282,11 +267,6 @@ def diff_rows(fitted: dict, base: Optional[dict] = None
     for m, v in sorted((cal.get("permute_overhead_s") or {}).items()):
         out.append((f"permute_overhead_s[{m}]", float(v),
                     float(base["permute_overhead_s"].get(m, float("nan")))))
-    rd = cal.get("remote_dma") or {}
-    for k in ("dma_overhead_s", "cpu_emulation_overhead_s"):
-        if k in rd:
-            out.append((f"remote_dma.{k}", float(rd[k]),
-                        float(base["remote_dma"][k])))
     if "wire_bytes_per_s" in cal:
         out.append(("wire_bytes_per_s", float(cal["wire_bytes_per_s"]),
                     float(base["wire_bytes_per_s"])))

@@ -43,10 +43,7 @@ from .ir import (
     AUTO_SPMD,
     AXIS_COMPOSED,
     DIRECT26,
-    FUSED_VARIANT,
     METHODS,
-    PERSISTENT_VARIANT,
-    REMOTE_DMA,
     PlanChoice,
     PlanConfig,
     build_plan,
@@ -68,54 +65,6 @@ DEFAULT_CALIBRATION: Dict[str, object] = {
     # relative compute factor per kernel variant (unknown -> 1.0: the
     # static model deliberately ties variants and lets the probes decide)
     "variant_factor": {},
-    # Method.REMOTE_DMA: kernel-initiated per-neighbor async copies
-    # bypass the XLA collective path entirely (0 ppermutes). Provenance:
-    # MODELED, pending the item-1 TPU recalibration session — no ICI
-    # measurement of this transport exists yet. dma_overhead_s is the
-    # modeled per-copy issue+sync cost on TPU (the whole point of the
-    # method: a fraction of a ppermute's ~0.66 ms dispatch);
-    # cpu_emulation_overhead_s prices the CPU lowering honestly — each
-    # emulated copy is a host-orchestrated device_put round-trip, so on
-    # a cpu-platform config REMOTE_DMA ranks BELOW the ppermute methods
-    # (the probes confirm; on tpu configs the model lets it compete).
-    "remote_dma": {
-        "dma_overhead_s": 8.0e-5,
-        "cpu_emulation_overhead_s": 4.0e-3,
-        "wire_bytes_per_s": 3.9e8,
-        "provenance": "modeled, pending item-1 TPU recalibration",
-    },
-    # The fused compute+exchange mega-kernel (kernel_variant == "fused"
-    # on a REMOTE_DMA choice): the substep's wall-clock is
-    # max(interior_compute, dma) + boundary_compute — wire time hides
-    # behind interior FLOPs. Scored against candidates whose totals omit
-    # the (common) sweep compute, the fused EXCHANGE-attributable cost is
-    # that expression minus the full sweep: per-copy issue overhead plus
-    # only the UNHIDDEN wire time, max(0, dma - interior_compute).
-    # Provenance: MODELED, pending the item-1 TPU session — no silicon
-    # measurement of the overlap exists yet; probe_remote_dma.py's fused
-    # leg is the measurement that flips this to measured.
-    "fused": {
-        "provenance": "modeled, pending item-1 TPU recalibration",
-    },
-    # The persistent whole-chunk mega-kernel (kernel_variant ==
-    # "persistent" on a REMOTE_DMA choice, multistep_k >= 2): one kernel
-    # launch executes the whole k-step chunk behind a single deep-halo
-    # (radius*k) exchange, so the chunk pays 2 program launches instead
-    # of the per-step lowering's 2k (plan/ir.ExchangePlan.
-    # launches_per_chunk — the same figure the launch census pins). The
-    # per-launch constants below price that saving: launch_overhead_s is
-    # the modeled TPU kernel-dispatch floor; cpu_dispatch_s is the
-    # host-orchestrated emulation's jit-call round-trip, priced honestly
-    # so persistent never wins a cpu ranking on a TPU-modeled constant.
-    # The redundant-compute side of the trade is the shared k>1
-    # shrinking-shell term below (cell_update_s). Provenance: MODELED,
-    # pending the item-1 TPU session — scripts/probe_persistent.py is the
-    # measurement that flips this to measured.
-    "persistent": {
-        "launch_overhead_s": 5.0e-6,
-        "cpu_dispatch_s": 2.0e-4,
-        "provenance": "modeled, pending item-1 TPU recalibration",
-    },
 }
 
 
@@ -129,7 +78,6 @@ class PlanCost:
     wire_bytes: int         # estimated interconnect bytes per exchange
     local_bytes: int        # estimated local slab bytes per exchange
     compute_overhead_s: float  # multistep redundant-compute price per step
-    dmas: int = 0           # kernel-initiated async copies (REMOTE_DMA only)
 
     def to_json(self) -> dict:
         return {
@@ -139,7 +87,6 @@ class PlanCost:
             "wire_bytes": self.wire_bytes,
             "local_bytes": self.local_bytes,
             "compute_overhead_s": self.compute_overhead_s,
-            "dmas": self.dmas,
         }
 
 
@@ -212,7 +159,7 @@ def placement_wire_matrix(spec: GridSpec, mesh_dim,
     return m
 
 
-# rank() scores every (method x batching x k x variant) candidate of a
+# rank() scores every (method x batching x k) candidate of a
 # partition, and each placed one needs the SAME wire matrix — a pure-
 # Python O(blocks x 26) halo_extent sweep that must not be rebuilt per
 # candidate (nor per between-chunk replan retune). Bounded: the key
@@ -313,33 +260,11 @@ def feasible(config: PlanConfig, choice: PlanChoice) -> Optional[Tuple]:
     the effective radius — for a multistep choice that radius is
     ``radius * k``, so a deep-halo depth whose staging would exceed a
     block's interior extent (a negative valid strip) is refused HERE,
-    before any kernel is planned. The fused compute+exchange variant is
-    a REMOTE_DMA-only, single-resident, k == 1 lowering; the persistent
-    whole-chunk variant is REMOTE_DMA-only, single-resident, k >= 2 —
-    any other combination is infeasible here (the loud-infeasibility
-    contract: realize() raises the same constraints). A ``placement`` must be a permutation of the
-    config's ``ndev`` mesh positions (plan/ir.validate_placement — the
-    same check realize() raises on)."""
+    before any kernel is planned. A ``placement`` must be a permutation
+    of the config's ``ndev`` mesh positions (plan/ir.validate_placement —
+    the same check realize() raises on)."""
     if validate_placement(choice.placement, config.ndev) is not None:
         return None
-    if choice.kernel_variant == FUSED_VARIANT:
-        if choice.method != REMOTE_DMA:
-            return None
-        if choice.multistep_k != 1:
-            # the fused lowering runs ONE fused exchange per step and
-            # ignores temporal_k (ops/jacobi._compile_jacobi_fused warns
-            # and proceeds per-step) — scoring k>1 would amortize an
-            # exchange the realized program pays every step
-            return None
-    if choice.kernel_variant == PERSISTENT_VARIANT:
-        if choice.method != REMOTE_DMA:
-            return None
-        if choice.multistep_k < 2:
-            # persistent IS communication-avoiding temporal fusion: the
-            # chunk depth is multistep_k, and at k == 1 the whole-chunk
-            # kernel degenerates to the fused per-step kernel — scoring
-            # it would duplicate that point under a second label
-            return None
     dim = Dim3.of(choice.partition)
     g = Dim3.of(config.grid)
     if g.x < dim.x or g.y < dim.y or g.z < dim.z:
@@ -369,11 +294,6 @@ def feasible(config: PlanConfig, choice: PlanChoice) -> Optional[Tuple]:
             return None  # halo would span multiple blocks
     resident = Dim3(dim.x // mesh_dim.x, dim.y // mesh_dim.y,
                     dim.z // mesh_dim.z)
-    if choice.kernel_variant == FUSED_VARIANT and resident != Dim3(1, 1, 1):
-        return None  # the fused kernel is single-resident (build_plan raises)
-    if (choice.kernel_variant == PERSISTENT_VARIANT
-            and resident != Dim3(1, 1, 1)):
-        return None  # the persistent kernel is single-resident too
     return spec, mesh_dim, resident
 
 
@@ -409,19 +329,15 @@ def score(config: PlanConfig, choice: PlanChoice,
     if feas is None:
         return None
     spec, mesh_dim, resident = feas
-    fused = choice.kernel_variant == FUSED_VARIANT
-    persistent = choice.kernel_variant == PERSISTENT_VARIANT
     plan = build_plan(spec, mesh_dim, choice.method,
                       batch_quantities=choice.batch_quantities,
-                      resident=resident, fused=fused,
-                      persistent=persistent)
+                      resident=resident)
     itemsizes = config.itemsizes()
     nq = config.num_quantities
     ngroups = config.dtype_group_count
     collectives = plan.collectives_per_exchange(nq, ngroups)
     wire = plan.wire_bytes(itemsizes, floating=config.floating_flags())
     local = plan.local_bytes(itemsizes)
-    dmas = plan.dmas_per_exchange(nq, ngroups)
     # placement pricing: wire time scales by the QAP cost ratio vs the
     # identity assignment (1.0 when no link costs are known, when the
     # links are uniform, or when nothing crosses the wire)
@@ -431,83 +347,12 @@ def score(config: PlanConfig, choice: PlanChoice,
         base = placement_cost(w, link_costs)
         if base > 0:
             pratio = placement_cost(w, link_costs, choice.placement) / base
-    # REMOTE_DMA-family launch economics: the per-step lowering pays 2
-    # program launches per substep (exchange + sweep), the persistent
-    # whole-chunk kernel pays 2 per CHUNK — plan.launches_per_chunk(k)
-    # is that prediction (the launch census audits it), and the
-    # per-launch constant is platform-split like the per-copy one.
-    # The permute methods compile the chunk into one XLA program whose
-    # dispatch cost is already inside their measured permute constants,
-    # so no launch term applies there (launches_per_chunk == 1).
-    launch_s = 0.0
-    if choice.method == REMOTE_DMA:
-        ps = cal["persistent"]
-        per_launch = (ps["launch_overhead_s"] if config.platform == "tpu"
-                      else ps["cpu_dispatch_s"])
-        launch_s = plan.launches_per_chunk(choice.multistep_k) * per_launch
-    if fused:
-        # overlap-aware: the fused substep runs
-        #   max(interior_compute, dma) + boundary_compute
-        # — wire time hides behind interior FLOPs. Candidates' totals
-        # omit the common full-sweep compute, so the fused cost charged
-        # here is that expression minus (interior + boundary): the
-        # per-copy issue overhead plus only the UNHIDDEN wire time.
-        # Per-copy overhead stays platform-split like plain REMOTE_DMA
-        # (the CPU schedule is host-orchestrated and must never win a
-        # cpu ranking on a TPU-modeled constant); provenance of all of
-        # it is cal["fused"]["provenance"] — MODELED until item 1's
-        # TPU session runs probe_remote_dma.py's fused leg.
-        rd = cal["remote_dma"]
-        per_dma = (rd["dma_overhead_s"] if config.platform == "tpu"
-                   else rd["cpu_emulation_overhead_s"])
-        wire_s = (wire / rd.get("wire_bytes_per_s", cal["wire_bytes_per_s"])
-                  * pratio)
-        b = spec.base
-        r0 = config.radius_obj()
-        shrink = [
-            (rm + rp) if n > 1 else 0
-            for n, rm, rp in (
-                (mesh_dim.x, r0.x(-1), r0.x(1)),
-                (mesh_dim.y, r0.y(-1), r0.y(1)),
-                (mesh_dim.z, r0.z(-1), r0.z(1)),
-            )
-        ]
-        interior_cells = (max(0, b.x - shrink[0]) * max(0, b.y - shrink[1])
-                          * max(0, b.z - shrink[2]))
-        interior_s = interior_cells * nq * cal["cell_update_s"]
-        exchange_s = (
-            dmas * per_dma
-            + max(0.0, wire_s - interior_s)
-            + local / cal["local_bytes_per_s"]
-            + launch_s
-        )
-    elif choice.method == REMOTE_DMA:
-        # kernel-initiated copies: no ppermute dispatch at all; the
-        # per-copy cost is platform-dependent (the CPU lowering is a
-        # host-orchestrated emulation and must never win a cpu ranking
-        # on the strength of a TPU-modeled constant)
-        rd = cal["remote_dma"]
-        per_dma = (rd["dma_overhead_s"] if config.platform == "tpu"
-                   else rd["cpu_emulation_overhead_s"])
-        # the persistent whole-chunk variant shares this branch: its wire
-        # model IS the deep-halo composed slab program (same dmas, same
-        # bytes), and its whole advantage is the launch term — 2 per
-        # chunk instead of 2k — plus the /k exchange amortization below;
-        # its price is the shared k>1 redundant-compute term
-        exchange_s = (
-            dmas * per_dma
-            + (wire / rd.get("wire_bytes_per_s", cal["wire_bytes_per_s"])
-               * pratio)
-            + local / cal["local_bytes_per_s"]
-            + launch_s
-        )
-    else:
-        overhead = cal["permute_overhead_s"][choice.method]
-        exchange_s = (
-            collectives * overhead
-            + wire / cal["wire_bytes_per_s"] * pratio
-            + local / cal["local_bytes_per_s"]
-        )
+    overhead = cal["permute_overhead_s"][choice.method]
+    exchange_s = (
+        collectives * overhead
+        + wire / cal["wire_bytes_per_s"] * pratio
+        + local / cal["local_bytes_per_s"]
+    )
     k = choice.multistep_k
     compute_overhead_s = 0.0
     if k > 1:
@@ -527,7 +372,7 @@ def score(config: PlanConfig, choice: PlanChoice,
     return PlanCost(
         total_s=total, exchange_s=exchange_s, collectives=collectives,
         wire_bytes=wire, local_bytes=local,
-        compute_overhead_s=compute_overhead_s, dmas=dmas,
+        compute_overhead_s=compute_overhead_s,
     )
 
 
@@ -550,37 +395,19 @@ def candidate_partitions(config: PlanConfig,
     return out
 
 
-# The default kernel-variant set, as an identity-comparable sentinel:
-# enumerate_candidates() grows it with REMOTE_DMA's fused and persistent
-# variants, while any EXPLICITLY passed variant list — (None,) included —
-# is honored verbatim (plan_tool --variants none tunes plain remote-dma
-# only).
-DEFAULT_VARIANTS: Tuple[Optional[str], ...] = (None,)
-
-
 def enumerate_candidates(
     config: PlanConfig,
     methods: Iterable[str] = METHODS,
     batch_options: Iterable[bool] = (True, False),
     ks: Iterable[int] = (1,),
-    variants: Iterable[Optional[str]] = DEFAULT_VARIANTS,
     oversubscribe: Sequence[int] = (1,),
     link_costs=None,
 ) -> List[PlanChoice]:
     """The search space: partition shape x method x quantity batching x
-    temporal depth k x kernel variant x block placement. Batching only
+    temporal depth k x block placement. Batching only
     branches when the config has more than one quantity (at Q=1 the two
-    programs are identical — PR 5's degeneration contract). With the
-    DEFAULT variant set, REMOTE_DMA additionally branches on the fused
-    compute+exchange variant (kernel_variant == "fused") and — whenever
-    ``ks`` reaches depth 2 — the persistent whole-chunk variant
-    (kernel_variant == "persistent") so the autotuner searches both the
-    overlap and the temporal-fusion levers out of the box; an EXPLICIT
-    ``variants`` restriction — ``(None,)`` included — is honored
-    verbatim (the sentinel comparison is by identity with
-    :data:`DEFAULT_VARIANTS`). Infeasible variant points (oversubscribed
-    partitions, fused at k > 1, persistent at k < 2) fall out at score()
-    like every other constraint.
+    programs are identical — PR 5's degeneration contract). Infeasible
+    points fall out at score() like every other constraint.
 
     With ``link_costs`` (non-uniform), every single-resident partition
     additionally branches on its QAP-solved placement
@@ -591,8 +418,7 @@ def enumerate_candidates(
     byte-identical to the pre-placement one."""
     if config.num_quantities <= 1:
         batch_options = (True,)
-    default_variants = variants is DEFAULT_VARIANTS
-    ks = tuple(ks)  # consumed once per method below, plus the k>=2 probe
+    ks = tuple(ks)  # consumed once per method below
     feas_by_part: Dict[Tuple[int, int, int], Optional[Tuple]] = {}
     placements_by_part: Dict[Tuple[int, int, int],
                              Optional[Tuple[int, ...]]] = {}
@@ -620,16 +446,6 @@ def enumerate_candidates(
                     placements_by_part[part] = solve_placement(w, link_costs)
         return placements_by_part[part]
 
-    def variant_list(method) -> List[Optional[str]]:
-        vlist = list(variants)
-        if method == REMOTE_DMA and default_variants:
-            if FUSED_VARIANT not in vlist:
-                vlist.append(FUSED_VARIANT)
-            if (PERSISTENT_VARIANT not in vlist
-                    and any(k >= 2 for k in ks)):
-                vlist.append(PERSISTENT_VARIANT)
-        return vlist
-
     out = []
     for part in candidate_partitions(config, oversubscribe):
         placements: Tuple[Optional[Tuple[int, ...]], ...] = (None,)
@@ -637,17 +453,14 @@ def enumerate_candidates(
         if placed is not None:
             placements = (None, placed)
         for method in methods:
-            vlist = variant_list(method)
             for batch in batch_options:
                 for k in ks:
-                    for variant in vlist:
-                        for placement in placements:
-                            out.append(PlanChoice(
-                                partition=part, method=method,
-                                batch_quantities=batch, multistep_k=k,
-                                kernel_variant=variant,
-                                placement=placement,
-                            ))
+                    for placement in placements:
+                        out.append(PlanChoice(
+                            partition=part, method=method,
+                            batch_quantities=batch, multistep_k=k,
+                            placement=placement,
+                        ))
     return out
 
 

@@ -77,7 +77,8 @@ def make_entry(config: PlanConfig, choice: PlanChoice, source: str,
 
 
 def retired_key(entry) -> Optional[str]:
-    """The retired choice key (``hierarchy`` / ``host_placement``) an
+    """The retired choice key (``hierarchy`` / ``host_placement``, or a
+    ``method`` / ``kernel_variant`` of the retired transport) an
     entry uses, or None — see :func:`~.ir.retired_choice_key`. Such an
     entry stays valid on disk and is a miss to :func:`lookup`;
     :func:`prune_db` removes it."""
@@ -242,7 +243,8 @@ def load_db(path: str) -> dict:
     if retired:
         log.warn(
             f"plan DB {path}: {len(retired)} entries use a retired key "
-            "(hierarchy/host_placement: the exchange has one level) and "
+            "(hierarchy/host_placement, or the method/kernel_variant of "
+            "the retired kernel-initiated transport) and "
             "are never served — `plan_tool prune` removes them: "
             + "; ".join(retired))
     return obj

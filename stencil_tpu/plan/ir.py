@@ -9,12 +9,11 @@ lower from (the reference analogue: the 26-direction transport plan
 ``realize`` builds before any sender exists, src/stencil.cu:327-464).
 
 Why an IR at all: the autotuner (plan/cost.py, plan/autotune.py)
-searches (partition shape x method x quantity batching x temporal k x
-kernel variant). With the plan as data, a candidate is *described and
+searches (partition shape x method x quantity batching x temporal k).
+With the plan as data, a candidate is *described and
 costed without compiling it* — collective counts and on-wire bytes fall
 out of the phase list — and the lowering stays a single code path per
-phase kind. ROADMAP #2's ``Method.REMOTE_DMA`` becomes another lowering
-of the same phases.
+phase kind.
 
 The IR is pure geometry: building a plan touches no jax and no devices,
 so the cost model can enumerate hundreds of candidates cheaply. The
@@ -37,29 +36,7 @@ from ..geometry import DIRECTIONS_26, Dim3, Radius
 AXIS_COMPOSED = "axis-composed"
 DIRECT26 = "direct26"
 AUTO_SPMD = "auto-spmd"
-REMOTE_DMA = "remote-dma"
-METHODS = (AXIS_COMPOSED, DIRECT26, AUTO_SPMD, REMOTE_DMA)
-
-# The fused compute+exchange kernel variant (ROADMAP #5): still
-# Method.REMOTE_DMA — same kernel-initiated transport, zero ppermutes —
-# but ONE kernel per substep starts every neighbor copy boundary-first,
-# computes interior tiles while the DMAs fly, waits the recv semaphores,
-# then computes the boundary tiles. A PlanChoice carries it as
-# ``kernel_variant == FUSED_VARIANT`` so the autotuner searches it and
-# the plan DB persists it like any other point in the space.
-FUSED_VARIANT = "fused"
-
-# The persistent whole-chunk mega-kernel variant (ROADMAP #7): still
-# Method.REMOTE_DMA transport, but ONE kernel executes an entire k-step
-# chunk — deep-halo (radius*k) exteriors staged once per chunk, the
-# shrinking valid strip re-swept each substep with ring-indexed window
-# rotation, neighbor barrier semaphores between substeps — dropping the
-# launch count from O(steps) to O(chunks) at the price of redundant
-# boundary compute the cost model prices. A PlanChoice carries it as
-# ``kernel_variant == PERSISTENT_VARIANT`` (``multistep_k`` is the chunk
-# depth, so persistent requires k >= 2 — at k == 1 it IS the fused
-# kernel).
-PERSISTENT_VARIANT = "persistent"
+METHODS = (AXIS_COMPOSED, DIRECT26, AUTO_SPMD)
 
 # Wire-compression itemsizes the IR can model without importing jax/numpy
 # (bfloat16 / float8_* are not numpy dtype names; everything else resolves
@@ -211,102 +188,6 @@ class DirectPhaseIR:
 
 
 @dataclass(frozen=True)
-class RemoteDmaPhaseIR:
-    """One kernel-initiated axis phase of a ``REMOTE_DMA`` plan.
-
-    Same composed-phase slab geometry as :class:`AxisPhaseIR` (full
-    padded extents, x→y→z order, edges/corners composing across phases —
-    the wire model is shared), but the boundary slabs move as
-    per-neighbor async remote copies issued from inside the kernel
-    (``pltpu.make_async_remote_copy`` on TPU; host-initiated
-    device-to-device copies in the CPU emulation) instead of
-    ``lax.ppermute``: the XLA collective path is bypassed entirely, so
-    :meth:`collectives` is ZERO by construction — the census pin — and
-    :meth:`dmas` counts the async copies one carrier pays (≤ 2 per
-    phase: one toward each neighbor; Q-independent under the PR-5
-    per-dtype packed-carrier geometry). ``fwd``/``bwd`` are the neighbor
-    rings the DMAs target (the same pairs the composed permutes use)."""
-
-    axis: str               # 'x' | 'y' | 'z' (mesh axis name)
-    adim: int               # stacked-array data dim
-    bdim: int               # stacked-array block dim
-    ring: int               # DMA participants along this axis
-    resident: int           # blocks resident per device along this axis
-    rm: int                 # low-side radius
-    rp: int                 # high-side radius
-    offset: int             # allocation-local compute origin
-    sizes: Tuple[int, ...]  # per-block logical sizes (full table)
-    fwd: Tuple[Tuple[int, int], ...]   # +axis neighbor ring (DMA targets)
-    bwd: Tuple[Tuple[int, int], ...]
-    wire_cells: int         # cells DMA'd per exchange per quantity (all devices)
-    local_cells: int        # cells moved locally (self-wrap / resident shifts)
-
-    @property
-    def blocks(self) -> int:
-        return self.ring * self.resident
-
-    @property
-    def uniform(self) -> bool:
-        return len(set(self.sizes)) == 1
-
-    @property
-    def active(self) -> bool:
-        return self.rm > 0 or self.rp > 0
-
-    def collectives(self) -> int:
-        """Always 0: the DMAs live inside the kernel custom-call, not on
-        the XLA collective path — nothing for a ppermute census to see."""
-        return 0
-
-    def dmas(self) -> int:
-        """Async remote copies one carrier pays for this phase."""
-        if self.ring <= 1 or not self.active:
-            return 0
-        return (1 if self.rm > 0 else 0) + (1 if self.rp > 0 else 0)
-
-
-@dataclass(frozen=True)
-class FusedPhaseIR:
-    """One per-direction message of a FUSED compute+exchange substep.
-
-    The fused kernel cannot use the composed x→y→z phase geometry: a
-    composed y slab carries x-halo data, so phase y's send depends on
-    phase x's receive — nothing could start boundary-first. Instead the
-    fused schedule sends one EXACT-extent message per active direction
-    (the DIRECT26 geometry re-transported): every message reads only the
-    sender's compute-region cells, so all of them start concurrently
-    before any compute, the interior tiles run while they fly, and the
-    boundary tiles run after the recv semaphores — the reference's 26
-    concurrent peer-access writes (§5.8), with the XLA collective path
-    bypassed exactly like :class:`RemoteDmaPhaseIR` (:meth:`collectives`
-    is ZERO by construction; :meth:`dmas` is 1 for a wire-crossing
-    direction, 0 for a self-wrap hand-off).
-
-    ``shape`` is the exact carrier extent (z, y, x) on a uniform
-    partition (radius along the direction's nonzero axes, block size on
-    the orthogonal ones); on uneven partitions the per-device extents
-    come from the size tables at lowering time and ``shape`` records the
-    base-block figure the byte model prices."""
-
-    direction: Tuple[int, int, int]       # (dx, dy, dz)
-    shape: Tuple[int, int, int]           # carrier extent (z, y, x)
-    src: Optional[Tuple[int, int, int]]   # uniform-only static starts (z, y, x)
-    dst: Optional[Tuple[int, int, int]]
-    crossing: bool                        # leaves the device (any ring axis)
-    wire_cells: int
-    local_cells: int
-
-    def collectives(self) -> int:
-        """Always 0: kernel-initiated copies, nothing on the XLA
-        collective path (the same pin as RemoteDmaPhaseIR)."""
-        return 0
-
-    def dmas(self) -> int:
-        """Async remote copies one carrier pays for this direction."""
-        return 1 if self.crossing else 0
-
-
-@dataclass(frozen=True)
 class ExchangePlan:
     """The full declarative exchange program for one (spec, mesh, method).
 
@@ -327,20 +208,10 @@ class ExchangePlan:
     resident: Tuple[int, int, int]
     axis_phases: Tuple[AxisPhaseIR, ...]  # always built (composed geometry)
     direct_phases: Tuple[DirectPhaseIR, ...] = ()
-    remote_phases: Tuple[RemoteDmaPhaseIR, ...] = ()
-    # the fused compute+exchange variant's per-direction messages (only
-    # built when ``fused``; REMOTE_DMA-only — see FusedPhaseIR)
-    fused_phases: Tuple[FusedPhaseIR, ...] = ()
-    fused: bool = False
-    # the persistent whole-chunk variant (REMOTE_DMA only): the phase
-    # geometry stays the deep-halo composed slab program (remote_phases
-    # built against the radius*k spec); what changes is the launch
-    # economics — see :meth:`launches_per_chunk`.
-    persistent: bool = False
     synthesized: bool = False
     # bf16-on-the-wire halo compression: wire-crossing carriers narrow to
     # this dtype before the send and widen on unpack (None = native).
-    # Applies to the packed-carrier methods (composed/direct26/remote-dma);
+    # Applies to the packed-carrier methods (composed/direct26);
     # local copies and self-wrap fills always stay native/lossless.
     wire_dtype: Optional[str] = None
     # per axis (x, y, z): False = a fixed boundary the exchange leaves
@@ -373,8 +244,6 @@ class ExchangePlan:
     def phases(self) -> Tuple:
         if self.method == DIRECT26:
             return self.direct_phases
-        if self.method == REMOTE_DMA:
-            return self.fused_phases if self.fused else self.remote_phases
         return self.axis_phases
 
     def collectives_per_exchange(self, quantities: int = 1,
@@ -388,47 +257,6 @@ class ExchangePlan:
         if self.synthesized:
             carriers = quantities  # the partitioner packs nothing today
         return sum(p.collectives() for p in self.phases) * carriers
-
-    def dmas_per_exchange(self, quantities: int = 1,
-                          dtype_groups: int = 1) -> int:
-        """Predicted kernel-initiated async remote copies of one
-        REMOTE_DMA exchange (0 for the ppermute methods): ≤ 2 per axis
-        phase per carrier, Q-independent under per-dtype packing — the
-        DMA analogue of :meth:`collectives_per_exchange`."""
-        if self.method != REMOTE_DMA:
-            return 0
-        carriers = dtype_groups if self.batch_quantities else quantities
-        phases = self.fused_phases if self.fused else self.remote_phases
-        return sum(p.dmas() for p in phases) * carriers
-
-    def launches_per_chunk(self, k: int = 1) -> int:
-        """Predicted device-program launches one k-step chunk pays — the
-        figure ``exchange.launches_per_chunk`` gauges and verify_plan
-        audits against the runtime's dispatch counters, exactly like
-        collectives and DMA bytes.
-
-        The unit is host-visible program dispatches of the REMOTE_DMA
-        runtime (the kernel-per-dispatch regime the reference's §5.8
-        peer-access kernels live in; the CPU emulation counts the same
-        thing):
-
-        - ``persistent``: 2 per chunk, k-independent — ONE deep-halo
-          staging exchange + ONE whole-chunk program (on TPU the chunk
-          program is a single mega-kernel launch). O(chunks).
-        - plain / fused REMOTE_DMA: 2 per substep — an exchange program
-          and a sweep program each step. O(steps).
-        - permute methods and AUTO_SPMD: 1 — the chunk compiles into one
-          XLA program; its in-module kernel count (O(k), censused by
-          ``utils.hlo_check.kernel_launch_census``) is a different unit
-          and is not this prediction's subject.
-        """
-        if int(k) < 1:
-            raise ValueError(f"launches_per_chunk needs k >= 1, got {k}")
-        if self.method != REMOTE_DMA:
-            return 1
-        if self.persistent:
-            return 2
-        return 2 * int(k)
 
     def wire_bytes(self, itemsizes: Sequence[int],
                    floating: Optional[Sequence[bool]] = None) -> int:
@@ -471,27 +299,12 @@ class ExchangePlan:
             f"resident={self.resident}"
             + (" (schedule synthesized by the SPMD partitioner)"
                if self.synthesized else "")
-            + (" (fused compute+exchange kernel)" if self.fused else "")
-            + (" (persistent whole-chunk kernel)" if self.persistent
-               else "")
             + (f" wire_dtype={self.wire_dtype}" if self.wire_dtype else "")
             + (f" periodic={self.periodic}" if not all(self.periodic) else "")
             + (" faces-only" if self.faces_only else ""),
         ]
         for p in self.phases:
-            if isinstance(p, FusedPhaseIR):
-                lines.append(
-                    f"  dir {p.direction}: shape(zyx)={p.shape} permutes=0 "
-                    f"dmas={p.dmas()} wire_cells={p.wire_cells} "
-                    f"local_cells={p.local_cells}"
-                )
-            elif isinstance(p, RemoteDmaPhaseIR):
-                lines.append(
-                    f"  axis {p.axis}: ring={p.ring} resident={p.resident} "
-                    f"rm={p.rm} rp={p.rp} permutes=0 dmas={p.dmas()} "
-                    f"wire_cells={p.wire_cells} local_cells={p.local_cells}"
-                )
-            elif isinstance(p, AxisPhaseIR):
+            if isinstance(p, AxisPhaseIR):
                 lines.append(
                     f"  axis {p.axis}: ring={p.ring} resident={p.resident} "
                     f"rm={p.rm} rp={p.rp} permutes={p.collectives()} "
@@ -512,12 +325,6 @@ class ExchangePlan:
             f"  total permutes/exchange (1 group): "
             f"{self.collectives_per_exchange()}"
         )
-        if self.method == REMOTE_DMA:
-            lines.append(
-                f"  total async remote copies/exchange (1 group): "
-                f"{self.dmas_per_exchange()} (kernel-initiated — the "
-                "census sees 0 ppermutes)"
-            )
         if self.wire_dtype and not self.synthesized:
             import dataclasses
 
@@ -732,86 +539,9 @@ def _direct_phases(spec, mesh_dim: Dim3,
     return tuple(phases)
 
 
-def _remote_phases(axis_phases: Tuple[AxisPhaseIR, ...]
-                   ) -> Tuple[RemoteDmaPhaseIR, ...]:
-    """REMOTE_DMA phases from the composed geometry: identical slab
-    extents, sizes, and neighbor rings — only the transport differs
-    (kernel-initiated DMAs instead of ppermutes), so the wire model is
-    literally the composed one and parity vs AXIS_COMPOSED is a
-    geometry-free claim about data movement."""
-    return tuple(
-        RemoteDmaPhaseIR(
-            axis=p.axis, adim=p.adim, bdim=p.bdim, ring=p.ring,
-            resident=p.resident, rm=p.rm, rp=p.rp, offset=p.offset,
-            sizes=p.sizes, fwd=p.fwd, bwd=p.bwd,
-            wire_cells=p.wire_cells, local_cells=p.local_cells,
-        )
-        for p in axis_phases
-    )
-
-
-def _fused_phases(spec, mesh_dim: Dim3) -> Tuple[FusedPhaseIR, ...]:
-    """Fused-substep messages: the DIRECT26 exact-extent direction set,
-    re-transported as kernel-initiated copies. Every message reads only
-    sender compute-region cells — no message depends on another, so the
-    fused kernel starts all of them boundary-first and hides the wire
-    time behind interior tiles. ``crossing`` (and hence :meth:`dmas`) is
-    a plan-level fact: a direction crosses iff any of its nonzero axes
-    has more than one device; self-wrap directions are local hand-offs
-    (lossless under wire compression, exactly like composed self-wrap
-    phases). Face → edge → corner order (stable within each rank) so the
-    uneven-partition lowering can layer padded writes like DIRECT26."""
-    r = spec.radius
-    base = spec.base
-    off = spec.compute_offset()
-    uniform = spec.is_uniform()
-    nblocks = spec.num_blocks()
-    md = {"z": mesh_dim.z, "y": mesh_dim.y, "x": mesh_dim.x}
-    dirs = [d for d in DIRECTIONS_26 if r.dir(-d) != 0]
-    dirs.sort(key=lambda d: abs(d.x) + abs(d.y) + abs(d.z))
-    phases = []
-    for d in dirs:
-        shape, src, dst = [], [], []
-        for dc, s, rmin, rplus, o in zip(
-            (d.z, d.y, d.x),
-            (base.z, base.y, base.x),
-            (r.z(-1), r.y(-1), r.x(-1)),
-            (r.z(1), r.y(1), r.x(1)),
-            (off.z, off.y, off.x),
-        ):
-            if dc == 1:
-                shape.append(rmin)
-                src.append(o + s - rmin)
-                dst.append(o - rmin)
-            elif dc == -1:
-                shape.append(rplus)
-                src.append(o)
-                dst.append(o + s)
-            else:
-                shape.append(s)
-                src.append(o)
-                dst.append(o)
-        if any(e == 0 for e in shape):
-            continue
-        comp = {"z": d.z, "y": d.y, "x": d.x}
-        crossing = any(comp[a] != 0 and md[a] > 1 for a in ("z", "y", "x"))
-        cells = shape[0] * shape[1] * shape[2] * nblocks
-        phases.append(FusedPhaseIR(
-            direction=(d.x, d.y, d.z), shape=tuple(shape),
-            src=tuple(src) if uniform else None,
-            dst=tuple(dst) if uniform else None,
-            crossing=crossing,
-            wire_cells=cells if crossing else 0,
-            local_cells=0 if crossing else cells,
-        ))
-    return tuple(phases)
-
-
 def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
                resident: Optional[Dim3] = None,
                wire_dtype: Optional[str] = None,
-               fused: bool = False,
-               persistent: bool = False,
                periodic=(True, True, True),
                faces_only: bool = False,
                quantity_radius=None) -> ExchangePlan:
@@ -822,12 +552,7 @@ def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
     grid (x, y, z); ``resident`` (blocks stacked per device) defaults to
     ``spec.dim / mesh_dim`` and must divide it exactly. ``wire_dtype``
     narrows wire-crossing carriers in the byte model (the bf16/fp8
-    on-the-wire halo compression knob). ``fused`` builds the fused
-    compute+exchange variant's per-direction message set (REMOTE_DMA
-    only, single-resident only — loud infeasibility otherwise);
-    ``persistent`` marks the whole-chunk mega-kernel variant (same
-    constraints; the phase geometry stays the composed slab program
-    against the caller's deep-halo radius*k spec). ``periodic`` (x, y, z)
+    on-the-wire halo compression knob). ``periodic`` (x, y, z)
     marks fixed axes and ``faces_only`` a star stencil's slab extents
     (see :class:`AxisPhaseIR`); both are lowerings of AXIS_COMPOSED with
     one block a device, and anything else refuses them.
@@ -843,21 +568,6 @@ def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
     mval = getattr(method, "value", method)
     if mval not in METHODS:
         raise ValueError(f"unknown exchange method {method!r}")
-    if fused and mval != REMOTE_DMA:
-        raise ValueError(
-            "the fused compute+exchange variant is a REMOTE_DMA lowering "
-            f"(kernel-initiated copies); got method {mval!r}"
-        )
-    if persistent and mval != REMOTE_DMA:
-        raise ValueError(
-            "the persistent whole-chunk variant is a REMOTE_DMA lowering "
-            f"(kernel-initiated copies); got method {mval!r}"
-        )
-    if persistent and fused:
-        raise ValueError(
-            "fused and persistent are distinct kernel variants of one "
-            "plan — choose one (persistent at k == 1 IS the fused kernel)"
-        )
     md = Dim3.of(mesh_dim)
     if spec.dim.x % md.x or spec.dim.y % md.y or spec.dim.z % md.z:
         raise ValueError(
@@ -866,18 +576,6 @@ def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
     if resident is None:
         resident = Dim3(spec.dim.x // md.x, spec.dim.y // md.y,
                         spec.dim.z // md.z)
-    if fused and resident != Dim3(1, 1, 1):
-        raise ValueError(
-            "the fused compute+exchange kernel supports single-resident "
-            f"partitions only (got resident {resident}); use the plain "
-            "REMOTE_DMA carrier or AXIS_COMPOSED for oversubscription"
-        )
-    if persistent and resident != Dim3(1, 1, 1):
-        raise ValueError(
-            "the persistent whole-chunk kernel supports single-resident "
-            f"partitions only (got resident {resident}); use the plain "
-            "REMOTE_DMA carrier or AXIS_COMPOSED for oversubscription"
-        )
     synthesized = mval == AUTO_SPMD
     periodic = tuple(bool(v) for v in periodic)
     if len(periodic) != 3:
@@ -925,8 +623,6 @@ def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
     direct_phases = (
         _direct_phases(spec, md, resident) if mval == DIRECT26 else ()
     )
-    remote_phases = _remote_phases(axis_phases) if mval == REMOTE_DMA else ()
-    fused_phases = _fused_phases(spec, md) if fused else ()
     return ExchangePlan(
         method=mval,
         pack_groups="dtype" if batch_quantities else "quantity",
@@ -935,10 +631,6 @@ def build_plan(spec, mesh_dim, method, batch_quantities: bool = True,
         resident=(resident.x, resident.y, resident.z),
         axis_phases=axis_phases,
         direct_phases=direct_phases,
-        remote_phases=remote_phases,
-        fused_phases=fused_phases,
-        fused=fused,
-        persistent=persistent,
         synthesized=synthesized,
         wire_dtype=wire_dtype,
         periodic=periodic,
@@ -1072,14 +764,23 @@ def validate_placement(placement, ndev: int) -> Optional[str]:
     return None
 
 
+# What a stored choice may still name and this program no longer runs:
+# the kernel-initiated transport (PRs 10 to 45) and its two kernel variants.
+RETIRED_METHODS = ("remote-dma",)
+RETIRED_VARIANTS = ("fused", "persistent")
+
+
 def retired_choice_key(obj) -> Optional[str]:
     """The retired key a stored choice USES, or None. Plan DBs and
     checkpoint manifests written by PRs 17 to 29 carry ``hierarchy``
     (the outer split of a two-level ICI+DCN exchange) and
     ``host_placement`` (its blocks-to-hosts assignment). Absent, null or
-    an identity ``host_placement`` is the one-level plan it always was;
-    anything else names a plan this program cannot run, and replaying it
-    as a one-level plan would be a different plan in silence."""
+    an identity ``host_placement`` is the one-level plan it always was.
+    Ones written by PRs 10 to 45 may name the kernel-initiated transport
+    (``method`` ``remote-dma``) or one of its kernel variants
+    (``kernel_variant`` ``fused`` / ``persistent``). Each names a plan
+    this program cannot run, and replaying it as the composed plan would
+    be a different plan in silence."""
     if not isinstance(obj, dict):
         return None
     if obj.get("hierarchy") is not None:
@@ -1087,6 +788,10 @@ def retired_choice_key(obj) -> Optional[str]:
     hp = obj.get("host_placement")
     if hp is not None and list(hp) != list(range(len(hp))):
         return "host_placement"
+    if obj.get("method") in RETIRED_METHODS:
+        return "method"
+    if obj.get("kernel_variant") in RETIRED_VARIANTS:
+        return "kernel_variant"
     return None
 
 
@@ -1129,7 +834,8 @@ class PlanChoice:
         if retired is not None:
             raise ValueError(
                 f"plan choice carries the retired key {retired!r} "
-                f"({obj[retired]!r}): the exchange has one level")
+                f"({obj[retired]!r}): the exchange has one level and no "
+                "kernel-initiated transport")
         return cls(
             partition=tuple(obj["partition"]),
             method=str(obj["method"]),
@@ -1139,17 +845,6 @@ class PlanChoice:
             placement=(None if placement is None
                        else tuple(int(v) for v in placement)),
         )
-
-    @property
-    def is_fused(self) -> bool:
-        """The fused compute+exchange mega-kernel variant of REMOTE_DMA."""
-        return self.kernel_variant == FUSED_VARIANT
-
-    @property
-    def is_persistent(self) -> bool:
-        """The persistent whole-chunk mega-kernel variant of REMOTE_DMA
-        (deep-halo temporal fusion; ``multistep_k`` is the chunk depth)."""
-        return self.kernel_variant == PERSISTENT_VARIANT
 
     @property
     def is_placed(self) -> bool:

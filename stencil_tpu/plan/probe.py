@@ -11,17 +11,7 @@ with the candidate label.
 Probes measure the exchange program of a candidate: its partition shape,
 method, quantity batching, and the DEEPENED radius of its temporal k
 (the k-step multistep exchanges radius*k halos once per k steps, so the
-probed per-step exchange cost is trimean/k). Kernel-variant candidates
-share the exchange probe — the variant's compute delta rides the static
-model until app-level probes exist (ROADMAP #1's TPU ledger) — EXCEPT the
-fused compute+exchange variant, whose exchange program itself differs
-(concurrent per-direction kernel-initiated transport) and is probed as
-such via ``time_exchange(fused=True)``. The persistent whole-chunk
-variant's EXCHANGE program is the deep-halo plain REMOTE_DMA slab
-program at radius*k — precisely what the scaled-radius probe above
-measures — so it shares that probe; its launch-count saving rides the
-static model's MODELED constants until scripts/probe_persistent.py runs
-on silicon (item 1).
+probed per-step exchange cost is trimean/k).
 """
 
 from __future__ import annotations
@@ -65,7 +55,6 @@ def probe_choice(config: PlanConfig, choice: PlanChoice,
             chunk=chunk if chunk is not None else min(iters, 5),
             batch_quantities=choice.batch_quantities,
             partition=choice.partition,
-            fused=choice.is_fused,
             # a placed candidate probes on its placed mesh — the tuned
             # assignment must be what the measurement measured
             placement=choice.placement,

@@ -227,37 +227,6 @@ def collective_census(hlo_text: str) -> Dict[str, Tuple[int, int]]:
     return out
 
 
-# Ops in a compiled (post-optimization) HLO module that dispatch a device
-# kernel: XLA's fused loops and the custom-call escape hatch (Mosaic
-# kernels land as tpu_custom_call custom-calls). Elementwise ops that
-# survive unfused still launch, but by the backends' own fusion pass they
-# are the noise floor — the census is a LOWER bound used for pinning
-# relative O(steps)-vs-O(chunks) shapes, not an absolute dispatch count.
-_LAUNCH_OP_RE = re.compile(r"=\s*[^=]*?\b(fusion|custom-call)\(")
-
-
-def kernel_launch_census(hlo_text: str) -> Dict[str, int]:
-    """``{op kind: count}`` of kernel-launch ops (``fusion`` /
-    ``custom-call``) over a compiled HLO module — the launch-count
-    analogue of :func:`collective_census`, counted the same way: STATIC
-    op instances across every computation (a fusion inside a while body
-    counts once), so census a single-chunk program when comparing
-    kernel variants. The persistent whole-chunk variant's pitch is this
-    number's shape — O(chunks) dispatched programs instead of O(steps)
-    (ops/persistent_stencil.py) — and the plan's
-    ``launches_per_chunk`` prediction is conformance-audited against
-    the measured host-dispatch count (analysis/verify_plan,
-    scripts/ci_persistent_gate.py); this census is the compiled-module
-    side of that evidence."""
-    out: Dict[str, int] = {}
-    for ln in hlo_text.splitlines():
-        m = _LAUNCH_OP_RE.search(ln)
-        if not m:
-            continue
-        out[m.group(1)] = out.get(m.group(1), 0) + 1
-    return out
-
-
 def collective_permute_pairs(hlo_text: str):
     """Every ``collective-permute``'s ``source_target_pairs``, one
     frozenset of (src, tgt) logical-device pairs per op instance, in

@@ -338,7 +338,7 @@ def test_committed_tree_lints_clean_against_committed_baseline():
 
 
 @pytest.mark.parametrize("method", ["axis-composed", "direct26",
-                                    "auto-spmd", "remote-dma"])
+                                    "auto-spmd"])
 def test_plan_auditor_agrees_per_method(method):
     from stencil_tpu.analysis import verify_plan as vp
 

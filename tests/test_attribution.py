@@ -150,6 +150,42 @@ def test_samples_from_records_matches_emitted_shape(tmp_path):
     assert all(s.phase == "exchange.iter" for s in samples)
 
 
+def test_records_of_the_retired_transport_are_refused_by_name(tmp_path):
+    """A metrics file the parent wrote under the kernel-initiated transport
+    carries its attribution records: a fit over them would price a method
+    no plan can name, so they are refused, by name, not dropped."""
+    records = [{"kind": "meta", "name": calibrate.ATTRIB_NAME,
+                "phase": "exchange.iter", "method": m, "collectives": 6,
+                "wire_bytes": 1000, "measured_s": 2e-3}
+               for m in (AXIS_COMPOSED, "remote-dma")]
+    assert len(calibrate.samples_from_records(records[:1])) == 1
+    with pytest.raises(CalibrationError, match="unknown method 'remote-dma'"):
+        calibrate.samples_from_records(records)
+
+
+def test_a_row_the_parent_fitted_for_the_retired_transport_still_prices():
+    """``plan_tool calibrate`` of the parent could install a per-copy
+    constant beside the permute overheads: the row stays valid on disk and
+    the composed plans price as the permute overheads say."""
+    row = {"calibration": {
+               "permute_overhead_s": {AXIS_COMPOSED: 7e-4},
+               "remote_dma": {"cpu_emulation_overhead_s": 4e-3,
+                              "provenance": "fitted(n=4, r2=0.990)"},
+               "provenance": "fitted(n=4, r2=0.990)"},
+           "provenance": "fitted(n=4, r2=0.990)", "n": 4, "r2": 0.99,
+           "platform": "cpu", "bandwidth_fit": False, "written_t": 0.0}
+    assert plandb.validate_calibration_row("cpu", row) == []
+    with_row = predict_exchange(_config(), _choice(), row["calibration"])
+    plain = predict_exchange(
+        _config(), _choice(),
+        {"permute_overhead_s": {AXIS_COMPOSED: 7e-4}})
+    assert with_row.predicted_s == plain.predicted_s
+    assert with_row.collectives == plain.collectives == 6
+    assert calibrate.diff_rows(row) == [
+        (f"permute_overhead_s[{AXIS_COMPOSED}]", 7e-4,
+         DEFAULT_CALIBRATION["permute_overhead_s"][AXIS_COMPOSED])]
+
+
 # -- the drift band == the perf_tool band -------------------------------------
 
 

@@ -55,28 +55,23 @@ def test_bench_exchange_sweep():
 def test_bench_exchange_method_ablation():
     rows, agree = bench_exchange.ablate(16, 16, 16, iters=2, devices=jax.devices()[:8])
     assert [r["config"].split("method=")[1] for r in rows] == [
-        "axis-composed", "direct26", "auto-spmd", "remote-dma",
+        "axis-composed", "direct26", "auto-spmd",
     ]
     # identical logical bytes — only the movement strategy differs
     assert len({r["bytes"] for r in rows}) == 1 and rows[0]["bytes"] > 0
-    # the CI gate: all four strategies deliver bit-identical halos
+    # the CI gate: all three strategies deliver bit-identical halos
     assert agree
     # census columns: with quantity batching (the default) the manual
     # methods' counts are Q-independent — the harness's 4 quantities ride
     # packed carriers: composed 6 total, direct26 one per direction —
     # auto >= 1 synthesized permute and nothing else (the partitioner
     # still emits per-quantity permutes; its schedule is its own).
-    # remote-dma bypasses the collective path entirely: 0 ppermutes,
-    # 0 bytes anywhere a census can see (the ISSUE-10 pin)
     by = {r["config"].split("method=")[1]: r for r in rows}
     assert by["axis-composed"]["cp_count"] == 6
     assert by["direct26"]["cp_count"] == 26
     assert by["auto-spmd"]["cp_count"] >= 1
-    assert by["remote-dma"]["cp_count"] == 0
-    assert by["remote-dma"]["cp_bytes"] == 0
     assert all(r["other_collectives"] == 0 for r in rows)
-    assert all(r["cp_bytes"] > 0 for r in rows
-               if "remote-dma" not in r["config"])
+    assert all(r["cp_bytes"] > 0 for r in rows)
     # the ablation CSV has the census columns
     assert bench_exchange.ablate_row(rows[0]).count(",") == \
         bench_exchange.ablate_header().count(",")

@@ -425,34 +425,89 @@ def test_quarantine_invalid_snapshot(tmp_path):
 # -- manifests written by PRs 17 to 29 carry the retired two-level keys ------
 
 
+def _rewrite_manifest(ck, edit):
+    snaps = [e for e in os.listdir(ck) if e.startswith("step-")]
+    mpath = os.path.join(ck, snaps[0], "manifest.json")
+    with open(mpath) as f:
+        manifest = json.load(f)
+    edit(manifest["meta"]["plan"])
+    with open(mpath, "w") as f:
+        json.dump(manifest, f)
+
+
 @pytest.mark.parametrize("key,value", [
     ("hierarchy", ["z", 2]),
     ("host_placement", [1, 0]),
     ("host_blocks", [0, 0, 0, 0, 1, 1, 1, 1]),
-])
+    ("method", "remote-dma"),
+    ("kernel_variant", "fused"),
+    ("kernel_variant", "persistent"),
+], ids=["hierarchy", "host_placement", "host_blocks", "remote-dma", "fused",
+        "persistent"])
 def test_restore_ignores_retired_plan_keys(tmp_path, capfd, key, value):
     """The block geometry never depended on the outer split, its host
     assignment or the host index per block: a manifest that carries them
     (null in a one-level run, set in a two-level one) restores bit-exact,
-    and says nothing."""
+    and says nothing. Nor did it depend on what carried the halos: a
+    manifest written under the kernel-initiated transport of PRs 10 to 45
+    or one of its kernel variants restores bit-exact under the composed
+    plan, with ONE warning that names what is retired."""
     ck = str(tmp_path / "ck")
     dd, h = make_domain((12, 12, 8), "float32")
     field = coord_field(dd.size, np.float32)
     dd.set_curr_global(h, field)
     dd.save_checkpoint(ck, 3, asynchronous=False)
-    snaps = [e for e in os.listdir(ck) if e.startswith("step-")]
-    mpath = os.path.join(ck, snaps[0], "manifest.json")
-    with open(mpath) as f:
-        manifest = json.load(f)
-    plan = manifest["meta"]["plan"]
-    # as the parent tree wrote a one-level run, then the key under test
-    plan["choice"].update(hierarchy=None, host_placement=None)
-    plan["host_blocks"] = [0] * 8
-    (plan if key == "host_blocks" else plan["choice"])[key] = value
-    with open(mpath, "w") as f:
-        json.dump(manifest, f)
+    transport = key in ("method", "kernel_variant")
+
+    def edit(plan):
+        # as the parent tree wrote a one-level run, then the key under test
+        plan["choice"].update(hierarchy=None, host_placement=None)
+        plan["host_blocks"] = [0] * 8
+        (plan if key == "host_blocks" else plan["choice"])[key] = value
+        if key == "kernel_variant":
+            plan["choice"]["method"] = "remote-dma"
+            plan["choice"]["multistep_k"] = 1 + (value == "persistent")
+
+    _rewrite_manifest(ck, edit)
     dd2, h2 = make_domain((12, 12, 8), "float32")
     capfd.readouterr()
     assert dd2.restore_checkpoint(ck) == 3
-    assert "[WARN]" not in capfd.readouterr().err
+    err = capfd.readouterr().err
+    assert err.count("[WARN]") == (1 if transport else 0), err
+    if transport:
+        assert "'remote-dma' is retired" in err and "axis-composed" in err
+    np.testing.assert_array_equal(dd2.get_curr_global(h2), field)
+
+
+def test_ckpt_restore_survives_unknown_future_method(tmp_path, capfd):
+    ck = str(tmp_path / "ck")
+    dd, h = make_domain((16, 16, 16), "float32")
+    field = coord_field(dd.size, np.float32)
+    dd.set_curr_global(h, field)
+    dd.save_checkpoint(ck, 2, asynchronous=False)
+    # a method neither this build nor any before it knew
+    _rewrite_manifest(
+        ck, lambda plan: plan["choice"].update(method="quantum-teleport"))
+    capfd.readouterr()
+    dd2, h2 = make_domain((16, 16, 16), "float32")
+    assert dd2.restore_checkpoint(ck) == 2   # warns, never crashes
+    assert "unknown to this build" in capfd.readouterr().err
+    np.testing.assert_array_equal(dd2.get_curr_global(h2), field)
+
+
+def test_ckpt_restore_warns_on_wire_dtype_delta(tmp_path, capfd):
+    ck = str(tmp_path / "ck")
+    dd = DistributedDomain(16, 16, 16)
+    dd.set_radius(1)
+    dd.set_devices(jax.devices()[:8])
+    dd.set_wire_dtype("bfloat16")
+    h = dd.add_data("temperature", "float32")
+    dd.realize()
+    field = coord_field(dd.size, np.float32)
+    dd.set_curr_global(h, field)
+    dd.save_checkpoint(ck, 2, asynchronous=False)
+    capfd.readouterr()
+    dd2, h2 = make_domain((16, 16, 16), "float32")
+    assert dd2.restore_checkpoint(ck) == 2
+    assert "wire_dtype" in capfd.readouterr().err
     np.testing.assert_array_equal(dd2.get_curr_global(h2), field)

@@ -141,3 +141,54 @@ def test_readme_commands_exist(capsys, monkeypatch):
                 wrong.append(f"stencil_tpu.apps.{app} {flag}")
     assert seen, "README.md shows no application command line"
     assert sorted(set(wrong)) == []
+
+
+# What PR 46 deleted: the kernel-initiated transport, its two kernel variants
+# and their CPU stand-in, with the options, gates and probes that selected
+# them. A document that still names one sends the reader after code that is
+# gone. ``plan/ir.py`` keeps the stored spellings it refuses
+# (``retired_choice_key``); ``benchmark/`` is the yardstick's and is not
+# this test's to hold; ``CHANGES.md`` says what went, by name.
+_DELETED = re.compile(
+    r"remote.dma|remote_emu|fused_stencil|persistent_stencil"
+    r"|set_fused_exchange|set_persistent_exchange|FUSED_VARIANT"
+    r"|PERSISTENT_VARIANT|launches_per_chunk|dmas_per_exchange"
+    r"|RemoteDmaPhaseIR|FusedPhaseIR|make_fused_astaroth_loop"
+    r"|_compile_jacobi_(fused|remote|persistent)|kernel_launch_census"
+    r"|ci_fused_gate|ci_persistent_gate|probe_persistent"
+    r"|fused\.overlap_fraction|fused_jacobi|persistent_jacobi|fused_exchange"
+    r"|--fused\b|--variants\b|--perturb-dmas"
+    r"|--kernel-variant[ =](fused|persistent)"
+    r"|kernel_variant\s*=\s*[\"']?(fused|persistent)", re.IGNORECASE)
+_HOLDS_THE_RETIRED_SPELLINGS = ("stencil_tpu/plan/ir.py",)
+_SAY_NOTHING_DELETED = {
+    "README.md": ("README.md",),
+    "COMPONENTS.md": ("COMPONENTS.md",),
+    "ROADMAP.md": ("ROADMAP.md",),
+    "PERF.md": ("PERF.md",),
+    "ci.yml": (".github/",),
+    "verify-skill": (".claude/skills/verify/SKILL.md",),
+    "package": ("stencil_tpu/",),
+    "scripts": ("scripts/",),
+    "entry-points": ("chip_smoke.py", "__graft_entry__.py", "pytest.ini",
+                     "lint-baseline.json", "perf-legs.json"),
+}
+
+
+@pytest.mark.parametrize("what", sorted(_SAY_NOTHING_DELETED))
+def test_nothing_names_what_pr_46_deleted(what):
+    files, _ = _tracked()
+    held = [f for f in files
+            if f.startswith(_SAY_NOTHING_DELETED[what])
+            and f not in _HOLDS_THE_RETIRED_SPELLINGS]
+    assert held, what
+    named = []
+    for f in held:
+        try:
+            text = _read(f)
+        except UnicodeDecodeError:
+            continue
+        named += [f"{f}:{i}: {m.group(0)}"
+                  for i, line in enumerate(text.splitlines(), 1)
+                  for m in [_DELETED.search(line)] if m]
+    assert named == []

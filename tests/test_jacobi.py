@@ -992,17 +992,13 @@ def test_uneven_overlap_asymmetric_radius():
 
 
 # The forks of the step builder, in one table: which builder
-# ``_compile_jacobi`` hands each (method, fused, persistent) to. ``None``
+# ``_compile_jacobi`` hands each method to. ``None``
 # is the inline composed-geometry build (the one the benchmark's cells
 # run), which returns an in-place ping-pong loop.
 _BUILDERS = [
     ("composed", Method.AXIS_COMPOSED, {}, None),
     ("direct26", Method.DIRECT26, {}, None),
     ("auto-spmd", Method.AUTO_SPMD, {}, "_compile_jacobi_auto"),
-    ("remote", Method.REMOTE_DMA, {}, "_compile_jacobi_remote"),
-    ("fused", Method.REMOTE_DMA, {"fused": True}, "_compile_jacobi_fused"),
-    ("persistent", Method.REMOTE_DMA, {"persistent": True},
-     "_compile_jacobi_persistent"),
 ]
 
 

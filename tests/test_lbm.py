@@ -99,7 +99,12 @@ def _worst(got, want):
 @pytest.mark.parametrize("n, part", [
     ((16, 16, 16), (1, 1, 1)), ((16, 16, 16), (1, 2, 2)),
     ((16, 16, 16), (2, 2, 2)), ((16, 12, 8), (2, 1, 1)),
-], ids=["one-block", "1x2x2", "2x2x2", "x-split"])
+    # uneven blocks (x 9+8, y 10+9; z 4+4+4+4 on the last): dead pad rows
+    # and columns beside the halos a population is read from
+    ((17, 19, 16), (1, 2, 2)), ((17, 19, 16), (2, 2, 2)),
+    ((17, 19, 16), (1, 2, 4)),
+], ids=["one-block", "1x2x2", "2x2x2", "x-split", "uneven-1x2x2",
+        "uneven-2x2x2", "uneven-1x2x4"])
 def test_every_cell_of_every_population_is_the_references(n, part, steps):
     f0, got, dd = _run(n, part, "float64", steps)
     assert _worst(got, ref.run(f0, OMEGA, steps)) < 1e-15

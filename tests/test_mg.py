@@ -197,6 +197,28 @@ def test_class_s_in_float32_follows_the_reference(nit, part):
     assert r["verified"] is (None if nit == 1 else False) or r["verified"]
 
 
+@pytest.mark.parametrize("part, refused", [
+    ((1, 2, 4), "along z"), ((4, 2, 1), "along x"),
+    ((2, 1, 1), None), ((1, 1, 2), None),
+], ids=str)
+def test_class_s_on_the_splits_the_table_above_leaves_out(part, refused):
+    """One axis split alone equals the reference as the even splits do; a
+    split the coarsest level (2^3) cannot take is refused by name before
+    anything is realized."""
+    if refused:
+        with pytest.raises(ValueError,
+                           match=f"level 2\\^3 does not split.*{refused}"):
+            _class_s("float64", part, 4)
+        return
+    r = _class_s("float64", part, 4)
+    u, res, norm = _reference_class_s(4)
+    assert abs(r["rnm2"] - norm) / norm < 1e-12 and r["verified"] is True
+    dd, hs = r["levels"][0]
+    assert tuple(dd.spec.dim) == part
+    np.testing.assert_allclose(unshard_blocks(dd.get_curr(hs["u"]), dd.spec),
+                               u, rtol=0, atol=1e-15)
+
+
 def test_run_takes_a_class_or_a_size_and_says_which_it_cannot_split():
     with pytest.raises(ValueError, match="a class or a size"):
         app.run()

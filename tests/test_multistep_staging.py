@@ -35,6 +35,16 @@ def test_planner_at_768_gives_depth_10_on_legal_row_strips():
     assert BUDGET // (p.y * p.x * 4) < 4 + 3 * (6 - 1) + 2
 
 
+def test_multistep_staging_planner_self_caps_never_overflows():
+    spec = GridSpec(Dim3(128, 128, 128), Dim3(1, 1, 8), Radius.constant(1))
+    # a generous budget reaches the requested depth with full planes
+    k, rows = plan_multistep_staging(spec, 4, budget=64 << 20)
+    assert k == 4 and rows is None
+    # a starved budget CAPS the depth rather than planning an overflow
+    k_small, _rows = plan_multistep_staging(spec, 4, budget=1 << 18)
+    assert k_small < 4
+
+
 def test_planner_at_512_keeps_full_planes():
     assert plan_multistep_staging(_cube(512), 10, BUDGET) == (10, None)
     assert plan_multistep_staging(_cube(512), 12, BUDGET) == (12, None)

@@ -160,6 +160,22 @@ def test_kernel_variant_plumbing(monkeypatch):
         assert recorded == [want] * 3, (env, arg, recorded)
 
 
+def test_astaroth_variant_checked_at_build_time(monkeypatch):
+    from stencil_tpu.astaroth.integrate import _check_variant
+
+    monkeypatch.delenv("STENCIL_ASTAROTH_VARIANT", raising=False)
+    _check_variant(None)
+    _check_variant("ring")
+    with pytest.raises(ValueError, match="valid values"):
+        _check_variant("bogus")
+    # the exchange's retired kernel variants are not the window's
+    with pytest.raises(ValueError, match="valid values"):
+        _check_variant("fused")
+    monkeypatch.setenv("STENCIL_ASTAROTH_VARIANT", "rnig")
+    with pytest.raises(ValueError, match="STENCIL_ASTAROTH_VARIANT"):
+        _check_variant(None)
+
+
 @pytest.mark.slow
 def test_distributed_pallas_step_matches_xla_path():
     """Full distributed step (exchange + fused substeps inside shard_map)

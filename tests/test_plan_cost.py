@@ -107,6 +107,19 @@ def test_infeasible_partitions_are_filtered():
     assert labels and all("8x1x1" not in l for l in labels)
 
 
+def test_deep_halo_exceeding_interior_is_refused_statically():
+    # 16^3 / (1, 2, 4): z blocks are 4 cells; radius 2 at k = 2 realizes
+    # a 4-cell halo, exactly feasible; k = 3 (6 cells) is not, and is
+    # refused HERE, before any kernel is planned
+    cfg = _config(["float32"], grid=(16, 16, 16), r=2)
+    assert feasible(cfg, PlanChoice(partition=(1, 2, 4),
+                                    method="axis-composed",
+                                    multistep_k=2)) is not None
+    assert feasible(cfg, PlanChoice(partition=(1, 2, 4),
+                                    method="axis-composed",
+                                    multistep_k=3)) is None
+
+
 def test_block_count_must_be_device_multiple():
     cfg = _config(["float32"], ndev=8)
     assert feasible(cfg, PlanChoice(partition=(3, 1, 1),

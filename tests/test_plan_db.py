@@ -149,14 +149,25 @@ def test_prune_filters_and_guard(tmp_path):
 # -- entries that use the retired two-level keys (written by PRs 17 to 29) ---
 
 
-def _db_with_retired_entry(tmp_path):
-    """A DB file as the parent tree wrote it: one one-level entry (the
-    keys present and null) and one that split z over two hosts."""
+# what an entry that nothing can serve any more carries in its choice
+_RETIRED = {
+    "two-level": {"hierarchy": ["z", 2], "host_placement": [1, 0]},
+    "remote-dma": {"method": "remote-dma"},
+    "fused": {"method": "remote-dma", "kernel_variant": "fused"},
+    "persistent": {"method": "remote-dma", "kernel_variant": "persistent",
+                   "multistep_k": 2},
+}
+
+
+def _db_with_retired_entry(tmp_path, retired="two-level"):
+    """A DB file as the parent tree wrote it: one one-level composed entry
+    (the keys present and null) and one under a plan since retired (z split
+    over two hosts; the kernel-initiated transport or one of its kernel
+    variants)."""
     flat, hier = _config(q=1), _config(q=2, grid=(32, 32, 32))
     stored = dict(_choice().to_json(), hierarchy=None, host_placement=None)
     db = plandb.empty_db()
-    for cfg, extra in ((flat, {}), (hier, {"hierarchy": ["z", 2],
-                                           "host_placement": [1, 0]})):
+    for cfg, extra in ((flat, {}), (hier, _RETIRED[retired])):
         entry = plandb.make_entry(cfg, _choice(), "probe", measured_s=0.01)
         entry["choice"] = {**stored, **extra}
         db["entries"][cfg.key()] = entry
@@ -171,10 +182,11 @@ def _file_bytes(path):
         return f.read()
 
 
+@pytest.mark.parametrize("retired", list(_RETIRED))
 @pytest.mark.parametrize("case", ["served-and-skipped", "file-untouched",
                                   "pruned"])
-def test_entry_with_retired_keys(tmp_path, capfd, case):
-    path, flat, hier = _db_with_retired_entry(tmp_path)
+def test_entry_with_retired_keys(tmp_path, capfd, case, retired):
+    path, flat, hier = _db_with_retired_entry(tmp_path, retired)
     before = _file_bytes(path)
     capfd.readouterr()
     db = plandb.load_db(path)
@@ -200,3 +212,36 @@ def test_entry_with_retired_keys(tmp_path, capfd, case):
         capfd.readouterr()
         assert plandb.lookup(plandb.load_db(path), flat) is not None
         assert "retired" not in capfd.readouterr().err
+
+
+@pytest.mark.parametrize("retired", list(_RETIRED))
+def test_plan_tool_shows_and_prunes_retired_entries(tmp_path, capsys,
+                                                    retired):
+    from stencil_tpu.apps import plan_tool
+
+    path, flat, hier = _db_with_retired_entry(tmp_path, retired)
+    key = plandb.retired_key(plandb.load_db(path)["entries"][hier.key()])
+    assert key == {"two-level": "hierarchy", "remote-dma": "method",
+                   "fused": "method", "persistent": "method"}[retired]
+    capsys.readouterr()
+    assert plan_tool.main(["show", "--db", path]) == 0
+    rows = capsys.readouterr().out.splitlines()
+    assert sum(f"retired({key})" in r for r in rows) == 1
+    assert sum(_choice().label() in r for r in rows) == 1
+    assert plan_tool.main(["prune", "--db", path]) == 0
+    assert list(plandb.load_db(path)["entries"]) == [flat.key()]
+
+
+@pytest.mark.parametrize("argv,named", [
+    (["explain", "--method", "remote-dma"], "remote-dma"),
+    (["autotune", "--no-probe", "--methods", "remote-dma"], "remote-dma"),
+    (["autotune", "--no-probe", "--variants", "fused"], "--variants"),
+], ids=["explain-method", "autotune-methods", "autotune-variants"])
+def test_plan_tool_refuses_the_retired_spellings(argv, named, capsys):
+    from stencil_tpu.apps import plan_tool
+
+    with pytest.raises(SystemExit) as e:
+        plan_tool.main(argv)
+    out = capsys.readouterr()
+    assert e.value.code not in (0, None)
+    assert named in out.err + str(e.value.code)

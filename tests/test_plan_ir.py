@@ -182,16 +182,26 @@ _STORED = {"partition": [2, 2, 2], "method": "axis-composed",
     ({"hierarchy": None, "host_placement": None}, None),
     ({"hierarchy": ["z", 2], "host_placement": None}, "hierarchy"),
     ({"hierarchy": None, "host_placement": [1, 0]}, "host_placement"),
-], ids=["absent", "null", "hierarchy", "host_placement"])
+    ({"method": "remote-dma"}, "method"),
+    ({"method": "remote-dma", "kernel_variant": "fused"}, "method"),
+    ({"kernel_variant": "fused"}, "kernel_variant"),
+    ({"kernel_variant": "persistent", "multistep_k": 2}, "kernel_variant"),
+    ({"kernel_variant": "ring"}, None),
+], ids=["absent", "null", "hierarchy", "host_placement", "remote-dma",
+        "remote-dma+fused", "fused", "persistent", "ring-still-loads"])
 def test_choice_from_json_of_retired_keys(extra, refused):
     """Plan DBs and checkpoint manifests written since PR 17 carry the
     two keys of the retired two-level exchange: absent or null loads as
     the one-level plan it always was; a choice that USED them is refused
-    by name, never replayed as another plan."""
+    by name, never replayed as another plan. The same for the method and
+    the two kernel variants of the kernel-initiated transport (PRs 10 to
+    45); Astaroth's window variant rides ``kernel_variant`` as before."""
     obj = {**_STORED, **extra}
     if refused is None:
         ch = PlanChoice.from_json(obj)
-        assert ch == PlanChoice(partition=(2, 2, 2), method="axis-composed")
+        assert ch == PlanChoice(
+            partition=(2, 2, 2), method="axis-composed",
+            kernel_variant=extra.get("kernel_variant"))
         assert set(ch.to_json()) == set(_STORED)
     else:
         with pytest.raises(ValueError, match=f"retired key '{refused}'"):

@@ -230,7 +230,7 @@ def _exchange_once(method, part, placement, dtype="float32", grid=16):
 
 
 @pytest.mark.parametrize("method", ["axis-composed", "direct26",
-                                    "auto-spmd", "remote-dma"])
+                                    "auto-spmd"])
 def test_placed_exchange_bit_identical_all_methods(method):
     _, ident = _exchange_once(method, (2, 2, 2), None)
     dd, placed = _exchange_once(method, (2, 2, 2), PERM8)

@@ -327,6 +327,10 @@ def _fingerprints(name):
     assert plan.pop("quantity_radius", None) is None
     for ph in plan["axis_phases"]:
         assert ph.pop("sides", None) is None
+    # ... as the four fields of the kernel-initiated transport did in each
+    # of these plans until PR 46 took them out of the record ...
+    plan.update(remote_phases=(), fused_phases=(), fused=False,
+                persistent=False)
     # ... and everything else is the parent's
     text = json.dumps(plan, sort_keys=True, default=str)
     hlo = ex._compiled.lower(dd._exchanged_state()).as_text()

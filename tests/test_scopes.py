@@ -47,7 +47,7 @@ def test_every_pallas_call_passes_a_name_and_every_kernel_is_in_the_vocabulary()
             assert isinstance(first, ast.Constant), (rel, "literal name")
             kernels.append(first.value)
     assert sites == [os.path.join("obs", "scopes.py")], sites
-    assert len(kernels) == 22
+    assert len(kernels) == 18
     assert set(kernels) == set(scopes.KERNELS)
     # an operator that plain XLA may compute carries its kernel's name all
     # the same (kernel_scope); a name outside the vocabulary is refused
@@ -82,7 +82,7 @@ def test_every_named_scope_is_in_the_vocabulary():
     assert seen == set(scopes.SCOPES)
     assert all(s.startswith(scopes.PREFIX) for s in scopes.SCOPES)
     assert scopes.layer_of("stencil.kernel.self_fill_x") == scopes.LAYER_HALO
-    assert scopes.layer_of("stencil.kernel.fused_jacobi") == scopes.LAYER_KERNELS
+    assert scopes.layer_of("stencil.kernel.jacobi_multistep") == scopes.LAYER_KERNELS
     for name in ("split_x_pack", "split_x_unpack"):
         assert scopes.KERNELS[name] == scopes.LAYER_HALO
         assert scopes.layer_of(scopes.KERNEL_PREFIX + name) == scopes.LAYER_HALO

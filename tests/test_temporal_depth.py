@@ -120,6 +120,14 @@ RUNS = {
     "explicit_1": (4, {"deep_halo": 1}, (1, 1, 0), None, "explicit"),
     "explicit_2": (4, {"deep_halo": 2}, (2, 2, 0), 2, "explicit"),
     "split_x": (4, {"partition": (2, 2, 1)}, (1, 1, 1), None, "mesh"),
+    # depths that do not divide the dispatch: passes, then single steps
+    "explicit_3_tail1": (4, {"deep_halo": 3}, (3, 3, 0), 3, "explicit"),
+    "explicit_4_tail2": (4, {"deep_halo": 4}, (4, 4, 0), 4, "explicit"),
+    "explicit_2_chunk7": (4, {"deep_halo": 2, "chunk": 7}, (2, 2, 0), 2,
+                          "explicit"),
+    # eight chips: (1,2,4), four blocks on z
+    "eight_chips_default": (8, {}, (5, 5, 0), 5, "block"),
+    "eight_chips_3_tail1": (8, {"deep_halo": 3}, (3, 3, 0), 3, "explicit"),
 }
 
 
@@ -159,12 +167,55 @@ def test_run_realizes_the_depth_it_picks(name, monkeypatch):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
-def test_persistent_still_needs_an_explicit_depth():
+@pytest.mark.parametrize("k, iters", [(2, 6), (3, 7)],
+                         ids=["k2", "k3-tail1"])
+def test_deep_halos_on_uneven_blocks_step_one_exchange_at_a_time(k, iters):
+    """Radius-k halos on the uneven (1, 2, 4) split (y 10 + 10, z 6 + 6 + 5
+    + 5; x not lanes): the multistep wants even tight-x blocks, so the loop
+    exchanges every step at the depth it was given, overlap shells at
+    dynamic offsets, and lands on the plain reference from a random field."""
+    from stencil_tpu.domain.grid import GridSpec
+    from stencil_tpu.geometry import Radius
+    from stencil_tpu.ops.jacobi import make_jacobi_loop
+    from stencil_tpu.parallel import HaloExchange, grid_mesh
+    from stencil_tpu.parallel.exchange import shard_blocks, unshard_blocks
+
+    spec = GridSpec(Dim3(18, 20, 22), Dim3(1, 2, 4), Radius.constant(k))
+    assert not spec.is_uniform()
+    g = spec.global_size
+    mesh = grid_mesh(spec.dim, jax.devices()[:8])
+    staged = len(telemetry.get().records(kind="counter",
+                                         name="kernel.multistep.staging"))
+    loop = make_jacobi_loop(HaloExchange(spec, mesh), iters, temporal_k=k)
+    assert len(telemetry.get().records(
+        kind="counter", name="kernel.multistep.staging")) == staged
+    field = np.random.default_rng(k).standard_normal(
+        (g.z, g.y, g.x)).astype(np.float32)
+    out, _ = loop(shard_blocks(field, spec, mesh),
+                  shard_blocks(np.zeros_like(field), spec, mesh),
+                  shard_blocks(sphere_sel(g), spec, mesh))
+    want = jacobi_reference(field, sphere_masks(g), iters)
+    np.testing.assert_allclose(unshard_blocks(out, spec), want, rtol=1e-5,
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("argv", [
+    ["--method", "remote-dma"],
+    ["--fused"],
+    ["--kernel-variant", "fused"],
+    ["--kernel-variant", "persistent", "--deep-halo", "2"],
+], ids=["method-remote-dma", "fused", "variant-fused", "variant-persistent"])
+def test_jacobi3d_app_refuses_the_retired_spellings(argv, capsys):
+    """What selected the kernel-initiated transport and its two kernel
+    variants on the command line is refused by ``argparse``, by name; the
+    depth is the application's pick or ``--deep-halo``."""
     from stencil_tpu.apps import jacobi3d
 
-    with pytest.raises(ValueError, match="--deep-halo >= 2"):
-        jacobi3d.run(16, 16, 16, iters=2, devices=jax.devices()[:1],
-                     kernel_variant="persistent")
+    with pytest.raises(SystemExit) as e:
+        jacobi3d.main(["--x", "8", "--y", "8", "--z", "8"] + argv)
+    assert e.value.code == 2
+    err = capsys.readouterr().err
+    assert (argv[1] if argv[0] == "--method" else argv[0]) in err
 
 
 # ------------------------------- the kernel at the cell's shape, k = 5
