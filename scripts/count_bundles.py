@@ -13,8 +13,16 @@ tile (64 positions at tiles (2, 128): the window shift and the DMAs).
 Prints, total and a position: bundles (= cycles, statically), whole-row
 lane rolls (``vrot.lane``, on three rotate units), the VALU operations by
 kind (four slots a bundle), loads (three slots) and stores (ONE slot) and
-how many of each are spills. No chip needed, and no number here is a
-device time.
+how many of each are spills. Where several loops lie at the depth, as a
+loop a stage and sphere branch of the jacobi multistep does
+
+    LIBTPU_INIT_ARGS=... python scripts/export_traffic.py multistep 512 compile
+    python scripts/count_bundles.py /tmp/llo jacobi_multistep 64 2
+
+(``768 rows`` with ``jacobi_multistep_rows``, ``512x4``; 64 vregs a trip of
+16 groups at 512 lanes), a line a loop follows the totals: its bundles,
+lane rolls, stores and the spills among them. No chip needed, and no
+number here is a device time.
 """
 
 from __future__ import annotations
@@ -31,18 +39,28 @@ VALU_OTHER = ("vand", "vor.", "vcmp", "vmov", "vweird", "vmax", "vmin",
 def count(path: str, depth):
     ops = collections.Counter()
     tally = collections.Counter()
+    loops, inside = [], False     # a tally a contiguous run at the depth
     for line in open(path):
         m = re.match(r"\s*(?:0x[0-9a-f]+|\d+)\s+(?:\w+)?:\s*(>*)\s*\{(.*)\}", line)
-        if not m or (depth is not None and len(m.group(1)) != depth):
+        if not m:
             continue
+        if depth is not None and len(m.group(1)) != depth:
+            inside = inside and len(m.group(1)) > depth
+            continue
+        if not inside:
+            inside = True
+            loops.append(collections.Counter())
         tally["bundles"] += 1
+        loops[-1]["bundles"] += 1
         for ins in m.group(2).split(";;"):
             op = re.search(r"=\s+([a-z][a-z0-9_.]*)", ins)
             if not op or op.group(1).startswith("inlined_call"):
                 continue
             ops[op.group(1)] += 1
+            loops[-1][op.group(1)] += 1
             if op.group(1) in ("vst", "vld") and "_spill" in ins:
                 tally[op.group(1) + " spill"] += 1
+                loops[-1][op.group(1) + " spill"] += 1
 
     def of(*prefixes):
         return sum(v for k, v in ops.items() if k.startswith(prefixes))
@@ -50,7 +68,7 @@ def count(path: str, depth):
     valu = [("sublane rotations", of("vrot.slane")), ("selects", of("vsel")),
             ("adds", of("vadd.f32")), ("subtractions", of("vsub.f32")),
             ("multiplies", of("vmul.f32")), ("other", of(*VALU_OTHER))]
-    return ([("bundles", tally["bundles"]),
+    return loops, ([("bundles", tally["bundles"]),
              ("lane rolls", of("vrot.lane")),
              ("VALU operations", sum(v for _, v in valu))]
             + [("  " + k, v) for k, v in valu]
@@ -66,8 +84,15 @@ def main(argv) -> int:
     paths = sorted(glob.glob(f"{dump}/*-{kernel}.1-*-final_bundles.txt"))
     if not paths:
         raise SystemExit(f"no final bundles of {kernel!r} under {dump}")
-    for name, n in count(paths[-1], depth):
+    loops, totals = count(paths[-1], depth)
+    for name, n in totals:
         print(f"{name:24s} {n:8d} {n / positions:9.1f}")
+    if depth is not None and len(loops) > 1:
+        for i, c in enumerate(loops):
+            rolls = sum(v for k, v in c.items() if k.startswith("vrot.lane"))
+            print(f"loop {i:3d}: {c['bundles']:6d} bundles "
+                  f"{c['bundles'] / positions:6.1f} a position, {rolls:4d} lane "
+                  f"rolls, {c['vst']:4d} stores, {c['vst spill']:3d} spills")
     return 0
 
 

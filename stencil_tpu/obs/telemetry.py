@@ -174,11 +174,21 @@ NAME_FIELDS = {
     # (0 = full planes), strips, the rows a staged strip holds beyond its
     # own, the rows one pass computes over all stages and strips against
     # the k * ny it keeps, and the VMEM scratch (benchmark reader
-    # multistep_recompute_share)
+    # multistep_recompute_share); and how a stage's body walks its rows:
+    # in aligned groups of group_rows, at most groups_per_trip a trip of
+    # its loop, rows_walked in all (whole groups: at least rows_computed),
+    # lane_rolls_per_vreg for x -+ 1 (whole rows, in both layouts), between
+    # stage_buffers scratch arrays (no reader of their own:
+    # kernel_ms_per_iter and stencil_kernel_roofline show the effect)
     "kernel.multistep.staging": (("module", str), ("k", int), ("rows", int),
                                  ("strips", int), ("halo_rows", int),
                                  ("rows_computed", int), ("rows_kept", int),
-                                 ("vmem_bytes", int)),
+                                 ("vmem_bytes", int), ("body", str),
+                                 ("group_rows", int),
+                                 ("groups_per_trip", int),
+                                 ("rows_walked", int),
+                                 ("lane_rolls_per_vreg", int),
+                                 ("stage_buffers", int)),
     # once a jacobi3d.run(): the depth k (value) its halos were realized
     # for, the steps a dispatch runs, how they divide into deep-halo
     # passes of k and single steps (k = 1: no pass, the loop builder's own

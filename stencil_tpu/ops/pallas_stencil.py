@@ -488,11 +488,16 @@ def pick_temporal_depth(size, partition, chunk: int,
     return k, "cap" if cap < chunk else "chunk"
 
 
+def _multistep_x_layout(spec: GridSpec):
+    """``(tight, kx, xo_k)`` of the multistep's staged rows: a single-block
+    x axis wraps in the kernel, tightly where the lanes allow."""
+    return _tight_x_layout(spec.dim.x == 1, spec.base.x,
+                           spec.compute_offset().x, spec.padded().x)
+
+
 def _staged_columns(spec: GridSpec) -> int:
     """Columns of a staged multistep row: nx under the tight-x layout."""
-    _, kx, _ = _tight_x_layout(spec.dim.x == 1, spec.base.x,
-                               spec.compute_offset().x, spec.padded().x)
-    return kx
+    return _multistep_x_layout(spec)[1]
 
 
 def _staging_bytes(kx: int, k: int, rows_staged: int, rows_out: int) -> int:
@@ -502,15 +507,59 @@ def _staging_bytes(kx: int, k: int, rows_staged: int, rows_out: int) -> int:
     return 4 * kx * ((_N_IN + 3 * (k - 1)) * rows_staged + 2 * rows_out)
 
 
+# a stage of the multistep walks its plane in groups of 8 rows (a vreg's
+# sublanes), at most this many a trip of its loop. A trip is bound by its
+# 2 lane rolls a vreg (7.6 bundles for three, one on each rotate unit) and
+# pays once for its addresses and until the first roll returns: some 60
+# bundles in the schedule and about 140 cycles on the chip, where the call
+# at 512^3 took 0.885 / 0.687 / 0.594 ms a step at 8 / 16 / 32 groups a
+# trip (0.49 + 3.2 / groups) and 3.54 / 3.00 / 2.68 at 768^3 on strips. 32
+# groups are 128 vregs a trip at 512 lanes and up to 156 at 768, with no
+# spill in a plain loop at either width: the scheduler streams them. 64
+# would gain 9 % more for twice the program to lower and compile
+# (scripts/count_bundles.py on the cells' own kernels, PERF.md section 6)
+_GROUP_ROWS = 8
+_GROUPS_PER_TRIP = 32
+
+
+def _stage_rows(spec: GridSpec, k: int, rows: Optional[int],
+                s: int) -> Tuple[int, int]:
+    """Slab rows ``[lo, hi)`` that stage ``s`` of ``k`` computes: ``k - s``
+    rows beyond each side of the strip's own (``rows``) or, in full planes
+    (``None``), of a multi-block y axis's; a single block's y ring is not
+    computed but filled."""
+    if rows is None:
+        ey = k - s if spec.dim.y > 1 else 0
+        yo = spec.compute_offset().y
+        return yo - ey, yo + spec.base.y + ey
+    return _round8(k) - (k - s), _round8(k) + rows + (k - s)
+
+
+def _stage_walk(lo: int, hi: int) -> Tuple[int, int, int, int]:
+    """``(g0, n_g, per_trip, trips)``: the aligned 8-row groups
+    ``[g0, g0 + n_g)`` that cover rows ``[lo, hi)``, walked in the fewest
+    trips of at most ``_GROUPS_PER_TRIP`` groups, all of one size; a last
+    trip that would pass the end is re-anchored onto it."""
+    g0 = lo // _GROUP_ROWS
+    n_g = -(-hi // _GROUP_ROWS) - g0
+    trips = -(-n_g // _GROUPS_PER_TRIP)
+    return g0, n_g, -(-n_g // trips), trips
+
+
 def multistep_staging(spec: GridSpec, k: int, rows: Optional[int]) -> dict:
     """What one pass of the depth-``k`` multistep stages and computes along
-    y, as the builders above lay it out (``rows``: the strip height, ``None``
+    y, as the builders below lay it out (``rows``: the strip height, ``None``
     = full planes): ``strips``; ``halo_rows``, the rows a staged strip holds
     beyond the ``rows`` it writes; ``rows_computed``, summed over all stages
     and strips (a strip's stage s computes ``k - s`` rows beyond each side of
     its own, a multi-block y axis in full planes likewise; a re-anchored
     last strip computes its overlap again); ``rows_kept`` = ``k * ny``, what
-    a pass with no recompute would compute; ``vmem_bytes`` of scratch."""
+    a pass with no recompute would compute; ``vmem_bytes`` of scratch. And
+    how a stage walks its rows (``body``): in aligned groups of
+    ``group_rows``, at most ``groups_per_trip`` a trip of its loop,
+    ``rows_walked`` in all (whole groups, a re-anchored last trip's twice),
+    with ``lane_rolls_per_vreg`` for ``x -+ 1`` (whole rows are rolled in
+    both layouts), between ``stage_buffers`` scratch arrays."""
     ny = spec.base.y
     beyond = k * (k - 1)        # 2 (k - s) rows over the stages s = 1..k
     if rows is None:
@@ -519,11 +568,196 @@ def multistep_staging(spec: GridSpec, k: int, rows: Optional[int]) -> dict:
     else:
         strips, staged, out = -(-ny // rows), rows + 2 * _round8(k), rows
         computed = strips * (k * rows + beyond)
+    walks = [_stage_walk(*_stage_rows(spec, k, rows, s))
+             for s in range(1, k + 1)]
+    kx = _staged_columns(spec)
     return {"k": k, "rows": rows or 0, "strips": strips,
             "halo_rows": staged - (rows or ny),
             "rows_computed": computed, "rows_kept": k * ny,
-            "vmem_bytes": _staging_bytes(_staged_columns(spec), k, staged,
-                                         out)}
+            "vmem_bytes": _staging_bytes(kx, k, staged, out),
+            "body": "row_groups", "group_rows": _GROUP_ROWS,
+            "groups_per_trip": max(per_trip for _, _, per_trip, _ in walks),
+            "rows_walked": strips * _GROUP_ROWS * sum(
+                per_trip * trips for _, _, per_trip, trips in walks),
+            "lane_rolls_per_vreg": 2,
+            "stage_buffers": k - 1}
+
+
+def _make_multistep_stage(spec: GridSpec, slab_rows: int, wrap_rows: bool):
+    """The ONE stage body of the temporal multistep, for both builders and
+    both layouts: ``stage(src, dst, dst_up, rows, zg, row_org, col_org)``
+    computes slab rows ``rows = (lo, hi)`` of a plane from the three
+    ``(ref, slot)`` planes ``src`` (below, centre, above in z) of the
+    stage before, all ``slab_rows`` tall, into the ``(ref, slot)`` plane
+    ``dst`` ``dst_up`` rows further up, spheres fixed up from global
+    coordinates (slab row r is global row ``r + row_org``, lane c global
+    column ``c + col_org``; ``wrap_rows``: periodic in g.y, as a
+    multi-block x is in g.x; ``zg`` the plane's global z).
+
+    The plane is walked in aligned 8-row groups, a few a trip of a
+    ``fori_loop`` that carries nothing, so that no value is larger than a
+    group and all of a trip's stay in registers (a whole plane is four
+    register files: Mosaic stored and reloaded every such value, and the
+    one store slot a bundle set the pace). Rows are loaded at their tile
+    boundary (Mosaic takes no row load at a traced offset off it) and
+    ``y -+ 1`` come from the group and its neighbour by ONE sublane
+    rotation and a select each. A group that straddles ``lo`` or ``hi`` is
+    computed whole: its rows outside hold values nothing reads (the next
+    stage reads its own extent + 1, inside this one's); the neighbour
+    group is clamped at the slab's two ends.
+
+    Rows are whole in BOTH layouts, and ``x -+ 1`` a lane roll of the row.
+    Tight-x (kx == nx, no x halos): the roll IS the periodic neighbourhood.
+    Inline x halos: a computed column's neighbours lie inside the row, so
+    the wrap lands in columns beyond the stage's x extent, which like the
+    rows beyond its y extent hold values nothing reads (a single block's x
+    ring is filled after the stage, as its y ring is). A load at the x
+    origin's lane offset is shifted by Mosaic before it can meet an iota:
+    7.3 lane rolls a vreg where whole rows take 2 (PERF.md section 6)."""
+    g = spec.global_size
+    mx = spec.dim.x > 1
+    kx = _staged_columns(spec)
+    hot_c = (g.x // 3, g.y // 2, g.z // 2)
+    cold_c = (g.x * 2 // 3, g.y // 2, g.z // 2)
+    assert hot_c[1:] == cold_c[1:] and g.y >= _GROUP_ROWS
+    thresh = (g.x // 10 + 1) ** 2
+    last = slab_rows // _GROUP_ROWS - 1
+
+    # a group's arithmetic, traced ONCE a build however many groups, stages
+    # and sphere branches call it (an inner jit: the kernel's trace holds a
+    # call a group)
+
+    @jax.jit
+    def average(up, c, dn, xs, z_lo, z_hi):
+        """A group's new rows from the centre plane's group ``c``, its
+        neighbour groups ``up`` and ``dn`` in y, the same rows ``xs`` to
+        roll, and the two z neighbours' groups."""
+        sub = jax.lax.broadcasted_iota(jnp.int32, c.shape, 0)
+        x_lo, x_hi = _roll_x_pair(xs, kx, 1)
+        y_lo = pltpu.roll(jnp.where(sub == 7, up, c), 1, 0)
+        y_hi = pltpu.roll(jnp.where(sub == 0, dn, c), 7, 0)
+        return (
+            x_lo
+            + x_hi
+            + y_lo
+            + y_hi
+            + z_lo
+            + z_hi
+        ) / 6.0  # divide: bit-parity with ops.jacobi.jacobi_sweep
+
+    @jax.jit
+    def fix_spheres(val, at, hot_x2, cold_x2, dz2):
+        """The hot and cold sphere's cells of a group whose first row is
+        global row ``at``: exact integer arithmetic on global coordinates;
+        the two spheres share their y and z centre."""
+        sub = jax.lax.broadcasted_iota(jnp.int32, val.shape, 0)
+        if wrap_rows:  # a group wraps once at most
+            row = sub + jnp.mod(at, g.y)
+            row = jnp.where(row >= g.y, row - g.y, row)
+        else:
+            row = sub + at
+        yz2 = (row - hot_c[1]) ** 2 + dz2
+        return jnp.where(yz2 + hot_x2 < thresh, HOT_TEMP,
+                         jnp.where(yz2 + cold_x2 < thresh, COLD_TEMP, val))
+
+    def stage(src, dst, dst_up, rows, zg, row_org, col_org):
+        g0, n_g, per_trip, trips = _stage_walk(*rows)
+        ct, ct_s = src[1]
+        dst_ref, dst_s = dst
+
+        def at_group(grp, n=1):
+            """``n`` aligned groups from group ``grp`` on."""
+            return pl.ds(pl.multiple_of(grp * _GROUP_ROWS, _GROUP_ROWS),
+                         n * _GROUP_ROWS)
+
+        def groups(plane, span):
+            """A trip's rows of a plane: ONE load at a traced offset, a
+            group each by aligned slices of the value."""
+            ref, slot = plane
+            got = ref[slot, span]
+            return [got[i * _GROUP_ROWS:(i + 1) * _GROUP_ROWS]
+                    for i in range(per_trip)]
+
+        def walk(spheres: bool):
+            if spheres:
+                # a column's terms are the same in every group. Halo-
+                # extended cells of a multi-block axis can sit beyond the
+                # global extent; their true coordinate is the periodic
+                # wrap, without which a sphere that crosses the boundary
+                # would clamp differently here than on the owning block.
+                col = jax.lax.broadcasted_iota(
+                    jnp.int32, (_GROUP_ROWS, kx), 1) + col_org
+                if mx:
+                    col = jnp.mod(col, g.x)
+                hot_x2 = (col - hot_c[0]) ** 2
+                cold_x2 = (col - cold_c[0]) ** 2
+                dz2 = (zg - hot_c[2]) ** 2
+
+            def trip(t, carry):
+                first = g0 + jnp.minimum(t * per_trip, n_g - per_trip)
+                span = at_group(first, per_trip)
+                # the centre plane's group before and after the trip's
+                # (held inside the slab: such a row is outside the extent)
+                before = first - 1 if g0 > 0 else jnp.maximum(first - 1, 0)
+                after = first + per_trip
+                if g0 + n_g > last:
+                    after = jnp.minimum(after, last)
+                ctr = ([ct[ct_s, at_group(before)]] + groups(src[1], span)
+                       + [ct[ct_s, at_group(after)]])
+                z_lo, z_hi = groups(src[0], span), groups(src[2], span)
+                # the rows to roll are loaded a second time, at the same
+                # offset formed another way so that the two loads are not
+                # merged: the scheduler issues a trip's rolls well ahead,
+                # and a row it also had to keep for the y shifts was
+                # spilled at 768 lanes
+                again = pl.multiple_of(
+                    g0 * _GROUP_ROWS + jnp.minimum(
+                        t * (per_trip * _GROUP_ROWS),
+                        (n_g - per_trip) * _GROUP_ROWS), _GROUP_ROWS)
+                x_src = groups(src[1], pl.ds(again, per_trip * _GROUP_ROWS))
+                vals = []
+                for i in range(per_trip):
+                    val = average(*ctr[i:i + 3], x_src[i], z_lo[i], z_hi[i])
+                    if spheres:
+                        val = fix_spheres(
+                            val, (first + i) * _GROUP_ROWS + row_org,
+                            hot_x2, cold_x2, dz2)
+                    vals.append(val)
+                out = pl.multiple_of(first * _GROUP_ROWS - dst_up,
+                                     _GROUP_ROWS)
+                dst_ref[dst_s, pl.ds(out, per_trip * _GROUP_ROWS)] = (
+                    jnp.concatenate(vals, axis=0))
+                return carry
+
+            jax.lax.fori_loop(0, trips, trip, 0)
+
+        # sphere fix-up only on planes intersecting the spheres (both share
+        # the same z center and radius)
+        near = jnp.abs(zg - hot_c[2]) <= g.x // 10
+
+        @pl.when(near)
+        def _():
+            walk(True)
+
+        @pl.when(jnp.logical_not(near))
+        def _():
+            walk(False)
+
+    return stage
+
+
+def _stage_planes(s: int, k: int, v, j, in_v, st_v, out_v):
+    """``(src, dst)`` of stage ``s`` at wavefront step ``j``, as
+    ``(ref, slot)`` planes: vplanes ``v - 1, v, v + 1`` of the stage before
+    (the input ring holds vplane u at ``(u + k) % _N_IN``, a stage's array
+    at ``u % 3``) and vplane ``v`` of this one's (the last: the out slot)."""
+    if s == 1:
+        src = [(in_v, jnp.mod(v + u + k, _N_IN)) for u in (-1, 0, 1)]
+    else:
+        src = [(st_v[s - 2], jnp.mod(v + u, 3)) for u in (-1, 0, 1)]
+    if s == k:
+        return src, (out_v, jnp.mod(j, 2))
+    return src, (st_v[s - 1], jnp.mod(v, 3))
 
 
 def make_pallas_jacobi_multistep(
@@ -531,7 +765,6 @@ def make_pallas_jacobi_multistep(
     k: int,
     interpret: bool = False,
     vma=None,
-    _skip_yfill: bool = False,
     rows: Optional[int] = None,
 ):
     """Temporal-blocked Jacobi: advance the field ``k`` steps in ONE pass
@@ -582,20 +815,13 @@ def make_pallas_jacobi_multistep(
     single-block y axis (use :func:`plan_multistep_staging` /
     :func:`valid_strip_rows` to pick a legal height).
 
-    ``_skip_yfill`` is a TIMING-PROBE knob (scripts/probe_noyfill.py): it
-    skips the per-stage y-ring fills, so the kernel computes WRONG results.
+    Both layouts run ONE stage body, :func:`_make_multistep_stage`, between
+    a scratch array a stage.
     """
     if rows is not None:
-        if _skip_yfill:
-            raise ValueError("_skip_yfill probes the full-plane y rings")
         return _make_multistep_row_tiled(
             spec, k, rows, interpret=interpret, vma=vma
         )
-    if _skip_yfill:
-        from ..utils import logging as _log
-
-        _log.warn("make_pallas_jacobi_multistep(_skip_yfill=True): "
-                  "TIMING PROBE ONLY — results are WRONG by construction")
     if not spec.aligned:
         raise ValueError("pallas multistep requires GridSpec(aligned=True)")
     p = spec.padded()
@@ -622,11 +848,7 @@ def make_pallas_jacobi_multistep(
         raise ValueError("domain too shallow for this temporal depth")
     J = nz + 2 * k  # pipeline steps: input vplanes -k .. nz+k-1
     g = spec.global_size
-    hot_c = (g.x // 3, g.y // 2, g.z // 2)
-    cold_c = (g.x * 2 // 3, g.y // 2, g.z // 2)
-    thresh = (g.x // 10 + 1) ** 2
-    tight_x, kx, xo_k = _tight_x_layout(not mx, nx, xo, px)
-    xs = slice(xo_k, xo_k + nx)
+    tight_x, kx, xo_k = _multistep_x_layout(spec)
     N_IN = _N_IN  # input ring: 3 live planes + 1 in flight
 
     def ext(s):
@@ -636,13 +858,13 @@ def make_pallas_jacobi_multistep(
 
     def kernel(*refs):
         if use_org:
-            org, curr_hbm, nxt_hbm, out_hbm, in_v, st_v, out_v, s_in, s_out = refs
+            org, *refs = refs
             ozv = org[0] if mz else 0
             oyv = org[1] if my else 0
             oxv = org[2] if mx else 0
         else:
-            curr_hbm, nxt_hbm, out_hbm, in_v, st_v, out_v, s_in, s_out = refs
             ozv = oyv = oxv = 0
+        curr_hbm, nxt_hbm, out_hbm, in_v, *st_v, out_v, s_in, s_out = refs
         j = pl.program_id(0)
 
         def _xsl():
@@ -683,7 +905,7 @@ def make_pallas_jacobi_multistep(
             the ring spans the full valid extent so the next stage's
             shifted reads stay within filled cells."""
             xw = slice(xo_k - ex, xo_k + nx + ex)
-            if not my and not _skip_yfill:
+            if not my:
                 ref[slot, yo - 1, xw] = ref[slot, yo + ny - 1, xw]
                 ref[slot, yo + ny, xw] = ref[slot, yo, xw]
             if not mx and not tight_x:
@@ -693,84 +915,25 @@ def make_pallas_jacobi_multistep(
                 ref[slot, yw, xo + nx] = ref[slot, yw, xo]
 
         fill_wrap(in_v, jnp.mod(j, N_IN), *ext(0))
+        stage = _make_multistep_stage(spec, py, my)
 
         for s in range(1, k + 1):
             @pl.when(j >= 2 * s)
             def _(s=s):
                 v = j - k - s  # this stage's output vplane
                 ey, ex = ext(s)
-
-                def prev_plane(u):
-                    """(ref, slot) holding stage s-1 (or input) vplane u."""
-                    if s == 1:
-                        return in_v, jnp.mod(u + k, N_IN)
-                    return st_v, jnp.mod(u, 3)
-
-                def rd(u, ys, xsl):
-                    ref, slot = prev_plane(u)
-                    if s == 1:
-                        return ref[slot, ys, xsl]
-                    return ref[s - 2, slot, ys, xsl]
-
-                cy = slice(yo - ey, yo + ny + ey)
-                cx = slice(xo_k - ex, xo_k + nx + ex)
-                if tight_x:
-                    x_lo, x_hi = _roll_x_pair(rd(v, cy, cx), nx, 1)
-                else:
-                    x_lo = rd(v, cy, slice(xo_k - ex - 1, xo_k + nx + ex - 1))
-                    x_hi = rd(v, cy, slice(xo_k - ex + 1, xo_k + nx + ex + 1))
-                avg = (
-                    x_lo
-                    + x_hi
-                    + rd(v, slice(yo - ey - 1, yo + ny + ey - 1), cx)
-                    + rd(v, slice(yo - ey + 1, yo + ny + ey + 1), cx)
-                    + rd(v - 1, cy, cx)
-                    + rd(v + 1, cy, cx)
-                ) / 6.0  # divide: bit-parity with ops.jacobi.jacobi_sweep
+                src, dst = _stage_planes(s, k, v, j, in_v, st_v, out_v)
                 if s == k:
                     # the same out slot was last used at step j-2; drain it
                     @pl.when(j >= 2 * k + 2)
                     def _():
                         out_dma(j - 2).wait()
 
-                def write(plane):
-                    if s == k:
-                        out_v[jnp.mod(j, 2), yo:yo + ny, xs] = plane
-                    else:
-                        st_v[s - 1, jnp.mod(v, 3), cy, cx] = plane
-
-                # sphere fix-up only on planes intersecting the spheres
-                # (both share the same z center and radius). Halo-extended
-                # cells of a multi-block axis can sit beyond the global
-                # extent (v < 0 / index >= g); their true coordinate is the
-                # periodic wrap — without it a boundary-crossing sphere
-                # would clamp differently here than on the owning block.
                 zg = jnp.mod(ozv + v, g.z) if mz else jnp.mod(v, nz)
-                near = jnp.abs(zg - hot_c[2]) <= g.x // 10
-
-                @pl.when(near)
-                def _():
-                    shape = (ny + 2 * ey, nx + 2 * ex)
-                    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0) + (oyv - ey)
-                    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + (oxv - ex)
-                    if my:
-                        row = jnp.mod(row, g.y)
-                    if mx:
-                        col = jnp.mod(col, g.x)
-                    dz2 = (zg - hot_c[2]) ** 2
-                    hot = (row - hot_c[1]) ** 2 + (col - hot_c[0]) ** 2 + dz2 < thresh
-                    cold = jnp.logical_and(
-                        jnp.logical_not(hot),
-                        (row - cold_c[1]) ** 2 + (col - cold_c[0]) ** 2 + dz2 < thresh,
-                    )
-                    write(jnp.where(hot, HOT_TEMP, jnp.where(cold, COLD_TEMP, avg)))
-
-                @pl.when(jnp.logical_not(near))
-                def _():
-                    write(avg)
-
+                stage(src, dst, 0, _stage_rows(spec, k, None, s), zg,
+                      oyv - yo, oxv - xo_k)
                 if s < k:
-                    fill_wrap(st_v.at[s - 1], jnp.mod(v, 3), ey, ex)
+                    fill_wrap(st_v[s - 1], jnp.mod(v, 3), ey, ex)
 
         @pl.when(j >= 2 * k)
         def _():
@@ -787,7 +950,10 @@ def make_pallas_jacobi_multistep(
         out_shape = jax.ShapeDtypeStruct((pz, py, px), jnp.float32, vma=frozenset(vma))
     scratch = [
         pltpu.VMEM((N_IN, py, kx), jnp.float32),
-        pltpu.VMEM((max(k - 1, 1), 3, py, kx), jnp.float32),
+        # an array a stage below the last: a stage's loads and stores are
+        # then provably apart (in one array at traced slots the scheduler
+        # chained every group behind the one before it)
+        *[pltpu.VMEM((3, py, kx), jnp.float32) for _ in range(k - 1)],
         pltpu.VMEM((2, py, kx), jnp.float32),
         pltpu.SemaphoreType.DMA((N_IN,)),
         pltpu.SemaphoreType.DMA((2,)),
@@ -873,20 +1039,17 @@ def _make_multistep_row_tiled(
     n_ty = -(-ny // ty)
     J = nz + 2 * k  # wavefront steps per strip: input vplanes -k .. nz+k-1
     g = spec.global_size
-    hot_c = (g.x // 3, g.y // 2, g.z // 2)
-    cold_c = (g.x * 2 // 3, g.y // 2, g.z // 2)
-    thresh = (g.x // 10 + 1) ** 2
-    tight_x, kx, xo_k = _tight_x_layout(not mx, nx, xo, px)
-    xs = slice(xo_k, xo_k + nx)
+    tight_x, kx, xo_k = _multistep_x_layout(spec)
 
     def kernel(*refs):
         if use_org:
-            org, curr_hbm, nxt_hbm, out_hbm, in_v, st_v, out_v, s_in, s_out, s_wrap = refs
+            org, *refs = refs
             ozv = org[0] if mz else 0
             oxv = org[2] if mx else 0
         else:
-            curr_hbm, nxt_hbm, out_hbm, in_v, st_v, out_v, s_in, s_out, s_wrap = refs
             ozv = oxv = 0
+        (curr_hbm, nxt_hbm, out_hbm, in_v, *st_v, out_v, s_in, s_out,
+         s_wrap) = refs
         yi = pl.program_id(0)
         j = pl.program_id(1)
         y0 = yo + jnp.minimum(yi * ty, ny - ty)  # uneven final strip re-anchors
@@ -987,73 +1150,29 @@ def _make_multistep_row_tiled(
                 ref[slot, yw, xo + nx] = ref[slot, yw, xo]
 
         fill_wrap_x(in_v, slot_j, k)
+        stage = _make_multistep_stage(spec, R, True)
 
         for s in range(1, k + 1):
             @pl.when(j >= 2 * s)
             def _(s=s):
                 v = j - k - s  # this stage's output vplane
                 es = k - s
-                ex = es if mx else 0
-
-                def rd(u, ys, xsl):
-                    if s == 1:
-                        return in_v[jnp.mod(u + k, _N_IN), ys, xsl]
-                    return st_v[s - 2, jnp.mod(u, 3), ys, xsl]
-
-                cy = slice(hp - es, hp + ty + es)
-                cx = slice(xo_k - ex, xo_k + nx + ex)
-                if tight_x:
-                    x_lo, x_hi = _roll_x_pair(rd(v, cy, cx), nx, 1)
-                else:
-                    x_lo = rd(v, cy, slice(xo_k - ex - 1, xo_k + nx + ex - 1))
-                    x_hi = rd(v, cy, slice(xo_k - ex + 1, xo_k + nx + ex + 1))
-                avg = (
-                    x_lo
-                    + x_hi
-                    + rd(v, slice(hp - es - 1, hp + ty + es - 1), cx)
-                    + rd(v, slice(hp - es + 1, hp + ty + es + 1), cx)
-                    + rd(v - 1, cy, cx)
-                    + rd(v + 1, cy, cx)
-                ) / 6.0  # divide: bit-parity with ops.jacobi.jacobi_sweep
+                src, dst = _stage_planes(s, k, v, j, in_v, st_v, out_v)
                 if s == k:
                     # the same out slot was last used at step j-2; drain it
                     @pl.when(j >= 2 * k + 2)
                     def _():
                         out_dma(j - 2).wait()
 
-                def write(plane):
-                    if s == k:
-                        out_v[jnp.mod(j, 2), :, xs] = plane
-                    else:
-                        st_v[s - 1, jnp.mod(v, 3), cy, cx] = plane
-
-                # sphere fix-up from global coordinates; strip rows (and the
-                # wrap-pad of edge strips) sit at their wrapped global y
+                # strip rows (and the wrap-pad of edge strips) sit at their
+                # wrapped global y
                 zg = jnp.mod(ozv + v, g.z) if mz else jnp.mod(v, nz)
-                near = jnp.abs(zg - hot_c[2]) <= g.x // 10
-
-                @pl.when(near)
-                def _():
-                    shape = (ty + 2 * es, nx + 2 * ex)
-                    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-                    row = jnp.mod(row + (y0 - yo) - es, g.y)
-                    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + (oxv - ex)
-                    if mx:
-                        col = jnp.mod(col, g.x)
-                    dz2 = (zg - hot_c[2]) ** 2
-                    hot = (row - hot_c[1]) ** 2 + (col - hot_c[0]) ** 2 + dz2 < thresh
-                    cold = jnp.logical_and(
-                        jnp.logical_not(hot),
-                        (row - cold_c[1]) ** 2 + (col - cold_c[0]) ** 2 + dz2 < thresh,
-                    )
-                    write(jnp.where(hot, HOT_TEMP, jnp.where(cold, COLD_TEMP, avg)))
-
-                @pl.when(jnp.logical_not(near))
-                def _():
-                    write(avg)
-
+                # the out planes hold a strip's own rows alone
+                stage(src, dst, hp if s == k else 0,
+                      _stage_rows(spec, k, ty, s), zg, y0 - yo - hp,
+                      oxv - xo_k)
                 if s < k:
-                    fill_wrap_x(st_v.at[s - 1], jnp.mod(v, 3), es)
+                    fill_wrap_x(st_v[s - 1], jnp.mod(v, 3), es)
 
         @pl.when(j >= 2 * k)
         def _():
@@ -1070,7 +1189,7 @@ def _make_multistep_row_tiled(
         out_shape = jax.ShapeDtypeStruct((pz, py, px), jnp.float32, vma=frozenset(vma))
     scratch = [
         pltpu.VMEM((_N_IN, R, kx), jnp.float32),
-        pltpu.VMEM((max(k - 1, 1), 3, R, kx), jnp.float32),
+        *[pltpu.VMEM((3, R, kx), jnp.float32) for _ in range(k - 1)],
         pltpu.VMEM((2, ty, kx), jnp.float32),
         pltpu.SemaphoreType.DMA((_N_IN,)),
         pltpu.SemaphoreType.DMA((2,)),
