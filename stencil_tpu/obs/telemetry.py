@@ -178,7 +178,9 @@ NAME_FIELDS = {
     # in aligned groups of group_rows, at most groups_per_trip a trip of
     # its loop, rows_walked in all (whole groups: at least rows_computed),
     # lane_rolls_per_vreg for x -+ 1 (whole rows, in both layouts), between
-    # stage_buffers scratch arrays (no reader of their own:
+    # stage_buffers scratch arrays; and the wrap_dmas an edge strip issues
+    # a grid step for its periodic y rows (0: full planes), wrap_prefetch
+    # grid steps ahead of their use (no reader of their own:
     # kernel_ms_per_iter and stencil_kernel_roofline show the effect)
     "kernel.multistep.staging": (("module", str), ("k", int), ("rows", int),
                                  ("strips", int), ("halo_rows", int),
@@ -188,7 +190,9 @@ NAME_FIELDS = {
                                  ("groups_per_trip", int),
                                  ("rows_walked", int),
                                  ("lane_rolls_per_vreg", int),
-                                 ("stage_buffers", int)),
+                                 ("stage_buffers", int),
+                                 ("wrap_dmas", int),
+                                 ("wrap_prefetch", int)),
     # once a jacobi3d.run(): the depth k (value) its halos were realized
     # for, the steps a dispatch runs, how they divide into deep-halo
     # passes of k and single steps (k = 1: no pass, the loop builder's own

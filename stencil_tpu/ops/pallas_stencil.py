@@ -559,7 +559,11 @@ def multistep_staging(spec: GridSpec, k: int, rows: Optional[int]) -> dict:
     ``group_rows``, at most ``groups_per_trip`` a trip of its loop,
     ``rows_walked`` in all (whole groups, a re-anchored last trip's twice),
     with ``lane_rolls_per_vreg`` for ``x -+ 1`` (whole rows are rolled in
-    both layouts), between ``stage_buffers`` scratch arrays."""
+    both layouts), between ``stage_buffers`` scratch arrays. And how an edge
+    strip gets its periodic y rows: ``wrap_dmas`` DMAs from the opposite
+    face a grid step (one strip is both edges: 2; full planes copy in VMEM:
+    0), started ``wrap_prefetch`` grid steps ahead of their use, as the slab
+    is."""
     ny = spec.base.y
     beyond = k * (k - 1)        # 2 (k - s) rows over the stages s = 1..k
     if rows is None:
@@ -580,7 +584,9 @@ def multistep_staging(spec: GridSpec, k: int, rows: Optional[int]) -> dict:
             "rows_walked": strips * _GROUP_ROWS * sum(
                 per_trip * trips for _, _, per_trip, trips in walks),
             "lane_rolls_per_vreg": 2,
-            "stage_buffers": k - 1}
+            "stage_buffers": k - 1,
+            "wrap_dmas": 0 if rows is None else 2 if strips == 1 else 1,
+            "wrap_prefetch": 0 if rows is None else 1}
 
 
 def _make_multistep_stage(spec: GridSpec, slab_rows: int, wrap_rows: bool):
@@ -1010,9 +1016,11 @@ def _make_multistep_row_tiled(
     anchored at output row ``y0`` holds virtual row ``y0 - hp + r``
     (``hp = round8(k)`` wrap-pad rows each side); virtual rows outside
     [yo, yo + ny) are the periodic wrap, delivered to edge strips by a
-    second hp-row DMA from the opposite face (both HBM row offsets and the
-    8-aligned VMEM offsets 0 / hp / hp + ty are DMA-legal, so no staged
-    single-row copies are needed). Stage s computes rows
+    second hp-row DMA from the opposite face that is started and waited
+    for WITH the slab's, a grid step ahead of its use (both HBM row offsets
+    and the 8-aligned VMEM offsets 0 / hp / hp + ty are DMA-legal, so no
+    staged single-row copies are needed; alone after the slab's wait its
+    latency was 24 % of the kernel at 768^3). Stage s computes rows
     [hp - (k-s), hp + ty + (k-s)) — interior strips recompute up to k rows
     each side of their output rows instead of reading a neighbor strip,
     which is what unchains the staging footprint from the plane size."""
@@ -1063,36 +1071,46 @@ def _make_multistep_row_tiled(
             return zo + jnp.mod(step - k, nz)  # wrapped physical plane
 
         def in_event(step, go):
-            """Start or wait the main slab DMA of input ``step``. Edge
-            strips skip the rows the wrap DMAs deliver, so every VMEM
-            destination offset/extent stays 8-row aligned and no fetch
-            leaves the valid [yo, yo + ny) rows."""
+            """Start or wait the DMAs of input ``step``: the main slab and,
+            on an edge strip, the opposite face's ``hp`` rows (periodic y)
+            with it, on the same ring slot. The slab skips the rows a wrap
+            delivers, so the destinations are disjoint, every VMEM
+            offset/extent stays 8-row aligned and no fetch leaves the valid
+            [yo, yo + ny) rows."""
             ph = in_plane(step)
             slot = jnp.mod(step, _N_IN)
 
-            def cp(src_lo, n_rows, dst_off):
+            def cp(sem, src_lo, n_rows, dst_off):
                 return pltpu.make_async_copy(
                     curr_hbm.at[pl.ds(ph, 1), pl.ds(src_lo, n_rows), _xsl()],
                     in_v.at[pl.ds(slot, 1), pl.ds(dst_off, n_rows)],
-                    s_in.at[slot],
+                    sem.at[slot],
                 )
 
+            def fetch(slab, *wraps):
+                go(cp(s_in, *slab))
+                for rows in wraps:
+                    go(cp(s_wrap, *rows))
+
+            below = (yo + ny - hp, hp, 0)   # the top face's rows, under row 0
+            above = (yo, hp, hp + ty)
+
             if n_ty == 1:
-                go(cp(y0, ty, hp))
+                fetch((y0, ty, hp), below, above)
                 return
 
             @pl.when(yi == 0)
             def _():
-                go(cp(y0, ty + hp, hp))
+                fetch((y0, ty + hp, hp), below)
 
             @pl.when(yi == n_ty - 1)
             def _():
-                go(cp(y0 - hp, hp + ty, 0))
+                fetch((y0 - hp, hp + ty, 0), above)
 
             if n_ty > 2:
                 @pl.when(jnp.logical_and(yi > 0, yi < n_ty - 1))
                 def _():
-                    go(cp(y0 - hp, R, 0))
+                    fetch((y0 - hp, R, 0))
 
         def out_dma(step):
             ph = zo + (step - 2 * k)
@@ -1112,34 +1130,6 @@ def _make_multistep_row_tiled(
 
         in_event(j, lambda c: c.wait())
 
-        # periodic y: edge strips receive the opposite face's rows (after
-        # the main slab DMA so the writes cannot race it)
-        slot_j = jnp.mod(j, _N_IN)
-        ph_j = in_plane(j)
-
-        def wrap_cp(src_lo, dst_off):
-            return pltpu.make_async_copy(
-                curr_hbm.at[pl.ds(ph_j, 1), pl.ds(src_lo, hp), _xsl()],
-                in_v.at[pl.ds(slot_j, 1), pl.ds(dst_off, hp)],
-                s_wrap,
-            )
-
-        def run_sync(cp):
-            cp.start()
-            cp.wait()
-
-        if n_ty == 1:
-            run_sync(wrap_cp(yo + ny - hp, 0))
-            run_sync(wrap_cp(yo, hp + ty))
-        else:
-            @pl.when(yi == 0)
-            def _():
-                run_sync(wrap_cp(yo + ny - hp, 0))
-
-            @pl.when(yi == n_ty - 1)
-            def _():
-                run_sync(wrap_cp(yo, hp + ty))
-
         def fill_wrap_x(ref, slot, es):
             """Periodic x ring of a plane whose valid row extent is
             [hp - es, hp + ty + es) — covers the next stage's x-shifted
@@ -1149,7 +1139,7 @@ def _make_multistep_row_tiled(
                 ref[slot, yw, xo - 1] = ref[slot, yw, xo + nx - 1]
                 ref[slot, yw, xo + nx] = ref[slot, yw, xo]
 
-        fill_wrap_x(in_v, slot_j, k)
+        fill_wrap_x(in_v, jnp.mod(j, _N_IN), k)
         stage = _make_multistep_stage(spec, R, True)
 
         for s in range(1, k + 1):
@@ -1193,7 +1183,7 @@ def _make_multistep_row_tiled(
         pltpu.VMEM((2, ty, kx), jnp.float32),
         pltpu.SemaphoreType.DMA((_N_IN,)),
         pltpu.SemaphoreType.DMA((2,)),
-        pltpu.SemaphoreType.DMA(()),
+        pltpu.SemaphoreType.DMA((_N_IN,)),  # the wrap rows of a ring slot
     ]
     params = pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"),

@@ -47,6 +47,20 @@ FORMS = {
                                          TIGHT, 3, 16),
     "row strips, inline-x": (Dim3(20, 32, 12), Dim3(1, 1, 1),
                              Radius.constant(1), 3, 16),
+    # the forms of the strips' periodic y rows that no cell runs (the 768^3
+    # cell has two strips: a wrap DMA each): one strip is both edges
+    "one row strip, both wraps": (Dim3(128, 32, 12), Dim3(1, 1, 1), TIGHT, 4,
+                                  32),
+    "one row strip, inline-x": (Dim3(20, 32, 12), Dim3(1, 1, 1),
+                                Radius.constant(1), 3, 32),
+    "z split, row strips on deep halos": (
+        Dim3(128, 32, 24), Dim3(1, 1, 2), Radius.constant(2).without_x(), 5,
+        16),
+    "z split, one row strip": (
+        Dim3(128, 32, 24), Dim3(1, 1, 2), Radius.constant(3).without_x(), 4,
+        32),
+    "x split, inline-x row strips": (Dim3(32, 32, 12), Dim3(2, 1, 1),
+                                     Radius.constant(2), 4, 16),
 }
 
 
@@ -189,14 +203,45 @@ def _eqns(jaxpr, out):
     return out
 
 
+def _ring_dma_groups(jaxpr, ring, depth=0, out=None):
+    """The DMAs into the input ring (VMEM of shape ``ring``) of a kernel's
+    jaxpr, in program order, grouped by the ``pl.when`` body that holds
+    them: ``(depth, [(primitive, rows, first row, slots, semaphores)])`` a
+    body, ``depth`` the bodies round it; ``slots`` (the ring's, the
+    semaphore's) and ``semaphores`` (the array) are the jaxpr's variables,
+    one object where one value is used twice."""
+    out = [] if out is None else out
+    here = []
+    for e in jaxpr.eqns:
+        if e.primitive.name in ("dma_start", "dma_wait"):
+            _, _, dst, (at,), sem, (sem_at,), *_ = jax.tree_util.tree_unflatten(
+                e.params["tree"], e.invars)
+            if dst.aval.shape == ring:
+                slot, rows = at.indices[0], at.indices[1]
+                assert slot.size == 1 and sem.aval.shape == (ps._N_IN,)
+                here.append((e.primitive.name, rows.size, rows.start,
+                             (slot.start, sem_at.indices[0]), sem))
+        for branch in e.params.get("branches", ()):
+            _ring_dma_groups(branch.jaxpr, ring, depth + 1, out)
+    if here:
+        out.append((depth, here))
+    return out
+
+
 @pytest.mark.parametrize("layout, rows", [("tight", None), ("tight", 16),
-                                          ("inline", None)])
+                                          ("inline", None), ("tight", 24),
+                                          ("tight", 48), ("inline", 16),
+                                          ("inline", 48)])
 def test_staging_counter_says_what_the_traced_body_does(layout, rows):
     """``kernel.multistep.staging``'s walk against the jaxpr of the built
     kernel: a loop a stage and sphere branch, its trips; two lane rolls and
     two sublane rotations a group in both layouts (whole rows are loaded
     and rolled, inline x halos too); a scratch array a stage; and no vector
-    value larger than a trip's groups."""
+    value larger than a trip's groups. And an edge strip's ``wrap_dmas``
+    (three strips, two, one; full planes have none): started in the branch
+    that starts the slab of the same ring slot, ``wrap_prefetch`` grid
+    step ahead (the branches of ``j == 0`` and ``j + 1 < J``), waited for
+    beside the slab's wait, and nothing starts after a wait."""
     k = 3
     nx = 256 if layout == "tight" else 20
     spec = GridSpec(Dim3(nx, 48, 12), Dim3(1, 1, 1),
@@ -217,6 +262,43 @@ def test_staging_counter_says_what_the_traced_body_does(layout, rows):
                if len(v.aval.shape) == 3]
     assert scratch.count((3, staged, kx)) == plan["stage_buffers"]
     assert (ps._N_IN, staged, kx) in scratch
+
+    groups = _ring_dma_groups(call.params["jaxpr"], (ps._N_IN, staged, kx))
+    kinds = [{name for name, *_ in dmas} for _, dmas in groups]
+    assert all(len(kind) == 1 for kind in kinds), "a body starts OR waits"
+    starts = [g for g, kind in zip(groups, kinds) if kind == {"dma_start"}]
+    waits = [g for g, kind in zip(groups, kinds) if kind == {"dma_wait"}]
+    assert groups == starts + waits, "no DMA into the ring starts after a wait"
+
+    def shape_of(group):
+        depth, dmas = group
+        if rows:    # the slab and the wraps of ONE step: one slot value
+            assert len({id(v) for *_, slots, _ in dmas for v in slots}) == 1
+        sems = [id(sem) for *_, sem in dmas]
+        # the slab on one semaphore array, the wrap rows on the other
+        assert len(set(sems)) == min(len(dmas), 2) and len(set(sems[1:])) <= 1
+        return depth, tuple((n, first) for _, n, first, _, _ in dmas)
+
+    # a strip position (first, last, interior: ``pl.when`` bodies, one
+    # deeper) waits for what it started, and starts it twice: step 0's at
+    # ``j == 0``, step j + 1's a grid step ahead
+    held = [shape_of(g) for g in waits]
+    ahead = [shape_of(g) for g in starts]
+    assert ahead == 2 * [(depth + 1, dmas) for depth, dmas in held]
+    # ... the wrap rows with the slab, in the same bodies
+    edge = [dmas for _, dmas in held if len(dmas) > 1]
+    assert plan["wrap_prefetch"] == (1 if edge else 0)
+    strips, hp, ty = plan["strips"], 8, rows or 0
+    assert [dmas for _, dmas in held] == {
+        None: [((staged, 0),)],
+        # slab, then the rows below and above it
+        1: [((ty, hp), (hp, 0), (hp, hp + ty))],
+        2: [((ty + hp, hp), (hp, 0)), ((hp + ty, 0), (hp, hp + ty))],
+        3: [((ty + hp, hp), (hp, 0)), ((hp + ty, 0), (hp, hp + ty)),
+            ((staged, 0),)],
+    }[strips if rows else None]
+    assert {len(dmas) - 1 for dmas in edge} == (
+        {plan["wrap_dmas"]} if rows else set())
 
     walks = [ps._stage_walk(*ps._stage_rows(spec, k, rows, s))
              for s in range(1, k + 1)]

@@ -64,6 +64,9 @@ def test_rows_computed_and_kept_by_hand(n, k, rows, computed, kept):
     got = multistep_staging(spec, k, rows)
     assert (got["rows_computed"], got["rows_kept"]) == (computed, kept)
     assert got["strips"] == 2 and got["rows"] == rows
+    # two strips, both edges: a wrap DMA each a grid step, fetched a step
+    # ahead with the slab
+    assert (got["wrap_dmas"], got["wrap_prefetch"]) == (1, 1)
     assert got["halo_rows"] == 2 * 8 * -(-k // 8)      # round8(k) a side
 
 
@@ -73,6 +76,8 @@ def test_full_planes_compute_what_they_keep():
     assert got["rows_computed"] == got["rows_kept"] == 10 * 512
     p = _cube(512).padded()
     assert got["halo_rows"] == p.y - 512
+    # the y ring is copied in VMEM: no wrap DMA
+    assert (got["wrap_dmas"], got["wrap_prefetch"]) == (0, 0)
     assert got["vmem_bytes"] == 4 * 512 * p.y * (4 + 3 * 9 + 2)
     # across a split y axis the deep halo is recomputed: k - s rows a side
     split = GridSpec(Dim3(128, 64, 32), Dim3(1, 2, 1), Radius.constant(4))
