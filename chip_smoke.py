@@ -899,6 +899,63 @@ def phase_mg(devs, klass: str, rehearsal: bool) -> dict:
     return facts
 
 
+def phase_hpcg(devs, n: int, rehearsal: bool) -> dict:
+    """HPCG's own problem through ``hpcg.run`` on one chip: a whole set of
+    50 iterations from x = 0 in ONE compiled program a dispatch (four
+    levels, each a fixed ``DistributedDomain``; on the chip the three
+    tight-x levels in the compiled ``hpcg_symgs`` sweep and the box kernel
+    with its wrapped lanes dropped, by the application's own
+    ``hpcg.iter_plan``), then every owned cell of x against the float64
+    reference of the benchmark's 50 iterations on the host, with the
+    residual's fall beside the reference's. float32 and float64 walk the
+    same 50 iterations apart (CG is a recurrence): what is held is the
+    distance to the reference where the solve stands, and the residual.
+    The rehearsal walks 16^3, where every level is plain XLA."""
+    import numpy as np
+
+    from benchmark.reference import hpcg as reference
+    from stencil_tpu.apps import hpcg
+    from stencil_tpu.obs import telemetry
+    from stencil_tpu.parallel.exchange import unshard_blocks
+
+    with PallasRecorder() as rec:
+        r = hpcg.run(n=n, sets=1, devices=devs[:1])
+    dd, hs = r["levels"][0]
+    plan = telemetry.get().records(kind="counter", name="hpcg.iter_plan")[-1]
+    assert len(plan["levels"]) == len(r["levels"]) == reference.LEVELS
+    tight = [lv for lv in plan["levels"] if lv["layout"] == "tight_x"]
+    facts = {"iter_ms": round(1e3 * r["iter_trimean_s"], 3),
+             "tight_x_levels": len(tight),
+             "normr_over_normr0": r["relative_residual"][-1],
+             "max_abs_x_minus_1": r["error"][-1]}
+    if not rehearsal:
+        assert len(tight) >= 2, plan
+        for lv in tight:
+            impls = {name: op["impl"] for name, op in lv["operators"].items()}
+            assert impls["hpcg_symgs"] == "pallas", lv
+            assert impls.get("hpcg_resid", "pallas") == "pallas", lv
+        assert tight[0]["operators"]["hpcg_spmv"]["impl"] == "pallas"
+        require_compiled_kernels(
+            rec, ["make_pallas_hpcg_symgs", "make_pallas_mg_box",
+                  "make_pallas_hpcg_spmv"], rehearsal)
+    for lv, lhs in r["levels"]:
+        for q in lhs:
+            held = unshard_blocks(lv.get_curr(lhs[q]), lv.spec)
+            assert np.isfinite(held).all(), (lv.size, q)
+    want, norms, normr0 = reference.solve((n, n, n))
+    got = unshard_blocks(dd.get_curr(hs["x"]), dd.spec)
+    diff = float(np.abs(got - want).max())
+    say(f"hpcg {n}^3: normr/normr0 {r['relative_residual'][-1]:.6e} after "
+        f"50 iterations, the reference's {norms[-1] / normr0:.6e}; max |x - "
+        f"reference| = {diff:.3e}, max |x - 1| = {r['error'][-1]:.3e} (the "
+        f"reference's {float(np.abs(want - 1).max()):.3e})")
+    # the residual fell as the reference's did, to float32's floor at most
+    assert r["relative_residual"][-1] <= max(3.0 * norms[-1] / normr0, 1e-6)
+    assert diff <= max(2e-2 * float(np.abs(want - 1).max()), 1e-5), diff
+    facts["max_abs_diff_x"] = diff
+    return facts
+
+
 def _lbm_reference(f, omega: float, steps: int, planes: int = 8):
     """``steps`` steps of the benchmark's float64 reference from the 19
     whole periodic arrays ``f``: its own ``stream`` and ``collide``, the
@@ -1036,6 +1093,7 @@ def build_phases(devs, rehearsal: bool) -> list:
                 four, Dim3(32, 32, 16), p221, True)),
             ("jacobi", 1, lambda: phase_jacobi(devs, 16, True, ref_n=16)),
             ("mg_class_a", 1, lambda: phase_mg(devs[:1], "W", True)),
+            ("hpcg_256", 1, lambda: phase_hpcg(devs, 16, True)),
             ("exchange", 1, lambda: phase_exchange(
                 devs[:1], Dim3(16, 16, 16), Dim3(1, 1, 1), True)),
             ("astaroth", 1, lambda: phase_astaroth(devs, 16, 16, True)),
@@ -1072,6 +1130,9 @@ def build_phases(devs, rehearsal: bool) -> list:
         # class A: 256^3, 256^3 and 128^3 on the tight-x layout, the six
         # levels below them ONE call that keeps them in VMEM
         ("mg_class_a", 1, lambda: phase_mg(devs[:1], "A", False)),
+        # 256^3 and 128^3 on the tight-x layout in the sweep's and the box
+        # kernel, 64^3 and 32^3 inline: a whole set of HPCG's own problem
+        ("hpcg_256", 1, lambda: phase_hpcg(devs, 256, False)),
         ("serve", 1, lambda: phase_serve(devs, 64, 16, False)),
     ]
 

@@ -47,6 +47,10 @@ CARRY = "stencil.carry"                     # reshapes, swaps, stacking
 # OUTSIDE the kernel, halo and glue scopes of what runs on the level, and of
 # no layer itself, so an op keeps the layer of its innermost scope
 MG_LEVEL = "stencil.mg.level"
+# a Krylov solver's reductions (dot products, norms: each a device scalar
+# that the next kernel of the same program reads) and its vector updates
+SOLVER_DOT = "stencil.solver.dot"
+SOLVER_AXPY = "stencil.solver.axpy"
 
 SCOPES: Dict[str, str] = {
     HALO_SELF_FILL: LAYER_HALO,
@@ -56,6 +60,8 @@ SCOPES: Dict[str, str] = {
     SWEEP_SHELL: LAYER_GLUE,
     MASK: LAYER_GLUE,
     CARRY: LAYER_GLUE,
+    SOLVER_DOT: LAYER_GLUE,
+    SOLVER_AXPY: LAYER_GLUE,
 }
 
 # pallas_call name -> layer. The self-fills and the split-x pack and unpack
@@ -76,6 +82,14 @@ KERNELS: Dict[str, str] = {
     "mg_coarse": LAYER_KERNELS,
     # D3Q19's stream-collide pass, Pallas or plain XLA (:func:`kernel_scope`)
     "lbm_d3q19": LAYER_KERNELS,
+    # HPCG: half an eight-colour Gauss-Seidel sweep (the planes of one z
+    # parity), the operator alone and in the residual (the MG box builder
+    # at HPCG's weights), injection and its transpose; Pallas or plain XLA
+    "hpcg_symgs": LAYER_KERNELS,
+    "hpcg_spmv": LAYER_KERNELS,
+    "hpcg_resid": LAYER_KERNELS,
+    "hpcg_restrict": LAYER_KERNELS,
+    "hpcg_prolong": LAYER_KERNELS,
     "self_fill_x": LAYER_HALO,
     "self_fill_y": LAYER_HALO,
     "self_fill_z": LAYER_HALO,
@@ -92,8 +106,9 @@ EXCHANGE_LOOP = "stencil_exchange_loop"
 ISO3DFD_LOOP = "stencil_iso3dfd_loop"
 MG_ITER = "stencil_mg_iter"
 LBM_STEP = "stencil_lbm_step"
+HPCG_ITER = "stencil_hpcg_iter"
 MODULES = (JACOBI_LOOP, JACOBI_STEP, ASTAROTH_ITER, EXCHANGE_LOOP,
-           ISO3DFD_LOOP, MG_ITER, LBM_STEP)
+           ISO3DFD_LOOP, MG_ITER, LBM_STEP, HPCG_ITER)
 
 
 def layer_of(scope: Optional[str]) -> Optional[str]:

@@ -266,6 +266,33 @@ NAME_FIELDS = {
                       ("kernel", str), ("chunk", int), ("block_cells", int),
                       ("carried", dict), ("halo_bytes_sent", int),
                       ("halo_bytes_if_all", int)),
+    # what ops/hpcg.make_hpcg_iter built, once per build (value: CG
+    # iterations a dispatch): per level, finest first, its ``level`` (4 the
+    # finest, 1 the coarsest), ``grid``, ``layout`` (tight_x / inline) and
+    # per operator (``operators``: hpcg_symgs, hpcg_resid, hpcg_spmv,
+    # hpcg_restrict FROM the level, hpcg_prolong ONTO it) what implements
+    # it (pallas / xla), its calls an iteration and the least bytes a call
+    # moves (benchmark/apps/hpcg.py holds the configuration to it). Only
+    # what differs from build to build: the colours' order is each sweep
+    # kernel's own ``hpcg.symgs_plan``, and that a dispatch has three
+    # reductions and no way to the host is read off the lowered program
+    # (tests/test_hpcg.py), not stated here
+    "hpcg.iter_plan": (("module", str), ("levels", list)),
+    # what ops/pallas_hpcg.make_pallas_hpcg_symgs built, when its body is
+    # first traced (value: the z parity of the planes the call updates):
+    # the level's grid, the colours in the call's order, the calls a sweep
+    # takes, the x planes a grid step holds, its VMEM scratch, and a vreg
+    # of a colour's update: lane rolls, the lane selects that drop their
+    # wrap on the fixed x, sublane shifts; and the lane rolls a vreg of a
+    # whole plane (the neighbouring planes' part once, then four colours).
+    # No benchmark reader: kernel_scope_ms_per_iter shows the effect
+    "hpcg.symgs_plan": (("grid", list), ("reverse", bool), ("order", list),
+                        ("passes_per_sweep", int), ("planes_in_ring", int),
+                        ("scratch_bytes", int),
+                        ("lane_rolls_per_vreg_colour", int),
+                        ("lane_selects_per_vreg_colour", int),
+                        ("sublane_shifts_per_vreg_colour", int),
+                        ("lane_rolls_per_vreg_plane", int)),
     # what a composed per-block exchange body issued, once per build
     # (value: ppermutes in all): per axis phase its ``axis``, the
     # ``permutes`` issued for it and whether its two directions went as
@@ -367,6 +394,9 @@ KNOWN_NAMES = frozenset(NAME_FIELDS) | frozenset({
     "mg.iter_trimean_s", "mg.mcells_per_s", "mg.rnm2",
     "lbm.realize", "lbm.init", "lbm.warmup", "lbm.steps", "lbm.step",
     "lbm.step_trimean_s", "lbm.mlups",
+    "hpcg.realize", "hpcg.init", "hpcg.warmup", "hpcg.steps", "hpcg.iter",
+    "hpcg.normr", "hpcg.normr0", "hpcg.iter_trimean_s",
+    "hpcg.mcells_per_s",
     "exchange.realize", "exchange.steps",
     "jacobi.realize", "jacobi.steps",
     "halo.self_fill.bytes_dma", "halo.split_x.bytes_dma",

@@ -99,6 +99,9 @@ class _Level(NamedTuple):
     n: tuple                    # owned cells of a block (z, y, x)
     tight: bool
     pallas: bool
+    # what a tight-x row's x -+ 1 means at the row's two ends: the periodic
+    # wrap (NPB MG), or nothing (a fixed x: ``ops/hpcg``'s hierarchy)
+    wrap_x: bool = True
 
 
 def _level(ex: HaloExchange, number: int, dtype, use_pallas) -> _Level:
@@ -109,7 +112,8 @@ def _level(ex: HaloExchange, number: int, dtype, use_pallas) -> _Level:
         raise ValueError(
             "MG's boxes read all 26 neighbours across a periodic wrap: a "
             "faces-only plan leaves edges and corners unfilled, a fixed "
-            "axis its ghost")
+            "axis its ghost (a hierarchy of FIXED domains is hpcg's: "
+            "ops/hpcg.make_hpcg_iter)")
     if not spec.is_uniform():
         raise ValueError(f"level {spec.global_size}: blocks must be equal")
     r = spec.radius
@@ -139,9 +143,16 @@ def _rows(a, lv: _Level, dz: int = 0, dy: int = 0):
 
 def _cols(t, lv: _Level, dx: int = 0):
     """The owned columns of whole rows shifted by dx: a slice where the x
-    halo is inline, a roll where x wraps in the row itself."""
+    halo is inline; where the row is the whole axis (tight-x) a roll on a
+    periodic x, and on a fixed one a shift that brings zeros in."""
     if lv.tight:
-        return jnp.roll(t, -dx, axis=2) if dx else t
+        if not dx:
+            return t
+        if lv.wrap_x:
+            return jnp.roll(t, -dx, axis=2)
+        pad = [(0, 0), (0, 0), (max(-dx, 0), max(dx, 0))]
+        return lax.slice_in_dim(jnp.pad(t, pad), max(dx, 0),
+                                max(dx, 0) + t.shape[2], axis=2)
     xo, nx = lv.lo[2], lv.n[2]
     return t[:, :, xo + dx:xo + dx + nx]
 
@@ -177,7 +188,14 @@ def _put(dst, owned, lv: _Level):
 
 def _xla_box(lv: _Level, w, sign: float, has_p: bool = True):
     """``fn(q, p, dst) -> dst`` with ``p +- Box(q)`` in its owned cells
-    (``p`` and ``dst`` may be one array; without ``p`` the box alone)."""
+    (``p`` and ``dst`` may be one array; without ``p`` the box alone).
+
+    What it assumes of the x axis: an inline level reads its x halo
+    columns as they stand (the fill's, or a fixed axis's ghost); a tight-x
+    level is the whole axis in its rows, and ``x -+ 1`` wraps where
+    ``lv.wrap_x`` (a periodic x) and reads zero beyond the two ends where
+    not (a fixed x). In y and z it reads the halo rows and planes as they
+    stand."""
 
     def fn(q, p, dst):
         box = _box(q, lv, w)

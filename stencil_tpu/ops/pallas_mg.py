@@ -71,6 +71,85 @@ def box_supported(spec: GridSpec, dtype) -> bool:
     return b.y % 8 == 0 and o.y % 8 == 0 and o.y >= 1 and o.y + b.y < p.y
 
 
+def x_neighbours(px: int, periodic_x: bool = True):
+    """``both(t) = t[x - 1] + t[x + 1]`` of whole tight-x rows by two lane
+    rolls. A roll WRAPS: on a periodic x that is the neighbourhood; on a
+    fixed x (``periodic_x`` false: a Dirichlet ring that the tight-x
+    layout does not store) the wrapped lane of each roll is dropped, so
+    that a cell at the domain's x edge reads nothing from beyond it (two
+    lane selects a roll pair)."""
+
+    def both(t):
+        left, right = pltpu.roll(t, 1, 1), pltpu.roll(t, px - 1, 1)
+        if not periodic_x:
+            # lane 0 of the first lane tile and the last lane of the last
+            lane = jax.lax.broadcasted_iota(jnp.int32, (t.shape[0], LANE), 1)
+            first = jnp.where(lane == 0, 0.0, left[:, :LANE])
+            last = jnp.where(lane == LANE - 1, 0.0, right[:, px - LANE:])
+            if px == LANE:
+                left, right = first, last
+            else:
+                left = jnp.concatenate([first, left[:, LANE:]], axis=1)
+                right = jnp.concatenate([right[:, :px - LANE], last], axis=1)
+        return left + right
+
+    return both
+
+
+def keep_plane(q_ref, c_ring, y_ring, slot, yo: int, ny: int, rows: int):
+    """The fresh plane of the box kernel's ring: kept, with its y sums,
+    over the owned rows."""
+    for row in range(0, ny, rows):
+        at = pl.ds(yo + row, rows)
+        c_ring[slot, pl.ds(row, rows), :] = q_ref[at, :]
+        y_ring[slot, pl.ds(row, rows), :] = (
+            q_ref[pl.ds(yo + row - 1, rows), :]
+            + q_ref[pl.ds(yo + row + 1, rows), :])
+
+
+def box_of_rows(c_ring, y_ring, lo, mid, hi, own, weights, both):
+    """``Box`` over the rows ``own`` of the ring's middle plane, by the
+    source's partial sums (module docstring); a class whose weight is 0 is
+    left out."""
+    w0, w1, w2, w3 = weights
+    c = c_ring[mid, own, :]
+    u1 = (y_ring[mid, own, :] + c_ring[lo, own, :]
+          + c_ring[hi, own, :])
+    box = w0 * c
+    if w1:
+        box = box + w1 * (both(c) + u1)
+    if w2 or w3:
+        u2 = y_ring[lo, own, :] + y_ring[hi, own, :]
+        if w2:
+            box = box + w2 * (u2 + both(u1))
+        if w3:
+            box = box + w3 * both(u2)
+    return box
+
+
+def box_stream(spec: GridSpec, vma=None):
+    """What every streamed box call shares: ``(plane, fresh, written,
+    shape, scratch, grid)``: a plane's block shape, the index maps of the
+    plane step s brings and of the one it writes, the result's shape, the
+    two rings, and the grid (the owned planes and the two round them)."""
+    p, off, b = spec.padded(), spec.compute_offset(), spec.base
+
+    def fresh(s):
+        return (s + off.z - 1, 0, 0)
+
+    def written(s):
+        # the plane step s writes, held at the first owned plane until the
+        # ring is full (no write-back happens while the index stands still)
+        return (jnp.clip(s - 2, 0, b.z - 1) + off.z, 0, 0)
+
+    shape = jax.ShapeDtypeStruct(
+        (p.z, p.y, p.x), jnp.float32,
+        vma=frozenset(vma) if vma is not None else None)
+    scratch = [pltpu.VMEM((3, b.y, p.x), jnp.float32),
+               pltpu.VMEM((3, b.y, p.x), jnp.float32)]
+    return (None, p.y, p.x), fresh, written, shape, scratch, (b.z + 2,)
+
+
 def make_pallas_mg_box(
     spec: GridSpec,
     name: str,
@@ -79,23 +158,34 @@ def make_pallas_mg_box(
     separate_dst: bool = False,
     interpret: bool = False,
     vma=None,
+    periodic_x: bool = True,
 ):
     """Build ``fn(q, p) -> out`` (``out`` aliased to ``p``) or, with
     ``separate_dst``, ``fn(q, p, dst) -> out`` (aliased to ``dst``, which
     is not read) over padded ``(pz, py, px)`` fp32 blocks: ``out = p + sign
     * Box(q)`` on the owned cells. ``name`` is the operator's kernel name
-    (``mg_resid`` or ``mg_psinv``)."""
+    (``mg_resid`` or ``mg_psinv``; ``hpcg_resid``: HPCG's residual, the
+    same box at the weights ``(26, -1, -1, -1)``).
+
+    What it assumes of the x axis: the block is the whole axis on the
+    tight-x layout (no x halo), and ``x -+ 1`` is a lane roll of the row.
+    ``periodic_x`` (the domain's own ``periodic[0]``) says what the roll's
+    wrap means: the periodic neighbour (NPB MG), or nothing at all (a fixed
+    x: the wrapped lanes are dropped, :func:`x_neighbours`). In y and z the
+    kernel reads the block's halo rows and planes as they stand: the
+    periodic fill's on a periodic axis, the application's ghost ring (zero
+    for a homogeneous Dirichlet face) on a fixed one."""
     if not box_supported(spec, jnp.float32):
         raise ValueError("pallas mg box unsupported on this spec")
     if len(weights) != 4:
         raise ValueError("a box takes four class weights")
-    w0, w1, w2, w3 = (float(w) for w in weights)
+    weights = tuple(float(w) for w in weights)
     p, off, b = spec.padded(), spec.compute_offset(), spec.base
-    pz, py, px = p.z, p.y, p.x
-    zo, yo = off.z, off.y
-    nz, ny = b.z, b.y
+    py, px = p.y, p.x
+    yo, ny = off.y, b.y
     rows = _chunk_rows(ny, px)
     minus = sign < 0
+    both = x_neighbours(px, periodic_x)
 
     def kernel(*refs):
         if separate_dst:
@@ -104,36 +194,14 @@ def make_pallas_mg_box(
             q_ref, p_ref, out_ref, c_ring, y_ring = refs
         s = pl.program_id(0)
         hi = s % 3
-
-        # the fresh plane: kept, with its y sums, over the owned rows
-        for row in range(0, ny, rows):
-            at = pl.ds(yo + row, rows)
-            c_ring[hi, pl.ds(row, rows), :] = q_ref[at, :]
-            y_ring[hi, pl.ds(row, rows), :] = (
-                q_ref[pl.ds(yo + row - 1, rows), :]
-                + q_ref[pl.ds(yo + row + 1, rows), :])
+        keep_plane(q_ref, c_ring, y_ring, hi, yo, ny, rows)
 
         @pl.when(s >= 2)
         def _():
             lo, mid = (s + 1) % 3, (s + 2) % 3          # s - 2, s - 1
-
-            def both(t):
-                return pltpu.roll(t, 1, 1) + pltpu.roll(t, px - 1, 1)
-
             for row in range(0, ny, rows):
-                own = pl.ds(row, rows)
-                c = c_ring[mid, own, :]
-                u1 = (y_ring[mid, own, :] + c_ring[lo, own, :]
-                      + c_ring[hi, own, :])
-                box = w0 * c
-                if w1:
-                    box = box + w1 * (both(c) + u1)
-                if w2 or w3:
-                    u2 = y_ring[lo, own, :] + y_ring[hi, own, :]
-                    if w2:
-                        box = box + w2 * (u2 + both(u1))
-                    if w3:
-                        box = box + w3 * both(u2)
+                box = box_of_rows(c_ring, y_ring, lo, mid, hi,
+                                  pl.ds(row, rows), weights, both)
                 at = pl.ds(yo + row, rows)
                 out_ref[at, :] = (p_ref[at, :] - box if minus
                                   else p_ref[at, :] + box)
@@ -142,28 +210,16 @@ def make_pallas_mg_box(
                 out_ref[edge, :] = (jnp.zeros((stop - start, px), jnp.float32)
                                     if separate_dst else p_ref[edge, :])
 
-    def fresh(s):
-        return (s + zo - 1, 0, 0)
-
-    def written(s):
-        # the plane step s writes, held at the first owned plane until the
-        # ring is full (no write-back happens while the index stands still)
-        return (jnp.clip(s - 2, 0, nz - 1) + zo, 0, 0)
-
-    plane = (None, py, px)
-    shape = jax.ShapeDtypeStruct(
-        (pz, py, px), jnp.float32,
-        vma=frozenset(vma) if vma is not None else None)
+    plane, fresh, written, shape, scratch, grid = box_stream(spec, vma)
     in_specs = [pl.BlockSpec(plane, fresh), pl.BlockSpec(plane, written)]
     if separate_dst:
         in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     params = dict(
-        grid=(nz + 2,),
+        grid=grid,
         out_shape=shape,
         in_specs=in_specs,
         out_specs=pl.BlockSpec(plane, written),
-        scratch_shapes=[pltpu.VMEM((3, ny, px), jnp.float32),
-                        pltpu.VMEM((3, ny, px), jnp.float32)],
+        scratch_shapes=scratch,
         input_output_aliases={2 if separate_dst else 1: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -174,7 +230,9 @@ def make_pallas_mg_box(
         return scopes.kernel_call("mg_resid", kernel, **params)
     if name == "mg_psinv":
         return scopes.kernel_call("mg_psinv", kernel, **params)
-    raise ValueError(f"{name!r} is not a box operator of MG")
+    if name == "hpcg_resid":
+        return scopes.kernel_call("hpcg_resid", kernel, **params)
+    raise ValueError(f"{name!r} is not a box operator")
 
 
 # ------------------------------------------------------------ the transfers

@@ -23,7 +23,7 @@ SMOKE = os.path.join(REPO, "chip_smoke.py")
 # every phase but astaroth, whose interpret-mode substeps alone take half
 # a minute: the full rehearsal is the slow-tier test below
 FAST_PHASES = ("four_chip_jacobi,four_chip_iso3dfd,mg_class_b_x4,lbm_x4,"
-               "four_chip_exchange,jacobi,mg_class_a,exchange,serve")
+               "four_chip_exchange,jacobi,mg_class_a,hpcg_256,exchange,serve")
 
 
 def _smoke(args, tmp_path, cwd=REPO, script=SMOKE, timeout=300):
