@@ -904,7 +904,8 @@ def phase_hpcg(devs, n: int, rehearsal: bool) -> dict:
     50 iterations from x = 0 in ONE compiled program a dispatch (four
     levels, each a fixed ``DistributedDomain``; on the chip the three
     tight-x levels in the compiled ``hpcg_symgs`` sweep and the box kernel
-    with its wrapped lanes dropped, by the application's own
+    with its wrapped lanes dropped, the transfers between two of them in
+    ``hpcg_restrict`` / ``hpcg_prolong``, by the application's own
     ``hpcg.iter_plan``), then every owned cell of x against the float64
     reference of the benchmark's 50 iterations on the host, with the
     residual's fall beside the reference's. float32 and float64 walk the
@@ -935,9 +936,14 @@ def phase_hpcg(devs, n: int, rehearsal: bool) -> dict:
             assert impls["hpcg_symgs"] == "pallas", lv
             assert impls.get("hpcg_resid", "pallas") == "pallas", lv
         assert tight[0]["operators"]["hpcg_spmv"]["impl"] == "pallas"
+        # between two tight-x levels the transfers are kernels too
+        for lv in tight[:-1]:
+            for name in ("hpcg_restrict", "hpcg_prolong"):
+                assert lv["operators"][name]["impl"] == "pallas", lv
         require_compiled_kernels(
             rec, ["make_pallas_hpcg_symgs", "make_pallas_mg_box",
-                  "make_pallas_hpcg_spmv"], rehearsal)
+                  "make_pallas_hpcg_spmv", "make_pallas_hpcg_restrict",
+                  "make_pallas_hpcg_prolong"], rehearsal)
     for lv, lhs in r["levels"]:
         for q in lhs:
             held = unshard_blocks(lv.get_curr(lhs[q]), lv.spec)

@@ -27,8 +27,14 @@ the Dirichlet face, holds zero and is never written, and so do the
 alignment rows. A level whose rows are whole lane tiles takes the tight-x
 layout, where x has no ring and the kernels drop the lane roll's wrap; on
 a TPU its operator is ``pallas_mg``'s box kernel at HPCG's weights and its
-sweep ``hpcg_symgs``. The others (64^3 of the 512^3 problem; everything off
-a TPU) are plain XLA over the same arrays.
+sweep ``hpcg_symgs``, and the transfers between two such levels are
+``hpcg_restrict`` and ``hpcg_prolong``: they fetch the even owned planes of
+the fine level alone (the cells a transfer uses are those planes' even
+rows, a quarter of the level), and the prolongation adds in place.
+The others (64^3 of the 512^3 problem, and the transfers between it and
+128^3; everything off a TPU) are plain XLA over the same arrays: there a
+transfer is a product with a 0/1 matrix along x and a pass over the whole
+fine block.
 
 :func:`make_hpcg_iter` returns ONE jitted program, ``step(state, b) ->
 state``: the state is donated and comes back in its slots, ``b`` is read
@@ -53,9 +59,11 @@ from jax import lax
 from ..obs import scopes, telemetry
 from ..parallel.exchange import HaloExchange, Method
 from . import mg as _mg
-from .pallas_hpcg import (DIAGONAL, WEIGHTS, make_pallas_hpcg_spmv,
+from .pallas_hpcg import (DIAGONAL, WEIGHTS, even_columns,
+                          make_pallas_hpcg_prolong,
+                          make_pallas_hpcg_restrict, make_pallas_hpcg_spmv,
                           make_pallas_hpcg_symgs, symgs_supported)
-from .pallas_mg import make_pallas_mg_box
+from .pallas_mg import make_pallas_mg_box, transfer_supported
 
 LEVELS = 4                  # numberOfMgLevels
 SET_ITERS = 50              # iterations a set
@@ -143,18 +151,6 @@ def _xla_symgs(lv: _mg._Level):
     return fn
 
 
-def _even_columns(nx: int, dtype):
-    """(nx, nx / 2) of 0 and 1: column c takes x = 2c. A stride of 2 along
-    x would cut every lane tile in half (XLA makes it a gather of one
-    element a cell); as a product the MXU does it, and with the highest
-    precision exactly (one term a result, the weight 1)."""
-    import numpy as np
-
-    m = np.zeros((nx, nx // 2), np.dtype(dtype))
-    m[2 * np.arange(nx // 2), np.arange(nx // 2)] = 1
-    return jnp.asarray(m)
-
-
 def _xla_restrict(fine: _mg._Level, coarse: _mg._Level):
     """``fn(t_fine, r_coarse) -> r_coarse``: injection, the coarse point c
     on the fine point 2c."""
@@ -162,7 +158,7 @@ def _xla_restrict(fine: _mg._Level, coarse: _mg._Level):
     def fn(t, rc):
         even = _mg._owned(t, fine)[::2, ::2, :]
         picked = jnp.einsum("zyx,xc->zyc", even,
-                            _even_columns(fine.n[2], t.dtype),
+                            even_columns(fine.n[2], t.dtype),
                             precision=lax.Precision.HIGHEST)
         return _mg._put(rc, picked, coarse)
 
@@ -177,7 +173,7 @@ def _xla_prolong(coarse: _mg._Level, fine: _mg._Level):
 
     def fn(xc, xf):
         wide = jnp.einsum("zyc,xc->zyx", _mg._owned(xc, coarse),
-                          _even_columns(fine.n[2], xf.dtype),
+                          even_columns(fine.n[2], xf.dtype),
                           precision=lax.Precision.HIGHEST)
         (zo, yo, xo), (pz, py, px), (mz, my, _) = fine.lo, fine.block, coarse.n
         spread = lax.pad(wide, xf.dtype.type(0),
@@ -252,8 +248,17 @@ def _build(exchanges, dtype, use_pallas, interpret):
                 spec, "hpcg_resid", WEIGHTS, -1.0, separate_dst=True,
                 interpret=interpret, periodic_x=False) if box
                 else _mg._xla_box(lv, WEIGHTS, -1.0), box)
-            put(i, "hpcg_restrict", _xla_restrict(lv, levels[i + 1]), False)
-            put(i, "hpcg_prolong", _xla_prolong(levels[i + 1], lv), False)
+            below = levels[i + 1]
+            # both levels on the box kernel's layout: the transfers fetch
+            # the even fine planes alone
+            kernels = box and below.pallas and transfer_supported(
+                spec, below.ex.spec, dtype)
+            put(i, "hpcg_restrict", make_pallas_hpcg_restrict(
+                spec, below.ex.spec, interpret=interpret) if kernels
+                else _xla_restrict(lv, below), kernels)
+            put(i, "hpcg_prolong", make_pallas_hpcg_prolong(
+                below.ex.spec, spec, interpret=interpret) if kernels
+                else _xla_prolong(below, lv), kernels)
         if i == 0:
             if box:
                 put(i, "hpcg_spmv", make_pallas_hpcg_spmv(
@@ -272,11 +277,14 @@ def iter_plan(levels, impls, itemsize: int) -> list:
         calls = {"hpcg_symgs": 2 if i + 1 < LEVELS else 1,
                  "hpcg_resid": 1, "hpcg_spmv": 1, "hpcg_restrict": 1,
                  "hpcg_prolong": 1}
-        # a sweep reads x whole twice a direction and r once, writes x once
+        # a sweep reads x whole twice a direction and r once, writes x
+        # once; a transfer uses the even rows of the level's even planes
+        # (a quarter of it: read, and for the prolongation written back)
+        # and the level below (an eighth)
         arrays = {"hpcg_symgs": 8 * cells, "hpcg_resid": 3 * cells,
                   "hpcg_spmv": 2 * cells,
-                  "hpcg_restrict": cells // 8 + cells // 8,
-                  "hpcg_prolong": 2 * cells + cells // 8}
+                  "hpcg_restrict": cells // 4 + cells // 8,
+                  "hpcg_prolong": 2 * (cells // 4) + cells // 8}
         g = lv.ex.spec.global_size
         out.append({
             "level": lv.number, "grid": [g.z, g.y, g.x],

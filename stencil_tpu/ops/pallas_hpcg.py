@@ -1,5 +1,7 @@
 """Pallas TPU kernels for HPCG on the tight-x layout: the operator alone
-(``hpcg_spmv``) and the eight-colour Gauss-Seidel sweep (``hpcg_symgs``).
+(``hpcg_spmv``), the eight-colour Gauss-Seidel sweep (``hpcg_symgs``) and
+the transfers between two tight-x levels (``hpcg_restrict``,
+``hpcg_prolong``).
 
 HPCG's operator is a 27-point box, 26 at the centre and -1 at every
 neighbour INSIDE the grid: ``pallas_mg``'s box at the weights ``(26, -1,
@@ -26,6 +28,17 @@ neighbours by one sublane shift each way and two lane rolls, the colour's
 rows picked by a lane and row parity mask. The plane is written in place.
 Nothing is dropped or deferred: the order of the eight colours and the
 in-place reads are the source's.
+
+The transfers. HPCG's restriction is an injection, ``rc[c] = t[2c]``, and
+its prolongation ``x[2c] += xc[c]``: both touch only the fine cells whose
+three indices are even. On this layout that is the even owned planes' even
+owned rows, a quarter of the level: a grid step takes ONE even fine plane
+whole (the odd ones, half the level, are never fetched), picks or updates
+its even rows at a row stride of 2 (on a buffer one lane tile wide, the
+only kind Mosaic takes a stride on) and its even columns by a product with
+a 0/1 matrix on the MXU (``pallas_mg._dot3``: exact, one term a result).
+The prolongation works in place on the fine array, so the planes it does
+not fetch stay as they are.
 """
 
 from __future__ import annotations
@@ -37,8 +50,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..domain.grid import GridSpec
 from ..obs import scopes, telemetry
-from .pallas_mg import (box_of_rows, box_stream, box_supported, _chunk_rows,
-                        keep_plane, x_neighbours)
+from .pallas_mg import (LANE, box_of_rows, box_stream, box_supported,
+                        _chunk_rows, _dot3, keep_plane, transfer_supported,
+                        x_neighbours)
 
 DIAGONAL = 26.0
 WEIGHTS = (DIAGONAL, -1.0, -1.0, -1.0)      # HPCG's A as a box by class
@@ -341,5 +355,149 @@ def make_pallas_hpcg_symgs(spec: GridSpec, parity: int, reverse: bool,
 
     def fn(x, r):
         return call(x, r, lane_odd, row_odd)
+
+    return fn
+
+
+# ------------------------------------------------------------ the transfers
+
+
+def even_columns(nx: int, dtype):
+    """(nx, nx / 2) of 0 and 1: column c takes x = 2c. A stride of 2 along
+    x would cut every lane tile in half (XLA makes it a gather of one
+    element a cell); as a product the MXU does it, exactly where every
+    piece of the value is kept (one term a result, the weight 1). The
+    matrix of a whole row is the one of a pair of lane tiles on its
+    diagonal: the kernels multiply by that block."""
+    import numpy as np
+
+    m = np.zeros((nx, nx // 2), np.float32)
+    m[2 * np.arange(nx // 2), np.arange(nx // 2)] = 1
+    return jnp.asarray(m, dtype)
+
+
+def _transfer_geometry(fine: GridSpec, coarse: GridSpec):
+    pf, of, bf = fine.padded(), fine.compute_offset(), fine.base
+    pc, oc, bc = coarse.padded(), coarse.compute_offset(), coarse.base
+    return pf, of, bf, pc, oc, bc
+
+
+def make_pallas_hpcg_restrict(fine: GridSpec, coarse: GridSpec,
+                              interpret: bool = False):
+    """Build ``fn(t_fine, rc) -> rc`` (aliased to ``rc``, which is not
+    read) over padded fp32 blocks of two tight-x levels: the injection
+    ``rc[c] = t[2c]`` onto the coarse level's owned cells, zero on the
+    ghost and padding rows of the planes it writes; the coarse ghost planes
+    are not written. One coarse plane a grid step from the ONE fine plane
+    it reads: the fine level's even owned planes are read whole (half of
+    it, for the quarter in their even rows), the coarse level written."""
+    if not transfer_supported(fine, coarse, jnp.float32):
+        raise ValueError("pallas hpcg restrict unsupported on these specs")
+    pf, of, bf, pc, oc, bc = _transfer_geometry(fine, coarse)
+    my = bc.y
+    tiles = pf.x // LANE
+    matrix = even_columns(2 * LANE, jnp.bfloat16)
+
+    def kernel(t_ref, m_ref, _old, out_ref, stage):
+        for pair in range(tiles // 2):
+            even = []
+            for k in (2 * pair, 2 * pair + 1):
+                # y: rows 2j of the owned rows, at a row stride of 2 (which
+                # Mosaic takes on a buffer one lane tile wide)
+                stage[k] = t_ref[pl.ds(of.y, bf.y), pl.ds(k * LANE, LANE)]
+                even.append(stage[k, pl.ds(0, my, stride=2), :])
+            out_ref[pl.ds(oc.y, my), pl.ds(pair * LANE, LANE)] = _dot3(
+                jnp.concatenate(even, axis=1), m_ref[...])
+        for start, stop in ((0, oc.y), (oc.y + my, pc.y)):
+            out_ref[pl.ds(start, stop - start), :] = jnp.zeros(
+                (stop - start, pc.x), jnp.float32)
+
+    call = scopes.kernel_call(
+        "hpcg_restrict", kernel,
+        grid=(bc.z,),
+        out_shape=jax.ShapeDtypeStruct((pc.z, pc.y, pc.x), jnp.float32),
+        in_specs=[
+            pl.BlockSpec((None, pf.y, pf.x), lambda c: (of.z + 2 * c, 0, 0)),
+            pl.BlockSpec(matrix.shape, lambda c: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((None, pc.y, pc.x),
+                               lambda c: (oc.z + c, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((tiles, bf.y, LANE), jnp.float32)],
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )
+
+    def fn(t, rc):
+        return call(t, matrix, rc)
+
+    return fn
+
+
+def make_pallas_hpcg_prolong(coarse: GridSpec, fine: GridSpec,
+                             interpret: bool = False):
+    """Build ``fn(xc, xf) -> xf`` (IN PLACE) over padded fp32 blocks of two
+    tight-x levels: ``xf[2c] += xc[c]`` over the coarse level's owned
+    cells. One even owned fine plane a grid step, read and written back
+    whole with the coarse plane spread onto its even rows and columns; the
+    odd planes, the ghost planes and every other cell of the block are not
+    touched: half the fine level read and written back, the coarse level
+    read."""
+    if not transfer_supported(fine, coarse, jnp.float32):
+        raise ValueError("pallas hpcg prolong unsupported on these specs")
+    pf, of, bf, pc, oc, bc = _transfer_geometry(fine, coarse)
+    my = bc.y
+    tiles = pf.x // LANE
+    # the transpose: coarse lane c onto fine lane 2c
+    matrix = even_columns(2 * LANE, jnp.bfloat16).T
+
+    def kernel(c_ref, m_ref, old_ref, out_ref, tall):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            # the odd rows: what is added to them, every step, is this 0
+            tall[...] = jnp.zeros(tall.shape, jnp.float32)
+
+        own = pl.ds(of.y, bf.y)
+        for pair in range(tiles // 2):
+            wide = _dot3(c_ref[pl.ds(oc.y, my), pl.ds(pair * LANE, LANE)],
+                         m_ref[...])
+            for half in (0, 1):
+                k = 2 * pair + half
+                cols = pl.ds(k * LANE, LANE)
+                # y: coarse row j onto fine row 2j, at a row stride of 2
+                tall[k, pl.ds(0, my, stride=2), :] = wide[
+                    :, half * LANE:(half + 1) * LANE]
+                out_ref[own, cols] = old_ref[own, cols] + tall[k]
+        for start, stop in ((0, of.y), (of.y + bf.y, pf.y)):
+            edge = pl.ds(start, stop - start)
+            out_ref[edge, :] = old_ref[edge, :]
+
+    def plane(c):
+        return (of.z + 2 * c, 0, 0)
+
+    fine_plane = (None, pf.y, pf.x)
+    call = scopes.kernel_call(
+        "hpcg_prolong", kernel,
+        grid=(bc.z,),
+        out_shape=jax.ShapeDtypeStruct((pf.z, pf.y, pf.x), jnp.float32),
+        in_specs=[
+            pl.BlockSpec((None, pc.y, pc.x), lambda c: (oc.z + c, 0, 0)),
+            pl.BlockSpec(matrix.shape, lambda c: (0, 0)),
+            pl.BlockSpec(fine_plane, plane),
+        ],
+        out_specs=pl.BlockSpec(fine_plane, plane),
+        scratch_shapes=[pltpu.VMEM((tiles, bf.y, LANE), jnp.float32)],
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )
+
+    def fn(xc, xf):
+        return call(xc, matrix, xf)
 
     return fn

@@ -22,6 +22,15 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 _COPY = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\S+)\s+copy\(", re.M)
 
 
+def _big_copies(text):
+    """``(instruction, shape)`` of every ``copy`` of a megabyte or more (of
+    four-byte elements) in a compiled module's text."""
+    return [(instr, shape) for instr, shape in _COPY.findall(text)
+            if 4 * math.prod(int(n) for n in re.findall(
+                r"\d+", shape.split("[", 1)[1].split("]")[0]) or [1])
+            >= 1 << 20]
+
+
 def _exchange(topo, n, radius, dim):
     """The tight-x exchange the applications realize on TPU devices."""
     from stencil_tpu.domain.grid import GridSpec
@@ -290,10 +299,7 @@ def test_the_lbm_step_holds_two_lattices_and_nothing_else(topo,
                           ("self_fill_z", 6)):
         calls = re.findall(rf"%{kernel}[.\d]* = .*tpu_custom_call", text)
         assert len(calls) == count, (kernel, len(calls))
-    big = [(instr, shape) for instr, shape in _COPY.findall(text)
-           if 4 * math.prod(int(n) for n in re.findall(
-               r"\d+", shape.split("[", 1)[1].split("]")[0]) or [1])
-           >= 1 << 20]
+    big = _big_copies(text)
     assert not big, big
     mem = compiled.memory_analysis()
     lattices = 2 * lbm.Q * 4 * math.prod(spec.block_shape_zyx())
@@ -301,3 +307,48 @@ def test_the_lbm_step_holds_two_lattices_and_nothing_else(topo,
     assert mem.argument_size_in_bytes == lattices
     assert mem.alias_size_in_bytes == lattices
     assert mem.temp_size_in_bytes == 0
+
+
+def test_the_hpcg_iteration_transfers_in_place_and_holds_nothing_of_its_own(
+        topo, as_on_the_chip):
+    """``hpcg512.steady``'s own program (512^3, four levels, one CG
+    iteration with its V-cycle): the transfers between the tight-x levels
+    (512 <-> 256, 256 <-> 128) are Pallas calls, the prolongation's aliased
+    operand is the sweep's result itself (a ``copy`` there is a pass over
+    0.55 GB a call), the ONE ``copy`` of a block is the restart's ``r <-
+    b`` under its ``conditional``, and the program allocates nothing of its
+    own (the XLA transfers held 0.69 GB of temporaries)."""
+    from stencil_tpu.domain.grid import GridSpec
+    from stencil_tpu.geometry import Dim3
+    from stencil_tpu.obs import scopes, telemetry
+    from stencil_tpu.ops import hpcg
+    from stencil_tpu.parallel import HaloExchange, grid_mesh
+
+    d = Dim3(1, 1, 1)
+    mesh = grid_mesh(d, list(topo.devices)[:1])
+    exs = [HaloExchange(GridSpec(Dim3(*s), d, hpcg.level_radius(s)), mesh,
+                        periodic=(False,) * 3)
+           for s in hpcg.level_sizes((512,) * 3)]
+    scopes.clear()
+    hpcg.make_hpcg_iter(exs)
+    rec = scopes._registry[scopes.HPCG_ITER][-1]
+    compiled = rec["fn"].lower(*rec["args"]).compile()
+    text = compiled.as_text()
+    plan = telemetry.get().records(kind="counter", name="hpcg.iter_plan")[-1]
+    impl = [[lv["operators"].get(n, {}).get("impl")
+             for n in ("hpcg_restrict", "hpcg_prolong")]
+            for lv in plan["levels"]]
+    assert impl == [["pallas"] * 2, ["pallas"] * 2, ["xla"] * 2, [None] * 2]
+    for kernel, count in (("hpcg_restrict", 2), ("hpcg_prolong", 2),
+                          ("hpcg_resid", 3), ("hpcg_spmv", 1),
+                          ("hpcg_symgs", 24)):
+        calls = re.findall(rf"%{kernel}[.\d]* = .*tpu_custom_call", text)
+        assert len(calls) == count, (kernel, len(calls))
+    # what a prolongation updates in place is a sweep's result, uncopied
+    fed = re.findall(r"%hpcg_prolong[.\d]* = \S+ custom-call\(([^)]*)\)", text)
+    assert len(fed) == 2 and all(
+        ops.split(", ")[2].startswith("%hpcg_symgs") for ops in fed), fed
+    big = _big_copies(text)
+    assert [shape.split("{")[0] for _, shape in big] == [
+        "f32[1,1,1,514,528,512]"], big
+    assert compiled.memory_analysis().temp_size_in_bytes == 0

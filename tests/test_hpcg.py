@@ -21,11 +21,15 @@ from stencil_tpu.geometry import Dim3, Radius  # noqa: E402
 from stencil_tpu.obs import scopes, telemetry  # noqa: E402
 from stencil_tpu.ops import hpcg as ops  # noqa: E402
 from stencil_tpu.ops import mg as ops_mg  # noqa: E402
-from stencil_tpu.ops.pallas_hpcg import (WEIGHTS, make_pallas_hpcg_spmv,  # noqa: E402
+from stencil_tpu.ops.pallas_hpcg import (WEIGHTS, make_pallas_hpcg_prolong,  # noqa: E402
+                                         make_pallas_hpcg_restrict,
+                                         make_pallas_hpcg_spmv,
                                          make_pallas_hpcg_symgs, symgs_plan)
 from stencil_tpu.ops.pallas_mg import make_pallas_mg_box  # noqa: E402
 
 TIGHT = (128, 16, 16)       # x, y, z: the finest level alone is tight-x
+# the two finest levels are tight-x: a plane of 16 owned rows, and of 32
+TWO_TIGHT = {"16_rows": (256, 16, 16), "32_rows": (256, 32, 16)}
 
 
 @pytest.fixture
@@ -205,6 +209,82 @@ def test_the_kernels_match_the_reference_on_a_tight_x_level(x64_off):
     orders = {(s["value"], s["reverse"]): s["order"] for s in sweeps[-4:]}
     assert orders == {(0, False): [0, 1, 2, 3], (1, False): [4, 5, 6, 7],
                       (1, True): [7, 6, 5, 4], (0, True): [3, 2, 1, 0]}
+
+
+def _dense(lv, rng, ring: bool):
+    """A padded block of a level, dense in EVERY cell of the block or
+    (``ring`` false) in its owned cells with the ring and padding at zero,
+    among it values that need all three bfloat16 pieces of ``_dot3``."""
+    a = rng.uniform(-3, 3, lv.block).astype(np.float32)
+    a.flat[::7] = np.float32(1 + 2.0 ** -23)
+    a.flat[3::11] = np.float32(-3.0000002)
+    a.flat[5::13] = np.float32(1e-30)
+    if not ring:
+        (z, y, x), (nz, ny, nx) = lv.lo, lv.n
+        own = np.zeros(lv.block, bool)
+        own[z:z + nz, y:y + ny, x:x + nx] = True
+        a = np.where(own, a, np.float32(0))
+    return jnp.asarray(a)
+
+
+@pytest.mark.parametrize("grid", sorted(TWO_TIGHT))
+@pytest.mark.parametrize("which", ["restrict", "prolong"])
+def test_a_transfer_kernel_is_bit_for_bit_the_xla_transfer(which, grid,
+                                                           x64_off):
+    """Between two tight-x levels the injection and the prolongation are
+    Pallas kernels that fetch the even fine planes alone: on the WHOLE
+    padded block the bits of the XLA transfer (a picked value is the value,
+    the one addition is the one XLA makes), with the odd planes, rows and
+    columns of the fine level dense."""
+    levels = app.make_levels(TWO_TIGHT[grid], jax.devices()[:1], "float32")
+    built, _, impls = ops._build([lv.halo_exchange for lv, _ in levels],
+                                 jnp.dtype("float32"), True, True)
+    fine, coarse = built[0], built[1]
+    assert impls[(0, "hpcg_" + which)] == "pallas"
+    rng = np.random.RandomState(5)
+    if which == "restrict":
+        # the fine level's ring too is dense: the kernel must not read it
+        args = _dense(fine, rng, True), _dense(coarse, rng, False)
+        want = ops._xla_restrict(fine, coarse)(*args)
+        got = make_pallas_hpcg_restrict(fine.ex.spec, coarse.ex.spec,
+                                        interpret=True)(*args)
+        assert _ring_max(levels[1][0], np.asarray(got)[None, None, None]) == 0
+    else:
+        args = _dense(coarse, rng, False), _dense(fine, rng, False)
+        want = ops._xla_prolong(coarse, fine)(*args)
+        got = make_pallas_hpcg_prolong(coarse.ex.spec, fine.ex.spec,
+                                       interpret=True)(*args)
+        # one cell in eight moved, each by its coarse cell's value
+        moved = np.asarray(got) != np.asarray(args[1])
+        (z, y, x), (nz, ny, nx) = fine.lo, fine.n
+        assert moved.sum() > 0.9 * coarse.n[0] * coarse.n[1] * coarse.n[2]
+        moved[z:z + nz:2, y:y + ny:2, x:x + nx:2] = False
+        assert not moved.any()
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+
+
+def test_two_tight_x_levels_transfer_in_pallas_and_match_the_reference(
+        x64_off):
+    """256 x 16 x 16: levels 4 and 3 are tight-x, so the transfers between
+    them are the kernels; level 3's own (its coarse level lies inline with
+    an x ring) stay XLA, as does everything below."""
+    _three_dispatches(TWO_TIGHT["16_rows"], use_pallas=True, interpret=True)
+    plan = telemetry.get().records(kind="counter", name="hpcg.iter_plan")[-1]
+    assert [lv["layout"] for lv in plan["levels"]] == [
+        "tight_x", "tight_x", "inline", "inline"]
+    impl = [{n: lv["operators"][n]["impl"] for n in
+             ("hpcg_restrict", "hpcg_prolong") if n in lv["operators"]}
+            for lv in plan["levels"]]
+    assert impl == [{"hpcg_restrict": "pallas", "hpcg_prolong": "pallas"},
+                    {"hpcg_restrict": "xla", "hpcg_prolong": "xla"},
+                    {"hpcg_restrict": "xla", "hpcg_prolong": "xla"}, {}]
+    # what a transfer must move: a quarter of the fine level (its even
+    # planes' even rows), twice for the prolongation, and the coarse level
+    top, cells = plan["levels"][0]["operators"], 256 * 16 * 16
+    assert top["hpcg_restrict"]["bytes_min"] == 4 * (cells // 4 + cells // 8)
+    assert top["hpcg_prolong"]["bytes_min"] == 4 * (cells // 2 + cells // 8)
 
 
 def _tight_spec():
