@@ -63,10 +63,12 @@ class BuilderCapture:
 
 class PallasBuilds:
     """Every ``pl.pallas_call`` built while active, as plain facts: the
-    kernel function's outer name, the grid, the result shapes, whether it
-    is an interpret-mode build and, from its first call, the operand
-    shapes. The kernel descriptions under ``kernels/`` compute bytes and
-    operations from these, never from constants."""
+    kernel function's outer name, the ``name=`` the call was given (the
+    stem of its instruction's name in the compiled module), the grid, the
+    result shapes, whether it is an interpret-mode build and, from its
+    first call, the operand shapes. The kernel descriptions under
+    ``kernels/`` compute bytes and operations from these, never from
+    constants."""
 
     def __enter__(self):
         import jax
@@ -82,6 +84,7 @@ class PallasBuilds:
             outs = jax.tree.leaves(kw.get("out_shape"))
             rec = {
                 "kernel": kernel.__qualname__.split(".")[0],
+                "name": kw.get("name"),
                 "grid": tuple(int(g) for g in grid),
                 "out_shapes": [tuple(int(d) for d in o.shape) for o in outs],
                 "out_dtypes": [str(o.dtype) for o in outs],

@@ -16,7 +16,6 @@ plane, gives every reader ``None``, as ``scope_lib`` does.
 
 from __future__ import annotations
 
-import time
 from collections import defaultdict
 
 from benchmark import scope_lib
@@ -24,6 +23,7 @@ from benchmark import scope_lib
 STAGES = ("trace", "lower", "backend")
 RUN_SPANS = (".realize", ".init", ".warmup", ".steps")
 _STAGE_OF = {f"compile.{s}": s for s in STAGES}
+INSIDE = "INSIDE THE MEASURED WINDOW"
 
 
 def stage_of(rec: dict):
@@ -101,7 +101,7 @@ def read(ctx):
         out = split(telemetry.get().records(kind="span"))
         if not out["stages"]:
             return None
-        table(out, ctx["window"]["seconds"], time.time_ns(), ctx["say"])
+        table(out, ctx["window"], ctx["say"])
     except Exception as e:  # a reader reports, it never fails the run
         ctx["say"](f"compile: failed: {type(e).__name__}: {e}")
         return None
@@ -184,11 +184,12 @@ def _say_rows(say, stages, kernels) -> None:
                     + (f"; missed: {', '.join(missed)}" if missed else ""))
 
 
-def table(out: dict, window_s, now_ns, say) -> None:
+def table(out: dict, window, say) -> None:
     """By top-level span of ``run()``: its seconds and memory, each
     program's stages under it, and what no stage covers; then the stages
-    outside ``run()``, by where they lie against ``run()`` and (``window_s``
-    given: a traced run read at ``now_ns``) the measured window."""
+    outside ``run()``, by where they lie against ``run()`` and (``window``
+    given: the measured window of a traced run, ``t0_ns`` on the clock the
+    program's spans carry and ``seconds``) against the window."""
     under = defaultdict(list)
     outside = []
     for r, top in out["stages"]:
@@ -220,10 +221,10 @@ def table(out: dict, window_s, now_ns, say) -> None:
         return
     t_first = min((s["t0_ns"] for s in out["run"]), default=None)
     t_last = max((s["t1_ns"] for s in out["run"]), default=None)
-    quiet = None
-    if window_s is not None and t_last is not None:
-        quiet = quiet_stretch(
-            [r for r, _ in outside if r["t0_ns"] >= t_last], t_last, now_ns)
+    opens = closes = None
+    if window is not None and t_last is not None:
+        opens = window["t0_ns"]
+        closes = opens + int(window["seconds"] * 1e9)
 
     def where(r):
         if t_first is None:
@@ -232,11 +233,13 @@ def table(out: dict, window_s, now_ns, say) -> None:
             return "before run() (import)"
         if r["t0_ns"] < t_last:
             return "inside run(), under no span of it"
-        if quiet is None:
+        if opens is None:
             return "after run()"
-        if r["t0_ns"] < quiet[1]:
+        if r["t1_ns"] <= opens:
             return "after run(), before the window (seed, first_chunk_check, warmup)"
-        return "after the window (checks, readers, op_map)"
+        if r["t0_ns"] >= closes:
+            return "after the window (checks, readers, op_map)"
+        return INSIDE
 
     groups = defaultdict(list)
     for r, top in outside:
@@ -247,25 +250,8 @@ def table(out: dict, window_s, now_ns, say) -> None:
         say(f"compile: outside run(), {label}: " + ", ".join(
             f"{s} {secs[s]:.3f}" for s in STAGES))
         _say_rows(say, mine, out["kernels"])
-    if quiet is not None:
-        length = (quiet[1] - quiet[0]) / 1e9
-        say(f"compile: the longest stretch after run() with no stage record "
-            f"is {length:.3f} s; the measured window is {window_s:.3f} s: "
-            + ("it fits, no stage record need lie inside it"
-               if length >= window_s else "A STAGE RAN INSIDE THE WINDOW"))
-
-
-def quiet_stretch(records, start_ns, end_ns):
-    """``(from, to)`` of the longest stretch between ``start_ns`` and
-    ``end_ns`` that no record of ``records`` touches. The trace's times are
-    relative to its own start, so the window cannot be placed on the
-    records' clock: it lies where nothing compiled for at least its
-    length, and where no such stretch exists a stage ran inside it."""
-    best, free_from = (start_ns, start_ns), start_ns
-    for r in sorted(records, key=lambda r: r["t0_ns"]):
-        if r["t0_ns"] - free_from > best[1] - best[0]:
-            best = (free_from, r["t0_ns"])
-        free_from = max(free_from, r["t1_ns"])
-    if end_ns - free_from > best[1] - best[0]:
-        best = (free_from, end_ns)
-    return best
+    if opens is not None:
+        say(f"compile: the measured window opened {(opens - t_last) / 1e9:.3f}"
+            f" s after run() and lasted {window['seconds']:.3f} s: "
+            + (f"{len(groups[INSIDE])} STAGE RECORD(S) LIE INSIDE THE WINDOW"
+               if groups.get(INSIDE) else "no stage record lies inside it"))

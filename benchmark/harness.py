@@ -124,6 +124,7 @@ def run_window(session, seconds: float, annotate) -> dict:
     times, enqueue, failed = [], [], 0
     usage0 = resource.getrusage(resource.RUSAGE_SELF)
     with annotate("bench.window"):
+        t0_ns = time.time_ns()      # the program's spans carry this clock
         t0 = time.perf_counter()
         end = t0 + seconds
         now = t0
@@ -147,8 +148,9 @@ def run_window(session, seconds: float, annotate) -> dict:
         t1 = time.perf_counter()
     usage1 = resource.getrusage(resource.RUSAGE_SELF)
     k = session.facts["iters_per_dispatch"]
-    return {"seconds": t1 - t0, "dispatch_s": times, "enqueue_s": enqueue,
-            "dispatches": len(times), "iters_per_dispatch": k,
+    return {"seconds": t1 - t0, "t0_ns": t0_ns, "dispatch_s": times,
+            "enqueue_s": enqueue, "dispatches": len(times),
+            "iters_per_dispatch": k,
             "iterations": k * (len(times) - failed), "failed": failed,
             "host": {"cpu_s": usage1.ru_utime + usage1.ru_stime
                      - usage0.ru_utime - usage0.ru_stime,

@@ -51,14 +51,6 @@ def class_ms_per_iter(ctx, classes, worst: bool = False):
     return _per_iter_ms(ctx, ns, worst)
 
 
-def glue_ms_per_iter(ctx):
-    chips = _chips(ctx)
-    if not chips:
-        return None
-    ns = [tr.class_ns(c, "glue") + tr.class_ns(c, "container") for c in chips]
-    return _per_iter_ms(ctx, ns)
-
-
 def launch_gap_ms(ctx):
     chips = _chips(ctx)
     if not chips:

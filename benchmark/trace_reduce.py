@@ -28,6 +28,7 @@ PALLAS_TARGETS = ("tpu_custom_call", "mosaic")
 _SHAPE = re.compile(r"\b(?:pred|[a-z]+\d+(?:e\d+m\d+\w*)?)\[([\d,]*)\]")
 _TARGET = re.compile(r'custom_call_target="([^"]+)"')
 _HOST_PREFIX = "bench."
+_SUFFIX = re.compile(r"(\.\d+)+$")     # ``fusion.3`` -> ``fusion``
 
 
 # ------------------------------------------------------------ HLO text
@@ -61,7 +62,7 @@ def parse_hlo(text: str) -> dict:
     if " = " not in text:
         name = text.lstrip("%")
         out["instr"] = name
-        out["opcode"] = re.sub(r"(\.\d+)+$", "", name)
+        out["opcode"] = _SUFFIX.sub("", name)
         return out
     lhs, rest = text.split(" = ", 1)
     out["instr"] = lhs.strip().lstrip("%")
@@ -183,16 +184,18 @@ def is_pallas(op: dict) -> bool:
 
 def match_build(op: dict, builds) -> dict | None:
     """The recorded ``pallas_call`` build whose call this custom-call is:
-    same number of operands and the same result shapes (the trace names no
-    kernel, so shapes are all there is)."""
+    same number of operands and the same result shapes. Where several
+    builds share both (a transfer onto a level and the box operator on it),
+    the one whose ``name=`` is the stem of the instruction's name
+    (``hpcg_prolong.1`` -> ``hpcg_prolong``); an older trace names no
+    kernel, and then the first such build is all there is."""
     want = sorted(op["results"])
-    hits = [b for b in builds
-            if sorted(tuple(s) for s in b["out_shapes"]) == want
-            and b["n_operands"] == len(op["operands"])]
-    if not hits:
-        hits = [b for b in builds
-                if sorted(tuple(s) for s in b["out_shapes"]) == want]
-    return hits[0] if hits else None
+    same = [b for b in builds
+            if sorted(tuple(s) for s in b["out_shapes"]) == want]
+    hits = [b for b in same if b["n_operands"] == len(op["operands"])] or same
+    stem = _SUFFIX.sub("", op["instr"])
+    named = [b for b in hits if b.get("name") == stem]
+    return (named or hits or [None])[0]
 
 
 def classify(trace: dict, kernels: dict, builds) -> None:

@@ -5,6 +5,7 @@ harness in-process at the rehearsal size (the test session's JAX is the
 from __future__ import annotations
 
 import argparse
+import copy
 import json
 import os
 import subprocess
@@ -24,6 +25,28 @@ def bench() -> dict:
 
 
 CELLS = [w["name"] for w in bench()["workloads"]]
+
+
+def one_more(b: dict) -> dict:
+    """A copy of ``b`` as the next PR leaves it: one configuration, one cell
+    (it joins every list ``mg512.steady`` is in) and one per-layer metric
+    APPENDED, nothing that was there moved. The tests that hold an entry to
+    its place take this and still pass."""
+    b = copy.deepcopy(b)
+    b["configs"].append({"name": "next-config", "source": "none",
+                         "file": "benchmark/configs/next-config.json",
+                         "reduced": [], "why": "test"})
+    b["workloads"].append({"name": "next.cell", "config": "next-config",
+                           "traffic": "steady", "chips": 1, "why": "test"})
+    for m in b["end_to_end"] + b["per_layer"]:
+        if "mg512.steady" in m.get("workloads", ()):
+            m["workloads"].append("next.cell")
+    b["per_layer"].append({"name": "next_metric", "unit": "ms",
+                           "better": "lower", "source": "program_span",
+                           "layer": "Stencil kernels",
+                           "moves": "mcells_per_s_per_chip",
+                           "workloads": ["next.cell"]})
+    return b
 
 
 def app_of(cell: str) -> str:

@@ -8,7 +8,7 @@ import os
 
 import pytest
 
-from _bench_util import BENCH_DIR, bench
+from _bench_util import BENCH_DIR, bench, one_more
 from benchmark import compile_lib, scope_lib
 from benchmark.harness import load_module
 
@@ -26,8 +26,6 @@ def program(monkeypatch):
     rec = telemetry.Recorder()
     monkeypatch.setattr(telemetry, "_recorder", rec)
     monkeypatch.setattr(telemetry, "_watcher", None)
-    # the readers run 70 s after run() started
-    monkeypatch.setattr(compile_lib.time, "time_ns", lambda: T0 + 70 * S)
     return rec
 
 
@@ -77,9 +75,11 @@ def _run(rec, missed=False):
 
 
 def _ctx(lines, chips=True, window_s=4.0):
+    """The window opens 30 s after run() started, on the spans' clock."""
     return {"trace": {"chips": [{"ops": []}] if chips else [], "host": []},
             "say": lines.append, "phases": {"app_run": 27.0},
-            "window": {"iterations": 2, "seconds": window_s}}
+            "window": {"iterations": 2, "seconds": window_s,
+                       "t0_ns": T0 + 30 * S}}
 
 
 def _read(ctx):
@@ -112,10 +112,10 @@ def test_the_readers_sum_by_ancestor_and_leave_out_what_is_outside(program):
             "0.375") in text
     assert ("outside run(), after the window (checks, readers, op_map): "
             "trace 0.000, lower 2.000, backend 0.000") in text
-    # the window lies where nothing compiled: seeding ended at 27.375 s,
-    # op_map lowered from 60 s on
-    assert ("the longest stretch after run() with no stage record is "
-            "32.625 s; the measured window is 4.000 s: it fits") in text
+    # the window's own clock places it: run() ended at 26.1 s, seeding at
+    # 27.375 s, the window ran from 30 to 34 s, op_map lowered from 60 s on
+    assert ("the measured window opened 3.900 s after run() and lasted "
+            "4.000 s: no stage record lies inside it") in text
     # four readers, one table
     assert sum("run() spent" in line for line in lines) == 1
 
@@ -132,19 +132,47 @@ def test_a_cold_run_counts_its_misses_named_and_folded(program):
 
 
 def test_a_stage_inside_the_window_is_called_out(program):
-    """The trace's clock starts at the trace, so the window is placed by
-    what it excludes: with a stage every 8 s after run() no stretch is as
-    long as a 20 s window."""
+    """``harness.run_window`` reads ``time.time_ns()`` as the window opens,
+    the clock the program's spans carry: of a stage every 8 s after run(),
+    the two that start inside a window from 30 to 50 s are named, and one
+    that straddles its opening is inside it too."""
     _run(program)
-    for at in (35.0, 43.0, 51.0):
+    for at in (29.75, 35.0, 43.0, 51.0):
         _span(program, "compile.backend", at, 0.5,
               fun="stencil_astaroth_iter", module="stencil_astaroth_iter",
               cache="miss")
     lines = []
     _read(_ctx(lines, window_s=20.0))
-    assert any("the measured window is 20.000 s: A STAGE RAN INSIDE THE "
-               "WINDOW" in line for line in lines)
-    assert compile_lib.quiet_stretch([], 5, 9) == (5, 9)
+    text = "\n".join(lines)
+    assert ("outside run(), INSIDE THE MEASURED WINDOW: trace 0.000, lower "
+            "0.000, backend 1.500") in text
+    assert ("lasted 20.000 s: 3 STAGE RECORD(S) LIE INSIDE THE WINDOW"
+            ) in text
+    assert ("outside run(), after the window (checks, readers, op_map): "
+            "trace 0.000, lower 2.000, backend 0.500") in text
+
+
+def test_the_window_carries_the_clock_the_programs_spans_carry():
+    """``run_window`` reads ``time.time_ns()`` once, as the window opens and
+    before the clock it times with: the window lies after it, whole."""
+    import contextlib
+    import time
+
+    from benchmark import harness
+
+    class Session:
+        facts = {"iters_per_dispatch": 1}
+
+        def dispatch(self):
+            return 0
+
+    before = time.time_ns()
+    window = harness.run_window(Session(), 0.02,
+                                lambda name: contextlib.nullcontext())
+    after = time.time_ns()
+    assert window["dispatches"] > 0 and window["seconds"] >= 0.02
+    assert before <= window["t0_ns"]
+    assert window["t0_ns"] + int(window["seconds"] * 1e9) <= after + 5_000_000
 
 
 def test_a_recompile_in_the_steps_is_a_child_of_the_steps(program):
@@ -210,7 +238,7 @@ def test_an_untraced_reading_places_no_window(program):
     _run(program)
     lines = []
     out = compile_lib.split(program.records(kind="span"))
-    compile_lib.table(out, None, None, lines.append)
+    compile_lib.table(out, None, lines.append)
     text = "\n".join(lines)
     assert "the measured window" not in text
     assert "outside run(), after run(): " in text
@@ -225,6 +253,38 @@ def test_every_new_entry_has_its_reader_file_and_the_cells_of_the_split(name):
     assert entry == dict(per_layer["app_run_compile_s"], name=name,
                          unit=READERS[name])
     assert entry["workloads"] == [w["name"] for w in bench()["workloads"]]
-    # appended: the entries that were there come first, in their order
-    names = [m["name"] for m in bench()["per_layer"]]
-    assert names[-4:] == list(READERS)
+    assert in_their_place(bench())
+
+
+def in_their_place(b: dict) -> bool:
+    """The four entries one after the other, in their order, after
+    ``halo_sent_share``, the last entry that was there before them. (Not
+    "the end of the list": whatever a later PR appends comes after.)"""
+    names = [m["name"] for m in b["per_layer"]]
+    at = names.index("halo_sent_share") + 1
+    return names[at:at + len(READERS)] == list(READERS)
+
+
+def _moved(b, name, before=None):
+    """``one_more(b)`` with one entry moved in front of another, or to the
+    end."""
+    b = one_more(b)
+    entries = b["per_layer"]
+    names = [m["name"] for m in entries]
+    entry = entries.pop(names.index(name))
+    names.remove(name)
+    entries.insert(names.index(before) if before else len(entries), entry)
+    return b
+
+
+@pytest.mark.parametrize("case, make, held", [
+    ("as committed", lambda b: b, True),
+    ("a cell and a metric appended", one_more, True),
+    ("two of the four swapped",
+     lambda b: _moved(b, "app_run_lower_s", "app_run_trace_s"), False),
+    ("one of the four moved to the end",
+     lambda b: _moved(b, "app_run_trace_s"), False),
+    ("an entry put between them and what was there",
+     lambda b: _moved(b, "next_metric", "app_run_trace_s"), False)])
+def test_the_four_are_held_to_their_order_not_to_the_end(case, make, held):
+    assert in_their_place(make(bench())) is held, case

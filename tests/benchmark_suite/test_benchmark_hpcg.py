@@ -1,7 +1,8 @@
 """What the HPCG cell brings: its adapter at the rehearsal size (the seeded
 state on host and device, the boxes, the two controls, a broken timed
-path), what holds the program to the configuration's plan, the two kernel
-descriptions' bytes and operations, and the entries."""
+path), what holds the program to the configuration's plan, the kernel
+descriptions' bytes and operations (the two transfers' against the
+program's own plan and a recorded build), and the entries."""
 
 import os
 
@@ -235,21 +236,130 @@ def test_half_a_sweep_moves_eight_bytes_a_cell_and_updates_half_the_rows():
         8 * 256 ** 3
 
 
+# (description, the RESULT's block, its level's cells, bytes a call by hand)
+TRANSFERS = [
+    # injection into 256^3: 2 x 256^3 fine cells read, 256^3 written
+    ("hpcg_restrict", (258, 272, 256), 256 ** 3, 201_326_592),
+    ("hpcg_restrict", (130, 144, 128), 128 ** 3, 25_165_824),
+    # prolongation onto 512^3: 256^3 read, 2 x 256^3 read and written back
+    ("hpcg_prolong", (514, 528, 512), 512 ** 3, 335_544_320),
+    ("hpcg_prolong", (258, 272, 256), 256 ** 3, 41_943_040),
+]
+
+
+@pytest.mark.parametrize("name, block, cells, by_hand", TRANSFERS)
+def test_a_transfer_is_charged_what_it_must_move(name, block, cells, by_hand):
+    mod = load_module("kernels", name)
+    assert mod.FAMILIES == (f"make_pallas_{name}",)
+    w = mod.work({"out_shapes": [block], "in_shapes": [block] * 3}, F512)
+    assert w["per"] == "call" and w["bytes"] == by_hand
+    # a quarter of the fine level and the coarse one, the fine quarter twice
+    # for the prolongation: 12 B a coarse cell, 2.5 B a fine cell
+    assert by_hand == {"hpcg_restrict": 12 * cells,
+                       "hpcg_prolong": 5 * cells // 2}[name]
+    assert w["flops"] == {"hpcg_restrict": 0, "hpcg_prolong": cells // 8}[name]
+    # what the kernel streams (whole even planes) is said, not charged
+    assert "lower bound" in w["note"] and "WHOLE" in w["note"]
+
+
+def test_the_four_transfer_calls_are_0_738_ms_at_the_hbm_peak():
+    """0.201 + 0.336 GB and an eighth of each a level down (PERF.md section
+    7, PR 52): charged the box's 12 B a cell of the level their result has
+    they read 2.489 ms, more than the 1.644 ms the calls take."""
+    least = sum(by_hand for *_, by_hand in TRANSFERS) / 819e9
+    assert least == pytest.approx(0.7375e-3, rel=1e-3)
+    box = load_module("kernels", "mg_box27")
+    as_boxes = sum(box.work({"out_shapes": [block]}, F512)["bytes"]
+                   for _, block, _, _ in TRANSFERS) / 819e9
+    assert as_boxes == pytest.approx(2.489e-3, rel=1e-3)
+
+
+def test_the_transfers_bytes_are_the_programs_own_plans():
+    """``hpcg.iter_plan`` gives every operator the least bytes a call moves
+    (``bytes_min``, by the FINE level of a transfer): the descriptions
+    count the same, from the result's block, at 512^3 <-> 256^3 and at
+    256^3 <-> 128^3."""
+    from types import SimpleNamespace
+
+    from stencil_tpu.ops import hpcg as ops
+
+    levels = [SimpleNamespace(
+        n=(n, n, n), number=ref.LEVELS - i, tight=n % 128 == 0,
+        ex=SimpleNamespace(spec=SimpleNamespace(
+            global_size=SimpleNamespace(x=n, y=n, z=n))))
+        for i, n in enumerate((512, 256, 128, 64))]
+    impls = {(i, name): "pallas" for i in range(3)
+             for name in ("hpcg_restrict", "hpcg_prolong")}
+    plan = ops.iter_plan(levels, impls, 4)
+    blocks = [(514, 528, 512), (258, 272, 256), (130, 144, 128)]
+    down, up = (load_module("kernels", n)
+                for n in ("hpcg_restrict", "hpcg_prolong"))
+    for i in (0, 1):
+        held = plan[i]["operators"]
+        assert down.work({"out_shapes": [blocks[i + 1]]}, F512)["bytes"] == \
+            held["hpcg_restrict"]["bytes_min"]
+        assert up.work({"out_shapes": [blocks[i]]}, F512)["bytes"] == \
+            held["hpcg_prolong"]["bytes_min"]
+
+
+def test_a_recorded_build_of_each_transfer_is_counted_from_its_shapes():
+    """The program's own builders between two tight-x levels, 256 x 32 x 16
+    onto 128 x 16 x 8, recorded as a run records them
+    (``capture.PallasBuilds``): family, ``name=``, operands, and the bytes
+    from the recorded result shape and the finest level's facts."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import capture
+    from stencil_tpu.apps import hpcg as app
+    from stencil_tpu.ops import hpcg as ops
+
+    was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)
+    try:
+        levels = app.make_levels((256, 32, 16), jax.devices()[:1], "float32")
+        with capture.PallasBuilds() as pallas:
+            built, fns, impls = ops._build(
+                [lv.halo_exchange for lv, _ in levels], jnp.dtype("float32"),
+                True, True)
+            fine, coarse = (jnp.zeros(lv.ex.spec.stacked_shape_zyx(),
+                                      jnp.float32) for lv in built[:2])
+            fns[(0, "hpcg_restrict")](fine, coarse)
+            fns[(0, "hpcg_prolong")](coarse, fine)
+    finally:
+        jax.config.update("jax_enable_x64", was)
+    assert impls[(0, "hpcg_restrict")] == impls[(0, "hpcg_prolong")] == "pallas"
+    spec = built[0].ex.spec
+    facts = capture.spec_facts(spec, 1, 4, 6)
+    got = {}
+    for name in ("hpcg_restrict", "hpcg_prolong"):
+        mod = load_module("kernels", name)
+        (build,) = [b for b in pallas.builds if b["kernel"] in mod.FAMILIES]
+        assert build["name"] == name and build["n_operands"] == 3
+        assert build["interpret"] and build["calls_traced"] == 1
+        got[name] = mod.work(build, facts)["bytes"]
+    fine_cells = 256 * 32 * 16
+    assert got == {"hpcg_restrict": 4 * 3 * fine_cells // 8,
+                   "hpcg_prolong": 4 * 5 * fine_cells // 8}
+    # every other build of the hierarchy carries its own name too
+    assert {b["name"] for b in pallas.builds} >= {
+        "hpcg_symgs", "hpcg_resid", "hpcg_spmv"}
+
+
 # ------------------------------------------------------------ the entries
 
 
 def test_the_cell_joins_the_shared_metrics_and_brings_none_of_its_own():
-    """No halo metric (one fixed block: no fill, no wire) and no per-layer
-    entry of its own: ``test_benchmark_compile_lib.py`` holds PR 38's four
-    entries to the END of ``per_layer`` (PERF.md section 7 has
-    ``solver_reduce_ms_per_iter`` for the ``benchmark`` issue that lets an
-    entry be appended)."""
+    """No halo metric (one fixed block: no fill, no wire); the reductions'
+    own metric, ``solver_reduce_ms_per_iter``, entry and reader, since PR
+    53 let an entry be appended after PR 38's four."""
     b = bench()
     (cell,) = [w for w in b["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "hpcg-512-f32", "steady", 1)
-    assert len(b["workloads"]) == 11
-    assert sum(w["chips"] == 4 for w in b["workloads"]) == 4
+    # the eleventh cell, four of the eleven on four chips; later cells follow
+    assert b["workloads"].index(cell) == 10
+    assert sum(w["chips"] == 4 for w in b["workloads"][:11]) == 4
     (config,) = [c for c in b["configs"] if c["name"] == "hpcg-512-f32"]
     assert config["reduced"] == ["dtype"]
     for word in ("HPCG 3.1", "GenerateProblem_ref.cpp", "CG_ref.cpp",
@@ -260,11 +370,13 @@ def test_the_cell_joins_the_shared_metrics_and_brings_none_of_its_own():
     assert joined == {
         "mcells_per_s_per_chip", "iter_ms_p95", "setup_s",
         "launch_gap_ms.app", "kernel_ms_per_iter", "kernel_scope_ms_per_iter",
-        "stencil_kernel_roofline", "xla_glue_ms_per_iter",
+        "stencil_kernel_roofline",
         "glue_program_ms_per_iter", "glue_compiler_ms_per_iter",
         "device_idle_share.app", "app_run_host_init_s", "app_run_compile_s",
         "app_run_steps_s", "app_run_trace_s", "app_run_lower_s",
-        "app_run_backend_s", "app_run_cache_misses"}
-    assert not any(os.path.exists(os.path.join(
-        BENCH_DIR, "layer_metrics", f"{name}.py"))
-        for name in ("solver_reduce_ms_per_iter",))
+        "app_run_backend_s", "app_run_cache_misses",
+        "solver_reduce_ms_per_iter"}
+    assert os.path.isfile(os.path.join(BENCH_DIR, "layer_metrics",
+                                       "solver_reduce_ms_per_iter.py"))
+    assert [m["workloads"] for m in b["per_layer"]
+            if m["name"] == "solver_reduce_ms_per_iter"] == [[CELL]]

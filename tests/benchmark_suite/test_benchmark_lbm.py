@@ -12,7 +12,7 @@ import os
 import numpy as np
 import pytest
 
-from _bench_util import BENCH_DIR, bench, open_session, rehearse
+from _bench_util import BENCH_DIR, bench, one_more, open_session, rehearse
 from benchmark import control, fields
 from benchmark.harness import load_module
 from benchmark.reference import lbm as ref
@@ -326,23 +326,73 @@ def test_the_pass_moves_152_bytes_a_cell_and_counts_the_references_terms():
 # ------------------------------------------------------------ the entries
 
 
-def test_the_cell_joins_mg512s_metrics_and_brings_none_of_its_own():
-    b = bench()
-    cell = next(w for w in b["workloads"] if w["name"] == CELL)
+def hold_the_entries(b: dict) -> None:
+    """The cell, its configuration and its place in every metric's list
+    come right AFTER ``mg512.steady``'s, the cell added before it: its
+    order among the cells it knew, not the end of the list, where every
+    later cell has to go."""
+    names = [w["name"] for w in b["workloads"]]
+    cell = b["workloads"][names.index(CELL)]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "lbm-d3q19-384-f32", "steady", 1)
-    assert b["workloads"][-1] is cell and b["configs"][-1]["name"] == (
-        "lbm-d3q19-384-f32")
-    assert b["configs"][-1]["reduced"] == []
+    assert names.index(CELL) == names.index("mg512.steady") + 1
+    configs = [c["name"] for c in b["configs"]]
+    assert configs.index("lbm-d3q19-384-f32") == \
+        configs.index("npb-mg-c-f32") + 1
+    assert b["configs"][configs.index("lbm-d3q19-384-f32")]["reduced"] == []
     for group in ("end_to_end", "per_layer"):
         for m in b[group]:
             lists = m.get("workloads")
             if lists is None:
                 continue
+            # mg512.steady's own metric (its coarse levels') is not shared
+            if m["name"] == "mg_coarse_ms_per_iter":
+                assert CELL not in lists
+                continue
             assert ("mg512.steady" in lists) == (CELL in lists), m["name"]
             if CELL in lists:
-                assert lists[-1] == CELL
+                assert lists.index(CELL) == lists.index("mg512.steady") + 1, \
+                    m["name"]
     assert not any("lbm" in m["name"] for m in b["per_layer"])
+
+
+def _reordered(what):
+    def make(b):
+        b = one_more(b)
+        if what == "cell":          # where the end-of-list test wanted it
+            cells = b["workloads"]
+            cells.append(cells.pop([w["name"] for w in cells].index(CELL)))
+        elif what == "config":
+            configs = b["configs"]
+            configs.append(configs.pop([c["name"] for c in configs].index(
+                "lbm-d3q19-384-f32")))
+        else:                       # in ONE metric's list
+            lists = next(m for m in b["per_layer"]
+                         if m["name"] == what)["workloads"]
+            lists.remove(CELL)
+            lists.insert(0, CELL)
+        return b
+    return make
+
+
+@pytest.mark.parametrize("case, make, held", [
+    ("as committed", lambda b: b, True),
+    ("a cell and a metric appended", one_more, True),
+    ("the cell moved to the end", _reordered("cell"), False),
+    ("its configuration moved to the end", _reordered("config"), False),
+    ("its place in one metric's list moved",
+     _reordered("kernel_scope_ms_per_iter"), False)])
+def test_the_entries_are_held_to_their_order_not_to_the_end(case, make, held):
+    b = make(bench())
+    if held:
+        hold_the_entries(b)
+    else:
+        with pytest.raises(AssertionError):
+            hold_the_entries(b)
+
+
+def test_the_cell_joins_mg512s_metrics_and_brings_none_of_its_own():
+    hold_the_entries(bench())
     with open(os.path.join(BENCH_DIR, "configs",
                            "lbm-d3q19-384-f32.json")) as f:
         held = json.load(f)

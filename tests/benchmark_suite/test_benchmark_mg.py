@@ -390,14 +390,13 @@ def test_the_transfers_count_the_fine_level_and_an_eighth():
 
 
 def test_the_cell_joins_the_shared_metrics_and_brings_none_of_its_own():
-    """``mg_coarse_ms_per_iter`` (ISSUE 40) is NOT here, entry or reader:
-    ``test_benchmark_compile_lib.py`` holds PR 38's four entries to the END
-    of ``per_layer`` and the driver reads an entry put before them as a
-    change to what was there, so it waits for a ``benchmark`` PR (PERF.md
-    section 7 has the entry and what the reader read)."""
+    """``mg_coarse_ms_per_iter`` (ISSUE 40) is its own, entry and reader,
+    since PR 53 let an entry be appended after PR 38's four."""
     b = bench()
-    assert not os.path.exists(os.path.join(BENCH_DIR, "layer_metrics",
-                                           "mg_coarse_ms_per_iter.py"))
+    assert os.path.isfile(os.path.join(BENCH_DIR, "layer_metrics",
+                                       "mg_coarse_ms_per_iter.py"))
+    assert [m["workloads"] for m in b["per_layer"]
+            if m["name"] == "mg_coarse_ms_per_iter"] == [[CELL]]
     (cell,) = [w for w in b["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "npb-mg-c-f32", "steady", 1)
@@ -407,10 +406,11 @@ def test_the_cell_joins_the_shared_metrics_and_brings_none_of_its_own():
               if CELL in m.get("workloads", [CELL])}
     assert joined == {
         "mcells_per_s_per_chip", "iter_ms_p95", "setup_s",
-        "launch_gap_ms.app", "halo_dev_ms.app", "halo_scope_ms.app",
+        "launch_gap_ms.app", "halo_scope_ms.app",
         "kernel_ms_per_iter", "kernel_scope_ms_per_iter",
-        "stencil_kernel_roofline", "xla_glue_ms_per_iter",
+        "stencil_kernel_roofline",
         "glue_program_ms_per_iter", "glue_compiler_ms_per_iter",
         "device_idle_share.app", "app_run_host_init_s", "app_run_compile_s",
         "app_run_steps_s", "app_run_trace_s", "app_run_lower_s",
-        "app_run_backend_s", "app_run_cache_misses"}
+        "app_run_backend_s", "app_run_cache_misses",
+        "mg_coarse_ms_per_iter"}

@@ -62,10 +62,12 @@ def test_without_the_counter_the_metric_is_left_out(recorder, monkeypatch):
 
 
 def test_the_entry_names_the_cells_that_build_a_multistep():
-    """Later cells may join the list; these two are where the reader has
-    something to read today."""
+    """Later cells may join the list; these three are where the reader has
+    something to read today (the four-chip cell runs the same multistep on
+    a deep halo: 1.7 % of its rows are recomputed)."""
     (m,) = [m for m in bench()["per_layer"] if m["name"] == NAME]
     assert (m["unit"], m["better"], m["source"], m["layer"], m["moves"]) == (
         "%", "lower", "program_counter", "Stencil kernels",
         "mcells_per_s_per_chip")
-    assert {"jacobi512.steady", "jacobi768.steady"} <= set(m["workloads"])
+    assert {"jacobi512.steady", "jacobi768.steady",
+            "jacobi512x4.weak"} <= set(m["workloads"])

@@ -182,7 +182,6 @@ def test_recorded_numbers_of_the_flagship_cell():
     ctx = {"trace": trace, "window": {"iterations": 60, "seconds": 0.1}}
     assert layer_lib.class_ms_per_iter(ctx, ("stencil",)) == pytest.approx(
         0.98, abs=0.01)
-    assert layer_lib.glue_ms_per_iter(ctx) == pytest.approx(0.504, abs=0.01)
     assert layer_lib.class_ms_per_iter(ctx, ("halo", "collective")) is None
 
 
@@ -197,6 +196,78 @@ def test_a_pallas_call_no_recorded_build_matches_is_glue():
     assert len(calls) == 6 and {op["cls"] for op in calls} == {"glue"}
     ctx = {"trace": trace, "window": {"iterations": 60, "seconds": 0.1}}
     assert layer_lib.class_ms_per_iter(ctx, ("stencil",)) is None
+
+
+# a level's block: the box operator on it and the prolongation onto it have
+# the same result shape and three operands each (chip, PR 52)
+BLOCK = (514, 528, 512)
+BOX = {"kernel": "make_pallas_mg_box", "name": "hpcg_resid",
+       "out_shapes": [BLOCK], "n_operands": 3}
+PROLONG = {"kernel": "make_pallas_hpcg_prolong", "name": "hpcg_prolong",
+           "out_shapes": [BLOCK], "n_operands": 3}
+
+
+def _call(instr, operands=3, shape=BLOCK):
+    return {"instr": instr, "opcode": "custom-call", "target":
+            "tpu_custom_call", "results": [shape],
+            "operands": [shape] * operands}
+
+
+@pytest.mark.parametrize("builds", [[BOX, PROLONG], [PROLONG, BOX]],
+                         ids=["box built first", "transfer built first"])
+def test_equal_shapes_and_operand_counts_are_told_apart_by_name(builds):
+    """Never by build order: the build whose ``name=`` is the stem of the
+    instruction's name."""
+    assert tr.match_build(_call("hpcg_prolong.1"), builds) is PROLONG
+    assert tr.match_build(_call("hpcg_resid.3"), builds) is BOX
+    assert tr.match_build(_call("hpcg_resid"), builds) is BOX
+
+
+def test_without_a_name_the_shapes_decide_as_before():
+    builds = [BOX, PROLONG]
+    # an older trace names no kernel; an older record holds no name
+    assert tr.match_build(_call("closed_call.7"), builds) is BOX
+    bare = [{k: v for k, v in b.items() if k != "name"} for b in builds]
+    assert tr.match_build(_call("hpcg_prolong.1"), bare) is bare[0]
+    # the operand count still comes first, a name never overrides a shape
+    four = dict(PROLONG, n_operands=4)
+    assert tr.match_build(_call("hpcg_prolong.1"), [BOX, four]) is BOX
+    assert tr.match_build(_call("hpcg_prolong.1", shape=(258, 272, 256)),
+                          builds) is None
+    assert tr.match_build(_call("hpcg_prolong.1", operands=5),
+                          [four]) is four       # by shape alone, as before
+
+
+def test_hpcgs_transfers_are_classed_under_their_own_descriptions():
+    """The four transfer calls of ``hpcg512.steady`` and the box calls on
+    the levels they write, through ``classify`` with the configuration's
+    own kernel lists."""
+    config = load_json(ROOT, "benchmark", "configs", "hpcg-512-f32.json")
+    kernels = {role: {n: load_module("kernels", n) for n in names}
+               for role, names in config["kernels"].items()}
+    mid, low = (258, 272, 256), (130, 144, 128)
+    builds = [dict(BOX), dict(BOX, out_shapes=[mid]),
+              dict(PROLONG), dict(PROLONG, out_shapes=[mid]),
+              {"kernel": "make_pallas_hpcg_restrict", "name": "hpcg_restrict",
+               "out_shapes": [mid], "n_operands": 3},
+              {"kernel": "make_pallas_hpcg_restrict", "name": "hpcg_restrict",
+               "out_shapes": [low], "n_operands": 3},
+              # no description: never a candidate
+              {"kernel": "make_something_else", "name": "hpcg_prolong",
+               "out_shapes": [BLOCK], "n_operands": 3}]
+    calls = {"hpcg_resid.3": (BLOCK, "mg_box27"),
+             "hpcg_resid.2": (mid, "mg_box27"),
+             "hpcg_prolong.1": (BLOCK, "hpcg_prolong"),
+             "hpcg_prolong": (mid, "hpcg_prolong"),
+             "hpcg_restrict.1": (mid, "hpcg_restrict"),
+             "hpcg_restrict": (low, "hpcg_restrict")}
+    ops = [dict(_call(instr, shape=shape), start=0.0, dur=1.0, self=1.0)
+           for instr, (shape, _) in calls.items()]
+    trace = {"chips": [{"ops": ops}]}
+    tr.classify(trace, kernels, builds)
+    assert {op["instr"]: (op["cls"], op["kernel"]) for op in ops} == {
+        instr: ("stencil", kernel) for instr, (_, kernel) in calls.items()}
+    assert all(op["build"]["out_shapes"] == op["results"] for op in ops)
 
 
 def test_four_chip_trace_exposes_its_collectives():
@@ -221,7 +292,7 @@ def test_four_chip_exchange_waits_over_a_millisecond_for_its_collectives():
                           "collective_exposed_ms.exch").read(ctx)
     assert 1.0 < exposed < 3.0
     halo = layer_lib.class_ms_per_iter(ctx, ("halo", "collective"))
-    assert exposed < halo < 0.1 * layer_lib.glue_ms_per_iter(ctx)
+    assert exposed < halo
     for chip in trace["chips"]:
         assert tr.in_flight(chip), "no collective found on a chip"
 
@@ -231,5 +302,4 @@ def test_a_cpu_trace_has_nothing_to_read(tmp_path):
     ctx = {"trace": trace, "window": {"iterations": 10, "seconds": 1.0}}
     assert layer_lib.launch_gap_ms(ctx) is None
     assert layer_lib.idle_share(ctx) is None
-    assert layer_lib.glue_ms_per_iter(ctx) is None
     assert tr.breakdown(trace) == {"device_ops": [], "idle_gaps": []}
