@@ -276,7 +276,8 @@ def check_pure_stdlib(ctx: FileContext) -> List[Finding]:
 # -- rule: telemetry-vocab ----------------------------------------------------
 
 _RECORD_NAME_ARG = {"counter": 0, "gauge": 0, "span": 0, "open_span": 0,
-                    "child_span": 0, "meta": 0, "emit": 1}
+                    "child_span": 0, "chunk_span": 0, "meta": 0,
+                    "emit": 1}
 
 _vocab_cache: Optional[frozenset] = None
 

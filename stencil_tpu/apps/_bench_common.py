@@ -6,7 +6,6 @@ loop (reference: bin/exchange_weak.cu:140-196)."""
 from __future__ import annotations
 
 import os
-import time
 from typing import Optional, Sequence
 
 import jax
@@ -14,10 +13,10 @@ import jax.numpy as jnp
 
 from ..api import DistributedDomain
 from ..geometry import Dim3, Radius
-from ..obs import telemetry
+from ..obs import scopes, telemetry
 from ..parallel import IntraNodeRandom, Method, NodeAware, Trivial
 from ..utils.statistics import Statistics
-from ..utils.sync import hard_sync
+from ..utils.sync import hard_sync, timed_chunk
 
 
 def add_metrics_flags(p, dma: bool = False) -> None:
@@ -297,15 +296,12 @@ def time_exchange(
     done = 0
     while done < iters:
         k = min(chunk, iters - done)
-        t0_ns, t0 = time.time_ns(), time.perf_counter()
-        state = loops[k](state)
-        hard_sync(state)
-        per = (time.perf_counter() - t0) / k
+        state, marks = timed_chunk(scopes.EXCHANGE_LOOP, loops[k], state)
+        per = marks.wall_s / k
         stats.insert(per)
         samples.append(per)
-        rec.child_span("exchange.iter", t0_ns, per, wall_s=per * k,
-                       phase="exchange", iters=k, method=method.value,
-                       batched=batch_quantities, **wtag)
+        rec.chunk_span("exchange.iter", marks, k, phase="exchange",
+                       method=method.value, batched=batch_quantities, **wtag)
         done += k
     dd._curr = dict(state)  # the loops donated the original buffers
     if rec.enabled:

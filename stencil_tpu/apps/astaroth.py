@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import argparse
 import os
-import time
 from typing import Optional
 
 import numpy as np
@@ -32,12 +31,12 @@ from ..astaroth.init import const_init, hash_init, radial_explosion_init
 from ..astaroth.integrate import FIELDS, make_astaroth_step, uses_pallas
 from ..astaroth.reductions import Reductions
 from ..geometry import Dim3, Radius, prime_factors
-from ..obs import telemetry
+from ..obs import scopes, telemetry
 from ..parallel import Method
 from ..apps._bench_common import placement_from_flags
 from ..utils import timer
 from ..utils.statistics import Statistics
-from ..utils.sync import hard_sync
+from ..utils.sync import hard_sync, timed_chunk
 from ..utils import logging as log
 
 DEFAULT_CONF = os.path.join(os.path.dirname(__file__), "..", "astaroth", "astaroth.conf")
@@ -234,6 +233,16 @@ def run(
 
     iter_time = Statistics()
     exch_time = Statistics()
+
+    def timed_exchange(loop, st, n):
+        """One exchange-only chunk of ``n`` exchanges: its span's seconds
+        are the whole chunk's."""
+        st, marks = timed_chunk(scopes.EXCHANGE_LOOP, loop, st)
+        exch_time.insert(marks.wall_s)
+        rec.chunk_span("astaroth.exchange", marks, n, per=marks.wall_s,
+                       phase="exchange")
+        return st, marks
+
     if no_compute:
         # measure pure exchange per substep (reference --no-compute flag)
         with rec.span("astaroth.warmup", phase="compile"):
@@ -242,14 +251,8 @@ def run(
             hard_sync(curr)
         end_steps = rec.open_span("astaroth.steps", phase="step")
         for _ in range(iters):
-            t0_ns, t0 = time.time_ns(), time.perf_counter()
-            curr = loop(curr)
-            hard_sync(curr)
-            dt_iter = time.perf_counter() - t0
-            iter_time.insert(dt_iter)
-            exch_time.insert(dt_iter)
-            rec.child_span("astaroth.exchange", t0_ns, dt_iter,
-                           phase="exchange", iters=3)
+            curr, marks = timed_exchange(loop, curr, 3)
+            iter_time.insert(marks.wall_s)
     else:
         chunk = max(1, min(chunk, iters))
         with rec.span("astaroth.warmup", phase="compile", iters=chunk):
@@ -325,29 +328,19 @@ def run(
                     at=injector.steps() if injector is not None else (),
                 )
 
-            chunk_t0_ns = 0
+            marks = None
 
             def step_fn(st, k):
-                nonlocal nxt, chunk_t0_ns
-                chunk_t0_ns = time.time_ns()
-                c, n2 = get_step(k)(st, nxt)
-                hard_sync(c)
-                nxt = n2
+                nonlocal nxt, marks
+                (c, nxt), marks = timed_chunk(scopes.ASTAROTH_ITER,
+                                              get_step(k), st, nxt)
                 return c
 
             def on_chunk(st, k, per, done_now):
                 for _ in range(k):
                     iter_time.insert(per)
-                rec.child_span("astaroth.iter", chunk_t0_ns, per,
-                               wall_s=per * k, phase="step", iters=k)
-                t1_ns, t1 = time.time_ns(), time.perf_counter()
-                st = exch_loop(st)
-                hard_sync(st)
-                ex_dt = time.perf_counter() - t1
-                exch_time.insert(ex_dt)
-                rec.child_span("astaroth.exchange", t1_ns, ex_dt,
-                               phase="exchange", iters=n_ex)
-                return st
+                rec.chunk_span("astaroth.iter", marks, k, per=per)
+                return timed_exchange(exch_loop, st, n_ex)[0]
 
             save_fn = restore_fn = quarantine_fn = flush_fn = None
             if ckpt_dir:
@@ -383,25 +376,16 @@ def run(
             next_ckpt = (start // ckpt_every + 1) * ckpt_every if (
                 ckpt_dir and ckpt_every > 0) else None
             while done < iters:
-                t0_ns, t0 = time.time_ns(), time.perf_counter()
-                curr, nxt = step(curr, nxt)
-                hard_sync(curr)
-                per = (time.perf_counter() - t0) / chunk
+                (curr, nxt), marks = timed_chunk(scopes.ASTAROTH_ITER, step,
+                                                 curr, nxt)
                 for _ in range(chunk):
-                    iter_time.insert(per)
-                rec.child_span("astaroth.iter", t0_ns, per,
-                               wall_s=per * chunk, phase="step", iters=chunk)
+                    iter_time.insert(marks.wall_s / chunk)
+                rec.chunk_span("astaroth.iter", marks, chunk)
                 done += chunk
                 if next_ckpt is not None and done >= next_ckpt and done < iters:
                     save_ckpt(done, curr)
                     next_ckpt = (done // ckpt_every + 1) * ckpt_every
-                t0_ns, t0 = time.time_ns(), time.perf_counter()
-                curr = exch_loop(curr)
-                hard_sync(curr)
-                ex_dt = time.perf_counter() - t0
-                exch_time.insert(ex_dt)
-                rec.child_span("astaroth.exchange", t0_ns, ex_dt,
-                               phase="exchange", iters=n_ex)
+                curr, _ = timed_exchange(exch_loop, curr, n_ex)
         if ckpt_dir:
             if done > start or start == 0:
                 save_ckpt(done, curr)  # the final state is always durable

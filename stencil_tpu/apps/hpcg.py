@@ -41,14 +41,14 @@ from jax import lax
 
 from ..api import DistributedDomain
 from ..geometry import Dim3
-from ..obs import telemetry
+from ..obs import scopes, telemetry
 from ..ops.hpcg import (COARSE, FINE, LEVELS, SCALARS, SET_ITERS,
                         level_radius, level_sizes, make_hpcg_iter)
 from ..ops.pallas_hpcg import DIAGONAL
 from ..utils import logging as log
 from ..utils import timer
 from ..utils.statistics import Statistics
-from ..utils.sync import hard_sync
+from ..utils.sync import hard_sync, timed_chunk
 
 
 def make_levels(size, devices, dtype: str):
@@ -206,13 +206,12 @@ def run(
     t_loop = time.perf_counter()
     for _ in range(int(sets)):
         for _ in range(SET_ITERS):
-            t0_ns, t0 = time.time_ns(), time.perf_counter()
-            state = step(state, b)
-            normr = float(state["normr"])       # what a stopping test reads
-            per = time.perf_counter() - t0
-            iter_time.insert(per)
-            rec.child_span("hpcg.iter", t0_ns, per, wall_s=per, phase="step",
-                           iters=1)
+            # the wait is for what a stopping test reads
+            state, marks = timed_chunk(scopes.HPCG_ITER, step, state, b,
+                                       scalar=lambda st: st["normr"])
+            normr = marks.value
+            iter_time.insert(marks.wall_s)
+            rec.chunk_span("hpcg.iter", marks, 1)
         normr0 = float(state["normr0"])
         relative.append(normr / normr0)
         errors.append(float(error(state["x"])))

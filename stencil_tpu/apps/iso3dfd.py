@@ -31,13 +31,13 @@ from jax import lax
 
 from ..api import DistributedDomain
 from ..geometry import Dim3, Radius, decompose_zy
-from ..obs import telemetry
+from ..obs import scopes, telemetry
 from ..ops.iso3dfd import DT, make_iso3dfd_step
 from ..ops.pallas_iso3dfd import RADIUS
 from ..utils import logging as log
 from ..utils import timer
 from ..utils.statistics import Statistics
-from ..utils.sync import hard_sync
+from ..utils.sync import hard_sync, timed_chunk
 
 QUANTITIES = ("prev", "next", "vel")
 VEL_SAMPLE = 2250000.0 * DT * DT        # the sample's constant v^2 dt^2: 9.0
@@ -138,14 +138,11 @@ def run(
     done = 0
     t_loop = time.perf_counter()
     while done < iters:
-        t0_ns, t0 = time.time_ns(), time.perf_counter()
-        prev, nxt = step(prev, nxt, vel)
-        hard_sync(prev)
-        per = (time.perf_counter() - t0) / chunk
+        (prev, nxt), marks = timed_chunk(scopes.ISO3DFD_LOOP, step, prev, nxt,
+                                         vel)
         for _ in range(chunk):
-            iter_time.insert(per)
-        rec.child_span("iso3dfd.iter", t0_ns, per, wall_s=per * chunk,
-                       phase="step", iters=chunk)
+            iter_time.insert(marks.wall_s / chunk)
+        rec.chunk_span("iso3dfd.iter", marks, chunk)
         done += chunk
     wall = time.perf_counter() - t_loop
     mcells = size.flatten() * done / wall / 1e6

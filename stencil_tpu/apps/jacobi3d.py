@@ -26,14 +26,14 @@ import jax.numpy as jnp
 
 from ..api import DistributedDomain
 from ..geometry import Dim3, prime_factors
-from ..obs import telemetry
+from ..obs import scopes, telemetry
 from ..ops.jacobi import INIT_TEMP, make_jacobi_loop, make_jacobi_step, sphere_sel
 from ..utils import timer
 from ..parallel import Method
 from ..parallel.exchange import shard_blocks
 from ..parallel.mesh import sharded_full
 from ..utils.statistics import Statistics
-from ..utils.sync import hard_sync
+from ..utils.sync import hard_sync, timed_chunk
 from ..utils import logging as log
 
 
@@ -312,20 +312,18 @@ def run(
     # valid snapshot with exponential backoff.
     iter_time = Statistics()
 
-    chunk_t0_ns = 0
+    marks = None
 
     def step_fn(st, k):
-        nonlocal nxt, chunk_t0_ns
-        chunk_t0_ns = time.time_ns()
-        c, n2 = get_loop(k)(st["temperature"], nxt, sel)
-        hard_sync(c)
-        nxt = n2
+        nonlocal nxt, marks
+        (c, nxt), marks = timed_chunk(
+            scopes.JACOBI_LOOP if k > 1 else scopes.JACOBI_STEP, get_loop(k),
+            st["temperature"], nxt, sel)
         return {"temperature": c}
 
     def on_chunk(st, k, per, done_now):
         iter_time.insert(per)
-        rec.child_span("jacobi.iter", chunk_t0_ns, per, wall_s=per * k,
-                       phase="step", iters=k)
+        rec.chunk_span("jacobi.iter", marks, k, per=per)
         if stepwise and done_now % paraview_every == 0:
             dd.set_curr(h, st["temperature"])
             dd.write_paraview(f"{prefix}jacobi3d_{done_now}")
@@ -455,16 +453,16 @@ def run(
             if tail:
                 slow_tail = FaultPlan(tail, seed=injector.seed)
         exch_samples = []
-        for i in range(3):
-            t0_ns, t0 = time.time_ns(), time.perf_counter()
+
+        def exchange(st, i):
             st = exch_loop(st)
-            if slow_tail is not None:
-                st = slow_tail.fire_due(st, iters + i, iters + i + 1)
-            hard_sync(st)
-            per = (time.perf_counter() - t0) / n_ex
-            exch_samples.append(per)
-            rec.child_span("jacobi.exchange", t0_ns, per,
-                           wall_s=per * n_ex, phase="exchange", iters=n_ex)
+            return st if slow_tail is None else slow_tail.fire_due(
+                st, iters + i, iters + i + 1)
+
+        for i in range(3):
+            st, marks = timed_chunk(scopes.EXCHANGE_LOOP, exchange, st, i)
+            exch_samples.append(marks.wall_s / n_ex)
+            rec.chunk_span("jacobi.exchange", marks, n_ex, phase="exchange")
         curr = st[h.idx]
         # per-phase attribution: pair the cost model's prediction for the
         # realized plan with the measured exchange share — the autotuner's

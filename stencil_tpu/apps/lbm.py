@@ -38,13 +38,13 @@ from jax import lax
 from ..api import DistributedDomain
 from ..astaroth.reductions import Reductions
 from ..geometry import Dim3, decompose_zy
-from ..obs import telemetry
+from ..obs import scopes, telemetry
 from ..ops.lbm import (Q, VELOCITIES, WEIGHTS, domain_radius, make_lbm_step,
                        omega_of, population_radius)
 from ..utils import logging as log
 from ..utils import timer
 from ..utils.statistics import Statistics
-from ..utils.sync import hard_sync
+from ..utils.sync import hard_sync, timed_chunk
 
 U0 = 0.05               # the vortex's peak speed, in lattice units
 DEFAULT_CHUNK = 5       # steps a dispatch
@@ -176,14 +176,10 @@ def run(
     done = 0
     t_loop = time.perf_counter()
     while done < steps:
-        t0_ns, t0 = time.time_ns(), time.perf_counter()
-        curr, nxt = step(curr, nxt)
-        hard_sync(curr)
-        per = (time.perf_counter() - t0) / chunk
+        (curr, nxt), marks = timed_chunk(scopes.LBM_STEP, step, curr, nxt)
         for _ in range(chunk):
-            step_time.insert(per)
-        rec.child_span("lbm.step", t0_ns, per, wall_s=per * chunk,
-                       phase="step", iters=chunk)
+            step_time.insert(marks.wall_s / chunk)
+        rec.chunk_span("lbm.step", marks, chunk)
         done += chunk
     wall = time.perf_counter() - t_loop
     mlups = size.flatten() * done / wall / 1e6

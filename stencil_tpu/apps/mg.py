@@ -33,13 +33,13 @@ from jax import lax
 from ..api import DistributedDomain
 from ..astaroth.reductions import Reductions
 from ..geometry import Dim3, decompose_zy
-from ..obs import telemetry
+from ..obs import scopes, telemetry
 from ..ops.mg import (S_LARGE, S_SMALL, level_radius, level_sizes,
                       make_mg_iter)
 from ..utils import logging as log
 from ..utils import timer
 from ..utils.statistics import Statistics
-from ..utils.sync import hard_sync
+from ..utils.sync import hard_sync, timed_chunk
 
 # class -> (cells an axis, iterations, the smoother's weights, the norm
 # NPB's verify holds the run to, to 1e-8 relative, in double precision)
@@ -248,14 +248,10 @@ def run(
     done = 0
     t_loop = time.perf_counter()
     while done < nit:
-        t0_ns, t0 = time.time_ns(), time.perf_counter()
-        state = step(state, v)
-        hard_sync(state)
-        per = (time.perf_counter() - t0) / chunk
+        state, marks = timed_chunk(scopes.MG_ITER, step, state, v)
         for _ in range(chunk):
-            iter_time.insert(per)
-        rec.child_span("mg.iter", t0_ns, per, wall_s=per * chunk,
-                       phase="step", iters=chunk)
+            iter_time.insert(marks.wall_s / chunk)
+        rec.chunk_span("mg.iter", marks, chunk)
         done += chunk
     wall = time.perf_counter() - t_loop
     mcells = n ** 3 * done / wall / 1e6

@@ -226,7 +226,8 @@ def _record_step_plan(ex, exteriors, iters, dtype, pallas_on, tight_x,
     """The counter ``astaroth.step_plan``, once a :func:`make_astaroth_step`
     build: the branch its ``iteration`` takes (mode, exchanges and shell
     passes an iteration), the rects a pass integrates from exchanged halos
-    and the cells they hold (benchmark reader shell_ns_per_cell)."""
+    and the cells they hold (no benchmark entry reads it: no cell's plan
+    has shells)."""
     spec = ex.spec
     if pallas_on:
         # the fused path exchanges once an iteration unless every substep

@@ -212,7 +212,8 @@ NAME_FIELDS = {
     # mesh, exchanges an iteration, the rects integrated from exchanged
     # halos beside the whole-block or interior pass and the cells they hold
     # (a block an iteration), a block's owned cells, and the bytes a chip
-    # sends an exchange by the plan (benchmark reader shell_ns_per_cell)
+    # sends an exchange by the plan (no benchmark entry reads it: the one
+    # reader, shell_ns_per_cell, lost its entry when no cell ran shells)
     "astaroth.step_plan": (("module", str), ("mode", str), ("pallas", bool),
                            ("tight_x", bool), ("blocks", int),
                            ("quantities", int), ("exchanges_per_iter", int),
@@ -566,6 +567,26 @@ class Recorder:
         return self.emit("span", name, phase=phase, seconds=seconds,
                          t0_ns=t0_ns, t1_ns=t0_ns + int(wall * 1e9),
                          parent=self._progress.get("span"), **tags)
+
+    def chunk_span(self, name: str, marks, iters: int,
+                   per: Optional[float] = None, phase: str = "step",
+                   **tags) -> dict:
+        """The :meth:`child_span` of one chunk of a step loop, from the
+        marks ``utils/sync.timed_chunk`` took where the chunk ran:
+        ``seconds`` an iteration (``per``, where the loop's caller timed
+        it: the guarded loops), ``t0_ns`` to ``t1_ns`` the chunk's wall,
+        ``iters``, and what the host did in it: ``enqueue_s`` until the
+        compiled call returned, ``wait_s`` from there until the wait
+        returned, ``sync`` what the wait was (``utils/sync.SYNCS``),
+        ``module`` the compiled loop (``scopes.MODULES``). Benchmark
+        readers ``chunk_over_window_ms.*`` / ``chunk_between_ms.*``
+        (``benchmark/chunk_lib.py``)."""
+        wall = marks.wall_s
+        return self.child_span(
+            name, marks.t0_ns, wall / iters if per is None else per,
+            wall_s=wall, phase=phase, iters=iters,
+            enqueue_s=marks.enqueue_s, wait_s=marks.wait_s, sync=marks.sync,
+            module=marks.module, **tags)
 
     def open_span(self, name: str, phase: Optional[str] = None, **tags):
         """:meth:`span` for a stretch of a long function that no ``with``

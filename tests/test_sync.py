@@ -1,4 +1,5 @@
-"""utils/sync.py (hard_sync) tests — the scalar-fetch completion barrier.
+"""utils/sync.py tests — the scalar-fetch completion barrier (hard_sync) and
+the one function that runs and times a chunk of a step loop (timed_chunk).
 
 hard_sync is the timing discipline every bench app rides (fetch one
 scalar, forcing completion of everything queued before it). Pinned here: it
@@ -10,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from stencil_tpu.utils.sync import hard_sync
+from stencil_tpu.utils.sync import SYNCS, hard_sync, timed_chunk
 
 
 def test_scalar_fetch_returns_first_element():
@@ -57,3 +58,18 @@ def test_sharded_stacked_array():
     )
     assert hard_sync(arr) == 1.5
     assert hard_sync({"q": arr}) == 1.5
+
+
+def test_timed_chunk_marks_the_call_and_the_wait():
+    # a loop that returns (curr, nxt): the wait is for the new state's leaf
+    loop = jax.jit(lambda x: (x + 1, x))
+    out, marks = timed_chunk("stencil_jacobi_loop", loop, jnp.zeros((4, 4)))
+    assert hard_sync(out[0]) == 1.0 and marks.value == 1.0
+    assert marks.sync == "hard_sync" and marks.module == "stencil_jacobi_loop"
+    assert marks.enqueue_s > 0 and marks.wait_s > 0 and marks.t0_ns > 0
+    assert marks.wall_s == marks.enqueue_s + marks.wait_s
+    # a scalar of the result that the caller reads anyway
+    out, marks = timed_chunk("m", lambda x: {"n": x.sum()}, jnp.ones(3),
+                             scalar=lambda o: o["n"])
+    assert (marks.value, marks.sync, marks.module) == (3.0, "scalar", "m")
+    assert {"hard_sync", "scalar"} == set(SYNCS)
